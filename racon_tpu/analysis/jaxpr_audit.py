@@ -18,8 +18,8 @@ device, no compilation:
   the geometry (`POA_RECOMPILE_BUDGET`, `ALIGN_RECOMPILE_BUDGET`).
   Every signature is one XLA compile at serving time; a geometry change
   that silently splits signatures is the biggest TPU latency cliff this
-  repo has hit (see docs/roadmap.md round-5 notes), so widening the
-  grid must consciously raise the literal.
+  repo has hit, so widening the grid must consciously raise the
+  literal.
 
 The audit traces through `jax.jit` wrappers (the pjit equation's inner
 jaxpr is walked recursively), so it sees exactly what XLA would lower.

@@ -21,22 +21,19 @@ from typing import Iterable
 from ..lint import FileContext, Violation
 from . import dotted_name
 
-#: Scope: the resilience package, the observability layer (trace spans
-#: must be monotonic or Perfetto renders negative durations), and the
-#: hw-session driver.
+#: Scope: the resilience package and the observability layer (trace
+#: spans must be monotonic or Perfetto renders negative durations).
 _SCOPED = (("resilience",), ("obs",))
-_SCOPED_FILES = ("racon_tpu/tools/hw_session.py",)
 
 
 class WallClockRule:
     id = "wall-clock"
-    doc = ("no time.time() in racon_tpu/resilience/, racon_tpu/obs/, or "
-           "tools/hw_session.py; deadlines, elapsed-time math, and trace "
-           "spans use time.monotonic()")
+    doc = ("no time.time() in racon_tpu/resilience/ or racon_tpu/obs/; "
+           "deadlines, elapsed-time math, and trace spans use "
+           "time.monotonic()")
 
     def check(self, ctx: FileContext) -> Iterable[Violation]:
-        if not (any(ctx.in_package(*p) for p in _SCOPED)
-                or ctx.relpath in _SCOPED_FILES):
+        if not any(ctx.in_package(*p) for p in _SCOPED):
             return
         # `from time import time` makes every bare time() call a
         # wall-clock read; track the local name it lands on.

@@ -104,6 +104,7 @@ def main(argv=None) -> int:
         return distrib_main(argv[1:])
     args = build_arg_parser().parse_args(argv)
 
+    from .device import DeviceUnavailable
     from .native import NativeError
     from .resilience import faults
     from .resilience.journal import JournalError
@@ -156,9 +157,10 @@ def main(argv=None) -> int:
             sys.stdout.write(f">{name}\n{data}\n")
         if args.report:
             polisher.report.write(args.report)
-    except JournalError as e:
+    except (JournalError, DeviceUnavailable) as e:
         # same single-line contract as a malformed fault spec: resuming
-        # against the wrong inputs must fail loudly before any compute
+        # against the wrong inputs, or --tpu without a TPU, must fail
+        # loudly before any compute
         print(e, file=sys.stderr)
         return 1
     except NativeError as e:

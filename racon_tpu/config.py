@@ -101,9 +101,6 @@ KNOBS: Dict[str, Knob] = {k.name: k for k in (
        "POA graph node capacity = factor x window length"),
     _k("RACON_TPU_ALIGN_COHORT", None, "int",
        "phase-1 jobs materialized per device cohort (default 64)"),
-    _k("RACON_TPU_COMPILE_CACHE", None, "str",
-       "persistent XLA compilation cache directory (default: uid-keyed "
-       "~/.cache path)"),
     _k("RACON_TPU_SHARD", "1", "bool",
        "shard kernel batches over the device mesh (0 forces "
        "single-device dispatch; output is byte-identical either way)"),
@@ -113,9 +110,6 @@ KNOBS: Dict[str, Knob] = {k.name: k for k in (
     _k("RACON_TPU_SHARD_MIN_BATCH", "0", "int",
        "smallest batch worth sharding (0 = one row per mesh shard); "
        "smaller batches dispatch single-device without padding"),
-    _k("RACON_TPU_FORCE_CPU", None, "bool",
-       "force the virtual-CPU backend before jax initializes (tools)",
-       scope="tools"),
     # -- resilience knobs -------------------------------------------------
     _k("RACON_TPU_TIER_RETRIES", "1", "int",
        "extra attempts per kernel tier before bisecting/demoting"),
@@ -179,7 +173,7 @@ KNOBS: Dict[str, Knob] = {k.name: k for k in (
        "bench entries (obs/costmodel.py; 0 disables)"),
     _k("RACON_TPU_MACHINE_PROFILE", "auto", "str",
        "machine profile for cost-model predictions: auto | cpu-host | "
-       "tpu-v4-lite (auto picks by backend platform)"),
+       "tpu-v5e (auto picks by platform and device_kind)"),
     _k("RACON_TPU_FLIGHT", "1", "bool",
        "always-on crash flight recorder: ring of the last N spans/events "
        "per process, dumped to the job dir on faults, TierDead, worker "
@@ -298,8 +292,6 @@ KNOBS: Dict[str, Knob] = {k.name: k for k in (
     _k("RACON_TPU_BENCH_PROFILE", "ont", "str",
        "benchmark read profile: ont | sr", scope="bench",
        affects_output=True),
-    _k("RACON_TPU_BENCH_LOG", None, "str",
-       "append one bench JSON line per run to this file", scope="bench"),
     _k("RACON_TPU_BENCH_FORCE_DEVICE", None, "bool",
        "treat the current backend as the measured device (CPU rehearsal)",
        scope="bench"),

@@ -88,22 +88,22 @@ def main(argv=None) -> int:
         raise SystemExit(143)
 
     signal.signal(signal.SIGTERM, _on_sigterm)
-    coord = Coordinator(
-        args.sequences, args.overlaps, args.targets, workdir,
-        args={
-            "window_length": args.window_length,
-            "quality_threshold": args.quality_threshold,
-            "error_threshold": args.error_threshold,
-            "trim": not args.no_trimming,
-            "fragment_correction": args.fragment_correction,
-            "match": args.match, "mismatch": args.mismatch,
-            "gap": args.gap, "num_threads": args.threads,
-        },
-        include_unpolished=args.include_unpolished,
-        backend="tpu" if args.tpu else "cpu",
-        workers=args.workers, chunks_hint=args.chunks,
-        trace_path=args.trace, report_path=args.report)
     try:
+        coord = Coordinator(
+            args.sequences, args.overlaps, args.targets, workdir,
+            args={
+                "window_length": args.window_length,
+                "quality_threshold": args.quality_threshold,
+                "error_threshold": args.error_threshold,
+                "trim": not args.no_trimming,
+                "fragment_correction": args.fragment_correction,
+                "match": args.match, "mismatch": args.mismatch,
+                "gap": args.gap, "num_threads": args.threads,
+            },
+            include_unpolished=args.include_unpolished,
+            backend="tpu" if args.tpu else "cpu",
+            workers=args.workers, chunks_hint=args.chunks,
+            trace_path=args.trace, report_path=args.report)
         result = coord.run(out_path, timeout=args.timeout or None)
     except (RuntimeError, TimeoutError, OSError) as e:
         print(f"[racon_tpu::distrib] {e}", file=sys.stderr)

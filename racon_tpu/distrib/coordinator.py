@@ -96,6 +96,9 @@ class Coordinator:
         self.include_unpolished = include_unpolished
         self.backend = backend
         self.n_workers = distrib_workers() if workers is None else workers
+        if backend == "tpu":
+            from ..device import check_device_workers
+            check_device_workers(self.n_workers, "distrib")
         self.chunks_hint = chunks_hint
         self.lease_ttl = (distrib_lease_ttl() if lease_ttl is None
                           else lease_ttl)

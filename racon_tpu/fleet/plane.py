@@ -143,6 +143,9 @@ class FleetPlane:
         self.max_retries = (distrib_max_retries() if max_retries is None
                             else max_retries)
         self.backend = backend
+        if backend == "tpu":
+            from ..device import check_device_workers
+            check_device_workers(self.max_workers, "fleet plane")
         self.trace_path = trace_path
         self.report_path = report_path
 

@@ -312,18 +312,22 @@ def served_sum_check(phases) -> dict:
 
 # -- export ----------------------------------------------------------------
 
-def _platform() -> Optional[str]:
-    """Backend platform for the trace provenance stamp.  Reads jax only
-    when the run already imported it (a traced polish always has) — this
-    module must stay importable, and write_trace callable, without a jax
-    dependency."""
-    jax = sys.modules.get("jax")
-    if jax is None:
+def _device() -> Optional[dict]:
+    """Backend platform / device_kind / device_count for the trace
+    provenance stamp.  Reads jax only when the run already imported it
+    (a traced device polish always has) — this module must stay
+    importable, and write_trace callable, without a jax dependency."""
+    if "jax" not in sys.modules:
         return None
+    from .. import device
+
     try:
-        return jax.devices()[0].platform
+        ident = device.identity()
     except Exception:  # noqa: BLE001 — provenance only, never fail a write
         return None
+    return {"platform": ident["platform"],
+            "device_kind": ident["device_kind"],
+            "device_count": ident["count"]}
 
 
 def write_trace() -> Optional[str]:
@@ -335,7 +339,7 @@ def write_trace() -> Optional[str]:
     if t is None or not path:
         return None
     try:
-        t.write(path, metrics=snapshot(), platform=_platform())
+        t.write(path, metrics=snapshot(), device=_device())
     except OSError as e:
         print(f"[racon_tpu::obs] WARNING: cannot write trace {path}: {e}",
               file=sys.stderr)

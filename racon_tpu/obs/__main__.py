@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 from typing import Dict, List, Tuple
 
@@ -248,13 +249,13 @@ def diff(old: dict, new: dict, threshold: float,
 # -- subcommands -----------------------------------------------------------
 
 def _profile_for(doc: dict, name: str) -> costmodel.MachineProfile:
-    """'auto' resolves from the platform stamped into the trace at write
-    time (falls back to cpu-host when absent)."""
-    platform = None
+    """'auto' resolves from the platform and device_kind stamped into
+    the trace at write time (cpu-host when no platform was stamped)."""
     od = doc.get("otherData")
-    if isinstance(od, dict):
-        platform = od.get("platform")
-    return costmodel.resolve_profile(name, platform)
+    if not isinstance(od, dict):
+        od = {}
+    return costmodel.resolve_profile(name, od.get("platform"),
+                                     od.get("device_kind"))
 
 
 def cmd_model(args) -> int:
@@ -304,9 +305,12 @@ def cmd_bench(args) -> int:
         print(f"[obs] bench history problem: {p}", file=sys.stderr)
     if problems:
         return 2
-    if not entries:
-        print("[obs] no bench history found", file=sys.stderr)
+    if not os.path.isdir(args.root):
+        print(f"[obs] no such bench root: {args.root}", file=sys.stderr)
         return 2
+    if not entries:
+        print("[obs] no bench history: nothing to gate")
+        return 0
     result = bench_track.trend(entries, threshold=args.threshold,
                                min_delta_s=args.min_delta_s)
     if args.as_json:
@@ -341,7 +345,7 @@ def merge_traces(docs: List[dict], paths: List[str]) -> dict:
     events: List[dict] = []
     processes: List[dict] = []
     counters: Dict[str, int] = {}
-    platform = None
+    device = {}
     dropped = 0
     for doc, path, t0 in zip(docs, paths, t0s):
         dt_us = ((t0 - base) // 1000) if (t0 is not None
@@ -365,7 +369,9 @@ def merge_traces(docs: List[dict], paths: List[str]) -> dict:
                 continue
         od = doc.get("otherData") if isinstance(doc.get("otherData"),
                                                 dict) else {}
-        platform = platform or od.get("platform")
+        for k in ("platform", "device_kind", "device_count"):
+            if od.get(k) and k not in device:
+                device[k] = od[k]
         processes.append({
             "path": path, "pid": od.get("pid"), "role": od.get("role"),
             "trace_id": od.get("trace_id"), "t0_monotonic_ns": t0,
@@ -373,8 +379,7 @@ def merge_traces(docs: List[dict], paths: List[str]) -> dict:
         })
     other = {"tool": "racon_tpu.obs", "clock": "monotonic",
              "dropped_events": dropped, "merged_from": list(paths)}
-    if platform:
-        other["platform"] = platform
+    other.update(device)
     merged = {
         "traceEvents": events,
         "displayTimeUnit": "ms",
@@ -614,7 +619,7 @@ def _sub_parser() -> argparse.ArgumentParser:
 
     b = sub.add_parser("bench",
                        help="trend + regression gate over BENCH_r*.json "
-                            "and docs/device_bench_log.jsonl")
+                            "and the entry files named")
     b.add_argument("extra", nargs="*",
                    help="extra bench-entry JSON file(s) appended to the "
                         "history (newest last) — CI injects a synthetic "

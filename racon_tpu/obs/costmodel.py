@@ -18,7 +18,7 @@ Three layers:
 * **MachineProfile** — peak FLOP/s, HBM bandwidth, serial-step latency,
   host engine cell rates, and the prediction-error bound the profile
   *declares* it can hold.  ``cpu-host`` (this repo's CI box class) and
-  ``tpu-v4-lite`` (anchored to the dp_cost_probe measurements in
+  ``tpu-v5e`` (published peaks; serial step from dp_cost_probe, see
   docs/benchmarks.md) ship built in.
 * **Roofline verdict** — predicted wall = max(compute, bandwidth,
   serial-step term); whichever term wins classifies the bucket as
@@ -39,7 +39,7 @@ from __future__ import annotations
 import math
 import re
 from dataclasses import dataclass
-from typing import Dict, List, NamedTuple, Optional
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 # -- kernel grid constants (mirrored from racon_tpu.ops; parity-tested) ----
 
@@ -144,6 +144,9 @@ class MachineProfile:
     host_poa_cells_per_s: float  # host SIMD POA engine
     host_align_cells_per_s: float  # host Myers aligner
     error_bound_ratio: float     # declared validate bound (>= 1)
+    #: ``jax.devices()[0].device_kind`` strings this profile describes;
+    #: 'auto' resolves a TPU by this and nothing else
+    device_kinds: Tuple[str, ...] = ()
 
 
 PROFILES: Dict[str, MachineProfile] = {p.name: p for p in (
@@ -173,15 +176,22 @@ PROFILES: Dict[str, MachineProfile] = {p.name: p for p in (
         error_bound_ratio=8.0,
     ),
     MachineProfile(
-        name="tpu-v4-lite",
-        description="single TPU chip of the v4-lite/v5e class; "
-                    "serial_step_s anchored to the dp_cost_probe "
-                    "measurement (~2.7 us/rank at production geometry, "
-                    "docs/benchmarks.md)",
-        clock_hz=9.4e8,
-        peak_flops=2.0e12,           # VPU f32/i32 class, one core
-        hbm_bytes_per_s=4.0e11,
-        serial_step_s=2.7e-6,        # measured: latency-bound rank loop
+        name="tpu-v5e",
+        description="one TPU v5e chip. Peaks as published (Google Cloud "
+                    "documentation, 'TPU v5e'): 197 TFLOP/s bf16, 393 "
+                    "TOP/s int8, 819 GB/s HBM; the clock follows from "
+                    "the bf16 peak (4 MXUs x 128x128 MACs x 2). The "
+                    "int32 DP kernels run on the VPU, far below the MXU "
+                    "peak, so the compute term is a floor, not an "
+                    "estimate. serial_step_s is NOT measured on this "
+                    "device: it is the v2-tier dp_cost_probe figure of "
+                    "2026-07-29, kept until ROADMAP S2 measures the "
+                    "shipped kernels from a trace",
+        device_kinds=("TPU v5 lite", "TPU v5e"),
+        clock_hz=1.5e9,
+        peak_flops=1.97e14,
+        hbm_bytes_per_s=8.19e11,
+        serial_step_s=2.7e-6,
         host_poa_cells_per_s=1.5e9,  # host VM SIMD engines
         host_align_cells_per_s=1.0e9,
         error_bound_ratio=2.5,
@@ -198,13 +208,23 @@ def profile(name: str) -> MachineProfile:
                        f"available: {sorted(PROFILES)}") from None
 
 
-def resolve_profile(name: str, platform: Optional[str] = None
-                    ) -> MachineProfile:
-    """'auto' picks by backend platform (tpu -> tpu-v4-lite, else
-    cpu-host); anything else must be a registered profile name."""
-    if name in ("", "auto", None):
-        return PROFILES["tpu-v4-lite" if platform == "tpu" else "cpu-host"]
-    return profile(name)
+def resolve_profile(name: str, platform: Optional[str] = None,
+                    device_kind: Optional[str] = None) -> MachineProfile:
+    """'auto' picks cpu-host off a TPU and, on one, the profile that
+    lists the run's ``device_kind``; a TPU no profile describes is an
+    error, never a default.  Anything else must be a registered name."""
+    if name not in ("", "auto", None):
+        return profile(name)
+    if platform != "tpu":
+        return PROFILES["cpu-host"]
+    for prof in PROFILES.values():
+        if device_kind in prof.device_kinds:
+            return prof
+    known = sorted(k for p in PROFILES.values() for k in p.device_kinds)
+    raise KeyError(
+        f"no machine profile for TPU device kind {device_kind!r} (known: "
+        f"{known}); add its published peaks to costmodel.PROFILES or "
+        f"name a profile explicitly")
 
 
 # -- closed-form estimates -------------------------------------------------
@@ -333,8 +353,6 @@ def lowered_cost(lowered) -> Optional[CostEstimate]:
         ca = lowered.cost_analysis()
     except Exception:  # noqa: BLE001 — optional-path probe
         return None
-    if isinstance(ca, (list, tuple)):   # older jax: one dict per device
-        ca = ca[0] if ca else None
     if not isinstance(ca, dict) or not ca:
         return None
     flops = float(ca.get("flops", 0.0))
@@ -767,7 +785,8 @@ def validate_trace(doc: dict, prof: MachineProfile) -> dict:
 def bench_cost_model(snapshot: Optional[dict], phase_wall: Dict[str, float],
                      profile_name: str = "auto",
                      platform: Optional[str] = None,
-                     n_devices: Optional[int] = None) -> Optional[dict]:
+                     n_devices: Optional[int] = None,
+                     device_kind: Optional[str] = None) -> Optional[dict]:
     """The `cost_model` stamp for a bench JSON entry: predicted vs
     measured per modeled phase, error %%, and the profile used.  Returns
     None when the run collected no metrics (cost model disarmed).
@@ -775,7 +794,7 @@ def bench_cost_model(snapshot: Optional[dict], phase_wall: Dict[str, float],
     from shard counters on device profiles)."""
     if not snapshot or not isinstance(snapshot.get("counters"), dict):
         return None
-    prof = resolve_profile(profile_name, platform)
+    prof = resolve_profile(profile_name, platform, device_kind)
     pred = predict_from_counters(snapshot["counters"], prof,
                                  n_devices=n_devices)
     out = {"profile": prof.name, "n_devices": pred["n_devices"],

@@ -228,8 +228,10 @@ def analyze(doc: dict, profile: str = "auto") -> dict:
             counters = m["counters"]
     if counters:
         od = doc.get("otherData")
-        platform = od.get("platform") if isinstance(od, dict) else None
-        prof = costmodel.resolve_profile(profile, platform)
+        if not isinstance(od, dict):
+            od = {}
+        prof = costmodel.resolve_profile(profile, od.get("platform"),
+                                         od.get("device_kind"))
         pred = costmodel.predict_from_counters(counters, prof)
         crosscheck = {"profile": prof.name, "phases": {}}
         for stage, alias in (("align", "align"), ("poa", "poa")):

@@ -239,7 +239,7 @@ class Tracer:
         return len(absorbed)
 
     def to_dict(self, metrics: Optional[dict] = None,
-                platform: Optional[str] = None) -> dict:
+                device: Optional[dict] = None) -> dict:
         """The full Chrome-trace JSON object.  Extra top-level keys are
         ignored by Perfetto, so the metrics snapshot and provenance ride
         along in the same file the timeline lives in."""
@@ -269,18 +269,18 @@ class Tracer:
             doc["otherData"]["trace_id"] = self.trace_id
             if self.parent_span:
                 doc["otherData"]["parent_span"] = self.parent_span
-        if platform:
-            # lets `obs validate --profile auto` pick the right machine
-            # profile without re-importing the backend
-            doc["otherData"]["platform"] = platform
+        if device:
+            # platform + device_kind let `obs validate --profile auto`
+            # pick the machine profile without re-importing the backend
+            doc["otherData"].update(device)
         if metrics is not None:
             doc["racon_tpu"] = {"metrics": metrics}
         return doc
 
     def write(self, path: str, metrics: Optional[dict] = None,
-              platform: Optional[str] = None) -> None:
+              device: Optional[dict] = None) -> None:
         tmp = f"{path}.tmp.{self.pid}"
         with open(tmp, "w") as f:
-            json.dump(self.to_dict(metrics, platform=platform), f)
+            json.dump(self.to_dict(metrics, device=device), f)
             f.write("\n")
         os.replace(tmp, path)

@@ -1,26 +1,3 @@
 """Device (JAX/XLA/Pallas) kernels: batched POA consensus and batched banded
 global alignment, plus their drivers that claim work from the native pipeline
 and fall back to the host for anything outside device limits."""
-
-import os
-
-from .. import config
-
-
-def enable_compilation_cache() -> None:
-    """Persist XLA compilations across processes (kernel geometries are
-    stable, so repeated CLI/bench invocations skip the expensive compiles).
-    Harmless no-op if the backend doesn't support it."""
-    try:
-        import jax
-
-        cache_dir = config.get_raw("RACON_TPU_COMPILE_CACHE") or os.path.join(
-            os.path.expanduser("~"), ".cache", "racon_tpu_xla")
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
-    except Exception:  # noqa: BLE001 -- cache is an optimization only
-        pass
-
-
-enable_compilation_cache()

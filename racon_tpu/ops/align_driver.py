@@ -101,6 +101,17 @@ def run_alignment_phase(pipeline, progress: bool = False,
         engine = "auto"
         try:
             engine = _engine()
+            if engine != "host":
+                # what served: compiled or interpreted kernels, the
+                # cohort size and the mesh width they dispatch over
+                from ..parallel.partitioner import get_partitioner
+                from .align_pallas import cohort_size
+
+                report.extra["kernels"] = {
+                    "engine": engine,
+                    "interpreted": engine == "hirschberg" and not _on_tpu(),
+                    "batch": cohort_size(),
+                    "shards": get_partitioner().batch_axis_size}
             if engine == "host":
                 pass
             elif engine == "hirschberg":

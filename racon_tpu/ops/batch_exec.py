@@ -21,7 +21,7 @@ and alignment paths share a single serving seam:
 * **pack/kernel wall split** — `pack_ns` (host export+pack) vs
   `kernel_ns` (blocked inside the lattice serve) accumulate per executor
   and surface as `report.extra["pack_wall_s"/"kernel_wall_s"]` in the
-  drivers, making VERDICT #7's "pack time < kernel time" criterion
+  drivers, making the "pack time < kernel time" feeder criterion
   machine-checkable (bench.py stamps the split into its log entries).
 
 The driver supplies an *ops* object (duck-typed; no registration):

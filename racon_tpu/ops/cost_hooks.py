@@ -11,8 +11,7 @@ next to the measured build wall in the same trace row.
 
 Gated on ``RACON_TPU_COST_MODEL`` (default on) and a no-op whenever obs
 is disarmed; anything unrecognized returns ``{}`` rather than guessing.
-The in-process registry (:func:`builds`) is what tests and the hw_session
-validation step read back.
+The in-process registry (:func:`builds`) is what tests read back.
 """
 
 from __future__ import annotations

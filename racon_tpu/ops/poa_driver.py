@@ -137,10 +137,9 @@ def _device_batch(kind: str) -> int:
 def _node_factor() -> int:
     """max_nodes = factor * window_length. The default 3 matches the
     geometry every recorded pin was measured under; repeat-dense windows
-    (4 of λ's 96) overflow it and fall back to the host, so hw_session
-    measures factor 4 (VMEM fits per docs/roadmap.md) for a same-session
-    pin refresh — the reference's per-entry capacity rejection is the
-    analogous knob (/root/reference/src/cuda/cudabatch.cpp:141-160)."""
+    (4 of λ's 96) overflow it and fall back to the host — the
+    reference's per-entry capacity rejection is the analogous knob
+    (/root/reference/src/cuda/cudabatch.cpp:141-160)."""
     return max(1, config.get_int("RACON_TPU_NODE_FACTOR"))
 
 
@@ -249,6 +248,11 @@ def run_consensus_phase(pipeline, *, match: int, mismatch: int, gap: int,
         requested = _kernel_kind()
         B = _device_batch(requested)
         use_pallas = _use_pallas()
+        # what the serving kernels were: compiled or interpreted Pallas,
+        # and the batch / shard geometry they were dispatched at
+        report.extra["kernels"] = {
+            "interpreted": use_pallas and _platform() != "tpu",
+            "batch": B, "shards": _shard_n(B)}
         # Bucket by (depth, backbone class) to bound padding waste in BOTH
         # dims: layers dropped at pack time (oversized/empty) only shrink
         # a window's true depth, so a window always fits the bucket its
@@ -272,10 +276,10 @@ def run_consensus_phase(pipeline, *, match: int, mismatch: int, gap: int,
             buckets.setdefault((bucket, window_class(bb)),
                                []).append((i, depth, bb))
 
-        # geometries (cfg, kind) whose kernel already failed — seeded from
-        # warm-up failures so the measured run never retries a kernel the
-        # warm-up proved dead
-        dead_geoms = set(_WARM_DEAD)
+        # geometries (cfg, kind) whose kernel already failed, with the
+        # cause — seeded from warm-up failures so the measured run never
+        # retries a kernel the warm-up proved dead
+        dead_geoms = dict(_WARM_DEAD)
         # The shared executor (ops/batch_exec.py) owns the in-flight
         # queue: JAX dispatch is async, so with depth Q the host
         # packs/exports chunks N+1..N+Q while chunk N executes — the
@@ -308,6 +312,14 @@ def run_consensus_phase(pipeline, *, match: int, mismatch: int, gap: int,
                 # kernel's VMEM budget; the entry tier is picked per
                 # geometry.
                 entry_kind = _pick_tier(cfg, use_pallas, requested)
+                # a tier the warm-up proved dead is skipped below
+                # without a retry; the demotion still belongs in this
+                # run's report
+                kind = entry_kind
+                while (cfg, kind) in _WARM_DEAD:
+                    nxt = _next_tier(cfg, kind)
+                    report.record_degrade(kind, nxt, _WARM_DEAD[(cfg, kind)])
+                    kind = nxt
                 # (Per-bucket depth is kept deliberately: the fused
                 # kernel's VMEM footprint is depth-independent now, but
                 # packing and host->device transfer scale with the padded
@@ -330,8 +342,8 @@ def run_consensus_phase(pipeline, *, match: int, mismatch: int, gap: int,
                           f"len<={wl_class}: {len(bucket_jobs)} windows",
                           file=sys.stderr)
         executor.flush()
-        # feeder split (VERDICT #7): host pack wall vs blocked kernel
-        # wall, stamped for bench.py's machine-checkable criterion
+        # feeder split: host pack wall vs blocked kernel wall, stamped
+        # for bench.py's machine-checkable criterion
         executor.stamp_walls(report)
 
     t0 = time.perf_counter()
@@ -392,10 +404,11 @@ def observed_window_lengths(draft_path: str, w: int) -> set:
     return lens or {1}
 
 
-# (cfg, kind) pairs whose kernel failed during warm-up; consulted by
-# run_consensus_phase so the measured run dispatches straight to the tier
-# the warm-up landed on instead of re-paying a compile-and-fail.
-_WARM_DEAD: set = set()
+# (cfg, kind) -> the exception that killed that kernel during warm-up;
+# consulted by run_consensus_phase so the measured run dispatches
+# straight to the tier the warm-up landed on instead of re-paying a
+# compile-and-fail, and records the demotion in its report.
+_WARM_DEAD: dict = {}
 
 
 def warm_geometries(window_lengths, match: int, mismatch: int,
@@ -438,7 +451,7 @@ def warm_geometries(window_lengths, match: int, mismatch: int,
                     # the tier it will actually fall back to, and
                     # remember the failure so the measured run doesn't
                     # retry it
-                    _WARM_DEAD.add((cfg, kind))
+                    _WARM_DEAD[(cfg, kind)] = e
                     nxt = _next_tier(cfg, kind)
                     _warn_degrade(e, nxt)
                     kind = nxt
@@ -477,7 +490,7 @@ def _live_tier(cfg, B, kind, dead_geoms, report=None):
         try:
             return _build_kernel(cfg, B, kind in _PALLAS_KINDS, kind), kind
         except Exception as e:  # noqa: BLE001 — compile seam
-            dead_geoms.add((cfg, kind))
+            dead_geoms[(cfg, kind)] = e
             nxt = _next_tier(cfg, kind)
             if report is not None:
                 report.record_failure(kind, e)
@@ -628,7 +641,7 @@ class _ConsensusOps:
         self.report.record_quarantine(item[0], exc)
 
     def demote(self, ctx, kind, cause):
-        self.dead_geoms.add((ctx.cfg, kind))
+        self.dead_geoms[(ctx.cfg, kind)] = cause
         nxt = _next_tier(ctx.cfg, kind)
         self.report.record_degrade(kind, nxt, cause)
         _warn_degrade(cause, nxt)
@@ -713,8 +726,8 @@ def _build_kernel(cfg, B, use_pallas, kind: str = "v2"):
         kind = "xla"
     faults.check(f"poa.compile.{kind}")
     # Column-compressed stepping rides in the cache key: flipping the
-    # knob mid-process (hw_session's compressed-vs-flat steps) must not
-    # serve a kernel built under the other loop shape.
+    # knob mid-process must not serve a kernel built under the other
+    # loop shape.
     colstep = config.get_bool("RACON_TPU_POA_COLSTEP")
     # Banded builds ride the cache key too: the flat and banded variants
     # of a geometry are distinct compiled kernels (extra wband input /

@@ -206,11 +206,11 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 v = jnp.where(giota == g, read(g), v)
             return v
 
-        bb_len = svec(lambda g: bb_len_s[0, g])
-        n_layers = svec(lambda g: n_layers_s[0, g])
+        bb_len = svec(lambda g: bb_len_s[0, 0, g])
+        n_layers = svec(lambda g: n_layers_s[0, 0, g])
         max_layers = jnp.max(n_layers)
         if band:
-            wbv = svec(lambda g: wband_s[0, g])       # (1,G,1) half-band
+            wbv = svec(lambda g: wband_s[0, 0, g])    # (1,G,1) half-band
 
         # ---- graph init from the backbone chain ------------------------
         # (parity: rt_poa.cpp add_alignment, empty-alignment branch)
@@ -309,6 +309,8 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             esc[...] = jnp.full((NC, G, 128), NEG, jnp.int32)
 
             # ---- DP over ranks in lock-step -----------------------------
+            rs64 = (r_start // BLK) * BLK
+
             def dp_body(r, _):
                 act = lact & (r >= r_lo) & (r < r_hi)
                 dmax_r = jnp.minimum(jnp.max(exr(rk_dmax, r)), DMAX)
@@ -355,13 +357,13 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 def _():
                     flush_chunk((r + 1) // BLK - 1)
                     # the chunk whose ring slots ranks [r+1, r+1+BLK)
-                    # will overwrite must have landed in HBM
-                    @pl.when(r + 1 >= RING)
+                    # will overwrite must have landed in HBM — if this
+                    # layer flushed it (its first chunk starts at rs64)
+                    @pl.when(r + 1 - RING >= rs64)
                     def _():
                         flush_wait((r + 1 - RING) // BLK)
                 return 0
 
-            rs64 = (r_start // BLK) * BLK
             if colstep:
                 # Rank-pair stepping (the lockstep variant of column
                 # compression, RACON_TPU_POA_COLSTEP): the 8 lanes hold
@@ -389,19 +391,19 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             else:
                 jax.lax.fori_loop(rs64, r_end, dp_body, 0)
 
+            # Every flush started is waited on exactly once: a DMA wait
+            # with no matching start never returns on the chip (interpret
+            # mode does not block, so only the TPU interpreter or silicon
+            # shows it).  The loop waited on every full chunk but the
+            # last; that one and the partial tail are still in flight.
+            @pl.when(r_end // BLK > rs64 // BLK)
+            def _():
+                flush_wait(r_end // BLK - 1)
+
             @pl.when(r_end % BLK != 0)
             def _():
                 flush_chunk(r_end // BLK)
-
-            n_chunks = (r_end + BLK - 1) // BLK - rs64 // BLK
-
-            @pl.when(n_chunks >= 1)
-            def _():
-                flush_wait(rs64 // BLK + n_chunks - 1)
-
-            @pl.when(n_chunks >= 2)
-            def _():
-                flush_wait(rs64 // BLK + n_chunks - 2)
+                flush_wait(r_end // BLK)
 
             # ---- end-node selection -------------------------------------
             # rank r is an end node iff no in-subgraph node has an edge
@@ -440,8 +442,10 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             jcur = jnp.where(walking, Ln, 0)
             nk0 = jnp.full((1, G, 1), KEY_INF, jnp.float32)
             run0 = jnp.zeros((1, G, 1), jnp.int32)
-            done0 = ~walking
-            b_top = jnp.max(jnp.where(done0, 0, cur)) // BLK
+            # loop-carried flags are i32 0/1: Mosaic cannot legalize an
+            # scf.for / scf.while that carries an i1 vector
+            done0 = jnp.where(walking, 0, 1)
+            b_top = jnp.max(jnp.where(walking, cur, 0)) // BLK
 
             def tb_load(b, half):
                 pltpu.make_async_copy(
@@ -468,7 +472,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
 
             def tb_rank_work(r, c):
                 cur, jcur, nk, run, done, failed = c[:6]
-                here = ~done & (cur == r)
+                here = (done == 0) & (cur == r)
                 row = ring_row(r)
                 ub = exr(rk_base, r)
                 scv = jnp.where(seqm1 == ub, M, X)
@@ -514,7 +518,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                                  keepdims=True)[:, :, 0:1]
                 stuck = here & (j_stop < 0)
                 failed = failed | jnp.where(stuck, 1, 0)
-                done = done | stuck
+                done = done | jnp.where(stuck, 1, 0)
                 act = here & ~stuck
                 j_stop = jnp.maximum(j_stop, 0)
                 if band:
@@ -564,7 +568,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 vl = (jj < jcur) & at_virt
                 runrem[...] = jnp.where(vl, run + (jcur - jj), runrem[...])
                 nkey[...] = jnp.where(vl, nk, nkey[...])
-                done = done | at_virt
+                done = done | jnp.where(at_virt, 1, 0)
                 out = (cur, jcur, nk, run, done, failed)
                 if band:
                     out = out + (hit_tb,)
@@ -574,7 +578,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 b = c[0]
                 r = b * BLK + (BLK - 1 - i)
                 cc = c[1:]
-                here_any = jnp.any(~cc[4] & (cc[0] == r))
+                here_any = jnp.any((cc[4] == 0) & (cc[0] == r))
                 cc2 = jax.lax.cond(here_any,
                                    lambda cc: tb_rank_work(r, cc),
                                    lambda cc: cc, cc)
@@ -602,7 +606,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 cur, jcur, nk, run, done, failed = jax.lax.fori_loop(
                     0, b_top + 1, tb_block,
                     (cur, jcur, nk0, run0, done0, failed))
-            failed = failed | jnp.where(~done & lact, 1, 0)
+            failed = failed | jnp.where((done == 0) & lact, 1, 0)
 
             # ---- graph update (parity: rt_poa.cpp add_alignment) --------
             maxL = jnp.max(jnp.where(lact & (failed == 0), Ln, 0))
@@ -830,7 +834,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
         # forward walk to a sink along heaviest out-edges
         def fcond(c):
             u, cnt, more = c
-            return jnp.any(more)
+            return jnp.any(more > 0)
 
         def fbody(c):
             u, cnt, more = c
@@ -840,31 +844,35 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                      (rr < n))
                 ew = jnp.maximum(ew, jnp.where(m, ew_f[e], NEG))
             wmax = jnp.max(ew, axis=(0, 2), keepdims=True)[:, :, 0:1]
-            any_out = more & (wmax > NEG)
+            any_out = (more > 0) & (wmax > NEG)
             cand_s = jnp.where(ew == wmax, score[...], NEG)
             smax = jnp.max(cand_s, axis=(0, 2), keepdims=True)[:, :, 0:1]
             v = jnp.min(jnp.where(cand_s == smax, rr, N), axis=(0, 2),
                         keepdims=True)[:, :, 0:1]
             emit(cnt, jnp.clip(v, 0, N - 1), any_out)
             return (jnp.where(any_out, v, u),
-                    cnt + jnp.where(any_out, 1, 0), any_out)
+                    cnt + jnp.where(any_out, 1, 0),
+                    jnp.where(any_out, 1, 0))
 
         _, cnt_f, _ = jax.lax.while_loop(
             fcond, fbody,
             (summit, cnt_b,
-             jnp.broadcast_to(jnp.bool_(True), (1, G, 1))))
+             jnp.ones((1, G, 1), jnp.int32)))
 
         for g in range(G):
-            cl_s[0, g] = scalar_of(cnt_f, g)
-            fl_s[0, g] = jnp.where(scalar_of(failed, g) > 0, 1, 0)
-            nn_s[0, g] = scalar_of(n, g)
+            cl_s[0, 0, g] = scalar_of(cnt_f, g)
+            fl_s[0, 0, g] = jnp.where(scalar_of(failed, g) > 0, 1, 0)
+            nn_s[0, 0, g] = scalar_of(n, g)
             if band:
-                bh_s[0, g] = jnp.where(scalar_of(hit, g) > 0, 1, 0)
+                bh_s[0, 0, g] = jnp.where(scalar_of(hit, g) > 0, 1, 0)
 
     def make(batch: int):
         assert batch % G == 0
         nb = batch // G
-        smem2 = pl.BlockSpec((1, G), lambda b: (b, 0),
+        # Per-window scalars ride a unit middle dim: Mosaic wants a
+        # block's last two dims to equal the array's (or tile 8x128), and
+        # a (1, G) block of an (nb, G) array only passes at nb == 1.
+        smem2 = pl.BlockSpec((1, 1, G), lambda b: (b, 0, 0),
                              memory_space=pltpu.SMEM)
         smem3 = pl.BlockSpec((1, G, D), lambda b: (b, 0, 0),
                              memory_space=pltpu.SMEM)
@@ -872,7 +880,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                             memory_space=pltpu.VMEM)
         hbm = pl.BlockSpec(memory_space=pl.ANY)
 
-        gshape = jax.ShapeDtypeStruct((nb, G), jnp.int32)
+        gshape = jax.ShapeDtypeStruct((nb, 1, G), jnp.int32)
         return pl.pallas_call(
             kernel,
             grid=(nb,),
@@ -930,12 +938,12 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 0, 2, 3, 1, 4)
             wsJ = wsJ.reshape(nb, G, D, JC, 128).transpose(0, 2, 3, 1, 4)
 
-            args = [bb_len.reshape(nb, G), n_layers.reshape(nb, G),
+            args = [bb_len.reshape(nb, 1, G), n_layers.reshape(nb, 1, G),
                     lens.reshape(nb, G, D), begins.reshape(nb, G, D),
                     ends.reshape(nb, G, D), to_n(bb), to_n(bbw),
                     seqsJ, wsJ]
             if band:
-                args.append(extra[0].reshape(nb, G))
+                args.append(extra[0].reshape(nb, 1, G))
             outs = call(*args)
             cb, cc, cl, fl, nn = outs[:5]
             cb = cb.transpose(0, 2, 1, 3).reshape(batch, N)

@@ -7,10 +7,10 @@ over DCN with an ordered host gather, no collectives needed).
 Layout: ``axes`` holds the logical-axis rule registry
 (windows/query/depth/lane -> mesh axes), ``partitioner`` the mesh
 discovery + Partitioner that wraps kernels via pjit/shard_map, ``mesh``
-the jax-version shard_map shim and legacy 1-D helpers."""
+the legacy 1-D helpers."""
 
 from .mesh import (  # noqa: F401
-    device_mesh, divisible_batch, resolve_shard_map, shard_batch_kernel)
+    device_mesh, divisible_batch, shard_batch_kernel)
 from .partitioner import (  # noqa: F401
     Partitioner, build_mesh, get_partitioner, mesh_shape,
     reset_partitioner)

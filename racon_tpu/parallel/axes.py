@@ -16,7 +16,7 @@ running:
   reductions and must stay whole on every device.
 
 A *rule set* maps each logical axis to a mesh axis name (or ``None`` =
-replicated), the T5X ``logical_axis_rules`` pattern (SNIPPETS.md [2]).
+replicated), the T5X ``logical_axis_rules`` pattern.
 ``resolve_spec`` turns a tuple of logical names — one per array dim —
 into a ``jax.sharding.PartitionSpec`` against a concrete mesh, which is
 how the partitioner (parallel/partitioner.py) derives pjit sharding
@@ -101,7 +101,7 @@ def resolve_spec(logical_axes: Sequence[Optional[str]],
 
     ``None`` entries (and logical axes whose rule maps to ``None``)
     resolve to a replicated dim.  Scalar/0-d arrays pass ``()`` and get
-    the empty spec (SNIPPETS.md [3]'s scalar convention)."""
+    the empty spec."""
     table = dict(rules)
     out = []
     for name in logical_axes:

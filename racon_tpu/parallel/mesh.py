@@ -17,33 +17,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 AXIS = "windows"
 
-# jax >= 0.7 promotes shard_map to the public namespace and renames the
-# replication-check kwarg check_rep -> check_vma; 0.4.x only has the
-# experimental spelling.
-
-
-def resolve_shard_map(jax_mod=None):
-    """(shard_map callable, replication-check-off kwargs) for this jax.
-
-    The version shim, factored out so tests can drive BOTH branches with
-    stand-in modules (a jax bump that moves/renames shard_map again must
-    fail a test, not silently kill the sharded tier).  ``jax_mod``
-    defaults to the real ``jax``."""
-    mod = jax if jax_mod is None else jax_mod
-    fn = getattr(mod, "shard_map", None)
-    if fn is not None:
-        return fn, {"check_vma": False}
-    sub = getattr(mod.experimental, "shard_map", None)
-    if sub is None:
-        import importlib
-        sub = importlib.import_module(
-            mod.__name__ + ".experimental.shard_map")
-    return sub.shard_map, {"check_rep": False}
-
-
-_shard_map, _NO_CHECK = resolve_shard_map()
-
-
 def device_mesh(devices: Optional[Sequence] = None) -> Mesh:
     devs = list(devices if devices is not None else jax.devices())
     import numpy as np
@@ -73,10 +46,10 @@ def shard_batch_build(build_local, batch, n_in, n_out):
         return None
     local = build_local(batch // n_dev)
     out_specs = (P(AXIS),) * n_out if n_out > 1 else P(AXIS)
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         lambda *a: local(*a), mesh=device_mesh(),
         in_specs=(P(AXIS),) * n_in, out_specs=out_specs,
-        **_NO_CHECK))
+        check_vma=False))
 
 
 def divisible_batch(n_devices: int, b: int) -> int:

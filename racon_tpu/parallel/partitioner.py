@@ -1,7 +1,7 @@
 """Hardware mesh discovery + the Partitioner that shards grid kernels.
 
 This is the multi-chip half of ROADMAP item 2: a real partitioning
-subsystem in the T5X mold (SNIPPETS.md [1]-[3]) sized for this repo's
+subsystem in the T5X mold sized for this repo's
 two embarrassingly parallel hot paths.  Three layers:
 
 * **mesh discovery** — ``mesh_shape()`` resolves the
@@ -50,7 +50,6 @@ import numpy as np
 from .. import config
 from ..ops.kernel_cache import device_keyed_cache
 from . import axes
-from .mesh import resolve_shard_map
 
 
 def _warn(msg: str) -> None:
@@ -94,7 +93,7 @@ def build_mesh(shape: Optional[Tuple[int, int]] = None):
     """A 2-D Mesh over ``axes.MESH_AXES`` for the current device set.
 
     Multi-host TPU topologies get ``create_hybrid_device_mesh`` (ICI
-    within a host, DCN across hosts — SNIPPETS.md [1]); anything else
+    within a host, DCN across hosts); anything else
     gets a flat reshape of ``jax.devices()`` in enumeration order, which
     is exactly what the CI forced-host CPU mesh and single-host silicon
     want.  Uses the first data*model devices when the shape deliberately
@@ -210,10 +209,10 @@ class Partitioner:
         local = build_local(batch // m)
         spec = self.spec("windows")
         out_specs = (spec,) * n_out if n_out > 1 else spec
-        smap, no_check = resolve_shard_map()
-        return jax.jit(smap(
+        return jax.jit(jax.shard_map(
             lambda *a: local(*a), mesh=self.mesh,
-            in_specs=(spec,) * n_in, out_specs=out_specs, **no_check))
+            in_specs=(spec,) * n_in, out_specs=out_specs,
+            check_vma=False))
 
     # -- batch padding (satellite: the one place pad math lives) -----------
 

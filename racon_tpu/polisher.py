@@ -223,6 +223,11 @@ class TpuPolisher:
                  target_path: str, journal_path: Optional[str] = None,
                  resume_journal: bool = False,
                  trace_path: Optional[str] = None, **kwargs):
+        from . import device
+
+        # before anything else: no TPU (and no JAX_PLATFORMS=cpu asked
+        # for by name) is an error here, not a quieter tier later
+        ident = device.require_tpu()
         reset_run_state(trace_path)
         self._kwargs = dict(kwargs)
         self._paths = (sequences_path, overlaps_path, target_path)
@@ -268,6 +273,7 @@ class TpuPolisher:
         # memory lattice edges land here, peak RSS is stamped in extra
         self._mem_rep = PhaseReport("memory", ())
         self.report = RunReport()
+        self.report.stamp_device(ident)
 
     def initialize(self) -> None:
         try:

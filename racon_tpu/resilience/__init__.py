@@ -20,7 +20,7 @@ that posture a tested subsystem instead of scattered try/except blocks:
   run resumes and reproduces byte-identical output.
 * `report`  — per-phase serving/fallback accounting surfaced through
   `Polisher.polish()`, the `--report` CLI flag, `RACON_TPU_REPORT`, and
-  `bench.py` / `tools/hw_session.py`.
+  `bench.py`.
 """
 
 from . import faults, journal, lattice, report, watchdog  # noqa: F401

@@ -4,8 +4,8 @@ Each polishing phase produces a `PhaseReport` (per-tier served counts,
 fallback causes, retries, bisections, quarantined window indices, wall
 time per tier); the polisher aggregates them into a `RunReport` surfaced
 through `TpuPolisher.report`, the CLI `--report PATH` flag, the
-`RACON_TPU_REPORT` env var (written at the end of `polish()` — the hook
-`bench.py` and `tools/hw_session.py` use), and the one-line bench JSON.
+`RACON_TPU_REPORT` env var (written at the end of `polish()`), and the
+one-line bench JSON.
 
 Invariant (regression-tested): a phase's per-tier served counts sum to
 its total job/window count, clean or fault-injected.
@@ -146,6 +146,18 @@ class RunReport:
         # session stamps the compute side's stage_s decomposition here
         # so the persisted report carries it; None outside serving
         self.ledger: Optional[dict] = None
+        # the device the 'tpu' backend ran on (racon_tpu/device.py:
+        # platform, device_kind, count) and the persistent-cache traffic
+        # at construction; None on the host path, which never
+        # initialises a backend
+        self.device: Optional[dict] = None
+        self._cache0: Optional[dict] = None
+
+    def stamp_device(self, ident: dict) -> None:
+        from .. import device
+
+        self.device = dict(ident)
+        self._cache0 = device.cache_traffic()
 
     def attach(self, phase_report: Optional[PhaseReport]) -> None:
         if phase_report is not None:
@@ -161,6 +173,8 @@ class RunReport:
 
         return {
             "phases": {k: v.as_dict() for k, v in self.phases.items()},
+            "device": self.device,
+            **self._cache_dict(),
             "fault_spec": active_spec(),
             # stale-knob check: RACON_TPU_* vars set in the environment
             # but unknown to the config registry — a typo'd knob surfaces
@@ -191,6 +205,21 @@ class RunReport:
             **({"ledger": dict(self.ledger)} if self.ledger else {}),
         }
 
+    def _cache_dict(self) -> dict:
+        """Persistent compilation cache: where it lives and this run's
+        share of the process's traffic (a warm run shows hits and no
+        misses).  Absent on the host path."""
+        if self._cache0 is None:
+            return {}
+        import jax
+
+        from .. import device
+
+        now = device.cache_traffic()
+        return {"jax_cache": {
+            "dir": jax.config.jax_compilation_cache_dir,
+            **{k: round(now[k] - self._cache0[k], 3) for k in now}}}
+
     def summary(self) -> dict:
         """Compact serving-mix view for logs and the bench JSON line."""
         out = {
@@ -216,8 +245,8 @@ class RunReport:
             f.write("\n")
 
     def write_env(self) -> None:
-        """Write to $RACON_TPU_REPORT when set (bench/hw_session hook);
-        a write failure warns, it never fails the polish."""
+        """Write to $RACON_TPU_REPORT when set; a write failure warns,
+        it never fails the polish."""
         path = config.get_raw(ENV_REPORT)
         if not path:
             return

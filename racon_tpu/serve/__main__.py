@@ -126,16 +126,21 @@ def main(argv=None) -> int:
             print(e, file=sys.stderr)
             return 1
 
-    daemon = ServeDaemon(
-        args.state_dir, backend=args.backend, port=args.port,
-        queue_depth=args.queue_depth, max_jobs=args.max_jobs,
-        window_budget=args.window_budget,
-        warm=False if args.no_warm else None,
-        warm_window_lengths=tuple(args.warm_window or (500,)),
-        warm_scores=(args.match, args.mismatch, args.gap),
-        host_lane=not args.no_host_lane,
-        fleet_min=args.fleet_min, fleet_max=args.fleet_max,
-        metrics_port=args.metrics_port)
+    from ..device import DeviceUnavailable
+    try:
+        daemon = ServeDaemon(
+            args.state_dir, backend=args.backend, port=args.port,
+            queue_depth=args.queue_depth, max_jobs=args.max_jobs,
+            window_budget=args.window_budget,
+            warm=False if args.no_warm else None,
+            warm_window_lengths=tuple(args.warm_window or (500,)),
+            warm_scores=(args.match, args.mismatch, args.gap),
+            host_lane=not args.no_host_lane,
+            fleet_min=args.fleet_min, fleet_max=args.fleet_max,
+            metrics_port=args.metrics_port)
+    except DeviceUnavailable as e:
+        print(f"[racon_tpu::serve] {e}", file=sys.stderr)
+        return 1
 
     from ..obs import flight
     flight.set_role("serve")

@@ -85,7 +85,7 @@ COMMON_RESP = ("ok", "error", "rejected")
 PROTOCOL = {
     "serve": {
         "ping": {"req": (), "opt": (),
-                 "resp": ("pid", "backend", "port")},
+                 "resp": ("pid", "backend", "port", "device")},
         "submit": {"req": ("sequences", "overlaps", "target"),
                    "opt": ("args", "include_unpolished", "backend",
                            "job_id", "submitter", "window_budget",

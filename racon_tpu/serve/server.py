@@ -79,6 +79,14 @@ class ServeDaemon:
                 backend=backend,
                 trace_path=os.path.join(fleet_dir, "trace.json"),
                 report_path=os.path.join(fleet_dir, "report.json"))
+        self.device = None
+        if backend == "tpu" and self.plane is None:
+            # the in-process device lane: no TPU is a start-up error,
+            # not interpreted kernels behind a listening port (with a
+            # plane the one device worker makes the same check, and
+            # this process must stay off the chip it holds)
+            from ..device import require_tpu
+            self.device = require_tpu()
         self.scheduler = Scheduler(self.session, queue_depth=queue_depth,
                                    max_jobs=max_jobs,
                                    window_budget=window_budget,
@@ -294,7 +302,8 @@ class ServeDaemon:
         op = req.get("op")
         if op == "ping":
             return {"ok": True, "pid": os.getpid(),
-                    "backend": self.session.backend, "port": self.port}
+                    "backend": self.session.backend, "port": self.port,
+                    "device": self.device}
         if op == "submit":
             spec = JobSpec.from_dict(
                 {k: v for k, v in req.items() if k != "op"})

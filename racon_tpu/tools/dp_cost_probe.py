@@ -638,8 +638,6 @@ def gate(R: int = 32, B: int = 1) -> bool:
     floor: >= 3x fewer cells on BOTH hot kernels).  Runs in interpret
     mode off-TPU (counts, not wall time, are the measurement), prints
     every ratio, returns False if any floor is missed."""
-    from racon_tpu.tools import force_cpu_if_requested
-    force_cpu_if_requested()
     import jax
 
     interp = jax.devices()[0].platform != "tpu"
@@ -679,8 +677,6 @@ def main():
     # seed-dependence check below
     assert R <= 8 * 256 - 1, f"R={R} exceeds the 2047 node-slot capacity"
 
-    from racon_tpu.tools import force_cpu_if_requested
-    force_cpu_if_requested()
     import jax
 
     platform = jax.devices()[0].platform

@@ -69,8 +69,6 @@ def main():
     depth = int(sys.argv[2]) if len(sys.argv) > 2 else 32
     iters = int(sys.argv[3]) if len(sys.argv) > 3 else 3
 
-    from racon_tpu.tools import force_cpu_if_requested
-    force_cpu_if_requested()
     import jax
 
     from racon_tpu.ops import poa_driver, poa_pallas

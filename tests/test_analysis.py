@@ -183,7 +183,7 @@ def test_audit_flags_float64():
     def f64(x):
         return x.astype(jnp.float64) * 2
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(f64)(
             jax.ShapeDtypeStruct((4,), "float32"))
     vs = jaxpr_audit.check_jaxpr(closed, "x.py", "f64")

@@ -444,10 +444,15 @@ def test_fleet_trace_merges_validates_and_parents(tmp_path):
     assert result["counters"].get("obs_events_absorbed", 0) > 0
     # live-telemetry aggregates rode back in the result
     tel = result["telemetry"]
-    assert set(tel["workers"]) == {"0", "1", "2"}
+    # which workers win chunks is a race (on a loaded machine the first
+    # one up can drain the queue before the others connect): every
+    # reporting worker is one of ours and together they served it all
+    assert tel["workers"] and set(tel["workers"]) <= {"0", "1", "2"}
     for ws in tel["workers"].values():
         assert ws["chunks"] >= 1
         assert ws["kernel_wall_s"] >= 0.0
+    assert (sum(ws["chunks"] for ws in tel["workers"].values())
+            >= result["chunks"])
     assert tel["queueing_p95_s"] is not None
 
     worker_traces = sorted(glob.glob(

@@ -251,13 +251,21 @@ def test_transient_fault_retried_at_tier(tmp_path, monkeypatch):
 def test_hung_device_call_hits_watchdog(tmp_path, monkeypatch):
     paths = _write_dataset(tmp_path)
     oracle = _oracle(paths)
+    # The deadline must separate a hang from a healthy call, and a first
+    # call that compiles outlasts any deadline short enough for a test
+    # (four such "timeouts" in a row declare the tier wedged).  So: one
+    # device (a timeout on a sharded batch rebuilds the kernel for
+    # single-device dispatch — a second cold compile), the kernel
+    # compiled by a clean run first, and a deadline ~8x the warm call.
+    _tpu_run(paths, monkeypatch, {"RACON_TPU_SHARD": "0"})
     res, p = _tpu_run(paths, monkeypatch, {
         # invocation 0 (pipelined submit) fails synchronously; invocation 1
         # (the lattice's retry attempt) hangs and trips the watchdog;
         # invocation 2 succeeds — all windows still served on device
         "RACON_TPU_FAULT": ("poa.run.xla:batch=0:count=1,"
-                            "poa.run.xla:batch=1:count=1:hang=2"),
-        "RACON_TPU_DEVICE_TIMEOUT": "0.3",
+                            "poa.run.xla:batch=1:count=1:hang=3"),
+        "RACON_TPU_DEVICE_TIMEOUT": "1.0",
+        "RACON_TPU_SHARD": "0",
     })
     assert res == oracle
     d = _assert_report_sums(p)

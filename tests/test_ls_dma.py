@@ -1,0 +1,98 @@
+"""DMA-semaphore accounting of the lockstep POA kernel, under the TPU
+interpreter.
+
+Plain interpret mode runs a DMA wait as a no-op, so a wait with no
+matching start — which never returns on the chip — passes every other
+test in the suite.  ``pltpu.InterpretParams`` models the semaphores (a
+wait blocks until its DMA was started) and fills scratch with NaN
+instead of zeros.  The ``ls`` tier's first chip run hung on exactly
+this: the H-ring spill waited twice on one chunk whenever a layer's DP
+ended on a 64-rank boundary, and waited on a chunk the layer never
+flushed whenever its DP started past rank 64.  This batch holds one grid
+program of each kind; the kernel runs in a child process so that a
+deadlock is a failed test, not a hung suite.
+"""
+
+import os
+import subprocess
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_CHILD = r"""
+import random
+import numpy as np
+from jax.experimental.pallas import tpu as pltpu
+from racon_tpu.ops import poa, poa_pallas_ls
+from racon_tpu.ops.encoding import encode
+
+cfg = poa.PoaConfig(max_nodes=512, max_len=256, max_backbone=256,
+                    max_edges=12, depth=4, match=5, mismatch=-4, gap=-8)
+G, BLK = poa_pallas_ls.G, poa_pallas_ls.BLK
+B = 2 * G
+rng = random.Random(3)
+bb = np.zeros((B, cfg.max_backbone), np.uint8)
+bbw = np.zeros((B, cfg.max_backbone), np.int32)
+bb_len = np.ones(B, np.int32)
+nl = np.zeros(B, np.int32)
+seqs = np.zeros((B, cfg.depth, cfg.max_len), np.uint8)
+ws = np.zeros((B, cfg.depth, cfg.max_len), np.int32)
+lens = np.zeros((B, cfg.depth), np.int32)
+bg = np.zeros((B, cfg.depth), np.int32)
+en = np.zeros((B, cfg.depth), np.int32)
+
+
+def put(b, backbone, layers, begin, end):
+    bb[b, :len(backbone)] = encode(np.frombuffer(backbone, np.uint8))
+    bb_len[b] = len(backbone)
+    nl[b] = len(layers)
+    for i, lay in enumerate(layers):
+        seqs[b, i, :len(lay)] = encode(np.frombuffer(lay, np.uint8))
+        ws[b, i, :len(lay)] = 1
+        lens[b, i] = len(lay)
+        bg[b, i], en[b, i] = begin, end
+
+
+for b in range(B):
+    if b < G:
+        # perfect full-span reads: the graph stays 2*BLK ranks, so every
+        # layer's DP ends exactly on a chunk boundary with two chunks
+        truth = bytes(rng.choice(b"ACGT") for _ in range(2 * BLK))
+        put(b, truth, [truth] * 3, 0, len(truth) - 1)
+    else:
+        # every layer of the program starts past the first chunk
+        truth = bytes(rng.choice(b"ACGT") for _ in range(200))
+        put(b, truth, [truth[100:]] * 3, 100, 199)
+
+interp = pltpu.InterpretParams(dma_execution_mode="on_wait",
+                               uninitialized_memory="nan")
+ls = poa_pallas_ls.build_lockstep_poa_kernel(cfg, interpret=interp)(B)
+cb, cc, cl, fl, nn = (np.asarray(x) for x in ls(
+    bb_len[:, None], nl[:, None], lens, bg, en, bb.astype(np.int32), bbw,
+    seqs.astype(np.int32), ws))
+jb, jc, jl, jf, jn = (np.asarray(x) for x in poa.build_poa_kernel(cfg)(
+    bb, bbw, bb_len, nl, seqs, ws, lens, bg, en))
+assert not fl.any() and not jf.any(), (fl.ravel(), jf.ravel())
+for b in range(B):
+    n = int(cl[b, 0])
+    assert n == int(jl[b]) and int(nn[b, 0]) == int(jn[b]), b
+    np.testing.assert_array_equal(cb[b, :n], jb[b, :n])
+    np.testing.assert_array_equal(cc[b, :n], jc[b, :n])
+print("ls == xla under the TPU interpreter")
+"""
+
+
+def test_lockstep_spill_semaphores_balance_under_tpu_interpreter():
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   p for p in (ROOT, os.environ.get("PYTHONPATH")) if p))
+    env.pop("XLA_FLAGS", None)   # one device: the child shards nothing
+    try:
+        r = subprocess.run([sys.executable, "-c", _CHILD], env=env,
+                           capture_output=True, text=True, timeout=420)
+    except subprocess.TimeoutExpired:
+        raise AssertionError(
+            "lockstep kernel deadlocked under the TPU interpreter: a DMA "
+            "wait without a matching start (it would hang the chip)")
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "ls == xla" in r.stdout

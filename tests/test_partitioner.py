@@ -1,10 +1,8 @@
 """The partitioning subsystem: mesh discovery, logical-axis rules, pad
-accounting, the will_shard gate, the sharded->single-device lattice edge,
-and the jax shard_map version shim — all on the 8-virtual-device mesh the
-conftest forces."""
+accounting, the will_shard gate and the sharded->single-device lattice
+edge — all on the 8-virtual-device mesh the conftest forces."""
 
 import random
-import types
 
 import jax
 import numpy as np
@@ -13,58 +11,22 @@ from jax.sharding import PartitionSpec
 
 from racon_tpu import obs
 from racon_tpu.parallel import axes, divisible_batch
-from racon_tpu.parallel.mesh import resolve_shard_map
 from racon_tpu.parallel.partitioner import (Partitioner, build_mesh,
                                             get_partitioner, mesh_shape)
 from racon_tpu.resilience import lattice as rl
 from racon_tpu.resilience.report import PhaseReport
 
 
-# -- shard_map version shim (satellite: compat-shim test coverage) ---------
+# -- shard_map wrap -------------------------------------------------------
 
-def test_resolve_shard_map_real_jax_runs():
-    """Whatever spelling this jax ships, the resolved pair must wrap and
-    execute a trivial sharded function over the real device mesh."""
-    smap, no_check = resolve_shard_map()
-    assert callable(smap)
-    assert no_check in ({"check_rep": False}, {"check_vma": False})
+def test_shard_build_runs_on_the_real_mesh():
+    """The partitioner's shard_map wrap must execute a trivial per-shard
+    kernel over the real device mesh (a jax bump that moves or renames
+    shard_map must fail a test, not silently kill the sharded tier)."""
     part = get_partitioner()
-    spec = part.spec("windows")
-    fn = jax.jit(smap(lambda x: x * 2, mesh=part.mesh,
-                      in_specs=(spec,), out_specs=spec, **no_check))
+    fn = part.shard_build(lambda b: (lambda x: x * 2), 8, 1, 1)
     x = np.arange(16, dtype=np.int32).reshape(8, 2)
     np.testing.assert_array_equal(np.asarray(fn(x)), x * 2)
-
-
-def test_resolve_shard_map_public_branch():
-    """jax >= 0.7 spelling: top-level shard_map, check_vma kwarg."""
-    sentinel = lambda *a, **k: "public"  # noqa: E731
-    fake = types.SimpleNamespace(shard_map=sentinel)
-    fn, no_check = resolve_shard_map(fake)
-    assert fn is sentinel
-    assert no_check == {"check_vma": False}
-
-
-def test_resolve_shard_map_experimental_branch():
-    """jax 0.4.x spelling: jax.experimental.shard_map.shard_map with the
-    check_rep kwarg."""
-    sentinel = lambda *a, **k: "experimental"  # noqa: E731
-    fake = types.SimpleNamespace(
-        experimental=types.SimpleNamespace(
-            shard_map=types.SimpleNamespace(shard_map=sentinel)))
-    fn, no_check = resolve_shard_map(fake)
-    assert fn is sentinel
-    assert no_check == {"check_rep": False}
-
-
-def test_resolve_shard_map_experimental_import_fallback():
-    """A jax whose `experimental` hasn't loaded the submodule yet: the
-    shim must import <mod>.experimental.shard_map by name."""
-    fake = types.SimpleNamespace(
-        __name__="jax", experimental=types.SimpleNamespace())
-    fn, no_check = resolve_shard_map(fake)
-    assert callable(fn)
-    assert no_check == {"check_rep": False}
 
 
 # -- logical axis rules ----------------------------------------------------

@@ -294,32 +294,6 @@ def test_align_job_lengths_bulk_matches_loop(tmp_path):
 
 # ------------------------------------------------------ unit: bench honesty
 
-def test_bench_normalize_entry_backfills_unreachable():
-    import bench
-    old = {"metric": "Mbp/s [TPU UNREACHABLE: host path only]",
-           "value": 0.01, "vs_baseline": 0.0}
-    fixed = bench.normalize_entry(old)
-    assert fixed["vs_baseline"] is None
-    assert fixed["device_status"] == "unreachable"
-    assert old["vs_baseline"] == 0.0      # input not mutated
-    # a measured zero on a reachable device is a real measurement
-    measured = {"metric": "Mbp/s (device)", "value": 0.0,
-                "vs_baseline": 0.0}
-    assert bench.normalize_entry(measured)["vs_baseline"] == 0.0
-    assert "device_status" not in bench.normalize_entry(measured)
-
-
-def test_bench_degraded_result_is_null_not_zero():
-    import bench
-    e = bench.degraded_result(1.25, "; note")
-    assert e["vs_baseline"] is None
-    assert e["device_status"] == "unreachable"
-    assert "TPU UNREACHABLE" in e["metric"]
-    assert e["cost_model"] is None       # explicit: no prediction joined
-    # round-trips through the reader unchanged
-    assert bench.normalize_entry(json.loads(json.dumps(e))) == e
-
-
 def test_bench_normalize_entry_malformed_partial_summaries():
     """The committed log is hand-editable and spans writer generations:
     backfill must cope with entries missing BOTH phase_wall and report,

@@ -14,7 +14,7 @@ from racon_tpu.tools import preprocess, simulate
 
 def test_bench_sr_profile_dataset_polishes(tmp_path):
     """The bench's short-read profile (150 bp @ ~1% error — the
-    hw_session bench_sam_sr workload) must produce a dataset the host
+    short-read bench workload) must produce a dataset the host
     pipeline actually polishes: reads are short-read-sized, windows are
     NGS-class, and the polished contig lands closer to the genome than
     the draft started."""

@@ -40,7 +40,7 @@ else
 fi
 
 # 1b. Focused lint over the preemption-tolerance modules: these carry
-#     the crash-resume contract (journal/watchdog/hw_session) and the
+#     the crash-resume contract (journal/watchdog) and the
 #     drivers that feed the journal, so their fault points / knob docs /
 #     broad-except waivers must stay lint-clean even when a full-tree
 #     run is baselined.
@@ -50,7 +50,6 @@ run "racon_tpu.analysis (resilience focus)" \
         racon_tpu/resilience/watchdog.py \
         racon_tpu/resilience/faults.py \
         racon_tpu/resilience/lattice.py \
-        racon_tpu/tools/hw_session.py \
         racon_tpu/ops/poa_driver.py \
         racon_tpu/ops/align_driver.py \
         racon_tpu/polisher.py
