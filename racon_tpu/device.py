@@ -11,7 +11,10 @@ tests and the ``chip_smoke.py`` rehearsal run the interpreted kernels.
 
 The identity (platform, device kind, count) and this process's
 persistent-compilation-cache traffic go into every ``RunReport``, so a
-CPU run and a chip run no longer produce the same report.
+CPU run and a chip run no longer produce the same report.  The same
+listener times the stages of making a program (trace, lower, compile) by
+the jitted function's name, and :func:`named` is how a kernel's wrapper
+gets the name that clock, the HLO module and a profile's device ops show.
 """
 
 from __future__ import annotations
@@ -20,17 +23,34 @@ import json
 import os
 import sys
 import threading
+import time
+
+from . import obs
 
 _CACHE_EVENTS = {
     "/jax/compilation_cache/compile_requests_use_cache": "requests",
     "/jax/compilation_cache/cache_hits": "hits",
     "/jax/compilation_cache/cache_misses": "misses",
 }
-#: wraps compile_or_get_cached: seconds spent compiling an executable
-#: or loading it from the persistent cache
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+#: the stages of making a program, as ``jax.monitoring`` times them:
+#: tracing the Python function to a jaxpr, lowering the jaxpr to an MLIR
+#: module (Pallas -> Mosaic happens here), and compile_or_get_cached
+#: (compiling an executable or loading it from the persistent cache)
+_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": ("trace", "traces"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": ("lower",
+                                                        "lowerings"),
+    "/jax/core/compile/backend_compile_duration": ("compile", None),
+}
 _lock = threading.Lock()
-_cache_counts = {"requests": 0, "hits": 0, "misses": 0, "compile_s": 0.0}
+_cache_counts = {"requests": 0, "hits": 0, "misses": 0, "compile_s": 0.0,
+                 "trace_s": 0.0, "lower_s": 0.0, "traces": 0,
+                 "lowerings": 0}
+_by_fun: dict = {}
+#: a stage shorter than this is counted but gets no span: eager
+#: primitives trace in microseconds, by the thousand under interpret mode
+_SPAN_FLOOR_S = 1e-3
+_making = threading.local()      # .depth: stages open on this thread
 _listening = False
 
 
@@ -55,19 +75,84 @@ def _on_cache_event(event: str, **_kw) -> None:
             _cache_counts[key] += 1
 
 
-def _on_compile(event: str, duration_secs: float, **_kw) -> None:
-    if event == _COMPILE_EVENT:
-        with _lock:
-            _cache_counts["compile_s"] += duration_secs
+def _on_stage_start(event: str, _value, **_kw) -> None:
+    # JAX records a stage's start time as a scalar under the stage's own
+    # event name; a JAX that stops doing so leaves depth at 0 and nested
+    # stages are then counted too (tests/test_launch_spans.py notices)
+    if event in _STAGES:
+        _making.depth = getattr(_making, "depth", 0) + 1
+
+
+def _on_stage(event: str, duration_secs: float, fun_name="?",
+              **_kw) -> None:
+    """One finished jit stage: into the process's totals, and, when
+    ``obs`` is armed, onto the job's timeline as a retroactive span
+    (from a millisecond up).  A trace or a lowering that ran while
+    another stage was open on the thread is left out (Pallas lowering
+    traces the kernel's jnp helpers by the thousand): the outer stage's
+    duration already holds its time.  ``compile_s`` stays what it was,
+    the sum of every ``backend_compile`` event, nested or not."""
+    stage, counter = _STAGES.get(event, (None, None))
+    if stage is None:
+        return
+    _making.depth = depth = max(getattr(_making, "depth", 1) - 1, 0)
+    if depth and stage != "compile":
+        return
+    # lowering and compiling name the module, "jit(<function>)"
+    fun = str(fun_name)
+    if fun.startswith("jit(") and fun.endswith(")"):
+        fun = fun[4:-1]
+    with _lock:
+        _cache_counts[f"{stage}_s"] += duration_secs
+        row = _by_fun.setdefault(fun, {"trace_s": 0.0, "lower_s": 0.0,
+                                       "compile_s": 0.0, "n": 0})
+        row[f"{stage}_s"] += duration_secs
+        if counter is not None:
+            _cache_counts[counter] += 1
+        if stage == "lower":
+            row["n"] += 1
+    if stage == "trace":
+        obs.count("jit.traces")     # in a window job: a retrace
+    if duration_secs >= _SPAN_FLOOR_S:
+        now = time.monotonic_ns()
+        obs.add_complete(f"jit.{stage}", now - int(duration_secs * 1e9),
+                         now, fun=fun)
+
+
+def named(name: str):
+    """Give a kernel's jitted wrapper the stable name its program goes
+    by everywhere: the HLO module (``jit_<name>``), the device ops of a
+    profile, ``jax.monitoring``'s ``fun_name``.  One name per kernel
+    kind, not per geometry (shapes are in the op text already); set
+    before ``jax.jit`` / ``shard_map`` sees the function."""
+    def deco(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+    return deco
+
+
+def named_like(local):
+    """The per-shard kernel `local` behind a plain function carrying its
+    name, for ``shard_map``: the sharded program is then ``jit_racon_*``
+    like the single-device one, not ``jit__lambda_``."""
+    def sharded(*a):
+        return local(*a)
+    return named(getattr(local, "__name__", "sharded"))(sharded)
 
 
 def cache_traffic() -> dict:
-    """Persistent-cache traffic of this process since the first
-    :func:`require_tpu`: compile requests that consulted the cache,
-    hits (executable loaded from disk), misses (compiled and written),
-    and the seconds spent compiling or loading."""
+    """What making programs cost this process since the first
+    :func:`require_tpu`: compile requests that consulted the persistent
+    cache, hits (executable loaded from disk), misses (compiled and
+    written), the seconds spent compiling or loading (``compile_s``),
+    tracing (``trace_s``, over ``traces`` outermost traces) and lowering
+    (``lower_s``, ``lowerings``), and the same seconds by the jitted
+    function's name (``by_fun``; ``n`` = programs lowered).  Process
+    lifetime, so work outside any job's tracer (``warm_for_target``) is
+    covered."""
     with _lock:
-        return dict(_cache_counts)
+        return {**_cache_counts,
+                "by_fun": {f: dict(row) for f, row in _by_fun.items()}}
 
 
 def identity() -> dict:
@@ -90,8 +175,9 @@ def require_tpu() -> dict:
     with _lock:
         if not _listening:
             jax.monitoring.register_event_listener(_on_cache_event)
+            jax.monitoring.register_scalar_listener(_on_stage_start)
             jax.monitoring.register_event_duration_secs_listener(
-                _on_compile)
+                _on_stage)
             _listening = True
     try:
         ident = identity()

@@ -171,13 +171,15 @@ def trace_path() -> Optional[str]:
 
 # -- recording hooks (each a cheap no-op when disarmed) --------------------
 
-def span(name: str, **args):
+def span(name: str, cat: str = "span", **args):
     """Context manager timing a region; returns the shared null span
-    when disarmed so the call site costs one identity return."""
+    when disarmed so the call site costs one identity return.  ``cat``
+    is the event's category: ``"launch"`` for the drivers' per-launch
+    spans, which a bounded shipment drops first (``Tracer.export``)."""
     t = _tracer
     if t is None:
         return NULL_SPAN
-    return Span(t, name, args)
+    return Span(t, name, args, cat)
 
 
 def event(name: str, **args) -> None:
@@ -191,12 +193,13 @@ def event(name: str, **args) -> None:
         t.add_instant(name, **args)
 
 
-def add_complete(name: str, t0_ns: int, t1_ns: int, **args) -> None:
+def add_complete(name: str, t0_ns: int, t1_ns: int, cat: str = "span",
+                 **args) -> None:
     """Retroactive span from raw monotonic_ns stamps (kernel-cache miss
     detection times the call first, then learns it was a compile)."""
     t = _tracer
     if t is not None:
-        t.add_complete(name, t0_ns, t1_ns, **args)
+        t.add_complete(name, t0_ns, t1_ns, cat=cat, **args)
 
 
 def count(name: str, n: int = 1) -> None:

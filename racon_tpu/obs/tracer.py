@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from typing import List, Optional
@@ -30,28 +31,48 @@ class Span:
     Records a Chrome-trace complete ("ph":"X") event on exit; ``set()``
     attaches key/value args that show up in the Perfetto detail pane.
     An exception escaping the body is recorded as an ``error`` arg so a
-    trace of a degraded run shows *where* the lattice demoted."""
+    trace of a degraded run shows *where* the lattice demoted.
 
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    One clock: when the process has imported JAX the span also enters a
+    ``jax.profiler.TraceAnnotation`` of the same name and args, so under
+    a running ``jax.profiler`` trace it sits on the ``/host:CPU`` plane
+    of the same ``.xplane.pb`` as the device ops.  With the profiler off
+    a ``TraceMe`` is a flag check; without JAX nothing is imported."""
 
-    def __init__(self, tracer: "Tracer", name: str, args: dict):
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_annotation")
+
+    def __init__(self, tracer: "Tracer", name: str, args: dict,
+                 cat: str = "span"):
         self._tracer = tracer
         self.name = name
+        self.cat = cat
         self.args = args
         self._t0 = 0
+        self._annotation = None
 
     def set(self, **attrs) -> "Span":
         self.args.update(attrs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
         return self
 
     def __enter__(self) -> "Span":
+        # getattr: another thread may be half-way through importing jax
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is not None:
+            self._annotation = profiler.TraceAnnotation(self.name,
+                                                        **self.args)
+            self._annotation.__enter__()
         self._t0 = time.monotonic_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        t1 = time.monotonic_ns()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.args.setdefault("error", exc_type.__name__)
-        self._tracer.add_complete(self.name, self._t0, time.monotonic_ns(),
+        self._tracer.add_complete(self.name, self._t0, t1, cat=self.cat,
                                   **self.args)
         return False
 
@@ -165,14 +186,23 @@ class Tracer:
         ``max_events`` events (newest win — the tail is where the crash
         or the result lives), thread names, and the clock epoch a peer
         needs to re-base them.  Bounded so a shipment always fits the
-        wire's one-line message limit."""
+        wire's one-line message limit.  The cap is filled with every
+        other category first and the drivers' ``"launch"`` spans last:
+        a job's tail is all launches, and a shipment of nothing else
+        would drop the ``phase.*`` spans from every merged trace."""
         with self._lock:
             events = list(self._events)
             names = dict(self._thread_names)
             dropped = self.dropped
         if max_events is not None and len(events) > max_events:
             dropped += len(events) - max_events
-            events = events[-max_events:]
+            launches, rest = [], []
+            for i, ev in enumerate(events):
+                (launches if ev.get("cat") == "launch" else rest).append(i)
+            keep = rest[-max_events:]
+            if len(keep) < max_events:
+                keep += launches[len(keep) - max_events:]
+            events = [events[i] for i in sorted(keep)]
         ship = {
             "pid": self.pid,
             "t0_mono_ns": self._t0,

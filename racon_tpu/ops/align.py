@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .encoding import encode
+from ..device import named
 from .kernel_cache import device_keyed_cache
 
 INF = jnp.int32(1 << 28)
@@ -135,13 +136,13 @@ def build_align_kernel(cap: int, band: int, shard_n: int = 1):
         ok = ok & (i == 0) & (j == 0)
         return ops, cnt, ok
 
+    batched = named("racon_align_xla")(jax.vmap(one))
     if shard_n > 1:
         from ..parallel.partitioner import get_partitioner
 
         return get_partitioner().partition(
-            jax.vmap(one), in_axes=[("query",)] * 4,
-            out_axes=("query",))
-    return jax.jit(jax.vmap(one))
+            batched, in_axes=[("query",)] * 4, out_axes=("query",))
+    return jax.jit(batched)
 
 
 class _XlaAlignOps:
@@ -154,6 +155,8 @@ class _XlaAlignOps:
     gather rows from the per-job views instead of re-materializing."""
 
     span_name = "align.cohort"
+    pack_span = "align.export"
+    install_span = "align.install"
     async_dispatch = True
 
     def __init__(self, pipeline, report, stats, state):

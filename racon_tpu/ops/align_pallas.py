@@ -41,7 +41,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import config
+from .. import config, obs
+from ..device import named
 from . import band as _band
 from .encoding import encode, pack_bases
 from .kernel_cache import device_keyed_cache
@@ -131,6 +132,7 @@ def _build_edge_kernel(rcap: int, K: int, backward: bool,
 
     TCAP = rcap + K
     QIN = rcap if pack == 1 else max(128, _round_up(rcap // pack, 128))
+    name = f"racon_hirschberg_edge_{'bwd' if backward else 'fwd'}"
 
     def kernel(scal_ref, q_ref, t_ref, out_ref, row_scr, tq_scr):
         lane_k = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
@@ -270,11 +272,13 @@ def _build_edge_kernel(rcap: int, K: int, backward: bool,
             scratch_shapes=[pltpu.VMEM((1, K), jnp.int32),
                             pltpu.VMEM((1, TCAP), jnp.int32)],
             interpret=interpret,
+            name=name,
         )
 
     def plain(b):
         call = make(b)
 
+        @named(name)
         def fn(scal, q, t):
             out = call(scal.reshape(b, 1, 4),
                        q.reshape(b, 1, QIN),
@@ -433,11 +437,13 @@ def _build_base_kernel(K: int, interpret: bool = False, pack: int = 1):
             scratch_shapes=[pltpu.VMEM((RB, K), jnp.int32),
                             pltpu.VMEM((1, TCAP), jnp.int32)],
             interpret=interpret,
+            name="racon_hirschberg_base",
         )
 
     def plain(b):
         call = make(b)
 
+        @named("racon_hirschberg_base")
         def fn(scal, q, t):
             ops, cnt, ok, dist = call(scal.reshape(b, 1, 4),
                                       q.reshape(b, 1, QCAP),
@@ -518,20 +524,22 @@ def align_pairs(pairs, *, interpret=None, band_overrides=None, hits=None):
         if not big:
             break
         active = [t for t in active if (t.ib - t.ia) <= BASE_ROWS]
-        new_tasks = _split_round(pairs, big, bands, failed, interpret,
-                                 verify)
+        with obs.span("align.round", cat="launch", tasks=len(big)) as sp:
+            new_tasks = _split_round(pairs, big, bands, failed, interpret,
+                                     verify, sp)
         active.extend(new_tasks)
 
     # base cases
     base = [t for t in active if t.pair not in failed]
     _solve_base(pairs, base, bands, segments, failed, interpret, verify)
 
-    for idx, segs in segments.items():
-        if idx in failed:
-            continue
-        segs.sort(key=lambda s: s[0])
-        results[idx] = np.concatenate([s[1] for s in segs]) if segs else \
-            np.zeros(0, np.int32)
+    with obs.span("align.traceback", cat="launch", pairs=len(segments)):
+        for idx, segs in segments.items():
+            if idx in failed:
+                continue
+            segs.sort(key=lambda s: s[0])
+            results[idx] = np.concatenate([s[1] for s in segs]) if segs \
+                else np.zeros(0, np.int32)
     if hits is not None and verify:
         # any banded-pair failure is a band hit: a verified-clean banded
         # pair cannot fail mid-recursion (certificate covers co-optima)
@@ -581,7 +589,29 @@ def _task_arrays(pairs, tasks, bands, rcap, K, backward, pack=1):
     return scal, qs, ts
 
 
-def _split_round(pairs, tasks, bands, failed, interpret, verify=None):
+def _launch(kernel, call, args, n_real, **geom):
+    """One kernel launch, split where the host stops working and starts
+    waiting: ``align.dispatch`` is the jitted call that returns device
+    futures (on a program's first use it traces, lowers and loads, which
+    shows as ``jit.*`` spans inside), ``align.wait`` the blocking copy
+    of the results back.  `n_real` of the batch's rows are tasks, the
+    rest pads it to a power of two."""
+    B = len(args[0])
+    with obs.span("align.dispatch", cat="launch", kernel=kernel, B=B,
+                  **geom):
+        outs = call(B)(*args)
+    with obs.span("align.wait", cat="launch", kernel=kernel, B=B, **geom):
+        outs = (tuple(np.asarray(x) for x in outs)
+                if isinstance(outs, (tuple, list)) else np.asarray(outs))
+    obs.count("align.launches.base" if kernel == "base"
+              else "align.launches.edge")
+    obs.count("align.tasks.real", n_real)
+    obs.count("align.tasks.pad", B - n_real)
+    return outs
+
+
+def _split_round(pairs, tasks, bands, failed, interpret, verify=None,
+                 round_span=obs.NULL_SPAN):
     """One Hirschberg round: split every oversized task at its midpoint."""
     out = []
     by_bucket = {}
@@ -592,64 +622,74 @@ def _split_round(pairs, tasks, bands, failed, interpret, verify=None):
         rcap = next(rb for rb in ROW_BUCKETS if half <= rb)
         by_bucket.setdefault((rcap, K), []).append(t)
 
+    round_span.set(buckets=len(by_bucket))
     pk = _pack_factor()
     for (rcap, K), group in sorted(by_bucket.items()):
         fwd = _build_edge_kernel(rcap, K, False, interpret, pk)
         bwd = _build_edge_kernel(rcap, K, True, interpret, pk)
-        # forward over [ia, imid], backward over [imid, ib]
-        f_tasks, b_tasks = [], []
-        for t in group:
-            imid = (t.ia + t.ib) // 2
-            f_tasks.append(_Task(t.pair, t.ia, imid, t.ja, t.jb))
-            b_tasks.append(_Task(t.pair, imid, t.ib, t.ja, t.jb))
-        fs, fq, ft = _task_arrays(pairs, f_tasks, bands, rcap, K, False, pk)
-        bs, bq, bt = _task_arrays(pairs, b_tasks, bands, rcap, K, True, pk)
         # pad the batch dim to a power of two so each (rcap, K) bucket
         # compiles a handful of kernel variants, not one per group size
         B = _pow2(len(group))
+        geom = dict(rcap=rcap, K=K)
+        with obs.span("align.pack", cat="launch", kernel="edge", B=B,
+                      **geom):
+            # forward over [ia, imid], backward over [imid, ib]
+            f_tasks, b_tasks = [], []
+            for t in group:
+                imid = (t.ia + t.ib) // 2
+                f_tasks.append(_Task(t.pair, t.ia, imid, t.ja, t.jb))
+                b_tasks.append(_Task(t.pair, imid, t.ib, t.ja, t.jb))
+            pad = lambda a: np.concatenate(
+                [a, np.repeat(a[-1:], B - len(group), axis=0)]) \
+                if B > len(group) else a
+            f_args = [pad(a) for a in _task_arrays(
+                pairs, f_tasks, bands, rcap, K, False, pk)]
+            b_args = [pad(a) for a in _task_arrays(
+                pairs, b_tasks, bands, rcap, K, True, pk)]
         m = _dispatch_shards(B)
         if m > 1:
             from .batch_exec import count_shard_rows
 
             count_shard_rows(len(group), B, m)  # forward launch
             count_shard_rows(len(group), B, m)  # backward launch
-        pad = lambda a: np.concatenate(
-            [a, np.repeat(a[-1:], B - len(group), axis=0)]) \
-            if B > len(group) else a
-        F = np.asarray(fwd(B)(pad(fs), pad(fq), pad(ft)))[:len(group)]
-        Bv = np.asarray(bwd(B)(pad(bs), pad(bq), pad(bt)))[:len(group)]
-        for gi, t in enumerate(group):
-            imid = (t.ia + t.ib) // 2
-            K_, gdmin = bands[t.pair]
-            # Both midpoint rows map lane o to absolute column
-            # j = imid + gdmin + o (independent of each frame's clipped
-            # origin); overlay onto the task's column range rel. ja.
-            jmid = imid + gdmin - t.ja + np.arange(K_)
-            span = t.jb - t.ja
-            fv = np.full(span + 1, INF, np.int64)
-            bv = np.full(span + 1, INF, np.int64)
-            m = (jmid >= 0) & (jmid <= span)
-            fv[jmid[m]] = F[gi][m]
-            bv[jmid[m]] = Bv[gi][m]
-            tot = fv + bv
-            jstar = int(np.argmin(tot))
-            if tot[jstar] >= INF:
-                failed.add(t.pair)
-                continue
-            v = verify.get(t.pair) if verify else None
-            if (v is not None and t.ia == 0 and t.ib == v[0]
-                    and t.ja == 0 and t.jb == v[1]):
-                # root task of a banded pair: tot[jstar] IS the global
-                # edit distance (every path crosses the midpoint row),
-                # so check the exact Ukkonen certificate here and abort
-                # the whole pair before recursing on an unproven band
-                if not _band.ukkonen_ok(v[0], v[1], v[2], v[3],
-                                        int(tot[jstar])):
+        F = _launch("edge_fwd", fwd, f_args, len(group),
+                    **geom)[:len(group)]
+        Bv = _launch("edge_bwd", bwd, b_args, len(group),
+                     **geom)[:len(group)]
+        with obs.span("align.select", cat="launch", tasks=len(group),
+                      **geom):
+            for gi, t in enumerate(group):
+                imid = (t.ia + t.ib) // 2
+                K_, gdmin = bands[t.pair]
+                # Both midpoint rows map lane o to absolute column
+                # j = imid + gdmin + o (independent of each frame's clipped
+                # origin); overlay onto the task's column range rel. ja.
+                jmid = imid + gdmin - t.ja + np.arange(K_)
+                span = t.jb - t.ja
+                fv = np.full(span + 1, INF, np.int64)
+                bv = np.full(span + 1, INF, np.int64)
+                m = (jmid >= 0) & (jmid <= span)
+                fv[jmid[m]] = F[gi][m]
+                bv[jmid[m]] = Bv[gi][m]
+                tot = fv + bv
+                jstar = int(np.argmin(tot))
+                if tot[jstar] >= INF:
                     failed.add(t.pair)
                     continue
-            jabs = t.ja + jstar
-            out.append(_Task(t.pair, t.ia, imid, t.ja, jabs))
-            out.append(_Task(t.pair, imid, t.ib, jabs, t.jb))
+                v = verify.get(t.pair) if verify else None
+                if (v is not None and t.ia == 0 and t.ib == v[0]
+                        and t.ja == 0 and t.jb == v[1]):
+                    # root task of a banded pair: tot[jstar] IS the global
+                    # edit distance (every path crosses the midpoint row),
+                    # so check the exact Ukkonen certificate here and abort
+                    # the whole pair before recursing on an unproven band
+                    if not _band.ukkonen_ok(v[0], v[1], v[2], v[3],
+                                            int(tot[jstar])):
+                        failed.add(t.pair)
+                        continue
+                jabs = t.ja + jstar
+                out.append(_Task(t.pair, t.ia, imid, t.ja, jabs))
+                out.append(_Task(t.pair, imid, t.ib, jabs, t.jb))
     return out
 
 
@@ -670,40 +710,47 @@ def _solve_base(pairs, tasks, bands, segments, failed, interpret,
                 from .batch_exec import count_shard_rows
 
                 count_shard_rows(len(chunk), B, m)
-            scal = np.zeros((B, 4), np.int32)
-            qraw = np.zeros((B, BASE_ROWS), np.int32)
-            ts = np.full((B, TCAP), 255, np.int32)
-            for bi, t in enumerate(chunk):
-                q, tt = pairs[t.pair]
-                _, gdmin = bands[t.pair]
-                R, S = t.ib - t.ia, t.jb - t.ja
-                scal[bi] = (R, S, gdmin + t.ia - t.ja, 0)
-                qraw[bi, :R] = q[t.ia:t.ib]
-                ts[bi, :S] = tt[t.ja:t.jb]
-            scal[len(chunk):, 0] = 1  # pad tasks: 1 empty-target row
-            if pk > 1:
-                qs = pack_bases(qraw, width=QCAP)
-            else:
-                # QCAP == _round_up(BASE_ROWS, 128) == BASE_ROWS here
-                qs = qraw
-            ops, cnt, ok, dist = (np.asarray(x)
-                                  for x in kern(B)(scal, qs, ts))
-            for bi, t in enumerate(chunk):
-                v = verify.get(t.pair) if verify else None
-                if (v is not None and t.ia == 0 and t.ib == v[0]
-                        and t.ja == 0 and t.jb == v[1]):
-                    # base-case-only banded pair: the kernel's terminal
-                    # distance carries the exact Ukkonen certificate
-                    if (not ok[bi]
-                            or not _band.ukkonen_ok(v[0], v[1], v[2],
-                                                    v[3], int(dist[bi]))):
+            geom = dict(rcap=BASE_ROWS, K=K)
+            with obs.span("align.pack", cat="launch", kernel="base", B=B,
+                          **geom):
+                scal = np.zeros((B, 4), np.int32)
+                qraw = np.zeros((B, BASE_ROWS), np.int32)
+                ts = np.full((B, TCAP), 255, np.int32)
+                for bi, t in enumerate(chunk):
+                    q, tt = pairs[t.pair]
+                    _, gdmin = bands[t.pair]
+                    R, S = t.ib - t.ia, t.jb - t.ja
+                    scal[bi] = (R, S, gdmin + t.ia - t.ja, 0)
+                    qraw[bi, :R] = q[t.ia:t.ib]
+                    ts[bi, :S] = tt[t.ja:t.jb]
+                scal[len(chunk):, 0] = 1  # pad tasks: 1 empty-target row
+                if pk > 1:
+                    qs = pack_bases(qraw, width=QCAP)
+                else:
+                    # QCAP == _round_up(BASE_ROWS, 128) == BASE_ROWS here
+                    qs = qraw
+            ops, cnt, ok, dist = _launch("base", kern, (scal, qs, ts),
+                                         len(chunk), **geom)
+            with obs.span("align.traceback", cat="launch",
+                          tasks=len(chunk), K=K):
+                for bi, t in enumerate(chunk):
+                    v = verify.get(t.pair) if verify else None
+                    if (v is not None and t.ia == 0 and t.ib == v[0]
+                            and t.ja == 0 and t.jb == v[1]):
+                        # base-case-only banded pair: the kernel's
+                        # terminal distance carries the exact Ukkonen
+                        # certificate
+                        if (not ok[bi]
+                                or not _band.ukkonen_ok(
+                                    v[0], v[1], v[2], v[3],
+                                    int(dist[bi]))):
+                            failed.add(t.pair)
+                            continue
+                    if not ok[bi]:
                         failed.add(t.pair)
                         continue
-                if not ok[bi]:
-                    failed.add(t.pair)
-                    continue
-                seg = ops[bi, :cnt[bi]][::-1].astype(np.int32)
-                segments[t.pair].append((t.ia, seg))
+                    seg = ops[bi, :cnt[bi]][::-1].astype(np.int32)
+                    segments[t.pair].append((t.ia, seg))
 
 
 from .align import ops_to_cigar  # same 0=M/1=I/2=D convention
@@ -730,6 +777,8 @@ class _HirschbergOps:
     every pair per attempt with a per-job Python loop)."""
 
     span_name = "align.cohort"
+    pack_span = "align.export"
+    install_span = "align.install"
     async_dispatch = False
 
     def __init__(self, pipeline, dims, report, stats, state):
@@ -753,6 +802,7 @@ class _HirschbergOps:
         tcap = max(1, max(self.dims[j][1] for j in chunk))
         qbuf = np.zeros((len(chunk), qcap), dtype=np.int32)
         tbuf = np.zeros((len(chunk), tcap), dtype=np.int32)
+        obs.count("native.calls.align_job", len(chunk))
         for bi, job in enumerate(chunk):
             qa, ta = self.pipeline.align_job(job)
             if len(qa) <= qcap and len(ta) <= tcap:
@@ -909,7 +959,6 @@ def run_jobs(pipeline, jobs, cohort: int = None, report=None,
     import sys
 
     from ..resilience import lattice as rl
-    from .. import obs
     from .batch_exec import BatchExecutor
 
     if cohort is None:

@@ -19,14 +19,25 @@ and alignment paths share a single serving seam:
   driver-supplied hooks called from exactly one place, so every engine
   inherits identical failure semantics;
 * **pack/kernel wall split** — `pack_ns` (host export+pack) vs
-  `kernel_ns` (blocked inside the lattice serve) accumulate per executor
-  and surface as `report.extra["pack_wall_s"/"kernel_wall_s"]` in the
-  drivers, making the "pack time < kernel time" feeder criterion
-  machine-checkable (bench.py stamps the split into its log entries).
+  `kernel_ns` (host wall blocked in the lattice serve) accumulate per
+  executor and surface as `report.extra["pack_wall_s"/"kernel_wall_s"]`
+  in the drivers.  `kernel_wall_s` is NOT kernel time: for a
+  host-orchestrated engine (Hirschberg) the serve is all of
+  `align_pairs` — task arrays, padding, the per-task midpoint loop, the
+  traceback — and for an async one it is dispatch + the blocking copy
+  back.  The launch-level spans tell those apart: the executor emits
+  the ops object's `pack_span` (exactly what `pack_ns` sums) and
+  `install_span`, the drivers emit `*.dispatch` / `*.wait` per launch
+  (category `"launch"`).
 
 The driver supplies an *ops* object (duck-typed; no registration):
 
     span_name: str            # per-chunk obs span name ("poa.chunk", …)
+    pack_span: str            # launch span over export + pack + shard
+                              # pad ("poa.pack", "align.export"): one
+                              # name per seam, none shared with a span
+                              # the driver emits itself
+    install_span: str         # launch span over the install loop
     async_dispatch: bool      # False = host-orchestrated engine: the
                               # chunk resolves inline through the lattice
                               # (watchdog-wrapped), nothing is queued
@@ -157,7 +168,6 @@ class BatchExecutor:
             self.report.record_degrade(
                 "batched", "stream-sequential",
                 RuntimeError("hard memory watermark"))
-        obs.count("mem.depth_collapses")
         self.flush()
 
     # -- feeding -----------------------------------------------------------
@@ -170,18 +180,18 @@ class BatchExecutor:
             ops.surrender(ctx, idxs, exported=False)
             return
         t0 = time.monotonic_ns()
-        chunk = ops.export(ctx, idxs)
-        if not chunk:
-            self.pack_ns += time.monotonic_ns() - t0
-            return
-        packed = ops.pack(ctx, chunk)
-        shard_m = getattr(ops, "shard_multiple", None)
-        if packed is not None and shard_m is not None:
-            m = shard_m(ctx, chunk)
-            if m > 1:
-                packed, _ = pad_to_multiple(packed, m)
-                self._count_shard(len(chunk), packed, m)
+        with obs.span(ops.pack_span, cat="launch", units=len(idxs)):
+            chunk = ops.export(ctx, idxs)
+            packed = ops.pack(ctx, chunk) if chunk else None
+            shard_m = getattr(ops, "shard_multiple", None)
+            if packed is not None and shard_m is not None:
+                m = shard_m(ctx, chunk)
+                if m > 1:
+                    packed, _ = pad_to_multiple(packed, m)
+                    self._count_shard(len(chunk), packed, m)
         self.pack_ns += time.monotonic_ns() - t0
+        if not chunk:
+            return
         if not getattr(ops, "async_dispatch", True):
             # host-orchestrated engine: the kernel call IS the blocking
             # compute, so it runs inside the lattice serve (bounded
@@ -256,10 +266,12 @@ class BatchExecutor:
                 kind = ops.demote(ctx, kind, td.cause)
                 continue
             self.kernel_ns += time.monotonic_ns() - t0
-            for sub, results in pairs:
-                ops.install(ctx, kind, sub, results)
-            for item, exc in quarantined:
-                ops.quarantine(ctx, item, exc)
+            with obs.span(ops.install_span, cat="launch",
+                          units=len(chunk)):
+                for sub, results in pairs:
+                    ops.install(ctx, kind, sub, results)
+                for item, exc in quarantined:
+                    ops.quarantine(ctx, item, exc)
             self._widen(ctx, kind, attempt)
             self._done(ctx, chunk)
             return

@@ -39,6 +39,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..device import named
 from .kernel_cache import device_keyed_cache
 
 NEG = jnp.int32(-(1 << 28))
@@ -410,6 +411,7 @@ def _polish_window(cfg: PoaConfig, bb_codes, bb_w, bb_len, n_layers,
 def build_poa_kernel(cfg: PoaConfig):
     """jit-compiled batch kernel: all inputs have a leading batch dim."""
 
+    @named("racon_poa_xla")
     def batch_fn(bb_codes, bb_w, bb_len, n_layers, seqs, ws, lens, begins,
                  ends):
         return jax.vmap(

@@ -242,6 +242,8 @@ def run_consensus_phase(pipeline, *, match: int, mismatch: int, gap: int,
                 stats["backbone"] += 1
                 continue
             jobs.append((i, min(k, DEPTH_CAP), bb_len))
+    # per-window ctypes calls (ROADMAP S5), counted once per loop
+    obs.count("native.calls.window_info", n - len(replayed))
     report.record_served("backbone", stats["backbone"])
 
     if jobs:
@@ -267,9 +269,8 @@ def run_consensus_phase(pipeline, *, match: int, mismatch: int, gap: int,
         # driver would have admitted it.  Dropped layers only thin the
         # POA coverage (consensus still forms; parity with the reference
         # is kept by the golden tests); the count is surfaced as
-        # report.extra["layers_dropped_maxlen"] and the
-        # `poa.layers_dropped_maxlen` metrics counter so a serving-mix
-        # or accuracy shift on mixed-length datasets is attributable.
+        # report.extra["layers_dropped_maxlen"] so a serving-mix or
+        # accuracy shift on mixed-length datasets is attributable.
         buckets = {}
         for i, depth, bb in jobs:
             bucket = next(b for b in DEPTH_BUCKETS if depth <= b)
@@ -300,7 +301,6 @@ def run_consensus_phase(pipeline, *, match: int, mismatch: int, gap: int,
             # must not bill as DP work.
             obs.count(f"poa.cells.d{depth_bucket}.c{wl_class}",
                       sum(d for _, d, _ in bucket_jobs) * wl_class)
-            obs.observe("poa.bucket_windows", len(bucket_jobs))
             # Bucket spans cover submit-side work; with pipelining a
             # chunk of bucket X may *drain* inside bucket Y's span — the
             # async-dispatch overlap the trace is there to make visible.
@@ -355,6 +355,9 @@ def run_consensus_phase(pipeline, *, match: int, mismatch: int, gap: int,
                 journal.append_window(i, tid, rank, "host",
                                       pipeline.get_consensus(i), polished)
             stats["host_fallback"] += 1
+    obs.count("native.calls.consensus_cpu_one", len(fallback))
+    if journal is not None:
+        obs.count("native.calls.window_info", len(fallback))
     report.add_wall("host", time.perf_counter() - t0)
     report.record_served("host", stats["host_fallback"])
     report.extra["device_rejected"] = stats["failed"]
@@ -532,6 +535,8 @@ class _ConsensusOps:
     down to the host floor."""
 
     span_name = "poa.chunk"
+    pack_span = "poa.pack"
+    install_span = "poa.install"
     async_dispatch = True
 
     def __init__(self, pipeline, B, trim, stats, fallback, report,
@@ -589,6 +594,7 @@ class _ConsensusOps:
 
     def dispatch(self, ctx, kind, packed, chunk):
         faults.check(f"poa.run.{kind}", [i for i, _, _ in chunk])
+        _count_launch(len(chunk), packed)
         return _submit(ctx.kernel, packed, kind in _PALLAS_KINDS,
                        _band_active(kind))
 
@@ -596,10 +602,10 @@ class _ConsensusOps:
         pallas = kind in _PALLAS_KINDS
         banded = _band_active(kind)
         faults.check(f"poa.run.{kind}", [i for i, _, _ in sub])
-        return _unpack(
-            _submit(ctx.kernel,
-                    _pack(sub, ctx.cfg, self.B, self._widths(sub, ctx.cfg)),
-                    pallas, banded), pallas, banded)
+        packed = _pack(sub, ctx.cfg, self.B, self._widths(sub, ctx.cfg))
+        _count_launch(len(sub), packed)
+        return _unpack(_submit(ctx.kernel, packed, pallas, banded),
+                       pallas, banded)
 
     def unpack(self, ctx, kind, outs):
         return _unpack(outs, kind in _PALLAS_KINDS, _band_active(kind))
@@ -830,6 +836,7 @@ def _export_chunk(pipeline, idxs, cfg, fallback, stats=None, report=None):
     failure (the `window.export` seam) quarantines just that window.
     """
     chunk = []
+    obs.count("native.calls.export_window", len(idxs))
     for i in idxs:
         try:
             wx = pipeline.export_window(i)
@@ -842,15 +849,11 @@ def _export_chunk(pipeline, idxs, cfg, fallback, stats=None, report=None):
         keep = [j for j in range(k) if 0 < wx.lens[j] <= cfg.max_len]
         # Per-class geometry admission (ADVICE.md): a layer longer than
         # THIS class's max_len is dropped here where the old dataset-max
-        # geometry admitted it; counted (report.extra + the named
-        # `poa.layers_dropped_maxlen` metrics counter) so serving-mix
+        # geometry admitted it; counted (report.extra) so serving-mix
         # shifts on mixed-length datasets stay attributable.
         if stats is not None:
-            dropped = int(
+            stats["layers_dropped"] += int(
                 sum(1 for ln in wx.lens[:DEPTH_CAP] if ln > cfg.max_len))
-            stats["layers_dropped"] += dropped
-            if dropped:
-                obs.count("poa.layers_dropped_maxlen", dropped)
         if len(keep) < len(wx.lens[:DEPTH_CAP]) and len(keep) < 2:
             fallback.append(i)
             continue
@@ -908,34 +911,49 @@ def _pack(chunk, cfg, pad_to=None, band_widths=None):
     return (bb, bbw, bb_len, n_layers, seqs, ws, lens, begins, ends, wband)
 
 
+def _count_launch(n_real, packed) -> None:
+    """One batch on its way to the device: `n_real` rows carry a
+    window, the rest pad the batch to its compiled size (and to the
+    shard multiple)."""
+    rows = len(packed[0])
+    obs.count("poa.launches")
+    obs.count("poa.rows.real", n_real)
+    obs.count("poa.rows.pad", rows - n_real)
+
+
 def _submit(kernel, packed, use_pallas, banded=False):
     """Dispatch one packed chunk; returns device futures (async).
     `packed` is _pack's 10-tuple (trailing per-window half-band row) or
     a legacy 9-tuple from flat-only callers (probes, the multichip
     worker) — the band row is only touched on banded dispatch."""
     bb, bbw, bb_len, n_layers, seqs, ws, lens, begins, ends = packed[:9]
-    if use_pallas:
-        args = [bb_len[:, None], n_layers[:, None], lens, begins,
-                ends, bb.astype(np.int32), bbw, seqs.astype(np.int32), ws]
-        if banded:
-            args.append(packed[9])
-        return kernel(*args)
-    return kernel(bb, bbw, bb_len, n_layers, seqs, ws, lens, begins, ends)
+    with obs.span("poa.dispatch", cat="launch", B=len(bb)):
+        if use_pallas:
+            args = [bb_len[:, None], n_layers[:, None], lens, begins,
+                    ends, bb.astype(np.int32), bbw, seqs.astype(np.int32),
+                    ws]
+            if banded:
+                args.append(packed[9])
+            return kernel(*args)
+        return kernel(bb, bbw, bb_len, n_layers, seqs, ws, lens, begins,
+                      ends)
 
 
 def _unpack(outs, use_pallas, banded=False):
     """Block on device futures; normalize to host arrays."""
     cb, cc, cl, fl = outs[0], outs[1], outs[2], outs[3]
-    cons_base = np.asarray(cb)
-    cons_cov = np.asarray(cc)
-    cons_len = np.asarray(cl)
-    failed = np.asarray(fl)
+    with obs.span("poa.wait", cat="launch", B=len(cb)):
+        cons_base = np.asarray(cb)
+        cons_cov = np.asarray(cc)
+        cons_len = np.asarray(cl)
+        failed = np.asarray(fl)
+        band_hit = (np.asarray(outs[5])[:, 0]
+                    if use_pallas and banded else None)
     if use_pallas:
         cons_len = cons_len[:, 0]
         failed = failed[:, 0]
         if banded:
-            return (cons_base, cons_cov, cons_len, failed,
-                    np.asarray(outs[5])[:, 0])
+            return cons_base, cons_cov, cons_len, failed, band_hit
     return cons_base, cons_cov, cons_len, failed
 
 

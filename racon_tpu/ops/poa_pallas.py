@@ -54,6 +54,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..device import named
 from .kernel_cache import device_keyed_cache
 from .poa import PoaConfig
 
@@ -691,12 +692,14 @@ def build_pallas_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 pltpu.SemaphoreType.DMA((2, 2)),        # per (slot, array)
             ],
             interpret=interpret,
+            name="racon_poa_v2",
         )
 
     @functools.lru_cache(maxsize=8)
     def jitted(batch: int):
         call = make(batch)
 
+        @named("racon_poa_v2")
         def fn(bb_len, n_layers, lens, begins, ends, bb, bbw, seqs, ws,
                *extra):
             # host-shaped inputs -> sublane-blocked tiles (XLA relayouts

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..device import named
 from .kernel_cache import device_keyed_cache
 from .poa import PoaConfig
 
@@ -918,6 +919,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 pltpu.SemaphoreType.DMA((2,)),               # tb load
             ],
             interpret=interpret,
+            name="racon_poa_ls",
         )
 
     @functools.lru_cache(maxsize=8)
@@ -925,6 +927,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
         call = make(batch)
         nb = batch // G
 
+        @named("racon_poa_ls")
         def fn(bb_len, n_layers, lens, begins, ends, bb, bbw, seqs, ws,
                *extra):
             def to_n(x):

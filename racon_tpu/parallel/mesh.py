@@ -15,6 +15,8 @@ from typing import Optional, Sequence
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..device import named_like
+
 AXIS = "windows"
 
 def device_mesh(devices: Optional[Sequence] = None) -> Mesh:
@@ -47,7 +49,7 @@ def shard_batch_build(build_local, batch, n_in, n_out):
     local = build_local(batch // n_dev)
     out_specs = (P(AXIS),) * n_out if n_out > 1 else P(AXIS)
     return jax.jit(jax.shard_map(
-        lambda *a: local(*a), mesh=device_mesh(),
+        named_like(local), mesh=device_mesh(),
         in_specs=(P(AXIS),) * n_in, out_specs=out_specs,
         check_vma=False))
 

@@ -48,6 +48,7 @@ from typing import Optional, Sequence, Tuple
 import numpy as np
 
 from .. import config
+from ..device import named_like
 from ..ops.kernel_cache import device_keyed_cache
 from . import axes
 
@@ -210,7 +211,7 @@ class Partitioner:
         spec = self.spec("windows")
         out_specs = (spec,) * n_out if n_out > 1 else spec
         return jax.jit(jax.shard_map(
-            lambda *a: local(*a), mesh=self.mesh,
+            named_like(local), mesh=self.mesh,
             in_specs=(spec,) * n_in, out_specs=out_specs,
             check_vma=False))
 

@@ -80,7 +80,6 @@ class PhaseReport:  # concurrency: single-writer accumulator; the coordinator se
 
     def add_wall(self, tier: str, seconds: float) -> None:
         self.wall_s[tier] = self.wall_s.get(tier, 0.0) + seconds
-        obs.observe(f"wall_s.{self.phase}.{tier}", seconds)
 
     def merge(self, other: "PhaseReport") -> None:
         """Fold another report for the same phase into this one (the
@@ -218,7 +217,8 @@ class RunReport:
         now = device.cache_traffic()
         return {"jax_cache": {
             "dir": jax.config.jax_compilation_cache_dir,
-            **{k: round(now[k] - self._cache0[k], 3) for k in now}}}
+            **{k: round(v - self._cache0[k], 3) for k, v in now.items()
+               if k != "by_fun"}}}
 
     def summary(self) -> dict:
         """Compact serving-mix view for logs and the bench JSON line."""

@@ -31,6 +31,8 @@ class FakeOps:
     whose every dispatch/attempt fails (forcing TierDead -> demote)."""
 
     span_name = "fake.chunk"
+    pack_span = "fake.pack"
+    install_span = "fake.install"
 
     def __init__(self, async_dispatch=True, tiers=("fast", "slow", "host"),
                  fail=None, dead_tiers=(), dispatch_fail=None):

@@ -98,6 +98,14 @@ def _ls(window_length, depth, B, band=False):
     return fn, _poa_args(cfg, B, band)
 
 
+def _v2(B):
+    from racon_tpu.ops.poa_pallas import build_pallas_poa_kernel
+
+    cfg = poa_driver.make_config(500, 8, *SCORES)
+    return (build_pallas_poa_kernel(cfg, interpret=False)(B),
+            _poa_args(cfg, B))
+
+
 def _edge(rcap, K, backward, B):
     pack = align_pallas._pack_factor()
     fn = align_pallas._build_edge_kernel(rcap, K, backward,
@@ -148,11 +156,7 @@ def test_lockstep_poa_kernel_lowers_at_node_factor_4(monkeypatch):
 
 
 def test_v2_poa_kernel_lowers_to_tpu():
-    from racon_tpu.ops.poa_pallas import build_pallas_poa_kernel
-
-    cfg = poa_driver.make_config(500, 8, *SCORES)
-    _export_tpu(build_pallas_poa_kernel(cfg, interpret=False)(2),
-                _poa_args(cfg, 2))
+    _export_tpu(*_v2(2))
 
 
 @pytest.mark.parametrize("rcap,K", [(512, 256), (8192, 1024)])
@@ -164,6 +168,28 @@ def test_hirschberg_edge_kernels_lower_to_tpu(single_device, rcap, K):
 @pytest.mark.parametrize("K", [256, 1024])
 def test_hirschberg_base_kernel_lowers_to_tpu(single_device, K):
     _export_tpu(*_base(K, 2))
+
+
+@pytest.mark.parametrize("name,build", [
+    ("racon_poa_ls", lambda: _ls(500, 8, SHARD_BATCH)),
+    ("racon_poa_v2", lambda: _v2(2)),
+    ("racon_hirschberg_edge_fwd", lambda: _edge(512, 256, False, 2)),
+    ("racon_hirschberg_edge_bwd", lambda: _edge(512, 256, True, 2)),
+    ("racon_hirschberg_base", lambda: _base(256, 2)),
+])
+def test_lowered_kernel_carries_its_stable_name(single_device, name, build):
+    """One name per kernel kind, in both places a profile shows: the HLO
+    module (``jit_<name>``, from the jitted wrapper) and the Mosaic
+    custom call's ``kernel_name`` (from ``pallas_call(name=)``).  The
+    call target stays ``tpu_custom_call``: the benchmark's rooflines
+    find the kernels by it."""
+    fn, args = build()
+    assert fn.__name__ == name
+    text = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        *args).mlir_module()
+    assert f"module @jit_{name} " in text
+    assert f'kernel_name = "{name}"' in text
+    assert "stablehlo.custom_call @tpu_custom_call" in text
 
 
 # -- Mosaic compile --------------------------------------------------------
