@@ -133,6 +133,14 @@ def load() -> ctypes.CDLL:
     lib.rt_pipeline_consensus_cpu_one.argtypes = [
         ctypes.c_void_p, ctypes.c_uint64]
 
+    lib.rt_pipeline_consensus_cpu_submit.restype = None
+    lib.rt_pipeline_consensus_cpu_submit.argtypes = [
+        ctypes.c_void_p, ctypes.c_uint64]
+
+    lib.rt_pipeline_consensus_cpu_join.restype = ctypes.c_int64
+    lib.rt_pipeline_consensus_cpu_join.argtypes = [
+        ctypes.c_void_p, u64p, ctypes.c_uint64, u8p]
+
     lib.rt_pipeline_set_consensus.restype = None
     lib.rt_pipeline_set_consensus.argtypes = [
         ctypes.c_void_p, ctypes.c_uint64, ctypes.c_char_p, ctypes.c_uint32,

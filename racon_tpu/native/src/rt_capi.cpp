@@ -283,6 +283,31 @@ void rt_pipeline_consensus_cpu_all(void* handle) {
   });
 }
 
+// Overlapped host fallback (ops/poa_driver.py): submit hands window i to a
+// pool worker and returns at once; join waits for all of them, writes each
+// window's polished flag (in the caller's order) and returns how many had
+// already finished when it was called, -1 if one failed.
+void rt_pipeline_consensus_cpu_submit(void* handle, uint64_t i) {
+  guarded_void([&] {
+    static_cast<PipelineHandle*>(handle)->pipeline->consensus_cpu_submit(i);
+  });
+}
+
+int64_t rt_pipeline_consensus_cpu_join(void* handle, const uint64_t* windows,
+                                       uint64_t n, uint8_t* polished) {
+  return guarded(
+      [&]() -> int64_t {
+        auto& p = *static_cast<PipelineHandle*>(handle)->pipeline;
+        const size_t finished = p.consensus_cpu_join();
+        for (uint64_t k = 0; k < n; ++k) {
+          polished[k] =
+              windows[k] < p.num_windows() && p.is_polished(windows[k]);
+        }
+        return static_cast<int64_t>(finished);
+      },
+      -1);
+}
+
 void rt_pipeline_set_consensus(void* handle, uint64_t i, const char* consensus,
                                uint32_t len, int polished) {
   guarded_void([&] {

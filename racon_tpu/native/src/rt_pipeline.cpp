@@ -4,8 +4,10 @@
 #include "rt_align.hpp"
 
 #include <algorithm>
+#include <chrono>
 #include <cstdio>
 #include <cstdlib>
+#include <exception>
 #include <future>
 #include <unordered_map>
 
@@ -421,6 +423,38 @@ void Pipeline::consensus_cpu_all() {
   } else if (!futs.empty()) {
     logger_.log("[racon_tpu::Pipeline::polish] generated consensus");
   }
+}
+
+void Pipeline::consensus_cpu_submit(size_t i) {
+  if (i >= windows_.size()) {
+    rt::fail("[racon_tpu::Pipeline::consensus_cpu_submit] error: window %zu "
+             "out of range!\n", i);
+  }
+  submitted_.emplace_back(pool_->submit([this, i] { consensus_cpu_one(i); }));
+}
+
+size_t Pipeline::consensus_cpu_join() {
+  std::vector<std::future<void>> futs;
+  futs.swap(submitted_);
+  size_t finished = 0;
+  for (auto& f : futs) {
+    finished += f.wait_for(std::chrono::seconds(0)) ==
+                std::future_status::ready;
+  }
+  std::exception_ptr first;
+  for (auto& f : futs) {
+    try {
+      f.get();
+    } catch (...) {
+      if (!first) {
+        first = std::current_exception();
+      }
+    }
+  }
+  if (first) {
+    std::rethrow_exception(first);
+  }
+  return finished;
 }
 
 void Pipeline::set_consensus(size_t i, std::string consensus, bool polished) {

@@ -19,6 +19,7 @@
 #pragma once
 
 #include <cstdint>
+#include <future>
 #include <memory>
 #include <string>
 #include <vector>
@@ -82,6 +83,14 @@ class Pipeline {
   // Host POA for one window / all unfinished windows (thread pool).
   bool consensus_cpu_one(size_t i);
   void consensus_cpu_all();
+  // Overlapped host fallback: hand window i to a pool worker (each owns
+  // its aligner slot) and return at once; the caller goes on installing
+  // other windows. join waits for every submitted window and returns how
+  // many had already finished when it was called; a failed window throws
+  // from join, after all of them have ended.
+  void consensus_cpu_submit(size_t i);
+  size_t consensus_cpu_join();
+  bool is_polished(size_t i) const { return polished_[i] != 0; }
 
   // Install a device-produced consensus for window i.
   void set_consensus(size_t i, std::string consensus, bool polished);
@@ -118,6 +127,7 @@ class Pipeline {
   std::vector<uint64_t> targets_coverages_;
 
   std::vector<std::unique_ptr<PoaAligner>> aligners_;  // one per thread
+  std::vector<std::future<void>> submitted_;  // consensus_cpu_submit's
   Logger logger_;
   // Declared last: destroyed first, so an exception-abandoned task queue
   // drains (and its tasks' member references stay valid) before any other

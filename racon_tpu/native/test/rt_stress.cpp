@@ -15,6 +15,11 @@
 //                            (the device/host consensus hand-off; one
 //                            external consensus caller only — that thread
 //                            owns the shared aligner slot n)
+//   6b. overlapped fallback — the consensus driver's interleaving: one
+//                            external thread hands rejected windows to
+//                            pool workers (consensus_cpu_submit) and
+//                            meanwhile exports, parity-checks and
+//                            installs other windows, then joins once
 //
 // Build + run:  make -C racon_tpu/native stress   (or tsan/asan/ubsan)
 #include <sys/stat.h>
@@ -323,6 +328,91 @@ static void stress_consensus_handoff() {
   CHECK_EQ(out[0].second, truth);
 }
 
+// ---- 6b. overlapped host fallback -------------------------------------------
+// What ops/poa_driver.py does since the fallback left the critical path:
+// the driver thread submits device-rejected windows to the pool
+// (consensus_cpu_submit: each worker owns its aligner slot) and goes on,
+// on *other* windows, reading them for export (the fields
+// rt_pipeline_window_export reads), running the sanitizer's sampled parity
+// consensus (the one external consensus_cpu_one caller, slot n) and
+// installing device results (set_consensus); one join at the end.
+static uint64_t export_like(const rt::Window& w) {
+  uint64_t sum = w.rank + w.id;
+  for (size_t k = 0; k < w.sequences.size(); ++k) {
+    const uint32_t len = w.sequences[k].second;
+    for (uint32_t p = 0; p < len; ++p) {
+      sum += static_cast<uint8_t>(w.sequences[k].first[p]);
+      if (w.qualities[k].first != nullptr) {
+        sum += static_cast<uint8_t>(w.qualities[k].first[p]);
+      }
+    }
+    sum += w.positions[k].first + w.positions[k].second;
+  }
+  return sum;
+}
+
+static void stress_overlapped_fallback() {
+  const int kLen = 12000;
+  const std::string truth = make_truth(kLen);
+  const std::string draft = make_draft(truth);
+
+  std::string reads, sam = "@HD\tVN:1.6\n@SQ\tSN:tgt\tLN:" +
+                           std::to_string(kLen) + "\n";
+  for (int i = 0; i < 5; ++i) {
+    const std::string rn = "r" + std::to_string(i);
+    reads += ">" + rn + "\n" + truth + "\n";
+    sam += rn + "\t0\ttgt\t1\t60\t" + std::to_string(kLen) + "M\t*\t0\t0\t" +
+           truth + "\t*\n";
+  }
+  const std::string reads_p = write_file("ovf_reads.fasta", reads);
+  const std::string sam_p = write_file("ovf_ovl.sam", sam);
+  const std::string tgt_p = write_file("ovf_tgt.fasta", ">tgt\n" + draft + "\n");
+
+  for (uint32_t threads : {1u, 4u}) {
+    rt::PipelineParams params;
+    params.window_length = 200;
+    params.match = 5;
+    params.mismatch = -4;
+    params.gap = -8;
+    params.num_threads = threads;
+    rt::Pipeline pipe(reads_p, sam_p, tgt_p, params);
+    pipe.initialize();
+    const size_t n = pipe.num_windows();
+    CHECK_EQ(n, static_cast<size_t>(kLen / 200));
+    CHECK_EQ(pipe.consensus_cpu_join(), 0u);  // nothing submitted: no wait
+
+    size_t submitted = 0, finished = 0;
+    uint64_t exported = 0;
+    std::thread driver([&] {
+      for (size_t i = 0; i < n; ++i) {
+        if (i % 3 == 1) {  // "device-rejected": to a pool worker, at once
+          pipe.consensus_cpu_submit(i);
+          ++submitted;
+          continue;
+        }
+        exported += export_like(pipe.window(i));
+        if (i % 6 == 0) {  // sampled parity, then the device result lands
+          CHECK(pipe.consensus_cpu_one(i));
+        }
+        pipe.set_consensus(i, truth.substr(i * 200, 200), true);
+      }
+      finished = pipe.consensus_cpu_join();
+    });
+    driver.join();
+    CHECK(exported != 0);
+    CHECK_EQ(submitted, n / 3);
+    CHECK(finished <= submitted);
+    for (size_t i = 0; i < n; ++i) {
+      CHECK(pipe.has_consensus(i));
+      CHECK(pipe.is_polished(i));
+    }
+    std::vector<std::pair<std::string, std::string>> out;
+    pipe.stitch(true, &out);
+    CHECK_EQ(out.size(), 1u);
+    CHECK_EQ(out[0].second, truth);
+  }
+}
+
 int main() {
   g_tmpdir = "/tmp/rt_stress_" + std::to_string(::getpid());
   ::mkdir(g_tmpdir.c_str(), 0755);
@@ -332,6 +422,7 @@ int main() {
   stress_pool_churn();
   stress_cigar_install();
   stress_consensus_handoff();
+  stress_overlapped_fallback();
   if (g_failures.load()) {
     std::fprintf(stderr, "%d/%d stress checks FAILED (artifacts in %s)\n",
                  g_failures.load(), g_checks.load(), g_tmpdir.c_str());

@@ -191,7 +191,13 @@ def run_consensus_phase(pipeline, *, match: int, mismatch: int, gap: int,
     chunk N+1 overlaps device execution of chunk N through JAX async
     dispatch — the analogue of the reference's greedy batch fill running
     concurrently with kernel execution
-    (/root/reference/src/cuda/cudapolisher.cpp:83-145).
+    (/root/reference/src/cuda/cudapolisher.cpp:83-145).  A window the
+    device path gives up on (kernel `failed` flag, export error, too few
+    admissible layers, surrender, quarantine) goes to the native thread
+    pool the moment it is found (_HostFallback) and is redone there while
+    the chip runs the next batches; the driver joins the pool once, after
+    the last install, and only then writes the host windows' journal
+    records and stats, in arrival order.
 
     Returns stats {device:…, host_fallback:…, backbone:…, failed:…,
     layers_dropped:…, report: PhaseReport} — the report's per-tier served
@@ -203,6 +209,17 @@ def run_consensus_phase(pipeline, *, match: int, mismatch: int, gap: int,
     appended as it is installed, so a crash loses at most the in-flight
     batch.
     """
+    fallback = _HostFallback(pipeline)
+    try:
+        return _consensus_phase(pipeline, fallback, match, mismatch, gap,
+                                trim, progress, journal)
+    except BaseException:  # noqa: BLE001 — re-raised: drain, not handle
+        fallback.drain()    # no pool worker outlives a failing phase
+        raise
+
+
+def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
+                     progress, journal) -> dict:
     n = pipeline.num_windows()
     report = PhaseReport("consensus",
                          rl.CONSENSUS_TIERS + ("backbone", "journal"))
@@ -214,8 +231,6 @@ def run_consensus_phase(pipeline, *, match: int, mismatch: int, gap: int,
     stats = _sanitize().guard_stats(stats, "poa_driver.run_consensus_phase")
 
     replayed = replay_windows(pipeline, journal, n, report)
-
-    fallback: List[int] = []
 
     # Metadata pass: geometry + depth buckets, no layer bytes touched.
     jobs = []          # (window_idx, estimated depth, backbone len)
@@ -348,13 +363,7 @@ def run_consensus_phase(pipeline, *, match: int, mismatch: int, gap: int,
 
     t0 = time.perf_counter()
     with obs.span("poa.host_fallback", windows=len(fallback)):
-        for i in fallback:
-            polished = pipeline.consensus_cpu_one(i)
-            if journal is not None:
-                _, _, rank, _, _, tid = pipeline.window_info(i)
-                journal.append_window(i, tid, rank, "host",
-                                      pipeline.get_consensus(i), polished)
-            stats["host_fallback"] += 1
+        fallback.join(journal, stats)
     obs.count("native.calls.consensus_cpu_one", len(fallback))
     if journal is not None:
         obs.count("native.calls.window_info", len(fallback))
@@ -366,6 +375,62 @@ def run_consensus_phase(pipeline, *, match: int, mismatch: int, gap: int,
     # datasets
     report.extra["layers_dropped_maxlen"] = stats["layers_dropped"]
     return stats
+
+
+class _HostFallback:
+    """The windows the device path gave up on, redone on the host while
+    the chip runs on.  The five producers see a list (`append`, `extend`);
+    each append hands the window to the *native* thread pool at once
+    (Pipeline.consensus_cpu_submit: a pool worker owns its aligner slot,
+    whereas every outside thread shares one, so Python threads calling
+    consensus_cpu_one would corrupt each other) and remembers the order
+    of arrival.  `join` waits for the pool once, then writes what the
+    serial loop wrote per window, on the calling thread and in that
+    order: the journal's "host" record and stats["host_fallback"].  An
+    empty fallback makes no native call."""
+
+    def __init__(self, pipeline):
+        self._pipeline = pipeline
+        self._order: List[int] = []
+
+    def __len__(self) -> int:
+        return len(self._order)
+
+    def append(self, i: int) -> None:
+        self._pipeline.consensus_cpu_submit(i)
+        self._order.append(i)
+
+    def extend(self, idxs) -> None:
+        for i in idxs:
+            self.append(i)
+
+    def join(self, journal, stats) -> List[int]:
+        """Wait for every submitted window (a failed one raises here),
+        write their records; returns the windows in arrival order."""
+        order, pipeline = self._order, self._pipeline
+        if not order:
+            return order
+        polished, hidden = pipeline.consensus_cpu_join(order)
+        # how often the overlap engaged: host work that had ended before
+        # the driver came to wait for it, and the rest
+        obs.count("poa.fallback.hidden", hidden)
+        obs.count("poa.fallback.exposed", len(order) - hidden)
+        for i, was_polished in zip(order, polished):
+            if journal is not None:
+                _, _, rank, _, _, tid = pipeline.window_info(i)
+                journal.append_window(i, tid, rank, "host",
+                                      pipeline.get_consensus(i),
+                                      was_polished)
+            stats["host_fallback"] += 1
+        return order
+
+    def drain(self) -> None:
+        """The phase is failing: wait the pool out, write nothing."""
+        if self._order:
+            try:
+                self._pipeline.consensus_cpu_join(())
+            except Exception:  # noqa: BLE001 — the phase's own error wins
+                pass
 
 
 def observed_window_lengths(draft_path: str, w: int) -> set:

@@ -176,6 +176,27 @@ class Pipeline:
             raise native.NativeError(f"consensus failed for window {i}")
         return bool(r)
 
+    def consensus_cpu_submit(self, i: int) -> None:
+        """Hand window i's host consensus to a native pool worker and
+        return at once (each worker owns its aligner slot; this thread
+        may go on exporting and installing *other* windows)."""
+        faults.check("native.call", (i,))
+        self._lib.rt_pipeline_consensus_cpu_submit(self._h, i)
+        native.check_error(self._lib)
+
+    def consensus_cpu_join(self, windows) -> Tuple[List[bool], int]:
+        """Wait for every submitted window.  Returns (polished flag per
+        window of `windows`, how many had already finished when the wait
+        began); a window that failed raises here, after all have ended."""
+        idx = (ctypes.c_uint64 * len(windows))(*windows)
+        polished = (ctypes.c_uint8 * len(windows))()
+        done = self._lib.rt_pipeline_consensus_cpu_join(
+            self._h, idx, len(windows), polished)
+        if done < 0:
+            native.check_error(self._lib)
+            raise native.NativeError("host consensus failed")
+        return [bool(p) for p in polished], int(done)
+
     def consensus_cpu_all(self) -> None:
         faults.check("native.call")
         with obs.span("native.consensus_cpu_all"):

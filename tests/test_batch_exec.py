@@ -14,10 +14,12 @@ import sys
 
 import numpy as np
 
+import racon_tpu
+
 from racon_tpu.ops.batch_exec import BatchExecutor, pipeline_depth
 from racon_tpu.resilience.report import PhaseReport
 
-from test_faults import (_assert_report_sums, _oracle, _tpu_run,
+from test_faults import (_ARGS, _assert_report_sums, _oracle, _tpu_run,
                          _write_dataset)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -265,6 +267,49 @@ def test_consensus_driver_full_lattice_chain(tmp_path, monkeypatch):
     # the executor stamped the feeder's wall split
     assert cons["extra"]["kernel_wall_s"] > 0
     assert cons["extra"]["pack_wall_s"] > 0
+
+
+def test_surrender_and_quarantine_share_the_overlapped_fallback(
+        tmp_path, monkeypatch):
+    """A quarantined window (bisection), a surrendered exported chunk
+    (tier death) and surrendered unexported chunks (the geometry already
+    at the host floor) all reach the host through the one fallback
+    object: each is handed to the native pool on arrival, none is
+    computed by the driver thread, and the journal's host records follow
+    the order of arrival."""
+    from racon_tpu.ops import poa_driver
+    from racon_tpu.pipeline import Pipeline
+    from test_host_fallback import spy
+
+    paths = _write_dataset(tmp_path, n_targets=16)      # 32 windows of 100
+    oracle = _oracle(paths)
+    arrived, external, surrenders = [], [], []
+    spy(monkeypatch, poa_driver._HostFallback, "append", arrived)
+    spy(monkeypatch, Pipeline, "consensus_cpu_one", external)
+    spy(monkeypatch, poa_driver._ConsensusOps, "surrender", surrenders,
+        key=lambda ctx, items, exported: (len(items), exported))
+    for k, v in {"RACON_TPU_PALLAS": "0", "RACON_TPU_POA_KERNEL": "v2",
+                 "RACON_TPU_BATCH_WINDOWS": "8",
+                 # window 2 poisons chunk 0 alone: bisected, quarantined;
+                 # 9 and 13 sit in opposite halves of chunk 1: tier dead
+                 "RACON_TPU_FAULT": ("poa.run.xla:window=2,"
+                                     "poa.run.xla:window=9,"
+                                     "poa.run.xla:window=13")}.items():
+        monkeypatch.setenv(k, v)
+    jp = str(tmp_path / "run.journal")
+    p = racon_tpu.create_polisher(*paths, backend="tpu", journal_path=jp,
+                                  **_ARGS)
+    p.initialize()
+    assert p.polish(True) == oracle
+    cons = _assert_report_sums(p)["phases"]["consensus"]
+    assert cons["quarantined"] == [2]
+    assert cons["served"]["host"] == 25 and cons["served"]["xla"] == 7
+    assert (8, True) in surrenders and (8, False) in surrenders
+    assert sorted(arrived) == [2] + list(range(8, 32)) and arrived[0] == 2
+    assert not external                 # the driver thread computed none
+    with open(jp) as f:
+        records = [json.loads(line) for line in f.read().splitlines()[1:]]
+    assert [r["i"] for r in records if r["tier"] == "host"] == arrived
 
 
 def test_xla_align_driver_through_executor(tmp_path, monkeypatch):
