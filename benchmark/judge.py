@@ -42,13 +42,30 @@ def is_a_count(metric: dict) -> bool:
 BAD_EVENTS = ("lattice.demote", "lattice.quarantine", "watchdog.timeout")
 
 
+#: completed window jobs ``correct`` needs: the median of fewer is one
+#: job's wall, and one job shows nothing of the loop between two
+LEAST_JOBS = 2
+
+
+def median_says_fits(elapsed_s: float, walls: list, seconds: float) -> bool:
+    """The running median job wall says another job ends inside the
+    window."""
+    return elapsed_s + statistics.median(walls) <= seconds
+
+
 def next_job_fits(elapsed_s: float, walls: list, seconds: float) -> bool:
-    """The stop rule of the closed loop: the first job always starts;
+    """The stop rule of the closed loop.  The first job always starts.
+    While fewer than ``LEAST_JOBS`` are done, the next starts if the one
+    before it ended inside the window: the open window is the evidence,
+    not a median of one sample, which would count a stall in the first
+    job twice (once in ``elapsed_s``, once as the forecast).  After that
     another starts only while the running median job wall says it ends
     inside the window."""
     if not walls:
         return True
-    return elapsed_s + statistics.median(walls) <= seconds
+    if len(walls) < LEAST_JOBS:
+        return elapsed_s < seconds
+    return median_says_fits(elapsed_s, walls, seconds)
 
 
 def served_units(phases: dict) -> tuple:
@@ -110,15 +127,22 @@ def err_removed_vs_host(draft: int, host: int, device: int):
     return 100.0 * (draft - device) / (draft - host)
 
 
+def accuracy_limits(draft: int, host: int, truth_bp: int) -> tuple:
+    """(the most edits the device path may leave, the count it has to
+    stay below) to the truth."""
+    slack = max(DEVICE_VS_HOST_MARGIN * host,
+                DEVICE_VS_HOST_PER_BP * truth_bp)
+    return host + slack, POLISH_MAX_SHARE_OF_DRAFT * draft
+
+
 def accuracy_problems(draft: int, host: int, device: int,
                       truth_bp: int) -> list:
     bad = []
-    slack = max(DEVICE_VS_HOST_MARGIN * host,
-                DEVICE_VS_HOST_PER_BP * truth_bp)
-    if device > host + slack:
+    at_most, below = accuracy_limits(draft, host, truth_bp)
+    if device > at_most:
         bad.append(f"device-polished edit distance {device} is more than "
-                   f"{slack:.0f} above the host's {host}")
-    if device >= POLISH_MAX_SHARE_OF_DRAFT * draft:
+                   f"{at_most - host:.0f} above the host's {host}")
+    if device >= below:
         bad.append(f"device-polished edit distance {device} is not below "
                    f"a quarter of the draft's {draft}")
     return bad

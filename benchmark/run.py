@@ -9,12 +9,16 @@ device, the native library built on this machine, the cell's data from
 ``--seed``, the host oracle, a ``PolishSession`` warmed for the cell's
 window lengths, the cell's job once as the warm-up job.  Window: the
 same job back to back through ``PolishSession.run_job`` (closed loop, one
-client, fresh job id each time) while the running median job wall says
-the next one ends inside ``--seconds``.  After the window, outside the
-timing: bytes compared, edit distances to the truth, and with
-``--trace 1`` the profiler trace reduced and the per-layer readers run.
+client, fresh job id each time) while ``judge.next_job_fits`` says so:
+the job that makes ``judge.LEAST_JOBS`` starts whenever the one before it
+ended inside ``--seconds``, any further one while the running median job
+wall says it ends inside.  After the window, outside the timing: bytes
+compared, edit distances to the truth, and with ``--trace 1`` the
+profiler trace reduced and the per-layer readers run.
 
-The last line of stdout is the contract's JSON object.  Without a TPU
+The last line of stdout is the contract's JSON object; the last lines of
+stderr are each number ``correct`` compared beside its limit and every
+problem found.  Without a TPU
 (or with another chip count than the cell's) it exits non-zero and
 prints no result; ``JAX_PLATFORMS=cpu`` by name is the toy-size
 rehearsal, which says ``"platform": "cpu"`` and writes no timing.
@@ -112,19 +116,23 @@ def job_summary(job: dict) -> dict:
     out = {k: job[k] for k in (
         "id", "wall_s", "polished_bp", "kernel_builds", "cache_misses",
         "cache_requests", "events", "counters")}
-    out["done_s"] = job.get("done_s")      # none for the warm-up job
+    for key in ("done_s", "started_by_least"):   # none for the warm-up job
+        out[key] = job.get(key)
     out["phases"] = {p: {k: ph.get(k) for k in ("total", "served", "wall_s",
                                                  "extra")}
                      for p, ph in job["phases"].items()}
-    out["span_s"] = {n: sum(d for _, d in items) / 1e9
-                     for n, items in sorted(job["spans"].items())}
+    spans = sorted(job["spans"].items())
+    out["span_s"] = {n: sum(d for _, d in items) / 1e9 for n, items in spans}
+    out["span_n"] = {n: len(items) for n, items in spans}
     return out
 
 
 def measure_window(run_job, seconds: float, trace_dir, n_traced: int):
     """The closed loop.  Returns (jobs, errors of jobs that raised);
     each job carries ``done_s``, its completion in seconds from the
-    window's start.  With
+    window's start, and ``started_by_least``: whether only the rule for
+    the first ``judge.LEAST_JOBS`` jobs started it, where the running
+    median said it would end after the window.  With
     ``n_traced`` the profiler runs around the first that many jobs, each
     inside the benchmark's own ``bench.job`` annotation."""
     import jax
@@ -144,8 +152,13 @@ def measure_window(run_job, seconds: float, trace_dir, n_traced: int):
     jobs, raised = [], []
     t_win = time.monotonic()
     try:
-        while judge.next_job_fits(time.monotonic() - t_win,
-                                  [j["wall_s"] for j in jobs], seconds):
+        while True:
+            elapsed, walls = (time.monotonic() - t_win,
+                              [j["wall_s"] for j in jobs])
+            if not judge.next_job_fits(elapsed, walls, seconds):
+                break
+            by_least = bool(walls) and not judge.median_says_fits(
+                elapsed, walls, seconds)
             job_id = f"w{len(jobs) + len(raised):04d}"
             annotate = (jax.profiler.TraceAnnotation("bench.job", job=job_id)
                         if tracing else contextlib.nullcontext())
@@ -161,6 +174,7 @@ def measure_window(run_job, seconds: float, trace_dir, n_traced: int):
                     break
                 continue
             job["done_s"] = time.monotonic() - t_win
+            job["started_by_least"] = by_least
             job["mono_ns_at_annotation"] = mono if tracing else None
             jobs.append(job)
             if tracing and len(jobs) >= n_traced:
@@ -191,10 +205,38 @@ def verdict(cell, first: dict, jobs: list, raised: list, dev: dict,
             problems += [f"{job['id']}: {b}" for b in bad]
     problems += [f"warmup: {b}" for b in judge.report_problems(
         first["report"], expect, **healthy)]
-    if len(jobs) < 2:
+    if len(jobs) < judge.LEAST_JOBS:
         problems.append(f"{len(jobs)} job(s) completed in the window; "
-                        "fewer than two")
+                        f"fewer than {judge.LEAST_JOBS}")
     return problems, failed
+
+
+def window_facts(jobs: list) -> dict:
+    """Facts of the window for the printed record, not metrics: when the
+    last job completed (a job started inside the window may end after
+    it) and how many jobs only the ``judge.LEAST_JOBS`` rule started: 0
+    in a healthy run, 1 after a stall in the job before."""
+    return {"window_end_s": jobs[-1]["done_s"] if jobs else None,
+            "jobs_started_by_least": sum(j["started_by_least"]
+                                         for j in jobs)}
+
+
+def compared(jobs: list, failed: int, edits: dict, truth_bp: int,
+             problems: list) -> list:
+    """Each number ``correct`` compared beside its limit, then every
+    problem found: the run's last lines on stderr, which is what the
+    driver keeps of a run that is not correct."""
+    from benchmark import judge
+
+    at_most, below = judge.accuracy_limits(edits["draft"], edits["host"],
+                                           truth_bp)
+    return [f"jobs completed in the window: {len(jobs)} (at least "
+            f"{judge.LEAST_JOBS}); " + json.dumps(window_facts(jobs)),
+            f"jobs failed: {failed} (limit 0)",
+            f"device edit distance: {edits['device']} (at most "
+            f"{at_most:.0f} beside the host's {edits['host']}; below "
+            f"{below:.0f}, a quarter of the draft's {edits['draft']})"
+            ] + [f"PROBLEM {p}" for p in problems]
 
 
 def read_trace(trace_dir: str, jobs: list):
@@ -347,7 +389,7 @@ def main(argv=None) -> int:
         "peak_bytes_in_use", 0)) for d in jax.local_devices())
     facts["hbm_peak_gb"] = memory_peak / 1e9
     line = {"correct": True, "attempted": len(jobs) + len(raised),
-            "failed": failed, "metrics": {},
+            **window_facts(jobs), "failed": failed, "metrics": {},
             "device": {"platform": dev["platform"],
                        "kind": dev["device_kind"], "count": dev["count"],
                        "memory_peak_bytes": memory_peak}}
@@ -415,6 +457,8 @@ def main(argv=None) -> int:
     for name, note in run["notes"].items():
         say(f"{name}: {json.dumps(note)}")
     say(f"{tag}detail: {os.path.join(out_dir, run_tag + '.json')}")
+    for text in compared(jobs, failed, edits, len(truth), problems):
+        print(f"benchmark: {tag}{text}", file=sys.stderr)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -39,14 +39,88 @@ def report(phases, **counters):
             "obs": {"metrics": {"counters": counters}}}
 
 
-def test_stop_rule():
-    assert judge.next_job_fits(0.0, [], 51)            # first always starts
-    assert judge.next_job_fits(200.0, [], 51)
-    assert judge.next_job_fits(28.0, [14.0, 14.2], 51)  # ends at 42.1
-    assert not judge.next_job_fits(42.3, [14.0, 14.2, 14.1], 51)
-    assert judge.next_job_fits(36.9, [14.0, 14.2, 14.1], 51)
+@pytest.mark.parametrize("elapsed, walls, seconds, expected", [
+    (0.0, [], 51, True),                     # the first always starts
+    (200.0, [], 51, True),
+    (28.0, [14.0, 14.2], 51, True),          # ends at 42.1
+    (42.3, [14.0, 14.2, 14.1], 51, False),
+    (36.9, [14.0, 14.2, 14.1], 51, True),
     # the median, not the mean: one slow job does not end the window
-    assert judge.next_job_fits(30.0, [14.0, 14.0, 40.0], 51)
+    (30.0, [14.0, 14.0, 40.0], 51, True),
+    # one job done: the job that makes two starts while the window is
+    # open, however long the first took (2 x 25.6 > 51 refused PR 24 on
+    # the parent's side; 26.9 s was PR 23's stalled SAM job) ...
+    (25.6, [25.6], 51, True),
+    (26.9, [26.9], 51, True),
+    (22.4, [22.4], 40, True),
+    # ... and never once the window has closed
+    (51.2, [51.2], 51, False),
+    (22.4, [22.4], 20, False),
+    # two done: back on the running median (40.4 + 20.2 > 51)
+    (40.4, [26.9, 13.5], 51, False),
+])
+def test_stop_rule(elapsed, walls, seconds, expected):
+    assert judge.next_job_fits(elapsed, walls, seconds) is expected
+
+
+class SteppedClock:
+    """Stands in for ``time`` in ``benchmark/run.py``: the fake job moves
+    it, nothing else does."""
+
+    def __init__(self):
+        self.now = 1000.0
+
+    def monotonic(self):
+        return self.now
+
+    def monotonic_ns(self):
+        return int(self.now * 1e9)
+
+
+@pytest.mark.parametrize("walls, seconds, done, by_least", [
+    ([27.0, 22.0], 51, [27.0, 49.0], 1),     # a 4.6 s stall in job 1
+    ([22.4, 22.4], 51, [22.4, 44.8], 0),     # the healthy PAF window
+    ([22.4, 22.4], 40, [22.4, 44.8], 1),     # the chip demonstration
+    ([22.4], 20, [22.4], 0),                 # job 1 ends after the window
+    ([27.0, 13.5, 13.5], 51, [27.0, 40.5], 1),   # PR 23's SAM stall
+    ([13.5, 13.5, 13.5, 13.5], 51, [13.5, 27.0, 40.5], 0),
+])
+def test_the_window_holds_two_jobs_after_a_stalled_first(
+        monkeypatch, walls, seconds, done, by_least):
+    from benchmark import run
+
+    clock = SteppedClock()
+    monkeypatch.setattr(run, "time", clock)
+    monkeypatch.setattr(run, "say", lambda msg: None)
+    left = list(walls)
+
+    def fake_job(job_id):
+        wall = left.pop(0)
+        clock.now += wall
+        return {"id": job_id, "wall_s": wall, "events": [],
+                "report": report(SAM_PHASES), "fasta": b">c\nACGT\n"}
+
+    jobs, raised = run.measure_window(fake_job, seconds, None, 0)
+    assert raised == []
+    assert [j["done_s"] for j in jobs] == pytest.approx(done)
+    facts = run.window_facts(jobs)
+    assert facts == {"window_end_s": pytest.approx(done[-1]),
+                     "jobs_started_by_least": by_least}
+
+    class Cell:
+        chips = 1
+        workload = {"expect": EXPECT_SAM}
+
+    first = {"report": report(SAM_PHASES), "fasta": b">c\nACGT\n"}
+    problems, failed = run.verdict(Cell, first, jobs, raised,
+                                   {"platform": "tpu"}, False)
+    assert failed == 0
+    assert any("fewer than" in p for p in problems) == (len(done) < 2)
+    # an answer altered where it is produced is not correct
+    jobs[-1]["fasta"] = b">c\nACGA\n"
+    problems, failed = run.verdict(Cell, first, jobs, raised,
+                                   {"platform": "tpu"}, False)
+    assert failed == 1 and any("bytes" in p for p in problems)
 
 
 def test_device_served_share_is_the_smokes_figure():
