@@ -102,6 +102,9 @@ def load() -> ctypes.CDLL:
         fn.restype = None
         fn.argtypes = [ctypes.c_void_p]
 
+    lib.rt_pipeline_prepare_counts.restype = None
+    lib.rt_pipeline_prepare_counts.argtypes = [ctypes.c_void_p, u64p]
+
     lib.rt_pipeline_num_align_jobs.restype = ctypes.c_uint64
     lib.rt_pipeline_num_align_jobs.argtypes = [ctypes.c_void_p]
 

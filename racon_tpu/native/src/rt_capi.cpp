@@ -143,6 +143,15 @@ void rt_pipeline_prepare(void* handle) {
       [&] { static_cast<PipelineHandle*>(handle)->pipeline->prepare(); });
 }
 
+// out[0..2] = targets, overlap records parsed, overlaps kept by the
+// filters: what prepare() saw, in one crossing.
+void rt_pipeline_prepare_counts(void* handle, uint64_t* out) {
+  const Pipeline& p = *static_cast<PipelineHandle*>(handle)->pipeline;
+  out[0] = p.num_targets();
+  out[1] = p.overlaps_parsed();
+  out[2] = p.overlaps_kept();
+}
+
 uint64_t rt_pipeline_num_align_jobs(void* handle) {
   return static_cast<PipelineHandle*>(handle)->pipeline->num_align_jobs();
 }

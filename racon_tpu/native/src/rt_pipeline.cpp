@@ -180,6 +180,7 @@ void Pipeline::prepare() {
     if (chunk.empty()) {
       break;
     }
+    overlaps_parsed_ += chunk.size();
     for (auto& o : chunk) {
       o->transmute(sequences_, name_to_id, id_to_id);
       if (!o->is_valid) {
@@ -214,6 +215,7 @@ void Pipeline::prepare() {
     }
     overlaps_.swap(kept);
   }
+  overlaps_kept_ = overlaps_.size();
 
   if (overlaps_.empty()) {
     rt::fail("[racon_tpu::Pipeline::initialize] error: empty overlap "

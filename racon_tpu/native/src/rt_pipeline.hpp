@@ -60,6 +60,13 @@ class Pipeline {
   // alignment. Parity: src/polisher.cpp:200-382.
   void prepare();
 
+  // What prepare() saw, for the drivers' counters: targets, overlap
+  // records parsed, overlaps left after the filters (error threshold,
+  // self overlaps, and in kC all but the longest per query).
+  uint64_t num_targets() const { return targets_size_; }
+  uint64_t overlaps_parsed() const { return overlaps_parsed_; }
+  uint64_t overlaps_kept() const { return overlaps_kept_; }
+
   // Overlaps still lacking a CIGAR (alignment jobs for the device).
   size_t num_align_jobs() const { return align_jobs_.size(); }
   void align_job_views(size_t job, const char** q, uint32_t* q_len,
@@ -118,6 +125,7 @@ class Pipeline {
   std::string dummy_quality_;
 
   std::vector<std::unique_ptr<Overlap>> overlaps_;
+  uint64_t overlaps_parsed_ = 0, overlaps_kept_ = 0;
   std::vector<size_t> align_jobs_;  // overlap indices lacking a CIGAR
 
   std::vector<std::shared_ptr<Window>> windows_;

@@ -994,6 +994,15 @@ def run_jobs(pipeline, jobs, cohort: int = None, report=None,
                            []).append(job)
     if band_states:
         obs.count("band.jobs", len(band_states))
+    # how the job's pairs fill the cohorts: a bucket's last cohort is
+    # partial, so many thin buckets mean many launches of few pairs
+    cohorts = sum(-(-len(items) // cohort) for items in buckets.values())
+    obs.count("align.buckets", len(buckets))
+    obs.count("align.cohorts", cohorts)
+    obs.count("align.cohorts.partial",
+              sum(len(items) % cohort > 0 for items in buckets.values()))
+    obs.count("align.cohorts.pairs", len(dims))
+    obs.count("align.cohorts.capacity", cohort * cohorts)
 
     state = {"served": 0}
     ops_obj = _HirschbergOps(pipeline, dims, report, stats, state)

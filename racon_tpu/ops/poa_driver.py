@@ -306,6 +306,11 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
             _ConsensusOps(pipeline, B, trim, stats, fallback, report,
                           journal, dead_geoms),
             report=report)
+        # windows in a class under the job's largest: the targets' tails
+        # (one per contig; one per read in fragment correction)
+        nominal = max(c for _, c in buckets)
+        obs.count("poa.windows.tail", sum(
+            len(b) for (_, c), b in buckets.items() if c < nominal))
         for (depth_bucket, wl_class), bucket_jobs in sorted(buckets.items()):
             obs.count(f"poa.windows.d{depth_bucket}.c{wl_class}",
                       len(bucket_jobs))

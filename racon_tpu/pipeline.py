@@ -68,6 +68,20 @@ class Pipeline:
         with obs.span("native.prepare"):
             self._lib.rt_pipeline_prepare(self._h)
             native.check_error(self._lib)
+        self._count_prepared()
+
+    def _prepare_counts(self) -> Tuple[int, int, int]:
+        """(targets, overlap records parsed, overlaps the filters kept):
+        what the native prepare saw, in one ABI crossing."""
+        out = (ctypes.c_uint64 * 3)()
+        self._lib.rt_pipeline_prepare_counts(self._h, out)
+        return int(out[0]), int(out[1]), int(out[2])
+
+    def _count_prepared(self) -> None:
+        targets, parsed, kept = self._prepare_counts()
+        obs.count("polish.targets", targets)
+        obs.count("overlaps.parsed", parsed)
+        obs.count("overlaps.kept", kept)
 
     def num_align_jobs(self) -> int:
         return self._lib.rt_pipeline_num_align_jobs(self._h)
@@ -132,6 +146,7 @@ class Pipeline:
         with obs.span("native.initialize"):
             self._lib.rt_pipeline_initialize(self._h)
             native.check_error(self._lib)
+        self._count_prepared()
 
     # -- phase 2 ----------------------------------------------------------
     def num_windows(self) -> int:
@@ -218,6 +233,8 @@ class Pipeline:
             n = self._lib.rt_pipeline_stitch(
                 self._h, 1 if drop_unpolished else 0)
             native.check_error(self._lib)
+        # targets the stitch left out: no window of theirs was polished
+        obs.count("polish.targets.dropped", self._prepare_counts()[0] - n)
         out = []
         ln = ctypes.c_uint64()
         for i in range(n):

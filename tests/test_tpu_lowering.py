@@ -39,6 +39,7 @@ from racon_tpu.parallel import reset_partitioner
 TPU_BATCH = 64          # poa_driver._batch_size() on a TPU
 SHARD_BATCH = 16        # the same batch over a four-chip host
 SCORES = (5, -4, -8)
+UNIT_SCORES = (1, -1, -1)   # upstream's fragment scenarios (-m 1 -x -1 -g -1)
 
 
 def _export_tpu(fn, args):
@@ -88,10 +89,10 @@ def _poa_args(cfg, B, band=False):
     return args + (np.zeros(B, np.int32),) if band else args
 
 
-def _ls(window_length, depth, B, band=False):
+def _ls(window_length, depth, B, band=False, scores=SCORES):
     from racon_tpu.ops.poa_pallas_ls import build_lockstep_poa_kernel
 
-    cfg = poa_driver.make_config(window_length, depth, *SCORES)
+    cfg = poa_driver.make_config(window_length, depth, *scores)
     # the VMEM-fit model must agree: a geometry it approves has to build
     assert poa_driver._fits_vmem(cfg, "ls"), "fit model rejects geometry"
     fn = build_lockstep_poa_kernel(cfg, interpret=False, band=band)(B)
@@ -130,14 +131,21 @@ def _base(K, B):
 
 # -- lowering --------------------------------------------------------------
 
-@pytest.mark.parametrize("window_length,depth,B", [
-    (100, 8, 8),             # small-window datasets, one grid program
-    (1000, 8, 8),            # the paf_w1000 golden scenario
-    (500, 8, SHARD_BATCH), (500, 32, SHARD_BATCH), (500, 200, SHARD_BATCH),
-    (500, 8, TPU_BATCH), (500, 32, TPU_BATCH), (500, 200, TPU_BATCH),
+@pytest.mark.parametrize("window_length,depth,B,scores", [
+    (100, 8, 8, SCORES),     # small-window datasets, one grid program
+    (1000, 8, 8, SCORES),    # the paf_w1000 golden scenario
+    (500, 8, SHARD_BATCH, SCORES), (500, 32, SHARD_BATCH, SCORES),
+    (500, 200, SHARD_BATCH, SCORES),
+    (500, 8, TPU_BATCH, SCORES), (500, 32, TPU_BATCH, SCORES),
+    (500, 200, TPU_BATCH, SCORES),
+] + [
+    # fragment correction (ecoli-frag.paf): every read ends in a tail
+    # window, so the classes under the nominal 512 fill at every depth
+    (wl_class, depth, TPU_BATCH, UNIT_SCORES)
+    for wl_class in (128, 256, 384) for depth in poa_driver.DEPTH_BUCKETS
 ])
-def test_lockstep_poa_kernel_lowers_to_tpu(window_length, depth, B):
-    _export_tpu(*_ls(window_length, depth, B))
+def test_lockstep_poa_kernel_lowers_to_tpu(window_length, depth, B, scores):
+    _export_tpu(*_ls(window_length, depth, B, scores=scores))
 
 
 def test_banded_lockstep_poa_kernel_lowers_past_one_program():
@@ -159,13 +167,17 @@ def test_v2_poa_kernel_lowers_to_tpu():
     _export_tpu(*_v2(2))
 
 
-@pytest.mark.parametrize("rcap,K", [(512, 256), (8192, 1024)])
+@pytest.mark.parametrize("rcap,K", [
+    (512, 256), (8192, 1024),
+    # read-vs-read overlaps of 0.5-6 kb (ecoli-frag.paf): the low buckets
+    (1024, 256), (2048, 256), (2048, 512),
+])
 def test_hirschberg_edge_kernels_lower_to_tpu(single_device, rcap, K):
     for backward in (False, True):
         _export_tpu(*_edge(rcap, K, backward, 2))
 
 
-@pytest.mark.parametrize("K", [256, 1024])
+@pytest.mark.parametrize("K", [256, 512, 1024])
 def test_hirschberg_base_kernel_lowers_to_tpu(single_device, K):
     _export_tpu(*_base(K, 2))
 
