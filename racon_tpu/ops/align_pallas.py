@@ -17,6 +17,19 @@ divide-and-conquer (Hirschberg) trick:
   * base-case kernel: subproblems of <= BASE_ROWS rows run the full
     moves-matrix DP in VMEM with in-kernel traceback, emitting op codes.
 
+Layout: a grid program runs GROUP = 8 tasks in lock-step, task g in
+sublane g of every (8, w) tile — the lane-lockstep layout of
+poa_pallas_ls.py — so a DP row's ops (one rotation of the staged target,
+log2(band) prefix-min steps) serve eight tasks for the price of one; one
+task per program left seven of the eight sublanes of every vreg empty.
+One program has one loop counter and one rotation amount, so whatever
+differs per task is folded into the data the host stages
+(_task_arrays), per-task scalars are (8, 1) columns broadcast along
+lanes, and the loop runs to the program's largest row count while
+shorter tasks carry their row through.  The host orders a launch's
+tasks by row count before it cuts them into programs; pad slots have no
+rows.  A task's result does not depend on which tasks share its program.
+
 Mosaic constraints honored throughout (no scalar VMEM stores — masked row
 RMW; no dynamic-lane scalar loads — masked reductions; 3-D per-program
 blocks; i32 everywhere).
@@ -109,89 +122,140 @@ def _pack_factor() -> int:
     return PACK if config.get_bool("RACON_TPU_ALIGN_PACK") else 1
 
 
+GROUP = 8                # tasks per grid program, one per sublane of the
+                         # int32 vreg tile
+MOVE_ROWS = 4            # base kernel: DP rows whose moves share a word
+
+
+def _row_ops(K, TCAP, scal_ref, t_ref):
+    """What both kernels build their DP rows from, on (GROUP, K) tiles:
+    the lane iota, the per-task scalars R, S, dmin as (GROUP, 1) columns
+    (broadcast along lanes), a left rotation by one traced amount for
+    all sublanes, the suffix min along lanes, and the forward DP row."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    lane_k = jax.lax.broadcasted_iota(jnp.int32, (GROUP, K), 1)
+    R = scal_ref[0, :, 0:1]
+    S = scal_ref[0, :, 1:2]
+    dmin = scal_ref[0, :, 2:3]
+
+    def lroll(x, amt, width):
+        # left-rotate every sublane by one traced amount in [0, width];
+        # pltpu.roll only accepts non-negative shifts
+        return pltpu.roll(x, jnp.mod(width - amt, width), 1)
+
+    def cummin_fwd(x):
+        # prefix min along lanes (left-to-right)
+        k = 1
+        while k < K:
+            sh = jnp.where(lane_k >= k, pltpu.roll(x, k, 1), INF)
+            x = jnp.minimum(x, sh)
+            k *= 2
+        return x
+
+    def cummin_bwd(x):
+        # suffix min along lanes (right-to-left)
+        k = 1
+        while k < K:
+            sh = jnp.where(lane_k < K - k, pltpu.roll(x, K - k, 1), INF)
+            x = jnp.minimum(x, sh)
+            k *= 2
+        return x
+
+    def fwd_row(k, qc, row):
+        """DP row i = k + 1 = 1..R from row i - 1 (j' = i + dmin + o)
+        and the moves that made it: 0 diagonal, 1 up, 2 left."""
+        i = k + 1
+        jv = i + dmin + lane_k
+        # target chars at j'-1 per lane: the host staged
+        # ts[x] = t[x + dmin], so lane o wants ts[k + o]
+        tc = lroll(t_ref[0], k, TCAP)[:, :K]
+        sub = row + jnp.where(tc == qc, 0, 1)
+        up = jnp.where(lane_k < K - 1, pltpu.roll(row, K - 1, 1),
+                       INF) + 1
+        V = jnp.minimum(sub, up)
+        mv = jnp.where(V == sub, 0, 1)
+        V = jnp.where(jv == 0, i, V)
+        mv = jnp.where(jv == 0, 1, mv)
+        V = jnp.where((jv < 0) | (jv > S), INF, V)
+        nrow = cummin_fwd(V - lane_k) + lane_k
+        mv = jnp.where(nrow < V, 2, mv)
+        nrow = jnp.minimum(nrow, INF)
+        nrow = jnp.where((jv < 0) | (jv > S), INF, nrow)
+        return nrow, mv
+
+    return lane_k, R, S, dmin, lroll, cummin_bwd, fwd_row
+
+
+def _group_rows(b, arrays):
+    """Inside a jitted kernel wrapper: pad `b` task rows up to whole
+    programs of GROUP with idle rows (all zero: R = 0) and fold them to
+    (programs, GROUP, w) tiles.  The host hands whole programs already;
+    a mesh shard of fewer than GROUP rows is one program with idle
+    sublanes, so the partitioner's gate needs no word about GROUP."""
+    nb = -(-b // GROUP)
+    return nb, [jnp.pad(a, ((0, nb * GROUP - b), (0, 0)))
+                .reshape(nb, GROUP, a.shape[1]) for a in arrays]
+
+
+def _group_scalars(nb, scal, rows_per_step):
+    """(programs, GROUP, 4) task scalars -> the kernels' two views: the
+    loop's trip count per program (its largest R in steps of
+    `rows_per_step`; an idle row has R = 0 and never sets it) for SMEM,
+    and the scalars as a (GROUP, 128) VMEM tile to cut columns from."""
+    rmax = jnp.max(scal[:, :, 0], axis=1)
+    trips = (rmax + rows_per_step - 1) // rows_per_step
+    return trips.reshape(nb, 1, 1), jnp.pad(scal, ((0, 0), (0, 0), (0, 124)))
+
+
 @device_keyed_cache(maxsize=64)
 def _build_edge_kernel(rcap: int, K: int, backward: bool,
                        interpret: bool = False, pack: int = 1):
     """Batched banded DP over up to `rcap` rows; returns the last row.
 
-    Per task (one grid program): query slice q (rcap), target slice t
-    (rcap + K), scalars R (rows), S (target span), dmin (local band
-    offset). Lane o of a row holds cell (i, j = i + dmin + o); the
-    backward kernel mirrors the recurrence (B[i][o] from B[i+1][o],
-    B[i+1][o-1]... expressed with opposite shifts).
+    GROUP (8) tasks per grid program run in lock-step, task g in sublane
+    g of every (8, w) tile, so each row op — the target rotation, the
+    log2(K) prefix-min steps — serves eight tasks for the price of one
+    (the layout of poa_pallas_ls.py; one task per program left seven of
+    the eight sublanes of each vreg empty).  Per task: query slice q,
+    target slice t (rcap + K), scalars R (rows), S (target span), dmin
+    (local band offset).  Lane o of a row holds cell (i, j = i + dmin +
+    o); the backward kernel mirrors the recurrence (B[i][o] from
+    B[i+1][o], B[i+1][o-1]... expressed with opposite shifts).
+
+    One program has one loop counter and one rotation amount, so what
+    differs per task lives in the data (_task_arrays): the staged target
+    is pre-shifted by each task's dmin (and R, backward) so that step k
+    rotates every sublane by the same k, and the backward query arrives
+    reversed so that step k reads word/code k in every sublane.  The
+    loop runs to the group's largest R; a task past its own R carries
+    its row through unchanged, so a task's result does not depend on
+    which tasks share its program.
 
     pack > 1: the query arrives packed `pack` codes per int32 word
-    (encoding.pack_bases; REVERSED for the backward kernel so the word
-    index ascends with the loop) and each serial iteration retires
-    `pack` DP rows off one scalar word read — the fori_loop trip count
-    drops from R to ceil(R / pack).  Rows past R carry the row value
-    through unchanged, so the result is byte-identical to pack == 1.
+    (encoding.pack_bases) and each serial iteration retires `pack` DP
+    rows off one word-column read — the fori_loop trip count drops from
+    R to ceil(R / pack), byte-identical to pack == 1.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    G = GROUP
     TCAP = rcap + K
     QIN = rcap if pack == 1 else max(128, _round_up(rcap // pack, 128))
     name = f"racon_hirschberg_edge_{'bwd' if backward else 'fwd'}"
 
-    def kernel(scal_ref, q_ref, t_ref, out_ref, row_scr, tq_scr):
-        lane_k = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
-        R = scal_ref[0, 0, 0]
-        S = scal_ref[0, 0, 1]
-        dmin = scal_ref[0, 0, 2]
+    def kernel(trip_ref, scal_ref, q_ref, t_ref, out_ref):
+        lane_k, R, S, dmin, lroll, cummin_bwd, fwd_row = _row_ops(
+            K, TCAP, scal_ref, t_ref)
 
-        QW = q_ref.shape[-1]
-
-        def lroll(x, amt, width):
-            # left-rotate by a (possibly negative) traced amount;
-            # pltpu.roll only accepts non-negative shifts
-            return pltpu.roll(x, jnp.mod(width - amt, width), 1)
-
-        def qchar(i):
-            # q char at index i: rotate the lane row and read lane 0
-            # (static extracts are allowed; dynamic-lane loads are not)
-            return lroll(q_ref[0], i, QW)[0, 0]
-
-        def cummin_fwd(x):
-            # prefix min along lanes (left-to-right)
-            k = 1
-            while k < K:
-                sh = jnp.where(lane_k >= k, pltpu.roll(x, k, 1), INF)
-                x = jnp.minimum(x, sh)
-                k *= 2
-            return x
-
-        def cummin_bwd(x):
-            # suffix min along lanes (right-to-left)
-            k = 1
-            while k < K:
-                sh = jnp.where(lane_k < K - k, pltpu.roll(x, K - k, 1),
-                               INF)
-                x = jnp.minimum(x, sh)
-                k *= 2
-            return x
-
-        def fwd_step(i, qc, row):
-            # i = 1..R ; j' = i + dmin + o
+        def bwd_step(k, qc, row):
+            # row i = R - 1 - k = R-1 .. 0, per task
+            i = R - 1 - k
             jv = i + dmin + lane_k
-            # target chars at j'-1 per lane: t[(i-1) + dmin + o],
-            # staged via a dynamic lane rotation of the target row
-            tc = lroll(tq_scr[:], i - 1 + dmin, TCAP)[:, :K]
-            sub = row + jnp.where(tc == qc, 0, 1)
-            up = jnp.where(lane_k < K - 1, pltpu.roll(row, K - 1, 1),
-                           INF) + 1
-            V = jnp.minimum(sub, up)
-            V = jnp.where(jv == 0, i, V)
-            V = jnp.where((jv < 0) | (jv > S), INF, V)
-            gv = lane_k
-            nrow = cummin_fwd(V - gv) + gv
-            nrow = jnp.minimum(nrow, INF)
-            nrow = jnp.where((jv < 0) | (jv > S), INF, nrow)
-            return nrow
-
-        def bwd_step(i, qc, row):
-            jv = i + dmin + lane_k
-            tc = lroll(tq_scr[:], i + dmin, TCAP)[:, :K]  # t[j']
+            # t[j'] per lane: the host staged ts[z] = t[z - rcap + R - 1
+            # + dmin], so lane o wants ts[rcap - k + o]
+            tc = lroll(t_ref[0], rcap - k, TCAP)[:, :K]
             # B[i][o]: diag = B[i+1][o] + sub(q[i], t[j']);
             # down (consume query) = B[i+1][o-1] + 1;
             # right (consume target) = B[i][o+1] + 1 (suffix chain)
@@ -199,7 +263,7 @@ def _build_edge_kernel(rcap: int, K: int, backward: bool,
             down = jnp.where(lane_k >= 1, pltpu.roll(row, 1, 1),
                              INF) + 1
             V = jnp.minimum(sub, down)
-            V = jnp.where(jv == S, R - i, V)
+            V = jnp.where(jv == S, k + 1, V)          # R - i
             V = jnp.where((jv < 0) | (jv > S), INF, V)
             gv = K - 1 - lane_k
             nrow = cummin_bwd(V - gv) + gv
@@ -207,83 +271,46 @@ def _build_edge_kernel(rcap: int, K: int, backward: bool,
             nrow = jnp.where((jv < 0) | (jv > S), INF, nrow)
             return nrow
 
-        if not backward:
-            # row 0: F[0][j'] = j' for j' in [0, S]
-            j0 = dmin + lane_k
-            row = jnp.where((j0 >= 0) & (j0 <= S), j0, INF)
-            tq_scr[:] = t_ref[0]
-            if pack == 1:
-                row = jax.lax.fori_loop(
-                    1, R + 1, lambda i, row: fwd_step(i, qchar(i - 1), row),
-                    row)
-            else:
-                # one packed-word scalar read feeds `pack` rows; rows
-                # past R carry `row` through unchanged (byte-identity)
-                def body(it, row):
-                    qword = lroll(q_ref[0], it, QW)[0, 0]
-                    for p in range(pack):
-                        i = it * pack + 1 + p
-                        qc = (qword >> (8 * p)) & 0xFF
-                        row = jnp.where(i <= R, fwd_step(i, qc, row), row)
-                    return row
+        step = bwd_step if backward else \
+            (lambda k, qc, row: fwd_row(k, qc, row)[0])
+        # row 0: F[0][j'] = j' ; row R: B[R][j'] = S - j' ; j' in [0, S]
+        j0 = (R if backward else 0) + dmin + lane_k
+        row = jnp.where((j0 >= 0) & (j0 <= S),
+                        S - j0 if backward else j0, INF)
 
-                row = jax.lax.fori_loop(0, (R + pack - 1) // pack, body,
-                                        row)
-        else:
-            # row R: B[R][j'] = S - j'
-            jR = R + dmin + lane_k
-            row = jnp.where((jR >= 0) & (jR <= S), S - jR, INF)
-            tq_scr[:] = t_ref[0]
-            if pack == 1:
-                def body1(k, row):
-                    i = R - 1 - k          # i = R-1 .. 0
-                    return bwd_step(i, qchar(i), row)
+        def body(it, row):
+            # one word-column read feeds `pack` rows; a task past its
+            # own R carries `row` through unchanged
+            qword = lroll(q_ref[0], it, QIN)[:, 0:1]
+            for p in range(pack):
+                k = it * pack + p
+                qc = qword if pack == 1 else (qword >> (8 * p)) & 0xFF
+                row = jnp.where(k < R, step(k, qc, row), row)
+            return row
 
-                row = jax.lax.fori_loop(0, R, body1, row)
-            else:
-                # the host packed the REVERSED query slice, so word it /
-                # byte p holds q[R - 1 - (it*pack + p)] — the word index
-                # ascends with the serial loop
-                def body(it, row):
-                    qword = lroll(q_ref[0], it, QW)[0, 0]
-                    for p in range(pack):
-                        k = it * pack + p
-                        i = R - 1 - k
-                        qc = (qword >> (8 * p)) & 0xFF
-                        row = jnp.where(k < R, bwd_step(i, qc, row), row)
-                    return row
+        out_ref[0] = jax.lax.fori_loop(0, trip_ref[0, 0, 0], body, row)
 
-                row = jax.lax.fori_loop(0, (R + pack - 1) // pack, body,
-                                        row)
-
-        out_ref[0] = row
-
-    def make(batch):
-        smem3 = pl.BlockSpec((1, 1, 4), lambda b: (b, 0, 0),
+    def make(nb):
+        smem1 = pl.BlockSpec((1, 1, 1), lambda b: (b, 0, 0),
                              memory_space=pltpu.SMEM)
-        vrow = lambda w: pl.BlockSpec((1, 1, w), lambda b: (b, 0, 0),
-                                      memory_space=pltpu.VMEM)
+        vtile = lambda w: pl.BlockSpec((1, G, w), lambda b: (b, 0, 0),
+                                       memory_space=pltpu.VMEM)
         return pl.pallas_call(
             kernel,
-            grid=(batch,),
-            in_specs=[smem3, vrow(QIN), vrow(TCAP)],
-            out_specs=vrow(K),
-            out_shape=jax.ShapeDtypeStruct((batch, 1, K), jnp.int32),
-            scratch_shapes=[pltpu.VMEM((1, K), jnp.int32),
-                            pltpu.VMEM((1, TCAP), jnp.int32)],
+            grid=(nb,),
+            in_specs=[smem1, vtile(128), vtile(QIN), vtile(TCAP)],
+            out_specs=vtile(K),
+            out_shape=jax.ShapeDtypeStruct((nb, G, K), jnp.int32),
             interpret=interpret,
             name=name,
         )
 
     def plain(b):
-        call = make(b)
-
         @named(name)
         def fn(scal, q, t):
-            out = call(scal.reshape(b, 1, 4),
-                       q.reshape(b, 1, QIN),
-                       t.reshape(b, 1, TCAP))
-            return out.reshape(b, K)
+            nb, (scal, q, t) = _group_rows(b, (scal, q, t))
+            out = make(nb)(*_group_scalars(nb, scal, pack), q, t)
+            return out.reshape(nb * G, K)[:b]
 
         return fn
 
@@ -301,155 +328,143 @@ def _build_edge_kernel(rcap: int, K: int, backward: bool,
 
 @device_keyed_cache(maxsize=32)
 def _build_base_kernel(K: int, interpret: bool = False, pack: int = 1):
+    """Full moves-matrix DP over up to BASE_ROWS rows with the traceback
+    in the kernel; returns op codes, their count, ok and the terminal
+    distance per task.
+
+    The forward DP is the edge kernel's: GROUP tasks per grid program in
+    lock-step, task g in sublane g, inputs staged by _task_arrays.  Each
+    loop iteration retires MOVE_ROWS rows and stores their moves as one
+    word tile, a byte per row, so the move matrix of eight tasks is
+    BASE_ROWS / MOVE_ROWS tiles of (GROUP, K): 0.5-4 MB of VMEM at K
+    256-2048.  The traceback is a serial walk of data-dependent length,
+    so it runs one task after another, reading sublane g of that matrix.
+    """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    G, U = GROUP, MOVE_ROWS
     RB = BASE_ROWS
     TCAP = RB + K
     OPS = _round_up(RB + K + 2, 128)
-    # pack > 1: packed query words (encoding.pack_bases), `pack` DP rows
-    # per serial iteration — same contract as _build_edge_kernel
+    # pack > 1: packed query words (encoding.pack_bases) — same contract
+    # as _build_edge_kernel; a word never straddles two iterations
+    assert U % pack == 0, (U, pack)
     QCAP = _round_up(RB, 128) if pack == 1 else \
         max(128, _round_up(RB // pack, 128))
 
-    def kernel(scal_ref, q_ref, t_ref, ops_ref, cnt_ref, ok_ref,
-               dist_ref, MVS, tq_scr):
-        lane_k = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
+    def kernel(trip_ref, scal_s, scal_ref, q_ref, t_ref, ops_ref, cnt_ref,
+               ok_ref, dist_ref, MVS, fin_scr):
+        lane_k, R, S, dmin, lroll, _, fwd_row = _row_ops(
+            K, TCAP, scal_ref, t_ref)
+        lane_1 = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
         lane_ops = jax.lax.broadcasted_iota(jnp.int32, (1, OPS), 1)
-        R = scal_ref[0, 0, 0]
-        S = scal_ref[0, 0, 1]
-        dmin = scal_ref[0, 0, 2]
 
         def load_lane(rowvec, iota, idx):
             return jnp.sum(jnp.where(iota == idx, rowvec,
                                      jnp.zeros_like(rowvec)))
 
-        def cummin_fwd(x):
-            k = 1
-            while k < K:
-                sh = jnp.where(lane_k >= k, pltpu.roll(x, k, 1), INF)
-                x = jnp.minimum(x, sh)
-                k *= 2
-            return x
+        def body(it, row):
+            words = {}
+            moves = jnp.zeros((G, K), jnp.int32)
+            for p in range(U):
+                k = it * U + p
+                w = p // pack
+                if w not in words:
+                    words[w] = lroll(q_ref[0], it * (U // pack) + w,
+                                     QCAP)[:, 0:1]
+                qc = words[w] if pack == 1 else \
+                    (words[w] >> (8 * (p % pack))) & 0xFF
+                nrow, mv = fwd_row(k, qc, row)
+                moves = moves | (mv << (8 * p))
+                # a task past its own R carries `row` through; its moves
+                # there are never read (the walk starts at row R)
+                row = jnp.where(k < R, nrow, row)
+            MVS[pl.ds(pl.multiple_of(it * G, G), G), :] = moves
+            return row
 
-        tq_scr[:] = t_ref[0]
         j0 = dmin + lane_k
         row0 = jnp.where((j0 >= 0) & (j0 <= S), j0, INF)
+        fin_scr[:] = jax.lax.fori_loop(0, trip_ref[0, 0, 0], body, row0)
 
-        def dp_row(i, qc, row):
-            jv = i + dmin + lane_k
-            tc = pltpu.roll(tq_scr[:], jnp.mod(TCAP - (i - 1 + dmin), TCAP),
-                            1)[:, :K]
-            sub = row + jnp.where(tc == qc, 0, 1)
-            up = jnp.where(lane_k < K - 1, pltpu.roll(row, K - 1, 1),
-                           INF) + 1
-            V = jnp.minimum(sub, up)
-            mv = jnp.where(V == sub, 0, 1)
-            V = jnp.where(jv == 0, i, V)
-            mv = jnp.where(jv == 0, 1, mv)
-            V = jnp.where((jv < 0) | (jv > S), INF, V)
-            nrow = cummin_fwd(V - lane_k) + lane_k
-            mv = jnp.where(nrow < V, 2, mv)
-            nrow = jnp.where((jv < 0) | (jv > S), INF, nrow)
-            return nrow, mv
+        def walk(g, carry):
+            Rg = scal_s[0, g, 0]
+            Sg = scal_s[0, g, 1]
+            dg = scal_s[0, g, 2]
+            # terminal distance D = DP[R][S]: lane o with R + dmin + o == S
+            # (INF when the terminal cell is out of band).  Free with the
+            # final row already live — it is the banded mode's exact
+            # Ukkonen-verify input (ops/band.py) for base-case-only pairs.
+            o_fin = Sg - Rg - dg
+            d_at = load_lane(fin_scr[pl.ds(g, 1), :], lane_1,
+                             jnp.clip(o_fin, 0, K - 1))
+            dist_ref[0, 0, g] = jnp.where((o_fin >= 0) & (o_fin < K),
+                                          d_at, INF)
 
-        QW = q_ref.shape[-1]
-        if pack == 1:
-            def body(i, row):
-                qc = pltpu.roll(q_ref[0], jnp.mod(QW - (i - 1), QW),
-                                1)[0, 0]
-                nrow, mv = dp_row(i, qc, row)
-                MVS[pl.ds(i - 1, 1), :] = mv
-                return nrow
+            # traceback from (R, S) to (0, 0); ops: 0=M 1=I(query)
+            # 2=D(target)
+            def cond(c):
+                i, j, cnt, ok = c
+                return ((i > 0) | (j > 0)) & (cnt < OPS) & ok
 
-            row_fin = jax.lax.fori_loop(1, R + 1, body, row0)
-        else:
-            def body(it, row):
-                qword = pltpu.roll(q_ref[0], jnp.mod(QW - it, QW),
-                                   1)[0, 0]
-                for p in range(pack):
-                    i = it * pack + 1 + p
-                    qc = (qword >> (8 * p)) & 0xFF
-                    nrow, mv = dp_row(i, qc, row)
+            def bodytb(c):
+                i, j, cnt, ok = c
+                o = j - i - dg
+                in_band = (o >= 0) & (o < K)
+                r = jnp.maximum(i - 1, 0)
+                mvrow = MVS[pl.ds(r // U * G + g, 1), :]
+                mv_at = (load_lane(mvrow, lane_1, jnp.clip(o, 0, K - 1))
+                         >> (8 * (r % U))) & 0xFF
+                mv = jnp.where(i > 0, jnp.where(in_band, mv_at, 3), 2)
+                ok = ok & (mv != 3)
+                ops_ref[0, pl.ds(g, 1), :] = jnp.where(
+                    lane_ops == cnt, mv, ops_ref[0, pl.ds(g, 1), :])
+                i = jnp.where(mv == 2, i, i - 1)
+                j = jnp.where(mv == 1, j, j - 1)
+                return (i, j, cnt + 1, ok)
 
-                    @pl.when(i <= R)
-                    def _():
-                        MVS[pl.ds(i - 1, 1), :] = mv
+            ops_ref[0, pl.ds(g, 1), :] = jnp.zeros((1, OPS), jnp.int32)
+            i, j, cnt, ok = jax.lax.while_loop(
+                cond, bodytb, (Rg, Sg, jnp.int32(0), jnp.bool_(True)))
+            ok = ok & (i == 0) & (j == 0)
+            cnt_ref[0, 0, g] = cnt
+            ok_ref[0, 0, g] = ok.astype(jnp.int32)
+            return carry
 
-                    row = jnp.where(i <= R, nrow, row)
-                return row
+        jax.lax.fori_loop(0, G, walk, 0)
 
-            row_fin = jax.lax.fori_loop(0, (R + pack - 1) // pack, body,
-                                        row0)
-
-        # terminal distance D = DP[R][S]: lane o with R + dmin + o == S
-        # (INF when the terminal cell is out of band).  Free with the
-        # final row already live — it is the banded mode's exact
-        # Ukkonen-verify input (ops/band.py) for base-case-only pairs.
-        o_fin = S - R - dmin
-        d_at = load_lane(row_fin, lane_k, jnp.clip(o_fin, 0, K - 1))
-        dist_ref[0, 0, 0] = jnp.where((o_fin >= 0) & (o_fin < K),
-                                      d_at, INF)
-
-        # traceback from (R, S) to (0, 0); ops: 0=M 1=I(query) 2=D(target)
-        def cond(c):
-            i, j, cnt, ok = c
-            return ((i > 0) | (j > 0)) & (cnt < OPS) & ok
-
-        def bodytb(c):
-            i, j, cnt, ok = c
-            o = j - i - dmin
-            in_band = (o >= 0) & (o < K)
-            mvrow = MVS[pl.ds(jnp.maximum(i - 1, 0), 1), :]
-            mv_at = load_lane(mvrow, lane_k, jnp.clip(o, 0, K - 1))
-            mv = jnp.where(i > 0, jnp.where(in_band, mv_at, 3), 2)
-            ok = ok & (mv != 3)
-            ops_ref[0] = jnp.where(lane_ops == cnt, mv, ops_ref[0])
-            i = jnp.where(mv == 2, i, i - 1)
-            j = jnp.where(mv == 1, j, j - 1)
-            return (i, j, cnt + 1, ok)
-
-        ops_ref[0] = jnp.zeros((1, OPS), jnp.int32)
-        i, j, cnt, ok = jax.lax.while_loop(
-            cond, bodytb, (R, S, jnp.int32(0), jnp.bool_(True)))
-        ok = ok & (i == 0) & (j == 0)
-        cnt_ref[0, 0, 0] = cnt
-        ok_ref[0, 0, 0] = ok.astype(jnp.int32)
-
-    def make(batch):
-        smem3 = pl.BlockSpec((1, 1, 4), lambda b: (b, 0, 0),
-                             memory_space=pltpu.SMEM)
+    def make(nb):
         smem1 = pl.BlockSpec((1, 1, 1), lambda b: (b, 0, 0),
                              memory_space=pltpu.SMEM)
-        vrow = lambda w: pl.BlockSpec((1, 1, w), lambda b: (b, 0, 0),
-                                      memory_space=pltpu.VMEM)
+        # per-task scalars ride a unit middle dim, as in poa_pallas_ls
+        smemg = pl.BlockSpec((1, 1, G), lambda b: (b, 0, 0),
+                             memory_space=pltpu.SMEM)
+        smem4 = pl.BlockSpec((1, G, 4), lambda b: (b, 0, 0),
+                             memory_space=pltpu.SMEM)
+        vtile = lambda w: pl.BlockSpec((1, G, w), lambda b: (b, 0, 0),
+                                       memory_space=pltpu.VMEM)
+        gshape = jax.ShapeDtypeStruct((nb, 1, G), jnp.int32)
         return pl.pallas_call(
             kernel,
-            grid=(batch,),
-            in_specs=[smem3, vrow(QCAP), vrow(TCAP)],
-            out_specs=[vrow(OPS), smem1, smem1, smem1],
-            out_shape=[
-                jax.ShapeDtypeStruct((batch, 1, OPS), jnp.int32),
-                jax.ShapeDtypeStruct((batch, 1, 1), jnp.int32),
-                jax.ShapeDtypeStruct((batch, 1, 1), jnp.int32),
-                jax.ShapeDtypeStruct((batch, 1, 1), jnp.int32),
-            ],
-            scratch_shapes=[pltpu.VMEM((RB, K), jnp.int32),
-                            pltpu.VMEM((1, TCAP), jnp.int32)],
+            grid=(nb,),
+            in_specs=[smem1, smem4, vtile(128), vtile(QCAP), vtile(TCAP)],
+            out_specs=[vtile(OPS), smemg, smemg, smemg],
+            out_shape=[jax.ShapeDtypeStruct((nb, G, OPS), jnp.int32),
+                       gshape, gshape, gshape],
+            scratch_shapes=[pltpu.VMEM((RB // U * G, K), jnp.int32),
+                            pltpu.VMEM((G, K), jnp.int32)],
             interpret=interpret,
             name="racon_hirschberg_base",
         )
 
     def plain(b):
-        call = make(b)
-
         @named("racon_hirschberg_base")
         def fn(scal, q, t):
-            ops, cnt, ok, dist = call(scal.reshape(b, 1, 4),
-                                      q.reshape(b, 1, QCAP),
-                                      t.reshape(b, 1, TCAP))
-            return (ops.reshape(b, OPS), cnt.reshape(b), ok.reshape(b),
-                    dist.reshape(b))
+            nb, (scal, q, t) = _group_rows(b, (scal, q, t))
+            trips, cols = _group_scalars(nb, scal, U)
+            ops, cnt, ok, dist = make(nb)(trips, scal, cols, q, t)
+            return (ops.reshape(nb * G, OPS)[:b], cnt.reshape(-1)[:b],
+                    ok.reshape(-1)[:b], dist.reshape(-1)[:b])
 
         return fn
 
@@ -554,21 +569,29 @@ def _pow2(n):
     return b
 
 
-def _task_arrays(pairs, tasks, bands, rcap, K, backward, pack=1):
-    """Pack tasks into kernel arrays. The staged target window is clipped
-    to the half's band-reachable columns (j <= ib + gdmin + K going
-    forward, j >= ia + gdmin going backward) so it fits rcap + K — the
-    full task span can be up to 2*rcap + K.
+def _task_arrays(pairs, slots, bands, rcap, K, backward, pack=1):
+    """Pack one launch's slots (a task, or None for a pad row) into the
+    edge kernel's arrays.  The staged target window is clipped to the
+    half's band-reachable columns (j <= ib + gdmin + K going forward,
+    j >= ia + gdmin going backward) so it fits rcap + K — the full task
+    span can be up to 2*rcap + K.
 
-    pack > 1: queries go out as packed words (the backward kernel's
-    query slice reversed first, so its word index ascends with the
-    serial loop)."""
-    B = len(tasks)
+    The kernel rotates all GROUP tasks of a program by one amount per
+    step, so each row is staged pre-shifted by what differs per task:
+    forward ts[x] = t[j_lo + x + dmin], backward ts[z] = t[j_lo + z -
+    rcap + R - 1 + dmin] (255 outside the window; those cells are out of
+    [0, S] and masked).  The backward query goes out reversed so that
+    step k reads q[R - 1 - k] at index k in every task; pack > 1 packs
+    the codes into words.  A pad row has R = 0: it costs its program
+    nothing and never sets a group's trip count."""
+    B = len(slots)
     TCAP = rcap + K
     scal = np.zeros((B, 4), np.int32)
     qs = np.zeros((B, rcap), np.int32)
     ts = np.full((B, TCAP), 255, np.int32)
-    for bi, t in enumerate(tasks):
+    for bi, t in enumerate(slots):
+        if t is None:
+            continue
         q, tt = pairs[t.pair]
         _, gdmin = bands[t.pair]
         R = t.ib - t.ia
@@ -580,13 +603,31 @@ def _task_arrays(pairs, tasks, bands, rcap, K, backward, pack=1):
             j_hi = min(t.jb, t.ib + gdmin + K)
         S = j_hi - j_lo
         assert 0 <= S <= TCAP, (S, TCAP)
-        scal[bi] = (R, S, gdmin + t.ia - j_lo, 0)
+        dmin = gdmin + t.ia - j_lo
+        scal[bi] = (R, S, dmin, 0)
         qrow = q[t.ia:t.ib]
-        qs[bi, :R] = qrow[::-1] if (pack > 1 and backward) else qrow
-        ts[bi, :S] = tt[j_lo:j_hi]
+        qs[bi, :R] = qrow[::-1] if backward else qrow
+        shift = dmin + (R - 1 - rcap if backward else 0)
+        lo, hi = max(0, -shift), min(TCAP, S - shift)
+        if hi > lo:
+            ts[bi, lo:hi] = tt[j_lo + lo + shift:j_lo + hi + shift]
     if pack > 1:
         qs = pack_bases(qs, width=max(128, _round_up(rcap // pack, 128)))
     return scal, qs, ts
+
+
+def _deal_programs(tasks, B):
+    """The `B` slots of one launch: `tasks` (ordered by R, so a
+    program's GROUP tasks are of a length) then pad slots (None; R = 0,
+    they ride in the last program).  Over a mesh every shard takes
+    B / shards consecutive slots, so the ordered programs are dealt
+    round the shards: no device gets all the long ones."""
+    slots = tasks + [None] * (B - len(tasks))
+    shards = _dispatch_shards(B)
+    if shards > 1:
+        deal = np.arange(B).reshape(-1, shards, min(GROUP, B // shards))
+        slots = [slots[i] for i in deal.transpose(1, 0, 2).ravel()]
+    return slots
 
 
 def _launch(kernel, call, args, n_real, **geom):
@@ -595,8 +636,23 @@ def _launch(kernel, call, args, n_real, **geom):
     futures (on a program's first use it traces, lowers and loads, which
     shows as ``jit.*`` spans inside), ``align.wait`` the blocking copy
     of the results back.  `n_real` of the batch's rows are tasks, the
-    rest pads it to a power of two."""
+    rest pads it to a power of two.
+
+    Counted here, once per launch: over a mesh the rows per device
+    (count_shard_rows), and how well the lock-step programs engage —
+    ``align.lockstep.rows.real`` the DP rows the tasks asked for,
+    ``.slots`` the sublane-rows their programs ran (GROUP x each
+    program's largest R)."""
     B = len(args[0])
+    shards = _dispatch_shards(B)
+    if shards > 1:
+        from .batch_exec import count_shard_rows
+
+        count_shard_rows(n_real, B, shards)
+    rows = args[0][:, 0].reshape(-1, min(GROUP, B // shards))
+    obs.count("align.lockstep.rows.real", int(rows.sum()))
+    obs.count("align.lockstep.rows.slots",
+              GROUP * int(rows.max(axis=1).sum()))
     with obs.span("align.dispatch", cat="launch", kernel=kernel, B=B,
                   **geom):
         outs = call(B)(*args)
@@ -627,38 +683,32 @@ def _split_round(pairs, tasks, bands, failed, interpret, verify=None,
     for (rcap, K), group in sorted(by_bucket.items()):
         fwd = _build_edge_kernel(rcap, K, False, interpret, pk)
         bwd = _build_edge_kernel(rcap, K, True, interpret, pk)
-        # pad the batch dim to a power of two so each (rcap, K) bucket
-        # compiles a handful of kernel variants, not one per group size
-        B = _pow2(len(group))
+        # pad the batch dim to a power of two (at least one program of
+        # GROUP tasks) so each (rcap, K) bucket compiles a handful of
+        # kernel variants, not one per group size
+        B = max(GROUP, _pow2(len(group)))
         geom = dict(rcap=rcap, K=K)
         with obs.span("align.pack", cat="launch", kernel="edge", B=B,
                       **geom):
+            # a program's eight tasks run to its largest R: order the
+            # launch by R before it is cut into programs (pad rows, R = 0,
+            # ride in the last one)
+            group.sort(key=lambda t: t.ib - t.ia)
+            slots = _deal_programs(group, B)
             # forward over [ia, imid], backward over [imid, ib]
-            f_tasks, b_tasks = [], []
-            for t in group:
-                imid = (t.ia + t.ib) // 2
-                f_tasks.append(_Task(t.pair, t.ia, imid, t.ja, t.jb))
-                b_tasks.append(_Task(t.pair, imid, t.ib, t.ja, t.jb))
-            pad = lambda a: np.concatenate(
-                [a, np.repeat(a[-1:], B - len(group), axis=0)]) \
-                if B > len(group) else a
-            f_args = [pad(a) for a in _task_arrays(
-                pairs, f_tasks, bands, rcap, K, False, pk)]
-            b_args = [pad(a) for a in _task_arrays(
-                pairs, b_tasks, bands, rcap, K, True, pk)]
-        m = _dispatch_shards(B)
-        if m > 1:
-            from .batch_exec import count_shard_rows
-
-            count_shard_rows(len(group), B, m)  # forward launch
-            count_shard_rows(len(group), B, m)  # backward launch
-        F = _launch("edge_fwd", fwd, f_args, len(group),
-                    **geom)[:len(group)]
-        Bv = _launch("edge_bwd", bwd, b_args, len(group),
-                     **geom)[:len(group)]
+            f_tasks = [t and _Task(t.pair, t.ia, (t.ia + t.ib) // 2,
+                                   t.ja, t.jb) for t in slots]
+            b_tasks = [t and _Task(t.pair, (t.ia + t.ib) // 2, t.ib,
+                                   t.ja, t.jb) for t in slots]
+            f_args = _task_arrays(pairs, f_tasks, bands, rcap, K, False, pk)
+            b_args = _task_arrays(pairs, b_tasks, bands, rcap, K, True, pk)
+        F = _launch("edge_fwd", fwd, f_args, len(group), **geom)
+        Bv = _launch("edge_bwd", bwd, b_args, len(group), **geom)
         with obs.span("align.select", cat="launch", tasks=len(group),
                       **geom):
-            for gi, t in enumerate(group):
+            for gi, t in enumerate(slots):
+                if t is None:
+                    continue
                 imid = (t.ia + t.ib) // 2
                 K_, gdmin = bands[t.pair]
                 # Both midpoint rows map lane o to absolute column
@@ -701,39 +751,24 @@ def _solve_base(pairs, tasks, bands, segments, failed, interpret,
         by_bucket.setdefault(K, []).append(t)
     pk = _pack_factor()
     for K, group in sorted(by_bucket.items()):
-        kern, OPS, QCAP, TCAP = _build_base_kernel(K, interpret, pk)
+        kern, _, _, _ = _build_base_kernel(K, interpret, pk)
+        group.sort(key=lambda t: t.ib - t.ia)   # like rows share a program
         for off in range(0, len(group), 64):
             chunk = group[off:off + 64]
-            B = _pow2(len(chunk))
-            m = _dispatch_shards(B)
-            if m > 1:
-                from .batch_exec import count_shard_rows
-
-                count_shard_rows(len(chunk), B, m)
+            B = max(GROUP, _pow2(len(chunk)))
             geom = dict(rcap=BASE_ROWS, K=K)
             with obs.span("align.pack", cat="launch", kernel="base", B=B,
                           **geom):
-                scal = np.zeros((B, 4), np.int32)
-                qraw = np.zeros((B, BASE_ROWS), np.int32)
-                ts = np.full((B, TCAP), 255, np.int32)
-                for bi, t in enumerate(chunk):
-                    q, tt = pairs[t.pair]
-                    _, gdmin = bands[t.pair]
-                    R, S = t.ib - t.ia, t.jb - t.ja
-                    scal[bi] = (R, S, gdmin + t.ia - t.ja, 0)
-                    qraw[bi, :R] = q[t.ia:t.ib]
-                    ts[bi, :S] = tt[t.ja:t.jb]
-                scal[len(chunk):, 0] = 1  # pad tasks: 1 empty-target row
-                if pk > 1:
-                    qs = pack_bases(qraw, width=QCAP)
-                else:
-                    # QCAP == _round_up(BASE_ROWS, 128) == BASE_ROWS here
-                    qs = qraw
-            ops, cnt, ok, dist = _launch("base", kern, (scal, qs, ts),
-                                         len(chunk), **geom)
+                slots = _deal_programs(chunk, B)
+                args = _task_arrays(pairs, slots, bands, BASE_ROWS, K,
+                                    False, pk)
+            ops, cnt, ok, dist = _launch("base", kern, args, len(chunk),
+                                         **geom)
             with obs.span("align.traceback", cat="launch",
                           tasks=len(chunk), K=K):
-                for bi, t in enumerate(chunk):
+                for bi, t in enumerate(slots):
+                    if t is None:
+                        continue
                     v = verify.get(t.pair) if verify else None
                     if (v is not None and t.ia == 0 and t.ib == v[0]
                             and t.ja == 0 and t.jb == v[1]):

@@ -3,6 +3,7 @@ the emitted op path must be a valid alignment whose cost equals the true
 (unbanded) edit distance whenever the optimal path stays in band.
 """
 
+import functools
 import random
 
 import numpy as np
@@ -289,3 +290,146 @@ def test_hirschberg_fuzz_exact(seed):
         assert path_cost(ops, q, t) == native.edit_distance(q, t), \
             (seed, len(q), len(t))
     assert n_served >= len(pairs) - 1, "band escapes should be rare here"
+
+
+# -- eight tasks per grid program (lock-step groups) -----------------------
+
+def _enc(q: bytes, t: bytes):
+    return (encode(np.frombuffer(q, np.uint8)).astype(np.int32),
+            encode(np.frombuffer(t, np.uint8)).astype(np.int32))
+
+
+def _fresh_kernels():
+    # the batch-keyed jitted closures bake in the shard_map decision
+    align_pallas._build_edge_kernel.cache_clear()
+    align_pallas._build_base_kernel.cache_clear()
+
+
+@pytest.fixture(params=["one_device", "mesh"])
+def placement(request, monkeypatch):
+    """Both ways a launch reaches its programs: the single-device jit,
+    and the suite's 8-device mesh, where a shard of fewer than GROUP
+    rows is one program with idle sublanes and larger launches are dealt
+    round the shards."""
+    from racon_tpu.parallel import reset_partitioner
+
+    if request.param == "one_device":
+        monkeypatch.setenv("RACON_TPU_SHARD", "0")
+    reset_partitioner()
+    _fresh_kernels()
+    yield request.param
+    reset_partitioner()
+    _fresh_kernels()
+
+
+@functools.lru_cache(maxsize=1)
+def _pool():
+    """65 pairs whose first round is one (rcap 512, K 256) launch, from
+    the shortest task an edge kernel sees (257 rows: halves of 128 and
+    129) to the bucket's cap (1024 rows: R = rcap), plus base-only pairs
+    of 1 and 256 rows, which share a base program with everything else."""
+    rng = random.Random(77)
+    lengths = [257, 1024, 1, 256] + [rng.randrange(258, 1024)
+                                     for _ in range(61)]
+    pairs = []
+    for n in lengths:
+        q = _rand(rng, n)
+        pairs.append((q, mutate(q, 0.04, rng) if n > 1 else q))
+    return pairs
+
+
+@functools.lru_cache(maxsize=1)
+def _pool_alone():
+    """Every pair of the pool through align_pairs in a call of its own:
+    launches of one task, seven idle sublanes beside it."""
+    return [align_pallas.align_pairs([_enc(q, t)], interpret=True)[0]
+            for q, t in _pool()]
+
+
+@pytest.mark.parametrize("n_tasks", [1, 7, 8, 9, 65])
+def test_grouping_is_invisible(placement, n_tasks):
+    """A task's result does not depend on which tasks share its program:
+    the same pairs in shuffled order, in launches of 1, 7, 8, 9 and 65
+    tasks (one group, one short of it, one over, many with whole pad
+    groups), give the op arrays each pair gives alone — and those cost
+    what the host aligner's path costs (op for op the two differ in
+    tie-breaks, as they did before)."""
+    pool, alone = _pool(), _pool_alone()
+    order = list(range(len(pool)))
+    random.Random(n_tasks).shuffle(order)
+    # the extremes first, so every size past 1 holds R = 129 next to
+    # R = rcap in one program (4 for the base kernel: R = 1 next to 256)
+    pick = ([0, 1, 2, 3] + [i for i in order if i > 3])[:n_tasks]
+    random.Random(n_tasks + 1).shuffle(pick)
+    got = align_pallas.align_pairs([_enc(*pool[i]) for i in pick],
+                                   interpret=True)
+    for i, ops in zip(pick, got):
+        assert ops is not None and alone[i] is not None, i
+        np.testing.assert_array_equal(ops, alone[i], err_msg=str(i))
+        q, t = pool[i]
+        assert path_cost(ops, q, t) == native.edit_distance(q, t), i
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+def test_one_row_task_beside_a_full_one(backward):
+    """The edge kernel itself, R = 1 next to R = rcap in one program:
+    the short task idles through 511 steps carrying its row, the long
+    one is not cut short.  Each equals what it gives in a program of its
+    own."""
+    rng = random.Random(9)
+    rcap, K = 512, 256
+    q = _rand(rng, rcap)
+    pairs = [_enc(q, mutate(q, 0.05, rng)), _enc(b"A", b"AC")]
+    bands = {0: (K, -100), 1: (K, -100)}
+    tasks = [align_pallas._Task(0, 0, rcap, 0, len(pairs[0][1])),
+             align_pallas._Task(1, 0, 1, 0, 2)]
+    kern = align_pallas._build_edge_kernel(rcap, K, backward, True,
+                                           align_pallas._pack_factor())
+
+    def run(slots):
+        args = align_pallas._task_arrays(pairs, slots, bands, rcap, K,
+                                         backward,
+                                         align_pallas._pack_factor())
+        return np.asarray(kern(len(slots))(*args))
+
+    both = run(tasks + [None] * 6)
+    assert (both[0] < align_pallas.INF).any()
+    assert (both[1] < align_pallas.INF).any()
+    for g, t in enumerate(tasks):
+        np.testing.assert_array_equal(both[g], run([t] + [None] * 7)[0])
+    assert (both[2:] >= 0).all()        # idle sublanes: any value, no fault
+
+
+@pytest.mark.parametrize("placement", ["one_device"], indirect=True)
+def test_pad_task_never_lengthens_a_group(placement):
+    """The counter pair that says how well the groups engage, checked by
+    hand: nine equal pairs of 600 rows.  Round 1 is one launch of 9
+    (padded to 16: a full program and one of 1 task + 7 pads), round 2
+    one of 18 (padded to 32: 2 + a partial + a program of pads only),
+    the base launch 36 (64: 4 + a partial + 3 of pads only).  A pad has
+    R = 0: it adds nothing to ``rows.real``, and a program of pads adds
+    nothing to ``rows.slots``."""
+    from racon_tpu import obs
+
+    rng = random.Random(21)
+    pairs = []
+    for _ in range(9):
+        q = _rand(rng, 600)
+        pairs.append(_enc(q, q))
+    obs.reset()
+    obs.configure(metrics=True)
+    try:
+        res = align_pallas.align_pairs(pairs, interpret=True)
+        counters = obs.snapshot()["counters"]
+    finally:
+        obs.reset()
+    assert all((r == 0).all() and len(r) == 600 for r in res)
+    # every round walks every row of every pair once (forward half +
+    # backward half), and so does the base launch
+    assert counters["align.lockstep.rows.real"] == 3 * 9 * 600
+    g = align_pallas.GROUP
+    assert counters["align.lockstep.rows.slots"] == g * (
+        2 * 2 * 300          # round 1, fwd + bwd: two programs at R 300
+        + 2 * 3 * 150        # round 2: three programs at R 150, one at 0
+        + 5 * 150)           # base: five programs at R 150, three at 0
+    assert counters["align.tasks.pad"] == 2 * 7 + 2 * 14 + 28

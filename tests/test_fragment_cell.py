@@ -384,3 +384,20 @@ def test_every_metric_of_the_cell_reads_nothing_from_an_older_program():
         assert value is None or isinstance(value, (int, float)), m["name"]
         if m["name"] in NEW_METRICS - {"frag_poa_host_fallback_s_per_mbp"}:
             assert value is None, m["name"]     # that span predates PR 28
+
+
+@pytest.mark.parametrize("cell_name", ["ecoli-ont.paf", "ecoli-frag.paf"])
+def test_lockstep_fill_share_reads_its_counter_pair(cell_name):
+    """``align_lockstep_fill_share`` (PR 29) in both PAF cells: the rows
+    the Hirschberg tasks asked for over the sublane-rows their programs
+    ran; nothing on a program that does not count them (the parent)."""
+    cell = loader.load_cell(cell_name)
+    spec, = (m for m in cell.per_layer
+             if m["name"] == "align_lockstep_fill_share")
+    assert spec["workloads"] == ["ecoli-ont.paf", "ecoli-frag.paf"]
+    read = reducers.registry()[spec["reducer"]]
+    run = _run({"align.lockstep.rows.real": 9000,
+                "align.lockstep.rows.slots": 10000}, {}, {})
+    assert read(run, **spec["params"]) == pytest.approx(90.0)
+    assert read(_run({"align.tasks.real": 5}, {}, {}),
+                **spec["params"]) is None

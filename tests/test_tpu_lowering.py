@@ -167,27 +167,31 @@ def test_v2_poa_kernel_lowers_to_tpu():
     _export_tpu(*_v2(2))
 
 
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+# one grid program of GROUP tasks (a launch of eight or fewer), and eight
+@pytest.mark.parametrize("B", [align_pallas.GROUP, TPU_BATCH])
 @pytest.mark.parametrize("rcap,K", [
-    (512, 256), (8192, 1024),
+    (512, 256), (8192, 1024), (8192, 2048),
     # read-vs-read overlaps of 0.5-6 kb (ecoli-frag.paf): the low buckets
     (1024, 256), (2048, 256), (2048, 512),
 ])
-def test_hirschberg_edge_kernels_lower_to_tpu(single_device, rcap, K):
-    for backward in (False, True):
-        _export_tpu(*_edge(rcap, K, backward, 2))
+def test_hirschberg_edge_kernels_lower_to_tpu(single_device, rcap, K, B,
+                                              backward):
+    _export_tpu(*_edge(rcap, K, backward, B))
 
 
-@pytest.mark.parametrize("K", [256, 512, 1024])
-def test_hirschberg_base_kernel_lowers_to_tpu(single_device, K):
-    _export_tpu(*_base(K, 2))
+@pytest.mark.parametrize("B", [align_pallas.GROUP, TPU_BATCH])
+@pytest.mark.parametrize("K", [256, 512, 1024, 2048])
+def test_hirschberg_base_kernel_lowers_to_tpu(single_device, K, B):
+    _export_tpu(*_base(K, B))
 
 
 @pytest.mark.parametrize("name,build", [
     ("racon_poa_ls", lambda: _ls(500, 8, SHARD_BATCH)),
     ("racon_poa_v2", lambda: _v2(2)),
-    ("racon_hirschberg_edge_fwd", lambda: _edge(512, 256, False, 2)),
-    ("racon_hirschberg_edge_bwd", lambda: _edge(512, 256, True, 2)),
-    ("racon_hirschberg_base", lambda: _base(256, 2)),
+    ("racon_hirschberg_edge_fwd", lambda: _edge(512, 256, False, 8)),
+    ("racon_hirschberg_edge_bwd", lambda: _edge(512, 256, True, 8)),
+    ("racon_hirschberg_base", lambda: _base(256, 8)),
 ])
 def test_lowered_kernel_carries_its_stable_name(single_device, name, build):
     """One name per kernel kind, in both places a profile shows: the HLO
@@ -220,3 +224,18 @@ def test_hirschberg_kernels_compile_for_v5e(single_device):
     for backward in (False, True):
         _compile_v5e(*_edge(8192, 1024, backward, TPU_BATCH))
     _compile_v5e(*_base(1024, TPU_BATCH))
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+@pytest.mark.parametrize("rcap,K", [(512, 256), (2048, 512), (8192, 2048)])
+def test_lockstep_edge_kernels_compile_for_v5e(single_device, rcap, K,
+                                               backward):
+    # the ends of the bucket ladder the two PAF cells run: (8, w) tiles in
+    # VMEM, the (8, 1) scalar columns and their lane broadcasts
+    _compile_v5e(*_edge(rcap, K, backward, TPU_BATCH))
+
+
+@pytest.mark.parametrize("K", [256, 2048])
+def test_lockstep_base_kernel_compiles_for_v5e(single_device, K):
+    # the move matrix of eight tasks, a byte per row: 0.5 and 4 MB of VMEM
+    _compile_v5e(*_base(K, TPU_BATCH))
