@@ -332,7 +332,7 @@ def main():
     # what served is in the report; the tag only repeats the request
     kernel_tag = (" [XLA kernel: RACON_TPU_PALLAS=0]"
                   if config.get_raw("RACON_TPU_PALLAS") == "0"
-                  else f" [pallas {config.get_str('RACON_TPU_POA_KERNEL')}]")
+                  else " [pallas ls]")
     if _forced_device():
         kernel_tag += " [FORCED DRY-RUN: not device evidence]"
     # numbers measured with the runtime sanitizer armed carry its
@@ -623,7 +623,7 @@ def stream_profile(contigs: int = 4) -> int:
         # rehearsal runs the small-window XLA path — same reasoning as
         # serve_profile: the twin at w=500 runs minutes/window on a CPU
         env.update(JAX_PLATFORMS="cpu", RACON_TPU_PALLAS="0",
-                   RACON_TPU_POA_KERNEL="v2", RACON_TPU_BATCH_WINDOWS="8",
+                   RACON_TPU_BATCH_WINDOWS="8",
                    RACON_TPU_DEVICE_ALIGNER="xla")
     device = _require_chip(env)
     budget = config.get_int("RACON_TPU_MEM_BUDGET_MB") or 2048

@@ -27,7 +27,7 @@ this orchestrator never imports JAX, so the chip has one owner at a time:
   verdict   edit distance of draft / host / device contigs to the truth
 
 Every device stage is judged from its run report (``judge_report``):
-platform ``tpu``, no degradation, ``v2``/``xla`` at zero, ``ls`` and
+platform ``tpu``, no degradation, ``xla`` at zero, ``ls`` and
 ``hirschberg`` serving their stated shares, the warm run loaded from the
 persistent cache.  A smoke that passes with ``ls`` at zero is the failure
 this script exists to prevent.
@@ -95,9 +95,8 @@ def judge_report(rep: dict, *, alignment: bool, rehearsal: bool = False,
         kernel_windows = cons.get("total", 0) - served.get("backbone", 0)
         if cons.get("degradations"):
             bad.append(f"consensus degraded: {cons['degradations']}")
-        for tier in ("v2", "xla"):
-            if served.get(tier, 0):
-                bad.append(f"consensus tier {tier} served {served[tier]}")
+        if served.get("xla", 0):
+            bad.append(f"consensus tier xla served {served['xla']}")
         if served.get("ls", 0) < LS_MIN_SHARE * max(kernel_windows, 1):
             bad.append(f"ls served {served.get('ls', 0)} of "
                        f"{kernel_windows} windows (< {LS_MIN_SHARE:.0%})")
@@ -127,8 +126,6 @@ def judge_report(rep: dict, *, alignment: bool, rehearsal: bool = False,
 
     counters = ((rep.get("obs") or {}).get("metrics") or {}).get(
         "counters") or {}
-    if counters.get("kernel.builds.poa.v2", 0):
-        bad.append("a v2 POA kernel was built")
     if counters.get("shard.demotions", 0):
         bad.append(f"shard demotions: {counters['shard.demotions']}")
     if dev.get("count", 1) > 1:

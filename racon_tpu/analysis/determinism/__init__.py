@@ -111,7 +111,7 @@ MUTANTS = [
        "            config.get_int(\"RACON_TPU_PIPELINE_DEPTH\"))"
        ".encode()\n")]),
     ("overkey-tier",
-     "key the journal fingerprint on the POA kernel tier knob: a "
+     "key the journal fingerprint on the Pallas-or-twin tier knob: a "
      "cost-only, taint-clean knob would force fingerprint misses "
      "between byte-identical runs",
      "fingerprint-overkey",
@@ -119,7 +119,7 @@ MUTANTS = [
        '            "backend": ("input:backend",),\n'
        '            "params": ("input:params",),\n',
        '            "backend": ("input:backend",),\n'
-       '            "tier": ("knob:RACON_TPU_POA_KERNEL",),\n'
+       '            "tier": ("knob:RACON_TPU_PALLAS",),\n'
        '            "params": ("input:params",),\n')]),
     ("drop-journal-waiver",
      "strip the documented waiver from the journal window-replay "

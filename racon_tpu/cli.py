@@ -127,17 +127,6 @@ def main(argv=None) -> int:
               f"variable(s) ignored: {', '.join(stale)} (known knobs: "
               f"see README.md)", file=sys.stderr)
 
-    if args.tpu:
-        # Validate device-path env config up front — a broad ValueError
-        # catch around the whole run would also swallow real bugs'
-        # tracebacks.
-        from .ops.poa_driver import _kernel_kind
-        try:
-            _kernel_kind()
-        except ValueError as e:
-            print(e, file=sys.stderr)
-            return 1
-
     try:
         polisher = create_polisher(
             args.sequences, args.overlaps, args.targets,

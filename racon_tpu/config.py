@@ -61,19 +61,8 @@ KNOBS: Dict[str, Knob] = {k.name: k for k in (
     _k("RACON_TPU_PALLAS", None, "bool",
        "fused Pallas kernels vs the XLA twin (default: 1 on TPU, 0 "
        "elsewhere)"),
-    _k("RACON_TPU_POA_KERNEL", "ls", "str",
-       "consensus kernel tier: 'ls' (lane-lockstep) or 'v2' (one "
-       "window/program)"),
     _k("RACON_TPU_DEVICE_ALIGNER", "auto", "str",
        "phase-1 aligner: auto | hirschberg | 1/xla | 0/host"),
-    _k("RACON_TPU_POA_COLSTEP", "1", "bool",
-       "column-compressed POA DP stepping: same-column siblings (v2) / "
-       "rank pairs (ls) share one serial loop iteration (0 restores the "
-       "one-rank-per-step loop; output is byte-identical either way)"),
-    _k("RACON_TPU_ALIGN_PACK", "1", "bool",
-       "packed Hirschberg DP: 4 query bases per word, 4 DP rows per "
-       "serial loop iteration (0 restores one-row-per-step kernels; "
-       "output is byte-identical either way)"),
     _k("RACON_TPU_BAND", "0", "bool",
        "banded DP on the hot kernels: Ukkonen-banded Hirschberg "
        "alignment + diagonal-banded POA with verify-and-widen "
@@ -168,9 +157,6 @@ KNOBS: Dict[str, Knob] = {k.name: k for k in (
     _k("RACON_TPU_TRACE_DEVICE", None, "bool",
        "with tracing armed on a real TPU backend, also capture a "
        "jax.profiler device trace next to the trace file"),
-    _k("RACON_TPU_COST_MODEL", "1", "bool",
-       "stamp analytic cost predictions into kernel.build spans and "
-       "bench entries (obs/costmodel.py; 0 disables)"),
     _k("RACON_TPU_MACHINE_PROFILE", "auto", "str",
        "machine profile for cost-model predictions: auto | cpu-host | "
        "tpu-v5e (auto picks by platform and device_kind)"),

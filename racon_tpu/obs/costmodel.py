@@ -2,7 +2,7 @@
 
 ROADMAP item 3's hardware-free half: before cutting serial DP steps we
 need to *predict* where the cycles go — per POA bucket (DEPTH_BUCKETS x
-128-lane window class, tier ls/v2/xla) and per aligner bucket — and
+128-lane window class, tier ls/xla) and per aligner bucket — and
 check those predictions against what `--trace` actually measured.  The
 vocabulary is the one AnySeq/GPU and gpuPairHMM use to justify DP
 optimizations: cell updates per second against a machine roofline.
@@ -24,9 +24,9 @@ Three layers:
   serial-step term); whichever term wins classifies the bucket as
   compute-bound / bandwidth-bound / serial-step-bound.  The measured
   0.188x story is the serial-step term winning by ~40x, which is why
-  the serial-step cut landed: the Pallas POA tiers now divide their
-  step count by POA_COLSTEP_PACK (column-compressed rank pairing,
-  ops/colstep.py) and the packed Hirschberg kernels divide theirs by
+  the serial-step cut landed: the Pallas POA tier divides its step
+  count by POA_RANK_PACK (rank-pair stepping,
+  ops/poa_pallas_ls.py) and the packed Hirschberg kernels divide theirs by
   ALIGN_ROW_PACK (ops/encoding.PACK rows per iteration).
 
 Everything here is stdlib-only (the obs package contract): the kernel
@@ -54,21 +54,17 @@ LS_GROUP = 8
 #: audited (and documented) at.
 AUDIT_WINDOW_LENGTHS = (500, 1000)
 
-POA_TIERS = ("ls", "v2", "xla")
+POA_TIERS = ("ls", "xla")
 
 #: Graph ranks per backbone position: POA graphs grow past the backbone
 #: as divergent layer bases fork nodes.  λ at ~30x measured ~2x
 #: (docs/benchmarks.md: ~1000 ranks over a 500-base backbone).
 NODE_GROWTH = 2.0
 
-#: ops.colstep.PACK — ranks retired per serial iteration by the
-#: column-compressed Pallas loops (v2 pairs adjacent same-column
-#: siblings, ls retires unconditional rank pairs).  At NODE_GROWTH=2.0
-#: the average column multiplicity is 2, so the greedy pairer runs at
-#: its ceiling and the serial-step divisor is the full pack factor.
-#: Applies to the v2 and ls tiers only; the XLA twin keeps the
-#: one-rank-per-step scan.
-POA_COLSTEP_PACK = 2.0
+#: Ranks retired per serial iteration by the lockstep Pallas kernel's
+#: pair loop (poa_pallas_ls.py `pair_body`: two consecutive ranks,
+#: unconditionally).  The XLA twin keeps the one-rank-per-step scan.
+POA_RANK_PACK = 2.0
 
 #: ops.encoding.PACK — query bases packed per int32 word by the packed
 #: Hirschberg kernels; each serial loop iteration scores PACK adjacent
@@ -158,7 +154,7 @@ PROFILES: Dict[str, MachineProfile] = {p.name: p for p in (
         clock_hz=3.0e9,
         # XLA CPU executes the scan-based DP kernels essentially
         # scalar + dispatch-bound; calibrated against traced runs of
-        # the v2 XLA twin on this repo's dev box.
+        # the XLA twin on this repo's dev box.
         peak_flops=2.0e9,
         hbm_bytes_per_s=1.0e10,
         # One serial DP step on this profile is one XLA while-loop
@@ -184,7 +180,7 @@ PROFILES: Dict[str, MachineProfile] = {p.name: p for p in (
                     "int32 DP kernels run on the VPU, far below the MXU "
                     "peak, so the compute term is a floor, not an "
                     "estimate. serial_step_s is NOT measured on this "
-                    "device: it is the v2-tier dp_cost_probe figure of "
+                    "device: it is the dp_cost_probe figure of "
                     "2026-07-29, kept until ROADMAP S2 measures the "
                     "shipped kernels from a trace",
         device_kinds=("TPU v5 lite", "TPU v5e"),
@@ -242,17 +238,14 @@ def poa_window_cost(depth: int, wl_class: int, tier: str) -> CostEstimate:
     cells = depth * ranks * wl_class
     flops = cells * POA_FLOPS_PER_CELL
     # HBM traffic: layer bases/weights streamed in, consensus out; the H
-    # matrix lives in VMEM (v2 ring / ls ring), so it does not cross HBM.
+    # matrix lives in VMEM (the ls ring), so it does not cross HBM.
     hbm = depth * wl_class * POA_LAYER_BYTES + 2 * wl_class * 5
     steps = depth * ranks
-    if tier in ("v2", "ls"):
-        # Column-compressed stepping (ops/colstep.py): the Pallas loops
-        # retire rank pairs per serial iteration.
-        steps /= POA_COLSTEP_PACK
     if tier == "ls":
+        # The Pallas loop retires a rank pair per serial iteration, and
         # G windows share one program's rank loop: the serial term
         # amortizes per window, the cell work does not.
-        steps /= LS_GROUP
+        steps /= POA_RANK_PACK * LS_GROUP
     return CostEstimate(flops, hbm, steps)
 
 
@@ -302,10 +295,8 @@ def banded_poa_window_cost(depth: int, wl_class: int, w: int,
     flops = cells * POA_FLOPS_PER_CELL
     hbm = depth * wl_class * POA_LAYER_BYTES + 2 * wl_class * 5
     steps = depth * ranks
-    if tier in ("v2", "ls"):
-        steps /= POA_COLSTEP_PACK
     if tier == "ls":
-        steps /= LS_GROUP
+        steps /= POA_RANK_PACK * LS_GROUP
     return CostEstimate(flops, hbm, steps)
 
 
@@ -527,7 +518,7 @@ def predict_from_counters(counters: Dict[str, int],
                      else infer_n_devices(counters))
     n_devices = max(1, int(n_devices))
     # ---- consensus / POA
-    tier = _dominant_tier(counters, "consensus", POA_TIERS) or "v2"
+    tier = _dominant_tier(counters, "consensus", POA_TIERS) or "ls"
     total_served = sum(v for k, v in counters.items()
                        if k.startswith("served.consensus."))
     host_served = counters.get("served.consensus.host", 0)
@@ -543,8 +534,7 @@ def predict_from_counters(counters: Dict[str, int],
         steps1 = float(raw)                      # sum(depth_i) * C
         ranks_steps = steps1 * NODE_GROWTH       # rank-loop steps
         cells = ranks_steps * c                  # DP cells
-        step_div = {"ls": LS_GROUP * POA_COLSTEP_PACK,
-                    "v2": POA_COLSTEP_PACK}.get(tier, 1.0)
+        step_div = LS_GROUP * POA_RANK_PACK if tier == "ls" else 1.0
         est = CostEstimate(cells * POA_FLOPS_PER_CELL,
                            steps1 * POA_LAYER_BYTES,
                            ranks_steps / step_div)

@@ -62,7 +62,7 @@ def stage_seconds(summary: dict) -> Dict[str, float]:
         stage = _REPORT_STAGES.get(phase)
         if stage is None or not isinstance(rep, dict):
             continue
-        # per-phase wall is a tier -> seconds split (xla/v2/journal/...):
+        # per-phase wall is a tier -> seconds split (xla/ls/journal/...):
         # the ledger wants the phase total, whichever tiers served it
         walls = rep.get("wall_s")
         if isinstance(walls, dict):

@@ -57,7 +57,7 @@ import numpy as np
 from .. import config, obs
 from ..device import named
 from . import band as _band
-from .encoding import encode, pack_bases
+from .encoding import PACK, encode, pack_bases
 from .kernel_cache import device_keyed_cache
 
 INF = 1 << 28
@@ -112,15 +112,6 @@ def _dispatch_shards(batch: int) -> int:
 # ---------------------------------------------------------------------------
 # distance-only kernels
 # ---------------------------------------------------------------------------
-
-def _pack_factor() -> int:
-    """Row-pack factor for the Hirschberg kernels: PACK (4) query bases
-    per 32-bit word and per serial loop iteration (RACON_TPU_ALIGN_PACK,
-    default on), 1 = the one-row-per-step kernels."""
-    from .encoding import PACK
-
-    return PACK if config.get_bool("RACON_TPU_ALIGN_PACK") else 1
-
 
 GROUP = 8                # tasks per grid program, one per sublane of the
                          # int32 vreg tile
@@ -210,7 +201,7 @@ def _group_scalars(nb, scal, rows_per_step):
 
 @device_keyed_cache(maxsize=64)
 def _build_edge_kernel(rcap: int, K: int, backward: bool,
-                       interpret: bool = False, pack: int = 1):
+                       interpret: bool = False):
     """Batched banded DP over up to `rcap` rows; returns the last row.
 
     GROUP (8) tasks per grid program run in lock-step, task g in sublane
@@ -232,17 +223,17 @@ def _build_edge_kernel(rcap: int, K: int, backward: bool,
     its row through unchanged, so a task's result does not depend on
     which tasks share its program.
 
-    pack > 1: the query arrives packed `pack` codes per int32 word
-    (encoding.pack_bases) and each serial iteration retires `pack` DP
-    rows off one word-column read — the fori_loop trip count drops from
-    R to ceil(R / pack), byte-identical to pack == 1.
+    The query arrives packed PACK (4) codes per int32 word
+    (encoding.pack_bases) and each serial iteration retires PACK DP
+    rows off one word-column read: the fori_loop runs ceil(R / PACK)
+    trips.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     G = GROUP
     TCAP = rcap + K
-    QIN = rcap if pack == 1 else max(128, _round_up(rcap // pack, 128))
+    QIN = max(128, _round_up(rcap // PACK, 128))
     name = f"racon_hirschberg_edge_{'bwd' if backward else 'fwd'}"
 
     def kernel(trip_ref, scal_ref, q_ref, t_ref, out_ref):
@@ -279,12 +270,12 @@ def _build_edge_kernel(rcap: int, K: int, backward: bool,
                         S - j0 if backward else j0, INF)
 
         def body(it, row):
-            # one word-column read feeds `pack` rows; a task past its
+            # one word-column read feeds PACK rows; a task past its
             # own R carries `row` through unchanged
             qword = lroll(q_ref[0], it, QIN)[:, 0:1]
-            for p in range(pack):
-                k = it * pack + p
-                qc = qword if pack == 1 else (qword >> (8 * p)) & 0xFF
+            for p in range(PACK):
+                k = it * PACK + p
+                qc = (qword >> (8 * p)) & 0xFF
                 row = jnp.where(k < R, step(k, qc, row), row)
             return row
 
@@ -309,7 +300,7 @@ def _build_edge_kernel(rcap: int, K: int, backward: bool,
         @named(name)
         def fn(scal, q, t):
             nb, (scal, q, t) = _group_rows(b, (scal, q, t))
-            out = make(nb)(*_group_scalars(nb, scal, pack), q, t)
+            out = make(nb)(*_group_scalars(nb, scal, PACK), q, t)
             return out.reshape(nb * G, K)[:b]
 
         return fn
@@ -327,7 +318,7 @@ def _build_edge_kernel(rcap: int, K: int, backward: bool,
 # ---------------------------------------------------------------------------
 
 @device_keyed_cache(maxsize=32)
-def _build_base_kernel(K: int, interpret: bool = False, pack: int = 1):
+def _build_base_kernel(K: int, interpret: bool = False):
     """Full moves-matrix DP over up to BASE_ROWS rows with the traceback
     in the kernel; returns op codes, their count, ok and the terminal
     distance per task.
@@ -347,11 +338,10 @@ def _build_base_kernel(K: int, interpret: bool = False, pack: int = 1):
     RB = BASE_ROWS
     TCAP = RB + K
     OPS = _round_up(RB + K + 2, 128)
-    # pack > 1: packed query words (encoding.pack_bases) — same contract
-    # as _build_edge_kernel; a word never straddles two iterations
-    assert U % pack == 0, (U, pack)
-    QCAP = _round_up(RB, 128) if pack == 1 else \
-        max(128, _round_up(RB // pack, 128))
+    # packed query words (encoding.pack_bases), as in _build_edge_kernel;
+    # a word never straddles two iterations
+    assert U % PACK == 0, (U, PACK)
+    QCAP = max(128, _round_up(RB // PACK, 128))
 
     def kernel(trip_ref, scal_s, scal_ref, q_ref, t_ref, ops_ref, cnt_ref,
                ok_ref, dist_ref, MVS, fin_scr):
@@ -369,12 +359,11 @@ def _build_base_kernel(K: int, interpret: bool = False, pack: int = 1):
             moves = jnp.zeros((G, K), jnp.int32)
             for p in range(U):
                 k = it * U + p
-                w = p // pack
+                w = p // PACK
                 if w not in words:
-                    words[w] = lroll(q_ref[0], it * (U // pack) + w,
+                    words[w] = lroll(q_ref[0], it * (U // PACK) + w,
                                      QCAP)[:, 0:1]
-                qc = words[w] if pack == 1 else \
-                    (words[w] >> (8 * (p % pack))) & 0xFF
+                qc = (words[w] >> (8 * (p % PACK))) & 0xFF
                 nrow, mv = fwd_row(k, qc, row)
                 moves = moves | (mv << (8 * p))
                 # a task past its own R carries `row` through; its moves
@@ -569,7 +558,7 @@ def _pow2(n):
     return b
 
 
-def _task_arrays(pairs, slots, bands, rcap, K, backward, pack=1):
+def _task_arrays(pairs, slots, bands, rcap, K, backward):
     """Pack one launch's slots (a task, or None for a pad row) into the
     edge kernel's arrays.  The staged target window is clipped to the
     half's band-reachable columns (j <= ib + gdmin + K going forward,
@@ -581,8 +570,8 @@ def _task_arrays(pairs, slots, bands, rcap, K, backward, pack=1):
     forward ts[x] = t[j_lo + x + dmin], backward ts[z] = t[j_lo + z -
     rcap + R - 1 + dmin] (255 outside the window; those cells are out of
     [0, S] and masked).  The backward query goes out reversed so that
-    step k reads q[R - 1 - k] at index k in every task; pack > 1 packs
-    the codes into words.  A pad row has R = 0: it costs its program
+    step k reads q[R - 1 - k] at index k in every task; the codes go
+    out packed PACK to a word.  A pad row has R = 0: it costs its program
     nothing and never sets a group's trip count."""
     B = len(slots)
     TCAP = rcap + K
@@ -611,8 +600,7 @@ def _task_arrays(pairs, slots, bands, rcap, K, backward, pack=1):
         lo, hi = max(0, -shift), min(TCAP, S - shift)
         if hi > lo:
             ts[bi, lo:hi] = tt[j_lo + lo + shift:j_lo + hi + shift]
-    if pack > 1:
-        qs = pack_bases(qs, width=max(128, _round_up(rcap // pack, 128)))
+    qs = pack_bases(qs, width=max(128, _round_up(rcap // PACK, 128)))
     return scal, qs, ts
 
 
@@ -679,10 +667,9 @@ def _split_round(pairs, tasks, bands, failed, interpret, verify=None,
         by_bucket.setdefault((rcap, K), []).append(t)
 
     round_span.set(buckets=len(by_bucket))
-    pk = _pack_factor()
     for (rcap, K), group in sorted(by_bucket.items()):
-        fwd = _build_edge_kernel(rcap, K, False, interpret, pk)
-        bwd = _build_edge_kernel(rcap, K, True, interpret, pk)
+        fwd = _build_edge_kernel(rcap, K, False, interpret)
+        bwd = _build_edge_kernel(rcap, K, True, interpret)
         # pad the batch dim to a power of two (at least one program of
         # GROUP tasks) so each (rcap, K) bucket compiles a handful of
         # kernel variants, not one per group size
@@ -700,8 +687,8 @@ def _split_round(pairs, tasks, bands, failed, interpret, verify=None,
                                    t.ja, t.jb) for t in slots]
             b_tasks = [t and _Task(t.pair, (t.ia + t.ib) // 2, t.ib,
                                    t.ja, t.jb) for t in slots]
-            f_args = _task_arrays(pairs, f_tasks, bands, rcap, K, False, pk)
-            b_args = _task_arrays(pairs, b_tasks, bands, rcap, K, True, pk)
+            f_args = _task_arrays(pairs, f_tasks, bands, rcap, K, False)
+            b_args = _task_arrays(pairs, b_tasks, bands, rcap, K, True)
         F = _launch("edge_fwd", fwd, f_args, len(group), **geom)
         Bv = _launch("edge_bwd", bwd, b_args, len(group), **geom)
         with obs.span("align.select", cat="launch", tasks=len(group),
@@ -749,9 +736,8 @@ def _solve_base(pairs, tasks, bands, segments, failed, interpret,
     for t in tasks:
         K = bands[t.pair][0]
         by_bucket.setdefault(K, []).append(t)
-    pk = _pack_factor()
     for K, group in sorted(by_bucket.items()):
-        kern, _, _, _ = _build_base_kernel(K, interpret, pk)
+        kern, _, _, _ = _build_base_kernel(K, interpret)
         group.sort(key=lambda t: t.ib - t.ia)   # like rows share a program
         for off in range(0, len(group), 64):
             chunk = group[off:off + 64]
@@ -761,7 +747,7 @@ def _solve_base(pairs, tasks, bands, segments, failed, interpret,
                           **geom):
                 slots = _deal_programs(chunk, B)
                 args = _task_arrays(pairs, slots, bands, BASE_ROWS, K,
-                                    False, pk)
+                                    False)
             ops, cnt, ok, dist = _launch("base", kern, args, len(chunk),
                                          **geom)
             with obs.span("align.traceback", cat="launch",

@@ -52,16 +52,9 @@ def device_keyed_cache(maxsize: int = 64):
                                                 devs[0].platform)
             built = cached(*topo, *args, **kwargs)
             if cached.cache_info().misses != misses0:
-                # shape/cost extraction for the analytic cost model:
-                # the predicted per-unit bill rides in the same span as
-                # the measured build wall (obs/costmodel.py)
-                from . import cost_hooks
-
-                pred = cost_hooks.record_build(build.__name__, args,
-                                               kwargs)
                 obs.add_complete("kernel.build", t0, time.monotonic_ns(),
                                  builder=build.__name__,
-                                 platform=devs[0].platform, **pred)
+                                 platform=devs[0].platform)
                 obs.count(f"kernel.builds.{build.__name__}")
             # Opt-in runtime sanitizer (RACON_TPU_SANITIZE=1): hand the
             # built kernel back wrapped in a checking proxy. Imported

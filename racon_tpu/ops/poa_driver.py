@@ -10,7 +10,7 @@ Mirrors the reference's CUDA polish orchestration
 (cudabatch.cpp:230-256).
 
 Failure handling runs through the explicit degradation lattice
-(racon_tpu/resilience/lattice.py): tiers ls -> v2 -> xla -> host, with
+(racon_tpu/resilience/lattice.py): tiers ls -> xla -> host, with
 per-tier bounded retry, a per-device-call watchdog, and batch bisection
 so one poisoned window is quarantined to the host instead of demoting the
 whole run a tier.  Every seam carries a named fault-injection point
@@ -47,8 +47,6 @@ def _sanitize():
     from ..analysis import sanitize
     return sanitize
 
-_PALLAS_KINDS = ("ls", "v2")
-
 #: The window lengths the static jaxpr audit traces the consensus kernel
 #: grid at: the CLI default (-w 500) and the large-window scenario
 #: (-w 1000).  Each maps to its 128-lane class exactly as
@@ -73,26 +71,11 @@ def _batch_size() -> int:
     return 64 if jax.devices()[0].platform == "tpu" else 4
 
 
-def _kernel_kind() -> str:
-    """Which fused Pallas kernel serves consensus batches.
-
-    'ls' (default) — v3 lane-lockstep, 8 windows per program
-    (poa_pallas_ls.py); 'v2' — one window per program (poa_pallas.py).
-    Either degrades through the lattice (ls -> v2 -> xla -> host) on
-    Mosaic failure.
-    """
-    k = config.get_str("RACON_TPU_POA_KERNEL")
-    if k not in _PALLAS_KINDS:
-        raise ValueError(
-            f"RACON_TPU_POA_KERNEL must be 'ls' or 'v2', got {k!r}")
-    return k
-
-
 def _band_active(kind: str) -> bool:
-    """Banded POA dispatch: RACON_TPU_BAND on and a Pallas tier serving
+    """Banded POA dispatch: RACON_TPU_BAND on and the Pallas tier serving
     (the XLA twin and the host floor always run flat — they are the
     byte-identity oracles the verify-and-widen ladder bottoms out on)."""
-    return kind in _PALLAS_KINDS and _band.enabled()
+    return kind == "ls" and _band.enabled()
 
 
 def _initial_poa_band(wx, keep, cfg):
@@ -117,17 +100,18 @@ def _shard_n(B: int) -> int:
     return part.batch_axis_size if part.will_shard(B) else 1
 
 
-def _device_batch(kind: str) -> int:
+def _device_batch(use_pallas: bool) -> int:
     """Batch size for the kernel geometry, padded UP to a mesh multiple
     when the batch will shard (the old round-DOWN spilled remainder
     windows to the slow path; pad rows are 1-base/0-layer windows and
-    show up in `shard.pad_rows`); the lockstep kernel additionally needs
-    the per-shard batch to be a multiple of its sublane group G."""
+    show up in `shard.pad_rows`); with the Pallas tier on, the lockstep
+    kernel additionally needs the per-shard batch to be a multiple of
+    its sublane group G (the XLA twin takes any batch)."""
     B = _batch_size()
     m = _shard_n(B)
     if m > 1:
         B = ((B + m - 1) // m) * m
-    if kind == "ls":
+    if use_pallas:
         from .poa_pallas_ls import G
         q = G * m
         B = max(1, (B + q - 1) // q) * q
@@ -262,9 +246,8 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
     report.record_served("backbone", stats["backbone"])
 
     if jobs:
-        requested = _kernel_kind()
-        B = _device_batch(requested)
         use_pallas = _use_pallas()
+        B = _device_batch(use_pallas)
         # what the serving kernels were: compiled or interpreted Pallas,
         # and the batch / shard geometry they were dispatched at
         report.extra["kernels"] = {
@@ -328,16 +311,13 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
                           wl_class=wl_class, windows=len(bucket_jobs)):
                 cfg = make_config(wl_class, depth_bucket, match, mismatch,
                                   gap)
-                # Large window geometries (e.g. -w 1000) overflow the fused
-                # kernel's VMEM budget; the entry tier is picked per
-                # geometry.
-                entry_kind = _pick_tier(cfg, use_pallas, requested)
+                entry_kind = _pick_tier(cfg, use_pallas)
                 # a tier the warm-up proved dead is skipped below
                 # without a retry; the demotion still belongs in this
                 # run's report
                 kind = entry_kind
                 while (cfg, kind) in _WARM_DEAD:
-                    nxt = _next_tier(cfg, kind)
+                    nxt = _next_tier(kind)
                     report.record_degrade(kind, nxt, _WARM_DEAD[(cfg, kind)])
                     kind = nxt
                 # (Per-bucket depth is kept deliberately: the fused
@@ -499,56 +479,44 @@ def warm_geometries(window_lengths, match: int, mismatch: int,
     if isinstance(window_lengths, int):
         window_lengths = [window_lengths]
     classes = sorted({window_class(max(w, 1)) for w in window_lengths})
-    requested = _kernel_kind()
-    B = _device_batch(requested)
     use_pallas = _use_pallas()
+    B = _device_batch(use_pallas)
     import itertools
     for depth_bucket, wl_class in itertools.product(DEPTH_BUCKETS, classes):
         cfg = make_config(wl_class, depth_bucket, match, mismatch, gap)
-        kind = _pick_tier(cfg, use_pallas, requested)
-        with obs.span("poa.warmup", depth=depth_bucket, wl_class=wl_class):
-            while kind != "host":
-                kernel, kind = _live_tier(cfg, B, kind, _WARM_DEAD)
-                if kind == "host":
-                    break
-                try:
-                    faults.check(f"poa.run.{kind}", ())
-                    pallas = kind in _PALLAS_KINDS
-                    banded = _band_active(kind)
-                    _unpack(_submit(kernel, _pack([], cfg, B), pallas,
-                                    banded), pallas, banded)
-                    break
-                except Exception as e:  # noqa: BLE001 — same degrade
-                    # philosophy as run_consensus_phase: a Mosaic failure
-                    # on one geometry must not abort the caller — warm
-                    # the tier it will actually fall back to, and
-                    # remember the failure so the measured run doesn't
-                    # retry it
-                    _WARM_DEAD[(cfg, kind)] = e
-                    nxt = _next_tier(cfg, kind)
-                    _warn_degrade(e, nxt)
-                    kind = nxt
+        kind = _pick_tier(cfg, use_pallas)
+        while kind != "host":
+            kernel, kind = _live_tier(cfg, B, kind, _WARM_DEAD)
+            if kind == "host":
+                break
+            try:
+                faults.check(f"poa.run.{kind}", ())
+                pallas = kind == "ls"
+                banded = _band_active(kind)
+                _unpack(_submit(kernel, _pack([], cfg, B), pallas,
+                                banded), pallas, banded)
+                break
+            except Exception as e:  # noqa: BLE001 — same degrade
+                # philosophy as run_consensus_phase: a Mosaic failure
+                # on one geometry must not abort the caller — warm
+                # the tier it will actually fall back to, and
+                # remember the failure so the measured run doesn't
+                # retry it
+                _WARM_DEAD[(cfg, kind)] = e
+                nxt = _next_tier(kind)
+                _warn_degrade(e, nxt)
+                kind = nxt
 
 
-def _pick_tier(cfg, use_pallas: bool, kind: str) -> str:
-    """Entry tier for a geometry after VMEM-fit checks: the requested
-    pallas tier if it fits, else the next tier down."""
-    if not use_pallas:
-        return "xla"
-    if _fits_vmem(cfg, kind):
-        return kind
-    if kind == "ls" and _fits_vmem(cfg, "v2"):
-        return "v2"
-    return "xla"
+def _pick_tier(cfg, use_pallas: bool) -> str:
+    """Entry tier for a geometry: the lockstep Pallas kernel if Pallas
+    is on and its scratch fits VMEM, else the XLA twin."""
+    return "ls" if use_pallas and _fits_vmem(cfg) else "xla"
 
 
-def _next_tier(cfg, kind: str) -> str:
-    """The lattice tier below `kind` for this geometry (VMEM-aware)."""
-    if kind == "ls" and _fits_vmem(cfg, "v2"):
-        return "v2"
-    if kind in _PALLAS_KINDS:
-        return "xla"
-    return "host"
+def _next_tier(kind: str) -> str:
+    """The lattice tier below `kind`."""
+    return "xla" if kind == "ls" else "host"
 
 
 def _live_tier(cfg, B, kind, dead_geoms, report=None):
@@ -558,13 +526,13 @@ def _live_tier(cfg, B, kind, dead_geoms, report=None):
     (kernel, kind); kernel is None iff kind == 'host'."""
     while kind != "host":
         if (cfg, kind) in dead_geoms:
-            kind = _next_tier(cfg, kind)
+            kind = _next_tier(kind)
             continue
         try:
-            return _build_kernel(cfg, B, kind in _PALLAS_KINDS, kind), kind
+            return _build_kernel(cfg, B, kind == "ls"), kind
         except Exception as e:  # noqa: BLE001 — compile seam
             dead_geoms[(cfg, kind)] = e
-            nxt = _next_tier(cfg, kind)
+            nxt = _next_tier(kind)
             if report is not None:
                 report.record_failure(kind, e)
                 report.record_degrade(kind, nxt, e)
@@ -574,9 +542,7 @@ def _live_tier(cfg, B, kind, dead_geoms, report=None):
 
 
 def _warn_degrade(e, to_kind: str) -> None:
-    tier = (f"the pallas '{to_kind}' kernel" if to_kind in _PALLAS_KINDS
-            else "the XLA kernel" if to_kind == "xla"
-            else "the host engine")
+    tier = "the XLA kernel" if to_kind == "xla" else "the host engine"
     print(f"[racon_tpu::poa] WARNING: kernel tier failed "
           f"({type(e).__name__}: {e}); falling back to {tier}",
           file=sys.stderr)
@@ -665,11 +631,11 @@ class _ConsensusOps:
     def dispatch(self, ctx, kind, packed, chunk):
         faults.check(f"poa.run.{kind}", [i for i, _, _ in chunk])
         _count_launch(len(chunk), packed)
-        return _submit(ctx.kernel, packed, kind in _PALLAS_KINDS,
+        return _submit(ctx.kernel, packed, kind == "ls",
                        _band_active(kind))
 
     def attempt(self, ctx, kind, sub):
-        pallas = kind in _PALLAS_KINDS
+        pallas = kind == "ls"
         banded = _band_active(kind)
         faults.check(f"poa.run.{kind}", [i for i, _, _ in sub])
         packed = _pack(sub, ctx.cfg, self.B, self._widths(sub, ctx.cfg))
@@ -678,7 +644,7 @@ class _ConsensusOps:
                        pallas, banded)
 
     def unpack(self, ctx, kind, outs):
-        return _unpack(outs, kind in _PALLAS_KINDS, _band_active(kind))
+        return _unpack(outs, kind == "ls", _band_active(kind))
 
     def span_args(self, ctx, chunk, pipelined):
         return {"windows": len(chunk), "pipelined": pipelined}
@@ -718,7 +684,7 @@ class _ConsensusOps:
 
     def demote(self, ctx, kind, cause):
         self.dead_geoms[(ctx.cfg, kind)] = cause
-        nxt = _next_tier(ctx.cfg, kind)
+        nxt = _next_tier(kind)
         self.report.record_degrade(kind, nxt, cause)
         _warn_degrade(cause, nxt)
         return nxt
@@ -760,55 +726,43 @@ def _platform() -> str:
     return jax.devices()[0].platform
 
 
-def _fits_vmem(cfg, kind: str = "v2", budget_bytes: int = 14 << 20) -> bool:
-    """Whether the fused Pallas kernel's VMEM scratch fits the core budget.
+def _fits_vmem(cfg, budget_bytes: int = 11 << 20) -> bool:
+    """Whether the lockstep Pallas kernel's VMEM scratch fits the core
+    budget.  Mirrors poa_pallas_ls.py's scratch_shapes: a 128-row H ring
+    instead of the full H matrix, plus rank-space graph arrays and
+    per-layer DMA slots; layers stream from HBM, so depth does not
+    appear.  The budget is where the v5e compiler draws the line, in
+    this sum's terms: it leaves out Mosaic's own temporaries, and the
+    compiler took every geometry summing to 10.4 MiB or less and
+    refused every one from 11.5 MiB up (16.3 MB against its 16 MB
+    scoped limit; classes 896-1152 at NODE_FACTOR 3 and 4).  So at
+    NODE_FACTOR 3 classes up to 1024 fit, -w 1000 included
+    (tests/test_pallas_ls.py holds the table, tests/test_tpu_lowering.py
+    compiles its last row)."""
+    from .poa_pallas_ls import G, RING, _round_up
 
-    v2 mirrors poa_pallas.py's blocked layout: layer arrays live in HBM
-    and stream through two DMA slots, so depth does not appear. ls mirrors
-    poa_pallas_ls.py's scratch_shapes: a 128-row H ring instead of the full
-    H matrix, plus rank-space graph arrays and per-layer DMA slots.
-    """
-    if kind == "ls":
-        from .poa_pallas_ls import G, RING, _round_up
-
-        NC = cfg.max_nodes // 128
-        JC = _round_up(cfg.max_len + 1, 128) // 128
-        lane_bytes = G * 128 * 4
-        ring = RING * JC * lane_bytes
-        j_rows = (1 + 2 + 2 * 2) * JC * lane_bytes   # H0, nkey/runrem, scr
-        n_rows = (9 + 2 * cfg.max_edges) * NC * lane_bytes
-        io = 4 * NC * lane_bytes                      # bb/bbw in, cons out
-        return ring + j_rows + n_rows + io < budget_bytes
-    from .poa_pallas import blocked_width
-
-    jw8 = 8 * blocked_width(cfg.max_len + 1)
-    nw8 = 8 * blocked_width(cfg.max_nodes)
-    h = (cfg.max_nodes + 1) * jw8 * 4
-    mv = (cfg.max_nodes + 1) * jw8 * 4  # move records, i32 (Mosaic tiling)
-    layer_slots = 2 * 2 * jw8 * 4       # double-buffered seq + weight rows
-    graph = nw8 * (10 * 4 + 2 * cfg.max_edges * 4)
-    return h + mv + layer_slots + graph < budget_bytes
+    NC = cfg.max_nodes // 128
+    JC = _round_up(cfg.max_len + 1, 128) // 128
+    lane_bytes = G * 128 * 4
+    ring = RING * JC * lane_bytes
+    j_rows = (1 + 2 + 2 * 2) * JC * lane_bytes   # H0, nkey/runrem, scr
+    n_rows = (9 + 2 * cfg.max_edges) * NC * lane_bytes
+    io = 4 * NC * lane_bytes                      # bb/bbw in, cons out
+    return ring + j_rows + n_rows + io < budget_bytes
 
 
-def _build_kernel(cfg, B, use_pallas, kind: str = "v2"):
-    """Memoization front for _build_kernel_cached: the XLA twin ignores
-    `kind`, so normalize it out of the key — a warm-up that degraded to
-    the twin under 'v2' must hit the same cache entry as a measured-run
-    request arriving via the 'ls' step-down (and as __graft_entry__'s
-    default-argument call).  The device topology (count + platform) is
-    part of the key: reconfiguring JAX devices after a first build must
-    never serve a stale sharded/interpreted kernel (ADVICE.md)."""
-    if not use_pallas:
-        kind = "xla"
+def _build_kernel(cfg, B, use_pallas):
+    """Memoization front for _build_kernel_cached: the lockstep Pallas
+    kernel or the XLA twin for a B-window batch.  The device topology
+    (count + platform) is part of the key: reconfiguring JAX devices
+    after a first build must never serve a stale sharded/interpreted
+    kernel (ADVICE.md)."""
+    kind = "ls" if use_pallas else "xla"
     faults.check(f"poa.compile.{kind}")
-    # Column-compressed stepping rides in the cache key: flipping the
-    # knob mid-process must not serve a kernel built under the other
-    # loop shape.
-    colstep = config.get_bool("RACON_TPU_POA_COLSTEP")
-    # Banded builds ride the cache key too: the flat and banded variants
+    # Banded builds ride the cache key: the flat and banded variants
     # of a geometry are distinct compiled kernels (extra wband input /
     # band_hit output), and the flat one is the ladder's oracle.
-    banded = use_pallas and _band_active(kind)
+    banded = _band_active(kind)
     # Shard count resolved here (not in the cached builder) so the key
     # is explicit: a will_shard flip — knob, demotion, mesh change —
     # can never serve a kernel wrapped for the wrong dispatch mode.
@@ -822,9 +776,8 @@ def _build_kernel(cfg, B, use_pallas, kind: str = "v2"):
         misses0 = _build_kernel_cached.cache_info().misses
         t0 = time.monotonic_ns()
         try:
-            built = _build_kernel_cached(cfg, B, use_pallas, kind,
-                                         _n_devices(), _platform(),
-                                         colstep, m, banded)
+            built = _build_kernel_cached(cfg, B, use_pallas, _n_devices(),
+                                         _platform(), m, banded)
         except Exception as e:  # noqa: BLE001 — shard lattice edge
             if m <= 1:
                 raise
@@ -837,25 +790,16 @@ def _build_kernel(cfg, B, use_pallas, kind: str = "v2"):
                 rl.record_shard_demotion(None, kind, e)
             continue
         if _build_kernel_cached.cache_info().misses != misses0:
-            from . import cost_hooks
-
-            # predicted per-window bill for this geometry/tier, stamped
-            # next to the measured build wall (obs/costmodel.py)
-            pred = cost_hooks.record_build(
-                "build_lockstep_poa_kernel" if kind == "ls"
-                else "build_pallas_poa_kernel" if kind == "v2"
-                else "build_poa_kernel", (cfg,), {})
             obs.add_complete("kernel.build", t0, time.monotonic_ns(),
                              builder=f"poa.{kind}", B=B, shards=m,
-                             max_nodes=cfg.max_nodes, depth=cfg.depth,
-                             **pred)
+                             max_nodes=cfg.max_nodes, depth=cfg.depth)
             obs.count(f"kernel.builds.poa.{kind}")
         return built
 
 
 @functools.lru_cache(maxsize=64)
-def _build_kernel_cached(cfg, B, use_pallas, kind, n_dev, platform,
-                         colstep=True, shard_n=1, banded=False):
+def _build_kernel_cached(cfg, B, use_pallas, n_dev, platform, shard_n=1,
+                         banded=False):
     """Single- or multi-device kernel for a B-window batch.
 
     shard_n > 1: batch dim sharded over the partitioner's mesh (the
@@ -871,22 +815,17 @@ def _build_kernel_cached(cfg, B, use_pallas, kind, n_dev, platform,
     entries compiled under different machine features fail to load and
     silently recompile — minutes per geometry on the CPU twin).
     """
-    assert not (use_pallas and not _fits_vmem(cfg, kind)), (
+    assert not (use_pallas and not _fits_vmem(cfg)), (
         "caller must check _fits_vmem before requesting the pallas kernel")
     if use_pallas:
-        if kind == "ls":
-            from .poa_pallas_ls import build_lockstep_poa_kernel as build
-        else:
-            from .poa_pallas import build_pallas_poa_kernel as build
+        from .poa_pallas_ls import build_lockstep_poa_kernel as build
         interp = platform != "tpu"
         if shard_n <= 1:
-            return build(cfg, interpret=interp, colstep=colstep,
-                         band=banded)(B)
+            return build(cfg, interpret=interp, band=banded)(B)
         from ..parallel.partitioner import get_partitioner
         n_in, n_out = (10, 6) if banded else (9, 5)
         sharded = get_partitioner().shard_build(
-            lambda b: build(cfg, interpret=interp, colstep=colstep,
-                            band=banded)(b),
+            lambda b: build(cfg, interpret=interp, band=banded)(b),
             B, n_in, n_out)
         assert sharded is not None, (B, shard_n)  # _device_batch divides B
         return sharded

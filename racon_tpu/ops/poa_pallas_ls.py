@@ -1,16 +1,15 @@
 """Lane-lockstep fused Pallas POA kernel (v3).
 
-Same window-consensus semantics as the host oracle (rt_poa.cpp) and the v2
-kernel (poa_pallas.py), re-laid for VPU throughput. The v2 kernel runs ONE
-window per grid step; its DP inner loop is a serial dependency chain of
-~50 single-vreg ops at ~40 cycles/op of latency (measured: dp_cost_probe,
-docs/benchmarks.md), so the VPU idles most of the time. This kernel runs
-EIGHT windows per grid step in lock-step, one per sublane:
+Same window-consensus semantics as the host oracle (rt_poa.cpp) and the
+XLA twin (poa.py), laid out for VPU throughput. One window per grid step
+makes the DP inner loop a serial dependency chain of ~50 single-vreg ops
+at ~40 cycles/op of latency (measured: dp_cost_probe, docs/benchmarks.md),
+so the VPU idles most of the time. This kernel runs EIGHT windows per
+grid step in lock-step, one per sublane:
 
   * j-rows: (JC, 8, 128) — window g in sublane g, DP column j at
     [j // 128, g, j % 128]. Every row op serves all 8 windows at once,
-    and lane-only prefix scans replace the v2 layout's cross-sublane
-    carries.
+    with lane-only prefix scans.
   * The graph lives in RANK SPACE: arrays (NC, 8, 128) keyed by
     topological rank (= column-key order), with in-edges stored as rank
     DISTANCES (rk_delta). Node insertion is a lane shift; there are no
@@ -63,7 +62,7 @@ def _round_up(x, m):
 
 @device_keyed_cache(maxsize=32)
 def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
-                              colstep: bool = True, band: bool = False):
+                              band: bool = False):
     N = cfg.max_nodes
     L = cfg.max_len
     BB = cfg.max_backbone
@@ -80,7 +79,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
     # The banded build (band=True, RACON_TPU_BAND) adds one SMEM input
     # (wband: per-window half-band width, 0 = flat semantics through the
     # same compiled kernel) and one SMEM output (band_hit: the composite
-    # verify signal — see poa_pallas.py / ops/band.py).  Every band op
+    # verify signal — see ops/band.py).  Every band op
     # is gated on the Python-level `band` flag so the flat build's jaxpr
     # is unchanged.
     def kernel(*refs):
@@ -365,32 +364,25 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                         flush_wait((r + 1 - RING) // BLK)
                 return 0
 
-            if colstep:
-                # Rank-pair stepping (the lockstep variant of column
-                # compression, RACON_TPU_POA_COLSTEP): the 8 lanes hold
-                # unrelated windows so per-column pairing cannot line up
-                # across the sublane dimension — instead every serial
-                # iteration retires TWO consecutive ranks, halving the
-                # trip count. Ranks still execute strictly in order
-                # inside the body (rank r's ring row is written before
-                # rank r+1's delta scan reads it at d == 1), so the
-                # result is byte-identical to the serial loop. The flush
-                # schedule is untouched: rs64 and BLK are even, so the
-                # (r+1) % BLK == 0 trigger only ever fires on the second
-                # rank of a pair.
-                def pair_body(p, _):
-                    r = rs64 + 2 * p
-                    dp_body(r, 0)
+            # Rank-pair stepping: every serial iteration retires TWO
+            # consecutive ranks, halving the trip count. Ranks still
+            # execute strictly in order inside the body (rank r's ring
+            # row is written before rank r+1's delta scan reads it at
+            # d == 1), so the result is that of one rank per iteration.
+            # The flush schedule is untouched: rs64 and BLK are even, so
+            # the (r+1) % BLK == 0 trigger only ever fires on the second
+            # rank of a pair.
+            def pair_body(p, _):
+                r = rs64 + 2 * p
+                dp_body(r, 0)
 
-                    @pl.when(r + 1 < r_end)
-                    def _():
-                        dp_body(r + 1, 0)
+                @pl.when(r + 1 < r_end)
+                def _():
+                    dp_body(r + 1, 0)
 
-                    return 0
+                return 0
 
-                jax.lax.fori_loop(0, (r_end - rs64 + 1) // 2, pair_body, 0)
-            else:
-                jax.lax.fori_loop(rs64, r_end, dp_body, 0)
+            jax.lax.fori_loop(0, (r_end - rs64 + 1) // 2, pair_body, 0)
 
             # Every flush started is waited on exactly once: a DMA wait
             # with no matching start never returns on the chip (interpret
@@ -408,8 +400,8 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
 
             # ---- end-node selection -------------------------------------
             # rank r is an end node iff no in-subgraph node has an edge
-            # from it (v2 fused this into the DP; here one masked dynamic
-            # shift per distance serves every rank at once)
+            # from it (one masked dynamic shift per distance serves every
+            # rank at once)
             dmax_all = jnp.minimum(
                 jnp.max(jnp.where(in_sub, dmax_v, 0)), DMAX)
 

@@ -15,8 +15,6 @@ from typing import Optional, Sequence
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..device import named_like
-
 AXIS = "windows"
 
 def device_mesh(devices: Optional[Sequence] = None) -> Mesh:
@@ -31,27 +29,6 @@ def shard_batch_kernel(fn, mesh: Mesh, n_in: int):
     batch = NamedSharding(mesh, P(AXIS))
     return jax.jit(fn, in_shardings=(batch,) * n_in,
                    out_shardings=batch)
-
-
-def shard_batch_build(build_local, batch, n_in, n_out):
-    """Batch-stripe a per-shard kernel BUILD over the 1-D `windows` mesh:
-    `build_local(batch // n_devices)` is wrapped in shard_map with every
-    input/output sharded on the leading batch dim — zero collectives,
-    results gather host-side in order. The shared wrap for both pallas
-    drivers (consensus poa_driver._build_kernel, aligner align_pallas);
-    reference analogue: per-device accelerator batches
-    (src/cuda/cudapolisher.cpp:96-114, 228-240). Returns None when the
-    batch doesn't divide over >1 devices and the plain single-device jit
-    is the right call."""
-    n_dev = len(jax.devices())
-    if n_dev <= 1 or batch < n_dev or batch % n_dev:
-        return None
-    local = build_local(batch // n_dev)
-    out_specs = (P(AXIS),) * n_out if n_out > 1 else P(AXIS)
-    return jax.jit(jax.shard_map(
-        named_like(local), mesh=device_mesh(),
-        in_specs=(P(AXIS),) * n_in, out_specs=out_specs,
-        check_vma=False))
 
 
 def divisible_batch(n_devices: int, b: int) -> int:

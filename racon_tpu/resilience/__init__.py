@@ -9,7 +9,7 @@ that posture a tested subsystem instead of scattered try/except blocks:
 * `faults`  — named injection points at every device/host seam, driven by
   the `RACON_TPU_FAULT` env spec, so any lattice edge can be triggered
   deterministically on the CPU backend in CI.
-* `lattice` — the ordered degradation tiers (ls -> v2 -> xla -> host for
+* `lattice` — the ordered degradation tiers (ls -> xla -> host for
   consensus; hirschberg/xla -> host for alignment) plus the shared
   retry / watchdog / batch-bisection machinery the drivers run through.
 * `watchdog`— the deadline-scoped timer around device dispatch and the

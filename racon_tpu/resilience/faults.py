@@ -7,7 +7,7 @@ them:
 
     RACON_TPU_FAULT="poa.run.ls:raise=MosaicError"
     RACON_TPU_FAULT="poa.run.xla:window=5"
-    RACON_TPU_FAULT="align.run:batch=1:count=1,poa.run.v2:hang=2"
+    RACON_TPU_FAULT="align.run:batch=1:count=1,poa.run.xla:hang=2"
 
 Spec grammar (comma-separated specs; colon-separated fields):
 
@@ -71,10 +71,8 @@ KNOWN_POINTS = frozenset({
                          # deterministic widening-exhaustion drill that
                          # drives the ladder to its flat floor
     "poa.compile.ls",    # lockstep consensus kernel build
-    "poa.compile.v2",    # one-window consensus kernel build
     "poa.compile.xla",   # XLA-twin consensus kernel build
     "poa.run.ls",        # lockstep consensus, per submitted batch
-    "poa.run.v2",        # one-window consensus, per submitted batch
     "poa.run.xla",       # XLA-twin consensus, per submitted batch
     "native.call",       # host (native) engine calls — the lattice floor
     "window.export",     # per-window export from the native pipeline

@@ -9,7 +9,7 @@ explicit and deterministically testable via `resilience.faults`.
 
 Tier orders (best first; a tier's failure demotes to the next):
 
-    consensus:  ls -> v2 -> xla -> host
+    consensus:  ls -> xla -> host
     alignment:  hirschberg -> host,  xla -> host
                 (the entry tier is chosen by RACON_TPU_DEVICE_ALIGNER;
                 either device engine degrades straight to the host Myers
@@ -47,7 +47,7 @@ from .watchdog import (WatchdogTimeout, call_with_watchdog,  # noqa: F401
 
 #: Consensus kernel tiers, best first.  "host" is the floor: windows are
 #: re-polished one-by-one by the native SPOA-equivalent engine.
-CONSENSUS_TIERS = ("ls", "v2", "xla", "host")
+CONSENSUS_TIERS = ("ls", "xla", "host")
 
 #: Alignment tiers.  hirschberg and xla are alternative entry engines
 #: (RACON_TPU_DEVICE_ALIGNER); both degrade straight to the host Myers

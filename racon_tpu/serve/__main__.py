@@ -118,13 +118,6 @@ def main(argv=None) -> int:
     except ValueError as e:
         print(e, file=sys.stderr)
         return 1
-    if args.backend == "tpu":
-        from ..ops.poa_driver import _kernel_kind
-        try:
-            _kernel_kind()
-        except ValueError as e:
-            print(e, file=sys.stderr)
-            return 1
 
     from ..device import DeviceUnavailable
     try:

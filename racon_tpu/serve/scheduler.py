@@ -8,7 +8,7 @@ lane** is a second worker running demoted jobs as ``python -m
 racon_tpu.cli`` subprocesses — the CPU oracle produces byte-identical
 output, so a demotion changes *where* a job runs, never *what* it
 returns.  This extends the kernel degradation lattice one level up:
-where a window falls ls → v2 → xla → host, a whole job falls
+where a window falls ls → xla → host, a whole job falls
 device-lane → host-lane.
 
 Admission control bounds what the daemon will hold: a queue-depth cap on

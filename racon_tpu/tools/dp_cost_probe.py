@@ -1,9 +1,11 @@
 """Isolate the per-node cost of the fused POA kernel's DP loop on the
 current backend (meant for the real TPU).
 
-Builds stripped-down Pallas kernels that run the same shape of
-rank-ordered DP loop as poa_pallas.py, adding back one cost component per
-mode, and times each:
+Builds stripped-down Pallas kernels that run a rank-ordered DP loop,
+adding back one cost component per mode, and times each.  Modes 0-8 are
+the one-window-per-program row layout (the tier removed in PR 31; kept
+as the baseline that shows why the lockstep layout exists), modes 9-12
+and 17-18 the lockstep layout of poa_pallas_ls.py, 13-16 the aligner's:
 
   mode 0: H-row math only (shift + cummax + write), node index = loop rank
   mode 1: + dynamic node index via the masked `order` load
@@ -28,12 +30,9 @@ mode, and times each:
   mode 10: mode 9 + a depth-4 delta scan (4 ring-row loads, masked max)
           and 12 exr-style (1,8,128) graph-row loads per rank — the
           ls dp_body's per-rank load traffic
-  mode 11: mode 1 under the COLUMN-COMPRESSED while_loop (the v2
-          colstep path in poa_pallas.py) on synthetic multiplicity-2
-          column keys (key = rank // 2): adjacent same-column ranks
-          retire in one iteration, so the serial trip count halves
   mode 12: mode 9 under the ls RANK-PAIR loop (poa_pallas_ls.py
-          colstep path): two unconditional dp steps per iteration
+          pair_body): two unconditional dp steps per iteration (there
+          is no mode 11: it timed the removed tier's column pairing)
   mode 13: the aligner band-loop baseline — a (1, 128) band row carried
           in registers, one scalar query-code load (masked loadn) and
           one shift+select recurrence per DP row
@@ -53,16 +52,16 @@ mode, and times each:
           backbone column is read/scored/written (`pl.ds(cb0, CB)`
           windowed ring access) — 13/4 = 3.25x fewer in-loop cells
 
-mode 4 approximates the full v2 dp_body; mode 10 approximates the ls
-dp_body. The deltas between modes say which component to attack next;
+mode 4 approximates a full one-window dp_body; mode 10 approximates the
+ls dp_body. The deltas between modes say which component to attack next;
 per-node microseconds are printed for each.
 
 Every kernel also returns a MEASURED in-loop count via a second SMEM
 output — serial loop iterations for modes 0-14, scored DP cells for
 the banded modes 15-18 — and `--gate` compares the compressed modes
 against their baselines on those measured counts, exiting nonzero
-unless the ratios clear the floors (11 vs 1 and 12 vs 9: >= 1.5x
-steps; 14 vs 13: >= 2x steps; 16 vs 15 and 18 vs 17: >= 3x cells, the
+unless the ratios clear the floors (12 vs 9: >= 1.5x steps; 14 vs
+13: >= 2x steps; 16 vs 15 and 18 vs 17: >= 3x cells, the
 RACON_TPU_BAND acceptance floor for BOTH hot kernels).
 Interpret-mode safe: the gate measures counts, not wall time, so CI
 runs it on CPU.
@@ -328,7 +327,7 @@ def build(mode: int, R: int, B: int, interpret: bool):
                 H[pl.ds((r + 1) % RING, 1)] = row.reshape(1, JC, 8, 128)
 
             if mode == 12:
-                # the ls colstep path: two unconditional ranks per serial
+                # the ls pair loop: two unconditional ranks per serial
                 # iteration (poa_pallas_ls.py pair_body), trailing rank
                 # guarded for odd R
                 def pair_ls(p, c):
@@ -505,10 +504,7 @@ def build(mode: int, R: int, B: int, interpret: bool):
         # graph state init (content irrelevant; loads must be real)
         order[:] = nn_i
         base[:] = nn_i % 4
-        # mode 11: synthetic multiplicity-2 column keys — every adjacent
-        # rank pair shares a column, so the colstep loop runs at its 2x
-        # compression ceiling (the NODE_GROWTH=2.0 expectation)
-        key[:] = ((nn_i // 2) if mode == 11 else nn_i).astype(jnp.float32)
+        key[:] = nn_i.astype(jnp.float32)
         in_cnt[:] = jnp.where(nn_i > 0, 2, 0)
         in_src[:] = jnp.zeros((E, 8, NW), jnp.int32)
         in_src[0:1] = jnp.maximum(nn_i - 1, 0).reshape(1, 8, NW)
@@ -518,9 +514,8 @@ def build(mode: int, R: int, B: int, interpret: bool):
         H[0:1] = (gvec + seed_ref[0, 0, 0]).reshape(1, 8, JW)
 
         # modes 5 and 7 are row-math variants of mode 0: no graph-state
-        # machinery, or their deltas vs mode 0 would be confounded;
-        # mode 11 is mode 1's body under the column-compressed loop
-        level = 0 if mode in (5, 7) else 1 if mode == 11 else mode
+        # machinery, or their deltas vs mode 0 would be confounded
+        level = 0 if mode in (5, 7) else mode
 
         def dp_work(r):
             if level >= 1:
@@ -566,34 +561,11 @@ def build(mode: int, R: int, B: int, interpret: bool):
             row = cummaxj(V - gvec) + gvec
             H[pl.ds(u + 1, 1)] = row.reshape(1, 8, JW)
 
-        if mode == 11:
-            # the v2 colstep while_loop (poa_pallas.py): retire rank r,
-            # and r+1 too when it shares r's column key
-            def col_cond(c):
-                return c[0] < R
+        def dp(r, c):
+            dp_work(r)
+            return c + 1
 
-            def col_body(c):
-                r, s = c
-                dp_work(r)
-                ku = loadn(key[:], loadn(order[:], r))
-                k2 = loadn(key[:], loadn(order[:], r + 1))
-                pair = (r + 1 < R) & (k2 == ku)
-
-                @pl.when(pair)
-                def _():
-                    dp_work(r + 1)
-
-                return (r + 1 + pair.astype(jnp.int32), s + 1)
-
-            _, iters = jax.lax.while_loop(
-                col_cond, col_body, (jnp.int32(0), jnp.int32(0)))
-        else:
-            def dp(r, c):
-                dp_work(r)
-                return c + 1
-
-            iters = jax.lax.fori_loop(0, R, dp, 0)
-        steps_ref[0, 0, 0] = iters
+        steps_ref[0, 0, 0] = jax.lax.fori_loop(0, R, dp, 0)
         # tap two lanes: a single lane can legitimately saturate to NEG in
         # the stripped-down modes, which would false-positive the seed check
         hr = H[pl.ds(R, 1)][0]
@@ -648,8 +620,7 @@ def gate(R: int = 32, B: int = 1) -> bool:
         jax.block_until_ready(steps)
         return int(np.asarray(steps)[0, 0, 0])
 
-    checks = (("poa-v2 colstep", 1, 11, 1.5, "serial steps"),
-              ("poa-ls rank-pair", 9, 12, 1.5, "serial steps"),
+    checks = (("poa-ls rank-pair", 9, 12, 1.5, "serial steps"),
               ("align row-pack", 13, 14, 2.0, "serial steps"),
               ("align banded-band", 15, 16, 3.0, "in-loop cells"),
               ("poa banded-window", 17, 18, 3.0, "in-loop cells"))
@@ -683,7 +654,7 @@ def main():
     interp = platform != "tpu"
     print(f"platform={platform} R={R} B={B}")
     prev = 0.0
-    for mode in range(19):
+    for mode in (m for m in range(19) if m != 11):   # there is no mode 11
         fn = build(mode, R, B, interp)
         seed = np.zeros((B, 1, 1), np.int32)
         t0 = time.time()

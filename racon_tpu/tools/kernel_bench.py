@@ -8,6 +8,7 @@ the way real data does — unlike a substitution-only batch, which never
 allocates insertion columns.
 
 Usage: python racon_tpu/tools/kernel_bench.py [batch] [depth] [iters]
+(batch: a multiple of the lockstep group, 8)
 """
 
 import os
@@ -71,12 +72,12 @@ def main():
 
     import jax
 
-    from racon_tpu.ops import poa_driver, poa_pallas
+    from racon_tpu.ops import poa_driver, poa_pallas_ls
 
     platform = jax.devices()[0].platform
     cfg = poa_driver.make_config(500, depth, 5, -4, -8)
     interp = platform != "tpu"
-    fn = poa_pallas.build_pallas_poa_kernel(cfg, interpret=interp)(B)
+    fn = poa_pallas_ls.build_lockstep_poa_kernel(cfg, interpret=interp)(B)
 
     rng = np.random.default_rng(0)
     bb, bbw, bl, nl, seqs, ws, lens, bg, en = make_batch(cfg, B, rng)

@@ -93,14 +93,13 @@ def measure(mesh_n: int, repeats: int) -> dict:
     use_pallas = tier != "xla"
     cfg = poa.PoaConfig(max_nodes=256, max_len=128, max_backbone=128,
                         max_edges=8, depth=4, match=5, mismatch=-4, gap=-8)
-    B = poa_driver._device_batch(tier)
+    B = poa_driver._device_batch(use_pallas)
     args = g._example_batch(cfg, B, np.random.default_rng(0))
     part = get_partitioner()
     shards = part.batch_axis_size if part.will_shard(B) else 1
 
     t0 = time.monotonic()
-    kern = poa_driver._build_kernel(cfg, B, use_pallas,
-                                    tier if use_pallas else "v2")
+    kern = poa_driver._build_kernel(cfg, B, use_pallas)
     res = poa_driver._unpack(poa_driver._submit(kern, args, use_pallas),
                              use_pallas)
     compile_s = time.monotonic() - t0

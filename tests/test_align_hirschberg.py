@@ -383,13 +383,11 @@ def test_one_row_task_beside_a_full_one(backward):
     bands = {0: (K, -100), 1: (K, -100)}
     tasks = [align_pallas._Task(0, 0, rcap, 0, len(pairs[0][1])),
              align_pallas._Task(1, 0, 1, 0, 2)]
-    kern = align_pallas._build_edge_kernel(rcap, K, backward, True,
-                                           align_pallas._pack_factor())
+    kern = align_pallas._build_edge_kernel(rcap, K, backward, True)
 
     def run(slots):
         args = align_pallas._task_arrays(pairs, slots, bands, rcap, K,
-                                         backward,
-                                         align_pallas._pack_factor())
+                                         backward)
         return np.asarray(kern(len(slots))(*args))
 
     both = run(tasks + [None] * 6)

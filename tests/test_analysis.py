@@ -201,6 +201,37 @@ def test_unknown_env_knobs_detects_typos():
     assert config.unknown_env_knobs({"RACON_TPU_PALLAS": "1"}) == []
 
 
+@pytest.mark.parametrize("name", [
+    "RACON_TPU_POA_KERNEL", "RACON_TPU_POA_COLSTEP", "RACON_TPU_ALIGN_PACK",
+    "RACON_TPU_COST_MODEL"])
+def test_removed_knobs_are_reported(tmp_path, monkeypatch, capsys, name):
+    """The knobs PR 31 removed (a selector with one tier left to select,
+    two kill switches every use passed one value to, a stamp nothing
+    read): one that is still set is reported as unknown, in the
+    registry's check and on the CLI's warning line, and changes
+    nothing."""
+    from racon_tpu import cli
+
+    assert name not in config.KNOBS
+    assert config.unknown_env_knobs({name: "0", "RACON_TPU_PALLAS": "1"}) \
+        == [name]
+
+    target = "ACGT" * 30
+    (tmp_path / "t.fasta").write_text(f">t\n{target}\n")
+    (tmp_path / "r.fasta").write_text(
+        "".join(f">r{i}\n{target}\n" for i in range(3)))
+    (tmp_path / "o.sam").write_text("@HD\tVN:1.6\n" + "".join(
+        f"r{i}\t0\tt\t1\t60\t{len(target)}M\t*\t0\t0\t{target}\t*\n"
+        for i in range(3)))
+    monkeypatch.setenv(name, "0")
+    assert cli.main([str(tmp_path / "r.fasta"), str(tmp_path / "o.sam"),
+                     str(tmp_path / "t.fasta")]) == 0
+    out, err = capsys.readouterr()
+    assert target in out
+    assert (f"unknown RACON_TPU_* environment variable(s) ignored: {name} "
+            in err)
+
+
 def test_run_report_surfaces_stale_knobs(monkeypatch):
     from racon_tpu.resilience.report import RunReport
 

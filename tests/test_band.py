@@ -283,21 +283,17 @@ def _poa_batch(cfg, B, seed, roll=0):
     return (bb, bbw, bl, nl, seqs, ws, lens, bg, en)
 
 
-@pytest.mark.parametrize("kernel", ["v2", "ls"])
-def test_poa_banded_kernel_byte_identity(kernel):
-    """Both banded POA builds: wband=0 reproduces the flat kernel
+def test_poa_banded_kernel_byte_identity():
+    """The banded POA build: wband=0 reproduces the flat kernel
     byte-for-byte (the ladder's floor runs through the same compiled
     build), a generous band matches the flat oracle with no hit, and a
     pathologically narrow band on drifted layers raises band_hit."""
     from racon_tpu.ops import poa, poa_driver
-    from racon_tpu.ops.poa_pallas import build_pallas_poa_kernel
-    from racon_tpu.ops.poa_pallas_ls import build_lockstep_poa_kernel
+    from racon_tpu.ops.poa_pallas_ls import build_lockstep_poa_kernel as build
 
     cfg = poa.PoaConfig(max_nodes=256, max_len=128, max_backbone=128,
                         max_edges=8, depth=4, match=5, mismatch=-4, gap=-8)
-    build = (build_pallas_poa_kernel if kernel == "v2"
-             else build_lockstep_poa_kernel)
-    B = 8 if kernel == "ls" else 2
+    B = 8
     flat = build(cfg, interpret=True)(B)
     banded = build(cfg, interpret=True, band=True)(B)
 
@@ -355,12 +351,11 @@ def _polish(tmp_path):
 
 
 def test_poa_banded_driver_byte_identity(tmp_path, monkeypatch):
-    """RACON_TPU_BAND=1 through the full consensus driver (pallas v2,
+    """RACON_TPU_BAND=1 through the full consensus driver (pallas ls,
     interpret): polished output byte-identical to the flat run, banded
     windows counted."""
     target = _polish_dataset(tmp_path)
     monkeypatch.setenv("RACON_TPU_PALLAS", "1")
-    monkeypatch.setenv("RACON_TPU_POA_KERNEL", "v2")
     monkeypatch.setenv("RACON_TPU_BATCH_WINDOWS", "4")
 
     monkeypatch.setenv("RACON_TPU_BAND", "0")
@@ -390,7 +385,6 @@ def test_poa_banded_fault_drill_exhausts_ladder(tmp_path, monkeypatch):
 
     target = _polish_dataset(tmp_path)
     monkeypatch.setenv("RACON_TPU_PALLAS", "1")
-    monkeypatch.setenv("RACON_TPU_POA_KERNEL", "v2")
     monkeypatch.setenv("RACON_TPU_BATCH_WINDOWS", "4")
 
     monkeypatch.setenv("RACON_TPU_BAND", "0")

@@ -288,7 +288,7 @@ def test_surrender_and_quarantine_share_the_overlapped_fallback(
     spy(monkeypatch, Pipeline, "consensus_cpu_one", external)
     spy(monkeypatch, poa_driver._ConsensusOps, "surrender", surrenders,
         key=lambda ctx, items, exported: (len(items), exported))
-    for k, v in {"RACON_TPU_PALLAS": "0", "RACON_TPU_POA_KERNEL": "v2",
+    for k, v in {"RACON_TPU_PALLAS": "0",
                  "RACON_TPU_BATCH_WINDOWS": "8",
                  # window 2 poisons chunk 0 alone: bisected, quarantined;
                  # 9 and 13 sit in opposite halves of chunk 1: tier dead
@@ -382,7 +382,7 @@ def test_kill_resume_through_executor(tmp_path):
                "-w", "100", "-q", "10", "-e", "0.3",
                "-m", "5", "-x", "-4", "-g", "-8", *extra, *paths]
         full_env = dict(os.environ, JAX_PLATFORMS="cpu",
-                        RACON_TPU_PALLAS="0", RACON_TPU_POA_KERNEL="v2",
+                        RACON_TPU_PALLAS="0",
                         RACON_TPU_BATCH_WINDOWS="2")
         full_env.pop("RACON_TPU_FAULT", None)
         # conftest's 8-virtual-device XLA_FLAGS would round the 2-window

@@ -106,19 +106,19 @@ def test_warm_up_demotions_land_in_the_run_report(tmp_path, monkeypatch):
     from racon_tpu.ops import poa_driver
 
     paths = _write_dataset(tmp_path)
-    for k, v in {"RACON_TPU_PALLAS": "1", "RACON_TPU_POA_KERNEL": "v2",
+    for k, v in {"RACON_TPU_PALLAS": "1",
                  "RACON_TPU_BATCH_WINDOWS": "8"}.items():
         monkeypatch.setenv(k, v)
     cfg = poa_driver.make_config(128, 8, 5, -4, -8)
-    monkeypatch.setitem(poa_driver._WARM_DEAD, (cfg, "v2"),
+    monkeypatch.setitem(poa_driver._WARM_DEAD, (cfg, "ls"),
                         RuntimeError("mosaic said no"))
     p = racon_tpu.create_polisher(*paths, backend="tpu", **_ARGS)
     p.initialize()
     p.polish(True)
     cons = p.report.as_dict()["phases"]["consensus"]
-    assert {"from": "v2", "to": "xla",
+    assert {"from": "ls", "to": "xla",
             "error": "RuntimeError: mosaic said no"} in cons["degradations"]
-    assert cons["served"]["v2"] == 0 and cons["served"]["xla"] > 0
+    assert cons["served"]["ls"] == 0 and cons["served"]["xla"] > 0
 
 
 # -- one compile cache -----------------------------------------------------
@@ -180,7 +180,7 @@ def _report(**over):
             "consensus": {
                 "total": 1000, "retries": 0, "bisections": 0,
                 "quarantined": [], "degradations": [],
-                "served": {"ls": 990, "v2": 0, "xla": 0, "host": 8,
+                "served": {"ls": 990, "xla": 0, "host": 8,
                            "backbone": 2, "journal": 0},
                 "extra": {"device_rejected": 8, "kernels": {
                     "interpreted": False, "batch": 64, "shards": 1}}},
@@ -208,19 +208,19 @@ def test_judge_passes_a_clean_report():
                                    warm=True) == []
 
 
-def test_judge_fails_ls_at_zero_with_v2_serving():
-    """The failure this PR exists to prevent: the run exits 0 with
-    correct output while every geometry was demoted to v2."""
+def test_judge_fails_ls_at_zero_with_xla_serving():
+    """The failure the smoke exists to prevent: the run exits 0 with
+    correct output while every geometry was demoted to the XLA twin."""
     bad = chip_smoke.judge_report(_report(**{
-        "phases/consensus/served": {"ls": 0, "v2": 990, "xla": 0,
-                                    "host": 8, "backbone": 2},
+        "phases/consensus/served": {"ls": 0, "xla": 990, "host": 8,
+                                    "backbone": 2},
         "phases/consensus/degradations": [
-            {"from": "ls", "to": "v2", "error": "ValueError: smem"}],
-        "obs/metrics/counters": {"kernel.builds.poa.v2": 3}}),
+            {"from": "ls", "to": "xla", "error": "ValueError: smem"}],
+        "obs/metrics/counters": {"kernel.builds.poa.xla": 3}}),
         alignment=True)
     text = json.dumps(bad)
-    assert "ls served 0" in text and "tier v2 served 990" in text
-    assert "degraded" in text and "v2 POA kernel was built" in text
+    assert "ls served 0" in text and "tier xla served 990" in text
+    assert "degraded" in text
 
 
 @pytest.mark.parametrize("over,needle", [

@@ -33,8 +33,7 @@ def test_grid_constants_match_ops_modules():
     assert costmodel.AUDIT_WINDOW_LENGTHS == poa_driver.AUDIT_WINDOW_LENGTHS
     assert costmodel.ALIGN_BUCKETS == align.BUCKETS
     assert costmodel.LS_GROUP == poa_pallas_ls.G
-    from racon_tpu.ops import colstep, encoding
-    assert costmodel.POA_COLSTEP_PACK == colstep.PACK
+    from racon_tpu.ops import encoding
     assert costmodel.ALIGN_ROW_PACK == encoding.PACK
     from racon_tpu import config
     from racon_tpu.ops import band
@@ -63,20 +62,15 @@ def test_roofline_picks_the_dominant_term():
     assert costmodel.roofline(serial_heavy, CPU)[1] == "serial-step-bound"
 
 
-def test_ls_tier_amortizes_serial_steps_by_group():
-    v2 = costmodel.poa_window_cost(32, 512, "v2")
-    ls = costmodel.poa_window_cost(32, 512, "ls")
-    assert ls.flops == v2.flops and ls.hbm_bytes == v2.hbm_bytes
-    assert v2.serial_steps == ls.serial_steps * costmodel.LS_GROUP
-
-
-def test_colstep_pack_divides_pallas_tier_serial_steps():
-    """Column compression only helps the Pallas loops; the XLA twin
-    still retires one rank per scan step."""
+def test_ls_tier_divides_serial_steps_by_pair_and_group():
+    """The lockstep kernel retires a rank pair per iteration for a group
+    of windows at once; the XLA twin still retires one rank of one
+    window per scan step.  The cell work is the same."""
     xla = costmodel.poa_window_cost(32, 512, "xla")
-    v2 = costmodel.poa_window_cost(32, 512, "v2")
-    assert xla.serial_steps == v2.serial_steps * costmodel.POA_COLSTEP_PACK
-    assert xla.flops == v2.flops and xla.hbm_bytes == v2.hbm_bytes
+    ls = costmodel.poa_window_cost(32, 512, "ls")
+    assert xla.serial_steps == (ls.serial_steps * costmodel.POA_RANK_PACK
+                                * costmodel.LS_GROUP)
+    assert xla.flops == ls.flops and xla.hbm_bytes == ls.hbm_bytes
 
 
 def test_row_pack_divides_hirschberg_serial_steps():
@@ -93,14 +87,14 @@ def test_banded_closed_forms_cut_cells_not_serial_steps():
     assert nar.flops * 2 == flat.flops
     assert costmodel.banded_cell_ratio("align", band=256, k=128) == 2.0
 
-    pf = costmodel.poa_window_cost(8, 512, "v2")
-    pb = costmodel.banded_poa_window_cost(8, 512, 8, "v2")
+    pf = costmodel.poa_window_cost(8, 512, "ls")
+    pb = costmodel.banded_poa_window_cost(8, 512, 8, "ls")
     assert pb.serial_steps == pf.serial_steps
     assert pb.hbm_bytes == pf.hbm_bytes      # layers stream in either way
     assert pb.flops == pf.flops * 17 / 512   # 2w+1 live columns
     assert costmodel.banded_cell_ratio("poa", wl_class=512, w=8) == 512 / 17
     # a band wider than the class floors at the flat bill
-    wide = costmodel.banded_poa_window_cost(8, 512, 10_000, "v2")
+    wide = costmodel.banded_poa_window_cost(8, 512, 10_000, "ls")
     assert wide.flops == pf.flops
     assert costmodel.banded_cell_ratio("poa", wl_class=512, w=10_000) == 1.0
 
@@ -111,7 +105,7 @@ def test_predict_emits_banded_info_rows_without_double_count():
                 "align.cells.total": 10_000_000,
                 "poa.cells.d8.c128": 1024,
                 "poa.cells.banded": 400_000,
-                "served.consensus.v2": 4}
+                "served.consensus.ls": 4}
     pred = costmodel.predict_from_counters(counters, CPU)
     banded = [b for b in pred["buckets"] if b["kind"] == "banded"]
     assert {b["phase"] for b in banded} == {"align", "poa"}
@@ -122,10 +116,10 @@ def test_predict_emits_banded_info_rows_without_double_count():
 
 
 def test_poa_window_cost_scales_with_depth_and_class():
-    small = costmodel.poa_window_cost(8, 128, "v2")
-    deep = costmodel.poa_window_cost(32, 128, "v2")
+    small = costmodel.poa_window_cost(8, 128, "ls")
+    deep = costmodel.poa_window_cost(32, 128, "ls")
     assert deep.flops == pytest.approx(small.flops * 4)
-    wide = costmodel.poa_window_cost(8, 256, "v2")
+    wide = costmodel.poa_window_cost(8, 256, "ls")
     assert wide.flops == pytest.approx(small.flops * 4)  # ranks x length
 
 
@@ -133,7 +127,7 @@ def test_tpu_poa_bucket_is_serial_step_bound():
     """The measured 0.188x story: the rank loop's latency chain, not
     FLOPs, dominates on the TPU profile — the prediction that justifies
     ROADMAP's next optimization target."""
-    est = costmodel.poa_window_cost(32, 512, "v2")
+    est = costmodel.poa_window_cost(32, 512, "ls")
     _, verdict = costmodel.roofline(est, TPU)
     assert verdict == "serial-step-bound"
 
@@ -166,7 +160,7 @@ def test_profile_lookup_and_auto_resolution():
 
 def _counters(device=True):
     c = {
-        "served.consensus.v2": 90, "served.consensus.host": 10,
+        "served.consensus.ls": 90, "served.consensus.host": 10,
         "poa.windows.d32.c512": 100,
         # 100 windows, ~30 admitted layers each, class 512
         "poa.cells.d32.c512": 100 * 30 * 512,
@@ -176,17 +170,17 @@ def _counters(device=True):
     }
     if not device:
         c["served.consensus.host"] = 100
-        del c["served.consensus.v2"]
+        del c["served.consensus.ls"]
     return c
 
 
 def test_predict_from_counters_builds_phases_and_buckets():
     pred = costmodel.predict_from_counters(_counters(), CPU)
     assert set(pred["phases"]) == {"poa", "align"}
-    assert pred["phases"]["poa"]["tier"] == "v2"
+    assert pred["phases"]["poa"]["tier"] == "ls"
     assert pred["phases"]["poa"]["predicted_s"] > 0.0
     kinds = {(b["kind"], b.get("tier")) for b in pred["buckets"]}
-    assert ("poa", "v2") in kinds and ("align", "xla") in kinds
+    assert ("poa", "ls") in kinds and ("align", "xla") in kinds
     poa_b = next(b for b in pred["buckets"] if b["kind"] == "poa")
     # measured steps at growth 1, scaled by NODE_GROWTH ranks, x class
     assert poa_b["cells"] == pytest.approx(
@@ -284,7 +278,7 @@ def test_bench_cost_model_none_when_metrics_disarmed():
 
 def _entry(src, value, vs=0.2, pw=None, **kw):
     e = {"mbp": 0.5, "input": "paf", "profile": "ont", "unit": "Mbp/s",
-         "value": value, "vs_baseline": vs, "kernel": "v2",
+         "value": value, "vs_baseline": vs, "kernel": "ls",
          "_source": src}
     if pw is not None:
         e["phase_wall"] = pw
@@ -442,47 +436,18 @@ def test_cli_legacy_flags_still_dispatch(tmp_path):
     assert obs_cli.main(["--validate", str(p)]) == 0
 
 
-# ------------------------------------------------- ops-side cost hooks
+# ------------------------------------------------ serial-step gate (CI)
 
-def test_cost_hooks_estimate_maps_builders():
-    from racon_tpu.ops import cost_hooks, poa_driver
+def test_probe_serial_step_gate(capsys):
+    """The dp_cost_probe gate: measured in-loop counts of the compressed
+    modes vs their baselines must clear the floors (>= 1.5x serial steps
+    for the lockstep POA shape, >= 2x for the packed aligner, >= 3x
+    in-loop cells for the two banded pairs)."""
+    from racon_tpu.tools import dp_cost_probe
 
-    cfg = poa_driver.make_config(500, 32, 5, -4, -8)
-    est = cost_hooks.estimate("build_poa_kernel", (cfg,), {})
-    assert est == costmodel.poa_window_cost(32, cfg.max_backbone, "xla")
-    est_ls = cost_hooks.estimate("build_lockstep_poa_kernel", (cfg,), {})
-    # xla keeps the one-rank-per-step scan; the ls tier amortizes by
-    # LS_GROUP *and* pairs ranks via column compression
-    assert (est_ls.serial_steps * costmodel.LS_GROUP
-            * costmodel.POA_COLSTEP_PACK == est.serial_steps)
-    est_a = cost_hooks.estimate("build_align_kernel", (1024, 256), {})
-    assert est_a == costmodel.align_job_cost(1024, 256, "xla")
-    assert cost_hooks.estimate("build_mystery_kernel", (1,), {}) is None
-    assert cost_hooks.estimate("build_align_kernel", (), {}) is None
-
-
-def test_cost_hooks_record_build_requires_armed_obs(monkeypatch):
-    from racon_tpu import obs
-    from racon_tpu.ops import cost_hooks, poa_driver
-
-    cost_hooks.reset()
-    obs.reset()
-    assert cost_hooks.record_build("build_align_kernel",
-                                   (1024, 256), {}) == {}
-    monkeypatch.setenv("RACON_TPU_METRICS", "1")
-    obs.configure()
-    try:
-        pred = cost_hooks.record_build("build_align_kernel", (1024, 256),
-                                       {})
-        assert set(pred) == {"pred_flops", "pred_hbm_bytes",
-                             "pred_serial_steps"}
-        assert cost_hooks.builds()[-1]["builder"] == "build_align_kernel"
-        snap = obs.snapshot()
-        assert snap["counters"]["cost_model.builds.build_align_kernel"] == 1
-        # the knob kills the stamp even when obs is armed
-        monkeypatch.setenv("RACON_TPU_COST_MODEL", "0")
-        assert cost_hooks.record_build("build_align_kernel", (1024, 256),
-                                       {}) == {}
-    finally:
-        cost_hooks.reset()
-        obs.reset()
+    assert dp_cost_probe.gate()
+    out = capsys.readouterr().out
+    assert out.count("OK") == 4 and "FAIL" not in out
+    assert "poa-ls rank-pair" in out
+    assert out.count("in-loop cells") == 2
+    assert "measured ratio" in out

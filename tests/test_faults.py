@@ -7,7 +7,7 @@ Edges covered here: xla -> host (tier death), bisect-quarantine (poisoned
 window), transient retry, watchdog timeout, window-export quarantine,
 hirschberg -> host (engine death mid-phase, served count preserved —
 ADVICE.md), and — in a bounded single-device subprocess, where the pallas
-tiers can build — ls -> v2 -> xla.
+kernel builds unsharded — ls -> xla.
 """
 
 import json
@@ -30,9 +30,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def test_parse_spec_valid():
     specs = faults.parse_spec(
         "poa.run.ls:batch=2:raise=MosaicError, align.run:window=5:count=1,"
-        "poa.run.v2:hang=0.5")
+        "poa.run.xla:hang=0.5")
     assert [s.point for s in specs] == ["poa.run.ls", "align.run",
-                                       "poa.run.v2"]
+                                       "poa.run.xla"]
     assert specs[0].batch == 2 and specs[0].raise_name == "MosaicError"
     assert specs[1].window == 5 and specs[1].count == 1
     assert specs[2].hang == 0.5
@@ -53,16 +53,16 @@ def test_parse_spec_malformed(bad):
 
 
 def test_check_fires_and_counts(monkeypatch):
-    monkeypatch.setenv("RACON_TPU_FAULT", "poa.run.v2:batch=1:count=1")
+    monkeypatch.setenv("RACON_TPU_FAULT", "poa.run.ls:batch=1:count=1")
     faults.reset()
-    faults.check("poa.run.v2")                     # invocation 0: no fire
+    faults.check("poa.run.ls")                     # invocation 0: no fire
     with pytest.raises(faults.MosaicError):
-        faults.check("poa.run.v2")                 # invocation 1: fires
-    faults.check("poa.run.v2")                     # spent
+        faults.check("poa.run.ls")                 # invocation 1: fires
+    faults.check("poa.run.ls")                     # spent
     faults.reset()                                 # fresh schedule
-    faults.check("poa.run.v2")
+    faults.check("poa.run.ls")
     with pytest.raises(faults.MosaicError):
-        faults.check("poa.run.v2")
+        faults.check("poa.run.ls")
 
 
 # ------------------------------------------------------------- unit: lattice
@@ -173,8 +173,7 @@ def _oracle(paths):
 
 
 def _tpu_run(paths, monkeypatch, env):
-    base = {"RACON_TPU_PALLAS": "0", "RACON_TPU_POA_KERNEL": "v2",
-            "RACON_TPU_BATCH_WINDOWS": "8"}
+    base = {"RACON_TPU_PALLAS": "0", "RACON_TPU_BATCH_WINDOWS": "8"}
     for k, v in {**base, **env}.items():
         monkeypatch.setenv(k, v)
     p = racon_tpu.create_polisher(*paths, backend="tpu", **_ARGS)
@@ -371,11 +370,11 @@ def test_native_call_fault_surfaces(tmp_path, monkeypatch):
 
 # ------------------------------------- pallas tiers (single-device subproc)
 
-def test_pallas_chain_ls_v2_xla(tmp_path):
-    """ls -> v2 -> xla, in a single-device subprocess (the in-process
-    8-virtual-device mesh can't build the sharded pallas kernels here).
-    Both pallas run points are killed; the chunk must degrade through v2
-    to the XLA twin and the output must match the host oracle."""
+def test_pallas_chain_ls_xla(tmp_path):
+    """ls -> xla, in a single-device subprocess (one lockstep group, not
+    the eight the in-process 8-virtual-device mesh would interpret).
+    The pallas run point is killed; the chunk must degrade to the XLA
+    twin and the output must match the host oracle."""
     paths = _write_dataset(tmp_path)
     code = f"""
 import sys
@@ -393,9 +392,8 @@ oracle = p0.polish(True)
 
 import os
 os.environ["RACON_TPU_PALLAS"] = "1"
-os.environ["RACON_TPU_POA_KERNEL"] = "ls"
 os.environ["RACON_TPU_BATCH_WINDOWS"] = "8"
-os.environ["RACON_TPU_FAULT"] = "poa.run.ls,poa.run.v2"
+os.environ["RACON_TPU_FAULT"] = "poa.run.ls"
 p = racon_tpu.create_polisher(*paths, backend="tpu", **args)
 p.initialize()
 res = p.polish(True)
@@ -404,8 +402,7 @@ d = p.report.as_dict()
 cons = d["phases"]["consensus"]
 assert sum(cons["served"].values()) == cons["total"], cons
 edges = {{(dg["from"], dg["to"]) for dg in cons["degradations"]}}
-assert ("ls", "v2") in edges, edges
-assert ("v2", "xla") in edges, edges
+assert ("ls", "xla") in edges, edges
 assert cons["served"]["xla"] == cons["total"], cons
 print("PALLAS-CHAIN-OK", json.dumps(cons["served"]))
 """
@@ -416,10 +413,10 @@ print("PALLAS-CHAIN-OK", json.dumps(cons["served"]))
 
 
 def test_pallas_compile_faults_chain_to_xla(tmp_path):
-    """poa.compile.ls / poa.compile.v2: both pallas kernel *builds* are
-    killed at the compile seam; the chunk must degrade ls -> v2 -> xla
-    and the output must match the host oracle (compile-seam twins of
-    the run-seam chain above)."""
+    """poa.compile.ls: the pallas kernel *build* is killed at the
+    compile seam; the chunk must degrade ls -> xla and the output must
+    match the host oracle (compile-seam twin of the run-seam chain
+    above)."""
     paths = _write_dataset(tmp_path)
     code = f"""
 import sys
@@ -437,9 +434,8 @@ oracle = p0.polish(True)
 
 import os
 os.environ["RACON_TPU_PALLAS"] = "1"
-os.environ["RACON_TPU_POA_KERNEL"] = "ls"
 os.environ["RACON_TPU_BATCH_WINDOWS"] = "8"
-os.environ["RACON_TPU_FAULT"] = "poa.compile.ls,poa.compile.v2"
+os.environ["RACON_TPU_FAULT"] = "poa.compile.ls"
 p = racon_tpu.create_polisher(*paths, backend="tpu", **args)
 p.initialize()
 res = p.polish(True)
@@ -448,8 +444,7 @@ d = p.report.as_dict()
 cons = d["phases"]["consensus"]
 assert sum(cons["served"].values()) == cons["total"], cons
 edges = {{(dg["from"], dg["to"]) for dg in cons["degradations"]}}
-assert ("ls", "v2") in edges, edges
-assert ("v2", "xla") in edges, edges
+assert ("ls", "xla") in edges, edges
 assert cons["served"]["xla"] == cons["total"], cons
 print("COMPILE-CHAIN-OK", json.dumps(cons["served"]))
 """
@@ -457,3 +452,22 @@ print("COMPILE-CHAIN-OK", json.dumps(cons["served"]))
                        text=True, timeout=570)
     assert r.returncode == 0, r.stderr[-3000:]
     assert "COMPILE-CHAIN-OK" in r.stdout
+
+
+def test_polish_byte_identical_under_fault_demotion(tmp_path, monkeypatch):
+    """End-to-end polish with the lockstep kernel serving (interpreted,
+    one device), one window poisoned via RACON_TPU_FAULT: it is
+    bisected out and quarantined to the host, the rest stay on the
+    kernel, and the polished output stays byte-identical to the CPU
+    oracle."""
+    paths = _write_dataset(tmp_path)
+    oracle = _oracle(paths)
+    res, p = _tpu_run(paths, monkeypatch, {
+        "RACON_TPU_PALLAS": "1", "RACON_TPU_SHARD": "0",
+        "RACON_TPU_FAULT": "poa.run.ls:window=2",
+    })
+    assert res == oracle
+    d = _assert_report_sums(p)
+    cons = d["phases"]["consensus"]
+    assert cons["served"]["ls"] == 5 and cons["served"]["host"] == 1
+    assert cons["quarantined"] == [2]

@@ -37,8 +37,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _ARGS = dict(window_length=100, quality_threshold=10, error_threshold=0.3,
              match=5, mismatch=-4, gap=-8, num_threads=1)
 
-_FAST_ENV = {"RACON_TPU_PALLAS": "0", "RACON_TPU_POA_KERNEL": "v2",
-             "RACON_TPU_BATCH_WINDOWS": "8"}
+_FAST_ENV = {"RACON_TPU_PALLAS": "0", "RACON_TPU_BATCH_WINDOWS": "8"}
 
 
 def _write_dataset(tmp_path, n_targets=3, n_reads=4, seed=11):

@@ -180,14 +180,10 @@ def test_device_path_golden(name, lambda_reference, monkeypatch):
             pytest.skip("interpret-mode device golden runs only the 'paf' "
                         "scenario (hours per scenario on a 1-core host); "
                         "full coverage is the RACON_TPU_HW_TESTS=1 branch")
-        # v2 tier: under this suite's 8-virtual-device mesh the ls tier's
-        # interpret λ run blows past 25 minutes (64-window sharded chunks),
-        # while standalone on one device it takes 197 s and lands on 1282
-        # — the exact round-2 hardware pin, 92/96 windows device-served
-        # (measured 2026-07-30, docs/benchmarks.md). ls interpret
-        # correctness is pinned by tests/test_pallas_ls.py; this branch
-        # checks the driver + band.
-        monkeypatch.setenv("RACON_TPU_POA_KERNEL", "v2")
+        # Off the chip the XLA twin serves (Pallas defaults off there):
+        # this branch checks the driver + band.  The interpreted ls
+        # kernel's λ run is tests/test_ls_band.py (one device, nightly);
+        # its correctness is pinned by tests/test_pallas_ls.py.
         res = run_scenario(name, backend="tpu")
         ed = ed_vs_reference(res, lambda_reference)
         assert abs(ed - gs.HOST_POLISH["paf"]) <= 15, ed

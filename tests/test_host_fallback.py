@@ -271,7 +271,7 @@ def _polish(paths, journal_path, threads):
 def test_overlapped_fallback_matches_the_serial_loop_byte_for_byte(
         tmp_path, monkeypatch, threads):
     paths = _noisy_dataset(tmp_path)
-    for k, v in {"RACON_TPU_PALLAS": "0", "RACON_TPU_POA_KERNEL": "v2",
+    for k, v in {"RACON_TPU_PALLAS": "0",
                  "RACON_TPU_BATCH_WINDOWS": "8"}.items():
         monkeypatch.setenv(k, v)
     _reject_chosen_windows(monkeypatch)

@@ -1,10 +1,10 @@
 """Nightly end-to-end band for the production ls kernel tier.
 
-The shipped consensus default (RACON_TPU_POA_KERNEL=ls, the lane-lockstep
-Pallas kernel) must be exercised end to end on real data recurringly —
-otherwise a regression in the ls driver plumbing would surface only via
-the component differentials (the quick suite's interpret λ band pins the
-v2 tier, tests/test_golden.py). Reference analogue: the upstream suite
+The shipped consensus kernel (ls, the lane-lockstep Pallas kernel) must
+be exercised end to end on real data recurringly — otherwise a
+regression in the ls driver plumbing would surface only via the
+component differentials (the quick suite's interpret λ band runs the XLA
+twin, tests/test_golden.py). Reference analogue: the upstream suite
 runs its accelerator path over the same λ goldens as the CPU path
 (/root/reference/test/racon_test.cpp:297-507).
 
@@ -35,7 +35,6 @@ import json, os, sys
 sys.path.insert(0, %(repo)r)
 from __graft_entry__ import _force_cpu
 _force_cpu(1)                      # 1-device mesh: escapes the suite's 8
-os.environ["RACON_TPU_POA_KERNEL"] = "ls"
 os.environ["RACON_TPU_PALLAS"] = "1"   # interpret-mode pallas on CPU
 
 import gzip
@@ -81,7 +80,7 @@ def test_ls_tier_lambda_end_to_end_band():
     out = json.loads(line[-1][len("RESULT "):])
     ed, stats = out["ed"], out["stats"]
 
-    # same band the quick suite pins for the v2 tier; the measured ls
+    # same band the quick suite pins for the twin; the measured ls
     # value is 1282 (host pin 1283)
     assert abs(ed - 1283) <= 15, (ed, stats)
     # the ls tier must actually SERVE: 92/96 windows measured, with 4

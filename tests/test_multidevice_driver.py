@@ -1,9 +1,11 @@
 """Consensus driver over the 8-virtual-device mesh: both kernel flavors must
-produce correct polished output with the batch sharded across devices."""
+produce correct polished output with the batch sharded across devices, and
+the batch is sized from the tier that serves."""
 
 import random
 
 import jax
+import numpy as np
 import pytest
 
 import racon_tpu
@@ -26,13 +28,11 @@ def _make_dataset(tmp_path, n_targets=3):
     return targets
 
 
-@pytest.mark.parametrize("pallas,kind", [("0", "v2"), ("1", "v2"),
-                                         ("1", "ls")])
-def test_sharded_driver(tmp_path, monkeypatch, capsys, pallas, kind):
+@pytest.mark.parametrize("pallas", ["0", "1"], ids=["0-xla", "1-ls"])
+def test_sharded_driver(tmp_path, monkeypatch, capsys, pallas):
     assert len(jax.devices()) == 8
     targets = _make_dataset(tmp_path)
     monkeypatch.setenv("RACON_TPU_PALLAS", pallas)
-    monkeypatch.setenv("RACON_TPU_POA_KERNEL", kind)
     monkeypatch.setenv("RACON_TPU_BATCH_WINDOWS", "8")
     p = racon_tpu.TpuPolisher(str(tmp_path / "reads.fasta"),
                               str(tmp_path / "ovl.sam"),
@@ -64,3 +64,41 @@ def test_sharded_driver(tmp_path, monkeypatch, capsys, pallas, kind):
     assert captured["host_fallback"] == 0 and captured["failed"] == 0
     if pallas == "1":
         assert "falling back" not in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("pallas,env,want", [
+    # Pallas on: the lockstep group G = 8 rounds the batch up per shard;
+    # 64 is what a TPU asks for, on one chip and on four
+    ("1", {"RACON_TPU_SHARD": "0"}, {4: 8, 64: 64}),
+    ("1", {"RACON_TPU_MESH_SHAPE": "4"}, {8: 32, 64: 64}),
+    # the XLA twin serves: the mesh round-up stays, the group's does not
+    ("0", {"RACON_TPU_SHARD": "0"}, {4: 4, 12: 12}),
+    ("0", {}, {8: 8, 12: 16}),
+], ids=["pallas-1dev", "pallas-4shards", "twin-1dev", "twin-8dev"])
+def test_device_batch_follows_serving_tier(monkeypatch, pallas, env, want):
+    """The batch the warm-up builds and launches at, read where the
+    driver hands it to the kernel builder: with Pallas off the twin must
+    not inherit the lockstep kernel's G x shards round-up."""
+    from racon_tpu.ops import poa_driver
+    from racon_tpu.parallel import reset_partitioner
+
+    assert len(jax.devices()) == 8
+    monkeypatch.setenv("RACON_TPU_PALLAS", pallas)
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    reset_partitioner()
+    built = []
+
+    def fake_build(cfg, B, use_pallas):
+        built.append((B, use_pallas))
+        return lambda *args: tuple(np.zeros((B, 1), np.int32)
+                                   for _ in range(5))
+
+    monkeypatch.setattr(poa_driver, "_build_kernel", fake_build)
+    monkeypatch.setattr(poa_driver, "_WARM_DEAD", {})
+    for asked, batch in want.items():
+        monkeypatch.setenv("RACON_TPU_BATCH_WINDOWS", str(asked))
+        del built[:]
+        poa_driver.warm_geometries(100, 5, -4, -8)
+        assert built == [(batch, pallas == "1")] * len(
+            poa_driver.DEPTH_BUCKETS), (asked, built)

@@ -159,8 +159,7 @@ _ARGS = dict(window_length=100, quality_threshold=10, error_threshold=0.3,
 
 
 def _tpu_run(paths, monkeypatch, env, **kwargs):
-    base = {"RACON_TPU_PALLAS": "0", "RACON_TPU_POA_KERNEL": "v2",
-            "RACON_TPU_BATCH_WINDOWS": "8"}
+    base = {"RACON_TPU_PALLAS": "0", "RACON_TPU_BATCH_WINDOWS": "8"}
     for k, v in {**base, **env}.items():
         monkeypatch.setenv(k, v)
     p = racon_tpu.create_polisher(*paths, backend="tpu", **_ARGS, **kwargs)

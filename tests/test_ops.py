@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from racon_tpu import native
-from racon_tpu.ops import align, poa
+from racon_tpu.ops import align, encoding, poa
 from racon_tpu.ops.encoding import decode, encode
 
 
@@ -181,3 +181,30 @@ def test_device_eligible():
     assert not align.device_eligible(0, 100)
     assert not align.device_eligible(100, 9000)
     assert not align.device_eligible(100, 1000)  # length gap exceeds band
+
+
+# --------------------------------------------------- packed encoding
+# (the Hirschberg kernels read the query PACK codes to an int32 word)
+
+def test_pack_bases_round_trip():
+    rng = np.random.default_rng(3)
+    for n in (0, 1, 3, 4, 5, 127, 128, 1000):
+        codes = rng.integers(0, 5, size=n).astype(np.int32)
+        words = encoding.pack_bases(codes)
+        assert words.shape[-1] == (n + encoding.PACK - 1) // encoding.PACK
+        np.testing.assert_array_equal(encoding.unpack_bases(words, n),
+                                      codes)
+
+
+def test_pack_bases_width_and_batch():
+    codes = (np.arange(10, dtype=np.int32) % 5).reshape(2, 5)
+    words = encoding.pack_bases(codes, width=128)
+    assert words.shape == (2, 128)
+    np.testing.assert_array_equal(encoding.unpack_bases(words, 5), codes)
+
+
+def test_pack_bases_is_lossless_for_code4():
+    # why packing is byte-per-code, not 2-bit: code 4 (N) must survive
+    codes = np.full(9, 4, np.int32)
+    np.testing.assert_array_equal(
+        encoding.unpack_bases(encoding.pack_bases(codes), 9), codes)

@@ -131,9 +131,6 @@ def test_polish_correct_at_any_pipeline_depth(tmp_path, monkeypatch, depth):
         return real_submit(kernel, packed, use_pallas, banded)
 
     monkeypatch.setenv("RACON_TPU_PALLAS", "0")
-    # v2 kind: the ls tier rounds the batch up to G*n_dev=64, which would
-    # swallow all 30 windows into a single chunk
-    monkeypatch.setenv("RACON_TPU_POA_KERNEL", "v2")
     monkeypatch.setenv("RACON_TPU_PIPELINE_DEPTH", depth)
     monkeypatch.setenv("RACON_TPU_BATCH_WINDOWS", "1")  # several chunks
     monkeypatch.setattr(poa_driver, "_submit", counting_submit)

@@ -1,4 +1,4 @@
-"""v3 lane-lockstep Pallas POA kernel differential tests (interpret mode on
+"""Lane-lockstep Pallas POA kernel differential tests (interpret mode on
 the CPU backend; on TPU hardware the same kernel runs compiled — the bench
 exercises that).
 
@@ -19,7 +19,23 @@ from racon_tpu import native
 from racon_tpu.ops import poa, poa_pallas_ls
 from racon_tpu.ops.encoding import decode, encode
 
-from tests.test_pallas import mutate
+
+
+def mutate(seq, rate, rng):
+    out = bytearray()
+    for c in seq:
+        r = rng.random()
+        if r < rate / 3:
+            out.append(rng.choice(b"ACGT"))
+        elif r < 2 * rate / 3:
+            pass
+        elif r < rate:
+            out.append(c)
+            out.append(rng.choice(b"ACGT"))
+        else:
+            out.append(c)
+    return bytes(out)
+
 
 CFG = poa.PoaConfig(max_nodes=384, max_len=256, max_backbone=128,
                     max_edges=12, depth=8, match=5, mismatch=-4, gap=-8)
@@ -250,10 +266,106 @@ def test_lockstep_dmax_cap_fails_window_to_host():
     assert ls_cons == jax_cons
 
 
+@pytest.mark.parametrize("tail", ["odd", "even"])
+def test_ls_pair_step_tail(tail):
+    """The rank loop retires two ranks per iteration; the second is
+    guarded by `r + 1 < r_end`.  One batch whose largest rank count is
+    odd (the guard skips a rank past the end) and one where it is even
+    (it never fires on the last pair), each against the XLA twin.
+    Perfect layers add no node, so a window has as many ranks as its
+    backbone has bases; the count is read back from the kernel."""
+    B = 8
+    a = _alloc(B, CFG)
+    rng = random.Random(3)
+    truth = bytes(rng.choice(b"ACGT") for _ in range(61))
+    backbone = truth if tail == "odd" else truth[:60]
+    # perfect layers add no node: rank count = backbone length
+    _set_window(a, 0, backbone, [backbone] * 3)
+    # a shorter batch-mate, so the longest window alone sets the tail
+    _set_window(a, 1, backbone[:40], [backbone[:40]] * 2)
+
+    (cb, cc, cl, fl, nn), (jb, jc, jl, jf, jn) = _run_both(a, CFG, B)
+
+    assert int(nn[:, 0].max()) == len(backbone)
+    assert (int(nn[:, 0].max()) % 2 == 1) == (tail == "odd")
+    assert not fl.any() and not jf.any()
+    for b, want in ((0, backbone), (1, backbone[:40])):
+        assert decode(cb[b, :cl[b, 0]]) == decode(jb[b, :jl[b]]) == want
+        assert int(nn[b, 0]) == int(jn[b])
+        np.testing.assert_array_equal(cc[b, :cl[b, 0]], jc[b, :jl[b]])
+
+
+def test_lockstep_production_geometry_real_window():
+    """Production-size config (N=1536, L=768, BB=512) on a real lambda
+    window: catches geometry-dependent bugs the small-config differentials
+    can't (tiling, padding, rank insertion at scale)."""
+    import os
+
+    from tests.conftest import DATA
+    if not os.path.isdir(DATA):
+        pytest.skip(f"lambda test data not found at {DATA} "
+                    "(set RACON_TPU_TEST_DATA)")
+
+    import racon_tpu
+    from racon_tpu.ops import poa_driver
+
+    pl = racon_tpu.Pipeline(DATA + "sample_reads.fastq.gz",
+                            DATA + "sample_overlaps.sam.gz",
+                            DATA + "sample_layout.fasta.gz",
+                            match=5, mismatch=-4, gap=-8, trim=False)
+    pl.initialize()
+    target = next((i for i in range(pl.num_windows())
+                   if 20 <= pl.window_info(i)[0] - 1 <= 32), None)
+    if target is None:
+        pytest.skip("no window with 21-32 layers in this dataset")
+    wx = pl.export_window(target)
+
+    cfg = poa_driver.make_config(512, 32, 5, -4, -8)
+    keep = [j for j in range(len(wx.lens))
+            if 0 < wx.lens[j] <= cfg.max_len][:cfg.depth]
+    B = 8
+    packed = poa_driver._pack([(target, wx, keep)], cfg, B)
+    kern = poa_pallas_ls.build_lockstep_poa_kernel(cfg, interpret=True)(B)
+    cb, cc, cl, fl = poa_driver._unpack(
+        poa_driver._submit(kern, packed, True), True)
+    assert not fl[0]
+    # Compare against the pipeline's own host consensus for the same
+    # window: the export is already layer-sorted, and re-sorting through
+    # the one-shot hook would permute equal begin keys differently
+    # (std::sort is not idempotent on ties).
+    pl.consensus_cpu_one(target)
+    assert decode(cb[0, :cl[0]]) == pl.get_consensus(target)
+
+
+@pytest.mark.parametrize("length,pallas,tier", [
+    (200, True, "ls"), (500, True, "ls"), (1000, True, "ls"),
+    (1152, True, "xla"), (1408, True, "xla"), (2000, True, "xla"),
+    (500, False, "xla")],
+    ids=["200", "500", "1000", "1152", "1408", "2000", "pallas-off"])
+def test_entry_tier_by_window_length(length, pallas, tier):
+    """Which tier a window length enters at, in every depth bucket and at
+    the score sets the deployments use: the lockstep kernel's scratch
+    fits VMEM up to class 1024 (so -w 200, -w 500 and upstream's largest
+    documented -w 1000 are served by it; the v5e compiler refuses class
+    1152), the XLA twin takes what is longer and everything when Pallas
+    is off.  A change to RING, NODE_FACTOR or the budget that drops a
+    documented window length off the kernel fails here, not on the
+    chip."""
+    from racon_tpu.ops import poa_driver
+
+    for depth in poa_driver.DEPTH_BUCKETS:
+        for scores in ((5, -4, -8), (3, -5, -4), (1, -1, -1)):
+            cfg = poa_driver.make_config(poa_driver.window_class(length),
+                                         depth, *scores)
+            assert poa_driver._pick_tier(cfg, pallas) == tier
+    assert poa_driver._next_tier("ls") == "xla"
+    assert poa_driver._next_tier("xla") == "host"
+
+
 def test_lockstep_driver_path_end_to_end(tmp_path, monkeypatch):
     """Full TpuPolisher flow with the lockstep branch of the consensus
-    driver (interpret mode): exercises RACON_TPU_POA_KERNEL=ls dispatch,
-    G-multiple batching, padding, marshalling, and unpacking."""
+    driver (interpret mode): exercises the Pallas dispatch, G-multiple
+    batching, padding, marshalling, and unpacking."""
     import random as _r
 
     import racon_tpu
@@ -271,7 +383,6 @@ def test_lockstep_driver_path_end_to_end(tmp_path, monkeypatch):
             f.write(f"r{i}\t0\ttgt\t1\t60\t240M\t*\t0\t0\t{target}\t*\n")
 
     monkeypatch.setenv("RACON_TPU_PALLAS", "1")
-    monkeypatch.setenv("RACON_TPU_POA_KERNEL", "ls")
     monkeypatch.setenv("RACON_TPU_BATCH_WINDOWS", "4")  # rounds up to G=8
     p = racon_tpu.TpuPolisher(str(tmp_path / "reads.fasta"),
                               str(tmp_path / "ovl.sam"),
@@ -285,12 +396,7 @@ def test_lockstep_driver_path_end_to_end(tmp_path, monkeypatch):
     assert res[0][1] == target  # perfect reads -> perfect consensus
 
 
-def test_lockstep_ls_failure_degrades_to_v2(tmp_path, monkeypatch, capsys):
-    """A Mosaic failure in the lockstep kernel must step down to the v2
-    pallas kernel (not straight to XLA), preserving the accelerated path."""
-    import racon_tpu
-    from racon_tpu.ops import poa_driver
-
+def _perfect_reads_dataset(tmp_path):
     target = "ACGT" * 60
     with open(tmp_path / "t.fasta", "w") as f:
         f.write(f">t\n{target}\n")
@@ -302,8 +408,32 @@ def test_lockstep_ls_failure_degrades_to_v2(tmp_path, monkeypatch, capsys):
         for i in range(4):
             f.write(f"r{i}\t0\tt\t1\t60\t{len(target)}M\t*\t0\t0\t{target}"
                     f"\t*\n")
+    return target
 
-    def broken_ls(cfg, interpret=False):
+
+def _polish_perfect_reads(tmp_path):
+    import racon_tpu
+
+    p = racon_tpu.TpuPolisher(str(tmp_path / "r.fasta"),
+                              str(tmp_path / "o.sam"),
+                              str(tmp_path / "t.fasta"),
+                              window_length=100, match=5, mismatch=-4,
+                              gap=-8)
+    p.initialize()
+    return p.polish(True), p.report.as_dict()["phases"]["consensus"]
+
+
+@pytest.mark.parametrize("seam", ["compile", "run"])
+def test_lockstep_failure_degrades_to_xla_kernel(tmp_path, monkeypatch,
+                                                 capsys, seam):
+    """A Mosaic failure of the lockstep kernel, at its build or at its
+    call, must degrade to the XLA kernel, not crash the polish."""
+    target = _perfect_reads_dataset(tmp_path)
+
+    def broken_ls(cfg, **kw):
+        if seam == "compile":
+            raise RuntimeError("synthetic mosaic failure")
+
         def make(batch):
             def call(*args):
                 raise RuntimeError("synthetic mosaic failure")
@@ -311,17 +441,49 @@ def test_lockstep_ls_failure_degrades_to_v2(tmp_path, monkeypatch, capsys):
         return make
 
     monkeypatch.setenv("RACON_TPU_PALLAS", "1")
-    monkeypatch.setenv("RACON_TPU_POA_KERNEL", "ls")
+    monkeypatch.setenv("RACON_TPU_SHARD", "0")
     monkeypatch.setattr(
         "racon_tpu.ops.poa_pallas_ls.build_lockstep_poa_kernel", broken_ls)
-    p = racon_tpu.TpuPolisher(str(tmp_path / "r.fasta"),
-                              str(tmp_path / "o.sam"),
-                              str(tmp_path / "t.fasta"),
-                              window_length=100, match=5, mismatch=-4,
-                              gap=-8)
-    p.initialize()
-    res = p.polish(True)
+    res, cons = _polish_perfect_reads(tmp_path)
     assert len(res) == 1
     assert res[0][1] == target
-    assert "falling back to the pallas 'v2' kernel" in \
-        capsys.readouterr().err
+    assert "falling back to the XLA kernel" in capsys.readouterr().err
+    assert [(d["from"], d["to"]) for d in cons["degradations"]] == \
+        [("ls", "xla")]
+    assert cons["served"]["ls"] == 0
+    assert cons["served"]["xla"] == cons["total"]
+
+
+def test_lockstep_runtime_failure_at_drain_degrades(tmp_path, monkeypatch,
+                                                    capsys):
+    """JAX async dispatch surfaces Mosaic runtime failures at the blocking
+    transfer, not at the kernel call — the drain-time recovery must re-run
+    the retained packed chunk through the XLA kernel and mark the geometry
+    dead."""
+    target = _perfect_reads_dataset(tmp_path)
+
+    class _LazyFail:
+        """Stands in for a device future whose error surfaces on transfer."""
+
+        def __array__(self, *a, **k):
+            raise RuntimeError("synthetic async mosaic failure")
+
+    def async_broken_ls(cfg, **kw):
+        def make(batch):
+            def call(*args):
+                return tuple(_LazyFail() for _ in range(5))
+            return call
+        return make
+
+    monkeypatch.setenv("RACON_TPU_PALLAS", "1")
+    # single-device dispatch: under shard_map the stand-in would fail
+    # where it is traced, which is the call seam, not the drain
+    monkeypatch.setenv("RACON_TPU_SHARD", "0")
+    monkeypatch.setattr(
+        "racon_tpu.ops.poa_pallas_ls.build_lockstep_poa_kernel",
+        async_broken_ls)
+    res, cons = _polish_perfect_reads(tmp_path)
+    assert len(res) == 1
+    assert res[0][1] == target
+    assert "falling back to the XLA kernel" in capsys.readouterr().err
+    assert cons["served"]["xla"] == cons["total"]

@@ -186,7 +186,7 @@ def test_record_shard_demotion_lattice_edge():
     """The edge is orthogonal to tier demotion: degradation list shows
     `<tier>+sharded -> <tier>` and the shard.demotions counter ticks."""
     obs.configure(metrics=True)
-    rep = PhaseReport("consensus", ("ls", "v2", "xla", "host"))
+    rep = PhaseReport("consensus", rl.CONSENSUS_TIERS)
     rl.record_shard_demotion(rep, "ls", RuntimeError("device lost"))
     assert rep.degradations == [{"from": "ls+sharded", "to": "ls",
                                  "error": "RuntimeError: device lost"}]

@@ -91,7 +91,7 @@ def test_journal_forces_sequential(tmp_path, monkeypatch, capsys):
     paths = _write_dataset(tmp_path)
     oracle = _oracle(paths)
     monkeypatch.setenv("RACON_TPU_PIPELINE_PHASES", "1")
-    for k, v in {"RACON_TPU_PALLAS": "0", "RACON_TPU_POA_KERNEL": "v2",
+    for k, v in {"RACON_TPU_PALLAS": "0",
                  "RACON_TPU_BATCH_WINDOWS": "8"}.items():
         monkeypatch.setenv(k, v)
     p = racon_tpu.create_polisher(*paths, backend="tpu",
@@ -301,7 +301,7 @@ def test_traced_pipelined_polish_overlap_and_pack_split(tmp_path):
         cmd = [sys.executable, "-m", "racon_tpu.cli", "--tpu",
                "-w", "100", "--trace", trace, "--report", report, *paths]
         full_env = dict(os.environ, JAX_PLATFORMS="cpu",
-                        RACON_TPU_PALLAS="0", RACON_TPU_POA_KERNEL="v2",
+                        RACON_TPU_PALLAS="0",
                         RACON_TPU_BATCH_WINDOWS="8",
                         RACON_TPU_DEVICE_ALIGNER="xla")
         full_env.pop("RACON_TPU_FAULT", None)

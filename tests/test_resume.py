@@ -176,7 +176,7 @@ def test_resume_wrong_params_refused(tmp_path):
 
 def test_tpu_journal_resume_mixes_tiers(tmp_path, monkeypatch):
     paths = _write_dataset(tmp_path)
-    for k, v in {"RACON_TPU_PALLAS": "0", "RACON_TPU_POA_KERNEL": "v2",
+    for k, v in {"RACON_TPU_PALLAS": "0",
                  "RACON_TPU_BATCH_WINDOWS": "8"}.items():
         monkeypatch.setenv(k, v)
     jp = str(tmp_path / "run.journal")
@@ -257,7 +257,7 @@ def test_wedged_tier_degrades_to_host_e2e(tmp_path, monkeypatch):
     p0 = racon_tpu.create_polisher(*paths, backend="cpu", **_ARGS)
     p0.initialize()
     oracle = p0.polish(True)
-    for k, v in {"RACON_TPU_PALLAS": "0", "RACON_TPU_POA_KERNEL": "v2",
+    for k, v in {"RACON_TPU_PALLAS": "0",
                  "RACON_TPU_BATCH_WINDOWS": "8",
                  "RACON_TPU_DEVICE_TIMEOUT": "0.3",
                  "RACON_TPU_WEDGE_LIMIT": "2",
@@ -309,7 +309,7 @@ def test_bench_normalize_entry_malformed_partial_summaries():
         {"value": 0.01, "report": "corrupt"})
     mixed = bench.normalize_entry({"value": 0.01, "report": {
         "alignment": {"served": {"xla": 5}},            # wall_s absent
-        "consensus": {"wall_s": {"v2": 1.5, "host": 0.5}},
+        "consensus": {"wall_s": {"ls": 1.5, "host": 0.5}},
         "stitch": {"wall_s": "not-a-dict"},
         "parse": 3.0,                                    # not even a dict
     }})
@@ -317,7 +317,7 @@ def test_bench_normalize_entry_malformed_partial_summaries():
     # an explicit stamp (even {}) is the writer's claim: never overwritten
     stamped = bench.normalize_entry(
         {"value": 0.01, "phase_wall": {},
-         "report": {"consensus": {"wall_s": {"v2": 1.0}}}})
+         "report": {"consensus": {"wall_s": {"ls": 1.0}}}})
     assert stamped["phase_wall"] == {}
     # an existing cost_model stamp survives untouched
     cm = {"profile": "cpu-host", "phases": {}, "ok": True}

@@ -6,7 +6,7 @@ across a daemon restart, and the load-test/bench plumbing.
 Conventions follow tests/test_faults.py: identical-read datasets (device
 and host consensus both reproduce the target exactly, so outputs are
 byte-comparable to the CpuPolisher oracle under any serving mix) and the
-fast device env (XLA twin, v2 kernel, 8-window batches).
+fast device env (XLA twin, 8-window batches).
 """
 
 import json
@@ -31,8 +31,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _ARGS = dict(window_length=100, quality_threshold=10, error_threshold=0.3,
              match=5, mismatch=-4, gap=-8, num_threads=1)
 
-_FAST_ENV = {"RACON_TPU_PALLAS": "0", "RACON_TPU_POA_KERNEL": "v2",
-             "RACON_TPU_BATCH_WINDOWS": "8"}
+_FAST_ENV = {"RACON_TPU_PALLAS": "0", "RACON_TPU_BATCH_WINDOWS": "8"}
 
 
 def _write_dataset(tmp_path, n_targets=3, n_reads=4):

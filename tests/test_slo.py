@@ -202,7 +202,7 @@ def test_stage_seconds_sums_per_tier_walls():
         "parse": {"wall_s": {"host": 0.5}},
         "alignment": {"wall_s": {"xla": 1.0, "host": 0.25}},
         "consensus": {"wall_s": 2.0},                 # scalar tolerated
-        "stitch": {"wall_s": {"host": "x", "v2": 0.5}},   # garbage skipped
+        "stitch": {"wall_s": {"host": "x", "ls": 0.5}},   # garbage skipped
         "memory": {"extra": {"peak_rss_mb": 1}},      # not a ledger stage
         "bogus_phase": {"wall_s": {"host": 9.0}},
     }

@@ -1,8 +1,7 @@
 """Lower and compile every production Pallas kernel for the TPU without one.
 
 Two gates, at the shapes the defaults dispatch — the TPU batch (64), the
-four-chip per-shard batch (16), the three depth buckets, the Hirschberg
-kernels at ``_pack_factor()``:
+four-chip per-shard batch (16), the three depth buckets:
 
 * **lowering** — ``jax.export`` with ``platforms=['tpu']`` runs the full
   Pallas -> Mosaic lowering on the CPU backend.  Interpret-mode tests (the
@@ -94,25 +93,15 @@ def _ls(window_length, depth, B, band=False, scores=SCORES):
 
     cfg = poa_driver.make_config(window_length, depth, *scores)
     # the VMEM-fit model must agree: a geometry it approves has to build
-    assert poa_driver._fits_vmem(cfg, "ls"), "fit model rejects geometry"
+    assert poa_driver._fits_vmem(cfg), "fit model rejects geometry"
     fn = build_lockstep_poa_kernel(cfg, interpret=False, band=band)(B)
     return fn, _poa_args(cfg, B, band)
 
 
-def _v2(B):
-    from racon_tpu.ops.poa_pallas import build_pallas_poa_kernel
-
-    cfg = poa_driver.make_config(500, 8, *SCORES)
-    return (build_pallas_poa_kernel(cfg, interpret=False)(B),
-            _poa_args(cfg, B))
-
-
 def _edge(rcap, K, backward, B):
-    pack = align_pallas._pack_factor()
     fn = align_pallas._build_edge_kernel(rcap, K, backward,
-                                         interpret=False, pack=pack)(B)
-    qin = rcap if pack == 1 else max(
-        128, align_pallas._round_up(rcap // pack, 128))
+                                         interpret=False)(B)
+    qin = max(128, align_pallas._round_up(rcap // align_pallas.PACK, 128))
     scal = np.zeros((B, 4), np.int32)
     scal[:, 0] = rcap
     scal[:, 1] = rcap + K
@@ -122,7 +111,7 @@ def _edge(rcap, K, backward, B):
 
 def _base(K, B):
     kern, _ops, qcap, tcap = align_pallas._build_base_kernel(
-        K, interpret=False, pack=align_pallas._pack_factor())
+        K, interpret=False)
     scal = np.zeros((B, 4), np.int32)
     scal[:, 0] = 1
     return kern(B), (scal, np.zeros((B, qcap), np.int32),
@@ -155,16 +144,11 @@ def test_banded_lockstep_poa_kernel_lowers_past_one_program():
 def test_lockstep_poa_kernel_lowers_at_node_factor_4(monkeypatch):
     """RACON_TPU_NODE_FACTOR=4 admits the repeat-dense windows factor 3
     rejects (interpret evidence: 96/96 λ windows device-served at ed
-    1282). v2 no longer fits VMEM at factor 4, so ls is the only pallas
-    tier there — all the more reason to gate it here."""
+    1282)."""
     monkeypatch.setenv("RACON_TPU_NODE_FACTOR", "4")
     fn, args = _ls(500, 8, TPU_BATCH)
     assert poa_driver.make_config(500, 8, *SCORES).max_nodes == 2048
     _export_tpu(fn, args)
-
-
-def test_v2_poa_kernel_lowers_to_tpu():
-    _export_tpu(*_v2(2))
 
 
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
@@ -188,7 +172,6 @@ def test_hirschberg_base_kernel_lowers_to_tpu(single_device, K, B):
 
 @pytest.mark.parametrize("name,build", [
     ("racon_poa_ls", lambda: _ls(500, 8, SHARD_BATCH)),
-    ("racon_poa_v2", lambda: _v2(2)),
     ("racon_hirschberg_edge_fwd", lambda: _edge(512, 256, False, 8)),
     ("racon_hirschberg_edge_bwd", lambda: _edge(512, 256, True, 8)),
     ("racon_hirschberg_base", lambda: _base(256, 8)),
@@ -213,6 +196,20 @@ def test_lowered_kernel_carries_its_stable_name(single_device, name, build):
 @pytest.mark.parametrize("depth", poa_driver.DEPTH_BUCKETS)
 def test_lockstep_poa_kernel_compiles_for_v5e(depth):
     _compile_v5e(*_ls(500, depth, TPU_BATCH))
+
+
+@pytest.mark.parametrize("node_factor,window_length",
+                         [("3", 1000), ("4", 896)])
+def test_lockstep_poa_kernel_compiles_at_its_largest_class(
+        monkeypatch, node_factor, window_length):
+    # the last geometry poa_driver._fits_vmem approves at each node
+    # factor (upstream's largest documented -w at the default one), at
+    # the deepest bucket: the compiler refuses the next class up (16.3 MB
+    # of scoped VMEM against 16), which is where the budget was drawn
+    monkeypatch.setenv("RACON_TPU_NODE_FACTOR", node_factor)
+    nxt = poa_driver.make_config(window_length + 128, 200, *SCORES)
+    assert not poa_driver._fits_vmem(nxt)
+    _compile_v5e(*_ls(window_length, 200, TPU_BATCH))
 
 
 def test_banded_lockstep_poa_kernel_compiles_for_v5e():
