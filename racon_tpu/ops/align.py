@@ -157,7 +157,6 @@ class _XlaAlignOps:
     span_name = "align.cohort"
     pack_span = "align.export"
     install_span = "align.install"
-    async_dispatch = True
 
     def __init__(self, pipeline, report, stats, state):
         self.pipeline = pipeline

@@ -48,7 +48,9 @@ reference's per-GPU aligner batches
 
 from __future__ import annotations
 
+import collections
 import functools
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -481,8 +483,39 @@ def _interpret() -> bool:
     return _jax.devices()[0].platform != "tpu"
 
 
-def align_pairs(pairs, *, interpret=None, band_overrides=None, hits=None):
-    """pairs: [(q_codes int32 np, t_codes int32 np)] -> [ops np | None].
+class _Launch:
+    """One dispatched kernel launch, a member of `in_flight` (the set of
+    launches dispatched and not yet waited for: what the device has to
+    do while the host works) until `wait` has blocked for its outputs."""
+
+    __slots__ = ("in_flight", "outs", "span_args")
+
+    def __init__(self, in_flight, outs, span_args):
+        self.in_flight, self.outs, self.span_args = in_flight, outs, span_args
+        in_flight.add(self)
+
+    def ready(self):
+        """Whether `wait` would return without blocking."""
+        return all(x.is_ready() for x in self.outs)
+
+    def wait(self):
+        with obs.span("align.wait", cat="launch", **self.span_args):
+            outs = tuple(np.asarray(x) for x in self.outs)
+        self.in_flight.discard(self)
+        self.outs = None
+        return outs
+
+
+def align_steps(pairs, *, interpret=None, band_overrides=None, hits=None,
+                in_flight=None):
+    """pairs: [(q_codes int32 np, t_codes int32 np)] -> [ops np | None],
+    as a generator: wherever it has dispatched launches and would block
+    next it yields the launches it is about to wait for, and it returns
+    the results.  Whoever drives it may do other host work at a yield —
+    advance another cohort, whose launches then queue behind these, or
+    come back when the yielded launches are `ready` — and nothing at all
+    (`align_pairs`): what is computed never depends on it.  No span is
+    open at a yield.
 
     ops are forward-ordered codes (0=M, 1=I, 2=D); None = host fallback
     (band escape / oversize).
@@ -496,9 +529,14 @@ def align_pairs(pairs, *, interpret=None, band_overrides=None, hits=None):
     certificate fails is aborted at its first round (no wasted
     recursion), gets result None, and its index is added to `hits` for
     the caller's verify-and-widen ladder.
+
+    in_flight: the set this call's launches join while they are out
+    (`_Launch`); cohorts that share the device share one.
     """
     if interpret is None:
         interpret = _interpret()
+    if in_flight is None:
+        in_flight = set()
     results = [None] * len(pairs)
     segments = {}   # pair index -> list of (ia, ops array)
     bands = {}
@@ -528,14 +566,13 @@ def align_pairs(pairs, *, interpret=None, band_overrides=None, hits=None):
         if not big:
             break
         active = [t for t in active if (t.ib - t.ia) <= BASE_ROWS]
-        with obs.span("align.round", cat="launch", tasks=len(big)) as sp:
-            new_tasks = _split_round(pairs, big, bands, failed, interpret,
-                                     verify, sp)
-        active.extend(new_tasks)
+        active.extend((yield from _split_round(
+            pairs, big, bands, failed, interpret, verify, in_flight)))
 
     # base cases
     base = [t for t in active if t.pair not in failed]
-    _solve_base(pairs, base, bands, segments, failed, interpret, verify)
+    yield from _solve_base(pairs, base, bands, segments, failed, interpret,
+                           verify, in_flight)
 
     with obs.span("align.traceback", cat="launch", pairs=len(segments)):
         for idx, segs in segments.items():
@@ -549,6 +586,22 @@ def align_pairs(pairs, *, interpret=None, band_overrides=None, hits=None):
         # pair cannot fail mid-recursion (certificate covers co-optima)
         hits.update(idx for idx in failed if idx in verify)
     return results
+
+
+def _drive(steps):
+    """A stepped alignment run alone to its end: at each yield it has
+    launches out and nobody else to feed the device, so it goes straight
+    on to its wait."""
+    while True:
+        try:
+            next(steps)
+        except StopIteration as stop:
+            return stop.value
+
+
+def align_pairs(pairs, **kwargs):
+    """`align_steps` driven alone to its end: pairs -> [ops np | None]."""
+    return _drive(align_steps(pairs, **kwargs))
 
 
 def _pow2(n):
@@ -618,19 +671,23 @@ def _deal_programs(tasks, B):
     return slots
 
 
-def _launch(kernel, call, args, n_real, **geom):
-    """One kernel launch, split where the host stops working and starts
-    waiting: ``align.dispatch`` is the jitted call that returns device
-    futures (on a program's first use it traces, lowers and loads, which
-    shows as ``jit.*`` spans inside), ``align.wait`` the blocking copy
-    of the results back.  `n_real` of the batch's rows are tasks, the
-    rest pads it to a power of two.
+def _launch(in_flight, kernel, call, args, n_real, **geom):
+    """Dispatch one kernel launch and start its outputs' copy back; the
+    `_Launch` it returns is waited for later, so that the host's next
+    work runs under this kernel.  ``align.dispatch`` is the jitted call
+    that returns device futures (on a program's first use it traces,
+    lowers and loads, which shows as ``jit.*`` spans inside) and the
+    start of the copies; ``align.wait`` (`_Launch.wait`) is what is left
+    of kernel and copy when the host comes to need the outputs.  `n_real`
+    of the batch's rows are tasks, the rest pads it to a power of two.
 
     Counted here, once per launch: over a mesh the rows per device
-    (count_shard_rows), and how well the lock-step programs engage —
+    (count_shard_rows); how well the lock-step programs engage —
     ``align.lockstep.rows.real`` the DP rows the tasks asked for,
     ``.slots`` the sublane-rows their programs ran (GROUP x each
-    program's largest R)."""
+    program's largest R); and whether the launch found the device fed:
+    ``align.queue.behind`` when an earlier launch is still out, else
+    ``align.queue.empty`` (the device idles until this one arrives)."""
     B = len(args[0])
     shards = _dispatch_shards(B)
     if shards > 1:
@@ -641,23 +698,35 @@ def _launch(kernel, call, args, n_real, **geom):
     obs.count("align.lockstep.rows.real", int(rows.sum()))
     obs.count("align.lockstep.rows.slots",
               GROUP * int(rows.max(axis=1).sum()))
-    with obs.span("align.dispatch", cat="launch", kernel=kernel, B=B,
-                  **geom):
+    obs.count("align.queue.behind" if in_flight else "align.queue.empty")
+    span_args = dict(kernel=kernel, B=B, **geom)
+    with obs.span("align.dispatch", cat="launch", **span_args):
         outs = call(B)(*args)
-    with obs.span("align.wait", cat="launch", kernel=kernel, B=B, **geom):
-        outs = (tuple(np.asarray(x) for x in outs)
-                if isinstance(outs, (tuple, list)) else np.asarray(outs))
+        if not isinstance(outs, (tuple, list)):
+            outs = (outs,)
+        for x in outs:
+            x.copy_to_host_async()
     obs.count("align.launches.base" if kernel == "base"
               else "align.launches.edge")
     obs.count("align.tasks.real", n_real)
     obs.count("align.tasks.pad", B - n_real)
-    return outs
+    return _Launch(in_flight, outs, span_args)
 
 
-def _split_round(pairs, tasks, bands, failed, interpret, verify=None,
-                 round_span=obs.NULL_SPAN):
-    """One Hirschberg round: split every oversized task at its midpoint."""
-    out = []
+def _half(t, backward):
+    """The edge task of one half of `t`: forward over [ia, imid],
+    backward over [imid, ib]."""
+    imid = (t.ia + t.ib) // 2
+    return _Task(t.pair, imid if backward else t.ia,
+                 t.ib if backward else imid, t.ja, t.jb)
+
+
+def _split_round(pairs, tasks, bands, failed, interpret, verify, in_flight):
+    """One Hirschberg round: split every oversized task at its midpoint.
+    Every bucket's forward and backward launch goes out before the first
+    wait (``align.round``; a backward pack runs under its forward
+    kernel), then one yield, then wait and select bucket by bucket, each
+    under the launches of the buckets behind it."""
     by_bucket = {}
     for t in tasks:
         K = bands[t.pair][0]
@@ -666,112 +735,161 @@ def _split_round(pairs, tasks, bands, failed, interpret, verify=None,
         rcap = next(rb for rb in ROW_BUCKETS if half <= rb)
         by_bucket.setdefault((rcap, K), []).append(t)
 
-    round_span.set(buckets=len(by_bucket))
-    for (rcap, K), group in sorted(by_bucket.items()):
-        fwd = _build_edge_kernel(rcap, K, False, interpret)
-        bwd = _build_edge_kernel(rcap, K, True, interpret)
-        # pad the batch dim to a power of two (at least one program of
-        # GROUP tasks) so each (rcap, K) bucket compiles a handful of
-        # kernel variants, not one per group size
-        B = max(GROUP, _pow2(len(group)))
-        geom = dict(rcap=rcap, K=K)
-        with obs.span("align.pack", cat="launch", kernel="edge", B=B,
-                      **geom):
-            # a program's eight tasks run to its largest R: order the
-            # launch by R before it is cut into programs (pad rows, R = 0,
-            # ride in the last one)
-            group.sort(key=lambda t: t.ib - t.ia)
-            slots = _deal_programs(group, B)
-            # forward over [ia, imid], backward over [imid, ib]
-            f_tasks = [t and _Task(t.pair, t.ia, (t.ia + t.ib) // 2,
-                                   t.ja, t.jb) for t in slots]
-            b_tasks = [t and _Task(t.pair, (t.ia + t.ib) // 2, t.ib,
-                                   t.ja, t.jb) for t in slots]
-            f_args = _task_arrays(pairs, f_tasks, bands, rcap, K, False)
-            b_args = _task_arrays(pairs, b_tasks, bands, rcap, K, True)
-        F = _launch("edge_fwd", fwd, f_args, len(group), **geom)
-        Bv = _launch("edge_bwd", bwd, b_args, len(group), **geom)
-        with obs.span("align.select", cat="launch", tasks=len(group),
-                      **geom):
-            for gi, t in enumerate(slots):
-                if t is None:
-                    continue
-                imid = (t.ia + t.ib) // 2
-                K_, gdmin = bands[t.pair]
-                # Both midpoint rows map lane o to absolute column
-                # j = imid + gdmin + o (independent of each frame's clipped
-                # origin); overlay onto the task's column range rel. ja.
-                jmid = imid + gdmin - t.ja + np.arange(K_)
-                span = t.jb - t.ja
-                fv = np.full(span + 1, INF, np.int64)
-                bv = np.full(span + 1, INF, np.int64)
-                m = (jmid >= 0) & (jmid <= span)
-                fv[jmid[m]] = F[gi][m]
-                bv[jmid[m]] = Bv[gi][m]
-                tot = fv + bv
-                jstar = int(np.argmin(tot))
-                if tot[jstar] >= INF:
-                    failed.add(t.pair)
-                    continue
-                v = verify.get(t.pair) if verify else None
-                if (v is not None and t.ia == 0 and t.ib == v[0]
-                        and t.ja == 0 and t.jb == v[1]):
-                    # root task of a banded pair: tot[jstar] IS the global
-                    # edit distance (every path crosses the midpoint row),
-                    # so check the exact Ukkonen certificate here and abort
-                    # the whole pair before recursing on an unproven band
-                    if not _band.ukkonen_ok(v[0], v[1], v[2], v[3],
-                                            int(tot[jstar])):
-                        failed.add(t.pair)
-                        continue
-                jabs = t.ja + jstar
-                out.append(_Task(t.pair, t.ia, imid, t.ja, jabs))
-                out.append(_Task(t.pair, imid, t.ib, jabs, t.jb))
+    issued = []
+    out_now = []    # the round's launches, until each is waited for
+    out = []
+    try:
+        with obs.span("align.round", cat="launch", tasks=len(tasks),
+                      buckets=len(by_bucket)):
+            for (rcap, K), group in sorted(by_bucket.items()):
+                # pad the batch dim to a power of two (at least one
+                # program of GROUP tasks) so each (rcap, K) bucket
+                # compiles a handful of kernel variants, not one per
+                # group size
+                B = max(GROUP, _pow2(len(group)))
+                geom = dict(rcap=rcap, K=K)
+                # a program's eight tasks run to its largest R: order the
+                # launch by R before it is cut into programs (pad rows,
+                # R = 0, ride in the last one)
+                group.sort(key=lambda t: t.ib - t.ia)
+                slots = _deal_programs(group, B)
+                launches = []
+                for backward in (False, True):
+                    kernel = "edge_bwd" if backward else "edge_fwd"
+                    with obs.span("align.pack", cat="launch", kernel=kernel,
+                                  B=B, **geom):
+                        args = _task_arrays(
+                            pairs, [t and _half(t, backward) for t in slots],
+                            bands, rcap, K, backward)
+                    launches.append(_launch(
+                        in_flight, kernel,
+                        _build_edge_kernel(rcap, K, backward, interpret),
+                        args, len(group), **geom))
+                issued.append((geom, len(group), slots, launches))
+                out_now.extend(launches)
+        yield out_now
+        for geom, n_tasks, slots, (fwd, bwd) in issued:
+            (F,), (Bv,) = fwd.wait(), bwd.wait()
+            with obs.span("align.select", cat="launch", tasks=n_tasks,
+                          **geom):
+                _select(slots, F, Bv, bands, verify, failed, out)
+    finally:
+        # launches an exception left out leave the set with this round
+        in_flight.difference_update(out_now)
     return out
 
 
-def _solve_base(pairs, tasks, bands, segments, failed, interpret,
-                verify=None):
+def _select(slots, F, Bv, bands, verify, failed, out):
+    """Pick each task's crossing column at its midpoint row from the
+    forward and backward edge rows; its two halves go to `out`."""
+    for gi, t in enumerate(slots):
+        if t is None:
+            continue
+        imid = (t.ia + t.ib) // 2
+        K_, gdmin = bands[t.pair]
+        # Both midpoint rows map lane o to absolute column j = imid +
+        # gdmin + o (independent of each frame's clipped origin);
+        # overlay onto the task's column range rel. ja.
+        jmid = imid + gdmin - t.ja + np.arange(K_)
+        span = t.jb - t.ja
+        fv = np.full(span + 1, INF, np.int64)
+        bv = np.full(span + 1, INF, np.int64)
+        m = (jmid >= 0) & (jmid <= span)
+        fv[jmid[m]] = F[gi][m]
+        bv[jmid[m]] = Bv[gi][m]
+        tot = fv + bv
+        jstar = int(np.argmin(tot))
+        if tot[jstar] >= INF:
+            failed.add(t.pair)
+            continue
+        v = verify.get(t.pair) if verify else None
+        if (v is not None and t.ia == 0 and t.ib == v[0]
+                and t.ja == 0 and t.jb == v[1]):
+            # root task of a banded pair: tot[jstar] IS the global edit
+            # distance (every path crosses the midpoint row), so check
+            # the exact Ukkonen certificate here and abort the whole
+            # pair before recursing on an unproven band
+            if not _band.ukkonen_ok(v[0], v[1], v[2], v[3],
+                                    int(tot[jstar])):
+                failed.add(t.pair)
+                continue
+        jabs = t.ja + jstar
+        out.append(_Task(t.pair, t.ia, imid, t.ja, jabs))
+        out.append(_Task(t.pair, imid, t.ib, jabs, t.jb))
+
+
+# Base launches a cohort may have out at once.  A launch holds ~2 MB on
+# the device (inputs and outputs of 64 tasks at K 2048) and as much in
+# packed host arrays, and a cohort has ~20 of them: eight is ~25 ms of
+# kernel ahead of the host, several times the jitter of its ~2 ms of
+# work per launch, at 16 MB.  The bound is there for a cohort of 100 kb
+# pairs, which has hundreds.
+BASE_AHEAD = 8
+
+
+def _solve_base(pairs, tasks, bands, segments, failed, interpret, verify,
+                in_flight):
+    """The base cases, every launch independent of every other: up to
+    BASE_AHEAD go out before the first wait, then one is waited for and
+    traced back (under the launches behind it) and the next one packed
+    and dispatched, with a yield before each wait."""
     by_bucket = {}
     for t in tasks:
         K = bands[t.pair][0]
         by_bucket.setdefault(K, []).append(t)
+    chunks = []
     for K, group in sorted(by_bucket.items()):
-        kern, _, _, _ = _build_base_kernel(K, interpret)
         group.sort(key=lambda t: t.ib - t.ia)   # like rows share a program
-        for off in range(0, len(group), 64):
-            chunk = group[off:off + 64]
-            B = max(GROUP, _pow2(len(chunk)))
-            geom = dict(rcap=BASE_ROWS, K=K)
-            with obs.span("align.pack", cat="launch", kernel="base", B=B,
-                          **geom):
-                slots = _deal_programs(chunk, B)
-                args = _task_arrays(pairs, slots, bands, BASE_ROWS, K,
-                                    False)
-            ops, cnt, ok, dist = _launch("base", kern, args, len(chunk),
-                                         **geom)
-            with obs.span("align.traceback", cat="launch",
-                          tasks=len(chunk), K=K):
-                for bi, t in enumerate(slots):
-                    if t is None:
-                        continue
-                    v = verify.get(t.pair) if verify else None
-                    if (v is not None and t.ia == 0 and t.ib == v[0]
-                            and t.ja == 0 and t.jb == v[1]):
-                        # base-case-only banded pair: the kernel's
-                        # terminal distance carries the exact Ukkonen
-                        # certificate
-                        if (not ok[bi]
-                                or not _band.ukkonen_ok(
-                                    v[0], v[1], v[2], v[3],
-                                    int(dist[bi]))):
-                            failed.add(t.pair)
-                            continue
-                    if not ok[bi]:
-                        failed.add(t.pair)
-                        continue
-                    seg = ops[bi, :cnt[bi]][::-1].astype(np.int32)
-                    segments[t.pair].append((t.ia, seg))
+        chunks.extend((K, group[off:off + 64])
+                      for off in range(0, len(group), 64))
+
+    def issue(K, chunk):
+        kern, _, _, _ = _build_base_kernel(K, interpret)
+        B = max(GROUP, _pow2(len(chunk)))
+        geom = dict(rcap=BASE_ROWS, K=K)
+        with obs.span("align.pack", cat="launch", kernel="base", B=B,
+                      **geom):
+            slots = _deal_programs(chunk, B)
+            args = _task_arrays(pairs, slots, bands, BASE_ROWS, K, False)
+        return slots, len(chunk), _launch(in_flight, "base", kern, args,
+                                          len(chunk), **geom)
+
+    todo = iter(chunks)
+    flying = collections.deque(
+        issue(*c) for c in itertools.islice(todo, BASE_AHEAD))
+    try:
+        while flying:
+            yield [flying[0][-1]]
+            slots, n_tasks, launch = flying.popleft()
+            outs = launch.wait()
+            with obs.span("align.traceback", cat="launch", tasks=n_tasks,
+                          K=launch.span_args["K"]):
+                _collect_base(slots, outs, segments, verify, failed)
+            flying.extend(issue(*c) for c in itertools.islice(todo, 1))
+    finally:
+        in_flight.difference_update(launch for *_, launch in flying)
+
+
+def _collect_base(slots, outs, segments, verify, failed):
+    """A base launch's op codes, reversed into each pair's segments."""
+    ops, cnt, ok, dist = outs
+    for bi, t in enumerate(slots):
+        if t is None:
+            continue
+        v = verify.get(t.pair) if verify else None
+        if (v is not None and t.ia == 0 and t.ib == v[0]
+                and t.ja == 0 and t.jb == v[1]):
+            # base-case-only banded pair: the kernel's terminal distance
+            # carries the exact Ukkonen certificate
+            if (not ok[bi] or not _band.ukkonen_ok(
+                    v[0], v[1], v[2], v[3], int(dist[bi]))):
+                failed.add(t.pair)
+                continue
+        if not ok[bi]:
+            failed.add(t.pair)
+            continue
+        seg = ops[bi, :cnt[bi]][::-1].astype(np.int32)
+        segments[t.pair].append((t.ia, seg))
 
 
 from .align import ops_to_cigar  # same 0=M/1=I/2=D convention
@@ -783,14 +901,61 @@ def cohort_size(default: int = 64) -> int:
     return max(1, int(env if env is not None else default))
 
 
+class _Cohort:
+    """One dispatched cohort: its stepped alignment (`align_steps` under
+    `_HirschbergOps._steps`), advanced by its own dispatch and unpack
+    and, whenever the launches it waits for have come back, on the time
+    of the cohort ahead of it."""
+
+    __slots__ = ("steps", "waits_for", "done", "results", "error")
+
+    def __init__(self, steps):
+        self.steps = steps
+        self.waits_for = ()       # the launches its next step blocks on
+        self.done = False
+        self.results = None
+        self.error = None
+
+    def advance(self):
+        """Run to the next yield or to the end, blocking where it has
+        to; an exception is the caller's."""
+        try:
+            self.waits_for = next(self.steps)
+        except StopIteration as stop:
+            self.done, self.results, self.waits_for = True, stop.value, ()
+
+    def advance_if_ready(self):
+        """One step on another cohort's time, if it would not block: the
+        host never waits for this cohort while the one ahead has work.
+        An exception is kept and raised when this cohort is unpacked, so
+        the lattice charges the cohort that failed."""
+        if (self.done or self.error is not None
+                or not all(launch.ready() for launch in self.waits_for)):
+            return
+        try:
+            self.advance()
+        except Exception as e:  # noqa: BLE001 — re-raised by unpack
+            self.error = e
+
+
 class _HirschbergOps:
     """Executor hooks (ops/batch_exec.py) for the Hirschberg engine.
 
-    The engine is host-orchestrated (align_pairs launches rounds of
-    kernel batches itself), so there is nothing to async-dispatch: each
-    cohort resolves inline through the lattice (`async_dispatch = False`)
-    — bounded retry, bisection-quarantine of a poisoned job, and tier
-    death to host all behave exactly as the pre-executor loop did.
+    The engine orchestrates its rounds on the host, as a generator that
+    yields where it has launches out and would block (`align_steps`), so
+    cohorts pipeline like any engine's chunks: `dispatch` creates a
+    cohort's generator and advances it to its first yield (first round
+    packed and dispatched), `unpack` drives it to its end and, at each
+    of its yields and again between the installs of its jobs, advances
+    the other cohort in flight by one step if that step would not block
+    (`_advance_others`).  One host thread; the device queue is the
+    concurrency: cohort N+1's packs and selects run under cohort N's
+    kernels and N's under N+1's, and N is installed while N+1 goes
+    through its rounds.  Lattice retries,
+    bisection probes and the widen ladder run `attempt`: the same
+    generator driven alone to its end — bounded retry,
+    bisection-quarantine of a poisoned job and tier death to host are
+    what they were.
 
     Single-copy packing: `pack` encodes each job once into two
     preallocated padded row buffers; the per-job views are what lattice
@@ -800,9 +965,10 @@ class _HirschbergOps:
     span_name = "align.cohort"
     pack_span = "align.export"
     install_span = "align.install"
-    async_dispatch = False
 
     def __init__(self, pipeline, dims, report, stats, state):
+        from ..resilience import lattice as rl
+
         self.pipeline = pipeline
         self.dims = dims          # job -> (n, m) from the bulk lengths
         self.report = report
@@ -811,9 +977,19 @@ class _HirschbergOps:
         self.pairs = {}           # job -> (q_view, t_view), packed once
         self.band = {}            # job -> band.BandState (banded jobs)
         self.dead = False
+        self.cohorts = {}         # first job -> _Cohort, while in flight
+        self.in_flight = set()    # their launches not yet waited for
+        # under a watchdog deadline `unpack` runs on a thread the lattice
+        # may abandon, and no generator may be left to two threads: then
+        # nothing is advanced but the cohort being resolved
+        self.step_others = rl.device_timeout() <= 0
 
     def live_tier(self, ctx, kind):
-        return "host" if self.dead else "hirschberg"
+        # a cohort dispatched before the engine died (`kind` is its own
+        # tier) still resolves from the launches it has out
+        if kind == "host" or (self.dead and kind is None):
+            return "host"
+        return "hirschberg"
 
     def export(self, ctx, group):
         return list(group)
@@ -837,10 +1013,13 @@ class _HirschbergOps:
                                    encode(ta).astype(np.int32))
         return None
 
-    def attempt(self, ctx, kind, sub):
+    def _steps(self, sub):
+        """`align_steps` over the packed views of `sub`, band state read
+        once at the start; returns one result per job (`_band.HIT` for a
+        band hit).  Pure: hit classification and ladder advance happen
+        in install()."""
         from ..resilience import faults
 
-        faults.check("align.run", sub)
         plist = [self.pairs[j] for j in sub]
         overrides = {}
         for bi, j in enumerate(sub):
@@ -848,7 +1027,7 @@ class _HirschbergOps:
             if st is not None and st.k is not None:
                 overrides[bi] = st.k
         if not overrides:
-            return align_pairs(plist)
+            return (yield from align_steps(plist, in_flight=self.in_flight))
         forced = False
         try:
             # the deterministic widening-exhaustion drill: an armed
@@ -858,13 +1037,43 @@ class _HirschbergOps:
         except faults.InjectedFault:
             forced = True
         hits = set()
-        res = align_pairs(plist, band_overrides=overrides, hits=hits)
+        res = yield from align_steps(plist, band_overrides=overrides,
+                                     hits=hits, in_flight=self.in_flight)
         if forced:
             hits.update(overrides)
-        # attempt stays pure (lattice retries/bisection re-call it);
-        # hit classification and ladder advance happen in install()
         return [_band.HIT if bi in hits else res[bi]
                 for bi in range(len(sub))]
+
+    def dispatch(self, ctx, kind, packed, chunk):
+        from ..resilience import faults
+
+        faults.check("align.run", chunk)
+        cohort = _Cohort(self._steps(chunk))
+        cohort.advance()
+        self.cohorts[chunk[0]] = cohort
+        return cohort
+
+    def attempt(self, ctx, kind, sub):
+        from ..resilience import faults
+
+        faults.check("align.run", sub)
+        return _drive(self._steps(sub))
+
+    def unpack(self, ctx, kind, cohort):
+        if cohort.error is not None:
+            raise cohort.error
+        while not cohort.done:
+            cohort.advance()
+            self._advance_others(cohort)
+        return cohort.results
+
+    def _advance_others(self, cohort):
+        """Give every cohort in flight but `cohort` a step, if its
+        launches are back."""
+        if self.step_others:
+            for other in self.cohorts.values():
+                if other is not cohort:
+                    other.advance_if_ready()
 
     def span_args(self, ctx, chunk, pipelined):
         return {"jobs": len(chunk)}
@@ -890,6 +1099,7 @@ class _HirschbergOps:
                 st.pending = False
             faults.check("align.install", (job,))
             self.pipeline.set_job_cigar(job, ops_to_cigar(ops))
+            self._advance_others(None)
             self.state["served"] += 1
             if self.stats is not None:
                 self.stats["device"] = self.stats.get("device", 0) + 1
@@ -906,6 +1116,8 @@ class _HirschbergOps:
     def demote(self, ctx, kind, cause):
         import sys
 
+        if self.dead:   # a cohort still in flight when the engine died
+            return "host"
         self.dead = True
         print(f"[racon_tpu::align] WARNING: hirschberg engine failed "
               f"({type(cause).__name__}: {cause}); remaining jobs fall "
@@ -928,6 +1140,7 @@ class _HirschbergOps:
 
     def done(self, ctx, chunk):
         # keep host memory O(cohort): packed views die with the chunk
+        self.cohorts.pop(chunk[0], None)
         for job in chunk:
             self.pairs.pop(job, None)
             self.band.pop(job, None)

@@ -6,12 +6,15 @@ and alignment paths share a single serving seam:
 * **single-copy packing** — the driver's `pack` hook copies each unit's
   bytes exactly once into preallocated padded buffers; lattice retries and
   bisection probes reuse the packed views instead of re-materializing;
-* **depth-Q async dispatch** — for engines whose kernel call is a JAX
-  async dispatch (`async_dispatch = True`), up to `depth` packed chunks
-  stay in flight, so the host packs chunk N+1 while chunk N executes —
-  the analogue of the reference's continuous batch fill running
-  concurrently with kernel execution
-  (/root/reference/src/cuda/cudapolisher.cpp:83-145);
+* **depth-Q async dispatch** — a kernel call is a JAX async dispatch,
+  so up to `depth` packed chunks stay in flight and the host packs
+  chunk N+1 while chunk N executes — the analogue of the reference's
+  continuous batch fill running concurrently with kernel execution
+  (/root/reference/src/cuda/cudapolisher.cpp:83-145).  An engine that
+  orchestrates many launches per chunk on the host (Hirschberg)
+  dispatches a generator advanced to its first wait, and its `unpack`
+  and `install` give the chunk behind it a step whenever that chunk's
+  launches are back, while they resolve their own;
 * **one resilience seam** — the degradation lattice
   (resilience/lattice.py: bounded retry, batch bisection-quarantine,
   tier demotion down to the host floor), the journal taps, the runtime
@@ -21,11 +24,12 @@ and alignment paths share a single serving seam:
 * **pack/kernel wall split** — `pack_ns` (host export+pack) vs
   `kernel_ns` (host wall blocked in the lattice serve) accumulate per
   executor and surface as `report.extra["pack_wall_s"/"kernel_wall_s"]`
-  in the drivers.  `kernel_wall_s` is NOT kernel time: for a
-  host-orchestrated engine (Hirschberg) the serve is all of
-  `align_pairs` — task arrays, padding, the per-task midpoint loop, the
-  traceback — and for an async one it is dispatch + the blocking copy
-  back.  The launch-level spans tell those apart: the executor emits
+  in the drivers.  `kernel_wall_s` is NOT kernel time: for the
+  Hirschberg engine the serve is a cohort's `align_steps` from its
+  first wait on — task arrays, padding, the per-task midpoint loop, the
+  traceback — and steps of the next cohort's at its yields; for a
+  one-launch engine it is the blocking copy back.  The launch-level
+  spans tell those apart: the executor emits
   the ops object's `pack_span` (exactly what `pack_ns` sums) and
   `install_span`, the drivers emit `*.dispatch` / `*.wait` per launch
   (category `"launch"`).
@@ -38,9 +42,6 @@ The driver supplies an *ops* object (duck-typed; no registration):
                               # name per seam, none shared with a span
                               # the driver emits itself
     install_span: str         # launch span over the install loop
-    async_dispatch: bool      # False = host-orchestrated engine: the
-                              # chunk resolves inline through the lattice
-                              # (watchdog-wrapped), nothing is queued
     live_tier(ctx, kind)      # best live tier at/below `kind` (None =
                               # the bucket's entry tier); may stash the
                               # kernel handle on ctx
@@ -191,12 +192,6 @@ class BatchExecutor:
                     self._count_shard(len(chunk), packed, m)
         self.pack_ns += time.monotonic_ns() - t0
         if not chunk:
-            return
-        if not getattr(ops, "async_dispatch", True):
-            # host-orchestrated engine: the kernel call IS the blocking
-            # compute, so it runs inside the lattice serve (bounded
-            # retry + watchdog) rather than as a fire-and-forget dispatch
-            self._resolve(ctx, chunk, None, kind)
             return
         try:
             outs = ops.dispatch(ctx, kind, packed, chunk)

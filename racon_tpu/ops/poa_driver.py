@@ -573,7 +573,6 @@ class _ConsensusOps:
     span_name = "poa.chunk"
     pack_span = "poa.pack"
     install_span = "poa.install"
-    async_dispatch = True
 
     def __init__(self, pipeline, B, trim, stats, fallback, report,
                  journal, dead_geoms):

@@ -217,11 +217,12 @@ def test_engine_failure_degrades_to_host(tmp_path, monkeypatch):
             of.write(f"r{i}\t{len(r)}\t0\t{len(r)}\t+\tt\t{len(truth)}\t0\t"
                      f"{len(truth)}\t{len(r)}\t{len(r)}\t60\n")
 
-    def boom(pairs, *, interpret=None):
+    def boom(pairs, **kwargs):
         raise RuntimeError("synthetic Mosaic failure")
 
     monkeypatch.setenv("RACON_TPU_DEVICE_ALIGNER", "hirschberg")
-    monkeypatch.setattr(ap, "align_pairs", boom)
+    # what a cohort's dispatch and every lattice attempt run
+    monkeypatch.setattr(ap, "align_steps", boom)
     p = racon_tpu.TpuPolisher(str(tmp_path / "r.fasta"),
                               str(tmp_path / "o.paf"),
                               str(tmp_path / "t.fasta"),
@@ -431,3 +432,278 @@ def test_pad_task_never_lengthens_a_group(placement):
         + 2 * 3 * 150        # round 2: three programs at R 150, one at 0
         + 5 * 150)           # base: five programs at R 150, three at 0
     assert counters["align.tasks.pad"] == 2 * 7 + 2 * 14 + 28
+
+
+# -- launches issued ahead: the stepped driver ------------------------------
+
+# sha256[:12] of each pair's op array as the driver gave it before
+# launches were issued ahead (commit b3fbb9d: one blocking launch at a
+# time); None = left to the host
+_MIXED_BEFORE = ["9a7b4734df14", "0d118ab5cf13", "68da9370a26a", None,
+                 "6edd9f6f9cc9", "c64ad544e0c4", "e921d0da4091", None,
+                 "dd781b86c09a", "2467635d72f3", "b7a00cde5115"]
+
+
+@functools.lru_cache(maxsize=1)
+def _mixed():
+    """Eleven pairs, no multiple of GROUP: three rounds deep (1400,
+    1100), two, one, base-only (200, 30, 64, and 257: a round of one
+    task), one past the widest band (the host's) and an empty one."""
+    rng = random.Random(32)
+    pairs = []
+    for n in (1400, 200, 1100, 30, 700, 257, 900, 64, 520):
+        q = _rand(rng, n)
+        pairs.append((q, mutate(q, 0.07, rng)))
+    pairs.insert(3, (b"A" * 100, b"A" * 3000))
+    pairs.insert(7, (b"", b"ACGT"))
+    return [_enc(q, t) for q, t in pairs]
+
+
+def _digest(ops):
+    import hashlib
+
+    return None if ops is None else hashlib.sha256(
+        np.asarray(ops, np.int32).tobytes()).hexdigest()[:12]
+
+
+def _interleave(gens):
+    """Advance the generators in turn, one step each, until all ended;
+    their return values in order."""
+    done = {}
+    while len(done) < len(gens):
+        for i, g in enumerate(gens):
+            if i in done:
+                continue
+            try:
+                next(g)
+            except StopIteration as stop:
+                done[i] = stop.value
+    return [done[i] for i in range(len(gens))]
+
+
+@pytest.mark.parametrize("split", [None, 4, 7, 1],
+                         ids=["alone", "4+7", "7+4", "1+10"])
+def test_stepped_driver_agrees_with_the_blocking_one(split):
+    """The generator driven alone to its end, and two generators over a
+    split of the same pairs advanced step by step with one set of
+    launches in flight between them, give op for op what the driver gave
+    when every launch blocked.  When the host waits never changes what
+    is computed."""
+    pairs = _mixed()
+    if split is None:
+        steps = align_pallas.align_steps(pairs, interpret=True)
+        n_yields = sum(1 for _ in iter(lambda: next(steps, "end"), "end"))
+        # it does hand over: a yield per round and per base launch
+        assert n_yields >= 4
+        got = align_pallas.align_pairs(pairs, interpret=True)
+    else:
+        in_flight = set()
+        a, b = _interleave([
+            align_pallas.align_steps(part, interpret=True,
+                                     in_flight=in_flight)
+            for part in (pairs[:split], pairs[split:])])
+        got = a + b
+        assert not in_flight            # every launch was waited for
+    assert [_digest(r) for r in got] == _MIXED_BEFORE
+
+
+def test_abandoned_steps_leave_no_launch_in_the_set():
+    """A generator dropped at a yield (its cohort failed, or the engine
+    stopped) takes its launches out of the shared set, so the next
+    cohort's `align.queue.*` counters still tell the truth."""
+    in_flight = set()
+    steps = align_pallas.align_steps(_mixed()[:3], interpret=True,
+                                     in_flight=in_flight)
+    next(steps)
+    assert len(in_flight) == 2          # round 1: edge_fwd + edge_bwd
+    steps.close()
+    assert not in_flight
+
+
+# -- two cohorts in flight, through the executor ----------------------------
+
+class _FakePipe:
+    """The three calls run_jobs makes of a pipeline, over byte pairs."""
+
+    def __init__(self, pairs):
+        self.pairs = pairs
+        self.cigars = {}
+
+    def align_job(self, i):
+        q, t = self.pairs[i]
+        return np.frombuffer(q, np.uint8), np.frombuffer(t, np.uint8)
+
+    def set_job_cigar(self, i, cigar):
+        self.cigars[i] = cigar
+
+
+@functools.lru_cache(maxsize=1)
+def _six_pairs():
+    """Six pairs of ~600 rows: one bucket, a round and a base launch;
+    at three to a cohort, two cohorts.  With each pair's CIGAR from the
+    blocking driver."""
+    rng = random.Random(41)
+    pairs = []
+    for _ in range(6):
+        q = _rand(rng, rng.randrange(560, 640))
+        pairs.append((q, mutate(q, 0.05, rng)))
+    cigars = [align_pallas.ops_to_cigar(r) for r in align_pallas.align_pairs(
+        [_enc(q, t) for q, t in pairs], interpret=True)]
+    return pairs, cigars
+
+
+def _run_six(monkeypatch, **env):
+    from racon_tpu.resilience import faults
+    from racon_tpu.resilience.report import PhaseReport
+
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    faults.reset()
+    pairs, _ = _six_pairs()
+    pipe = _FakePipe(pairs)
+    rep = PhaseReport("alignment", ("hirschberg", "host"))
+    served = align_pallas.run_jobs(pipe, list(range(6)), cohort=3,
+                                   report=rep)
+    return pipe, rep, served
+
+
+def _spy_dispatch_and_unpack(monkeypatch):
+    """The order in which the executor calls the engine's two hooks."""
+    order = []
+    real = align_pallas._HirschbergOps
+    for hook in ("dispatch", "unpack"):
+        def spy(self, ctx, kind, x, *rest, _hook=hook,
+                _real=getattr(real, hook)):
+            order.append(_hook)
+            return _real(self, ctx, kind, x, *rest)
+        monkeypatch.setattr(real, hook, spy)
+    return order
+
+
+def test_two_cohorts_in_flight_install_what_the_blocking_driver_gave(
+        monkeypatch):
+    """Depth 2 (the default): cohort 1's first round goes out before
+    cohort 0 is waited for, cohort 0's unpack and install advance
+    cohort 1 whenever its launches are back, and every CIGAR is the
+    blocking driver's."""
+    from racon_tpu import obs
+
+    order = _spy_dispatch_and_unpack(monkeypatch)
+    want = _six_pairs()[1]             # before the counters are armed
+    obs.reset()
+    obs.configure(metrics=True)
+    try:
+        pipe, rep, served = _run_six(monkeypatch)
+        counters = obs.snapshot()["counters"]
+    finally:
+        obs.reset()
+    assert order == ["dispatch", "dispatch", "unpack", "unpack"]
+    assert served == 6 and rep.retries == 0 and rep.bisections == 0
+    assert [pipe.cigars[i] for i in range(6)] == want
+    # 2 cohorts x (2 rounds of fwd + bwd, 1 base launch).  Alone, each
+    # cohort's three phases would each start on an empty queue (6); with
+    # cohort 1 dispatched behind cohort 0's first round fewer do (how
+    # many fewer follows when launches come back: not asserted)
+    assert counters["align.launches.edge"] == 8
+    assert counters["align.launches.base"] == 2
+    assert counters["align.queue.empty"] + counters["align.queue.behind"] \
+        == 10
+    assert 1 <= counters["align.queue.empty"] < 6
+
+
+@pytest.mark.parametrize("where", ["dispatch", "neighbour_step"])
+def test_fault_in_the_second_cohort_is_charged_to_it(monkeypatch, where):
+    """Job 4 poisons cohort 1 = [3, 4, 5] while cohort 0 is in flight:
+    at cohort 1's own dispatch (the armed `align.run` fault), or inside
+    its generator while cohort 0's unpack is advancing it — that
+    exception is kept and raised when cohort 1 is unpacked (if its
+    launches were not back in time, it fails in its own unpack: the
+    same from there on).  Either way
+    the lattice retries, bisects and quarantines within cohort 1 only;
+    cohort 0 installs whole, from the launches it had out."""
+    env = {"RACON_TPU_TIER_RETRIES": "1"}
+    if where == "dispatch":
+        env["RACON_TPU_FAULT"] = "align.run:window=4"
+    else:
+        real = align_pallas._HirschbergOps._steps
+
+        def poisoned(self, sub):
+            steps = real(self, sub)
+            n = 0
+            while True:
+                try:
+                    waits_for = next(steps)
+                except StopIteration as stop:
+                    return stop.value
+                n += 1
+                if n == 2 and 4 in sub:     # past dispatch's first step
+                    raise RuntimeError("poisoned job 4")
+                yield waits_for
+
+        monkeypatch.setattr(align_pallas._HirschbergOps, "_steps", poisoned)
+    pipe, rep, served = _run_six(monkeypatch, **env)
+    want = _six_pairs()[1]
+    assert served == 5
+    assert sorted(pipe.cigars) == [0, 1, 2, 3, 5]
+    assert all(pipe.cigars[i] == want[i] for i in pipe.cigars)
+    # a cohort that fails at its dispatch is resolved there and then, as
+    # for every engine; one that failed on cohort 0's time waits its turn
+    assert list(pipe.cigars) == ([3, 5, 0, 1, 2] if where == "dispatch"
+                                 else [0, 1, 2, 3, 5])
+    assert rep.quarantined == [4]
+    assert rep.retries >= 1 and rep.bisections >= 1
+    assert rep.served.get("hirschberg") == 5
+    assert not rep.as_dict()["degradations"]
+
+
+def test_hard_memory_watermark_resolves_each_cohort_inline(monkeypatch):
+    """Under the hard watermark the executor's depth is 1: a cohort is
+    dispatched and unpacked before the next is packed, nothing is
+    advanced on another cohort's time, and the CIGARs are the same."""
+    from racon_tpu.ops import batch_exec
+
+    order = _spy_dispatch_and_unpack(monkeypatch)
+    monkeypatch.setattr(batch_exec.budget, "hard_latched", lambda: True)
+    pipe, rep, served = _run_six(monkeypatch)
+    assert order == ["dispatch", "unpack", "dispatch", "unpack"]
+    assert served == 6
+    assert [pipe.cigars[i] for i in range(6)] == _six_pairs()[1]
+    assert [(d["from"], d["to"]) for d in rep.as_dict()["degradations"]] \
+        == [("batched", "stream-sequential")]
+
+
+def test_cohort_steps_on_anothers_time_only_when_it_would_not_block():
+    """`_Cohort.advance_if_ready`: no step while a launch it waits for
+    is still out, one step once all are back, and an exception from that
+    step is kept for the cohort's own unpack."""
+
+    class FakeLaunch:
+        def __init__(self, back):
+            self.back = back
+
+        def ready(self):
+            return self.back
+
+    first, second = FakeLaunch(False), FakeLaunch(True)
+    trail = []
+
+    def steps():
+        trail.append("dispatched")
+        yield [second, first]
+        trail.append("stepped")
+        yield [second]
+        raise RuntimeError("boom")
+
+    cohort = align_pallas._Cohort(steps())
+    cohort.advance()                    # its own dispatch
+    cohort.advance_if_ready()
+    assert trail == ["dispatched"]      # `first` is still out
+    first.back = True
+    cohort.advance_if_ready()
+    assert trail == ["dispatched", "stepped"] and cohort.error is None
+    cohort.advance_if_ready()           # raises inside: kept, not raised
+    assert isinstance(cohort.error, RuntimeError) and not cohort.done
+    cohort.advance_if_ready()           # and never advanced again
+    ops = align_pallas._HirschbergOps(None, {}, None, None, {"served": 0})
+    with pytest.raises(RuntimeError, match="boom"):
+        ops.unpack(None, "hirschberg", cohort)

@@ -30,19 +30,21 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 class FakeOps:
     """Executor hooks over trivial integer work units.  `fail` maps an
     attempt invocation index to an exception; `dead_tiers` lists tiers
-    whose every dispatch/attempt fails (forcing TierDead -> demote)."""
+    whose every dispatch/attempt fails (forcing TierDead -> demote);
+    `dispatch_fail` / `unpack_fail` list the invocations of those hooks
+    that raise."""
 
     span_name = "fake.chunk"
     pack_span = "fake.pack"
     install_span = "fake.install"
 
-    def __init__(self, async_dispatch=True, tiers=("fast", "slow", "host"),
-                 fail=None, dead_tiers=(), dispatch_fail=None):
-        self.async_dispatch = async_dispatch
+    def __init__(self, tiers=("fast", "slow", "host"), fail=None,
+                 dead_tiers=(), dispatch_fail=None, unpack_fail=None):
         self.tiers = list(tiers)
         self.fail = dict(fail or {})
         self.dead_tiers = set(dead_tiers)
         self.dispatch_fail = set(dispatch_fail or ())
+        self.unpack_fail = set(unpack_fail or ())
         self.attempts = 0
         self.dispatches = 0
         self.unpacks = 0
@@ -82,6 +84,8 @@ class FakeOps:
 
     def unpack(self, ctx, kind, outs):
         self.unpacks += 1
+        if self.unpacks in self.unpack_fail:
+            raise RuntimeError(f"unpack {self.unpacks} failed")
         return list(outs)
 
     def span_args(self, ctx, chunk, pipelined):
@@ -144,12 +148,38 @@ def test_stamp_walls_accumulates_into_report_extra():
 
 
 def test_sync_engine_resolves_inline():
-    ops = FakeOps(async_dispatch=False)
-    ex = BatchExecutor(ops, depth=4, report=_rep())
+    """An engine whose chunk is a generator of many launches (the
+    Hirschberg ops' shape): `dispatch` advances it to its first wait,
+    `unpack` drives it to its end.  At depth 1 the chunk resolves in
+    its own submit, through the dispatched generator and not a second
+    attempt."""
+
+    class StepOps(FakeOps):
+        def dispatch(self, ctx, kind, packed, chunk):
+            self.dispatches += 1
+
+            def steps():
+                yield                       # launches out, would block
+                return [x * 10 for x in packed]
+
+            gen = steps()
+            next(gen)
+            return gen
+
+        def unpack(self, ctx, kind, gen):
+            self.unpacks += 1
+            try:
+                while True:
+                    next(gen)
+            except StopIteration as stop:
+                return stop.value
+
+    ops = StepOps()
+    ex = BatchExecutor(ops, depth=1, report=_rep())
     ex.submit(None, [1, 2])
-    # resolved before flush: host-orchestrated engines never queue
+    # resolved before flush: nothing queues at depth 1
     assert [(i, r) for _, i, r in ops.installed] == [(1, 10), (2, 20)]
-    assert ops.dispatches == 0 and ops.unpacks == 0 and ops.attempts == 1
+    assert ops.dispatches == 1 and ops.unpacks == 1 and ops.attempts == 0
     ex.flush()
     assert len(ops.installed) == 2
 
@@ -171,15 +201,16 @@ def test_dispatch_failure_resolves_through_lattice():
 def test_transient_failure_retried_at_tier(monkeypatch):
     monkeypatch.setenv("RACON_TPU_TIER_RETRIES", "1")
     rep = _rep()
-    # sync engine so the FIRST lattice attempt is the serving call
-    ops = FakeOps(async_dispatch=False,
-                  fail={1: RuntimeError("transient")})
+    # the dispatched futures fail at the copy back (lattice attempt 0);
+    # the one retry re-attempts the chunk from its packed views
+    ops = FakeOps(unpack_fail={1})
     ex = BatchExecutor(ops, report=rep)
     ex.submit(None, [1, 2, 3])
     ex.flush()
     assert [(i, r) for _, i, r in ops.installed] == \
         [(1, 10), (2, 20), (3, 30)]
     assert rep.retries == 1 and rep.bisections == 0
+    assert ops.attempts == 1
     assert not ops.demoted
 
 
@@ -187,6 +218,11 @@ def test_poisoned_item_bisected_and_quarantined(monkeypatch):
     monkeypatch.setenv("RACON_TPU_TIER_RETRIES", "0")
 
     class PoisonOps(FakeOps):
+        def dispatch(self, ctx, kind, packed, chunk):
+            if 3 in chunk:
+                raise RuntimeError("poisoned")
+            return super().dispatch(ctx, kind, packed, chunk)
+
         def attempt(self, ctx, kind, sub):
             self.attempts += 1
             if 3 in sub:
@@ -194,7 +230,7 @@ def test_poisoned_item_bisected_and_quarantined(monkeypatch):
             return [x * 10 for x in sub]
 
     rep = _rep()
-    ops = PoisonOps(async_dispatch=False)
+    ops = PoisonOps()
     ex = BatchExecutor(ops, report=rep)
     ex.submit(None, [1, 2, 3, 4])
     ex.flush()

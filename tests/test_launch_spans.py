@@ -119,6 +119,119 @@ def test_align_pairs_emits_the_launch_spans_and_counters():
     assert len(_spans()) <= 8 * len(dispatch)
 
 
+def _by_time(events):
+    return sorted(events, key=lambda e: (e["ts"], -e["dur"]))
+
+
+def test_align_launches_are_issued_ahead_of_their_waits(monkeypatch):
+    """The issue order that keeps the device fed.  In a round every
+    bucket's forward and backward launch is dispatched before the first
+    wait; up to BASE_AHEAD base launches are out before the first base
+    wait, and each later one goes out as one comes back.  Each launch
+    is still one dispatch and one wait, and says once whether it found
+    an earlier launch still out (``align.queue.behind``) or the device
+    with nothing to do (``.empty``)."""
+    rng = random.Random(6)
+    # 16 + 120 base tasks: three base launches, against a bound of two
+    monkeypatch.setattr(align_pallas, "BASE_AHEAD", 2)
+    pairs = [_pair(rng, 1400), _pair(rng, 1100)] + \
+        [_pair(rng, 300, rate=0.03) for _ in range(60)]
+    _armed()
+    res = align_pallas.align_pairs(pairs, interpret=True)
+    assert all(r is not None for r in res)
+    counters = obs.snapshot()["counters"]
+    spans = _by_time(_spans())
+    launches = [e for e in spans
+                if e["name"] in ("align.dispatch", "align.wait")]
+    assert len(_spans("align.dispatch")) == len(_spans("align.wait"))
+
+    # rounds: inside each align.round span, 2 dispatches per bucket and
+    # no wait; the waits follow, forward before backward
+    rounds = _spans("align.round")
+    assert len(rounds) >= 2
+    for r in rounds:
+        lo, hi = r["ts"], r["ts"] + r["dur"]
+        inside = [e for e in launches if lo <= e["ts"] <= hi]
+        assert [e["name"] for e in inside] == \
+            ["align.dispatch"] * (2 * r["args"]["buckets"])
+        assert [e["args"]["kernel"] for e in inside] == \
+            ["edge_fwd", "edge_bwd"] * r["args"]["buckets"]
+    edge = [e for e in launches if e["args"]["kernel"] != "base"]
+    first_wait = next(i for i, e in enumerate(edge)
+                      if e["name"] == "align.wait")
+    # edge_bwd's dispatch precedes edge_fwd's wait
+    assert [(e["name"], e["args"]["kernel"])
+            for e in edge[first_wait - 1:first_wait + 2]] == [
+        ("align.dispatch", "edge_bwd"), ("align.wait", "edge_fwd"),
+        ("align.wait", "edge_bwd")]
+
+    # base: the launches go out ahead of the first wait
+    base = [e["name"] for e in launches if e["args"]["kernel"] == "base"]
+    assert counters["align.launches.base"] == 3
+    assert base == ["align.dispatch"] * 2 + [
+        "align.wait", "align.dispatch", "align.wait", "align.wait"]
+
+    assert counters["align.queue.behind"] + counters["align.queue.empty"] \
+        == counters["align.launches.edge"] + counters["align.launches.base"]
+    # driven alone, the queue is empty when a round starts and when the
+    # base phase starts, and never in between
+    assert counters["align.queue.empty"] == len(rounds) + 1
+
+
+def test_no_align_span_is_open_across_a_yield():
+    """`Span` enters a `jax.profiler.TraceAnnotation`: one held open
+    over a yield would be closed on another cohort's time, out of
+    order.  Two cohorts advanced in turn with an instant event at every
+    hand-over: no ``align.*`` span contains one, the spans of the two
+    nest properly on the one thread, and sharing the set of launches in
+    flight shows in the counters: fewer launches find the queue empty
+    than when each cohort runs alone."""
+    import time
+
+    rng = random.Random(8)
+    cohorts = [[_pair(rng, 1400), _pair(rng, 200)],
+               [_pair(rng, 1100), _pair(rng, 600), _pair(rng, 90)]]
+    empty_alone = 0
+    for c in cohorts:
+        _armed()
+        align_pallas.align_pairs(c, interpret=True)
+        empty_alone += obs.snapshot()["counters"]["align.queue.empty"]
+
+    _armed()
+    in_flight = set()
+    gens = [align_pallas.align_steps(c, interpret=True, in_flight=in_flight)
+            for c in cohorts]
+    live = list(gens)
+    while live:
+        for g in list(live):
+            try:
+                next(g)
+            except StopIteration:
+                live.remove(g)
+            time.sleep(0.002)
+            obs.event("test.handover")
+            time.sleep(0.002)
+    events = obs.tracer().events()
+    handovers = [e["ts"] for e in events if e["name"] == "test.handover"]
+    spans = [e for e in _spans() if e["name"].startswith("align.")]
+    assert len(handovers) >= 8 and spans
+    for sp in spans:
+        lo, hi = sp["ts"], sp["ts"] + sp["dur"]
+        assert not any(lo < t < hi for t in handovers), sp
+    # proper nesting: two spans are apart or one holds the other
+    for a in spans:
+        for b in spans:
+            a0, a1 = a["ts"], a["ts"] + a["dur"]
+            b0, b1 = b["ts"], b["ts"] + b["dur"]
+            assert a1 <= b0 or b1 <= a0 or (a0 <= b0 and b1 <= a1) \
+                or (b0 <= a0 and a1 <= b1), (a, b)
+    counters = obs.snapshot()["counters"]
+    assert counters["align.queue.behind"] + counters["align.queue.empty"] \
+        == counters["align.launches.edge"] + counters["align.launches.base"]
+    assert counters["align.queue.empty"] < empty_alone
+    assert not in_flight
+
+
 # ------------------------------------------------------- consensus launches
 
 def test_poa_chunk_emits_the_launch_spans_and_counters(tmp_path,
