@@ -39,11 +39,20 @@ In-band-only contract as the reference's banded CUDA aligner; pairs whose
 optimal path escapes the band are detected (INF at a midpoint) and left to
 the host engine.
 
-Multi-device: kernel batches whose size divides the mesh shard over the
-1-D `windows` axis (shard_map, leading batch dim, zero collectives) —
-the same batch striping as the consensus path and the analogue of the
-reference's per-GPU aligner batches
-(/root/reference/src/cuda/cudapolisher.cpp:96-114).
+Multi-device: on a mesh of m > 1 chips every launch the partitioner's
+gate admits (`will_shard`: at least one row per shard — a launch has at
+least GROUP rows and is a power of two, so on four or eight chips that
+is every launch) runs under shard_map over the 1-D `windows` axis
+(leading batch dim, zero collectives): the same batch striping as the
+consensus path and the analogue of the reference's per-GPU aligner
+batches (/root/reference/src/cuda/cudapolisher.cpp:96-114).  Each shard
+takes B / m consecutive slots, so the host deals the launch's ordered
+programs round the shards (`_deal_programs`); a share of fewer than
+GROUP rows runs as one program with idle sublanes (`_group_rows`): the
+gate knows nothing of GROUP.  ``align.mesh.*`` counts, per launch,
+whether it went over the mesh, its real and pad rows there, and the
+per-shard programs that were whole or short of eight.  Where a pair is
+aligned never changes its CIGAR.
 """
 
 from __future__ import annotations
@@ -483,10 +492,17 @@ def _interpret() -> bool:
     return _jax.devices()[0].platform != "tpu"
 
 
+class _InFlight(set):
+    """The launches dispatched and not yet waited for (what the device
+    has to do while the host works), and how many launches this set has
+    seen go over the mesh and to one device (the report's tally)."""
+
+    sharded = single = 0
+
+
 class _Launch:
-    """One dispatched kernel launch, a member of `in_flight` (the set of
-    launches dispatched and not yet waited for: what the device has to
-    do while the host works) until `wait` has blocked for its outputs."""
+    """One dispatched kernel launch, a member of `in_flight` until
+    `wait` has blocked for its outputs."""
 
     __slots__ = ("in_flight", "outs", "span_args")
 
@@ -530,13 +546,13 @@ def align_steps(pairs, *, interpret=None, band_overrides=None, hits=None,
     recursion), gets result None, and its index is added to `hits` for
     the caller's verify-and-widen ladder.
 
-    in_flight: the set this call's launches join while they are out
-    (`_Launch`); cohorts that share the device share one.
+    in_flight: the `_InFlight` set this call's launches join while they
+    are out (`_Launch`); cohorts that share the device share one.
     """
     if interpret is None:
         interpret = _interpret()
     if in_flight is None:
-        in_flight = set()
+        in_flight = _InFlight()
     results = [None] * len(pairs)
     segments = {}   # pair index -> list of (ia, ops array)
     bands = {}
@@ -681,8 +697,13 @@ def _launch(in_flight, kernel, call, args, n_real, **geom):
     of kernel and copy when the host comes to need the outputs.  `n_real`
     of the batch's rows are tasks, the rest pads it to a power of two.
 
-    Counted here, once per launch: over a mesh the rows per device
-    (count_shard_rows); how well the lock-step programs engage —
+    Counted here, once per launch: whether it went over the mesh
+    (``align.mesh.launches.sharded`` / ``.single``) and, if it did, the
+    rows per device (count_shard_rows, shared with consensus), its own
+    ``align.mesh.rows.real`` / ``.pad`` and the grid programs its shards
+    ran: ``align.mesh.programs.whole`` with all GROUP sublanes offered a
+    slot, ``.short`` where a shard's share is under GROUP rows (one
+    program, idle sublanes); how well the lock-step programs engage —
     ``align.lockstep.rows.real`` the DP rows the tasks asked for,
     ``.slots`` the sublane-rows their programs ran (GROUP x each
     program's largest R); and whether the launch found the device fed:
@@ -690,16 +711,27 @@ def _launch(in_flight, kernel, call, args, n_real, **geom):
     ``align.queue.empty`` (the device idles until this one arrives)."""
     B = len(args[0])
     shards = _dispatch_shards(B)
+    per_shard = B // shards
     if shards > 1:
         from .batch_exec import count_shard_rows
 
         count_shard_rows(n_real, B, shards)
-    rows = args[0][:, 0].reshape(-1, min(GROUP, B // shards))
+        in_flight.sharded += 1
+        obs.count("align.mesh.launches.sharded")
+        obs.count("align.mesh.rows.real", n_real)
+        obs.count("align.mesh.rows.pad", B - n_real)
+        obs.count("align.mesh.programs.whole" if per_shard >= GROUP
+                  else "align.mesh.programs.short",
+                  shards * max(1, per_shard // GROUP))
+    else:
+        in_flight.single += 1
+        obs.count("align.mesh.launches.single")
+    rows = args[0][:, 0].reshape(-1, min(GROUP, per_shard))
     obs.count("align.lockstep.rows.real", int(rows.sum()))
     obs.count("align.lockstep.rows.slots",
               GROUP * int(rows.max(axis=1).sum()))
     obs.count("align.queue.behind" if in_flight else "align.queue.empty")
-    span_args = dict(kernel=kernel, B=B, **geom)
+    span_args = dict(kernel=kernel, B=B, shards=shards, **geom)
     with obs.span("align.dispatch", cat="launch", **span_args):
         outs = call(B)(*args)
         if not isinstance(outs, (tuple, list)):
@@ -978,7 +1010,7 @@ class _HirschbergOps:
         self.band = {}            # job -> band.BandState (banded jobs)
         self.dead = False
         self.cohorts = {}         # first job -> _Cohort, while in flight
-        self.in_flight = set()    # their launches not yet waited for
+        self.in_flight = _InFlight()  # their launches not yet waited for
         # under a watchdog deadline `unpack` runs on a thread the lattice
         # may abandon, and no generator may be left to two threads: then
         # nothing is advanced but the cohort being resolved
@@ -1274,4 +1306,8 @@ def run_jobs(pipeline, jobs, cohort: int = None, report=None,
             report.record_degrade("hirschberg", "host", cause)
     if report is not None:
         executor.stamp_walls(report)
+        # how many of the phase's launches went over the mesh
+        report.extra.setdefault("kernels", {}).update(
+            launches_sharded=ops_obj.in_flight.sharded,
+            launches_single=ops_obj.in_flight.single)
     return state["served"]

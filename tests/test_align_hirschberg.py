@@ -139,28 +139,21 @@ def test_polish_with_hirschberg_engine(tmp_path, monkeypatch):
     assert native.edit_distance(dev[0][1].encode(), truth.encode()) <= 8
 
 
-def test_sharded_batches_over_mesh_exact(monkeypatch):
-    """A homogeneous batch that divides the 8-device mesh runs the edge
-    and base kernels under shard_map (the consensus path's no-collective
-    batch striping) and must emit the same exact-optimal paths as the
+@pytest.mark.parametrize("shards", [4, 8])
+def test_sharded_batches_over_mesh_exact(monkeypatch, shards):
+    """Every launch of a homogeneous batch runs the edge and base kernels
+    under shard_map over the 4- or 8-device mesh (the consensus path's
+    no-collective batch striping), says so in its counters and span
+    arguments, and emits the same exact-optimal paths as the
     single-device build."""
-    import jax
+    from racon_tpu import obs
+    from racon_tpu.parallel import reset_partitioner
 
-    if len(jax.devices()) < 8:
-        pytest.skip("needs the suite's 8-virtual-device mesh")
-
-    shard_calls = []
-    real = align_pallas._shard_over_mesh
-
-    def recording(build_local, batch, n_in, n_out):
-        out = real(build_local, batch, n_in, n_out)
-        shard_calls.append((batch, out is not None))
-        return out
-
-    monkeypatch.setattr(align_pallas, "_shard_over_mesh", recording)
-    # fresh builders so cached single-device jits can't bypass the recorder
+    monkeypatch.setenv("RACON_TPU_MESH_SHAPE", str(shards))
+    # fresh builders: the jitted kernels keep the mesh they were built under
     align_pallas._build_edge_kernel.cache_clear()
     align_pallas._build_base_kernel.cache_clear()
+    reset_partitioner()
 
     rng = random.Random(23)
     pairs = []
@@ -171,15 +164,39 @@ def test_sharded_batches_over_mesh_exact(monkeypatch):
     enc = [(encode(np.frombuffer(q, np.uint8)).astype(np.int32),
             encode(np.frombuffer(t, np.uint8)).astype(np.int32))
            for q, t in pairs]
-    results = align_pallas.align_pairs(enc, interpret=True)
+    obs.reset()
+    obs.configure(metrics=True)
+    try:
+        results = align_pallas.align_pairs(enc, interpret=True)
+        counters = obs.snapshot()["counters"]
+        launches = [e["args"] for e in obs.tracer().events()
+                    if e["ph"] == "X" and e["name"] == "align.dispatch"]
+    finally:
+        obs.reset()
+        align_pallas._build_edge_kernel.cache_clear()
+        align_pallas._build_base_kernel.cache_clear()
 
-    assert any(ok for _, ok in shard_calls), shard_calls  # mesh engaged
+    # two rounds (8 tasks of 700 rows, 16 of 350) and the base launch
+    # (32 of 175): each one over the mesh, none on one device
+    assert [(a["kernel"], a["B"]) for a in launches] == [
+        ("edge_fwd", 8), ("edge_bwd", 8), ("edge_fwd", 16),
+        ("edge_bwd", 16), ("base", 32)]
+    assert all(a["shards"] == shards for a in launches)
+    assert counters["align.mesh.launches.sharded"] == 5
+    assert "align.mesh.launches.single" not in counters
+    assert counters["align.mesh.rows.real"] == 2 * 8 + 2 * 16 + 32
+    assert counters["align.mesh.rows.pad"] == 0
+    assert all(counters[f"shard.rows.d{i}"] == 80 // shards
+               for i in range(shards))
+    # a share of eight or more rows is whole programs (the base launch on
+    # four devices), a smaller one is one short program per shard
+    whole = 32 // 8 if shards == 4 else 0
+    assert counters.get("align.mesh.programs.whole", 0) == whole
+    assert counters["align.mesh.programs.short"] \
+        == shards * (5 if shards == 8 else 4)
     for (q, t), ops in zip(pairs, results):
         assert ops is not None
         assert path_cost(ops, q, t) == native.edit_distance(q, t)
-
-    align_pallas._build_edge_kernel.cache_clear()
-    align_pallas._build_base_kernel.cache_clear()
 
 
 def test_engine_auto_defaults_to_hirschberg_on_tpu(monkeypatch):
@@ -497,7 +514,7 @@ def test_stepped_driver_agrees_with_the_blocking_one(split):
         assert n_yields >= 4
         got = align_pallas.align_pairs(pairs, interpret=True)
     else:
-        in_flight = set()
+        in_flight = align_pallas._InFlight()
         a, b = _interleave([
             align_pallas.align_steps(part, interpret=True,
                                      in_flight=in_flight)
@@ -511,7 +528,7 @@ def test_abandoned_steps_leave_no_launch_in_the_set():
     """A generator dropped at a yield (its cohort failed, or the engine
     stopped) takes its launches out of the shared set, so the next
     cohort's `align.queue.*` counters still tell the truth."""
-    in_flight = set()
+    in_flight = align_pallas._InFlight()
     steps = align_pallas.align_steps(_mixed()[:3], interpret=True,
                                      in_flight=in_flight)
     next(steps)

@@ -198,7 +198,7 @@ def test_no_align_span_is_open_across_a_yield():
         empty_alone += obs.snapshot()["counters"]["align.queue.empty"]
 
     _armed()
-    in_flight = set()
+    in_flight = align_pallas._InFlight()
     gens = [align_pallas.align_steps(c, interpret=True, in_flight=in_flight)
             for c in cohorts]
     live = list(gens)

@@ -47,16 +47,22 @@ def _export_tpu(fn, args):
 
 
 @functools.lru_cache(maxsize=1)
-def _v5e():
-    """Sharding on one described (not attached) v5e chip, or None."""
+def _v5e_devices():
+    """The four chips of a described (not attached) v5e host, or None."""
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+        return topologies.get_topology_desc("v5e:2x2", "tpu").devices
     except Exception:  # noqa: BLE001 — no libtpu / no such topology
         return None
-    return SingleDeviceSharding(topo.devices[0])
+
+
+def _v5e():
+    """Sharding on one described v5e chip, or None."""
+    from jax.sharding import SingleDeviceSharding
+
+    devices = _v5e_devices()
+    return None if devices is None else SingleDeviceSharding(devices[0])
 
 
 def _compile_v5e(fn, args):
@@ -236,3 +242,49 @@ def test_lockstep_edge_kernels_compile_for_v5e(single_device, rcap, K,
 def test_lockstep_base_kernel_compiles_for_v5e(single_device, K):
     # the move matrix of eight tasks, a byte per row: 0.5 and 4 MB of VMEM
     _compile_v5e(*_base(K, TPU_BATCH))
+
+
+# -- Mosaic compile over the mesh ------------------------------------------
+
+@pytest.fixture
+def v5e_mesh(monkeypatch):
+    """The partitioner over the (4, 1) mesh of a described v5e:2x2 host
+    in place of the one over this process's CPU devices: the Hirschberg
+    builders then wrap their kernels in shard_map for four chips."""
+    from jax.sharding import Mesh
+
+    from racon_tpu.parallel import axes, partitioner
+
+    devices = _v5e_devices()
+    if devices is None:
+        pytest.skip("this libtpu cannot describe a v5e topology")
+    mesh = Mesh(np.asarray(devices, dtype=object).reshape(4, 1),
+                axes.MESH_AXES)
+    part = partitioner.Partitioner(mesh, axes.rules_key())
+    monkeypatch.setattr(partitioner, "get_partitioner", lambda: part)
+    align_pallas._build_edge_kernel.cache_clear()
+    align_pallas._build_base_kernel.cache_clear()
+    yield part
+    align_pallas._build_edge_kernel.cache_clear()
+    align_pallas._build_base_kernel.cache_clear()
+
+
+@pytest.mark.parametrize("B", [8, 16, 32, 64])
+@pytest.mark.parametrize("kernel", ["edge_fwd", "edge_bwd", "base"])
+def test_sharded_hirschberg_kernels_compile_for_a_v5e_host(v5e_mesh, kernel,
+                                                           B):
+    """The launches ``ecoli-ont-x4.paf`` ships, at the bucket 8 kb ONT
+    reads land in: a shard's share of 2 or 4 rows (one program with idle
+    sublanes, padded inside shard_map), 8 (one whole program) and 16
+    (two).  One Mosaic kernel per chip and no collective: the batch is
+    striped, nothing crosses chips."""
+    assert align_pallas._dispatch_shards(B) == 4
+    fn, args = (_base(1024, B) if kernel == "base"
+                else _edge(8192, 1024, kernel == "edge_bwd", B))
+    rows = v5e_mesh.sharding("windows")
+    specs = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rows)
+             for a in args]
+    text = fn.lower(*specs).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not [c for c in ("all-gather", "all-reduce", "all-to-all",
+                            "collective-permute") if c in text]
