@@ -205,11 +205,15 @@ def render(doc: dict, path: str) -> str:
             mix = "  ".join(f"{t}={n}" for t, n in sorted(tiers.items()))
             lines.append(f"  {phase:<16s} {mix}  (sum="
                          f"{sum(tiers.values())})")
-    mesh = {k: v for k, v in sorted(b["counters"].items())
-            if k.startswith("align.mesh.")}
-    if mesh:
-        lines.append("-- alignment launches over the mesh " + "-" * 8)
-        lines += [f"  {k:<32s} {v}" for k, v in mesh.items()]
+    for title, prefixes in (
+            ("alignment launches over the mesh", ("align.mesh.",)),
+            ("consensus programs in lock-step",
+             ("poa.programs.", "poa.lockstep."))):
+        rows = {k: v for k, v in sorted(b["counters"].items())
+                if k.startswith(prefixes)}
+        if rows:
+            lines.append(f"-- {title} " + "-" * (43 - len(title)))
+            lines += [f"  {k:<32s} {v}" for k, v in rows.items()]
     if b["span_quantiles"]:
         lines.append("-- span durations (p50/p99 from log2 histograms) --")
         for name, q in b["span_quantiles"].items():

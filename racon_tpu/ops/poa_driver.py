@@ -106,7 +106,10 @@ def _device_batch(use_pallas: bool) -> int:
     windows to the slow path; pad rows are 1-base/0-layer windows and
     show up in `shard.pad_rows`); with the Pallas tier on, the lockstep
     kernel additionally needs the per-shard batch to be a multiple of
-    its sublane group G (the XLA twin takes any batch)."""
+    its sublane group G (the XLA twin takes any batch).  The batch
+    does not follow the kernel's group width, the width follows the
+    batch (_group_width): 64 windows, or 16 a shard, run as programs of
+    sixteen; a batch of 8 somebody asked for stays 8."""
     B = _batch_size()
     m = _shard_n(B)
     if m > 1:
@@ -330,8 +333,8 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
                 # Sequential loops run lock-step across the batch, so keep
                 # batches depth-homogeneous — and length-homogeneous
                 # within equal depth: a lockstep program's DP range is the
-                # union over its 8 windows, so mixing a short window into
-                # a long group bills it the long group's ranks.
+                # union over its 8 or 16 windows, so mixing a short window
+                # into a long program bills it the long program's ranks.
                 bucket_jobs.sort(key=lambda job: (job[1], job[2]))
                 ctx = _BucketCtx(cfg, entry_kind)
                 for off in range(0, len(bucket_jobs), B):
@@ -627,9 +630,17 @@ class _ConsensusOps:
         # windows are 1-base/0-layer — free).
         return _pack(chunk, ctx.cfg, self.B, self._widths(chunk, ctx.cfg))
 
+    def _groups(self, ctx, kind):
+        """Group width of the kernel the last live_tier built (which
+        keyed on the same partitioner state shard_multiple reads)."""
+        if kind != "ls":
+            return 0
+        return _group_width(ctx.cfg,
+                            self.B // self.shard_multiple(ctx, None))
+
     def dispatch(self, ctx, kind, packed, chunk):
         faults.check(f"poa.run.{kind}", [i for i, _, _ in chunk])
-        _count_launch(len(chunk), packed)
+        _count_launch(len(chunk), packed, self._groups(ctx, kind))
         return _submit(ctx.kernel, packed, kind == "ls",
                        _band_active(kind))
 
@@ -638,7 +649,7 @@ class _ConsensusOps:
         banded = _band_active(kind)
         faults.check(f"poa.run.{kind}", [i for i, _, _ in sub])
         packed = _pack(sub, ctx.cfg, self.B, self._widths(sub, ctx.cfg))
-        _count_launch(len(sub), packed)
+        _count_launch(len(sub), packed, self._groups(ctx, kind))
         return _unpack(_submit(ctx.kernel, packed, pallas, banded),
                        pallas, banded)
 
@@ -725,29 +736,42 @@ def _platform() -> str:
     return jax.devices()[0].platform
 
 
-def _fits_vmem(cfg, budget_bytes: int = 11 << 20) -> bool:
-    """Whether the lockstep Pallas kernel's VMEM scratch fits the core
-    budget.  Mirrors poa_pallas_ls.py's scratch_shapes: a 128-row H ring
-    instead of the full H matrix, plus rank-space graph arrays and
-    per-layer DMA slots; layers stream from HBM, so depth does not
-    appear.  The budget is where the v5e compiler draws the line, in
-    this sum's terms: it leaves out Mosaic's own temporaries, and the
-    compiler took every geometry summing to 10.4 MiB or less and
-    refused every one from 11.5 MiB up (16.3 MB against its 16 MB
-    scoped limit; classes 896-1152 at NODE_FACTOR 3 and 4).  So at
-    NODE_FACTOR 3 classes up to 1024 fit, -w 1000 included
-    (tests/test_pallas_ls.py holds the table, tests/test_tpu_lowering.py
-    compiles its last row)."""
-    from .poa_pallas_ls import G, RING, _round_up
+#: sublane groups a lockstep program may run, widest first
+GROUP_WIDTHS = (2, 1)
 
-    NC = cfg.max_nodes // 128
-    JC = _round_up(cfg.max_len + 1, 128) // 128
-    lane_bytes = G * 128 * 4
-    ring = RING * JC * lane_bytes
-    j_rows = (1 + 2 + 2 * 2) * JC * lane_bytes   # H0, nkey/runrem, scr
-    n_rows = (9 + 2 * cfg.max_edges) * NC * lane_bytes
-    io = 4 * NC * lane_bytes                      # bb/bbw in, cons out
-    return ring + j_rows + n_rows + io < budget_bytes
+
+def _fits_vmem(cfg, groups: int = 1) -> bool:
+    """Whether the lockstep Pallas kernel's VMEM arrays
+    (poa_pallas_ls.scratch_bytes) fit at `groups` sublane groups a
+    program.  A group is held to where the v5e compiler draws the line
+    under its default 16 MB scoped limit, in that sum's terms: the sum
+    leaves out Mosaic's own temporaries, and the compiler refused every
+    program of eight from 11.5 MiB up (classes 896-1152 at NODE_FACTOR 3
+    and 4).  So at NODE_FACTOR 3 classes up to 1024 fit, -w 1000
+    included, and 1152 / 1280 enter at the XLA twin
+    (tests/test_pallas_ls.py holds the table, tests/test_tpu_lowering.py
+    compiles its last row).  A wider program admits no class that one
+    group does not; past class 512 its sum outgrows the default limit
+    and it is compiled under one sized from the sum
+    (poa_pallas_ls.vmem_limit_bytes), which may not pass half the
+    chip's VMEM."""
+    from . import poa_pallas_ls as ls
+
+    limit = ls.vmem_limit_bytes(cfg, groups)
+    return (ls.scratch_bytes(cfg) < ls.DEFAULT_LIMIT_HOLDS
+            and (limit is None or limit <= ls.VMEM_CEILING))
+
+
+def _group_width(cfg, shard_batch: int) -> int:
+    """Sublane groups U a lockstep program runs, so U x 8 windows under
+    one control flow: the widest the per-shard batch divides into and
+    VMEM holds.  A function of the window class, the per-shard batch
+    and the VMEM sum, nothing else: 64 windows on one chip and 16 a
+    shard on four both give 2; a batch of 8 gives 1."""
+    from .poa_pallas_ls import G
+
+    return next((u for u in GROUP_WIDTHS
+                 if shard_batch % (u * G) == 0 and _fits_vmem(cfg, u)), 1)
 
 
 def _build_kernel(cfg, B, use_pallas):
@@ -817,15 +841,19 @@ def _build_kernel_cached(cfg, B, use_pallas, n_dev, platform, shard_n=1,
     assert not (use_pallas and not _fits_vmem(cfg)), (
         "caller must check _fits_vmem before requesting the pallas kernel")
     if use_pallas:
-        from .poa_pallas_ls import build_lockstep_poa_kernel as build
+        from .poa_pallas_ls import build_lockstep_poa_kernel
         interp = platform != "tpu"
+
+        def build(b):
+            return build_lockstep_poa_kernel(
+                cfg, interpret=interp, band=banded,
+                groups=_group_width(cfg, b))(b)
+
         if shard_n <= 1:
-            return build(cfg, interpret=interp, band=banded)(B)
+            return build(B)
         from ..parallel.partitioner import get_partitioner
         n_in, n_out = (10, 6) if banded else (9, 5)
-        sharded = get_partitioner().shard_build(
-            lambda b: build(cfg, interpret=interp, band=banded)(b),
-            B, n_in, n_out)
+        sharded = get_partitioner().shard_build(build, B, n_in, n_out)
         assert sharded is not None, (B, shard_n)  # _device_batch divides B
         return sharded
     kernel = poa.build_poa_kernel(cfg)
@@ -919,14 +947,32 @@ def _pack(chunk, cfg, pad_to=None, band_widths=None):
     return (bb, bbw, bb_len, n_layers, seqs, ws, lens, begins, ends, wband)
 
 
-def _count_launch(n_real, packed) -> None:
+def _count_launch(n_real, packed, groups: int = 0) -> None:
     """One batch on its way to the device: `n_real` rows carry a
     window, the rest pad the batch to its compiled size (and to the
-    shard multiple)."""
+    shard multiple).  `groups` is the lockstep kernel's group width for
+    this launch (0: the XLA twin serves, which has no grid programs):
+    its programs count as wide or narrow, both keys at every launch so
+    that a job served by narrow programs alone reads 0 % wide and not
+    nothing, and lock-step is billed what it costs: every window of a
+    program runs the program's largest layer count."""
+    from .poa_pallas_ls import G
+
     rows = len(packed[0])
     obs.count("poa.launches")
     obs.count("poa.rows.real", n_real)
     obs.count("poa.rows.pad", rows - n_real)
+    width = groups * G                 # windows a grid program
+    programs = rows // width if width else 0
+    obs.count("poa.programs.wide", programs if groups > 1 else 0)
+    obs.count("poa.programs.narrow", programs if groups == 1 else 0)
+    if width:
+        # a shard's rows are contiguous and a multiple of the program's
+        # width, so programs are consecutive runs of the packed rows
+        n_layers = np.asarray(packed[3])
+        obs.count("poa.lockstep.layers.real", int(n_layers.sum()))
+        obs.count("poa.lockstep.layers.slots", int(
+            width * n_layers.reshape(-1, width).max(axis=1).sum()))
 
 
 def _submit(kernel, packed, use_pallas, banded=False):

@@ -1,16 +1,24 @@
-"""Lane-lockstep fused Pallas POA kernel (v3).
+"""Lane-lockstep fused Pallas POA kernel (`ls`, `racon_poa_ls`).
 
 Same window-consensus semantics as the host oracle (rt_poa.cpp) and the
-XLA twin (poa.py), laid out for VPU throughput. One window per grid step
-makes the DP inner loop a serial dependency chain of ~50 single-vreg ops
-at ~40 cycles/op of latency (measured: dp_cost_probe, docs/benchmarks.md),
-so the VPU idles most of the time. This kernel runs EIGHT windows per
-grid step in lock-step, one per sublane:
+XLA twin (poa.py), laid out for VPU throughput. A window's DP is a
+serial chain of dependent vector operations (per rank: a vector-to-scalar
+turn, a dynamic loop of ring-row loads, a shift, ten dependent lane
+rolls, a masked reduce, a read-modify-write; ~700-1000 cycles a rank on
+the v5e whether a row holds 4 vregs or 7), so one window per program
+leaves the VPU idle. One grid program therefore runs U x 8 windows in
+lock-step under ONE control flow: one window per sublane, U sublane
+groups (U = `groups`: 2 where the per-shard batch and VMEM allow, which
+is every geometry the benchmark's cells run; the driver derives it, see
+poa_driver._group_width):
 
-  * j-rows: (JC, 8, 128) — window g in sublane g, DP column j at
-    [j // 128, g, j % 128]. Every row op serves all 8 windows at once,
-    with lane-only prefix scans.
-  * The graph lives in RANK SPACE: arrays (NC, 8, 128) keyed by
+  * j-rows: (U, JC, 8, 128) — window u*8 + g of the program in group u,
+    sublane g, DP column j at [u, j // 128, g, j % 128]. Every row op
+    serves all U x 8 windows at once, with lane-only prefix scans; a
+    loop branch, a vector-to-scalar turn or a `pl.when` is paid once
+    per program step. Nothing crosses the group axis except the loop
+    bounds, which are maxima or unions over the program's windows.
+  * The graph lives in RANK SPACE: arrays (U, NC, 8, 128) keyed by
     topological rank (= column-key order), with in-edges stored as rank
     DISTANCES (rk_delta). Node insertion is a lane shift; there are no
     node ids at all. Rank distance is bounded in practice: measured max
@@ -18,9 +26,11 @@ grid step in lock-step, one per sublane:
     edges (RT_POA_STATS histograms), so distances are capped at DMAX=64
     and a window with a longer in-subgraph edge fails to the host path
     (the same degradation lattice as every other device limit).
-  * H rows live in a 128-row rank-keyed VMEM ring (the distance cap makes
-    older rows dead); completed 64-row chunks are DMA'd to an HBM spill
-    buffer under the compute.
+    Insertion alone is bound by throughput, not latency, and is gated
+    per group: a group pays for its own windows' insertions only.
+  * H rows live in a 128-row rank-keyed VMEM ring (RING, U, JC, 8, 128)
+    (the distance cap makes older rows dead); completed 64-row chunks
+    are DMA'd to an HBM spill buffer under the compute.
   * No move matrix. The traceback re-derives moves from H values exactly
     like the pure-JAX twin (poa.py _traceback, differentially verified
     against the host), walking rank blocks top-down with the spill buffer
@@ -60,9 +70,50 @@ def _round_up(x, m):
     return (x + m - 1) // m * m
 
 
+def scratch_bytes(cfg: PoaConfig, groups: int = 1) -> int:
+    """VMEM the kernel's arrays sum to at `groups` sublane groups a
+    program.  Mirrors make()'s scratch_shapes: a 128-row H ring instead
+    of the full H matrix, plus rank-space graph arrays and per-layer DMA
+    slots; layers stream from HBM, so depth does not appear.  Mosaic's
+    own temporaries are left out (poa_driver._fits_vmem holds the sum
+    to where the compiler draws the line)."""
+    NC = cfg.max_nodes // 128
+    JC = _round_up(cfg.max_len + 1, 128) // 128
+    lane_bytes = groups * G * 128 * 4
+    ring = RING * JC * lane_bytes
+    j_rows = (1 + 2 + 2 * 2) * JC * lane_bytes   # H0, nkey/runrem, scr
+    n_rows = (9 + 2 * cfg.max_edges) * NC * lane_bytes
+    io = 4 * NC * lane_bytes                      # bb/bbw in, cons out
+    return ring + j_rows + n_rows + io
+
+
+#: arrays summing to less than this compile under the v5e compiler's
+#: default 16 MB scoped-VMEM limit (it took every sum up to 10.85 MiB and
+#: refused every one from 11.5 MiB up: 16.3 MB with its own temporaries)
+DEFAULT_LIMIT_HOLDS = 11 << 20
+#: the most a raised limit may ask for: half the v5e's 128 MiB of VMEM
+VMEM_CEILING = 64 << 20
+
+
+def vmem_limit_bytes(cfg: PoaConfig, groups: int):
+    """The scoped-VMEM limit a program is compiled under: None, the
+    compiler's default, wherever the arrays' sum is one the default is
+    known to hold (every program of eight the driver admits, and the
+    program of sixteen up to class 512: 10.85 MiB).  The default is not
+    the chip's VMEM, so a wider program of a larger class asks for
+    twice its own sum: the arrays, and as much again for Mosaic's
+    temporaries, which took 42 % of the sum at one group."""
+    total = scratch_bytes(cfg, groups)
+    if total < DEFAULT_LIMIT_HOLDS:
+        return None
+    return _round_up(2 * total, 1 << 20)
+
+
 @device_keyed_cache(maxsize=32)
 def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
-                              band: bool = False):
+                              band: bool = False, groups: int = 1):
+    U = groups                          # sublane groups per program
+    W = U * G                           # windows per program
     N = cfg.max_nodes
     L = cfg.max_len
     BB = cfg.max_backbone
@@ -99,69 +150,90 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
              seq_scr, w_scr, dma_sem, flush_sem, tb_sem) = refs
         b_prog = pl.program_id(0)
 
-        lane_n = jax.lax.broadcasted_iota(jnp.int32, (NC, G, 128), 2)
-        chunk_n = jax.lax.broadcasted_iota(jnp.int32, (NC, G, 128), 0)
+        # index vectors carry a unit group axis and broadcast over U
+        lane_n = jax.lax.broadcasted_iota(jnp.int32, (1, NC, G, 128), 3)
+        chunk_n = jax.lax.broadcasted_iota(jnp.int32, (1, NC, G, 128), 1)
         rr = chunk_n * 128 + lane_n                    # global rank index
-        lane_j = jax.lax.broadcasted_iota(jnp.int32, (JC, G, 128), 2)
-        chunk_j = jax.lax.broadcasted_iota(jnp.int32, (JC, G, 128), 0)
+        lane_j = jax.lax.broadcasted_iota(jnp.int32, (1, JC, G, 128), 3)
+        chunk_j = jax.lax.broadcasted_iota(jnp.int32, (1, JC, G, 128), 1)
         jj = chunk_j * 128 + lane_j                    # global j index
-        lane1 = jax.lax.broadcasted_iota(jnp.int32, (G, 128), 1)
-        giota = jax.lax.broadcasted_iota(jnp.int32, (1, G, 1), 1)
+        lane1 = jax.lax.broadcasted_iota(jnp.int32, (1, 1, G, 128), 3)
+        giota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, G, 1), 2)
+        uiota = jax.lax.broadcasted_iota(jnp.int32, (U, 1, 1, 1), 0)
         gvec = jj * GP
+        n_shape = (U, NC, G, 128)
+        j_shape = (U, JC, G, 128)
+        w_shape = (U, 1, G, 1)
 
         # ---- helpers ----------------------------------------------------
-        # (1, G, 1) per-window scalar-vectors are the working currency;
+        # (U, 1, G, 1) per-window scalar-vectors are the working currency;
         # extracts are masked sums (zero elsewhere), so indices must be in
         # range — callers clamp.
 
+        # Arrays are (u, C, G, 128) with u = U, or u = 1 inside the
+        # per-group node-insertion block; the index vectors broadcast.
         def glob(x):
-            return rr if x.shape[-3] == NC else jj
+            return rr if x.shape[1] == NC else jj
 
         def lanes_of(x):
-            return lane_n if x.shape[-3] == NC else lane_j
+            return lane_n if x.shape[1] == NC else lane_j
+
+        def wsum(x):
+            """per-window reduction over chunks and lanes -> (u,1,G,1)."""
+            return jnp.sum(x, axis=(1, 3), keepdims=True)
+
+        def wmax(x):
+            return jnp.max(x, axis=(1, 3), keepdims=True)
+
+        def wmin(x):
+            return jnp.min(x, axis=(1, 3), keepdims=True)
+
+        def wany(x):
+            return jnp.any(x, axis=(1, 3), keepdims=True)
 
         def _lane_extract(c, idx):
-            """(G,128) row -> (1,G,1) value at lane idx (masked sum)."""
+            """(U,1,G,128) rows -> (U,1,G,1) value at lane idx (masked
+            sum)."""
             m = lane1 == (idx % 128)
-            return jnp.sum(jnp.where(m, c, jnp.zeros_like(c)), axis=-1,
-                           keepdims=True)[None]
+            return jnp.sum(jnp.where(m, c, jnp.zeros_like(c)), axis=3,
+                           keepdims=True)
 
         def exr(ref, r):
-            """ref (C,G,128) at global index r (shared scalar) -> (1,G,1).
+            """ref (U,C,G,128) at global index r (shared scalar) ->
+            (U,1,G,1).
 
             Reads THROUGH the ref with pl.ds — dynamic_slice on a loaded
             value does not lower to Mosaic (caught by the jax.export
             cross-lowering check; interpret mode accepts it silently).
-            One (1,G,128) VMEM load + a lane mask, not an O(N) masked
+            One (U,1,G,128) VMEM load + a lane mask, not an O(N) masked
             reduction over every chunk."""
-            return _lane_extract(ref[pl.ds(r // 128, 1)][0], r)
+            return _lane_extract(ref[:, pl.ds(r // 128, 1)], r)
 
         def exs(ref, slot, j):
-            """(2,JC,G,128) double-buffer ref at (slot, global j)."""
+            """(2,U,JC,G,128) double-buffer ref at (slot, global j)."""
             return _lane_extract(
-                ref[pl.ds(slot, 1), pl.ds(j // 128, 1)][0, 0], j)
+                ref[pl.ds(slot, 1), :, pl.ds(j // 128, 1)][0], j)
 
         def ex_v(val, rv):
-            """val (C,G,128) at per-window indices rv (1,G,1)."""
+            """val (U,C,G,128) at per-window indices rv (U,1,G,1)."""
             m = glob(val) == rv
-            return jnp.sum(jnp.where(m, val, jnp.zeros_like(val)),
-                           axis=(0, 2), keepdims=True)[:, :, 0:1]
+            return wsum(jnp.where(m, val, jnp.zeros_like(val)))
 
         def rmw(ref, r, v, active):
             """ref value at shared scalar index r <- v where active."""
-            c = ref[pl.ds(r // 128, 1)]
-            m = (lane1 == (r % 128))[None] & active
-            ref[pl.ds(r // 128, 1)] = jnp.where(m, v, c)
+            c = ref[:, pl.ds(r // 128, 1)]
+            m = (lane1 == (r % 128)) & active
+            ref[:, pl.ds(r // 128, 1)] = jnp.where(m, v, c)
 
         def rmw_v(ref, rv, v, active):
-            """masked write at per-window global indices rv (1,G,1)."""
-            ref[...] = jnp.where((glob(ref[...]) == rv) & active, v,
-                                 ref[...])
+            """masked write at per-window global indices rv (u,1,G,1)."""
+            x = ref[...]
+            ref[...] = jnp.where((glob(x) == rv) & active, v, x)
 
         def shift_right(x, fill):
             """lane shift: out[i] = x[i-1], out[0] = fill (global index)."""
-            ln = pltpu.roll(x, 1, 2)
-            carry = pltpu.roll(ln, 1, 0)
+            ln = pltpu.roll(x, 1, 3)
+            carry = pltpu.roll(ln, 1, 1)
             y = jnp.where(lanes_of(x) == 0, carry, ln)
             return jnp.where(glob(x) == 0, fill, y)
 
@@ -170,47 +242,51 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             end; crosses lane chunks."""
             dl = d % 128
             dc = d // 128
-            xs = pltpu.roll(x, -dl, 2)
-            xc = pltpu.roll(xs, -dc, 0)
-            xc2 = pltpu.roll(xs, -(dc + 1), 0)
+            xs = pltpu.roll(x, -dl, 3)
+            xc = pltpu.roll(xs, -dc, 1)
+            xc2 = pltpu.roll(xs, -(dc + 1), 1)
             y = jnp.where(lanes_of(x) < 128 - dl, xc, xc2)
-            top = x.shape[-3] * 128
+            top = x.shape[1] * 128
             return jnp.where(glob(x) + d < top, y, fill)
 
         def cummaxj(x):
-            """prefix max over the global j index of a (JC,G,128) array:
-            radix-4 within lanes, then an exclusive chunk prefix."""
+            """prefix max over the global j index of a (U,JC,G,128)
+            array: radix-4 within lanes, then an exclusive chunk prefix."""
             w = 1
             while w < 128:
                 for k in (1, 2, 3):
                     if k * w < 128:
                         x = jnp.maximum(
                             x, jnp.where(lane_j >= k * w,
-                                         pltpu.roll(x, k * w, 2), NEG))
+                                         pltpu.roll(x, k * w, 3), NEG))
                 w *= 4
-            tot = jnp.max(x, axis=2, keepdims=True)
-            p = jnp.broadcast_to(tot, (JC, G, 128))
-            acc = jnp.full((JC, G, 128), NEG, jnp.int32)
+            tot = jnp.max(x, axis=3, keepdims=True)
+            p = jnp.broadcast_to(tot, j_shape)
+            acc = jnp.full(j_shape, NEG, jnp.int32)
             for k in range(1, JC):
                 acc = jnp.maximum(
-                    acc, jnp.where(chunk_j >= k, pltpu.roll(p, k, 0), NEG))
+                    acc, jnp.where(chunk_j >= k, pltpu.roll(p, k, 1), NEG))
             return jnp.maximum(x, acc)
 
-        def scalar_of(v, g):
-            return jnp.sum(jnp.where(giota == g, v, jnp.zeros_like(v)))
+        # window i = u*G + g of the program sits in group u, sublane g
+        at_window = [(uiota == i // G) & (giota == i % G) for i in range(W)]
+
+        def scalar_of(v, i):
+            return jnp.sum(jnp.where(at_window[i], v, jnp.zeros_like(v)))
 
         def svec(read):
-            """(1,G,1) vector from G SMEM scalars (SMEM is scalar-only)."""
-            v = jnp.zeros((1, G, 1), jnp.int32)
-            for g in range(G):
-                v = jnp.where(giota == g, read(g), v)
+            """(U,1,G,1) vector from W SMEM scalars (SMEM is
+            scalar-only)."""
+            v = jnp.zeros(w_shape, jnp.int32)
+            for i in range(W):
+                v = jnp.where(at_window[i], read(i), v)
             return v
 
-        bb_len = svec(lambda g: bb_len_s[0, 0, g])
-        n_layers = svec(lambda g: n_layers_s[0, 0, g])
+        bb_len = svec(lambda i: bb_len_s[0, 0, i])
+        n_layers = svec(lambda i: n_layers_s[0, 0, i])
         max_layers = jnp.max(n_layers)
         if band:
-            wbv = svec(lambda g: wband_s[0, 0, g])    # (1,G,1) half-band
+            wbv = svec(lambda i: wband_s[0, 0, i])    # (U,1,G,1) half-band
 
         # ---- graph init from the backbone chain ------------------------
         # (parity: rt_poa.cpp add_alignment, empty-alignment branch)
@@ -220,12 +296,12 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
         rk_cov[...] = jnp.where(used0, 1, 0)
         chain = (rr > 0) & used0
         rk_cnt[...] = jnp.where(chain, 1, 0)
-        rk_delta[...] = jnp.zeros((E, NC, G, 128), jnp.int32)
+        rk_delta[...] = jnp.zeros((E,) + n_shape, jnp.int32)
         rk_delta[0:1] = jnp.where(chain, 1, 0)[None]
         bbw = bbw_ref[0]
-        rk_ew[...] = jnp.zeros((E, NC, G, 128), jnp.int32)
+        rk_ew[...] = jnp.zeros((E,) + n_shape, jnp.int32)
         rk_ew[0:1] = jnp.where(chain, shift_right(bbw, 0) + bbw, 0)[None]
-        H0[...] = gvec
+        H0[...] = jnp.broadcast_to(gvec, j_shape)
 
         def start_copy(li, slot):
             pltpu.make_async_copy(seqs_hbm.at[b_prog, li],
@@ -258,12 +334,12 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
         # ================= one layer =====================================
         def do_layer(li, slot, carry):
             if band:
-                n, failed, hit = carry                 # (1,G,1) i32
+                n, failed, hit = carry                 # (U,1,G,1) i32
             else:
-                n, failed = carry                      # (1,G,1) i32
-            Ln = svec(lambda g: lens_s[0, g, li])
-            begin = svec(lambda g: begins_s[0, g, li])
-            end = svec(lambda g: ends_s[0, g, li])
+                n, failed = carry                      # (U,1,G,1) i32
+            Ln = svec(lambda i: lens_s[0, i, li])
+            begin = svec(lambda i: begins_s[0, i, li])
+            end = svec(lambda i: ends_s[0, i, li])
             lact = (li < n_layers) & (Ln > 0) & (failed == 0)
 
             # full-graph rule (reference: src/window.cpp:88-97)
@@ -275,15 +351,12 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                            end.astype(jnp.float32))
 
             keys = rk_key[...]
-            r_lo = jnp.sum(jnp.where(keys < lo, 1, 0), axis=(0, 2),
-                           keepdims=True)[:, :, 0:1]
-            r_hi = jnp.minimum(
-                jnp.sum(jnp.where(keys <= hi, 1, 0), axis=(0, 2),
-                        keepdims=True)[:, :, 0:1], n)
+            r_lo = wsum(jnp.where(keys < lo, 1, 0))
+            r_hi = jnp.minimum(wsum(jnp.where(keys <= hi, 1, 0)), n)
             r_start = jnp.min(jnp.where(lact, r_lo, N))
             r_end = jnp.max(jnp.where(lact, r_hi, 0))
 
-            seqv = seq_scr[pl.ds(slot, 1)][0]          # (JC, G, 128)
+            seqv = seq_scr[pl.ds(slot, 1)][0]          # (U, JC, G, 128)
             seqm1 = shift_right(seqv, 255)             # lane j: seq[j-1]
             rk_dmax[...] = jnp.max(rk_delta[...], axis=0)
 
@@ -297,16 +370,14 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             # window (its H row is evicted from the ring; the host path
             # takes over — the rank-distance histograms say this is rare)
             in_sub = (rr >= r_lo) & (rr < r_hi)
-            far = jnp.zeros((1, G, 1), jnp.int32)
+            far = jnp.zeros(w_shape, jnp.int32)
             for e in range(E):
                 bad = ((delta_v[e] > DMAX) & in_sub &
                        ((rr - delta_v[e]) >= r_lo))
-                far = far | jnp.any(bad, axis=(0, 2),
-                                    keepdims=True)[:, :, 0:1].astype(
-                    jnp.int32)
+                far = far | wany(bad).astype(jnp.int32)
             failed = failed | jnp.where(lact & (far > 0), 1, 0)
 
-            esc[...] = jnp.full((NC, G, 128), NEG, jnp.int32)
+            esc[...] = jnp.full(n_shape, NEG, jnp.int32)
 
             # ---- DP over ranks in lock-step -----------------------------
             rs64 = (r_start // BLK) * BLK
@@ -332,7 +403,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                         has = has | (ds[e] == d)
                     return jnp.where(has, jnp.maximum(P, prow), P)
 
-                P0 = jnp.full((JC, G, 128), NEG, jnp.int32)
+                P0 = jnp.full(j_shape, NEG, jnp.int32)
                 P = jax.lax.fori_loop(1, dmax_r + 1, delta_scan, P0)
                 P = jnp.where(any_valid, P, H0v)
 
@@ -414,13 +485,12 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
 
             has_out = jax.lax.fori_loop(
                 1, dmax_all + 1, out_body,
-                jnp.zeros((NC, G, 128), jnp.int32))
+                jnp.zeros(n_shape, jnp.int32))
             endok = in_sub & (has_out == 0)
 
             escv = jnp.where(endok, esc[...], NEG)
-            best_s = jnp.max(escv, axis=(0, 2), keepdims=True)[:, :, 0:1]
-            best_r = jnp.min(jnp.where((escv == best_s) & endok, rr, N),
-                             axis=(0, 2), keepdims=True)[:, :, 0:1]
+            best_s = wmax(escv)
+            best_r = wmin(jnp.where((escv == best_s) & endok, rr, N))
             has_end = best_s > NEG
             failed = failed | jnp.where(lact & ~has_end, 1, 0)
             if band:
@@ -433,8 +503,8 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             walking = lact & has_end & (failed == 0)
             cur = jnp.where(walking, best_r, -1)
             jcur = jnp.where(walking, Ln, 0)
-            nk0 = jnp.full((1, G, 1), KEY_INF, jnp.float32)
-            run0 = jnp.zeros((1, G, 1), jnp.int32)
+            nk0 = jnp.full(w_shape, KEY_INF, jnp.float32)
+            run0 = jnp.zeros(w_shape, jnp.int32)
             # loop-carried flags are i32 0/1: Mosaic cannot legalize an
             # scf.for / scf.while that carries an i1 vector
             done0 = jnp.where(walking, 0, 1)
@@ -486,7 +556,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 def mscan(d, c2):
                     wdiag, wup = c2
                     prow = ring_row(r - d)
-                    s_of_d = jnp.full((1, G, 1), BIG, jnp.int32)
+                    s_of_d = jnp.full(w_shape, BIG, jnp.int32)
                     for e in range(E - 1, -1, -1):
                         s_of_d = jnp.where(ds[e] == d, e, s_of_d)
                     has = s_of_d < BIG
@@ -497,7 +567,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                     wup = jnp.minimum(wup, jnp.where(um, pk, WNONE))
                     return (wdiag, wup)
 
-                W0 = jnp.full((JC, G, 128), WNONE, jnp.int32)
+                W0 = jnp.full(j_shape, WNONE, jnp.int32)
                 wdiag, wup = jax.lax.fori_loop(1, dmax_r + 1, mscan,
                                                (W0, W0))
                 vdiag = ~any_v & (shift_right(H0v, NEG) + scv == row)
@@ -507,8 +577,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
 
                 # insertion run: walk left to the nearest explained cell
                 okm = ok & (jj <= jcur) & here
-                j_stop = jnp.max(jnp.where(okm, jj, -1), axis=(0, 2),
-                                 keepdims=True)[:, :, 0:1]
+                j_stop = wmax(jnp.where(okm, jj, -1))
                 stuck = here & (j_stop < 0)
                 failed = failed | jnp.where(stuck, 1, 0)
                 done = done | jnp.where(stuck, 1, 0)
@@ -617,10 +686,8 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 keys = rk_key[...]
                 basev = rk_base[...]
                 cand = (keys == k0) & (basev == b)
-                has = jnp.any(cand, axis=(0, 2),
-                              keepdims=True)[:, :, 0:1] & is_match
-                found = jnp.min(jnp.where(cand, rr, N), axis=(0, 2),
-                                keepdims=True)[:, :, 0:1]
+                has = wany(cand) & is_match
+                found = wmin(jnp.where(cand, rr, N))
 
                 runf = run_j.astype(jnp.float32)
                 hi2 = jnp.where(nk_j < KEY_INF, nk_j, prev_key + 1.0)
@@ -631,46 +698,62 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 need_new = act & ~has
                 overflow = need_new & (n >= N)
                 do_new = need_new & ~overflow
-                p_ins = jnp.sum(jnp.where(keys <= key_val, 1, 0),
-                                axis=(0, 2), keepdims=True)[:, :, 0:1]
+                p_ins = wsum(jnp.where(keys <= key_val, 1, 0))
                 nid = jnp.where(has, found, jnp.minimum(p_ins, N - 1))
 
-                @pl.when(jnp.any(do_new))
-                def _():
-                    sh = (rr >= p_ins) & do_new
-                    v = rk_base[...]
-                    rk_base[...] = jnp.where(sh, shift_right(v, -1), v)
-                    v = rk_cov[...]
-                    rk_cov[...] = jnp.where(sh, shift_right(v, 0), v)
-                    v = rk_cnt[...]
-                    rk_cnt[...] = jnp.where(sh, shift_right(v, 0), v)
-                    vk = rk_key[...]
-                    rk_key[...] = jnp.where(sh, shift_right(vk, KEY_INF),
-                                            vk)
-                    for e in range(E):
-                        vd = rk_delta[e]
-                        sd = shift_right(vd, 0)
-                        # an edge whose source sits below the insertion
-                        # point now spans it: distance grows by one
-                        sd = sd + jnp.where(
-                            (sd > 0) & (rr - 1 - sd < p_ins), 1, 0)
-                        rk_delta[e] = jnp.where(sh, sd, vd)
-                        vw = rk_ew[e]
-                        rk_ew[e] = jnp.where(sh, shift_right(vw, 0), vw)
-                    rmw_v(rk_base, p_ins, b, do_new)
-                    rmw_v(rk_key, p_ins, key_val, do_new)
-                    rmw_v(rk_cov, p_ins, 0, do_new)
-                    rmw_v(rk_cnt, p_ins, 0, do_new)
-                    # zero the inserted row's edge slots through the ref
-                    # (a loaded slice is immutable; write like eslot_write)
-                    new_row = (rr == p_ins) & do_new
-                    for e in range(E):
-                        vd2 = rk_delta[pl.ds(e, 1)][0]
-                        rk_delta[pl.ds(e, 1)] = jnp.where(
-                            new_row, 0, vd2)[None]
-                        vw2 = rk_ew[pl.ds(e, 1)][0]
-                        rk_ew[pl.ds(e, 1)] = jnp.where(
-                            new_row, 0, vw2)[None]
+                # Node insertion is throughput-bound (2 * E + 4 arrays
+                # of NC vregs shifted), not a dependency chain: each
+                # group pays for its own insertions only, under its own
+                # gate.  Everything else in the step is shared.
+                def insert_node(u):
+                    grp = pl.ds(u, 1)
+                    dn = do_new[u:u + 1]
+                    pi = p_ins[u:u + 1]
+
+                    @pl.when(jnp.any(dn))
+                    def _():
+                        sh = (rr >= pi) & dn
+                        new_row = (rr == pi) & dn
+                        for ref, fill, val in (
+                                (rk_base, -1, b[u:u + 1]),
+                                (rk_key, KEY_INF, key_val[u:u + 1]),
+                                (rk_cov, 0, 0), (rk_cnt, 0, 0)):
+                            v = ref[grp]
+                            v = jnp.where(sh, shift_right(v, fill), v)
+                            ref[grp] = jnp.where(new_row, val, v)
+
+                        # A loop over the edge slots, not E copies of
+                        # its body: the block is traced and lowered once
+                        # per group, and E copies made a program's
+                        # trace + lower 28 % longer at two groups, which
+                        # set-up pays 3 to 12 times a process.  Measured
+                        # on the v5e: the loop costs 5-8 % of the
+                        # kernel's time at ONT error rates and under 1 %
+                        # on short reads; four slots a trip cost the
+                        # same, so it is the dynamic slot, not the trip.
+                        def shift_slot(e, _):
+                            slot = pl.ds(e, 1)
+                            vd = rk_delta[slot, grp][0]
+                            sd = shift_right(vd, 0)
+                            # an edge whose source sits below the
+                            # insertion point now spans it: distance
+                            # grows by one
+                            sd = sd + jnp.where(
+                                (sd > 0) & (rr - 1 - sd < pi), 1, 0)
+                            # the inserted row starts with no edges
+                            rk_delta[slot, grp] = jnp.where(
+                                new_row, 0, jnp.where(sh, sd, vd))[None]
+                            vw = rk_ew[slot, grp][0]
+                            rk_ew[slot, grp] = jnp.where(
+                                new_row, 0,
+                                jnp.where(sh, shift_right(vw, 0),
+                                          vw))[None]
+                            return 0
+
+                        jax.lax.fori_loop(0, E, shift_slot, 0)
+
+                for u in range(U):
+                    insert_node(u)
 
                 touch = act & ~overflow
                 rmw_v(rk_cov, nid, ex_v(rk_cov[...], nid) + 1, touch)
@@ -692,7 +775,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
 
                 same = jax.lax.fori_loop(
                     0, cnt_max, same_scan,
-                    jnp.full((1, G, 1), -1, jnp.int32))
+                    jnp.full(w_shape, -1, jnp.int32))
                 ew = prev_w + wj
                 add_new = has_prev & (same < 0) & (cntv < E)
 
@@ -723,9 +806,9 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             n, failed, _, _, _ = jax.lax.fori_loop(
                 0, maxL, upd_body,
                 (n, failed,
-                 jnp.full((1, G, 1), -1, jnp.int32),
-                 jnp.full((1, G, 1), -1.0, jnp.float32),
-                 jnp.zeros((1, G, 1), jnp.int32)))
+                 jnp.full(w_shape, -1, jnp.int32),
+                 jnp.full(w_shape, -1.0, jnp.float32),
+                 jnp.zeros(w_shape, jnp.int32)))
             return (n, failed, hit) if band else (n, failed)
 
         @pl.when(max_layers > 0)
@@ -745,17 +828,17 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
         if band:
             n, failed, hit = jax.lax.fori_loop(
                 0, max_layers, layer_loop,
-                (bb_len, jnp.zeros((1, G, 1), jnp.int32),
-                 jnp.zeros((1, G, 1), jnp.int32)))
+                (bb_len, jnp.zeros(w_shape, jnp.int32),
+                 jnp.zeros(w_shape, jnp.int32)))
         else:
             n, failed = jax.lax.fori_loop(
                 0, max_layers, layer_loop,
-                (bb_len, jnp.zeros((1, G, 1), jnp.int32)))
+                (bb_len, jnp.zeros(w_shape, jnp.int32)))
 
         # ================= consensus =====================================
         # (parity: rt_poa.cpp generate_consensus — heaviest bundle)
-        score[...] = jnp.zeros((NC, G, 128), jnp.int32)
-        spred[...] = jnp.full((NC, G, 128), -1, jnp.int32)
+        score[...] = jnp.zeros(n_shape, jnp.int32)
+        spred[...] = jnp.full(n_shape, -1, jnp.int32)
         n_max = jnp.max(n)
         delta_f = [rk_delta[e] for e in range(E)]
         ew_f = [rk_ew[e] for e in range(E)]
@@ -764,9 +847,9 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             best_r, best_s = c
             act = r < n
             cnt_r = exr(rk_cnt, r)
-            bw = jnp.full((1, G, 1), NEG, jnp.int32)
-            bs = jnp.full((1, G, 1), NEG, jnp.int32)
-            bp = jnp.full((1, G, 1), -1, jnp.int32)
+            bw = jnp.full(w_shape, NEG, jnp.int32)
+            bs = jnp.full(w_shape, NEG, jnp.int32)
+            bp = jnp.full(w_shape, -1, jnp.int32)
             for e in range(E):
                 d_e = exr(rk_delta.at[e], r)
                 w_e = exr(rk_ew.at[e], r)
@@ -785,8 +868,8 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
 
         summit, _ = jax.lax.fori_loop(
             0, n_max, score_body,
-            (jnp.zeros((1, G, 1), jnp.int32),
-             jnp.full((1, G, 1), NEG, jnp.int32)))
+            (jnp.zeros(w_shape, jnp.int32),
+             jnp.full(w_shape, NEG, jnp.int32)))
 
         # backward walk to a source (ranks into revbuf)
         def bcond(c):
@@ -802,10 +885,10 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                     cnt + jnp.where(act, 1, 0))
 
         _, cnt_b = jax.lax.while_loop(
-            bcond, bbody, (summit, jnp.zeros((1, G, 1), jnp.int32)))
+            bcond, bbody, (summit, jnp.zeros(w_shape, jnp.int32)))
 
-        cons_base_ref[0] = jnp.full((NC, G, 128), -1, jnp.int32)
-        cons_cov_ref[0] = jnp.zeros((NC, G, 128), jnp.int32)
+        cons_base_ref[0] = jnp.full(n_shape, -1, jnp.int32)
+        cons_cov_ref[0] = jnp.zeros(n_shape, jnp.int32)
         base_f = rk_base[...]
         cov_f = rk_cov[...]
 
@@ -831,17 +914,16 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
 
         def fbody(c):
             u, cnt, more = c
-            ew = jnp.full((NC, G, 128), NEG, jnp.int32)
+            ew = jnp.full(n_shape, NEG, jnp.int32)
             for e in range(E):
                 m = ((delta_f[e] > 0) & (delta_f[e] == rr - u) &
                      (rr < n))
                 ew = jnp.maximum(ew, jnp.where(m, ew_f[e], NEG))
-            wmax = jnp.max(ew, axis=(0, 2), keepdims=True)[:, :, 0:1]
-            any_out = (more > 0) & (wmax > NEG)
-            cand_s = jnp.where(ew == wmax, score[...], NEG)
-            smax = jnp.max(cand_s, axis=(0, 2), keepdims=True)[:, :, 0:1]
-            v = jnp.min(jnp.where(cand_s == smax, rr, N), axis=(0, 2),
-                        keepdims=True)[:, :, 0:1]
+            w_top = wmax(ew)
+            any_out = (more > 0) & (w_top > NEG)
+            cand_s = jnp.where(ew == w_top, score[...], NEG)
+            smax = wmax(cand_s)
+            v = wmin(jnp.where(cand_s == smax, rr, N))
             emit(cnt, jnp.clip(v, 0, N - 1), any_out)
             return (jnp.where(any_out, v, u),
                     cnt + jnp.where(any_out, 1, 0),
@@ -850,30 +932,36 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
         _, cnt_f, _ = jax.lax.while_loop(
             fcond, fbody,
             (summit, cnt_b,
-             jnp.ones((1, G, 1), jnp.int32)))
+             jnp.ones(w_shape, jnp.int32)))
 
-        for g in range(G):
-            cl_s[0, 0, g] = scalar_of(cnt_f, g)
-            fl_s[0, 0, g] = jnp.where(scalar_of(failed, g) > 0, 1, 0)
-            nn_s[0, 0, g] = scalar_of(n, g)
+        for i in range(W):
+            cl_s[0, 0, i] = scalar_of(cnt_f, i)
+            fl_s[0, 0, i] = jnp.where(scalar_of(failed, i) > 0, 1, 0)
+            nn_s[0, 0, i] = scalar_of(n, i)
             if band:
-                bh_s[0, 0, g] = jnp.where(scalar_of(hit, g) > 0, 1, 0)
+                bh_s[0, 0, i] = jnp.where(scalar_of(hit, i) > 0, 1, 0)
 
     def make(batch: int):
-        assert batch % G == 0
-        nb = batch // G
+        assert batch % W == 0, (batch, U, G)
+        nb = batch // W
         # Per-window scalars ride a unit middle dim: Mosaic wants a
         # block's last two dims to equal the array's (or tile 8x128), and
-        # a (1, G) block of an (nb, G) array only passes at nb == 1.
-        smem2 = pl.BlockSpec((1, 1, G), lambda b: (b, 0, 0),
+        # a (1, W) block of an (nb, W) array only passes at nb == 1.
+        smem2 = pl.BlockSpec((1, 1, W), lambda b: (b, 0, 0),
                              memory_space=pltpu.SMEM)
-        smem3 = pl.BlockSpec((1, G, D), lambda b: (b, 0, 0),
+        smem3 = pl.BlockSpec((1, W, D), lambda b: (b, 0, 0),
                              memory_space=pltpu.SMEM)
-        vblk = pl.BlockSpec((1, NC, G, 128), lambda b: (b, 0, 0, 0),
+        vblk = pl.BlockSpec((1, U, NC, G, 128), lambda b: (b, 0, 0, 0, 0),
                             memory_space=pltpu.VMEM)
         hbm = pl.BlockSpec(memory_space=pl.ANY)
 
-        gshape = jax.ShapeDtypeStruct((nb, 1, G), jnp.int32)
+        def n_rows(*lead, dtype=jnp.int32):
+            return pltpu.VMEM(lead + (U, NC, G, 128), dtype)
+
+        def j_rows(*lead, dtype=jnp.int32):
+            return pltpu.VMEM(lead + (U, JC, G, 128), dtype)
+
+        gshape = jax.ShapeDtypeStruct((nb, 1, W), jnp.int32)
         return pl.pallas_call(
             kernel,
             grid=(nb,),
@@ -882,34 +970,36 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             out_specs=[vblk, vblk, smem2, smem2, smem2] +
                       ([smem2] if band else []) + [hbm],
             out_shape=[
-                jax.ShapeDtypeStruct((nb, NC, G, 128), jnp.int32),
-                jax.ShapeDtypeStruct((nb, NC, G, 128), jnp.int32),
+                jax.ShapeDtypeStruct((nb, U, NC, G, 128), jnp.int32),
+                jax.ShapeDtypeStruct((nb, U, NC, G, 128), jnp.int32),
                 gshape, gshape, gshape,
             ] + ([gshape] if band else []) + [
-                jax.ShapeDtypeStruct((nb, N, JC, G, 128), jnp.int32),
+                jax.ShapeDtypeStruct((nb, N, U, JC, G, 128), jnp.int32),
             ],
             scratch_shapes=[
-                pltpu.VMEM((RING, JC, G, 128), jnp.int32),   # Hring
-                pltpu.VMEM((JC, G, 128), jnp.int32),         # H0
-                pltpu.VMEM((NC, G, 128), jnp.int32),         # rk_base
-                pltpu.VMEM((NC, G, 128), jnp.float32),       # rk_key
-                pltpu.VMEM((NC, G, 128), jnp.int32),         # rk_cov
-                pltpu.VMEM((NC, G, 128), jnp.int32),         # rk_cnt
-                pltpu.VMEM((E, NC, G, 128), jnp.int32),      # rk_delta
-                pltpu.VMEM((E, NC, G, 128), jnp.int32),      # rk_ew
-                pltpu.VMEM((NC, G, 128), jnp.int32),         # rk_dmax
-                pltpu.VMEM((NC, G, 128), jnp.int32),         # esc
-                pltpu.VMEM((NC, G, 128), jnp.int32),         # score
-                pltpu.VMEM((NC, G, 128), jnp.int32),         # spred
-                pltpu.VMEM((NC, G, 128), jnp.int32),         # revbuf
-                pltpu.VMEM((JC, G, 128), jnp.float32),       # nkey
-                pltpu.VMEM((JC, G, 128), jnp.int32),         # runrem
-                pltpu.VMEM((2, JC, G, 128), jnp.int32),      # seq_scr
-                pltpu.VMEM((2, JC, G, 128), jnp.int32),      # w_scr
+                j_rows(RING),                                # Hring
+                j_rows(),                                    # H0
+                n_rows(),                                    # rk_base
+                n_rows(dtype=jnp.float32),                   # rk_key
+                n_rows(),                                    # rk_cov
+                n_rows(),                                    # rk_cnt
+                n_rows(E),                                   # rk_delta
+                n_rows(E),                                   # rk_ew
+                n_rows(),                                    # rk_dmax
+                n_rows(),                                    # esc
+                n_rows(),                                    # score
+                n_rows(),                                    # spred
+                n_rows(),                                    # revbuf
+                j_rows(dtype=jnp.float32),                   # nkey
+                j_rows(),                                    # runrem
+                j_rows(2),                                   # seq_scr
+                j_rows(2),                                   # w_scr
                 pltpu.SemaphoreType.DMA((2, 2)),             # layer DMA
                 pltpu.SemaphoreType.DMA((2,)),               # flush
                 pltpu.SemaphoreType.DMA((2,)),               # tb load
             ],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=vmem_limit_bytes(cfg, U)),
             interpret=interpret,
             name="racon_poa_ls",
         )
@@ -917,32 +1007,33 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
     @functools.lru_cache(maxsize=8)
     def jitted(batch: int):
         call = make(batch)
-        nb = batch // G
+        nb = batch // W
 
         @named("racon_poa_ls")
         def fn(bb_len, n_layers, lens, begins, ends, bb, bbw, seqs, ws,
                *extra):
+            # window b * W + u * G + g of the batch: program b, group u,
+            # sublane g
             def to_n(x):
                 x = jnp.pad(x.reshape(batch, BB), ((0, 0), (0, N - BB)))
-                return x.reshape(nb, G, NC, 128).transpose(0, 2, 1, 3)
+                return x.reshape(nb, U, G, NC, 128).transpose(0, 1, 3, 2, 4)
 
-            seqsJ = jnp.pad(seqs, ((0, 0), (0, 0), (0, JL - L)),
-                            constant_values=255)
-            wsJ = jnp.pad(ws, ((0, 0), (0, 0), (0, JL - L)))
-            seqsJ = seqsJ.reshape(nb, G, D, JC, 128).transpose(
-                0, 2, 3, 1, 4)
-            wsJ = wsJ.reshape(nb, G, D, JC, 128).transpose(0, 2, 3, 1, 4)
+            def to_j(x, fill):
+                x = jnp.pad(x, ((0, 0), (0, 0), (0, JL - L)),
+                            constant_values=fill)
+                return x.reshape(nb, U, G, D, JC, 128).transpose(
+                    0, 3, 1, 4, 2, 5)
 
-            args = [bb_len.reshape(nb, 1, G), n_layers.reshape(nb, 1, G),
-                    lens.reshape(nb, G, D), begins.reshape(nb, G, D),
-                    ends.reshape(nb, G, D), to_n(bb), to_n(bbw),
-                    seqsJ, wsJ]
+            args = [bb_len.reshape(nb, 1, W), n_layers.reshape(nb, 1, W),
+                    lens.reshape(nb, W, D), begins.reshape(nb, W, D),
+                    ends.reshape(nb, W, D), to_n(bb), to_n(bbw),
+                    to_j(seqs, 255), to_j(ws, 0)]
             if band:
-                args.append(extra[0].reshape(nb, 1, G))
+                args.append(extra[0].reshape(nb, 1, W))
             outs = call(*args)
             cb, cc, cl, fl, nn = outs[:5]
-            cb = cb.transpose(0, 2, 1, 3).reshape(batch, N)
-            cc = cc.transpose(0, 2, 1, 3).reshape(batch, N)
+            cb = cb.transpose(0, 1, 3, 2, 4).reshape(batch, N)
+            cc = cc.transpose(0, 1, 3, 2, 4).reshape(batch, N)
             res = (cb, cc, cl.reshape(batch, 1), fl.reshape(batch, 1),
                    nn.reshape(batch, 1))
             if band:

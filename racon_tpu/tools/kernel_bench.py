@@ -77,7 +77,9 @@ def main():
     platform = jax.devices()[0].platform
     cfg = poa_driver.make_config(500, depth, 5, -4, -8)
     interp = platform != "tpu"
-    fn = poa_pallas_ls.build_lockstep_poa_kernel(cfg, interpret=interp)(B)
+    # the program width the driver would ship this batch at
+    fn = poa_pallas_ls.build_lockstep_poa_kernel(
+        cfg, interpret=interp, groups=poa_driver._group_width(cfg, B))(B)
 
     rng = np.random.default_rng(0)
     bb, bbw, bl, nl, seqs, ws, lens, bg, en = make_batch(cfg, B, rng)

@@ -283,19 +283,22 @@ def _poa_batch(cfg, B, seed, roll=0):
     return (bb, bbw, bl, nl, seqs, ws, lens, bg, en)
 
 
-def test_poa_banded_kernel_byte_identity():
+@pytest.mark.parametrize("groups", [1, 2], ids=["u1", "u2"])
+def test_poa_banded_kernel_byte_identity(groups):
     """The banded POA build: wband=0 reproduces the flat kernel
     byte-for-byte (the ladder's floor runs through the same compiled
     build), a generous band matches the flat oracle with no hit, and a
-    pathologically narrow band on drifted layers raises band_hit."""
+    pathologically narrow band on drifted layers raises band_hit; in
+    programs of eight and of sixteen (wband and band_hit carry the
+    group axis with the rest)."""
     from racon_tpu.ops import poa, poa_driver
     from racon_tpu.ops.poa_pallas_ls import build_lockstep_poa_kernel as build
 
     cfg = poa.PoaConfig(max_nodes=256, max_len=128, max_backbone=128,
                         max_edges=8, depth=4, match=5, mismatch=-4, gap=-8)
-    B = 8
-    flat = build(cfg, interpret=True)(B)
-    banded = build(cfg, interpret=True, band=True)(B)
+    B = 8 * groups
+    flat = build(cfg, interpret=True, groups=groups)(B)
+    banded = build(cfg, interpret=True, band=True, groups=groups)(B)
 
     def run(kern, packed9, wband):
         is_banded = wband is not None

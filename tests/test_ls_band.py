@@ -36,6 +36,9 @@ sys.path.insert(0, %(repo)r)
 from __graft_entry__ import _force_cpu
 _force_cpu(1)                      # 1-device mesh: escapes the suite's 8
 os.environ["RACON_TPU_PALLAS"] = "1"   # interpret-mode pallas on CPU
+# the batch decides the program's width (poa_driver._group_width): 64, a
+# TPU's batch, runs programs of sixteen; 8 the program of eight
+os.environ["RACON_TPU_BATCH_WINDOWS"] = %(batch)r
 
 import gzip
 from racon_tpu import native
@@ -70,8 +73,9 @@ print("RESULT " + json.dumps({"ed": native.edit_distance(rc, ref),
 
 @pytest.mark.skipif(not FULL, reason="~200 s single-device interpret run; "
                     "set RACON_TPU_FULL_GOLDEN=1 (nightly band)")
-def test_ls_tier_lambda_end_to_end_band():
-    child = _CHILD % {"repo": REPO, "data": DATA}
+@pytest.mark.parametrize("batch", ["64", "8"], ids=["u2", "u1"])
+def test_ls_tier_lambda_end_to_end_band(batch):
+    child = _CHILD % {"repo": REPO, "data": DATA, "batch": batch}
     r = subprocess.run([sys.executable, "-c", child], capture_output=True,
                        text=True, timeout=1800, cwd=REPO)
     assert r.returncode == 0, r.stderr[-2000:]

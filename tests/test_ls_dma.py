@@ -9,13 +9,17 @@ instead of zeros.  The ``ls`` tier's first chip run hung on exactly
 this: the H-ring spill waited twice on one chunk whenever a layer's DP
 ended on a 64-rank boundary, and waited on a chunk the layer never
 flushed whenever its DP started past rank 64.  This batch holds one grid
-program of each kind; the kernel runs in a child process so that a
-deadlock is a failed test, not a hung suite.
+program of each kind, at one sublane group a program and at two (the
+spill, the traceback loads and the layer copies carry the group axis);
+the kernel runs in a child process so that a deadlock is a failed test,
+not a hung suite.
 """
 
 import os
 import subprocess
 import sys
+
+import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -28,8 +32,9 @@ from racon_tpu.ops.encoding import encode
 
 cfg = poa.PoaConfig(max_nodes=512, max_len=256, max_backbone=256,
                     max_edges=12, depth=4, match=5, mismatch=-4, gap=-8)
-G, BLK = poa_pallas_ls.G, poa_pallas_ls.BLK
-B = 2 * G
+U = __GROUPS__
+W, BLK = U * poa_pallas_ls.G, poa_pallas_ls.BLK
+B = 2 * W
 rng = random.Random(3)
 bb = np.zeros((B, cfg.max_backbone), np.uint8)
 bbw = np.zeros((B, cfg.max_backbone), np.int32)
@@ -54,7 +59,7 @@ def put(b, backbone, layers, begin, end):
 
 
 for b in range(B):
-    if b < G:
+    if b < W:
         # perfect full-span reads: the graph stays 2*BLK ranks, so every
         # layer's DP ends exactly on a chunk boundary with two chunks
         truth = bytes(rng.choice(b"ACGT") for _ in range(2 * BLK))
@@ -66,7 +71,8 @@ for b in range(B):
 
 interp = pltpu.InterpretParams(dma_execution_mode="on_wait",
                                uninitialized_memory="nan")
-ls = poa_pallas_ls.build_lockstep_poa_kernel(cfg, interpret=interp)(B)
+ls = poa_pallas_ls.build_lockstep_poa_kernel(cfg, interpret=interp,
+                                             groups=U)(B)
 cb, cc, cl, fl, nn = (np.asarray(x) for x in ls(
     bb_len[:, None], nl[:, None], lens, bg, en, bb.astype(np.int32), bbw,
     seqs.astype(np.int32), ws))
@@ -82,13 +88,15 @@ print("ls == xla under the TPU interpreter")
 """
 
 
-def test_lockstep_spill_semaphores_balance_under_tpu_interpreter():
+@pytest.mark.parametrize("groups", [1, 2], ids=["u1", "u2"])
+def test_lockstep_spill_semaphores_balance_under_tpu_interpreter(groups):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=os.pathsep.join(
                    p for p in (ROOT, os.environ.get("PYTHONPATH")) if p))
     env.pop("XLA_FLAGS", None)   # one device: the child shards nothing
     try:
-        r = subprocess.run([sys.executable, "-c", _CHILD], env=env,
+        r = subprocess.run([sys.executable, "-c",
+                            _CHILD.replace("__GROUPS__", str(groups))], env=env,
                            capture_output=True, text=True, timeout=420)
     except subprocess.TimeoutExpired:
         raise AssertionError(

@@ -2,12 +2,14 @@
 the CPU backend; on TPU hardware the same kernel runs compiled — the bench
 exercises that).
 
-The kernel (racon_tpu/ops/poa_pallas_ls.py) runs 8 windows per grid step in
-sublane lock-step; these tests assert lockstep == XLA twin == host oracle on
-one mixed batch covering varying lengths/depths, quality weights, partial
-spans, padding windows, and the DMAX rank-distance cap (which must fail the
-window to the host path, reproducing the reference's accelerator->CPU
-fallback lattice, /root/reference/src/cuda/cudapolisher.cpp:354-378).
+The kernel (racon_tpu/ops/poa_pallas_ls.py) runs U x 8 windows per grid
+step in sublane lock-step (U sublane groups under one control flow); these
+tests assert lockstep == XLA twin == host oracle on one mixed batch covering
+varying lengths/depths, quality weights, partial spans, padding windows,
+and the DMAX rank-distance cap (which must fail the window to the host
+path, reproducing the reference's accelerator->CPU fallback lattice,
+/root/reference/src/cuda/cudapolisher.cpp:354-378), each at one group a
+program and at two.
 """
 
 import random
@@ -68,20 +70,48 @@ def _set_window(a, b, backbone, layers, weights=None, begins=None,
         a["en"][b, i] = (len(backbone) - 1) if ends is None else ends[i]
 
 
-def _run_both(a, cfg, B):
-    ls_fn = poa_pallas_ls.build_lockstep_poa_kernel(cfg, interpret=True)(B)
-    jax_fn = poa.build_poa_kernel(cfg)
-    cb, cc, cl, fl, nn = (np.asarray(x) for x in ls_fn(
+GROUPS = pytest.mark.parametrize("groups", [1, 2], ids=["u1", "u2"])
+
+
+def _run_ls(a, cfg, groups=1):
+    B = len(a["bb"])
+    ls_fn = poa_pallas_ls.build_lockstep_poa_kernel(
+        cfg, interpret=True, groups=groups)(B)
+    return tuple(np.asarray(x) for x in ls_fn(
         a["bb_len"][:, None], a["nl"][:, None], a["lens"], a["bg"],
         a["en"], a["bb"].astype(np.int32), a["bbw"],
         a["seqs"].astype(np.int32), a["ws"]))
+
+
+def _deal(a, cfg, groups):
+    """The batch's windows dealt round-robin over the sublane groups of
+    programs `groups` wide (window b of a program of eight lands in
+    group b % groups), pad windows filling the other slots; returns the
+    wide batch and where each window went."""
+    B = len(a["bb"])
+    pos = np.array([(b // 8) * 8 * groups + (b % groups) * 8 +
+                    (b % 8) // groups for b in range(B)])
+    wide = _alloc(B * groups, cfg)
+    for k, v in a.items():
+        wide[k][pos] = v
+    return wide, pos
+
+
+def _run_both(a, cfg, B, groups=1):
+    """ls at `groups` sublane groups a program and the XLA twin, on the
+    same B windows; at groups > 1 the ls batch is the dealt one and its
+    outputs come back in the windows' own order."""
+    wide, pos = _deal(a, cfg, groups)
+    ls = tuple(x[pos] for x in _run_ls(wide, cfg, groups))
+    jax_fn = poa.build_poa_kernel(cfg)
     jb, jc, jl, jf, jn = (np.asarray(x) for x in jax_fn(
         a["bb"], a["bbw"], a["bb_len"], a["nl"], a["seqs"], a["ws"],
         a["lens"], a["bg"], a["en"]))
-    return (cb, cc, cl, fl, nn), (jb, jc, jl, jf, jn)
+    return ls, (jb, jc, jl, jf, jn)
 
 
-def test_lockstep_matches_host_and_jax():
+@GROUPS
+def test_lockstep_matches_host_and_jax(groups):
     """One mixed 8-window batch: perfect reads, rising mutation/depth,
     quality weights, partial spans, and a 1-base padding window — each
     asserted against both the XLA twin and the host oracle (consensus,
@@ -131,7 +161,8 @@ def test_lockstep_matches_host_and_jax():
     # w7: padding window (1-base backbone, zero layers) — must not crash
     # or flag failure, like the driver's pad-to-B windows
 
-    (cb, cc, cl, fl, nn), (jb, jc, jl, jf, jn) = _run_both(a, CFG, B)
+    (cb, cc, cl, fl, nn), (jb, jc, jl, jf, jn) = _run_both(a, CFG, B,
+                                                           groups)
 
     assert not fl.any(), f"unexpected device failures: {fl[:, 0]}"
     assert not jf.any()
@@ -150,8 +181,9 @@ def test_lockstep_matches_host_and_jax():
                                       err_msg=f"window {b} coverage")
 
 
+@GROUPS
 @pytest.mark.parametrize("seed", [101, 202, 303])
-def test_lockstep_differential_fuzz(seed):
+def test_lockstep_differential_fuzz(seed, groups):
     """Seeded random windows — lengths, depths, mutation rates, partial
     spans, per-base layer weights AND backbone weights (the product
     exports PHRED-33 backbone weights, dummy '!' = 0 when the target has
@@ -185,7 +217,8 @@ def test_lockstep_differential_fuzz(seed):
         a["bbw"][b, :len(backbone)] = bq
         cases[b] = (backbone, layers, w, bq, begins, ends)
 
-    (cb, cc, cl, fl, nn), (jb, jc, jl, jf, jn) = _run_both(a, CFG, B)
+    (cb, cc, cl, fl, nn), (jb, jc, jl, jf, jn) = _run_both(a, CFG, B,
+                                                           groups)
 
     assert not fl.any() and not jf.any()
     for b, (backbone, layers, w, bq, begins, ends) in cases.items():
@@ -199,7 +232,8 @@ def test_lockstep_differential_fuzz(seed):
         assert ls == jx == host, f"seed {seed} window {b}"
 
 
-def test_lockstep_ring_spill_at_large_geometry():
+@GROUPS
+def test_lockstep_ring_spill_at_large_geometry(groups):
     """Windows of 420+ ranks force the 128-row H ring to wrap multiple
     times: DP chunks are DMA'd to the HBM spill buffer under compute and
     streamed back block-descending during traceback (poa_pallas_ls.py
@@ -220,7 +254,8 @@ def test_lockstep_ring_spill_at_large_geometry():
         _set_window(a, b, backbone, layers)
         cases[b] = (backbone, layers)
 
-    (cb, cc, cl, fl, nn), (jb, jc, jl, jf, jn) = _run_both(a, big, B)
+    (cb, cc, cl, fl, nn), (jb, jc, jl, jf, jn) = _run_both(a, big, B,
+                                                           groups)
 
     assert not fl.any() and not jf.any()
     for b, (backbone, layers) in cases.items():
@@ -232,7 +267,8 @@ def test_lockstep_ring_spill_at_large_geometry():
         assert int(nn[b, 0]) == int(jn[b]), f"window {b} node count"
 
 
-def test_lockstep_dmax_cap_fails_window_to_host():
+@GROUPS
+def test_lockstep_dmax_cap_fails_window_to_host(groups):
     """A window whose graph grows an in-subgraph edge with rank distance
     beyond DMAX must raise its failed flag (-> driver host fallback), and
     must not poison its batch-mates.
@@ -256,7 +292,8 @@ def test_lockstep_dmax_cap_fails_window_to_host():
     mate = mutate(truth, 0.1, rng)
     _set_window(a, 1, truth, [mate, mutate(truth, 0.1, rng)])
 
-    (cb, cc, cl, fl, nn), (jb, jc, jl, jf, jn) = _run_both(a, CFG, B)
+    (cb, cc, cl, fl, nn), (jb, jc, jl, jf, jn) = _run_both(a, CFG, B,
+                                                           groups)
 
     assert fl[0, 0] == 1, "DMAX overflow must fail the window"
     assert not jf[0], "the XLA twin has no DMAX cap and must succeed"
@@ -266,8 +303,9 @@ def test_lockstep_dmax_cap_fails_window_to_host():
     assert ls_cons == jax_cons
 
 
+@GROUPS
 @pytest.mark.parametrize("tail", ["odd", "even"])
-def test_ls_pair_step_tail(tail):
+def test_ls_pair_step_tail(tail, groups):
     """The rank loop retires two ranks per iteration; the second is
     guarded by `r + 1 < r_end`.  One batch whose largest rank count is
     odd (the guard skips a rank past the end) and one where it is even
@@ -284,7 +322,8 @@ def test_ls_pair_step_tail(tail):
     # a shorter batch-mate, so the longest window alone sets the tail
     _set_window(a, 1, backbone[:40], [backbone[:40]] * 2)
 
-    (cb, cc, cl, fl, nn), (jb, jc, jl, jf, jn) = _run_both(a, CFG, B)
+    (cb, cc, cl, fl, nn), (jb, jc, jl, jf, jn) = _run_both(a, CFG, B,
+                                                           groups)
 
     assert int(nn[:, 0].max()) == len(backbone)
     assert (int(nn[:, 0].max()) % 2 == 1) == (tail == "odd")
@@ -293,6 +332,60 @@ def test_ls_pair_step_tail(tail):
         assert decode(cb[b, :cl[b, 0]]) == decode(jb[b, :jl[b]]) == want
         assert int(nn[b, 0]) == int(jn[b])
         np.testing.assert_array_equal(cc[b, :cl[b, 0]], jc[b, :jl[b]])
+
+
+def _sixteen(fill):
+    """Sixteen windows for one program of two groups.  `mixed`: unequal
+    layer counts and lengths across the groups (short and shallow in
+    group 0, long and deep in group 1, so every loop bound is set by one
+    group and masked in the other), one window that trips the DMAX cap
+    beside fifteen that do not, one pad slot in each group.  `pad-group`:
+    group 1 is pad windows only (a batch's last program)."""
+    rng = random.Random(34)
+    a = _alloc(16, CFG)
+    for b in range(16):
+        if fill == "pad-group" and b >= 8:
+            continue
+        if fill == "mixed" and b in (3, 12):
+            continue
+        deep = fill == "mixed" and b >= 8
+        L = rng.randrange(85, 115) if deep else rng.randrange(30, 70)
+        truth = bytes(rng.choice(b"ACGT") for _ in range(L))
+        rate = rng.uniform(0.04, 0.15)
+        nl = rng.randrange(5, CFG.depth + 1) if deep else rng.randrange(2, 5)
+        layers = [mutate(truth, rate, rng) for _ in range(nl)]
+        w = [np.array([rng.randrange(1, 60) for _ in range(len(l))],
+                      np.int32) for l in layers]
+        _set_window(a, b, mutate(truth, rate, rng), layers, weights=w)
+    if fill == "mixed":
+        truth = bytes(rng.choice(b"CGT") for _ in range(50))
+        far = truth[:25] + b"A" * (poa_pallas_ls.DMAX + 10) + truth[25:]
+        _set_window(a, 9, far, [truth, truth])
+    return a
+
+
+@pytest.mark.parametrize("fill", ["mixed", "pad-group"])
+def test_program_of_sixteen_equals_programs_of_eight(fill):
+    """One program of two sublane groups gives every window what the same
+    window gets in a program of eight: consensus, coverage, length,
+    `failed` and node count, byte for byte — the groups share the control
+    flow (loop bounds are maxima over sixteen windows) and nothing else."""
+    a = _sixteen(fill)
+    narrow = _run_ls(a, CFG, groups=1)
+    wide = _run_ls(a, CFG, groups=2)
+    assert int(narrow[3].sum()) == (1 if fill == "mixed" else 0)
+    if fill == "mixed":
+        assert narrow[3][9, 0] == 1
+        # group 1 sets every loop bound, group 0 is masked under it
+        assert a["nl"][:8].max() < a["nl"][8:].max()
+        assert a["bb_len"][:8].max() < a["bb_len"][8:].max()
+    for name, x, y in zip(("consensus", "coverage", "length", "failed",
+                           "nodes"), narrow, wide):
+        np.testing.assert_array_equal(x, y, err_msg=f"{fill}: {name}")
+    jf = np.asarray(poa.build_poa_kernel(CFG)(
+        a["bb"], a["bbw"], a["bb_len"], a["nl"], a["seqs"], a["ws"],
+        a["lens"], a["bg"], a["en"])[3])
+    assert not jf.any()
 
 
 def test_lockstep_production_geometry_real_window():
@@ -337,17 +430,20 @@ def test_lockstep_production_geometry_real_window():
     assert decode(cb[0, :cl[0]]) == pl.get_consensus(target)
 
 
-@pytest.mark.parametrize("length,pallas,tier", [
-    (200, True, "ls"), (500, True, "ls"), (1000, True, "ls"),
-    (1152, True, "xla"), (1408, True, "xla"), (2000, True, "xla"),
-    (500, False, "xla")],
+@pytest.mark.parametrize("length,pallas,tier,groups", [
+    (200, True, "ls", 2), (500, True, "ls", 2), (1000, True, "ls", 2),
+    (1152, True, "xla", 0), (1408, True, "xla", 0), (2000, True, "xla", 0),
+    (500, False, "xla", 0)],
     ids=["200", "500", "1000", "1152", "1408", "2000", "pallas-off"])
-def test_entry_tier_by_window_length(length, pallas, tier):
+def test_entry_tier_by_window_length(length, pallas, tier, groups):
     """Which tier a window length enters at, in every depth bucket and at
-    the score sets the deployments use: the lockstep kernel's scratch
+    the score sets the deployments use, and how wide its programs are at
+    a TPU's batch (64, or 16 a shard): the lockstep kernel's scratch
     fits VMEM up to class 1024 (so -w 200, -w 500 and upstream's largest
-    documented -w 1000 are served by it; the v5e compiler refuses class
-    1152), the XLA twin takes what is longer and everything when Pallas
+    documented -w 1000 are served by it, sixteen windows a program; the
+    v5e compiler refuses class 1152, and the limit the wide program
+    raises past class 512 admits no class a program of eight does not
+    fit), the XLA twin takes what is longer and everything when Pallas
     is off.  A change to RING, NODE_FACTOR or the budget that drops a
     documented window length off the kernel fails here, not on the
     chip."""
@@ -358,6 +454,13 @@ def test_entry_tier_by_window_length(length, pallas, tier):
             cfg = poa_driver.make_config(poa_driver.window_class(length),
                                          depth, *scores)
             assert poa_driver._pick_tier(cfg, pallas) == tier
+            if pallas:
+                fits = [u for u in (1, 2) if poa_driver._fits_vmem(cfg, u)]
+                assert fits == ([1, 2] if groups else [])
+            if groups:
+                assert poa_driver._group_width(cfg, 64) == groups
+                assert poa_driver._group_width(cfg, 16) == groups
+                assert poa_driver._group_width(cfg, 8) == 1
     assert poa_driver._next_tier("ls") == "xla"
     assert poa_driver._next_tier("xla") == "host"
 

@@ -100,7 +100,13 @@ def _ls(window_length, depth, B, band=False, scores=SCORES):
     cfg = poa_driver.make_config(window_length, depth, *scores)
     # the VMEM-fit model must agree: a geometry it approves has to build
     assert poa_driver._fits_vmem(cfg), "fit model rejects geometry"
-    fn = build_lockstep_poa_kernel(cfg, interpret=False, band=band)(B)
+    # at the group width the driver derives for this class and batch, and
+    # so under the vmem_limit_bytes it ships with: sixteen windows a
+    # program at 64 and at 16 a shard, eight at a batch of 8
+    groups = poa_driver._group_width(cfg, B)
+    assert groups == (1 if B % 16 else 2), (window_length, depth, B)
+    fn = build_lockstep_poa_kernel(cfg, interpret=False, band=band,
+                                   groups=groups)(B)
     return fn, _poa_args(cfg, B, band)
 
 
@@ -204,6 +210,53 @@ def test_lockstep_poa_kernel_compiles_for_v5e(depth):
     _compile_v5e(*_ls(500, depth, TPU_BATCH))
 
 
+@pytest.mark.parametrize("window_length,depth,B,scores", [
+    # a shard's batch on four chips: one program of sixteen a chip
+    (500, 8, SHARD_BATCH, SCORES), (500, 32, SHARD_BATCH, SCORES),
+    (500, 200, SHARD_BATCH, SCORES),
+    # chr20-sr.sam: class 256 at the deepest bucket
+    (200, 200, TPU_BATCH, (3, -5, -4)),
+    # ecoli-frag.paf: the tail classes at unit scores
+    (128, 32, TPU_BATCH, UNIT_SCORES), (256, 32, TPU_BATCH, UNIT_SCORES),
+    (384, 200, TPU_BATCH, UNIT_SCORES),
+    # a batch of 8 somebody asked for: the program of eight, U = 1
+    (500, 32, 8, SCORES),
+])
+def test_lockstep_program_compiles_for_v5e_at_the_cells_geometries(
+        window_length, depth, B, scores):
+    """The program of sixteen (two sublane groups, 10.85 MiB of arrays at
+    class 512 against the compiler's default 16 MB scoped limit) at every
+    geometry a benchmark cell runs beside class 512 at batch 64 above,
+    with the limit it ships with; and the program of eight it narrows to."""
+    _compile_v5e(*_ls(window_length, depth, B, scores=scores))
+
+
+def test_wide_program_past_class_512_needs_the_limit_it_ships_with(
+        monkeypatch):
+    """The program of sixteen compiles under the compiler's default
+    scoped-VMEM limit up to class 512 (10.85 MiB of arrays) and ships
+    with no limit there; at class 640 (13.83 MiB) the compiler refuses
+    it without one, and takes it under the limit sized from the sum."""
+    from racon_tpu.ops import poa_pallas_ls
+
+    MiB = 1 << 20
+    at_512 = poa_driver.make_config(500, 200, *SCORES)
+    at_640 = poa_driver.make_config(640, 8, *SCORES)
+    assert poa_pallas_ls.vmem_limit_bytes(at_512, 1) is None
+    assert poa_pallas_ls.vmem_limit_bytes(at_512, 2) is None
+    assert poa_pallas_ls.vmem_limit_bytes(at_640, 1) is None
+    assert poa_pallas_ls.vmem_limit_bytes(at_640, 2) == 28 * MiB
+    _compile_v5e(*_ls(640, 8, SHARD_BATCH))
+    monkeypatch.setattr(poa_pallas_ls, "vmem_limit_bytes",
+                        lambda cfg, groups: None)
+    poa_pallas_ls.build_lockstep_poa_kernel.cache_clear()
+    try:
+        with pytest.raises(Exception, match="memory space vmem"):
+            _compile_v5e(*_ls(640, 8, SHARD_BATCH))
+    finally:
+        poa_pallas_ls.build_lockstep_poa_kernel.cache_clear()
+
+
 @pytest.mark.parametrize("node_factor,window_length",
                          [("3", 1000), ("4", 896)])
 def test_lockstep_poa_kernel_compiles_at_its_largest_class(
@@ -285,6 +338,28 @@ def test_sharded_hirschberg_kernels_compile_for_a_v5e_host(v5e_mesh, kernel,
     specs = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rows)
              for a in args]
     text = fn.lower(*specs).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert not [c for c in ("all-gather", "all-reduce", "all-to-all",
+                            "collective-permute") if c in text]
+
+
+def test_sharded_lockstep_program_compiles_for_a_v5e_host(v5e_mesh):
+    """The consensus launch of the four-chip cells, as the driver builds
+    it: 64 rows under shard_map over the (4, 1) mesh, 16 a shard, so one
+    program of sixteen a chip (two sublane groups) where there were two
+    of eight.  One Mosaic kernel per chip and no collective."""
+    cfg = poa_driver.make_config(500, 200, *SCORES)
+    assert poa_driver._group_width(cfg, TPU_BATCH // 4) == 2
+    poa_driver._build_kernel_cached.cache_clear()
+    try:
+        fn = poa_driver._build_kernel_cached(cfg, TPU_BATCH, True, 4, "tpu",
+                                             4, False)
+        rows = v5e_mesh.sharding("windows")
+        specs = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rows)
+                 for a in _poa_args(cfg, TPU_BATCH)]
+        text = fn.lower(*specs).compile().as_text()
+    finally:
+        poa_driver._build_kernel_cached.cache_clear()
     assert "tpu_custom_call" in text
     assert not [c for c in ("all-gather", "all-reduce", "all-to-all",
                             "collective-permute") if c in text]
