@@ -27,7 +27,6 @@ jaxpr is walked recursively), so it sees exactly what XLA would lower.
 
 from __future__ import annotations
 
-import itertools
 from typing import Iterable, List, Optional, Sequence, Set, Tuple
 
 from .lint import Violation
@@ -143,13 +142,11 @@ def audit_poa(window_lengths: Optional[Sequence[int]] = None,
 
     wls = tuple(window_lengths if window_lengths is not None
                 else poa_driver.AUDIT_WINDOW_LENGTHS)
-    classes = sorted({poa_driver.window_class(max(int(w), 1)) for w in wls})
     out: List[Violation] = []
     signatures: Set[Tuple] = set()
-    for depth_bucket, wl_class in itertools.product(
-            poa_driver.DEPTH_BUCKETS, classes):
+    for depth_bucket, wl_class, rung in poa_driver.audit_grid(wls):
         cfg = poa_driver.make_config(wl_class, depth_bucket,
-                                     match, mismatch, gap)
+                                     match, mismatch, gap, rung)
         # Bypass the topology cache: the audit must not touch
         # jax.devices() (stays runnable with no backend configured) and
         # must not pollute the production cache with audit entries.
@@ -166,7 +163,8 @@ def audit_poa(window_lengths: Optional[Sequence[int]] = None,
             jax.ShapeDtypeStruct((1, cfg.depth), i32),         # begins
             jax.ShapeDtypeStruct((1, cfg.depth), i32),         # ends
         ]
-        label = f"poa d={depth_bucket} w={wl_class}"
+        label = (f"poa d={depth_bucket} w={wl_class} "
+                 f"rung={poa_driver.NODE_RUNGS[rung]}")
         try:
             closed = jax.make_jaxpr(kernel)(*args)
         except Exception as e:  # noqa: BLE001 — audit reports, not raises
@@ -175,7 +173,9 @@ def audit_poa(window_lengths: Optional[Sequence[int]] = None,
                 f"{label}: abstract trace failed: "
                 f"{type(e).__name__}: {e}"))
             continue
-        signatures.add(_signature(closed.in_avals))
+        # a node rung changes no input shape and is a program of its own
+        # all the same: max_nodes rides the signature
+        signatures.add((cfg.max_nodes,) + _signature(closed.in_avals))
         out.extend(check_jaxpr(closed, _POA_PATH, label))
     budget = poa_driver.POA_RECOMPILE_BUDGET
     if len(signatures) > budget:
@@ -183,7 +183,8 @@ def audit_poa(window_lengths: Optional[Sequence[int]] = None,
             "recompile-budget", _POA_PATH, 0,
             f"POA grid compiles {len(signatures)} distinct jit "
             f"signatures over depths={tuple(poa_driver.DEPTH_BUCKETS)} "
-            f"x windows={wls}, exceeding POA_RECOMPILE_BUDGET="
+            f"x windows={wls} x node rungs, exceeding "
+            f"POA_RECOMPILE_BUDGET="
             f"{budget}; raise the declared budget only after sizing "
             f"the serving-latency cost"))
     return out

@@ -87,7 +87,9 @@ KNOBS: Dict[str, Knob] = {k.name: k for k in (
        "phase-pipeline handoff queue depth: aligned target chunks the "
        "worker may buffer ahead of consensus"),
     _k("RACON_TPU_NODE_FACTOR", "3", "int",
-       "POA graph node capacity = factor x window length"),
+       "POA graph node capacity of the base rung = factor x window "
+       "length (windows of up to ~55 long-read layers); deeper windows "
+       "run on the upper rung, 5 x, which is derived and no knob"),
     _k("RACON_TPU_ALIGN_COHORT", None, "int",
        "phase-1 jobs materialized per device cohort (default 64)"),
     _k("RACON_TPU_SHARD", "1", "bool",

@@ -208,7 +208,10 @@ def render(doc: dict, path: str) -> str:
     for title, prefixes in (
             ("alignment launches over the mesh", ("align.mesh.",)),
             ("consensus programs in lock-step",
-             ("poa.programs.", "poa.lockstep."))):
+             ("poa.programs.", "poa.lockstep.")),
+            ("consensus graph capacity by rung",
+             ("poa.windows.rung.", "poa.nodes.", "poa.windows.overflow.",
+              "poa.layers."))):
         rows = {k: v for k, v in sorted(b["counters"].items())
                 if k.startswith(prefixes)}
         if rows:

@@ -45,6 +45,24 @@ from .kernel_cache import device_keyed_cache
 NEG = jnp.int32(-(1 << 28))
 KEY_INF = jnp.float32(jnp.inf)
 
+#: Why a kernel gave a window up: the value of its `failed` output, 0 for
+#: a window it served.  The first cause a window meets is the one it
+#: keeps (nothing runs for it afterwards).  `nodes` and `edges` are the
+#: two capacities of PoaConfig (a node that does not fit max_nodes, a
+#: node's in-edge past max_edges); `distance` is the lockstep kernel's
+#: own (an in-subgraph edge over more ranks than its H ring holds, which
+#: the XLA twin has no limit on); `other` is a traceback that found no
+#: way back.  Shared by the twin, poa_pallas_ls and the driver's
+#: poa.windows.overflow.* counters.
+FAIL_OTHER, FAIL_NODES, FAIL_EDGES, FAIL_DISTANCE = 1, 2, 3, 4
+FAIL_CAUSES = {FAIL_OTHER: "other", FAIL_NODES: "nodes",
+               FAIL_EDGES: "edges", FAIL_DISTANCE: "distance"}
+
+
+def first_cause(failed, cond, cause: int):
+    """`failed` with `cause` where `cond` holds and nothing failed yet."""
+    return jnp.where((failed == 0) & cond, cause, failed)
+
 
 class PoaConfig(NamedTuple):
     max_nodes: int = 1536     # node slots per window graph
@@ -64,7 +82,7 @@ class Graph(NamedTuple):
     in_src: jnp.ndarray  # i32 [N, E] source node id, -1 empty slot
     in_w: jnp.ndarray    # i32 [N, E] edge weight
     n: jnp.ndarray       # i32 [] node count
-    failed: jnp.ndarray  # bool []
+    failed: jnp.ndarray  # i32 [] 0 or the FAIL_* cause
 
 
 def _init_graph(cfg: PoaConfig, bb_codes, bb_w, bb_len):
@@ -86,7 +104,7 @@ def _init_graph(cfg: PoaConfig, bb_codes, bb_w, bb_len):
     prev_w = jnp.roll(bbw, 1)
     in_w = in_w.at[:, 0].set(jnp.where(chain, prev_w + bbw, 0))
     return Graph(base, key, cov, in_src, in_w,
-                 bb_len.astype(jnp.int32), jnp.bool_(False))
+                 bb_len.astype(jnp.int32), jnp.int32(0))
 
 
 def _dp_matrix(cfg: PoaConfig, g: Graph, seq, sub_mask, order, n_sub):
@@ -254,7 +272,7 @@ def _update_graph(cfg: PoaConfig, g: Graph, pos_node, seq, w, L):
         touch = act & ~overflow
         cov = g.cov.at[nid].add(jnp.where(touch, 1, 0))
         n = g.n + jnp.where(do_new, 1, 0)
-        failed = g.failed | overflow
+        failed = first_cause(g.failed, overflow, FAIL_NODES)
 
         # Edge prev -> nid with weight w[j-1] + w[j].
         has_prev = touch & (prev >= 0)
@@ -271,7 +289,8 @@ def _update_graph(cfg: PoaConfig, g: Graph, pos_node, seq, w, L):
             jnp.where(use_empty, ew, in_w[nid, slot]))
         in_src = g.in_src.at[nid, slot].set(
             jnp.where(use_empty, prev, g.in_src[nid, slot]))
-        failed = failed | (has_prev & ~same.any() & ~empty.any())
+        failed = first_cause(
+            failed, has_prev & ~same.any() & ~empty.any(), FAIL_EDGES)
 
         prev = jnp.where(act, nid, prev)
         prev_key = jnp.where(act, key[nid], prev_key)
@@ -301,7 +320,7 @@ def _add_layer(cfg: PoaConfig, g: Graph, seq, w, L, begin, end, bb_len):
 
     H = _dp_matrix(cfg, g, seq, sub_mask, order, n_sub)
     pos_node, ok = _traceback(cfg, g, H, seq, sub_mask, order, n_sub, L)
-    g = g._replace(failed=g.failed | ~ok)
+    g = g._replace(failed=first_cause(g.failed, ~ok, FAIL_OTHER))
     return _update_graph(cfg, g, pos_node, seq, w, L)
 
 
@@ -391,7 +410,7 @@ def _polish_window(cfg: PoaConfig, bb_codes, bb_w, bb_len, n_layers,
         seq = seqs[li]
         w = ws[li]
         L = lens[li]
-        use = (L > 0) & ~g.failed
+        use = (L > 0) & (g.failed == 0)
         g = jax.lax.cond(
             use,
             lambda g: _add_layer(cfg, g, seq, w, L, begins[li], ends[li],

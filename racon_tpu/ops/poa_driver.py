@@ -9,6 +9,16 @@ Mirrors the reference's CUDA polish orchestration
 (:354-378), and the host-side trim identical to the CPU path
 (cudabatch.cpp:230-256).
 
+Graph capacity comes in rungs (NODE_RUNGS).  The base rung is
+RACON_TPU_NODE_FACTOR x the window class (3 x: 1536 node slots at -w
+500), which holds a long-read window up to ~55 layers; the upper rung is
+UPPER_NODE_FACTOR x (5 x: 2560), sized for DEPTH_CAP layers.  A window's
+rung is chosen before any kernel runs, from window_info alone
+(node_estimate: the layers' bases over the backbone's length, read
+against NODE_ENVELOPE), and is part of the bucket key (depth bucket,
+window class, rung); a window that outgrows its rung all the same is
+redone on the host and counted by cause (poa.windows.overflow.*).
+
 Failure handling runs through the explicit degradation lattice
 (racon_tpu/resilience/lattice.py): tiers ls -> xla -> host, with
 per-tier bounded retry, a per-device-call watchdog, and batch bisection
@@ -39,6 +49,33 @@ from .encoding import decode, encode
 DEPTH_CAP = 200                    # reference: MAX_DEPTH_PER_WINDOW
 DEPTH_BUCKETS = (8, 32, DEPTH_CAP)
 
+#: Graph-capacity rungs, smallest first: make_config's `rung` indexes
+#: _rung_factors(), the counters and span args carry the name.
+NODE_RUNGS = ("base", "upper")
+
+#: max_nodes of the upper rung = this x the window class: what
+#: DEPTH_CAP layers need.  NODE_ENVELOPE reads 4.10 nodes a backbone
+#: base at 200 effective layers and 4.17 at 240; 5 leaves a fifth of
+#: room over that, for reads noisier than the profile measured.
+UPPER_NODE_FACTOR = 5
+
+#: (effective layers, nodes per backbone base): the most nodes the host
+#: engine's graphs held at up to that many effective layers (a window's
+#: layer bases over its backbone length, so a layer that covers a tenth
+#: of the window counts a tenth).  Read off rt_poa.cpp's graphs, the
+#: oracle both kernels are verified against, over 698 windows of 500 bp
+#: at 30x to 200x of benchmark/generate.py's ONT profile (5/3/3 %
+#: sub/ins/del, -m 5 -x -4 -g -8); not fitted, not padded.  It is
+#: concave: match + gap (5 - 8) outscores a mismatch (-4) and a fresh
+#: insertion (-8), so the denser the graph, the more of a new layer's
+#: errors land on nodes that are already there.  The exact graph of the
+#: same layers (benchmark/reference_depth.py) holds half as many nodes
+#: again at 100 layers; it bounds this from above.
+NODE_ENVELOPE = ((0, 1.0), (8, 1.44), (16, 1.88), (24, 2.26), (32, 2.51),
+                 (40, 2.72), (48, 2.89), (56, 3.09), (64, 3.17), (80, 3.44),
+                 (100, 3.61), (128, 3.83), (160, 3.97), (200, 4.10),
+                 (240, 4.17))
+
 
 def _sanitize():
     """The runtime sanitizer module (lazy: the analysis package must not
@@ -53,14 +90,33 @@ def _sanitize():
 #: run_consensus_phase buckets real windows.
 AUDIT_WINDOW_LENGTHS = (500, 1000)
 
-#: Declared compile budget for the audited POA grid: one jit signature
-#: per (depth bucket, window class) — len(DEPTH_BUCKETS) x
-#: len(AUDIT_WINDOW_LENGTHS) = 6.  A deliberate literal, not a product:
-#: widening DEPTH_BUCKETS, the audited window set, or any geometry
-#: change that splits signatures must consciously revisit this number or
-#: the jaxpr audit (racon_tpu/analysis) fails tier-1 — silent recompile
-#: blow-ups are the single biggest TPU serving-latency cliff.
-POA_RECOMPILE_BUDGET = 6
+#: Declared compile budget for the audited POA grid (audit_grid): one
+#: program per (depth bucket, window class) on the base rung —
+#: len(DEPTH_BUCKETS) x len(AUDIT_WINDOW_LENGTHS) = 6 — plus one per
+#: window class on the upper rung, which only the DEPTH_CAP bucket can
+#: ask for (a window of at most 32 layers holds under 2.6 x its backbone,
+#: NODE_ENVELOPE): 6 + 2 = 8.  Revisited on purpose for the node rungs:
+#: the two more are built by the first job deep enough to need them, not
+#: by every process's warm-up, so a process that never sees a deep
+#: window still builds 3 per window class.  A deliberate literal, not a
+#: product: widening DEPTH_BUCKETS, the audited window set, the rungs or
+#: any geometry change that splits signatures must consciously revisit
+#: this number or the jaxpr audit (racon_tpu/analysis) fails tier-1 —
+#: silent recompile blow-ups are the single biggest TPU serving-latency
+#: cliff.
+POA_RECOMPILE_BUDGET = 8
+
+
+def audit_grid(window_lengths=AUDIT_WINDOW_LENGTHS) -> list:
+    """(depth bucket, window class, rung) of every consensus program the
+    driver can ask for at these window lengths: each depth bucket on the
+    base rung, and the DEPTH_CAP bucket on every rung above it
+    (_node_rung)."""
+    classes = sorted({window_class(max(int(w), 1)) for w in window_lengths})
+    grid = [(d, c, 0) for d in DEPTH_BUCKETS for c in classes]
+    grid += [(DEPTH_CAP, c, r) for c in classes
+             for r in range(1, len(_rung_factors()))]
+    return grid
 
 
 def _batch_size() -> int:
@@ -122,12 +178,32 @@ def _device_batch(use_pallas: bool) -> int:
 
 
 def _node_factor() -> int:
-    """max_nodes = factor * window_length. The default 3 matches the
-    geometry every recorded pin was measured under; repeat-dense windows
-    (4 of λ's 96) overflow it and fall back to the host — the
-    reference's per-entry capacity rejection is the analogous knob
-    (/root/reference/src/cuda/cudabatch.cpp:141-160)."""
+    """The base rung: max_nodes = factor * window_length for every
+    window the estimate says it holds, which is every window of a 30x
+    job.  The default 3 matches the geometry every recorded pin was
+    measured under; repeat-dense windows (4 of λ's 96) overflow it and
+    fall back to the host — the reference's per-entry capacity
+    rejection is the analogous knob
+    (/root/reference/src/cuda/cudabatch.cpp:141-160).  The upper rung
+    is not a knob: UPPER_NODE_FACTOR, derived from NODE_ENVELOPE at
+    DEPTH_CAP."""
     return max(1, config.get_int("RACON_TPU_NODE_FACTOR"))
+
+
+def _rung_factors() -> tuple:
+    """max_nodes / window class of each rung, smallest first: the knob's
+    base rung, then the upper one unless the knob already reaches it."""
+    base = _node_factor()
+    return (base, UPPER_NODE_FACTOR) if base < UPPER_NODE_FACTOR else (base,)
+
+
+def node_estimate(bb_len: int, layer_bytes: int) -> int:
+    """Nodes a long-read window's graph will hold at most, before any
+    kernel runs: the backbone times NODE_ENVELOPE at the window's
+    effective layers (window_info's layer bytes over its backbone
+    length)."""
+    eff = layer_bytes / max(bb_len, 1)
+    return int(np.ceil(bb_len * np.interp(eff, *zip(*NODE_ENVELOPE))))
 
 
 def window_class(bb_len: int) -> int:
@@ -139,13 +215,13 @@ def window_class(bb_len: int) -> int:
 
 
 def make_config(window_length: int, depth: int, match: int, mismatch: int,
-                gap: int) -> poa.PoaConfig:
+                gap: int, rung: int = 0) -> poa.PoaConfig:
     def ceil128(x):
         return (x + 127) // 128 * 128
 
     max_backbone = ceil128(window_length)
     max_len = ceil128(window_length + window_length // 2)
-    max_nodes = ceil128(_node_factor() * window_length)
+    max_nodes = ceil128(_rung_factors()[rung] * window_length)
     return poa.PoaConfig(max_nodes=max_nodes, max_len=max_len,
                          max_backbone=max_backbone, max_edges=12,
                          depth=depth, match=match, mismatch=mismatch,
@@ -220,12 +296,12 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
     replayed = replay_windows(pipeline, journal, n, report)
 
     # Metadata pass: geometry + depth buckets, no layer bytes touched.
-    jobs = []          # (window_idx, estimated depth, backbone len)
+    jobs = []          # (window_idx, estimated depth, backbone len, nodes)
     with obs.span("poa.metadata", windows=n):
         for i in range(n):
             if i in replayed:
                 continue
-            (n_seqs, bb_len, _rank, _is_tgs, _bytes,
+            (n_seqs, bb_len, _rank, is_tgs, layer_bytes,
              tid) = pipeline.window_info(i)
             k = n_seqs - 1
             if k < 2:
@@ -243,7 +319,13 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
                                           wx.backbone.tobytes(), False)
                 stats["backbone"] += 1
                 continue
-            jobs.append((i, min(k, DEPTH_CAP), bb_len))
+            # Short accurate reads (racon's NGS windows: mean read under
+            # 1 kb) never outgrow the base rung: at DEPTH_CAP layers of
+            # 0.8 % substitutions a column holds under two nodes.  The
+            # envelope is the long reads'.
+            jobs.append((i, min(k, DEPTH_CAP), bb_len,
+                         node_estimate(bb_len, layer_bytes) if is_tgs
+                         else bb_len))
     # per-window ctypes calls (ROADMAP S5), counted once per loop
     obs.count("native.calls.window_info", n - len(replayed))
     report.record_served("backbone", stats["backbone"])
@@ -256,8 +338,10 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
         report.extra["kernels"] = {
             "interpreted": use_pallas and _platform() != "tpu",
             "batch": B, "shards": _shard_n(B)}
-        # Bucket by (depth, backbone class) to bound padding waste in BOTH
-        # dims: layers dropped at pack time (oversized/empty) only shrink
+        # Bucket by (depth, backbone class, node rung) to bound padding
+        # waste in all three: a deep window's graph needs node arrays a
+        # shallow one would pay for in every whole-array step (_node_rung).
+        # Layers dropped at pack time (oversized/empty) only shrink
         # a window's true depth, so a window always fits the bucket its
         # estimate chose; and short windows run in their own 128-grid
         # geometry class instead of the dataset-max geometry (one long
@@ -273,10 +357,19 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
         # report.extra["layers_dropped_maxlen"] so a serving-mix or
         # accuracy shift on mixed-length datasets is attributable.
         buckets = {}
-        for i, depth, bb in jobs:
+        capacities = {}    # window class -> its rungs' max_nodes
+        for win, depth, bb, est_nodes in jobs:
             bucket = next(b for b in DEPTH_BUCKETS if depth <= b)
-            buckets.setdefault((bucket, window_class(bb)),
-                               []).append((i, depth, bb))
+            wl_class = window_class(bb)
+            if wl_class not in capacities:
+                capacities[wl_class] = _rung_capacities(
+                    wl_class, use_pallas, match, mismatch, gap)
+            rung = _node_rung(est_nodes, bucket, capacities[wl_class])
+            buckets.setdefault((bucket, wl_class, rung),
+                               []).append((win, depth, bb))
+        report.extra["rung_windows"] = {
+            name: sum(len(b) for (_, _, r), b in buckets.items()
+                      if NODE_RUNGS[r] == name) for name in NODE_RUNGS}
 
         # geometries (cfg, kind) whose kernel already failed, with the
         # cause — seeded from warm-up failures so the measured run never
@@ -294,10 +387,11 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
             report=report)
         # windows in a class under the job's largest: the targets' tails
         # (one per contig; one per read in fragment correction)
-        nominal = max(c for _, c in buckets)
+        nominal = max(c for _, c, _ in buckets)
         obs.count("poa.windows.tail", sum(
-            len(b) for (_, c), b in buckets.items() if c < nominal))
-        for (depth_bucket, wl_class), bucket_jobs in sorted(buckets.items()):
+            len(b) for (_, c, _), b in buckets.items() if c < nominal))
+        for (depth_bucket, wl_class, rung), bucket_jobs in sorted(
+                buckets.items()):
             obs.count(f"poa.windows.d{depth_bucket}.c{wl_class}",
                       len(bucket_jobs))
             # Measured-cell counter for the cost model (obs/costmodel.py):
@@ -311,9 +405,10 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
             # chunk of bucket X may *drain* inside bucket Y's span — the
             # async-dispatch overlap the trace is there to make visible.
             with obs.span("poa.bucket", depth=depth_bucket,
-                          wl_class=wl_class, windows=len(bucket_jobs)):
+                          wl_class=wl_class, rung=NODE_RUNGS[rung],
+                          windows=len(bucket_jobs)):
                 cfg = make_config(wl_class, depth_bucket, match, mismatch,
-                                  gap)
+                                  gap, rung)
                 entry_kind = _pick_tier(cfg, use_pallas)
                 # a tier the warm-up proved dead is skipped below
                 # without a retry; the demotion still belongs in this
@@ -336,14 +431,14 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
                 # union over its 8 or 16 windows, so mixing a short window
                 # into a long program bills it the long program's ranks.
                 bucket_jobs.sort(key=lambda job: (job[1], job[2]))
-                ctx = _BucketCtx(cfg, entry_kind)
+                ctx = _BucketCtx(cfg, entry_kind, NODE_RUNGS[rung])
                 for off in range(0, len(bucket_jobs), B):
                     executor.submit(
-                        ctx, [i for i, _, _ in bucket_jobs[off:off + B]])
+                        ctx, [win for win, _, _ in bucket_jobs[off:off + B]])
                 if progress:
                     print(f"[racon_tpu::poa] bucket depth<={depth_bucket} "
-                          f"len<={wl_class}: {len(bucket_jobs)} windows",
-                          file=sys.stderr)
+                          f"len<={wl_class} nodes<={cfg.max_nodes}: "
+                          f"{len(bucket_jobs)} windows", file=sys.stderr)
         executor.flush()
         # feeder split: host pack wall vs blocked kernel wall, stamped
         # for bench.py's machine-checkable criterion
@@ -517,6 +612,35 @@ def _pick_tier(cfg, use_pallas: bool) -> str:
     return "ls" if use_pallas and _fits_vmem(cfg) else "xla"
 
 
+def _rung_capacities(wl_class: int, use_pallas: bool, match: int,
+                     mismatch: int, gap: int) -> tuple:
+    """max_nodes of the rungs a window of this class may climb, smallest
+    first.  With the Pallas tier on, a rung whose node arrays the
+    lockstep kernel cannot hold in VMEM (the upper rung past class 768)
+    is left out: such windows stay on the rung below, and the ones that
+    outgrow it go to the host."""
+    caps = []
+    for rung in range(len(_rung_factors())):
+        cfg = make_config(wl_class, DEPTH_CAP, match, mismatch, gap, rung)
+        if rung and use_pallas and not _fits_vmem(cfg):
+            break
+        caps.append(cfg.max_nodes)
+    return tuple(caps)
+
+
+def _node_rung(est_nodes: int, depth_bucket: int, capacities) -> int:
+    """Index into NODE_RUNGS of the rung a window runs on: the smallest
+    that holds its node estimate, the top one if none does (a window
+    that overflows it goes to the host, counted).  Only the DEPTH_CAP
+    bucket climbs: a window of at most 32 layers holds under 2.6 x its
+    backbone (NODE_ENVELOPE), which keeps the programs a process can
+    build at POA_RECOMPILE_BUDGET."""
+    if depth_bucket != DEPTH_CAP:
+        return 0
+    return next((r for r, cap in enumerate(capacities) if est_nodes <= cap),
+                len(capacities) - 1)
+
+
 def _next_tier(kind: str) -> str:
     """The lattice tier below `kind`."""
     return "xla" if kind == "ls" else "host"
@@ -552,15 +676,16 @@ def _warn_degrade(e, to_kind: str) -> None:
 
 
 class _BucketCtx:
-    """Per-(depth, class) bucket context the executor threads through the
-    ops hooks: the geometry, its entry tier, and the kernel handle the
-    most recent live_tier resolution built."""
+    """Per-(depth, class, rung) bucket context the executor threads
+    through the ops hooks: the geometry, its entry tier, its rung's name
+    and the kernel handle the most recent live_tier resolution built."""
 
-    __slots__ = ("cfg", "entry_kind", "kernel")
+    __slots__ = ("cfg", "entry_kind", "rung", "kernel")
 
-    def __init__(self, cfg, entry_kind):
+    def __init__(self, cfg, entry_kind, rung=NODE_RUNGS[0]):
         self.cfg = cfg
         self.entry_kind = entry_kind
+        self.rung = rung
         self.kernel = None
 
 
@@ -640,21 +765,22 @@ class _ConsensusOps:
 
     def dispatch(self, ctx, kind, packed, chunk):
         faults.check(f"poa.run.{kind}", [i for i, _, _ in chunk])
-        _count_launch(len(chunk), packed, self._groups(ctx, kind))
+        _count_launch(len(chunk), packed, self._groups(ctx, kind),
+                      ctx.rung)
         return _submit(ctx.kernel, packed, kind == "ls",
-                       _band_active(kind))
+                       _band_active(kind), ctx.rung)
 
     def attempt(self, ctx, kind, sub):
         pallas = kind == "ls"
         banded = _band_active(kind)
         faults.check(f"poa.run.{kind}", [i for i, _, _ in sub])
         packed = _pack(sub, ctx.cfg, self.B, self._widths(sub, ctx.cfg))
-        _count_launch(len(sub), packed, self._groups(ctx, kind))
-        return _unpack(_submit(ctx.kernel, packed, pallas, banded),
-                       pallas, banded)
+        _count_launch(len(sub), packed, self._groups(ctx, kind), ctx.rung)
+        return _unpack(_submit(ctx.kernel, packed, pallas, banded,
+                               ctx.rung), pallas, banded, ctx.rung)
 
     def unpack(self, ctx, kind, outs):
-        return _unpack(outs, kind == "ls", _band_active(kind))
+        return _unpack(outs, kind == "ls", _band_active(kind), ctx.rung)
 
     def span_args(self, ctx, chunk, pipelined):
         return {"windows": len(chunk), "pipelined": pipelined}
@@ -872,6 +998,9 @@ def _export_chunk(pipeline, idxs, cfg, fallback, stats=None, report=None):
     failure (the `window.export` seam) quarantines just that window.
     """
     chunk = []
+    # this chunk's admitted layers that DEPTH_CAP dropped, and the bases
+    # of the layers that are packed
+    capped = bases = 0
     obs.count("native.calls.export_window", len(idxs))
     for i in idxs:
         try:
@@ -893,7 +1022,12 @@ def _export_chunk(pipeline, idxs, cfg, fallback, stats=None, report=None):
         if len(keep) < len(wx.lens[:DEPTH_CAP]) and len(keep) < 2:
             fallback.append(i)
             continue
-        chunk.append((i, wx, keep[:DEPTH_CAP]))
+        capped += max(0, len(keep) - DEPTH_CAP)
+        keep = keep[:DEPTH_CAP]
+        bases += int(wx.lens[keep].sum())
+        chunk.append((i, wx, keep))
+    obs.count("poa.layers.capped", capped)
+    obs.count("poa.layers.bases", bases)
     return chunk
 
 
@@ -947,21 +1081,29 @@ def _pack(chunk, cfg, pad_to=None, band_widths=None):
     return (bb, bbw, bb_len, n_layers, seqs, ws, lens, begins, ends, wband)
 
 
-def _count_launch(n_real, packed, groups: int = 0) -> None:
+def _count_launch(n_real, packed, groups: int = 0,
+                  rung: str = NODE_RUNGS[0]) -> None:
     """One batch on its way to the device: `n_real` rows carry a
     window, the rest pad the batch to its compiled size (and to the
-    shard multiple).  `groups` is the lockstep kernel's group width for
-    this launch (0: the XLA twin serves, which has no grid programs):
-    its programs count as wide or narrow, both keys at every launch so
-    that a job served by narrow programs alone reads 0 % wide and not
-    nothing, and lock-step is billed what it costs: every window of a
-    program runs the program's largest layer count."""
+    shard multiple), on the node rung `rung` (every rung's key at every
+    launch, a zero too, so that a job the base rung served alone reads
+    0 % upper and not nothing).  `groups` is the lockstep kernel's
+    group width for this launch (0: the XLA twin serves, which has no
+    grid programs): its programs count as wide or narrow, both keys at
+    every launch so that a job served by narrow programs alone reads
+    0 % wide and not nothing, and lock-step is billed what it costs:
+    every window of a program runs the program's largest layer
+    count."""
     from .poa_pallas_ls import G
 
     rows = len(packed[0])
     obs.count("poa.launches")
     obs.count("poa.rows.real", n_real)
     obs.count("poa.rows.pad", rows - n_real)
+    for name in NODE_RUNGS:
+        obs.count(f"poa.windows.rung.{name}", n_real if name == rung else 0)
+    n_layers = np.asarray(packed[3])
+    obs.count("poa.layers.admitted", int(n_layers.sum()))
     width = groups * G                 # windows a grid program
     programs = rows // width if width else 0
     obs.count("poa.programs.wide", programs if groups > 1 else 0)
@@ -969,19 +1111,19 @@ def _count_launch(n_real, packed, groups: int = 0) -> None:
     if width:
         # a shard's rows are contiguous and a multiple of the program's
         # width, so programs are consecutive runs of the packed rows
-        n_layers = np.asarray(packed[3])
         obs.count("poa.lockstep.layers.real", int(n_layers.sum()))
         obs.count("poa.lockstep.layers.slots", int(
             width * n_layers.reshape(-1, width).max(axis=1).sum()))
 
 
-def _submit(kernel, packed, use_pallas, banded=False):
+def _submit(kernel, packed, use_pallas, banded=False,
+            rung: str = NODE_RUNGS[0]):
     """Dispatch one packed chunk; returns device futures (async).
     `packed` is _pack's 10-tuple (trailing per-window half-band row) or
     a legacy 9-tuple from flat-only callers (probes, the multichip
     worker) — the band row is only touched on banded dispatch."""
     bb, bbw, bb_len, n_layers, seqs, ws, lens, begins, ends = packed[:9]
-    with obs.span("poa.dispatch", cat="launch", B=len(bb)):
+    with obs.span("poa.dispatch", cat="launch", B=len(bb), rung=rung):
         if use_pallas:
             args = [bb_len[:, None], n_layers[:, None], lens, begins,
                     ends, bb.astype(np.int32), bbw, seqs.astype(np.int32),
@@ -993,22 +1135,33 @@ def _submit(kernel, packed, use_pallas, banded=False):
                       ends)
 
 
-def _unpack(outs, use_pallas, banded=False):
-    """Block on device futures; normalize to host arrays."""
+class _Unpacked(tuple):
+    """_unpack's host arrays, (cons_base, cons_cov, cons_len, failed[,
+    band_hit]) as every caller takes them apart; `nodes`, the kernels'
+    fifth output (each window's graph size at the end), rides beside
+    them for _install's fill counters."""
+
+    nodes = None
+
+
+def _unpack(outs, use_pallas, banded=False, rung: str = NODE_RUNGS[0]):
+    """Block on device futures; normalize to host arrays.  `failed` is 0
+    for a served window, else the cause (poa.FAIL_CAUSES)."""
     cb, cc, cl, fl = outs[0], outs[1], outs[2], outs[3]
-    with obs.span("poa.wait", cat="launch", B=len(cb)):
+    with obs.span("poa.wait", cat="launch", B=len(cb), rung=rung):
         cons_base = np.asarray(cb)
         cons_cov = np.asarray(cc)
         cons_len = np.asarray(cl)
         failed = np.asarray(fl)
+        nodes = np.asarray(outs[4])
         band_hit = (np.asarray(outs[5])[:, 0]
                     if use_pallas and banded else None)
     if use_pallas:
-        cons_len = cons_len[:, 0]
-        failed = failed[:, 0]
-        if banded:
-            return cons_base, cons_cov, cons_len, failed, band_hit
-    return cons_base, cons_cov, cons_len, failed
+        cons_len, failed, nodes = cons_len[:, 0], failed[:, 0], nodes[:, 0]
+    res = _Unpacked((cons_base, cons_cov, cons_len, failed)
+                    + ((band_hit,) if use_pallas and banded else ()))
+    res.nodes = nodes
+    return res
 
 
 def _install(pipeline, chunk, results, trim, stats, fallback, report=None,
@@ -1027,6 +1180,9 @@ def _install(pipeline, chunk, results, trim, stats, fallback, report=None,
     else:
         cons_base, cons_cov, cons_len, failed = results
         band_hit = None
+    nodes = getattr(results, "nodes", None)
+    n_served = nodes_used = 0
+    overflow = dict.fromkeys(poa.FAIL_CAUSES, 0)   # cause -> windows
     retry = []
     for bi, (i, wx, keep) in enumerate(chunk):
         st = band_states.get(i) if band_states else None
@@ -1047,7 +1203,12 @@ def _install(pipeline, chunk, results, trim, stats, fallback, report=None,
         if failed[bi]:
             fallback.append(i)
             stats["failed"] += 1
+            cause = int(failed[bi])
+            overflow[cause if cause in overflow else poa.FAIL_OTHER] += 1
             continue
+        n_served += 1
+        if nodes is not None:
+            nodes_used += int(nodes[bi])
         cl = int(cons_len[bi])
         codes = cons_base[bi, :cl]
         cov = cons_cov[bi, :cl]
@@ -1087,4 +1248,11 @@ def _install(pipeline, chunk, results, trim, stats, fallback, report=None,
         stats["device"] += 1
         if report is not None and tier is not None:
             report.record_served(tier)
+    # once per launch: how full the served windows' graphs were, and why
+    # the kernel gave the others up (every cause's key, a zero too)
+    if nodes is not None:
+        obs.count("poa.nodes.used", nodes_used)
+        obs.count("poa.nodes.capacity", n_served * cons_base.shape[1])
+    for cause, name in poa.FAIL_CAUSES.items():
+        obs.count(f"poa.windows.overflow.{name}", overflow[cause])
     return retry

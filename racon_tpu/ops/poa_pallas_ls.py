@@ -54,7 +54,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..device import named
 from .kernel_cache import device_keyed_cache
-from .poa import PoaConfig
+from .poa import (FAIL_DISTANCE, FAIL_EDGES, FAIL_NODES, FAIL_OTHER,
+                  PoaConfig, first_cause)
 
 NEG = -(1 << 28)
 G = 8            # windows per kernel program (the sublane dimension)
@@ -375,7 +376,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 bad = ((delta_v[e] > DMAX) & in_sub &
                        ((rr - delta_v[e]) >= r_lo))
                 far = far | wany(bad).astype(jnp.int32)
-            failed = failed | jnp.where(lact & (far > 0), 1, 0)
+            failed = first_cause(failed, lact & (far > 0), FAIL_DISTANCE)
 
             esc[...] = jnp.full(n_shape, NEG, jnp.int32)
 
@@ -492,7 +493,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             best_s = wmax(escv)
             best_r = wmin(jnp.where((escv == best_s) & endok, rr, N))
             has_end = best_s > NEG
-            failed = failed | jnp.where(lact & ~has_end, 1, 0)
+            failed = first_cause(failed, lact & ~has_end, FAIL_OTHER)
             if band:
                 # score-deficit verify (host mirror: band.poa_deficit_bound)
                 deficit_bad = (M * Ln - best_s >
@@ -579,7 +580,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 okm = ok & (jj <= jcur) & here
                 j_stop = wmax(jnp.where(okm, jj, -1))
                 stuck = here & (j_stop < 0)
-                failed = failed | jnp.where(stuck, 1, 0)
+                failed = first_cause(failed, stuck, FAIL_OTHER)
                 done = done | jnp.where(stuck, 1, 0)
                 act = here & ~stuck
                 j_stop = jnp.maximum(j_stop, 0)
@@ -668,7 +669,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 cur, jcur, nk, run, done, failed = jax.lax.fori_loop(
                     0, b_top + 1, tb_block,
                     (cur, jcur, nk0, run0, done0, failed))
-            failed = failed | jnp.where((done == 0) & lact, 1, 0)
+            failed = first_cause(failed, (done == 0) & lact, FAIL_OTHER)
 
             # ---- graph update (parity: rt_poa.cpp add_alignment) --------
             maxL = jnp.max(jnp.where(lact & (failed == 0), Ln, 0))
@@ -758,7 +759,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 touch = act & ~overflow
                 rmw_v(rk_cov, nid, ex_v(rk_cov[...], nid) + 1, touch)
                 n = n + jnp.where(do_new, 1, 0)
-                failed = failed | jnp.where(overflow, 1, 0)
+                failed = first_cause(failed, overflow, FAIL_NODES)
 
                 # edge prev -> nid with weight w[j-1] + w[j]
                 prev_r = prev_r + jnp.where(do_new & (prev_r >= p_ins),
@@ -795,8 +796,8 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                     cnt_max, jnp.max(jnp.where(add_new, cntv + 1, 0)))
                 jax.lax.fori_loop(0, slot_hi, eslot_write, 0)
                 rmw_v(rk_cnt, nid, cntv + 1, add_new)
-                failed = failed | jnp.where(
-                    has_prev & (same < 0) & (cntv >= E), 1, 0)
+                failed = first_cause(
+                    failed, has_prev & (same < 0) & (cntv >= E), FAIL_EDGES)
 
                 prev_r = jnp.where(act, nid, prev_r)
                 prev_key = jnp.where(act, key_val, prev_key)
@@ -936,7 +937,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
 
         for i in range(W):
             cl_s[0, 0, i] = scalar_of(cnt_f, i)
-            fl_s[0, 0, i] = jnp.where(scalar_of(failed, i) > 0, 1, 0)
+            fl_s[0, 0, i] = scalar_of(failed, i)     # 0 or a FAIL_* cause
             nn_s[0, 0, i] = scalar_of(n, i)
             if band:
                 bh_s[0, 0, i] = jnp.where(scalar_of(hit, i) > 0, 1, 0)

@@ -188,7 +188,15 @@ class PolishSession:
         job's consensus phase finds everything hot.  Device backend
         only; returns the wall seconds spent.  Same mechanism as the
         phase pipeline's warm-up thread (polisher.py) and bench.py's
-        prewarm — ``poa_driver.warm_geometries``."""
+        prewarm — ``poa_driver.warm_geometries``.
+
+        Every geometry of the base node rung, that is: what every job
+        needs.  The upper rung's program (windows of more than ~55
+        layers, ``poa_driver.NODE_RUNGS``) is built by the first job
+        deep enough to ask for it, which pays its ~10 s of trace, lower
+        and compile once per process; building it here would charge
+        every process, deep jobs or none, one more program per window
+        class."""
         if self.backend != "tpu":
             return 0.0
         from ..ops import poa_driver

@@ -91,7 +91,10 @@ def test_count_launch_full_batch_of_wide_programs():
     assert c == {"poa.launches": 1, "poa.rows.real": 64, "poa.rows.pad": 0,
                  "poa.programs.wide": 4, "poa.programs.narrow": 0,
                  "poa.lockstep.layers.real": sum(layers),
-                 "poa.lockstep.layers.slots": by_hand}
+                 "poa.lockstep.layers.slots": by_hand,
+                 # the node rungs' counters (tests/test_deep_cell.py)
+                 "poa.windows.rung.base": 64, "poa.windows.rung.upper": 0,
+                 "poa.layers.admitted": sum(layers)}
     assert by_hand == 16 * (layers[15] + layers[31] + layers[47]
                             + layers[63])
 
@@ -135,7 +138,9 @@ def test_count_launch_geometry_that_gets_one_group():
 def test_count_launch_of_the_xla_twin_has_no_programs():
     c = _counted(3, [5, 5, 5, 0], 0)
     assert c == {"poa.launches": 1, "poa.rows.real": 3, "poa.rows.pad": 1,
-                 "poa.programs.wide": 0, "poa.programs.narrow": 0}
+                 "poa.programs.wide": 0, "poa.programs.narrow": 0,
+                 "poa.windows.rung.base": 3, "poa.windows.rung.upper": 0,
+                 "poa.layers.admitted": 15}
 
 
 def test_driver_counts_what_it_launches(tmp_path, monkeypatch):
@@ -151,9 +156,9 @@ def test_driver_counts_what_it_launches(tmp_path, monkeypatch):
     counted = []
     real = poa_driver._count_launch
 
-    def spy(n_real, packed, groups=0):
+    def spy(n_real, packed, groups=0, *rung):
         counted.append((n_real, len(packed[0]), groups))
-        real(n_real, packed, groups)
+        real(n_real, packed, groups, *rung)
 
     monkeypatch.setattr(poa_driver, "_count_launch", spy)
     res, phase = _polish_perfect_reads(tmp_path)

@@ -94,10 +94,10 @@ def _poa_args(cfg, B, band=False):
     return args + (np.zeros(B, np.int32),) if band else args
 
 
-def _ls(window_length, depth, B, band=False, scores=SCORES):
+def _ls(window_length, depth, B, band=False, scores=SCORES, rung=0):
     from racon_tpu.ops.poa_pallas_ls import build_lockstep_poa_kernel
 
-    cfg = poa_driver.make_config(window_length, depth, *scores)
+    cfg = poa_driver.make_config(window_length, depth, *scores, rung)
     # the VMEM-fit model must agree: a geometry it approves has to build
     assert poa_driver._fits_vmem(cfg), "fit model rejects geometry"
     # at the group width the driver derives for this class and batch, and
@@ -255,6 +255,32 @@ def test_wide_program_past_class_512_needs_the_limit_it_ships_with(
             _compile_v5e(*_ls(640, 8, SHARD_BATCH))
     finally:
         poa_pallas_ls.build_lockstep_poa_kernel.cache_clear()
+
+
+@pytest.mark.parametrize("window_length,B,limit_mib", [
+    (500, 8, None), (500, TPU_BATCH, 27),
+    (768, TPU_BATCH, 39),    # the last class the upper rung is climbed at
+], ids=["w500-u1", "w500-u2", "w768-u2"])
+def test_upper_rung_program_compiles_for_v5e(window_length, B, limit_mib):
+    """The deep cell's program (ecoli-ont-deep.sam): class 512 on the
+    upper node rung, 2560 graph slots, node arrays of 20 lane-chunks
+    where the base rung's are 12.  One group's arrays sum to 6.58 MiB,
+    under the default scoped-VMEM limit; the program of sixteen's to
+    13.16 MiB, which compiles under vmem_limit_bytes (27 MiB) and had
+    not met the chip before PR 35.  Past class 768 one group's arrays
+    pass what the default limit holds and poa_driver._rung_capacities
+    leaves the rung out (tests/test_deep_cell.py holds the table)."""
+    from racon_tpu.ops import poa_pallas_ls
+
+    cfg = poa_driver.make_config(window_length, 200, *SCORES, 1)
+    groups = poa_driver._group_width(cfg, B)
+    assert cfg.max_nodes == 5 * poa_driver.window_class(window_length)
+    assert groups == (1 if B == 8 else 2)
+    limit = poa_pallas_ls.vmem_limit_bytes(cfg, groups)
+    assert limit == (limit_mib and limit_mib << 20)
+    fn, args = _ls(window_length, 200, B, rung=1)
+    _export_tpu(fn, args)
+    _compile_v5e(fn, args)
 
 
 @pytest.mark.parametrize("node_factor,window_length",
