@@ -16,7 +16,9 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
-from typing import Optional
+from typing import List, Optional
+
+import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _LIB_PATH = os.path.join(_DIR, "build", "libracon_host.so")
@@ -167,6 +169,24 @@ def load() -> ctypes.CDLL:
     lib.rt_pipeline_window_type.restype = ctypes.c_int
     lib.rt_pipeline_window_type.argtypes = [ctypes.c_void_p]
 
+    # rt_hirschberg.hpp: numpy buffers go in by address (`_addr`)
+    vp = ctypes.c_void_p
+    lib.rt_hirschberg_pack.restype = ctypes.c_int64
+    lib.rt_hirschberg_pack.argtypes = [
+        vp, vp, ctypes.c_uint64, ctypes.c_int32, ctypes.c_int32,
+        ctypes.c_int, ctypes.c_uint32, vp, vp, vp]
+
+    lib.rt_hirschberg_select.restype = None
+    lib.rt_hirschberg_select.argtypes = [
+        vp, vp, ctypes.c_uint32, vp, vp, vp, ctypes.c_uint64, vp, vp]
+
+    lib.rt_hirschberg_gather.restype = None
+    lib.rt_hirschberg_gather.argtypes = [
+        vp, vp, ctypes.c_uint64, ctypes.c_int, vp]
+
+    lib.rt_ops_to_cigars.restype = ctypes.c_int64
+    lib.rt_ops_to_cigars.argtypes = [vp, vp, ctypes.c_uint64, vp, vp]
+
     _lib = lib
     return lib
 
@@ -235,3 +255,97 @@ def window_consensus(backbone: bytes, layers, *, backbone_qual: bytes = None,
         return ctypes.string_at(ptr), bool(polished.value)
     finally:
         lib.rt_free(ptr)
+
+
+# --------------------------------------------------------------------------
+# host bookkeeping of the device's Hirschberg aligner (rt_hirschberg.hpp)
+# --------------------------------------------------------------------------
+
+def _addr(a: np.ndarray, dtype) -> int:
+    """Address of a C-contiguous array of `dtype`, checked before native
+    code reads it as one."""
+    if a.dtype != dtype or not a.flags.c_contiguous:
+        raise TypeError(f"want a C-contiguous {np.dtype(dtype).name} array, "
+                        f"got {a.dtype.name} {a.shape}")
+    return a.ctypes.data
+
+
+#: columns of the per-pair table `hirschberg_pack` reads (int64): address
+#: of the pair's int32 query codes, of its target codes, n, m, gdmin
+PAIR_COLS = 5
+#: columns of a task table (int32): pair (-1 = pad slot), ia, ib, ja, jb
+TASK_COLS = 5
+
+
+def hirschberg_pack(pairs: np.ndarray, tasks: np.ndarray, rcap: int, K: int,
+                    backward: bool, q_words: int):
+    """Stage one launch: (scal [B, 4], qs [B, q_words], ts [B, rcap + K])
+    for the B slots of `tasks`, from the code arrays `pairs` points at
+    (the caller keeps them alive)."""
+    B = len(tasks)
+    if pairs.shape[1:] != (PAIR_COLS,) or tasks.shape[1:] != (TASK_COLS,):
+        raise ValueError((pairs.shape, tasks.shape))
+    if B and int(tasks[:, 0].max()) >= len(pairs):
+        raise IndexError("task of a pair outside the table")
+    scal = np.empty((B, 4), np.int32)
+    qs = np.empty((B, q_words), np.int32)
+    ts = np.empty((B, rcap + K), np.int32)
+    bad = load().rt_hirschberg_pack(
+        _addr(pairs, np.int64), _addr(tasks, np.int32), B, rcap, K,
+        1 if backward else 0, q_words, scal.ctypes.data, qs.ctypes.data,
+        ts.ctypes.data)
+    if bad >= 0:
+        raise ValueError(f"slot {bad}: task {tasks[bad].tolist()} does not "
+                         f"fit its pair or rcap={rcap}, K={K}")
+    return scal, qs, ts
+
+
+def hirschberg_select(F: np.ndarray, Bv: np.ndarray, rows: np.ndarray,
+                      lo: np.ndarray, hi: np.ndarray):
+    """(lane, tot) per task: the first lane of row `rows[i]` within
+    [lo[i], hi[i]] that minimises F + Bv; lane -1 where the range misses
+    the row."""
+    n = len(rows)
+    if F.shape != Bv.shape or F.ndim != 2 or not len(lo) == len(hi) == n:
+        raise ValueError((F.shape, Bv.shape, n, len(lo), len(hi)))
+    if n and not 0 <= int(rows.min()) <= int(rows.max()) < len(F):
+        raise IndexError("row outside the launch")
+    lane = np.empty(n, np.int32)
+    tot = np.empty(n, np.int32)
+    load().rt_hirschberg_select(
+        _addr(F, np.int32), _addr(Bv, np.int32), F.shape[1],
+        _addr(rows, np.int32), _addr(lo, np.int32), _addr(hi, np.int32), n,
+        lane.ctypes.data, tot.ctypes.data)
+    return lane, tot
+
+
+def hirschberg_gather(src: np.ndarray, cnt: np.ndarray,
+                      reverse: bool) -> np.ndarray:
+    """The int32 codes of the segments laid back to back: segment s is
+    cnt[s] codes read at address src[s] (the caller vouches for it and
+    keeps it alive), back to front if `reverse`."""
+    n = len(src)
+    if len(cnt) != n or (n and int(cnt.min()) < 0):
+        raise ValueError((n, len(cnt)))
+    out = np.empty(int(cnt.sum()), np.int32)
+    load().rt_hirschberg_gather(
+        _addr(src, np.int64), _addr(cnt, np.int32), n, 1 if reverse else 0,
+        out.ctypes.data)
+    return out
+
+
+def ops_to_cigars(ops: np.ndarray, off: np.ndarray) -> List[str]:
+    """CIGAR strings of the pairs whose forward op codes (0=M, 1=I, 2=D)
+    lie back to back in `ops`, pair p at ops[off[p]:off[p + 1]]."""
+    n = len(off) - 1
+    if n < 0 or off[0] != 0 or int(off[-1]) != len(ops) \
+            or (np.diff(off.astype(np.int64)) < 0).any():
+        raise ValueError("offsets do not tile the op codes")
+    out = np.empty(2 * len(ops), np.uint8)
+    ends = np.empty(n + 1, np.uint64)
+    if load().rt_ops_to_cigars(
+            _addr(ops, np.int32), _addr(off, np.uint64), n, out.ctypes.data,
+            ends.ctypes.data) < 0:
+        raise ValueError("op code outside 0..2")
+    text = out[:int(ends[-1])].tobytes().decode("ascii")
+    return [text[a:b] for a, b in zip(ends[:-1].tolist(), ends[1:].tolist())]

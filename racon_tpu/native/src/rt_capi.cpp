@@ -10,6 +10,7 @@
 #include <vector>
 
 #include "rt_align.hpp"
+#include "rt_hirschberg.hpp"
 #include "rt_pipeline.hpp"
 #include "rt_poa.hpp"
 #include "rt_sequence.hpp"
@@ -56,6 +57,33 @@ const char* rt_last_error() {
 }
 
 // ---------- standalone kernels -------------------------------------------
+
+// Host bookkeeping of the device's Hirschberg aligner, one call per launch
+// (rt_hirschberg.hpp): the caller owns and sizes every buffer.
+int64_t rt_hirschberg_pack(const int64_t* pairs, const int32_t* tasks,
+                           uint64_t n_slots, int32_t rcap, int32_t K,
+                           int backward, uint32_t q_words, int32_t* scal,
+                           int32_t* qs, int32_t* ts) {
+  return rt::hirschberg_pack(pairs, tasks, n_slots, rcap, K, backward != 0,
+                             q_words, scal, qs, ts);
+}
+
+void rt_hirschberg_select(const int32_t* F, const int32_t* Bv, uint32_t K,
+                          const int32_t* rows, const int32_t* lo,
+                          const int32_t* hi, uint64_t n, int32_t* lane,
+                          int32_t* tot) {
+  rt::hirschberg_select(F, Bv, K, rows, lo, hi, n, lane, tot);
+}
+
+void rt_hirschberg_gather(const int64_t* src, const int32_t* cnt, uint64_t n,
+                          int reverse, int32_t* out) {
+  rt::hirschberg_gather(src, cnt, n, reverse != 0, out);
+}
+
+int64_t rt_ops_to_cigars(const int32_t* ops, const uint64_t* off, uint64_t n,
+                         char* out, uint64_t* out_off) {
+  return rt::ops_to_cigars(ops, off, n, out, out_off);
+}
 
 int64_t rt_edit_distance(const char* q, uint32_t q_len, const char* t,
                          uint32_t t_len) {

@@ -23,6 +23,7 @@
 
 #include "../src/rt_align.hpp"
 #include "../src/rt_error.hpp"
+#include "../src/rt_hirschberg.hpp"
 #include "../src/rt_overlap.hpp"
 #include "../src/rt_parsers.hpp"
 #include "../src/rt_pipeline.hpp"
@@ -530,11 +531,86 @@ static void test_pipeline() {
   CHECK(threw);
 }
 
+// ---- Hirschberg launch bookkeeping ----------------------------------------
+// The pytest layer holds these to the per-task Python they replaced; here
+// they run under the sanitizer builds, on buffers sized exactly.
+
+static void test_hirschberg() {
+  const std::vector<int32_t> q = {0, 1, 2, 3, 4, 0, 1, 2, 3, 0};
+  const std::vector<int32_t> t = {0, 1, 2, 3, 0, 1, 2, 3, 0, 1, 2, 3};
+  const int64_t pairs[rt::kHirschbergPairCols] = {
+      reinterpret_cast<int64_t>(q.data()), reinterpret_cast<int64_t>(t.data()),
+      static_cast<int64_t>(q.size()), static_cast<int64_t>(t.size()), -2};
+  // slot 0: rows [2, 9) against columns [1, 12]; slot 1 a pad
+  const int32_t tasks[2 * rt::kHirschbergTaskCols] = {0, 2, 9, 1, 12,
+                                                      -1, 0, 0, 0, 0};
+  const int32_t rcap = 8, K = 4;
+  const uint32_t q_words = 2;
+  for (bool backward : {false, true}) {
+    std::vector<int32_t> scal(2 * 4, -1), qs(2 * q_words, -1),
+        ts(2 * (rcap + K), -1);
+    CHECK_EQ(rt::hirschberg_pack(pairs, tasks, 2, rcap, K, backward, q_words,
+                                 scal.data(), qs.data(), ts.data()),
+             int64_t{-1});
+    CHECK_EQ(scal[0], 7);                          // R
+    CHECK_EQ(scal[1], backward ? 11 : 10);         // S: clipped going forward
+    CHECK_EQ(scal[2], -1);                         // dmin = gdmin + ia - j_lo
+    CHECK_EQ(qs[0], backward ? (3 | 2 << 8 | 1 << 16 | 0 << 24)
+                             : (2 | 3 << 8 | 4 << 16 | 0 << 24));
+    CHECK_EQ(scal[4], 0);                          // the pad slot: R = 0
+    CHECK_EQ(qs[q_words], 0);
+    CHECK_EQ(ts[rcap + K], 255);
+  }
+  {
+    // a task past the query's end is refused, nothing read
+    const int32_t bad[rt::kHirschbergTaskCols] = {0, 2, 11, 1, 12};
+    std::vector<int32_t> scal(4), qs(q_words), ts(rcap + K);
+    CHECK_EQ(rt::hirschberg_pack(pairs, bad, 1, rcap, K, false, q_words,
+                                 scal.data(), qs.data(), ts.data()),
+             int64_t{0});
+  }
+  {
+    const int32_t F[8] = {5, 1, 1, 9, 9, 9, 9, 0};
+    const int32_t B[8] = {0, 1, 1, 0, 0, 0, 0, 9};
+    const int32_t rows[3] = {0, 0, 1}, lo[3] = {-3, 3, 2}, hi[3] = {9, 1, 3};
+    int32_t lane[3], tot[3];
+    rt::hirschberg_select(F, B, 4, rows, lo, hi, 3, lane, tot);
+    CHECK_EQ(lane[0], 1);                          // first of the two 2s
+    CHECK_EQ(tot[0], 2);
+    CHECK_EQ(lane[1], -1);                         // empty range
+    CHECK(tot[1] >= (1 << 28));
+    CHECK_EQ(lane[2], 2);                          // 9, 9: the first
+    CHECK_EQ(tot[2], 9);
+  }
+  {
+    const std::vector<int32_t> ops = {2, 1, 0, 0, 7, 7, 1, 1, 1, 0};
+    const int64_t src[2] = {reinterpret_cast<int64_t>(ops.data()),
+                            reinterpret_cast<int64_t>(ops.data() + 6)};
+    const int32_t cnt[2] = {4, 4};
+    std::vector<int32_t> out(8, -1);
+    rt::hirschberg_gather(src, cnt, 2, true, out.data());
+    const std::vector<int32_t> want = {0, 0, 1, 2, 0, 1, 1, 1};
+    CHECK(out == want);
+    const uint64_t off[4] = {0, 4, 4, 8};
+    std::vector<char> text(2 * 8);
+    uint64_t ends[4];
+    const int64_t len = rt::ops_to_cigars(out.data(), off, 3, text.data(),
+                                          ends);
+    CHECK_EQ(std::string(text.data(), static_cast<size_t>(len)),
+             std::string("2M1I1D1M3I"));
+    CHECK_EQ(ends[1], uint64_t{6});
+    CHECK_EQ(ends[2], uint64_t{6});
+    CHECK_EQ(rt::ops_to_cigars(ops.data(), off, 3, text.data(), ends),
+             int64_t{-1});                         // a 7 is no op code
+  }
+}
+
 int main() {
   g_tmpdir = "/tmp/rt_test_" + std::to_string(::getpid());
   ::mkdir(g_tmpdir.c_str(), 0755);
   test_sequence();
   test_align();
+  test_hirschberg();
   test_overlap();
   test_poa();
   test_parsers();

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import native
 from .encoding import encode
 from ..device import named
 from .kernel_cache import device_keyed_cache
@@ -372,17 +373,15 @@ def run_jobs(pipeline, jobs, batch: int = 16, report=None,
     return state["served"]
 
 
-_OPC = np.frombuffer(b"MID", dtype=np.uint8)
+def ops_to_cigars(ops_list) -> list:
+    """CIGAR strings of forward-ordered op code arrays (0=M, 1=I, 2=D):
+    one native run-length pass over them all."""
+    off = np.zeros(len(ops_list) + 1, np.uint64)
+    np.cumsum([len(ops) for ops in ops_list], out=off[1:])
+    flat = np.concatenate(ops_list) if len(ops_list) else ()
+    return native.ops_to_cigars(np.ascontiguousarray(flat, np.int32), off)
 
 
 def ops_to_cigar(ops: np.ndarray) -> str:
     """Run-length encode forward-ordered op codes (0=M,1=I,2=D)."""
-    if len(ops) == 0:
-        return ""
-    change = np.nonzero(np.diff(ops))[0]
-    starts = np.concatenate([[0], change + 1])
-    ends = np.concatenate([change + 1, [len(ops)]])
-    out = []
-    for s, e in zip(starts, ends):
-        out.append(f"{e - s}{chr(_OPC[ops[s]])}")
-    return "".join(out)
+    return ops_to_cigars([ops])[0]

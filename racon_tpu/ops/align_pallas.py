@@ -24,7 +24,7 @@ log2(band) prefix-min steps) serve eight tasks for the price of one; one
 task per program left seven of the eight sublanes of every vreg empty.
 One program has one loop counter and one rotation amount, so whatever
 differs per task is folded into the data the host stages
-(_task_arrays), per-task scalars are (8, 1) columns broadcast along
+(_pack_launch), per-task scalars are (8, 1) columns broadcast along
 lanes, and the loop runs to the program's largest row count while
 shorter tasks carry their row through.  The host orders a launch's
 tasks by row count before it cuts them into programs; pad slots have no
@@ -65,10 +65,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import config, obs
+from .. import config, native, obs
 from ..device import named
 from . import band as _band
-from .encoding import PACK, encode, pack_bases
+from .encoding import PACK, encode
 from .kernel_cache import device_keyed_cache
 
 INF = 1 << 28
@@ -226,7 +226,7 @@ def _build_edge_kernel(rcap: int, K: int, backward: bool,
     B[i+1][o], B[i+1][o-1]... expressed with opposite shifts).
 
     One program has one loop counter and one rotation amount, so what
-    differs per task lives in the data (_task_arrays): the staged target
+    differs per task lives in the data (_pack_launch): the staged target
     is pre-shifted by each task's dmin (and R, backward) so that step k
     rotates every sublane by the same k, and the backward query arrives
     reversed so that step k reads word/code k in every sublane.  The
@@ -335,7 +335,7 @@ def _build_base_kernel(K: int, interpret: bool = False):
     distance per task.
 
     The forward DP is the edge kernel's: GROUP tasks per grid program in
-    lock-step, task g in sublane g, inputs staged by _task_arrays.  Each
+    lock-step, task g in sublane g, inputs staged by _pack_launch.  Each
     loop iteration retires MOVE_ROWS rows and stores their moves as one
     word tile, a byte per row, so the move matrix of eight tasks is
     BASE_ROWS / MOVE_ROWS tiles of (GROUP, K): 0.5-4 MB of VMEM at K
@@ -480,13 +480,6 @@ def _build_base_kernel(K: int, interpret: bool = False):
 # host orchestrator
 # ---------------------------------------------------------------------------
 
-class _Task:
-    __slots__ = ("pair", "ia", "ib", "ja", "jb")
-
-    def __init__(self, pair, ia, ib, ja, jb):
-        self.pair, self.ia, self.ib, self.ja, self.jb = pair, ia, ib, ja, jb
-
-
 def _interpret() -> bool:
     import jax as _jax
     return _jax.devices()[0].platform != "tpu"
@@ -522,6 +515,64 @@ class _Launch:
         return outs
 
 
+# A round's tasks are a table, one int32 row a task: query rows [ia, ib)
+# of pair `pair` against its target columns [ja, jb].  In a launch's table
+# (one row a slot) a pad slot has pair -1.
+PAIR, IA, IB, JA, JB = range(native.TASK_COLS)
+_ROW_BUCKETS = np.array(ROW_BUCKETS)
+
+
+class _Run:
+    """What one `align_steps` call's launches read and write, per pair:
+    `table` (native.PAIR_COLS int64 columns: the addresses of the pair's
+    int32 query and target codes, kept alive in `held`, then n, m and
+    gdmin), the band `K` (0 = not aligned here), `banded` (under a band
+    override: its root task carries the Ukkonen certificate) and
+    `failed`; `segs` collects the base launches' op codes."""
+
+    def __init__(self, pairs, band_overrides, interpret, in_flight):
+        self.interpret, self.in_flight = interpret, in_flight
+        self.table = np.zeros((len(pairs), native.PAIR_COLS), np.int64)
+        self.K = np.zeros(len(pairs), np.int32)
+        self.banded = np.zeros(len(pairs), bool)
+        self.failed = np.zeros(len(pairs), bool)
+        self.held = []
+        self.segs = []
+        for idx, (q, t) in enumerate(pairs):
+            n, m = len(q), len(t)
+            K = band_for(n, m)
+            if K == 0 or n == 0 or m == 0 or (n + 1) // 2 > ROW_BUCKETS[-1]:
+                continue
+            kb = band_overrides.get(idx) if band_overrides else None
+            if kb is not None and kb < K:
+                K = int(kb)
+                self.banded[idx] = True
+            gdmin = int(np.minimum(0, m - n) - (K - 1 - abs(m - n)) // 2)
+            q = np.ascontiguousarray(q, np.int32)
+            t = np.ascontiguousarray(t, np.int32)
+            self.held.append((q, t))
+            self.table[idx] = (q.ctypes.data, t.ctypes.data, n, m, gdmin)
+            self.K[idx] = K
+        self.n, self.m, self.gdmin = (
+            self.table[:, c].astype(np.int32) for c in (2, 3, 4))
+
+    def roots(self, tasks):
+        """Mask over a task table: the root task of a banded pair."""
+        if not self.banded.any():
+            return np.zeros(len(tasks), bool)
+        pair = tasks[:, PAIR]
+        return ((pair >= 0) & self.banded[pair] & (tasks[:, IA] == 0)
+                & (tasks[:, JA] == 0) & (tasks[:, IB] == self.n[pair])
+                & (tasks[:, JB] == self.m[pair]))
+
+    def certified(self, pair, distance) -> bool:
+        """The exact Ukkonen in-band certificate of a banded pair whose
+        global edit distance came out as `distance`."""
+        return _band.ukkonen_ok(int(self.n[pair]), int(self.m[pair]),
+                                int(self.K[pair]), int(self.gdmin[pair]),
+                                int(distance))
+
+
 def align_steps(pairs, *, interpret=None, band_overrides=None, hits=None,
                 in_flight=None):
     """pairs: [(q_codes int32 np, t_codes int32 np)] -> [ops np | None],
@@ -548,59 +599,38 @@ def align_steps(pairs, *, interpret=None, band_overrides=None, hits=None,
 
     in_flight: the `_InFlight` set this call's launches join while they
     are out (`_Launch`); cohorts that share the device share one.
+
+    The host's bookkeeping runs a launch at a time over a table of tasks
+    (`_pack_launch`, `_select`, `_collect_base`, `_assemble`: one native
+    call each), never a task at a time.
     """
     if interpret is None:
         interpret = _interpret()
     if in_flight is None:
         in_flight = _InFlight()
-    results = [None] * len(pairs)
-    segments = {}   # pair index -> list of (ia, ops array)
-    bands = {}
-    verify = {}     # pair index -> (n, m, K, gdmin) for banded pairs
-    active = []
-    for idx, (q, t) in enumerate(pairs):
-        n, m = len(q), len(t)
-        K = band_for(n, m)
-        if K == 0 or n == 0 or m == 0 or (n + 1) // 2 > ROW_BUCKETS[-1]:
-            continue
-        kb = band_overrides.get(idx) if band_overrides else None
-        if kb is not None and kb < K:
-            K = int(kb)
-        else:
-            kb = None
-        gdmin = int(np.minimum(0, m - n) - (K - 1 - abs(m - n)) // 2)
-        bands[idx] = (K, gdmin)
-        if kb is not None:
-            verify[idx] = (n, m, K, gdmin)
-        segments[idx] = []
-        active.append(_Task(idx, 0, n, 0, m))
+    run = _Run(pairs, band_overrides, interpret, in_flight)
+    active = np.flatnonzero(run.K).astype(np.int32)
+    tasks = np.zeros((len(active), native.TASK_COLS), np.int32)
+    tasks[:, PAIR], tasks[:, IB], tasks[:, JB] = (
+        active, run.n[active], run.m[active])
 
-    failed = set()
     while True:
-        big = [t for t in active if (t.ib - t.ia) > BASE_ROWS
-               and t.pair not in failed]
-        if not big:
+        small = tasks[:, IB] - tasks[:, IA] <= BASE_ROWS
+        big = tasks[~small & ~run.failed[tasks[:, PAIR]]]
+        if not len(big):
             break
-        active = [t for t in active if (t.ib - t.ia) <= BASE_ROWS]
-        active.extend((yield from _split_round(
-            pairs, big, bands, failed, interpret, verify, in_flight)))
+        tasks = np.concatenate(
+            [tasks[small], (yield from _split_round(run, big))])
 
     # base cases
-    base = [t for t in active if t.pair not in failed]
-    yield from _solve_base(pairs, base, bands, segments, failed, interpret,
-                           verify, in_flight)
+    yield from _solve_base(run, tasks[~run.failed[tasks[:, PAIR]]])
 
-    with obs.span("align.traceback", cat="launch", pairs=len(segments)):
-        for idx, segs in segments.items():
-            if idx in failed:
-                continue
-            segs.sort(key=lambda s: s[0])
-            results[idx] = np.concatenate([s[1] for s in segs]) if segs \
-                else np.zeros(0, np.int32)
-    if hits is not None and verify:
+    with obs.span("align.traceback", cat="launch", pairs=len(active)):
+        results = _assemble(run)
+    if hits is not None:
         # any banded-pair failure is a band hit: a verified-clean banded
         # pair cannot fail mid-recursion (certificate covers co-optima)
-        hits.update(idx for idx in failed if idx in verify)
+        hits.update(np.flatnonzero(run.failed & run.banded).tolist())
     return results
 
 
@@ -627,9 +657,10 @@ def _pow2(n):
     return b
 
 
-def _task_arrays(pairs, slots, bands, rcap, K, backward):
-    """Pack one launch's slots (a task, or None for a pad row) into the
-    edge kernel's arrays.  The staged target window is clipped to the
+def _pack_launch(run, tasks, rcap, K, backward):
+    """Pack one launch's slots (`tasks`: a row a slot, pair -1 for a pad
+    row) into the edge kernel's arrays, in one native call
+    (rt_hirschberg.cpp).  The staged target window is clipped to the
     half's band-reachable columns (j <= ib + gdmin + K going forward,
     j >= ia + gdmin going backward) so it fits rcap + K — the full task
     span can be up to 2*rcap + K.
@@ -642,52 +673,28 @@ def _task_arrays(pairs, slots, bands, rcap, K, backward):
     step k reads q[R - 1 - k] at index k in every task; the codes go
     out packed PACK to a word.  A pad row has R = 0: it costs its program
     nothing and never sets a group's trip count."""
-    B = len(slots)
-    TCAP = rcap + K
-    scal = np.zeros((B, 4), np.int32)
-    qs = np.zeros((B, rcap), np.int32)
-    ts = np.full((B, TCAP), 255, np.int32)
-    for bi, t in enumerate(slots):
-        if t is None:
-            continue
-        q, tt = pairs[t.pair]
-        _, gdmin = bands[t.pair]
-        R = t.ib - t.ia
-        if backward:
-            j_lo = max(t.ja, t.ia + gdmin)
-            j_hi = t.jb
-        else:
-            j_lo = t.ja
-            j_hi = min(t.jb, t.ib + gdmin + K)
-        S = j_hi - j_lo
-        assert 0 <= S <= TCAP, (S, TCAP)
-        dmin = gdmin + t.ia - j_lo
-        scal[bi] = (R, S, dmin, 0)
-        qrow = q[t.ia:t.ib]
-        qs[bi, :R] = qrow[::-1] if backward else qrow
-        shift = dmin + (R - 1 - rcap if backward else 0)
-        lo, hi = max(0, -shift), min(TCAP, S - shift)
-        if hi > lo:
-            ts[bi, lo:hi] = tt[j_lo + lo + shift:j_lo + hi + shift]
-    qs = pack_bases(qs, width=max(128, _round_up(rcap // PACK, 128)))
-    return scal, qs, ts
+    return native.hirschberg_pack(
+        run.table, tasks, rcap, K, backward,
+        max(128, _round_up(rcap // PACK, 128)))
 
 
-def _deal_programs(tasks, B):
-    """The `B` slots of one launch: `tasks` (ordered by R, so a
-    program's GROUP tasks are of a length) then pad slots (None; R = 0,
-    they ride in the last program).  Over a mesh every shard takes
-    B / shards consecutive slots, so the ordered programs are dealt
-    round the shards: no device gets all the long ones."""
-    slots = tasks + [None] * (B - len(tasks))
+def _deal_programs(group, B):
+    """The `B` slots of one launch as a task table: `group` (ordered by
+    R, so a program's GROUP tasks are of a length) then pad slots (pair
+    -1; R = 0, they ride in the last program).  Over a mesh every shard
+    takes B / shards consecutive slots, so the ordered programs are
+    dealt round the shards: no device gets all the long ones."""
+    slots = np.zeros((B, native.TASK_COLS), np.int32)
+    slots[:len(group)] = group
+    slots[len(group):, PAIR] = -1
     shards = _dispatch_shards(B)
     if shards > 1:
         deal = np.arange(B).reshape(-1, shards, min(GROUP, B // shards))
-        slots = [slots[i] for i in deal.transpose(1, 0, 2).ravel()]
+        slots = slots[deal.transpose(1, 0, 2).ravel()]
     return slots
 
 
-def _launch(in_flight, kernel, call, args, n_real, **geom):
+def _launch(in_flight, kernel, call, args, n_real, n_single=0, **geom):
     """Dispatch one kernel launch and start its outputs' copy back; the
     `_Launch` it returns is waited for later, so that the host's next
     work runs under this kernel.  ``align.dispatch`` is the jitted call
@@ -706,9 +713,13 @@ def _launch(in_flight, kernel, call, args, n_real, **geom):
     program, idle sublanes); how well the lock-step programs engage —
     ``align.lockstep.rows.real`` the DP rows the tasks asked for,
     ``.slots`` the sublane-rows their programs ran (GROUP x each
-    program's largest R); and whether the launch found the device fed:
+    program's largest R); whether the launch found the device fed:
     ``align.queue.behind`` when an earlier launch is still out, else
-    ``align.queue.empty`` (the device idles until this one arrives)."""
+    ``align.queue.empty`` (the device idles until this one arrives);
+    and how the host handles its tasks: ``align.host.tasks.batched``
+    those whose pack, select or collect is a share of one per-launch
+    call, ``.single`` the `n_single` that also get a step of their own
+    (a banded pair's root: the Ukkonen certificate)."""
     B = len(args[0])
     shards = _dispatch_shards(B)
     per_shard = B // shards
@@ -742,61 +753,66 @@ def _launch(in_flight, kernel, call, args, n_real, **geom):
               else "align.launches.edge")
     obs.count("align.tasks.real", n_real)
     obs.count("align.tasks.pad", B - n_real)
+    obs.count("align.host.tasks.batched", n_real - n_single)
+    obs.count("align.host.tasks.single", n_single)
     return _Launch(in_flight, outs, span_args)
 
 
-def _half(t, backward):
-    """The edge task of one half of `t`: forward over [ia, imid],
-    backward over [imid, ib]."""
-    imid = (t.ia + t.ib) // 2
-    return _Task(t.pair, imid if backward else t.ia,
-                 t.ib if backward else imid, t.ja, t.jb)
+def _buckets(order, *keys):
+    """Cut `order` (task indices sorted by `keys`) where a key changes:
+    [(the bucket's key values, its indices)], in `order`'s order."""
+    if not len(order):
+        return []
+    cols = np.stack([k[order] for k in keys], axis=1)
+    cuts = (np.flatnonzero((cols[1:] != cols[:-1]).any(axis=1)) + 1).tolist()
+    return [(tuple(cols[a].tolist()), order[a:b])
+            for a, b in zip([0] + cuts, cuts + [len(order)])]
 
 
-def _split_round(pairs, tasks, bands, failed, interpret, verify, in_flight):
+def _split_round(run, tasks):
     """One Hirschberg round: split every oversized task at its midpoint.
     Every bucket's forward and backward launch goes out before the first
     wait (``align.round``; a backward pack runs under its forward
     kernel), then one yield, then wait and select bucket by bucket, each
-    under the launches of the buckets behind it."""
-    by_bucket = {}
-    for t in tasks:
-        K = bands[t.pair][0]
-        R = t.ib - t.ia
-        half = (R + 1) // 2
-        rcap = next(rb for rb in ROW_BUCKETS if half <= rb)
-        by_bucket.setdefault((rcap, K), []).append(t)
+    under the launches of the buckets behind it.  Returns the halves, a
+    task table."""
+    R = tasks[:, IB] - tasks[:, IA]
+    Ks = run.K[tasks[:, PAIR]]
+    rcaps = _ROW_BUCKETS[np.searchsorted(_ROW_BUCKETS, (R + 1) // 2)]
+    # a launch is a (rcap, K) bucket; a program's eight tasks run to its
+    # largest R, so each is ordered by R before it is cut into programs
+    # (pad rows, R = 0, ride in the last one)
+    buckets = _buckets(np.lexsort((R, Ks, rcaps)), rcaps, Ks)
 
     issued = []
     out_now = []    # the round's launches, until each is waited for
     out = []
     try:
         with obs.span("align.round", cat="launch", tasks=len(tasks),
-                      buckets=len(by_bucket)):
-            for (rcap, K), group in sorted(by_bucket.items()):
+                      buckets=len(buckets)):
+            for (rcap, K), group in buckets:
                 # pad the batch dim to a power of two (at least one
                 # program of GROUP tasks) so each (rcap, K) bucket
                 # compiles a handful of kernel variants, not one per
                 # group size
                 B = max(GROUP, _pow2(len(group)))
                 geom = dict(rcap=rcap, K=K)
-                # a program's eight tasks run to its largest R: order the
-                # launch by R before it is cut into programs (pad rows,
-                # R = 0, ride in the last one)
-                group.sort(key=lambda t: t.ib - t.ia)
-                slots = _deal_programs(group, B)
+                slots = _deal_programs(tasks[group], B)
+                imid = (slots[:, IA] + slots[:, IB]) // 2
+                n_single = int(run.roots(slots).sum())
                 launches = []
                 for backward in (False, True):
                     kernel = "edge_bwd" if backward else "edge_fwd"
                     with obs.span("align.pack", cat="launch", kernel=kernel,
                                   B=B, **geom):
-                        args = _task_arrays(
-                            pairs, [t and _half(t, backward) for t in slots],
-                            bands, rcap, K, backward)
+                        # forward over [ia, imid], backward over [imid, ib]
+                        half = slots.copy()
+                        half[:, IA if backward else IB] = imid
+                        args = _pack_launch(run, half, rcap, K, backward)
                     launches.append(_launch(
-                        in_flight, kernel,
-                        _build_edge_kernel(rcap, K, backward, interpret),
-                        args, len(group), **geom))
+                        run.in_flight, kernel,
+                        _build_edge_kernel(rcap, K, backward, run.interpret),
+                        args, len(group), n_single, **geom))
                 issued.append((geom, len(group), slots, launches))
                 out_now.extend(launches)
         yield out_now
@@ -804,50 +820,44 @@ def _split_round(pairs, tasks, bands, failed, interpret, verify, in_flight):
             (F,), (Bv,) = fwd.wait(), bwd.wait()
             with obs.span("align.select", cat="launch", tasks=n_tasks,
                           **geom):
-                _select(slots, F, Bv, bands, verify, failed, out)
+                out.append(_select(run, slots, F, Bv))
     finally:
         # launches an exception left out leave the set with this round
-        in_flight.difference_update(out_now)
-    return out
+        run.in_flight.difference_update(out_now)
+    return np.concatenate(out)
 
 
-def _select(slots, F, Bv, bands, verify, failed, out):
+def _select(run, slots, F, Bv):
     """Pick each task's crossing column at its midpoint row from the
-    forward and backward edge rows; its two halves go to `out`."""
-    for gi, t in enumerate(slots):
-        if t is None:
-            continue
-        imid = (t.ia + t.ib) // 2
-        K_, gdmin = bands[t.pair]
-        # Both midpoint rows map lane o to absolute column j = imid +
-        # gdmin + o (independent of each frame's clipped origin);
-        # overlay onto the task's column range rel. ja.
-        jmid = imid + gdmin - t.ja + np.arange(K_)
-        span = t.jb - t.ja
-        fv = np.full(span + 1, INF, np.int64)
-        bv = np.full(span + 1, INF, np.int64)
-        m = (jmid >= 0) & (jmid <= span)
-        fv[jmid[m]] = F[gi][m]
-        bv[jmid[m]] = Bv[gi][m]
-        tot = fv + bv
-        jstar = int(np.argmin(tot))
-        if tot[jstar] >= INF:
-            failed.add(t.pair)
-            continue
-        v = verify.get(t.pair) if verify else None
-        if (v is not None and t.ia == 0 and t.ib == v[0]
-                and t.ja == 0 and t.jb == v[1]):
-            # root task of a banded pair: tot[jstar] IS the global edit
-            # distance (every path crosses the midpoint row), so check
-            # the exact Ukkonen certificate here and abort the whole
-            # pair before recursing on an unproven band
-            if not _band.ukkonen_ok(v[0], v[1], v[2], v[3],
-                                    int(tot[jstar])):
-                failed.add(t.pair)
-                continue
-        jabs = t.ja + jstar
-        out.append(_Task(t.pair, t.ia, imid, t.ja, jabs))
-        out.append(_Task(t.pair, imid, t.ib, jabs, t.jb))
+    forward and backward edge rows (F, Bv: a row a slot), in one native
+    call; returns its two halves, a task table in slot order.
+
+    Both midpoint rows map lane o to absolute column j = imid + gdmin +
+    o (independent of each frame's clipped origin), so the task's
+    columns [ja, jb] are a range of lanes, the lane order is the column
+    order and the first minimal lane is the first minimal column.  A
+    task with no finite lane fails its pair."""
+    rows = np.flatnonzero(slots[:, PAIR] >= 0).astype(np.int32)
+    tasks = slots[rows]
+    pair = tasks[:, PAIR]
+    imid = (tasks[:, IA] + tasks[:, IB]) // 2
+    lane0 = imid + run.gdmin[pair]
+    lane, tot = native.hirschberg_select(
+        np.ascontiguousarray(F), np.ascontiguousarray(Bv), rows,
+        tasks[:, JA] - lane0, tasks[:, JB] - lane0)
+    ok = tot < INF
+    # root task of a banded pair: `tot` IS the global edit distance
+    # (every path crosses the midpoint row), so check the exact Ukkonen
+    # certificate here and abort the whole pair before recursing on an
+    # unproven band
+    for i in np.flatnonzero(run.roots(tasks) & ok):
+        ok[i] = run.certified(pair[i], tot[i])
+    run.failed[pair[~ok]] = True
+    tasks, imid, jabs = tasks[ok], imid[ok], (lane0 + lane)[ok]
+    halves = np.repeat(tasks[:, None, :], 2, axis=1)
+    halves[:, 0, IB] = halves[:, 1, IA] = imid
+    halves[:, 0, JB] = halves[:, 1, JA] = jabs
+    return halves.reshape(-1, native.TASK_COLS)
 
 
 # Base launches a cohort may have out at once.  A launch holds ~2 MB on
@@ -859,32 +869,29 @@ def _select(slots, F, Bv, bands, verify, failed, out):
 BASE_AHEAD = 8
 
 
-def _solve_base(pairs, tasks, bands, segments, failed, interpret, verify,
-                in_flight):
+def _solve_base(run, tasks):
     """The base cases, every launch independent of every other: up to
     BASE_AHEAD go out before the first wait, then one is waited for and
     traced back (under the launches behind it) and the next one packed
     and dispatched, with a yield before each wait."""
-    by_bucket = {}
-    for t in tasks:
-        K = bands[t.pair][0]
-        by_bucket.setdefault(K, []).append(t)
-    chunks = []
-    for K, group in sorted(by_bucket.items()):
-        group.sort(key=lambda t: t.ib - t.ia)   # like rows share a program
-        chunks.extend((K, group[off:off + 64])
-                      for off in range(0, len(group), 64))
+    R = tasks[:, IB] - tasks[:, IA]
+    Ks = run.K[tasks[:, PAIR]]
+    # ordered by R: like rows share a program
+    chunks = [(K, group[off:off + 64])
+              for (K,), group in _buckets(np.lexsort((R, Ks)), Ks)
+              for off in range(0, len(group), 64)]
 
     def issue(K, chunk):
-        kern, _, _, _ = _build_base_kernel(K, interpret)
+        kern, _, _, _ = _build_base_kernel(K, run.interpret)
         B = max(GROUP, _pow2(len(chunk)))
         geom = dict(rcap=BASE_ROWS, K=K)
         with obs.span("align.pack", cat="launch", kernel="base", B=B,
                       **geom):
-            slots = _deal_programs(chunk, B)
-            args = _task_arrays(pairs, slots, bands, BASE_ROWS, K, False)
-        return slots, len(chunk), _launch(in_flight, "base", kern, args,
-                                          len(chunk), **geom)
+            slots = _deal_programs(tasks[chunk], B)
+            args = _pack_launch(run, slots, BASE_ROWS, K, False)
+        n_single = int(run.roots(slots).sum())
+        return slots, len(chunk), _launch(run.in_flight, "base", kern, args,
+                                          len(chunk), n_single, **geom)
 
     todo = iter(chunks)
     flying = collections.deque(
@@ -896,35 +903,62 @@ def _solve_base(pairs, tasks, bands, segments, failed, interpret, verify,
             outs = launch.wait()
             with obs.span("align.traceback", cat="launch", tasks=n_tasks,
                           K=launch.span_args["K"]):
-                _collect_base(slots, outs, segments, verify, failed)
+                _collect_base(run, slots, outs)
             flying.extend(issue(*c) for c in itertools.islice(todo, 1))
     finally:
-        in_flight.difference_update(launch for *_, launch in flying)
+        run.in_flight.difference_update(launch for *_, launch in flying)
 
 
-def _collect_base(slots, outs, segments, verify, failed):
-    """A base launch's op codes, reversed into each pair's segments."""
+def _collect_base(run, slots, outs):
+    """A base launch's op codes, reversed (the walk runs from the end)
+    and laid back to back in slot order by one native call; `run.segs`
+    keeps them with each segment's pair, first query row and length."""
     ops, cnt, ok, dist = outs
-    for bi, t in enumerate(slots):
-        if t is None:
-            continue
-        v = verify.get(t.pair) if verify else None
-        if (v is not None and t.ia == 0 and t.ib == v[0]
-                and t.ja == 0 and t.jb == v[1]):
-            # base-case-only banded pair: the kernel's terminal distance
-            # carries the exact Ukkonen certificate
-            if (not ok[bi] or not _band.ukkonen_ok(
-                    v[0], v[1], v[2], v[3], int(dist[bi]))):
-                failed.add(t.pair)
-                continue
-        if not ok[bi]:
-            failed.add(t.pair)
-            continue
-        seg = ops[bi, :cnt[bi]][::-1].astype(np.int32)
-        segments[t.pair].append((t.ia, seg))
+    rows = np.flatnonzero(slots[:, PAIR] >= 0)
+    good = ok[rows] != 0
+    # base-case-only banded pair: the kernel's terminal distance carries
+    # the exact Ukkonen certificate
+    for i in np.flatnonzero(run.roots(slots[rows]) & good):
+        good[i] = run.certified(slots[rows[i], PAIR], dist[rows[i]])
+    run.failed[slots[rows[~good], PAIR]] = True
+    rows = rows[good]
+    ops = np.ascontiguousarray(ops, np.int32)
+    cnt = np.ascontiguousarray(cnt[rows], np.int32)
+    if len(cnt) and not 0 <= cnt.min() <= cnt.max() <= ops.shape[1]:
+        raise ValueError("base kernel: op count outside its row")
+    codes = native.hirschberg_gather(
+        ops.ctypes.data + rows * ops.strides[0], cnt, True)
+    run.segs.append((codes, slots[rows, PAIR], slots[rows, IA], cnt))
 
 
-from .align import ops_to_cigar  # same 0=M/1=I/2=D convention
+def _assemble(run):
+    """Each pair's forward op codes from the base launches' segments,
+    ordered by first query row and copied to one array by one native
+    call: [ops view | None] per pair (None: not aligned here, or
+    failed)."""
+    results = [None] * len(run.K)
+    served = np.flatnonzero((run.K > 0) & ~run.failed)
+    if not len(served):
+        return results
+    assert run.segs, "a pair that did not fail has base segments"
+    codes = np.concatenate([s[0] for s in run.segs])
+    pair, ia, cnt = (np.concatenate([s[c] for s in run.segs])
+                     for c in (1, 2, 3))
+    starts = np.cumsum(cnt, dtype=np.int64) - cnt
+    keep = np.flatnonzero(~run.failed[pair])
+    keep = keep[np.lexsort((ia[keep], pair[keep]))]
+    cnt = np.ascontiguousarray(cnt[keep])
+    flat = native.hirschberg_gather(
+        codes.ctypes.data + codes.itemsize * starts[keep], cnt, False)
+    # a pair's op codes end where its last segment ends
+    end_of = np.zeros(len(run.K) + 1, np.int64)
+    end_of[1:] = np.cumsum(np.bincount(pair[keep], cnt, len(run.K)))
+    for idx in served.tolist():
+        results[idx] = flat[end_of[idx]:end_of[idx + 1]]
+    return results
+
+
+from .align import ops_to_cigars  # same 0=M/1=I/2=D convention
 
 
 def cohort_size(default: int = 64) -> int:
@@ -1113,6 +1147,9 @@ class _HirschbergOps:
     def install(self, ctx, kind, sub, results):
         from ..resilience import faults
 
+        # one run-length pass over the cohort's op codes
+        cigars = iter(ops_to_cigars(
+            [ops for ops in results if isinstance(ops, np.ndarray)]))
         for job, ops in zip(sub, results):
             if isinstance(ops, _band.Hit):
                 # banded verify failed: advance this job's widening
@@ -1130,7 +1167,7 @@ class _HirschbergOps:
             if st is not None:
                 st.pending = False
             faults.check("align.install", (job,))
-            self.pipeline.set_job_cigar(job, ops_to_cigar(ops))
+            self.pipeline.set_job_cigar(job, next(cigars))
             self._advance_others(None)
             self.state["served"] += 1
             if self.stats is not None:

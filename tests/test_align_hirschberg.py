@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from racon_tpu import native
-from racon_tpu.ops import align_pallas
+from racon_tpu.ops import align, align_pallas
 from racon_tpu.ops.encoding import encode
 from tests.test_align import mutate
 
@@ -266,7 +266,7 @@ def test_cigar_roundtrip():
     q = _rand(rng, 300)
     t = mutate(q, 0.1, rng)
     ops = _align_one(q, t)
-    cigar = align_pallas.ops_to_cigar(ops)
+    cigar = align.ops_to_cigar(ops)
     qc = tc = 0
     num = ""
     for ch in cigar:
@@ -311,6 +311,25 @@ def test_hirschberg_fuzz_exact(seed):
 
 
 # -- eight tasks per grid program (lock-step groups) -----------------------
+
+def _run_state(pairs, K=None, gdmin=None):
+    """The per-pair state of an `align_steps` call over `pairs`, with the
+    band and its origin forced where a test fixes them by hand."""
+    state = align_pallas._Run(pairs, None, True, align_pallas._InFlight())
+    if K is not None:
+        state.K[:] = K
+    if gdmin is not None:
+        state.table[:, 4] = state.gdmin[:] = gdmin
+    return state
+
+
+def _slots(tasks, B):
+    """A launch's task table: `tasks` then pad slots (pair -1)."""
+    slots = np.zeros((B, 5), np.int32)
+    slots[:len(tasks)] = tasks
+    slots[len(tasks):, 0] = -1
+    return slots
+
 
 def _enc(q: bytes, t: bytes):
     return (encode(np.frombuffer(q, np.uint8)).astype(np.int32),
@@ -398,21 +417,21 @@ def test_one_row_task_beside_a_full_one(backward):
     rcap, K = 512, 256
     q = _rand(rng, rcap)
     pairs = [_enc(q, mutate(q, 0.05, rng)), _enc(b"A", b"AC")]
-    bands = {0: (K, -100), 1: (K, -100)}
-    tasks = [align_pallas._Task(0, 0, rcap, 0, len(pairs[0][1])),
-             align_pallas._Task(1, 0, 1, 0, 2)]
+    state = _run_state(pairs, K=K, gdmin=-100)
+    tasks = np.array([[0, 0, rcap, 0, len(pairs[0][1])], [1, 0, 1, 0, 2]],
+                     np.int32)
     kern = align_pallas._build_edge_kernel(rcap, K, backward, True)
 
-    def run(slots):
-        args = align_pallas._task_arrays(pairs, slots, bands, rcap, K,
+    def run(group):
+        args = align_pallas._pack_launch(state, _slots(group, 8), rcap, K,
                                          backward)
-        return np.asarray(kern(len(slots))(*args))
+        return np.asarray(kern(8)(*args))
 
-    both = run(tasks + [None] * 6)
+    both = run(tasks)
     assert (both[0] < align_pallas.INF).any()
     assert (both[1] < align_pallas.INF).any()
-    for g, t in enumerate(tasks):
-        np.testing.assert_array_equal(both[g], run([t] + [None] * 7)[0])
+    for g in range(2):
+        np.testing.assert_array_equal(both[g], run(tasks[g:g + 1])[0])
     assert (both[2:] >= 0).all()        # idle sublanes: any value, no fault
 
 
@@ -564,7 +583,7 @@ def _six_pairs():
     for _ in range(6):
         q = _rand(rng, rng.randrange(560, 640))
         pairs.append((q, mutate(q, 0.05, rng)))
-    cigars = [align_pallas.ops_to_cigar(r) for r in align_pallas.align_pairs(
+    cigars = [align.ops_to_cigar(r) for r in align_pallas.align_pairs(
         [_enc(q, t) for q, t in pairs], interpret=True)]
     return pairs, cigars
 
@@ -724,3 +743,308 @@ def test_cohort_steps_on_anothers_time_only_when_it_would_not_block():
     ops = align_pallas._HirschbergOps(None, {}, None, None, {"served": 0})
     with pytest.raises(RuntimeError, match="boom"):
         ops.unpack(None, "hirschberg", cohort)
+
+
+# -- the host side works a launch at a time: parity with the per-task loops --
+
+from tests import hirschberg_oracle as oracle  # noqa: E402
+
+
+def _as_tasks(slots):
+    """A launch's task table as the oracle's slot list."""
+    return [None if r[0] < 0 else oracle.Task(*r) for r in slots.tolist()]
+
+
+def _bands(state):
+    return {p: (int(state.K[p]), int(state.gdmin[p]))
+            for p in range(len(state.K))}
+
+
+@functools.lru_cache(maxsize=1)
+def _pack_pool():
+    """Three pairs of one band bucket (K 256) and tasks of every kind a
+    launch carries: roots (their halves' target windows are clipped to
+    rcap + K on both sides), deeper tasks off the diagonal, a one-row
+    task, and tasks against an empty target span (S = 0) at either end."""
+    rng = random.Random(77)
+    pairs = []
+    for n in (1000, 940, 700):
+        q = _rand(rng, n)
+        pairs.append(_enc(q, mutate(q, 0.06, rng) + _rand(rng, 40)))
+    m = [len(t) for _, t in pairs]
+    tasks = np.array([
+        [0, 0, 1000, 0, m[0]], [1, 0, 940, 0, m[1]], [2, 0, 700, 0, m[2]],
+        [0, 0, 500, 0, 470], [0, 500, 1000, 470, m[0]],
+        [1, 235, 470, 250, 520], [2, 350, 525, 330, 560],
+        [2, 10, 11, 12, 14],
+        [0, 100, 160, 130, 130], [1, 0, 40, 0, 0], [2, 600, 700, m[2], m[2]],
+    ], np.int32)
+    return pairs, tasks
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+@pytest.mark.parametrize("case", ["halves", "whole-with-pads", "one-task",
+                                  "pads-only"])
+def test_launch_pack_equals_the_per_slot_loop(case, backward):
+    """`_pack_launch` (one native call a launch) against `_task_arrays`
+    as it was (a Python loop over the slots): scal, qs and ts are equal
+    bit for bit, dtype and shape included — forward and backward, pad
+    slots, target windows clipped to rcap + K, S = 0."""
+    pairs, tasks = _pack_pool()
+    state = _run_state(pairs)
+    assert set(state.K.tolist()) == {256}
+    K = 256
+    if case == "halves":
+        # what `_split_round` sends: each task's forward or backward half
+        rcap, slots = 512, _slots(tasks, 16)
+        slots[:len(tasks), 1 if backward else 2] = \
+            (tasks[:, 1] + tasks[:, 2]) // 2
+    elif case == "whole-with-pads":
+        # what `_solve_base` sends, at the edge kernel's geometry: the
+        # tasks as they are (R up to rcap), five pad slots behind them
+        rcap, slots = 1024, _slots(tasks, 16)
+    elif case == "one-task":
+        rcap, slots = 512, _slots(tasks[7:8], 8)
+    else:
+        rcap, slots = 512, _slots(tasks[:0], 8)
+    got = align_pallas._pack_launch(state, slots, rcap, K, backward)
+    want = oracle.task_arrays(pairs, _as_tasks(slots), _bands(state), rcap,
+                              K, backward)
+    for g, w, name in zip(got, want, ("scal", "qs", "ts")):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        np.testing.assert_array_equal(g, w, err_msg=name)
+    if case == "halves":
+        S = got[0][:len(tasks), 1]
+        assert (S == 0).any()
+        assert (S[:3] < tasks[:3, 4] - tasks[:3, 3]).all()     # clipped
+
+
+def test_launch_pack_refuses_a_task_outside_its_pair():
+    pairs, tasks = _pack_pool()
+    state = _run_state(pairs)
+    bad = tasks[:1].copy()
+    bad[0, 2] = 5000                       # ib past the query's end
+    with pytest.raises(ValueError, match="slot 0"):
+        align_pallas._pack_launch(state, _slots(bad, 8), 8192, 256, False)
+
+
+@pytest.mark.parametrize("shards", [1, 2, 4])
+@pytest.mark.parametrize("n_tasks,B", [(11, 16), (5, 8), (40, 64)])
+def test_slot_order_is_the_dealt_order(monkeypatch, shards, n_tasks, B):
+    """`_deal_programs` as an index over the task table against the list
+    it was: tasks in their order, pads last, the programs dealt round
+    the shards of a mesh (2 and 4) — so the lock-step fill and the mesh
+    pad counters read what they read.  The launch packed from it equals
+    the per-slot loop's over the dealt list."""
+    pairs, pool = _pack_pool()
+    tasks = pool[np.arange(n_tasks) % len(pool)]
+    monkeypatch.setattr(align_pallas, "_dispatch_shards", lambda b: shards)
+    slots = align_pallas._deal_programs(tasks, B)
+    want = oracle.deal_programs([oracle.Task(*r) for r in tasks.tolist()],
+                                B, shards)
+    assert [None if t is None else t.row() for t in want] == \
+        [None if r[0] < 0 else r for r in slots.tolist()]
+    state = _run_state(pairs)
+    got = align_pallas._pack_launch(state, slots, 1024, 256, False)
+    for g, w in zip(got, oracle.task_arrays(pairs, want, _bands(state),
+                                            1024, 256, False)):
+        np.testing.assert_array_equal(g, w)
+
+
+def _select_case(name):
+    """Crafted edge rows for one launch of K = 8 lanes: (state, slots, F,
+    Bv, verify).  Pair p is a 40 x 40 pair whose tasks sit wherever the
+    case needs them; lane o of a task is column imid + gdmin + o."""
+    INF = align_pallas.INF
+    K = 8
+    pairs = [_enc(b"A" * 40, b"A" * 40) for _ in range(4)]
+    state = _run_state(pairs, K=K, gdmin=-3)
+    verify = {}
+    F = np.full((8, K), 7, np.int32)
+    Bv = np.full((8, K), 9, np.int32)
+    if name == "ties":
+        # imid 10 -> columns 7..14, all inside [ja, jb]; the least total
+        # three times: the first of them wins, in every row
+        tasks = [[0, 0, 20, 0, 40], [1, 4, 16, 2, 30], [2, 9, 11, 7, 14]]
+        F[:3] = [[5, 4, 3, 9, 3, 3, 8, 9]] * 3
+        Bv[:3] = [[5, 4, 3, 9, 3, 3, 8, 9]] * 3
+    elif name == "span-cuts-the-lanes":
+        # [ja, jb] leaves the lanes out on the left (columns under ja
+        # hold the least totals and may not win), on the right, on both
+        # sides down to one column, and not at all
+        tasks = [[0, 0, 20, 10, 40], [1, 0, 20, 0, 9], [2, 0, 20, 11, 11],
+                 [3, 0, 20, 0, 40]]
+        F[:4] = [[0, 0, 0, 6, 5, 4, 3, 2]] * 4
+        Bv[:4] = [[0, 0, 0, 1, 1, 1, 1, 1]] * 4
+    elif name == "all-inf":
+        # no finite crossing: the pair fails, its neighbour with one
+        # finite lane does not; a row whose only finite lanes lie outside
+        # its span fails too
+        tasks = [[0, 0, 20, 0, 40], [1, 0, 20, 0, 40], [2, 0, 20, 12, 40]]
+        F[0], Bv[0] = INF, INF
+        F[1, :], Bv[1, :] = INF, 3
+        F[1, 4] = 2
+        F[2], Bv[2] = [1, 1, 1, 1, 1, INF, INF, INF], 1
+    elif name in ("banded-root-certified", "banded-root-refused"):
+        # pair 0 runs under a band override: its root task's total is the
+        # pair's edit distance and carries the Ukkonen certificate; the
+        # same task of pair 1 (no override) is selected like any other
+        state.banded[0] = True
+        verify[0] = (40, 40, K, -3)
+        tasks = [[0, 0, 40, 0, 40], [1, 0, 40, 0, 40], [0, 0, 20, 0, 20]]
+        d = 1 if name.endswith("certified") else 9
+        F[:3], Bv[:3] = d, 0
+    slots = _slots(np.array(tasks, np.int32), 8)
+    if name == "ties":                     # pads between the tasks too
+        slots = slots[[0, 7, 1, 6, 2, 5, 4, 3]]
+        F[:5:2], Bv[:5:2] = F[:3].copy(), Bv[:3].copy()
+    return state, slots, F, Bv, verify
+
+
+@pytest.mark.parametrize("name", ["ties", "span-cuts-the-lanes", "all-inf",
+                                  "banded-root-certified",
+                                  "banded-root-refused"])
+def test_select_equals_the_per_task_overlay(name):
+    """`_select` (one native call a launch) against the per-task overlay
+    it replaced: the same halves in the same order, the same pairs
+    failed — ties go to the first minimal column, lanes outside a task's
+    columns never win, a task with no finite crossing fails its pair, a
+    banded pair's root answers to the Ukkonen certificate."""
+    state, slots, F, Bv, verify = _select_case(name)
+    got = align_pallas._select(state, slots, F, Bv)
+    want, failed = [], set()
+    oracle.select(_as_tasks(slots), F, Bv, _bands(state), verify, failed,
+                  want)
+    assert got.dtype == np.int32
+    assert got.tolist() == [t.row() for t in want]
+    assert set(np.flatnonzero(state.failed).tolist()) == failed
+    n_failed = {"all-inf": 2, "banded-root-refused": 1}.get(name, 0)
+    assert len(failed) == n_failed
+    if name == "banded-root-certified":
+        # the certificate was asked for: a distance of 9 is refused by it
+        assert not align_pallas._band.ukkonen_ok(40, 40, 8, -3, 9)
+        assert len(want) == 6
+    if name == "span-cuts-the-lanes":
+        assert [r[4] for r in got.tolist()[::2]] == [14, 7, 11, 7]
+
+
+def _base_outs(rng, slots, OPS=384, not_ok=(), dist=None):
+    """A base launch's outputs as the kernel shapes them: op codes back
+    to front in the first cnt lanes, garbage behind them."""
+    B = len(slots)
+    ops = rng.integers(0, 3, (B, OPS)).astype(np.int32)
+    cnt = rng.integers(0, OPS + 1, B).astype(np.int32)
+    cnt[:3] = (0, 1, OPS)
+    ok = np.ones(B, np.int32)
+    ok[list(not_ok)] = 0
+    return ops, cnt, ok, (np.zeros(B, np.int32) if dist is None else dist)
+
+
+@pytest.mark.parametrize("banded", [False, True], ids=["flat", "banded"])
+def test_collect_equals_the_per_segment_loop(banded):
+    """`_collect_base` a launch and `_assemble` at the end (a native copy
+    each) against a slice, a reverse and a list append per segment, then
+    a sort and a concatenate per pair: the same op strings, int32, for
+    the same pairs; a task the kernel did not finish fails its pair, a
+    base-only banded pair answers to the certificate."""
+    rng = np.random.default_rng(8)
+    pairs = [_enc(b"A" * 300, b"A" * 300) for _ in range(5)]
+    state = _run_state(pairs, K=256, gdmin=-3)
+    verify = {}
+    # two launches; a pair's segments arrive out of order and across both
+    first = np.array([[0, 200, 300, 0, 0], [1, 0, 150, 0, 0],
+                      [2, 0, 300, 0, 300], [0, 0, 100, 0, 0],
+                      [3, 0, 150, 0, 0], [4, 0, 300, 0, 300]], np.int32)
+    second = np.array([[1, 150, 300, 0, 0], [0, 100, 200, 0, 0],
+                       [3, 150, 300, 0, 0]], np.int32)
+    dist = np.zeros(8, np.int32)
+    if banded:
+        # pairs 2 and 4 are whole in one base task under an override:
+        # 2's terminal distance is certified, 4's is not
+        state.banded[[2, 4]] = True
+        verify = {2: (300, 300, 256, -3), 4: (300, 300, 256, -3)}
+        dist[2], dist[6] = 5, 200
+    launches = [(_slots(first, 8)[[0, 1, 2, 6, 3, 4, 5, 7]],
+                 _base_outs(rng, range(8), dist=dist)),
+                (_slots(second, 8), _base_outs(rng, range(8), not_ok=[2]))]
+    segments, failed = {}, set()
+    for slots, outs in launches:
+        align_pallas._collect_base(state, slots, outs)
+        oracle.collect_base(_as_tasks(slots), outs, segments, verify, failed)
+    got = align_pallas._assemble(state)
+    want = oracle.assemble(segments, failed, len(pairs))
+    assert failed == ({3, 4} if banded else {3})
+    assert set(np.flatnonzero(state.failed).tolist()) == failed
+    for g, w in zip(got, want):
+        assert (g is None) == (w is None)
+        if w is not None:
+            assert g.dtype == w.dtype == np.int32
+            np.testing.assert_array_equal(g, w)
+    assert len(got[0]) > 0 and got[3] is None
+
+
+def _op_strings():
+    rng = np.random.default_rng(5)
+    runs = rng.integers(1, 12, 1500)
+    long = np.repeat(np.arange(1500) % 3, runs).astype(np.int32)
+    return {"empty": np.zeros(0, np.int32),
+            "one-run": np.full(8000, 2, np.int32),
+            "one-op": np.array([1], np.int32),
+            "1500-runs": long,
+            "uint8": (np.arange(40) // 7 % 3).astype(np.uint8)}
+
+
+@pytest.mark.parametrize("name", sorted(_op_strings()))
+def test_ops_to_cigar_equals_the_per_run_loop(name):
+    """The native run-length pass against the f-string a run it replaced,
+    alone and as one of a cohort's strings."""
+    strings = _op_strings()
+    ops = strings[name]
+    want = oracle.ops_to_cigar(ops)
+    assert align.ops_to_cigar(ops) == want
+    cohort = [strings[k] for k in sorted(strings)]
+    assert align.ops_to_cigars(cohort) == \
+        [oracle.ops_to_cigar(o) for o in cohort]
+    if name == "1500-runs":
+        assert sum(c in "MID" for c in want) == 1500
+    with pytest.raises(ValueError):
+        align.ops_to_cigar(np.array([0, 3], np.int32))
+
+
+def test_host_task_counters_say_what_ran_per_launch():
+    """``align.host.tasks.batched`` / ``.single``, once per launch beside
+    ``align.tasks.real``: every task's pack, select or collect is a
+    share of a per-launch call; a banded pair's root task also gets a
+    step of its own (the Ukkonen certificate), and is counted for it —
+    in the edge launches of its round, or in the base launch if the
+    pair is a base case whole."""
+    from racon_tpu import obs
+
+    pairs = _mixed()
+
+    def counters(**kwargs):
+        obs.reset()
+        obs.configure(metrics=True)
+        try:
+            res = align_pallas.align_pairs(pairs, interpret=True, **kwargs)
+            return res, obs.snapshot()["counters"]
+        finally:
+            obs.reset()
+
+    flat, c = counters()
+    assert c["align.host.tasks.single"] == 0
+    assert c["align.host.tasks.batched"] == c["align.tasks.real"] > 0
+    # pair 0 (1400 rows: its root is split, forward + backward launch)
+    # and pair 1 (200 rows: its root is a base task) under their own
+    # flat band as an override narrower than... the flat bucket itself
+    # is not narrower, so take half of it
+    K0 = align_pallas.band_for(len(pairs[0][0]), len(pairs[0][1]))
+    K1 = align_pallas.band_for(len(pairs[1][0]), len(pairs[1][1]))
+    hits = set()
+    banded, c = counters(band_overrides={0: K0 // 2, 1: K1 // 2}, hits=hits)
+    assert c["align.host.tasks.single"] == 2 + 1
+    assert c["align.host.tasks.single"] + c["align.host.tasks.batched"] \
+        == c["align.tasks.real"]
+    for i in (0, 1):
+        if i not in hits:               # certified: the flat kernel's ops
+            np.testing.assert_array_equal(banded[i], flat[i])

@@ -323,3 +323,96 @@ def test_bench_normalize_entry_malformed_partial_summaries():
     cm = {"profile": "cpu-host", "phases": {}, "ok": True}
     assert bench.normalize_entry(
         {"value": 0.01, "cost_model": cm})["cost_model"] == cm
+
+
+# ------------------------------------------- the journal's CIGAR contract
+
+def test_paf_job_journals_one_fsynced_cigar_record_a_pair(tmp_path,
+                                                          monkeypatch):
+    """The Hirschberg engine installs a cohort's CIGARs from one native
+    run-length pass; what the journal is given has not moved: one
+    `cigar` record a pair, each its own append with its own flush and
+    fsync, in install order (bucket by bucket, cohort by cohort, job by
+    job), holding the CIGAR the per-run loop gives for the same ops —
+    and a run resumed from it replays every pair and polishes the same
+    bytes."""
+    import random
+
+    from racon_tpu.ops import align_pallas
+    from racon_tpu.ops.encoding import encode
+    from racon_tpu.resilience import journal as journal_mod
+    from tests import hirschberg_oracle as oracle
+    from tests.test_align import mutate
+
+    rng = random.Random(17)
+    with open(tmp_path / "targets.fasta", "w") as tf, \
+            open(tmp_path / "reads.fasta", "w") as rf, \
+            open(tmp_path / "ovl.paf", "w") as of:
+        # two row buckets, the later one's jobs first in job order; the
+        # 300 bp pairs are base cases whole
+        for t, n in enumerate((1100, 300, 580)):
+            seq = bytes(rng.choice(b"ACGT") for _ in range(n))
+            tf.write(f">t{t}\n{seq.decode()}\n")
+            for i in range(4):
+                read = mutate(seq, 0.06, rng)
+                rf.write(f">t{t}r{i}\n{read.decode()}\n")
+                of.write(f"t{t}r{i}\t{len(read)}\t0\t{len(read)}\t+\tt{t}\t"
+                         f"{n}\t0\t{n}\t{n}\t{n}\t60\n")
+    paths = (str(tmp_path / "reads.fasta"), str(tmp_path / "ovl.paf"),
+             str(tmp_path / "targets.fasta"))
+    for k, v in {"RACON_TPU_PALLAS": "0", "RACON_TPU_BATCH_WINDOWS": "8",
+                 "RACON_TPU_DEVICE_ALIGNER": "hirschberg",
+                 "RACON_TPU_ALIGN_COHORT": "3"}.items():
+        monkeypatch.setenv(k, v)
+
+    events = []
+    real_append = journal_mod.Journal.append_cigar
+
+    def append_cigar(self, job, tier, cigar):
+        events.append(("cigar", job))
+        real_append(self, job, tier, cigar)
+
+    monkeypatch.setattr(journal_mod.Journal, "append_cigar", append_cigar)
+    monkeypatch.setattr(journal_mod.os, "fsync",
+                        lambda fd: events.append(("fsync",)))
+
+    jp = str(tmp_path / "run.journal")
+    p = racon_tpu.create_polisher(*paths, backend="tpu", journal_path=jp,
+                                  **_ARGS)
+    p.initialize()
+    first = p.polish(True)
+    assert p.report.as_dict()["phases"]["alignment"]["served"][
+        "hirschberg"] == 12
+
+    # what each pair's CIGAR is, from the same ops by the per-run loop
+    pipe = Pipeline(*paths, **_ARGS)
+    pipe.prepare()
+    want, bucket = {}, {}
+    for job in range(pipe.num_align_jobs()):
+        q, t = (encode(a).astype(np.int32) for a in pipe.align_job(job))
+        (ops,) = align_pallas.align_pairs([(q, t)], interpret=True)
+        want[job] = oracle.ops_to_cigar(ops)
+        half = (len(q) + 1) // 2
+        bucket[job] = (align_pallas.band_for(len(q), len(t)),
+                       next(rb for rb in align_pallas.ROW_BUCKETS
+                            if half <= rb))
+    order = sorted(want, key=lambda j: (bucket[j], j))
+    assert len(set(bucket.values())) == 2 and len(set(want.values())) > 6
+
+    with open(jp) as f:
+        records = [json.loads(line) for line in f]
+    cigars = [r for r in records if r["kind"] == "cigar"]
+    assert [r["i"] for r in cigars] == order
+    assert [r["cigar"] for r in cigars] == [want[j] for j in order]
+    assert all(r["tier"] == "hirschberg" for r in cigars)
+    # every record its own append, flushed and fsynced before the next
+    at = events.index(("cigar", order[0]))
+    assert events[at:at + 24] == [e for j in order
+                                  for e in (("cigar", j), ("fsync",))]
+
+    p2 = racon_tpu.create_polisher(*paths, backend="tpu", journal_path=jp,
+                                   resume_journal=True, **_ARGS)
+    p2.initialize()
+    assert p2.polish(True) == first
+    served = p2.report.as_dict()["phases"]["alignment"]["served"]
+    assert served["journal"] == 12 and not served.get("hirschberg")
