@@ -107,6 +107,12 @@ def load() -> ctypes.CDLL:
     lib.rt_pipeline_prepare_counts.restype = None
     lib.rt_pipeline_prepare_counts.argtypes = [ctypes.c_void_p, u64p]
 
+    lib.rt_pipeline_stage_marks.restype = ctypes.c_uint64
+    lib.rt_pipeline_stage_marks.argtypes = [ctypes.c_void_p, u64p,
+                                            ctypes.c_uint64]
+    lib.rt_steady_clock_ns.restype = ctypes.c_int64
+    lib.rt_steady_clock_ns.argtypes = []
+
     lib.rt_pipeline_num_align_jobs.restype = ctypes.c_uint64
     lib.rt_pipeline_num_align_jobs.argtypes = [ctypes.c_void_p]
 

@@ -180,6 +180,27 @@ void rt_pipeline_prepare_counts(void* handle, uint64_t* out) {
   out[2] = p.overlaps_kept();
 }
 
+// The stage marks of the last coarse call (prepare, build_windows,
+// initialize, stitch), five values a mark: stage id (rt::Stage), start
+// and end in steady_clock nanoseconds, items, bytes. Writes at most `cap`
+// marks and returns how many the call left, in one crossing.
+uint64_t rt_pipeline_stage_marks(void* handle, uint64_t* out, uint64_t cap) {
+  const auto& marks =
+      static_cast<PipelineHandle*>(handle)->pipeline->stage_marks();
+  for (uint64_t i = 0; i < marks.size() && i < cap; ++i) {
+    out[5 * i] = static_cast<uint64_t>(marks[i].stage);
+    out[5 * i + 1] = static_cast<uint64_t>(marks[i].t0_ns);
+    out[5 * i + 2] = static_cast<uint64_t>(marks[i].t1_ns);
+    out[5 * i + 3] = marks[i].items;
+    out[5 * i + 4] = marks[i].bytes;
+  }
+  return marks.size();
+}
+
+// The clock the marks are stamped with, for the test that holds it to
+// Python's time.monotonic_ns().
+int64_t rt_steady_clock_ns() { return Pipeline::steady_now_ns(); }
+
 uint64_t rt_pipeline_num_align_jobs(void* handle) {
   return static_cast<PipelineHandle*>(handle)->pipeline->num_align_jobs();
 }

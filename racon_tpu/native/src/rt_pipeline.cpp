@@ -66,6 +66,25 @@ Pipeline::Pipeline(const std::string& sequences_path,
   }
 }
 
+int64_t Pipeline::steady_now_ns() {
+  return std::chrono::duration_cast<std::chrono::nanoseconds>(
+             std::chrono::steady_clock::now().time_since_epoch())
+      .count();
+}
+
+void Pipeline::begin_marks() {
+  if (!marks_held_) {
+    marks_.clear();
+  }
+}
+
+void Pipeline::mark(Stage stage, int64_t* t0_ns, uint64_t items,
+                    uint64_t bytes) {
+  const int64_t now = steady_now_ns();
+  marks_.push_back({stage, *t0_ns, now, items, bytes});
+  *t0_ns = now;
+}
+
 void Pipeline::remove_invalid_overlaps(
     std::vector<std::unique_ptr<Overlap>>& overlaps, uint64_t begin,
     uint64_t end) {
@@ -105,6 +124,8 @@ void Pipeline::prepare() {
                  "initialized!\n");
     return;
   }
+  begin_marks();
+  int64_t stage_t0 = steady_now_ns();
 
   // Targets, all at once (parity: src/polisher.cpp:200-208).
   sequences_ = tparser_->parse(0);
@@ -121,6 +142,11 @@ void Pipeline::prepare() {
     name_to_id[sequences_[i]->name + "t"] = i;
     id_to_id[i << 1 | 1] = i;
   }
+  uint64_t targets_length = 0;
+  for (uint64_t i = 0; i < targets_size_; ++i) {
+    targets_length += sequences_[i]->data.size();
+  }
+  mark(Stage::kPrepareTargets, &stage_t0, targets_size_, targets_length);
 
   logger_.log("[racon_tpu::Pipeline::initialize] loaded target sequences");
   std::vector<bool> has_name(targets_size_, true);
@@ -164,6 +190,7 @@ void Pipeline::prepare() {
   has_name.resize(sequences_.size(), false);
   has_data.resize(sequences_.size(), false);
   has_reverse_data.resize(sequences_.size(), false);
+  mark(Stage::kPrepareReads, &stage_t0, read_ordinal, total_reads_length);
 
   logger_.log("[racon_tpu::Pipeline::initialize] loaded sequences");
   // Short reads get NGS windows (no trim), long reads TGS
@@ -229,6 +256,7 @@ void Pipeline::prepare() {
       has_data[o->q_id] = true;
     }
   }
+  mark(Stage::kPrepareOverlaps, &stage_t0, overlaps_parsed_, overlaps_kept_);
 
   // Per-sequence transmute (free unused fields, build reverse complements)
   // on the pool (parity: src/polisher.cpp:373-382).
@@ -246,6 +274,7 @@ void Pipeline::prepare() {
       f.get();
     }
   }
+  mark(Stage::kPrepareTransmute, &stage_t0, sequences_.size());
 
   logger_.log("[racon_tpu::Pipeline::initialize] loaded overlaps");
   // Collect alignment jobs (overlaps without a CIGAR).
@@ -296,6 +325,10 @@ void Pipeline::align_jobs_cpu() {
 }
 
 void Pipeline::build_windows() {
+  begin_marks();
+  int64_t stage_t0 = steady_now_ns();
+  const uint64_t num_overlaps = overlaps_.size();
+
   // Breaking-point walks on the pool (cheap CIGAR scans now that every
   // overlap has a CIGAR; parity: src/polisher.cpp:466-488).
   {
@@ -310,6 +343,7 @@ void Pipeline::build_windows() {
       f.get();
     }
   }
+  mark(Stage::kWindowsBreaks, &stage_t0, num_overlaps);
 
   // Create windows per target (parity: src/polisher.cpp:388-403).
   std::vector<uint64_t> id_to_first_window_id(targets_size_ + 1, 0);
@@ -329,6 +363,7 @@ void Pipeline::build_windows() {
   }
 
   targets_coverages_.assign(targets_size_, 0);
+  mark(Stage::kWindowsCreate, &stage_t0, windows_.size());
 
   // Distribute overlap pieces into windows (parity: src/polisher.cpp:407-461).
   for (auto& o : overlaps_) {
@@ -386,15 +421,31 @@ void Pipeline::build_windows() {
 
   done_.assign(windows_.size(), 0);
   polished_.assign(windows_.size(), 0);
+  uint64_t layers = 0;  // a window's first sequence is its backbone
+  for (const auto& w : windows_) {
+    layers += w->sequences.size() - 1;
+  }
+  mark(Stage::kWindowsLayers, &stage_t0, layers);
 
   logger_.log("[racon_tpu::Pipeline::initialize] transformed data into "
               "windows");
 }
 
 void Pipeline::initialize() {
-  prepare();
-  align_jobs_cpu();
-  build_windows();
+  begin_marks();
+  marks_held_ = true;
+  try {
+    prepare();
+    int64_t stage_t0 = steady_now_ns();
+    const uint64_t jobs = align_jobs_.size();
+    align_jobs_cpu();
+    mark(Stage::kInitializeAlign, &stage_t0, jobs);
+    build_windows();
+  } catch (...) {
+    marks_held_ = false;
+    throw;
+  }
+  marks_held_ = false;
 }
 
 bool Pipeline::consensus_cpu_one(size_t i) {
@@ -472,6 +523,9 @@ void Pipeline::stitch(bool drop_unpolished_sequences,
                  "consumed by a previous stitch!\n");
   }
   stitched_ = true;
+  begin_marks();
+  int64_t stage_t0 = steady_now_ns();
+  const size_t first_record = dst->size();
 
   std::string polished_data;
   uint32_t num_polished_windows = 0;
@@ -502,6 +556,12 @@ void Pipeline::stitch(bool drop_unpolished_sequences,
     }
     windows_[i].reset();
   }
+  uint64_t stitched_length = 0;
+  for (size_t i = first_record; i < dst->size(); ++i) {
+    stitched_length += (*dst)[i].second.size();
+  }
+  mark(Stage::kStitchJoin, &stage_t0, dst->size() - first_record,
+       stitched_length);
 }
 
 }  // namespace rt

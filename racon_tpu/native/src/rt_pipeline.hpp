@@ -46,6 +46,30 @@ struct PipelineParams {
   uint32_t num_threads = 1;
 };
 
+// One stage of a coarse call (prepare, build_windows, initialize,
+// stitch), stamped with std::chrono::steady_clock at the boundaries the
+// call already logs: CLOCK_MONOTONIC under libstdc++ on Linux, the clock
+// Python's time.monotonic_ns() reads, so the tracer lays the marks among
+// its spans without an offset. Always recorded (a dozen clock reads a
+// job, none inside a per-read, per-overlap or per-window loop).
+enum class Stage : uint32_t {
+  kPrepareTargets = 0,  // items targets, bytes their bases
+  kPrepareReads,        // items reads, bytes their bases
+  kPrepareOverlaps,     // items records parsed; the bytes slot: overlaps kept
+  kPrepareTransmute,    // items sequences
+  kInitializeAlign,     // items alignment jobs (the fused initialize only)
+  kWindowsBreaks,       // items overlaps
+  kWindowsCreate,       // items windows
+  kWindowsLayers,       // items layers added
+  kStitchJoin,          // items records, bytes their bases
+};
+
+struct StageMark {
+  Stage stage;
+  int64_t t0_ns, t1_ns;
+  uint64_t items, bytes;
+};
+
 class Pipeline {
  public:
   // Exits with a reference-compatible message on unsupported extensions or
@@ -108,12 +132,21 @@ class Pipeline {
   void stitch(bool drop_unpolished_sequences,
               std::vector<std::pair<std::string, std::string>>* dst);
 
+  // The stage marks of the last coarse call, in the order they ran.
+  const std::vector<StageMark>& stage_marks() const { return marks_; }
+  static int64_t steady_now_ns();
+
   const PipelineParams& params() const { return params_; }
   WindowType window_type() const { return window_type_; }
 
  private:
   void remove_invalid_overlaps(std::vector<std::unique_ptr<Overlap>>& overlaps,
                                uint64_t begin, uint64_t end);
+  // A coarse call starts its table afresh, unless initialize() holds it
+  // for the three calls it fuses; mark() closes the stage that began at
+  // *t0_ns now, and moves *t0_ns on to the next stage's start.
+  void begin_marks();
+  void mark(Stage stage, int64_t* t0_ns, uint64_t items, uint64_t bytes = 0);
 
   PipelineParams params_;
   std::unique_ptr<SequenceParser> sparser_, tparser_;
@@ -136,6 +169,8 @@ class Pipeline {
 
   std::vector<std::unique_ptr<PoaAligner>> aligners_;  // one per thread
   std::vector<std::future<void>> submitted_;  // consensus_cpu_submit's
+  std::vector<StageMark> marks_;
+  bool marks_held_ = false;  // inside initialize(): one table for all three
   Logger logger_;
   // Declared last: destroyed first, so an exception-abandoned task queue
   // drains (and its tasks' member references stay valid) before any other

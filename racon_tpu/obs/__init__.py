@@ -84,7 +84,8 @@ def reset() -> None:
 
 
 def configure(trace_path: Optional[str] = None,
-              metrics: Optional[bool] = None) -> None:
+              metrics: Optional[bool] = None,
+              epoch_ns: Optional[int] = None) -> None:
     """Arm for one run.  Explicit arguments (the CLI flags) win; ``None``
     falls back to the ``RACON_TPU_TRACE`` / ``RACON_TPU_METRICS`` knobs.
     Tracing implies metrics (the snapshot rides inside the trace file);
@@ -97,7 +98,11 @@ def configure(trace_path: Optional[str] = None,
     per ``run()``).  Arming a *different* path swaps in a fresh tracer,
     so a second in-process run can never append spans into the previous
     run's file — the scoped teardown (``release()``) plus this check is
-    the regression surface tests/test_obs.py pins."""
+    the regression surface tests/test_obs.py pins.
+
+    ``epoch_ns`` (a ``time.monotonic_ns()`` stamp) is where a fresh
+    tracer's ts=0 lies: the start of the state reset that ends in this
+    arming, so that the job's root span begins at 0."""
     global _tracer, _metrics, _trace_path
     if trace_path is None:
         trace_path = config.get_str(ENV_TRACE) or None
@@ -109,7 +114,7 @@ def configure(trace_path: Optional[str] = None,
         if _tracer is not None and _trace_path == trace_path:
             return
         _trace_path = trace_path
-        _tracer = Tracer()
+        _tracer = Tracer(t0_ns=epoch_ns)
         _metrics = Metrics()
         _tracer.role = _role
         ctx = context.current()
@@ -196,10 +201,32 @@ def event(name: str, **args) -> None:
 def add_complete(name: str, t0_ns: int, t1_ns: int, cat: str = "span",
                  **args) -> None:
     """Retroactive span from raw monotonic_ns stamps (kernel-cache miss
-    detection times the call first, then learns it was a compile)."""
+    detection times the call first, then learns it was a compile).  Its
+    parent is the caller's innermost open span unless ``parent_id=``
+    says otherwise (``Tracer.add_complete``)."""
     t = _tracer
     if t is not None:
         t.add_complete(name, t0_ns, t1_ns, cat=cat, **args)
+
+
+def begin(name: str, t0_ns: Optional[int] = None, root: bool = False,
+          **args) -> None:
+    """Open a span that another function ends (``Tracer.begin``): the
+    job's root span ``job`` and the two halves of its boundary,
+    ``job.open`` and ``job.close``, which reach from a polisher's
+    constructor into ``initialize()`` and from ``polish()`` into the
+    serve session."""
+    t = _tracer
+    if t is not None:
+        t.begin(name, t0_ns, root, **args)
+
+
+def end(name: str, **args) -> None:
+    """Close what ``begin`` opened under this name; a no-op when
+    disarmed or when no such span is open."""
+    t = _tracer
+    if t is not None:
+        t.end(name, **args)
 
 
 def count(name: str, n: int = 1) -> None:

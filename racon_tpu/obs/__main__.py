@@ -133,6 +133,35 @@ def span_quantiles(doc: dict) -> Dict[str, dict]:
     return out
 
 
+def span_self_times(doc: dict) -> Dict[str, dict]:
+    """Per span name: how many, their summed duration, and their summed
+    **self time**, a span's duration less that of its children on the
+    same thread (``parent`` names the span that was open when a span
+    began).  Self times add up to the time under the roots, so the table
+    shows where a job's boundary goes without the benchmark.  Empty for
+    a trace whose events carry no ids."""
+    spans = [ev for ev in doc.get("traceEvents", [])
+             if isinstance(ev, dict) and ev.get("ph") == "X"
+             and isinstance(ev.get("id"), int)]
+    by_id = {(ev.get("pid"), ev["id"]): ev for ev in spans}
+    child_us: Dict[tuple, int] = {}
+    for ev in spans:
+        key = (ev.get("pid"), ev.get("parent"))
+        parent = by_id.get(key)
+        if parent is not None and parent.get("tid") == ev.get("tid"):
+            child_us[key] = child_us.get(key, 0) + int(ev.get("dur", 0))
+    out: Dict[str, dict] = {}
+    for ev in spans:
+        dur = int(ev.get("dur", 0))
+        row = out.setdefault(ev["name"],
+                             {"count": 0, "total_us": 0, "self_us": 0})
+        row["count"] += 1
+        row["total_us"] += dur
+        row["self_us"] += max(
+            0, dur - child_us.get((ev.get("pid"), ev["id"]), 0))
+    return out
+
+
 def dropped_events(doc: dict) -> int:
     od = doc.get("otherData")
     if isinstance(od, dict):
@@ -161,6 +190,7 @@ def breakdown(doc: dict) -> dict:
                                                      0) + 1
     return {"phase_us": walls, "served": served, "events": events,
             "counters": counters, "span_quantiles": span_quantiles(doc),
+            "span_self": span_self_times(doc),
             # pipelined runs overlap phase spans in wall time; phase_us
             # above sums work time, this records the concurrency
             "phase_overlap_us": costmodel.phase_overlaps_us(doc),
@@ -223,6 +253,12 @@ def render(doc: dict, path: str) -> str:
             lines.append(f"  {name:<24s} n={q['count']:<6d} "
                          f"p50<={q['p50_us'] / 1e3:>9.2f} ms  "
                          f"p99<={q['p99_us'] / 1e3:>9.2f} ms")
+    if b["span_self"]:
+        lines.append("-- span time: total, and self (less its children) --")
+        for name, row in sorted(b["span_self"].items()):
+            lines.append(f"  {name:<28s} n={row['count']:<6d} "
+                         f"total {row['total_us'] / 1e3:>10.2f} ms  "
+                         f"self {row['self_us'] / 1e3:>10.2f} ms")
     if b["events"]:
         lines.append("-- events " + "-" * 34)
         for name, n in sorted(b["events"].items()):

@@ -17,12 +17,16 @@ Design constraints (mirrored by tests/test_obs.py):
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import sys
 import threading
 import time
 from typing import List, Optional
+
+#: ``add_complete``'s default parent: the caller's innermost open span.
+_CALLER = object()
 
 
 class Span:
@@ -37,9 +41,15 @@ class Span:
     ``jax.profiler.TraceAnnotation`` of the same name and args, so under
     a running ``jax.profiler`` trace it sits on the ``/host:CPU`` plane
     of the same ``.xplane.pb`` as the device ops.  With the profiler off
-    a ``TraceMe`` is a flag check; without JAX nothing is imported."""
+    a ``TraceMe`` is a flag check; without JAX nothing is imported.
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_annotation")
+    A span knows what caused it: ``id`` counts up per tracer, ``parent``
+    is the id of the span that was open on the same thread when this one
+    began (the tracer's per-thread stack), or the job's root span for
+    the first span of a worker thread."""
+
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_annotation",
+                 "id", "parent")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict,
                  cat: str = "span"):
@@ -49,6 +59,8 @@ class Span:
         self.args = args
         self._t0 = 0
         self._annotation = None
+        self.id = 0
+        self.parent = None
 
     def set(self, **attrs) -> "Span":
         self.args.update(attrs)
@@ -63,6 +75,7 @@ class Span:
             self._annotation = profiler.TraceAnnotation(self.name,
                                                         **self.args)
             self._annotation.__enter__()
+        self.id, self.parent = self._tracer._push()
         self._t0 = time.monotonic_ns()
         return self
 
@@ -72,8 +85,9 @@ class Span:
             self._annotation.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.args.setdefault("error", exc_type.__name__)
-        self._tracer.add_complete(self.name, self._t0, t1, cat=self.cat,
-                                  **self.args)
+        self._tracer._pop(self.id)
+        self._tracer._complete(self.name, self._t0, t1, self.cat, self.args,
+                               self.id, self.parent)
         return False
 
 
@@ -102,7 +116,8 @@ class Tracer:
     lock, so spans opened from watchdog threads, the native callback
     thread, or test thread pools interleave safely."""
 
-    def __init__(self, max_events: int = 200_000):
+    def __init__(self, max_events: int = 200_000,
+                 t0_ns: Optional[int] = None):
         self._lock = threading.Lock()
         self._events: List[dict] = []
         self._thread_names = {}   # tid -> python thread name ("M" events)
@@ -114,8 +129,18 @@ class Tracer:
         self.on_complete = None
         # Event timestamps are offsets from tracer creation so traces
         # start near ts=0 regardless of the monotonic clock's epoch.
-        self._t0 = time.monotonic_ns()
+        # ``t0_ns`` moves the epoch back to where the armed run began
+        # (the state reset that ends in arming), so that the job's root
+        # span starts at ts=0 and not before it.
+        self._t0 = time.monotonic_ns() if t0_ns is None else t0_ns
         self.pid = os.getpid()
+        # Causes: ids count up from 1; each thread keeps the ids of its
+        # open spans; ``root_id`` is the job's root span, the parent of
+        # a span that a thread with nothing open begins.
+        self._ids = itertools.count(1)
+        self._open = threading.local()
+        self.root_id: Optional[int] = None
+        self._held = {}           # name -> (Span begun, its thread)
         #: Cross-process provenance, stamped by ``obs.configure`` from
         #: ``obs.context`` / ``obs.set_role``.  ``role`` names this
         #: process's track in a merged timeline ("coordinator",
@@ -143,8 +168,37 @@ class Tracer:
         # to ts=0 keeps every emitted event schema-valid (ts >= 0).
         return max(0, (t_ns - self._t0) // 1000)
 
-    def _append(self, ev: dict) -> None:
-        tid = threading.get_ident()
+    def _stack(self) -> list:
+        try:
+            return self._open.stack
+        except AttributeError:
+            stack = self._open.stack = []
+            return stack
+
+    def _innermost(self) -> Optional[int]:
+        """The id of the span open innermost on this thread; the job's
+        root span where the thread has none open."""
+        stack = self._stack()
+        return stack[-1] if stack else self.root_id
+
+    def _push(self) -> tuple:
+        """(a fresh id, its parent's id or None), the id now innermost
+        on this thread."""
+        parent = self._innermost()
+        sid = next(self._ids)
+        self._stack().append(sid)
+        return sid, parent
+
+    def _pop(self, sid: int) -> None:
+        stack = self._stack()
+        if stack and stack[-1] == sid:
+            stack.pop()
+        elif sid in stack:        # ended out of order
+            stack.remove(sid)
+
+    def _append(self, ev: dict, tid: Optional[int] = None) -> None:
+        if tid is None:
+            tid = threading.get_ident()
         ev["pid"] = self.pid
         ev["tid"] = tid
         with self._lock:
@@ -155,19 +209,70 @@ class Tracer:
                 return
             self._events.append(ev)
 
-    def add_complete(self, name: str, t0_ns: int, t1_ns: int,
-                     cat: str = "span", **args) -> None:
-        """Record a finished region [t0_ns, t1_ns] (monotonic_ns stamps).
-        Exposed directly (not only via Span) so call sites that detect an
-        interesting region *after the fact* — e.g. a kernel-cache miss —
-        can stamp it retroactively."""
+    def _complete(self, name: str, t0_ns: int, t1_ns: int, cat: str,
+                  args: dict, sid: int, parent: Optional[int],
+                  tid: Optional[int] = None) -> None:
         dur = max(0, (t1_ns - t0_ns) // 1000)
         self._append({"name": name, "cat": cat, "ph": "X",
                       "ts": self._ts_us(t0_ns), "dur": dur,
-                      "args": args})
+                      "id": sid, "parent": parent, "args": args}, tid)
         cb = self.on_complete
         if cb is not None:
             cb(name, dur)
+
+    def add_complete(self, name: str, t0_ns: int, t1_ns: int,
+                     cat: str = "span", parent_id=_CALLER, **args) -> None:
+        """Record a finished region [t0_ns, t1_ns] (monotonic_ns stamps).
+        Exposed directly (not only via Span) so call sites that detect an
+        interesting region *after the fact* — e.g. a kernel-cache miss —
+        can stamp it retroactively.  Its parent is the span innermost on
+        the caller's thread now (the job's root span where none is open)
+        unless ``parent_id`` names another, or None for no parent."""
+        if parent_id is _CALLER:
+            parent_id = self._innermost()
+        self._complete(name, t0_ns, t1_ns, cat, args, next(self._ids),
+                       parent_id)
+
+    def begin(self, name: str, t0_ns: Optional[int] = None,
+              root: bool = False, cat: str = "span", **args) -> None:
+        """Open a span that another function will ``end``: one open span
+        per name.  It is innermost on this thread until then, and with
+        ``root`` the parent of whatever a thread with nothing open
+        begins.  Still open when the trace is written or shipped, it is
+        in the file up to that moment, with the arg ``open``.  No
+        ``TraceAnnotation``: that needs a ``with`` block."""
+        self.end(name)            # one open span per name
+        sp = Span(self, name, args, cat)
+        sp.id, sp.parent = self._push()
+        sp._t0 = time.monotonic_ns() if t0_ns is None else t0_ns
+        with self._lock:
+            self._held[name] = (sp, threading.get_ident())
+        if root:
+            self.root_id = sp.id
+
+    def end(self, name: str, **args) -> None:
+        """Close the span ``begin`` opened under this name (a no-op when
+        none is open), on the thread that began it."""
+        t1 = time.monotonic_ns()
+        with self._lock:
+            sp, tid = self._held.pop(name, (None, None))
+        if sp is None:
+            return
+        self._pop(sp.id)
+        sp.args.update(args)
+        self._complete(sp.name, sp._t0, t1, sp.cat, sp.args, sp.id,
+                       sp.parent, tid)
+
+    def _still_open(self) -> List[dict]:
+        """The spans begun and not ended, as events up to now (caller
+        holds the lock)."""
+        now = time.monotonic_ns()
+        return [{"name": sp.name, "cat": sp.cat, "ph": "X",
+                 "ts": self._ts_us(sp._t0),
+                 "dur": max(0, (now - sp._t0) // 1000), "id": sp.id,
+                 "parent": sp.parent, "args": dict(sp.args, open=True),
+                 "pid": self.pid, "tid": tid}
+                for sp, tid in self._held.values()]
 
     def add_instant(self, name: str, cat: str = "event", **args) -> None:
         """Record a point event (lattice demotion, watchdog timeout, …)."""
@@ -191,7 +296,7 @@ class Tracer:
         a job's tail is all launches, and a shipment of nothing else
         would drop the ``phase.*`` spans from every merged trace."""
         with self._lock:
-            events = list(self._events)
+            events = list(self._events) + self._still_open()
             names = dict(self._thread_names)
             dropped = self.dropped
         if max_events is not None and len(events) > max_events:
@@ -274,7 +379,8 @@ class Tracer:
         ignored by Perfetto, so the metrics snapshot and provenance ride
         along in the same file the timeline lives in."""
         with self._lock:
-            events = list(self._events) + list(self._foreign)
+            events = (list(self._events) + self._still_open()
+                      + list(self._foreign))
             names = dict(self._thread_names)
             meta = list(self._foreign_meta)
             dropped = self.dropped

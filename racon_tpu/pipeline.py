@@ -17,6 +17,21 @@ import numpy as np
 from . import native, obs
 from .resilience import faults
 
+#: The native engine's stage marks (``rt::Stage`` in rt_pipeline.hpp),
+#: by stage id: the child span each becomes, and what its second count
+#: (after ``items``) is called there, if it has one.
+_STAGES = (
+    ("native.prepare.targets", "bytes"),
+    ("native.prepare.reads", "bytes"),
+    ("native.prepare.overlaps", "kept"),
+    ("native.prepare.transmute", None),
+    ("native.initialize.align", None),
+    ("native.build_windows.breaks", None),
+    ("native.build_windows.create", None),
+    ("native.build_windows.layers", None),
+    ("native.stitch.join", "bytes"),
+)
+
 
 @dataclass
 class WindowExport:
@@ -64,11 +79,39 @@ class Pipeline:
     # trace separates time inside the C++ engine from device batching;
     # per-window calls (export_window, consensus_cpu_one) are counted in
     # the drivers instead — a span per window would swamp the buffer.
+    # What happens inside one comes back as stage marks, stamped by the
+    # engine on the spans' clock and laid under the call's span once
+    # that has closed (reading and stamping them is the tracing's own
+    # time, not the call's).
     def prepare(self) -> None:
-        with obs.span("native.prepare"):
+        with obs.span("native.prepare") as sp:
             self._lib.rt_pipeline_prepare(self._h)
             native.check_error(self._lib)
+        self._stamp_stages(sp)
         self._count_prepared()
+
+    def stage_marks(self) -> List[Tuple[int, int, int, int, int]]:
+        """(stage id, start ns, end ns, items, bytes) for each stage of
+        the last coarse call, on ``time.monotonic_ns()``'s clock, in one
+        ABI crossing."""
+        cap = len(_STAGES)
+        out = (ctypes.c_uint64 * (5 * cap))()
+        n = min(self._lib.rt_pipeline_stage_marks(self._h, out, cap), cap)
+        return [tuple(int(v) for v in out[5 * i:5 * i + 5])
+                for i in range(n)]
+
+    def _stamp_stages(self, call_span) -> None:
+        """The last coarse call's stages as child spans of the span that
+        was open around it (nothing crosses the ABI when disarmed)."""
+        if not obs.enabled():
+            return
+        parent = getattr(call_span, "id", None)
+        for stage, t0, t1, items, extra in self.stage_marks():
+            name, second = _STAGES[stage]
+            args = {"items": items}
+            if second is not None:
+                args[second] = extra
+            obs.add_complete(name, t0, t1, parent_id=parent, **args)
 
     def _prepare_counts(self) -> Tuple[int, int, int]:
         """(targets, overlap records parsed, overlaps the filters kept):
@@ -138,14 +181,16 @@ class Pipeline:
             native.check_error(self._lib)
 
     def build_windows(self) -> None:
-        with obs.span("native.build_windows"):
+        with obs.span("native.build_windows") as sp:
             self._lib.rt_pipeline_build_windows(self._h)
             native.check_error(self._lib)
+        self._stamp_stages(sp)
 
     def initialize(self) -> None:
-        with obs.span("native.initialize"):
+        with obs.span("native.initialize") as sp:
             self._lib.rt_pipeline_initialize(self._h)
             native.check_error(self._lib)
+        self._stamp_stages(sp)
         self._count_prepared()
 
     # -- phase 2 ----------------------------------------------------------
@@ -229,18 +274,22 @@ class Pipeline:
             self._h, i, consensus, len(consensus), 1 if polished else 0)
 
     def stitch(self, drop_unpolished: bool = True) -> List[Tuple[str, str]]:
-        with obs.span("native.stitch"):
+        with obs.span("native.stitch") as sp:
             n = self._lib.rt_pipeline_stitch(
                 self._h, 1 if drop_unpolished else 0)
             native.check_error(self._lib)
+        self._stamp_stages(sp)
         # targets the stitch left out: no window of theirs was polished
         obs.count("polish.targets.dropped", self._prepare_counts()[0] - n)
         out = []
         ln = ctypes.c_uint64()
-        for i in range(n):
-            p = self._lib.rt_pipeline_result_name(self._h, i, ctypes.byref(ln))
-            name = ctypes.string_at(p, ln.value).decode()
-            p = self._lib.rt_pipeline_result_data(self._h, i, ctypes.byref(ln))
-            data = ctypes.string_at(p, ln.value).decode()
-            out.append((name, data))
+        with obs.span("native.stitch.copy", records=n):
+            for i in range(n):
+                p = self._lib.rt_pipeline_result_name(self._h, i,
+                                                      ctypes.byref(ln))
+                name = ctypes.string_at(p, ln.value).decode()
+                p = self._lib.rt_pipeline_result_data(self._h, i,
+                                                      ctypes.byref(ln))
+                data = ctypes.string_at(p, ln.value).decode()
+                out.append((name, data))
         return out

@@ -111,14 +111,25 @@ def reset_run_state(trace_path: Optional[str]) -> None:
     here (the topology-keyed kernel cache, the XLA compile cache) stays
     hot across jobs.  It also means in-process polishes cannot overlap;
     the serve scheduler serializes device-lane jobs for exactly this
-    reason."""
+    reason.
+
+    The armed tracer's clock starts where this reset does, and two spans
+    open there for other functions to end: ``job``, the root that every
+    span of the run hangs under (the serve session ends it; a bare
+    polisher leaves it open, and the trace written at the end of
+    ``polish()`` holds it up to that write), and ``job.open`` under it,
+    which ``initialize()`` ends where ``phase.parse`` begins."""
+    t0 = time.monotonic_ns()
     faults.reset()     # per-run firing schedule (deterministic)
     watchdog.reset()   # per-run wedge streaks
     budget.configure()  # fresh memory watermarks + RSS watchdog
     from .analysis import sanitize
     sanitize.reset()   # per-run sanitizer findings
     obs.reset()        # per-run trace/metrics (disarmed unless armed
-    obs.configure(trace_path=trace_path)  # by --trace / the knobs)
+    obs.configure(trace_path=trace_path,  # by --trace / the knobs)
+                  epoch_ns=t0)
+    obs.begin("job", t0, root=True)
+    obs.begin("job.open", t0)
 
 
 def _open_journal(paths: Tuple[str, str, str], backend: str,
@@ -134,8 +145,38 @@ def _open_journal(paths: Tuple[str, str, str], backend: str,
         resume, on_mismatch = True, "fresh"
     if journal_path is None:
         return None
-    fp = input_fingerprint(paths, params, backend)
-    return Journal(journal_path, fp, resume=resume, on_mismatch=on_mismatch)
+    with obs.span("job.open.journal"):
+        fp = input_fingerprint(paths, params, backend)
+        return Journal(journal_path, fp, resume=resume,
+                       on_mismatch=on_mismatch)
+
+
+def _open_pipeline(paths: Tuple[str, str, str], kwargs: dict) -> Pipeline:
+    """The run's native pipeline (format sniffing, parsers opened, the
+    thread pool and its aligners): the last seam of ``job.open``."""
+    with obs.span("job.open.pipeline"):
+        return Pipeline(*paths, **kwargs)
+
+
+def _close_job(journal: Optional[Journal], report: RunReport,
+               before_report=None) -> None:
+    """What ``polish()`` does after ``phase.stitch``, as the first part
+    of ``job.close`` (the serve session goes on under the same span and
+    ends it): the journal's close, the report, the trace file.  The
+    write is stamped after it has ended, so that the file a later write
+    leaves holds it."""
+    obs.begin("job.close")
+    if journal is not None:
+        with obs.span("job.close.journal"):
+            journal.close()
+    with obs.span("job.close.report"):
+        if before_report is not None:
+            before_report()
+        report.finalize().write_env()
+    obs.maybe_stop_device_trace()
+    t0 = time.monotonic_ns()
+    if obs.write_trace() is not None:
+        obs.add_complete("job.close.trace", t0, time.monotonic_ns())
 
 
 class CpuPolisher:
@@ -149,8 +190,8 @@ class CpuPolisher:
         self._journal = _open_journal(
             (sequences_path, overlaps_path, target_path), "cpu",
             journal_path, resume_journal, kwargs)
-        self._pipeline = Pipeline(sequences_path, overlaps_path, target_path,
-                                  **kwargs)
+        self._pipeline = _open_pipeline(
+            (sequences_path, overlaps_path, target_path), kwargs)
         self.report = RunReport()
 
     def initialize(self) -> None:
@@ -159,6 +200,7 @@ class CpuPolisher:
         # split Python calls carry extra fault-injection points that
         # would shift deterministic fault schedules); the host path's
         # phase attribution is therefore one span.
+        obs.end("job.open")
         with obs.span("phase.parse", fused="parse+align+window_assign"):
             self._pipeline.initialize()
 
@@ -170,10 +212,7 @@ class CpuPolisher:
                 self._polish_journaled(self._journal)
         with obs.span("phase.stitch"):
             out = self._pipeline.stitch(drop_unpolished)
-        if self._journal is not None:
-            self._journal.close()
-        self.report.finalize().write_env()
-        obs.write_trace()
+        _close_job(self._journal, self.report)
         return out
 
     def _polish_unjournaled(self) -> None:
@@ -260,8 +299,7 @@ class TpuPolisher:
         # Chunked modes parse per target chunk; the full-target
         # Pipeline is only built when we end up sequential.
         self._pipeline = (None if (self._pipelined or self._stream) else
-                          Pipeline(sequences_path, overlaps_path,
-                                   target_path, **kwargs))
+                          _open_pipeline(self._paths, kwargs))
         self._queue = None
         self._worker = None
         self._warm = None
@@ -290,6 +328,7 @@ class TpuPolisher:
                 self._chunks = chunks
                 if self._stream:
                     self._arm_streaming(chunks)
+                obs.end("job.open")     # the chunks' phases start here
                 if self._pipelined:
                     self._start_phase_pipeline(chunks, run_alignment_phase)
                 # streaming without pipelining defers the per-chunk
@@ -298,7 +337,8 @@ class TpuPolisher:
             self._pipelined = False
             self._stream = False
         if self._pipeline is None:
-            self._pipeline = Pipeline(*self._paths, **self._kwargs)
+            self._pipeline = _open_pipeline(self._paths, self._kwargs)
+        obs.end("job.open")
         with obs.span("phase.parse"):
             self._pipeline.prepare()
         with obs.span("phase.align") as sp:
@@ -626,12 +666,7 @@ class TpuPolisher:
             self.report.attach(stats.get("report"))
             with obs.span("phase.stitch"):
                 out = self._pipeline.stitch(drop_unpolished)
-        if self._journal is not None:
-            self._journal.close()
-        self._stamp_memory()
-        self.report.finalize().write_env()
-        obs.maybe_stop_device_trace()
-        obs.write_trace()
+        _close_job(self._journal, self.report, self._stamp_memory)
         return out
 
 

@@ -170,6 +170,10 @@ class PolishSession:
         self.jobs_run = 0
         self.warmed: List[int] = []
         self.warm_wall_s = 0.0
+        #: (job id, start, end) of the last job's second trace write on
+        #: the monotonic clock: the one part of a job's close its own
+        #: trace file cannot hold, handed to the next job's tracer
+        self._release_prev = None
         self._lock = threading.Lock()
         os.makedirs(os.path.join(workdir, "jobs"), exist_ok=True)
 
@@ -261,10 +265,16 @@ class PolishSession:
                 spec.sequences, spec.overlaps, spec.target, backend=backend,
                 journal_path=journal_path, resume_journal=True,
                 trace_path=trace_path, **spec.polish_args())
-            # The constructor armed this request's tracer; the instant
-            # event tags the per-request trace with its job id (every
-            # span in the file belongs to this job — the trace itself is
-            # per-request).
+            # The constructor armed this request's tracer and opened its
+            # root span `job` (ended below).  Every span in the file
+            # belongs to this job (the trace itself is per-request; the
+            # instant event tags it with the job id), except the second
+            # trace write of the job before, which that job's own file
+            # could not hold.
+            if self._release_prev is not None:
+                prev_id, r0, r1 = self._release_prev
+                obs.add_complete("job.release.prev", r0, r1, parent_id=None,
+                                 job=prev_id, t0_mono_ns=r0)
             obs.event("serve.job", job=job_id, backend=backend, cold=cold,
                       submitter=spec.submitter)
             polisher.initialize()
@@ -273,33 +283,41 @@ class PolishSession:
                 # consensus phase has not started.  The journal makes the
                 # cancellation cheap to undo — a re-run resumes from here.
                 raise JobCancelled(job_id)
+            # polish() left `job.close` open after its own part (journal,
+            # report, first trace write); the rest of the close is here
             out = polisher.polish(not spec.include_unpolished)
             kernel_builds = obs.counter_total("kernel.builds.")
 
-            with open(out_path, "w") as f:
-                for name, data in out:
-                    f.write(f">{name}\n{data}\n")
-            summary = polisher.report.summary()
-            # compute-side latency-ledger fragment: per-stage seconds
-            # from this run's own report plus the build/replay overlays,
-            # persisted with the report and shipped in the result for
-            # the scheduler's job ledger
-            stage_s = ledger.stage_seconds(summary)
-            stage_s.update(ledger.overlay_seconds(obs.snapshot()))
-            polisher.report.ledger = {"job": job_id, "stage_s": stage_s}
-            report_doc = dict(polisher.report.as_dict())
-            report_doc["job_id"] = job_id
-            with open(report_path, "w") as f:
-                json.dump(report_doc, f, indent=1)
-                f.write("\n")
+            with obs.span("job.close.output"):
+                with open(out_path, "w") as f:
+                    for name, data in out:
+                        f.write(f">{name}\n{data}\n")
+            with obs.span("job.close.report"):
+                summary = polisher.report.summary()
+                # compute-side latency-ledger fragment: per-stage seconds
+                # from this run's own report plus the build/replay
+                # overlays, persisted with the report and shipped in the
+                # result for the scheduler's job ledger
+                stage_s = ledger.stage_seconds(summary)
+                stage_s.update(ledger.overlay_seconds(obs.snapshot()))
+                polisher.report.ledger = {"job": job_id, "stage_s": stage_s}
+                report_doc = dict(polisher.report.as_dict())
+                report_doc["job_id"] = job_id
+                # where the job's spans are: the one artifact whose path
+                # a reader of the report cannot derive from the report's
+                report_doc["trace"] = trace_path
+                with open(report_path, "w") as f:
+                    json.dump(report_doc, f, indent=1)
+                    f.write("\n")
 
-            self.jobs_run += 1
-            obs.telemetry_tick(jobs_run=self.jobs_run, job=job_id)
-            # bounded span shipment: rides inside the result payload so
-            # a tracing submitter can absorb this job's spans into its
-            # own merged timeline
-            ship = obs.shipment()
-            return {
+            with obs.span("job.close.ship"):
+                self.jobs_run += 1
+                obs.telemetry_tick(jobs_run=self.jobs_run, job=job_id)
+                # bounded span shipment: rides inside the result payload
+                # so a tracing submitter can absorb this job's spans into
+                # its own merged timeline
+                ship = obs.shipment()
+            result = {
                 "job_id": job_id,
                 "backend": backend,
                 "cold": cold,
@@ -315,6 +333,8 @@ class PolishSession:
                 "summary": summary,
                 "ledger": {"stage_s": dict(stage_s)},
             }
+            obs.end("job.close")
+            obs.end("job", job=job_id, backend=backend, cold=cold)
         except JobCancelled:
             raise
         except Exception as e:  # noqa: BLE001 — post-mortem breadcrumb;
@@ -326,8 +346,14 @@ class PolishSession:
             # scoped teardown: re-write the (now complete) per-job trace
             # and disarm, so the next job — or a bare polisher in the
             # same process — can never append into this job's file
+            r0 = time.monotonic_ns()
             obs.release(write=True)
+            self._release_prev = (job_id, r0, time.monotonic_ns())
             context.clear()
+        # the second write cannot be in the file it writes: timed here,
+        # and stamped into the next job's tracer as `job.release.prev`
+        result["release_s"] = (self._release_prev[2] - r0) / 1e9
+        return result
 
     def stats(self) -> dict:
         return {
