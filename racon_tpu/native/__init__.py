@@ -112,6 +112,10 @@ def load() -> ctypes.CDLL:
                                             ctypes.c_uint64]
     lib.rt_steady_clock_ns.restype = ctypes.c_int64
     lib.rt_steady_clock_ns.argtypes = []
+    lib.rt_pool_parallel_for_probe.restype = ctypes.c_uint32
+    lib.rt_pool_parallel_for_probe.argtypes = [
+        ctypes.c_uint32, ctypes.c_uint64, ctypes.c_uint64,
+        ctypes.POINTER(ctypes.c_uint32)]
 
     lib.rt_pipeline_num_align_jobs.restype = ctypes.c_uint64
     lib.rt_pipeline_num_align_jobs.argtypes = [ctypes.c_void_p]

@@ -10,6 +10,7 @@
 #include <vector>
 
 #include "rt_align.hpp"
+#include "rt_error.hpp"
 #include "rt_hirschberg.hpp"
 #include "rt_pipeline.hpp"
 #include "rt_poa.hpp"
@@ -200,6 +201,28 @@ uint64_t rt_pipeline_stage_marks(void* handle, uint64_t* out, uint64_t cap) {
 // The clock the marks are stamped with, for the test that holds it to
 // Python's time.monotonic_ns().
 int64_t rt_steady_clock_ns() { return Pipeline::steady_now_ns(); }
+
+// rt::ThreadPool::parallel_for on a pool of its own, for the tests that
+// hold the blocked loop to "every index exactly once" and to the error a
+// task-per-item loop read in order raises: visits[i] counts the calls of
+// fn(i), and every i >= fail_from throws rt::Error naming i (fail_from
+// >= n: none does). Returns the runner tasks enqueued; 0 with
+// rt_last_error() set after a throw.
+uint32_t rt_pool_parallel_for_probe(uint32_t threads, uint64_t n,
+                                    uint64_t fail_from, uint32_t* visits) {
+  return guarded(
+      [&]() -> uint32_t {
+        rt::ThreadPool pool(threads);
+        return pool.parallel_for(n, [&](uint64_t i) {
+          __atomic_fetch_add(&visits[i], 1, __ATOMIC_RELAXED);
+          if (i >= fail_from) {
+            rt::fail("[racon_tpu::parallel_for_probe] error: item %llu!\n",
+                     static_cast<unsigned long long>(i));
+          }
+        });
+      },
+      0);
+}
 
 uint64_t rt_pipeline_num_align_jobs(void* handle) {
   return static_cast<PipelineHandle*>(handle)->pipeline->num_align_jobs();

@@ -259,22 +259,19 @@ void Pipeline::prepare() {
   mark(Stage::kPrepareOverlaps, &stage_t0, overlaps_parsed_, overlaps_kept_);
 
   // Per-sequence transmute (free unused fields, build reverse complements)
-  // on the pool (parity: src/polisher.cpp:373-382).
-  {
-    std::vector<std::future<void>> futs;
-    for (uint64_t i = 0; i < sequences_.size(); ++i) {
-      futs.emplace_back(pool_->submit([this, &has_name, &has_data,
-                                       &has_reverse_data, i] {
+  // on the pool (parity: src/polisher.cpp:373-382). Blocked: an item is
+  // well under a microsecond for a short read, less than a task costs to
+  // enqueue (align_jobs_cpu and consensus_cpu keep a task an item: theirs
+  // are milliseconds each and the progress bar reads them in order).
+  const uint32_t transmute_tasks = pool_->parallel_for(
+      sequences_.size(), [this, &has_name, &has_data,
+                          &has_reverse_data](uint64_t i) {
         sequences_[i]->transmute(has_name[i] || i < targets_size_,
                                  has_data[i] || i < targets_size_,
                                  has_reverse_data[i]);
-      }));
-    }
-    for (auto& f : futs) {
-      f.get();
-    }
-  }
-  mark(Stage::kPrepareTransmute, &stage_t0, sequences_.size());
+      });
+  mark(Stage::kPrepareTransmute, &stage_t0, sequences_.size(),
+       transmute_tasks);
 
   logger_.log("[racon_tpu::Pipeline::initialize] loaded overlaps");
   // Collect alignment jobs (overlaps without a CIGAR).
@@ -330,20 +327,14 @@ void Pipeline::build_windows() {
   const uint64_t num_overlaps = overlaps_.size();
 
   // Breaking-point walks on the pool (cheap CIGAR scans now that every
-  // overlap has a CIGAR; parity: src/polisher.cpp:466-488).
-  {
-    std::vector<std::future<void>> futs;
-    for (auto& o : overlaps_) {
-      Overlap* op = o.get();
-      futs.emplace_back(pool_->submit([this, op] {
-        op->find_breaking_points(sequences_, params_.window_length);
-      }));
-    }
-    for (auto& f : futs) {
-      f.get();
-    }
-  }
-  mark(Stage::kWindowsBreaks, &stage_t0, num_overlaps);
+  // overlap has a CIGAR; parity: src/polisher.cpp:466-488). Blocked like
+  // the transmute loop and for the same reason: a 150 bp CIGAR scan costs
+  // less than a task (align_jobs_cpu and consensus_cpu keep one an item).
+  const uint32_t breaks_tasks =
+      pool_->parallel_for(num_overlaps, [this](uint64_t i) {
+        overlaps_[i]->find_breaking_points(sequences_, params_.window_length);
+      });
+  mark(Stage::kWindowsBreaks, &stage_t0, num_overlaps, breaks_tasks);
 
   // Create windows per target (parity: src/polisher.cpp:388-403).
   std::vector<uint64_t> id_to_first_window_id(targets_size_ + 1, 0);

@@ -56,9 +56,9 @@ enum class Stage : uint32_t {
   kPrepareTargets = 0,  // items targets, bytes their bases
   kPrepareReads,        // items reads, bytes their bases
   kPrepareOverlaps,     // items records parsed; the bytes slot: overlaps kept
-  kPrepareTransmute,    // items sequences
+  kPrepareTransmute,    // items sequences; the bytes slot: pool tasks
   kInitializeAlign,     // items alignment jobs (the fused initialize only)
-  kWindowsBreaks,       // items overlaps
+  kWindowsBreaks,       // items overlaps; the bytes slot: pool tasks
   kWindowsCreate,       // items windows
   kWindowsLayers,       // items layers added
   kStitchJoin,          // items records, bytes their bases

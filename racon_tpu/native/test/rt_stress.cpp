@@ -7,6 +7,10 @@
 //   3. mid-flight cancel   — cancel_pending() vs running workers; dropped
 //                            futures must break, not hang; pool reusable
 //   4. pool churn          — rapid create/submit/destroy cycles
+//   4b. blocked loop       — parallel_for: every index once from plain
+//                            (unsynchronised) per-index writes, throwing
+//                            items, and a cancel_pending() racing its
+//                            runners: returns or throws, never hangs
 //   5. CIGAR install race  — concurrent set_job_cigar on disjoint jobs,
 //                            then pooled host alignment for the rest
 //                            (the device/host alignment hand-off)
@@ -182,6 +186,66 @@ static void stress_pool_churn() {
     }
   }
   CHECK_EQ(ran.load(), kCycles * kJobs);
+}
+
+// ---- 4b. blocked parallel-for ----------------------------------------------
+// Plain per-index writes (a second visit of an index from another thread
+// is a race the sanitizer reports, as the pipeline's per-object writes
+// would be); items that throw: the lowest index is the one rethrown, after
+// every runner has ended, so the by-reference captures are still alive;
+// cancel_pending() against queued runners: the call ends either way and
+// no index runs twice.
+static void stress_parallel_for() {
+  rt::ThreadPool pool(4);
+  for (uint64_t n : {0ull, 1ull, 3ull, 64ull, 65ull, 1000ull, 100000ull}) {
+    std::vector<uint32_t> visits(n, 0);
+    const uint32_t tasks =
+        pool.parallel_for(n, [&visits](uint64_t i) { ++visits[i]; });
+    CHECK(tasks <= pool.num_threads());
+    CHECK(n == 0 || tasks >= 1);
+    uint64_t once = 0;
+    for (uint32_t v : visits) {
+      once += v == 1;
+    }
+    CHECK_EQ(once, n);
+  }
+  for (uint64_t fail_from : {0ull, 777ull, 9999ull}) {
+    std::vector<uint32_t> visits(10000, 0);
+    uint64_t thrown = ~0ull;
+    try {
+      pool.parallel_for(visits.size(), [&visits, fail_from](uint64_t i) {
+        ++visits[i];
+        if (i >= fail_from) {
+          throw i;
+        }
+      });
+    } catch (uint64_t i) {
+      thrown = i;
+    }
+    CHECK_EQ(thrown, fail_from);
+    bool ran_as_it_should = true;  // all up to the failure, none twice
+    for (uint64_t i = 0; i < visits.size(); ++i) {
+      ran_as_it_should &= visits[i] <= 1 && (i > fail_from || visits[i] == 1);
+    }
+    CHECK(ran_as_it_should);
+  }
+  for (int round = 0; round < 50; ++round) {
+    rt::ThreadPool small(2);
+    std::vector<uint32_t> visits(4096, 0);
+    std::thread canceller([&small] { small.cancel_pending(); });
+    bool whole = true;
+    try {
+      small.parallel_for(visits.size(), [&visits](uint64_t i) { ++visits[i]; });
+    } catch (const std::future_error&) {
+      whole = false;  // a runner was dropped before it began
+    }
+    canceller.join();
+    bool none_twice = true;
+    for (uint32_t v : visits) {
+      none_twice &= v <= 1 && (!whole || v == 1);
+    }
+    CHECK(none_twice);
+  }
 }
 
 // ---- pipeline fixtures -----------------------------------------------------
@@ -420,6 +484,7 @@ int main() {
   stress_shutdown_backlog();
   stress_cancellation();
   stress_pool_churn();
+  stress_parallel_for();
   stress_cigar_install();
   stress_consensus_handoff();
   stress_overlapped_fallback();

@@ -24,9 +24,9 @@ _STAGES = (
     ("native.prepare.targets", "bytes"),
     ("native.prepare.reads", "bytes"),
     ("native.prepare.overlaps", "kept"),
-    ("native.prepare.transmute", None),
+    ("native.prepare.transmute", "tasks"),
     ("native.initialize.align", None),
-    ("native.build_windows.breaks", None),
+    ("native.build_windows.breaks", "tasks"),
     ("native.build_windows.create", None),
     ("native.build_windows.layers", None),
     ("native.stitch.join", "bytes"),
@@ -102,16 +102,25 @@ class Pipeline:
 
     def _stamp_stages(self, call_span) -> None:
         """The last coarse call's stages as child spans of the span that
-        was open around it (nothing crosses the ABI when disarmed)."""
+        was open around it, and what its blocked pool loops took as
+        ``native.pool.items`` over ``native.pool.tasks``, counted once a
+        call (nothing crosses the ABI when disarmed)."""
         if not obs.enabled():
             return
         parent = getattr(call_span, "id", None)
+        pool_items = pool_tasks = 0
         for stage, t0, t1, items, extra in self.stage_marks():
             name, second = _STAGES[stage]
             args = {"items": items}
             if second is not None:
                 args[second] = extra
+            if second == "tasks":   # a blocked loop on the native pool
+                pool_items += items
+                pool_tasks += extra
             obs.add_complete(name, t0, t1, parent_id=parent, **args)
+        if pool_tasks:
+            obs.count("native.pool.items", pool_items)
+            obs.count("native.pool.tasks", pool_tasks)
 
     def _prepare_counts(self) -> Tuple[int, int, int]:
         """(targets, overlap records parsed, overlaps the filters kept):

@@ -300,6 +300,8 @@ def test_disarmed_path_allocates_nothing(sample, monkeypatch):
     pl = _pipeline(sample)
     pl.prepare()
     pl.build_windows()
+    # nor was the blocked loops' work counted (ISSUE 40): no registry
+    assert obs.snapshot() is None and obs.counter_total("native.pool.") == 0
 
 
 # ----------------------------------------------------- (d) a served job's file
