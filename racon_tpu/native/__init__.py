@@ -106,6 +106,10 @@ def load() -> ctypes.CDLL:
 
     lib.rt_pipeline_prepare_counts.restype = None
     lib.rt_pipeline_prepare_counts.argtypes = [ctypes.c_void_p, u64p]
+    lib.rt_pipeline_window_growth.restype = None
+    lib.rt_pipeline_window_growth.argtypes = [ctypes.c_void_p, u64p]
+    lib.rt_pipeline_filter_counts.restype = None
+    lib.rt_pipeline_filter_counts.argtypes = [ctypes.c_void_p, u64p]
 
     lib.rt_pipeline_stage_marks.restype = ctypes.c_uint64
     lib.rt_pipeline_stage_marks.argtypes = [ctypes.c_void_p, u64p,

@@ -181,6 +181,17 @@ void rt_pipeline_prepare_counts(void* handle, uint64_t* out) {
   out[2] = p.overlaps_kept();
 }
 
+// out[0..3] = overlaps dropped by the error threshold, window layers
+// offered, dropped as too short, dropped by mean quality: what the filters
+// of prepare() and build_windows() did, in one crossing.
+void rt_pipeline_filter_counts(void* handle, uint64_t* out) {
+  const Pipeline& p = *static_cast<PipelineHandle*>(handle)->pipeline;
+  out[0] = p.overlaps_dropped_error();
+  out[1] = p.layers_offered();
+  out[2] = p.layers_dropped_short();
+  out[3] = p.layers_dropped_quality();
+}
+
 // The stage marks of the last coarse call (prepare, build_windows,
 // initialize, stitch), five values a mark: stage id (rt::Stage), start
 // and end in steady_clock nanoseconds, items, bytes. Writes at most `cap`
@@ -278,6 +289,20 @@ void rt_pipeline_initialize(void* handle) {
 
 uint64_t rt_pipeline_num_windows(void* handle) {
   return static_cast<PipelineHandle*>(handle)->pipeline->num_windows();
+}
+
+// Per window, in one crossing: out[2 i] = the layer bases the alignments put
+// off the backbone (Window::stray_bases), out[2 i + 1] = the nodes the host
+// engine's graph held (0 until its consensus ran there).
+void rt_pipeline_window_growth(void* handle, uint64_t* out) {
+  guarded_void([&] {
+  const auto& p = *static_cast<PipelineHandle*>(handle)->pipeline;
+  for (size_t i = 0; i < p.num_windows(); ++i) {
+    const bool held = p.has_window(i);  // stitch lets the windows go
+    out[2 * i] = held ? p.window(i).stray_bases : 0;
+    out[2 * i + 1] = held ? p.window(i).graph_nodes : 0;
+  }
+  });
 }
 
 // Window metadata: [n_total_seqs (incl. backbone), backbone_len, rank, type,

@@ -215,11 +215,16 @@ void Overlap::find_breaking_points(
     cigar = align_global_cigar(q, q_len, t, t_len);
   }
 
-  find_breaking_points_from_cigar(window_length);
+  const auto& query = *sequences[q_id];
+  find_breaking_points_from_cigar(
+      window_length,
+      strand ? query.reverse_complement.data() : query.data.data(),
+      sequences[t_id]->data.data());
   std::string().swap(cigar);
 }
 
-void Overlap::find_breaking_points_from_cigar(uint32_t window_length) {
+void Overlap::find_breaking_points_from_cigar(uint32_t window_length,
+                                              const char* q, const char* t) {
   // Window end positions on the target (inclusive), then the overlap end.
   // Parity: src/overlap.cpp:229-235.
   std::vector<int32_t> window_ends;
@@ -233,6 +238,9 @@ void Overlap::find_breaking_points_from_cigar(uint32_t window_length) {
   uint32_t w = 0;
   bool found_first = false;
   std::pair<uint32_t, uint32_t> first_match{0, 0}, last_match{0, 0};
+  // strays of the piece so far, and insertions since its last anchor
+  // (they count once another anchor of the same piece follows)
+  uint32_t strays = 0, hanging = 0;
 
   int32_t q_ptr = static_cast<int32_t>(strand ? (q_length - q_end) : q_begin) - 1;
   int32_t t_ptr = static_cast<int32_t>(t_begin) - 1;
@@ -241,8 +249,10 @@ void Overlap::find_breaking_points_from_cigar(uint32_t window_length) {
     if (found_first) {
       breaking_points.emplace_back(first_match);
       breaking_points.emplace_back(last_match);
+      breaking_strays.emplace_back(strays);
     }
     found_first = false;
+    strays = hanging = 0;
     ++w;
   };
 
@@ -258,6 +268,12 @@ void Overlap::find_breaking_points_from_cigar(uint32_t window_length) {
           found_first = true;
           first_match = {static_cast<uint32_t>(t_ptr),
                          static_cast<uint32_t>(q_ptr)};
+          hanging = 0;
+        }
+        strays += hanging;
+        hanging = 0;
+        if (q[q_ptr] != t[t_ptr]) {
+          ++strays;
         }
         last_match = {static_cast<uint32_t>(t_ptr) + 1,
                       static_cast<uint32_t>(q_ptr) + 1};
@@ -266,7 +282,9 @@ void Overlap::find_breaking_points_from_cigar(uint32_t window_length) {
         }
       }
     } else if (op == 'I') {
-      q_ptr += std::atoi(cigar.c_str() + j);
+      const int32_t n = std::atoi(cigar.c_str() + j);
+      q_ptr += n;
+      hanging += static_cast<uint32_t>(n);
       j = i + 1;
     } else if (op == 'D' || op == 'N') {
       uint32_t n = static_cast<uint32_t>(std::atoi(cigar.c_str() + j));

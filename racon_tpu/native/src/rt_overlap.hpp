@@ -42,6 +42,7 @@ struct Overlap {
   // Flattened (t_pos, q_pos) pairs; even index = first match in a window,
   // odd index = one-past the last match.
   std::vector<std::pair<uint32_t, uint32_t>> breaking_points;
+  std::vector<uint32_t> breaking_strays;  // one a piece (pair of points)
 
   Overlap() : is_transmuted(true) {}
 
@@ -86,8 +87,12 @@ struct Overlap {
                        uint32_t* t_len) const;
 
   // CIGAR walk emitting per-window match anchors.
-  // Parity: src/overlap.cpp:226-292.
-  void find_breaking_points_from_cigar(uint32_t window_length);
+  // Parity: src/overlap.cpp:226-292.  Over `q` and `t` (the strand's whole
+  // query and the whole target) it also counts, per piece, the read bases
+  // between the piece's first and last anchor that stray from the target:
+  // aligned to another base, or inserted (breaking_strays, one a piece).
+  void find_breaking_points_from_cigar(uint32_t window_length, const char* q,
+                                       const char* t);
 };
 
 }  // namespace rt

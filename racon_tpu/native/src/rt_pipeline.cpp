@@ -96,6 +96,8 @@ void Pipeline::remove_invalid_overlaps(
     }
     if (overlaps[i]->error > params_.error_threshold ||
         overlaps[i]->q_id == overlaps[i]->t_id) {
+      overlaps_dropped_error_ +=
+          overlaps[i]->error > params_.error_threshold ? 1 : 0;
       overlaps[i].reset();
       continue;
     }
@@ -363,8 +365,10 @@ void Pipeline::build_windows() {
     const auto& bp = o->breaking_points;
 
     for (size_t j = 0; j + 1 < bp.size(); j += 2) {
+      ++layers_offered_;
       if (bp[j + 1].second - bp[j].second <
           0.02 * params_.window_length) {
+        ++layers_dropped_short_;
         continue;
       }
 
@@ -377,6 +381,7 @@ void Pipeline::build_windows() {
         }
         average_quality /= bp[j + 1].second - bp[j].second;
         if (average_quality < params_.quality_threshold) {
+          ++layers_dropped_quality_;
           continue;
         }
       }
@@ -403,7 +408,8 @@ void Pipeline::build_windows() {
       windows_[window_id]->add_layer(data, data_length, quality,
                                      quality_length,
                                      bp[j].first - window_start,
-                                     bp[j + 1].first - window_start - 1);
+                                     bp[j + 1].first - window_start - 1,
+                                     o->breaking_strays[j / 2]);
     }
     o.reset();
   }

@@ -90,6 +90,14 @@ class Pipeline {
   uint64_t num_targets() const { return targets_size_; }
   uint64_t overlaps_parsed() const { return overlaps_parsed_; }
   uint64_t overlaps_kept() const { return overlaps_kept_; }
+  // Overlaps the error threshold (-e) dropped in prepare(), and what
+  // build_windows() did with the pieces the breaking points cut: offered
+  // to a window, dropped as under 2 % of a window, dropped by mean
+  // quality (-q).
+  uint64_t overlaps_dropped_error() const { return overlaps_dropped_error_; }
+  uint64_t layers_offered() const { return layers_offered_; }
+  uint64_t layers_dropped_short() const { return layers_dropped_short_; }
+  uint64_t layers_dropped_quality() const { return layers_dropped_quality_; }
 
   // Overlaps still lacking a CIGAR (alignment jobs for the device).
   size_t num_align_jobs() const { return align_jobs_.size(); }
@@ -110,6 +118,7 @@ class Pipeline {
   // ---- phase 2: consensus -------------------------------------------------
   size_t num_windows() const { return windows_.size(); }
   const Window& window(size_t i) const { return *windows_[i]; }
+  bool has_window(size_t i) const { return windows_[i] != nullptr; }
 
   // Host POA for one window / all unfinished windows (thread pool).
   bool consensus_cpu_one(size_t i);
@@ -159,6 +168,9 @@ class Pipeline {
 
   std::vector<std::unique_ptr<Overlap>> overlaps_;
   uint64_t overlaps_parsed_ = 0, overlaps_kept_ = 0;
+  uint64_t overlaps_dropped_error_ = 0;
+  uint64_t layers_offered_ = 0, layers_dropped_short_ = 0;
+  uint64_t layers_dropped_quality_ = 0;
   std::vector<size_t> align_jobs_;  // overlap indices lacking a CIGAR
 
   std::vector<std::shared_ptr<Window>> windows_;

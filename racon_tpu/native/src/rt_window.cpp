@@ -33,7 +33,7 @@ Window::Window(uint64_t id_, uint32_t rank_, WindowType type_,
 
 void Window::add_layer(const char* sequence, uint32_t sequence_length,
                        const char* quality, uint32_t quality_length,
-                       uint32_t begin, uint32_t end) {
+                       uint32_t begin, uint32_t end, uint32_t strays) {
   if (sequence_length == 0 || begin == end) {
     return;
   }
@@ -49,6 +49,7 @@ void Window::add_layer(const char* sequence, uint32_t sequence_length,
   sequences.emplace_back(sequence, sequence_length);
   qualities.emplace_back(quality, quality_length);
   positions.emplace_back(begin, end);
+  stray_bases += strays;
 }
 
 static std::vector<uint32_t> layer_weights(const char* quality, uint32_t len) {
@@ -108,6 +109,7 @@ bool Window::generate_consensus(PoaAligner& aligner, bool trim) {
 
   std::vector<uint32_t> coverages;
   consensus = graph.generate_consensus(&coverages);
+  graph_nodes = graph.num_nodes();
 
   if (type == WindowType::kTGS && trim) {
     const uint32_t average_coverage =

@@ -32,6 +32,11 @@ struct Window {
   std::vector<std::pair<const char*, uint32_t>> sequences;
   std::vector<std::pair<const char*, uint32_t>> qualities;  // ptr may be null
   std::vector<std::pair<uint32_t, uint32_t>> positions;     // begin, end (inclusive)
+  // Of the layers' bases, those their alignments put off the backbone
+  // (another base, or inserted): what makes a graph grow.
+  uint64_t stray_bases = 0;
+  // Nodes the host engine's graph held when generate_consensus ended.
+  uint32_t graph_nodes = 0;
 
   Window(uint64_t id_, uint32_t rank_, WindowType type_, const char* backbone,
          uint32_t backbone_length, const char* quality,
@@ -39,7 +44,7 @@ struct Window {
 
   void add_layer(const char* sequence, uint32_t sequence_length,
                  const char* quality, uint32_t quality_length, uint32_t begin,
-                 uint32_t end);
+                 uint32_t end, uint32_t strays = 0);
 
   // CPU oracle / fallback consensus via the host POA engine.
   // Returns true if POA actually ran (>= 2 layers), false when the backbone

@@ -241,7 +241,9 @@ def render(doc: dict, path: str) -> str:
              ("poa.programs.", "poa.lockstep.")),
             ("consensus graph capacity by rung",
              ("poa.windows.rung.", "poa.nodes.", "poa.windows.overflow.",
-              "poa.layers."))):
+              "poa.layers.", "poa.backbone.")),
+            ("what the filters and the band ladder did",
+             ("overlaps.", "layers.", "align.pairs."))):
         rows = {k: v for k, v in sorted(b["counters"].items())
                 if k.startswith(prefixes)}
         if rows:

@@ -1162,7 +1162,11 @@ class _HirschbergOps:
                              cells_counter="align.cells.banded")
                 continue
             if ops is None:
-                continue  # band escape: host aligns it
+                # host aligns it: the optimal path left the band (counted)
+                # or band_for refused the pair (counted by run_jobs)
+                if band_for(*self.dims[job]):
+                    obs.count("align.pairs.host.escaped")
+                continue
             st = self.band.get(job)
             if st is not None:
                 st.pending = False
@@ -1285,9 +1289,11 @@ def run_jobs(pipeline, jobs, cohort: int = None, report=None,
     banded_on = _band.enabled()
     band_states = {}
     buckets = {}
+    by_band = collections.Counter()     # band_for's bucket -> pairs
     for job in jobs:
         n, m = dims[job]
         K = band_for(n, m)
+        by_band[K] += 1
         kb = _band.plan_align_band(n, m, K) if banded_on and K else None
         if kb is not None:
             band_states[job] = _band.BandState(kb)
@@ -1297,6 +1303,13 @@ def run_jobs(pipeline, jobs, cohort: int = None, report=None,
                            []).append(job)
     if band_states:
         obs.count("band.jobs", len(band_states))
+    # where the band ladder put the job's pairs: one counter a band
+    # bucket (every key at every job, a zero too), and the pairs band_for
+    # holds no bucket for (|m - n| + a tenth of the longer side past the
+    # widest band), which the host aligns
+    for K in BANDS:
+        obs.count(f"align.pairs.band.k{K}", by_band[K])
+    obs.count("align.pairs.host.refused", by_band[0])
     # how the job's pairs fill the cohorts: a bucket's last cohort is
     # partial, so many thin buckets mean many launches of few pairs
     cohorts = sum(-(-len(items) // cohort) for items in buckets.values())

@@ -11,13 +11,16 @@ Mirrors the reference's CUDA polish orchestration
 
 Graph capacity comes in rungs (NODE_RUNGS).  The base rung is
 RACON_TPU_NODE_FACTOR x the window class (3 x: 1536 node slots at -w
-500), which holds a long-read window up to ~55 layers; the upper rung is
-UPPER_NODE_FACTOR x (5 x: 2560), sized for DEPTH_CAP layers.  A window's
-rung is chosen before any kernel runs, from window_info alone
-(node_estimate: the layers' bases over the backbone's length, read
-against NODE_ENVELOPE), and is part of the bucket key (depth bucket,
-window class, rung); a window that outgrows its rung all the same is
-redone on the host and counted by cause (poa.windows.overflow.*).
+500), which holds a long-read window up to ~55 layers of the ONT profile
+on a polished draft, or ~33 of 17 % reads on a raw layout; the upper
+rung is UPPER_NODE_FACTOR x (5 x: 2560), sized for DEPTH_CAP layers.  A
+window's rung is chosen before any kernel runs, from what the job tells
+of it (node_estimate: its layers' bases and how many of them its
+alignments put off the backbone, read against NODE_ENVELOPE), and is part
+of the bucket key (depth bucket, window class, rung); a window that
+outgrows its rung all the same is redone on the host and counted by
+cause (poa.windows.overflow.*) and as a miss of the rule
+(poa.windows.rung.miss.d<bucket>).
 
 Failure handling runs through the explicit degradation lattice
 (racon_tpu/resilience/lattice.py): tiers ls -> xla -> host, with
@@ -55,26 +58,38 @@ NODE_RUNGS = ("base", "upper")
 
 #: max_nodes of the upper rung = this x the window class: what
 #: DEPTH_CAP layers need.  NODE_ENVELOPE reads 4.10 nodes a backbone
-#: base at 200 effective layers and 4.17 at 240; 5 leaves a fifth of
-#: room over that, for reads noisier than the profile measured.
+#: base at 200 effective layers of the ONT profile and 4.17 at 240; 5
+#: leaves a fifth of room over that.
 UPPER_NODE_FACTOR = 5
 
-#: (effective layers, nodes per backbone base): the most nodes the host
-#: engine's graphs held at up to that many effective layers (a window's
-#: layer bases over its backbone length, so a layer that covers a tenth
-#: of the window counts a tenth).  Read off rt_poa.cpp's graphs, the
-#: oracle both kernels are verified against, over 698 windows of 500 bp
-#: at 30x to 200x of benchmark/generate.py's ONT profile (5/3/3 %
-#: sub/ins/del, -m 5 -x -4 -g -8); not fitted, not padded.  It is
-#: concave: match + gap (5 - 8) outscores a mismatch (-4) and a fresh
-#: insertion (-8), so the denser the graph, the more of a new layer's
-#: errors land on nodes that are already there.  The exact graph of the
-#: same layers (benchmark/reference_depth.py) holds half as many nodes
-#: again at 100 layers; it bounds this from above.
-NODE_ENVELOPE = ((0, 1.0), (8, 1.44), (16, 1.88), (24, 2.26), (32, 2.51),
-                 (40, 2.72), (48, 2.89), (56, 3.09), (64, 3.17), (80, 3.44),
-                 (100, 3.61), (128, 3.83), (160, 3.97), (200, 4.10),
-                 (240, 4.17))
+#: (growth, nodes per backbone base): the most nodes the host engine's
+#: graphs held among windows whose growth is under the next key.  A
+#: window's **growth** is what the job itself tells before any kernel
+#: runs: sqrt(layer bases x stray bases) / backbone length, the geometric
+#: mean of how deep the window is covered and how deep it is covered by
+#: bases its alignments put off the backbone (another base, or inserted:
+#: the native breaking-point walk counts them, Pipeline.window_growth).
+#: Layers alone do not tell: 33 layers hold 2.4 nodes a backbone base
+#: where reads stray by 7 % (the ONT profile on a 1 % draft) and 2.9
+#: where they stray by 18 % (17 % reads on a raw layout that is itself a
+#: read).  Strays alone do not either: a backbone error sends every
+#: layer astray onto one node.  Against growth the graphs of both fall on
+#: one curve (within 3 %): read off rt_poa.cpp's graphs, the oracle both
+#: kernels are verified against, over 2 460 windows of 128-500 bp: the
+#: ONT profile (5/3/3 % sub/ins/del) at 30x to 100x from SAM and PAF,
+#: fragment correction at 30x (unit scores), and a raw layout at 34x
+#: (7.7/4.6/4.6 % on both sides); not fitted, not padded.  Past growth 40
+#: (138 layers of the ONT profile) the values are the ONT profile's at
+#: 160, 200 and 240 layers.  It is concave: match + gap (5 - 8) outscores
+#: a mismatch (-4) and a fresh insertion (-8), so the denser the graph,
+#: the more of a new layer's strays land on nodes that are already
+#: there.  The exact graph of the same layers
+#: (benchmark/reference_depth.py) holds half as many nodes again at 100
+#: layers; it bounds this from above.
+NODE_ENVELOPE = ((0, 1.43), (2, 1.82), (4, 2.20), (6, 2.47), (8, 2.68),
+                 (10, 2.85), (12, 2.97), (14, 3.12), (16, 3.26), (18, 3.35),
+                 (20, 3.52), (24, 3.67), (28, 3.79), (32, 3.88), (36, 3.90),
+                 (44, 3.97), (54, 4.10), (65, 4.17))
 
 
 def _sanitize():
@@ -93,15 +108,17 @@ AUDIT_WINDOW_LENGTHS = (500, 1000)
 #: Declared compile budget for the audited POA grid (audit_grid): one
 #: program per (depth bucket, window class) on the base rung —
 #: len(DEPTH_BUCKETS) x len(AUDIT_WINDOW_LENGTHS) = 6 — plus one per
-#: window class on the upper rung, which only the DEPTH_CAP bucket can
-#: ask for (a window of at most 32 layers holds under 2.6 x its backbone,
-#: NODE_ENVELOPE): 6 + 2 = 8.  Revisited on purpose for the node rungs:
-#: the two more are built by the first job deep enough to need them, not
-#: by every process's warm-up, so a process that never sees a deep
-#: window still builds 3 per window class.  A deliberate literal, not a
-#: product: widening DEPTH_BUCKETS, the audited window set, the rungs or
-#: any geometry change that splits signatures must consciously revisit
-#: this number or the jaxpr audit (racon_tpu/analysis) fails tier-1 —
+#: window class on the upper rung: 6 + 2 = 8.  Revisited on purpose for
+#: the node rungs (PR 35) and again when windows of every depth were let
+#: climb (PR 41): a climber of at most 32 layers runs in the DEPTH_CAP
+#: bucket's upper-rung program, padded in depth, so the upper rung still
+#: has one program a window class.  The two more are built by the first
+#: job that needs them, not by every process's warm-up, so a process
+#: that never sees such a window still builds 3 per window class.  A
+#: deliberate literal, not a product: widening DEPTH_BUCKETS, the
+#: audited window set, the rungs or any geometry change that splits
+#: signatures must consciously revisit this number or the jaxpr audit
+#: (racon_tpu/analysis) fails tier-1 —
 #: silent recompile blow-ups are the single biggest TPU serving-latency
 #: cliff.
 POA_RECOMPILE_BUDGET = 8
@@ -110,8 +127,8 @@ POA_RECOMPILE_BUDGET = 8
 def audit_grid(window_lengths=AUDIT_WINDOW_LENGTHS) -> list:
     """(depth bucket, window class, rung) of every consensus program the
     driver can ask for at these window lengths: each depth bucket on the
-    base rung, and the DEPTH_CAP bucket on every rung above it
-    (_node_rung)."""
+    base rung, and the DEPTH_CAP bucket on every rung above it (where
+    _consensus_phase puts every window that climbs)."""
     classes = sorted({window_class(max(int(w), 1)) for w in window_lengths})
     grid = [(d, c, 0) for d in DEPTH_BUCKETS for c in classes]
     grid += [(DEPTH_CAP, c, r) for c in classes
@@ -197,13 +214,21 @@ def _rung_factors() -> tuple:
     return (base, UPPER_NODE_FACTOR) if base < UPPER_NODE_FACTOR else (base,)
 
 
-def node_estimate(bb_len: int, layer_bytes: int) -> int:
+def window_growth(bb_len: int, layer_bytes: int, stray_bytes: int) -> float:
+    """NODE_ENVELOPE's key for a window: the geometric mean of its layer
+    bases and its stray bases, per backbone base."""
+    return float(np.sqrt(float(layer_bytes) * float(stray_bytes))
+                 / max(bb_len, 1))
+
+
+def node_estimate(bb_len: int, layer_bytes: int, stray_bytes: int) -> int:
     """Nodes a long-read window's graph will hold at most, before any
-    kernel runs: the backbone times NODE_ENVELOPE at the window's
-    effective layers (window_info's layer bytes over its backbone
-    length)."""
-    eff = layer_bytes / max(bb_len, 1)
-    return int(np.ceil(bb_len * np.interp(eff, *zip(*NODE_ENVELOPE))))
+    kernel runs: the backbone times NODE_ENVELOPE at the window's growth
+    (the entry at or under it: what the table says, no more)."""
+    growth = window_growth(bb_len, layer_bytes, stray_bytes)
+    keys, values = zip(*NODE_ENVELOPE)
+    at = max(int(np.searchsorted(keys, growth, side="right")) - 1, 0)
+    return int(np.ceil(bb_len * values[at]))
 
 
 def window_class(bb_len: int) -> int:
@@ -297,6 +322,10 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
 
     # Metadata pass: geometry + depth buckets, no layer bytes touched.
     jobs = []          # (window_idx, estimated depth, backbone len, nodes)
+    # how far each window's layers stray from its backbone, one crossing;
+    # a pipeline that cannot tell keeps its windows on the base rung
+    strays = (pipeline.window_growth()[:, 0]
+              if hasattr(pipeline, "window_growth") else None)
     with obs.span("poa.metadata", windows=n):
         for i in range(n):
             if i in replayed:
@@ -324,8 +353,8 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
             # 0.8 % substitutions a column holds under two nodes.  The
             # envelope is the long reads'.
             jobs.append((i, min(k, DEPTH_CAP), bb_len,
-                         node_estimate(bb_len, layer_bytes) if is_tgs
-                         else bb_len))
+                         node_estimate(bb_len, layer_bytes, int(strays[i]))
+                         if is_tgs and strays is not None else bb_len))
     # per-window ctypes calls (ROADMAP S5), counted once per loop
     obs.count("native.calls.window_info", n - len(replayed))
     report.record_served("backbone", stats["backbone"])
@@ -364,7 +393,11 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
             if wl_class not in capacities:
                 capacities[wl_class] = _rung_capacities(
                     wl_class, use_pallas, match, mismatch, gap)
-            rung = _node_rung(est_nodes, bucket, capacities[wl_class])
+            rung = _node_rung(est_nodes, capacities[wl_class])
+            if rung:
+                # a rung above the base has one program a window class,
+                # the DEPTH_CAP bucket's, whatever the window's depth
+                bucket = DEPTH_CAP
             buckets.setdefault((bucket, wl_class, rung),
                                []).append((win, depth, bb))
         report.extra["rung_windows"] = {
@@ -628,15 +661,14 @@ def _rung_capacities(wl_class: int, use_pallas: bool, match: int,
     return tuple(caps)
 
 
-def _node_rung(est_nodes: int, depth_bucket: int, capacities) -> int:
+def _node_rung(est_nodes: int, capacities) -> int:
     """Index into NODE_RUNGS of the rung a window runs on: the smallest
     that holds its node estimate, the top one if none does (a window
-    that overflows it goes to the host, counted).  Only the DEPTH_CAP
-    bucket climbs: a window of at most 32 layers holds under 2.6 x its
-    backbone (NODE_ENVELOPE), which keeps the programs a process can
-    build at POA_RECOMPILE_BUDGET."""
-    if depth_bucket != DEPTH_CAP:
-        return 0
+    that overflows the rung it ran on goes to the host, counted).  A
+    window of any depth may climb: 30 layers that stray by a sixth on a
+    raw backbone need what 60 of the ONT profile do.  A climber runs in
+    the DEPTH_CAP bucket's program (the caller's rule), so the programs
+    a process can build stay at POA_RECOMPILE_BUDGET."""
     return next((r for r, cap in enumerate(capacities) if est_nodes <= cap),
                 len(capacities) - 1)
 
@@ -798,7 +830,8 @@ class _ConsensusOps:
         retry = _install(self.pipeline, sub, results, self.trim, self.stats,
                          self.fallback, self.report, kind, self.journal,
                          band_states=self.band,
-                         band_cap=ctx.cfg.max_len // 2, force_hit=forced)
+                         band_cap=ctx.cfg.max_len // 2, force_hit=forced,
+                         depth_bucket=ctx.cfg.depth)
         if retry:
             self._band_retry.extend(retry)
 
@@ -1166,7 +1199,7 @@ def _unpack(outs, use_pallas, banded=False, rung: str = NODE_RUNGS[0]):
 
 def _install(pipeline, chunk, results, trim, stats, fallback, report=None,
              tier=None, journal=None, band_states=None, band_cap=0,
-             force_hit=False):
+             force_hit=False, depth_bucket=None):
     san = _sanitize()
     sanitizing = san.enabled()
     if sanitizing:
@@ -1181,7 +1214,7 @@ def _install(pipeline, chunk, results, trim, stats, fallback, report=None,
         cons_base, cons_cov, cons_len, failed = results
         band_hit = None
     nodes = getattr(results, "nodes", None)
-    n_served = nodes_used = 0
+    n_served = nodes_used = backbone_bases = 0
     overflow = dict.fromkeys(poa.FAIL_CAUSES, 0)   # cause -> windows
     retry = []
     for bi, (i, wx, keep) in enumerate(chunk):
@@ -1207,6 +1240,7 @@ def _install(pipeline, chunk, results, trim, stats, fallback, report=None,
             overflow[cause if cause in overflow else poa.FAIL_OTHER] += 1
             continue
         n_served += 1
+        backbone_bases += len(wx.backbone)
         if nodes is not None:
             nodes_used += int(nodes[bi])
         cl = int(cons_len[bi])
@@ -1253,6 +1287,12 @@ def _install(pipeline, chunk, results, trim, stats, fallback, report=None,
     if nodes is not None:
         obs.count("poa.nodes.used", nodes_used)
         obs.count("poa.nodes.capacity", n_served * cons_base.shape[1])
+        obs.count("poa.backbone.bases", backbone_bases)
     for cause, name in poa.FAIL_CAUSES.items():
         obs.count(f"poa.windows.overflow.{name}", overflow[cause])
+    # what the rung rule misjudged: graphs that outgrew the rung the
+    # estimate chose, by the depth bucket they ran in (every bucket's key)
+    for bucket in DEPTH_BUCKETS:
+        obs.count(f"poa.windows.rung.miss.d{bucket}",
+                  overflow[poa.FAIL_NODES] if bucket == depth_bucket else 0)
     return retry

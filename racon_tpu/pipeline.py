@@ -129,11 +129,30 @@ class Pipeline:
         self._lib.rt_pipeline_prepare_counts(self._h, out)
         return int(out[0]), int(out[1]), int(out[2])
 
+    def filter_counts(self) -> Tuple[int, int, int, int]:
+        """(overlaps the error threshold dropped, window layers offered,
+        dropped as under 2 % of a window, dropped by mean quality): what
+        the native filters did so far, in one ABI crossing."""
+        out = (ctypes.c_uint64 * 4)()
+        self._lib.rt_pipeline_filter_counts(self._h, out)
+        return int(out[0]), int(out[1]), int(out[2]), int(out[3])
+
     def _count_prepared(self) -> None:
         targets, parsed, kept = self._prepare_counts()
         obs.count("polish.targets", targets)
         obs.count("overlaps.parsed", parsed)
         obs.count("overlaps.kept", kept)
+
+    def _count_filtered(self) -> None:
+        """What the native filters dropped, once the windows are built
+        (one crossing for both filters; nothing when disarmed)."""
+        if not obs.enabled():
+            return
+        dropped_error, offered, short, quality = self.filter_counts()
+        obs.count("overlaps.dropped.error", dropped_error)
+        obs.count("layers.offered", offered)
+        obs.count("layers.dropped.short", short)
+        obs.count("layers.dropped.quality", quality)
 
     def num_align_jobs(self) -> int:
         return self._lib.rt_pipeline_num_align_jobs(self._h)
@@ -194,6 +213,7 @@ class Pipeline:
             self._lib.rt_pipeline_build_windows(self._h)
             native.check_error(self._lib)
         self._stamp_stages(sp)
+        self._count_filtered()
 
     def initialize(self) -> None:
         with obs.span("native.initialize") as sp:
@@ -201,10 +221,23 @@ class Pipeline:
             native.check_error(self._lib)
         self._stamp_stages(sp)
         self._count_prepared()
+        self._count_filtered()
 
     # -- phase 2 ----------------------------------------------------------
     def num_windows(self) -> int:
         return self._lib.rt_pipeline_num_windows(self._h)
+
+    def window_growth(self) -> np.ndarray:
+        """(windows, 2) uint64 in one ABI crossing: the layer bases each
+        window's alignments put off its backbone (another base, or
+        inserted: what makes a graph grow), and the nodes the host
+        engine's graph held (0 where it did not run)."""
+        out = np.zeros((self.num_windows(), 2), dtype=np.uint64)
+        if len(out):
+            self._lib.rt_pipeline_window_growth(
+                self._h, out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)))
+            native.check_error(self._lib)
+        return out
 
     def window_info(self, i: int) -> Tuple[int, int, int, bool, int, int]:
         out = (ctypes.c_uint64 * 6)()
