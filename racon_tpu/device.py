@@ -45,7 +45,10 @@ _STAGES = {
 _lock = threading.Lock()
 _cache_counts = {"requests": 0, "hits": 0, "misses": 0, "compile_s": 0.0,
                  "trace_s": 0.0, "lower_s": 0.0, "traces": 0,
-                 "lowerings": 0}
+                 "lowerings": 0, "program_hits": 0, "program_misses": 0,
+                 "program_skipped": 0, "program_load_s": 0.0}
+_PROGRAM_OUTCOMES = {"hit": "program_hits", "miss": "program_misses",
+                     "skipped": "program_skipped"}
 _by_fun: dict = {}
 #: a stage shorter than this is counted but gets no span: eager
 #: primitives trace in microseconds, by the thousand under interpret mode
@@ -119,6 +122,19 @@ def _on_stage(event: str, duration_secs: float, fun_name="?",
                          now, fun=fun)
 
 
+def count_program(outcome: str, load_s: float = 0.0) -> None:
+    """One kernel program resolved against the program cache
+    (``ops/kernel_cache.Program``): a ``hit`` (read from disk and
+    deserialized in ``load_s`` seconds), a ``miss`` (traced, lowered and
+    written) or ``skipped`` (``jax.export`` refused it; it runs as a
+    plain ``jax.jit``).  Into the process's totals and, when ``obs`` is
+    armed, the job's counters."""
+    with _lock:
+        _cache_counts[_PROGRAM_OUTCOMES[outcome]] += 1
+        _cache_counts["program_load_s"] += load_s
+    obs.count(f"kernel.program.{outcome}")
+
+
 def named(name: str):
     """Give a kernel's jitted wrapper the stable name its program goes
     by everywhere: the HLO module (``jit_<name>``), the device ops of a
@@ -147,7 +163,11 @@ def cache_traffic() -> dict:
     written), the seconds spent compiling or loading (``compile_s``),
     tracing (``trace_s``, over ``traces`` outermost traces) and lowering
     (``lower_s``, ``lowerings``), and the same seconds by the jitted
-    function's name (``by_fun``; ``n`` = programs lowered).  Process
+    function's name (``by_fun``; ``n`` = programs lowered); kernel
+    programs loaded from the program cache (``program_hits``, in
+    ``program_load_s`` seconds of read + deserialize), derived and
+    written to it (``program_misses``) and refused by ``jax.export``
+    (``program_skipped``).  Process
     lifetime, so work outside any job's tracer (``warm_for_target``) is
     covered."""
     with _lock:

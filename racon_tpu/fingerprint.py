@@ -1,6 +1,6 @@
 """One registry for every cache/journal fingerprint composition.
 
-Three seams in the tree key cached or resumable artifacts on an
+Four seams in the tree key cached or resumable artifacts on an
 identity fingerprint:
 
 * the **journal** header (`resilience/journal.py`) — one polishing
@@ -11,7 +11,10 @@ identity fingerprint:
   under;
 * the **serve job dir** (`serve/session.py` / `serve/scheduler.py`) —
   the per-job artifact namespace whose backend-keyed journal turns a
-  re-submitted job into a resume.
+  re-submitted job into a resume;
+* the **program cache** (`ops/kernel_cache.Program`) — the file name a
+  lowered kernel program is kept under beside the compile cache, so the
+  next process loads it instead of tracing and lowering it again.
 
 They used to compose their keys ad hoc, one per module.  This module is
 now the single authority: the helpers below build the actual keys, and
@@ -36,6 +39,7 @@ importable from anywhere, including before jax initializes.
 
 from __future__ import annotations
 
+import glob
 import hashlib
 import os
 from typing import Dict, Optional, Sequence, Tuple
@@ -99,6 +103,26 @@ SITES = {
             "builder_args": ("args:builder",),
         },
     },
+    "program_cache": {
+        "helper": "program_key",
+        "description": "ops/kernel_cache.Program file name: a lowered "
+                       "program is a pure function of its builder's "
+                       "arguments, its input shapes, the device it was "
+                       "lowered for, the libraries that lowered it and "
+                       "the source of the kernel bodies",
+        "complete": False,
+        "components": {
+            "builder": ("args:builder",),
+            "avals": ("args:avals",),
+            "n_devices": ("topology:n_devices",),
+            "platform": ("topology:platform",),
+            "device_kind": ("topology:device_kind",),
+            "versions": ("const:jax-version", "const:jaxlib-version",
+                         "const:backend-version",
+                         "const:export-calling-convention"),
+            "source": ("const:kernel-source-bytes",),
+        },
+    },
     "serve_job_dir": {
         "helper": "serve_job_paths",
         "description": "serve/session.py per-job artifact namespace: "
@@ -151,6 +175,42 @@ def kernel_cache_key(n_dev: int, platform: str) -> Tuple[int, str]:
     memoized kernel build (the builder's own args are the rest of the
     key — a built kernel is a pure function of both)."""
     return (int(n_dev), str(platform))
+
+
+#: Every module a kernel body can come from, relative to the package.
+KERNEL_SOURCES = ("ops/*.py", "parallel/*.py", "device.py")
+
+
+def kernel_source_digest(package_dir: str) -> str:
+    """sha256 over the bytes of ``KERNEL_SOURCES``, each under its name
+    relative to the package: an edit to a kernel file moves it, the
+    checkout's path and a file's line numbers as such do not."""
+    h = hashlib.sha256()
+    for pattern in KERNEL_SOURCES:
+        for path in sorted(glob.glob(os.path.join(package_dir, pattern))):
+            h.update(os.path.relpath(path, package_dir).encode())
+            h.update(b"\0")
+            with open(path, "rb") as f:
+                h.update(f.read())
+            h.update(b"\0")
+    return h.hexdigest()
+
+
+def program_key(builder, avals, topology, versions, source: str) -> str:
+    """File name (less its suffix) of one lowered program in the program
+    cache.  ``builder`` is the builder's name and arguments, ``avals``
+    the inputs' (shape, dtype) pairs, ``topology`` (device count,
+    platform, device kind), ``versions`` the libraries that lower
+    (jax, jaxlib, the backend's own version string, ``jax.export``'s
+    calling convention) and ``source`` :func:`kernel_source_digest`.
+    Each part goes in by its ``repr``: ints, strings, bools and tuples
+    of them, so the same program gets the same name in every process."""
+    h = hashlib.sha256()
+    for part in (builder, tuple(avals), tuple(topology), tuple(versions),
+                 source):
+        h.update(repr(part).encode())
+        h.update(b"\0")
+    return h.hexdigest()
 
 
 def serve_job_paths(workdir: str, job_id: str,

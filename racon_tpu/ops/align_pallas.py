@@ -69,7 +69,7 @@ from .. import config, native, obs
 from ..device import named
 from . import band as _band
 from .encoding import PACK, encode
-from .kernel_cache import device_keyed_cache
+from .kernel_cache import Program, device_keyed_cache
 
 INF = 1 << 28
 BASE_ROWS = 256          # subproblems at or below this row count run the
@@ -314,12 +314,12 @@ def _build_edge_kernel(rcap: int, K: int, backward: bool,
             out = make(nb)(*_group_scalars(nb, scal, PACK), q, t)
             return out.reshape(nb * G, K)[:b]
 
-        return fn
+        return Program(fn, key=(name, rcap, K, interpret, b))
 
     @functools.lru_cache(maxsize=8)
     def jitted(batch):
         sharded = _shard_over_mesh(plain, batch, 3, 1)
-        return sharded if sharded is not None else jax.jit(plain(batch))
+        return sharded if sharded is not None else plain(batch)
 
     return jitted
 
@@ -466,12 +466,12 @@ def _build_base_kernel(K: int, interpret: bool = False):
             return (ops.reshape(nb * G, OPS)[:b], cnt.reshape(-1)[:b],
                     ok.reshape(-1)[:b], dist.reshape(-1)[:b])
 
-        return fn
+        return Program(fn, key=("racon_hirschberg_base", K, interpret, b))
 
     @functools.lru_cache(maxsize=8)
     def jitted(batch):
         sharded = _shard_over_mesh(plain, batch, 3, 4)
-        return sharded if sharded is not None else jax.jit(plain(batch))
+        return sharded if sharded is not None else plain(batch)
 
     return jitted, OPS, QCAP, TCAP
 

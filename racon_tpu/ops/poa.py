@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from ..device import named
-from .kernel_cache import device_keyed_cache
+from .kernel_cache import Program, device_keyed_cache
 
 NEG = jnp.int32(-(1 << 28))
 KEY_INF = jnp.float32(jnp.inf)
@@ -428,7 +428,8 @@ def _polish_window(cfg: PoaConfig, bb_codes, bb_w, bb_len, n_layers,
 
 @device_keyed_cache(maxsize=32)
 def build_poa_kernel(cfg: PoaConfig):
-    """jit-compiled batch kernel: all inputs have a leading batch dim."""
+    """The batch kernel as a Program: all inputs have a leading batch
+    dim."""
 
     @named("racon_poa_xla")
     def batch_fn(bb_codes, bb_w, bb_len, n_layers, seqs, ws, lens, begins,
@@ -438,4 +439,4 @@ def build_poa_kernel(cfg: PoaConfig):
             _polish_window(cfg, a, b, c, d, e, f, gg, h, i)
         )(bb_codes, bb_w, bb_len, n_layers, seqs, ws, lens, begins, ends)
 
-    return jax.jit(batch_fn)
+    return Program(batch_fn, key=("racon_poa_xla", cfg))

@@ -53,7 +53,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..device import named
-from .kernel_cache import device_keyed_cache
+from .kernel_cache import Program, device_keyed_cache
 from .poa import (FAIL_DISTANCE, FAIL_EDGES, FAIL_NODES, FAIL_OTHER,
                   PoaConfig, first_cause)
 
@@ -1041,6 +1041,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 res = res + (outs[5].reshape(batch, 1),)
             return res
 
-        return jax.jit(fn)
+        return Program(fn, key=("racon_poa_ls", cfg, interpret, band, U,
+                                batch))
 
     return jitted

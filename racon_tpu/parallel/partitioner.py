@@ -49,7 +49,7 @@ import numpy as np
 
 from .. import config
 from ..device import named_like
-from ..ops.kernel_cache import device_keyed_cache
+from ..ops.kernel_cache import Program, body_and_key, device_keyed_cache
 from . import axes
 
 
@@ -181,39 +181,52 @@ class Partitioner:
     # -- kernel wrapping ---------------------------------------------------
 
     def partition(self, fn, in_axes: Sequence, out_axes):
-        """jit ``fn`` with sharding constraints resolved from logical
-        axes — the pjit path for XLA-tier kernels.
+        """``fn`` (a bare function or a single-device Program, taken by
+        its body and key) as a Program with sharding constraints
+        resolved from logical axes — the pjit path for XLA-tier kernels.
 
         ``in_axes`` is one logical-axis tuple per input; ``out_axes`` is
         a single tuple (one output) or a tuple of tuples."""
-        import jax
-
         in_sh = tuple(self.sharding(*a) for a in in_axes)
         if (isinstance(out_axes, (list, tuple)) and out_axes
                 and isinstance(out_axes[0], (list, tuple))):
             out_sh = tuple(self.sharding(*a) for a in out_axes)
         else:
             out_sh = self.sharding(*out_axes)
-        return jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh)
+        body, key = body_and_key(fn)
+        return Program(
+            body, shardings=(in_sh, out_sh),
+            key=key and ("partition", key, self._mesh_key(),
+                         tuple(map(tuple, in_axes)), repr(out_axes)))
+
+    def _mesh_key(self):
+        """The mesh as a program's key carries it: axis names and sizes
+        (which devices fill them is the call's business, not the
+        program's)."""
+        return tuple(self.mesh.shape.items())
 
     def shard_build(self, build_local, batch: int, n_in: int, n_out: int):
         """shard_map wrap of a per-shard kernel *builder* — the Pallas
         path, where each device traces a kernel of the local batch size.
         Every input/output is sharded on the leading ``windows`` dim.
-        Returns None when this batch shouldn't shard (caller keeps its
-        single-device build)."""
+        ``build_local`` returns the per-shard Program (taken by its body
+        and key) or a bare function.  Returns None when this batch
+        shouldn't shard (caller keeps its single-device build)."""
         import jax
 
         m = self.batch_axis_size
         if self._disabled is not None or m <= 1 or batch % m or batch < m:
             return None
-        local = build_local(batch // m)
+        body, key = body_and_key(build_local(batch // m))
         spec = self.spec("windows")
+        rows = self.sharding("windows")
         out_specs = (spec,) * n_out if n_out > 1 else spec
-        return jax.jit(jax.shard_map(
-            named_like(local), mesh=self.mesh,
-            in_specs=(spec,) * n_in, out_specs=out_specs,
-            check_vma=False))
+        return Program(
+            jax.shard_map(named_like(body), mesh=self.mesh, in_specs=(spec,) * n_in,
+                          out_specs=out_specs, check_vma=False),
+            shardings=((rows,) * n_in,
+                       (rows,) * n_out if n_out > 1 else rows),
+            key=key and ("shard_map", key, self._mesh_key(), n_in, n_out))
 
     # -- batch padding (satellite: the one place pad math lives) -----------
 

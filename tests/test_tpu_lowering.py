@@ -389,3 +389,56 @@ def test_sharded_lockstep_program_compiles_for_a_v5e_host(v5e_mesh):
     assert "tpu_custom_call" in text
     assert not [c for c in ("all-gather", "all-reduce", "all-to-all",
                             "collective-permute") if c in text]
+
+
+# -- what the program cache hands XLA ---------------------------------------
+
+def _compile_exported(prog, args, sharding):
+    """A process that found `prog` in the program cache
+    (``ops/kernel_cache.Program``) runs ``jax.jit`` of the deserialized
+    export's ``call`` under the program's shardings, not the body:
+    compile that for the described chips."""
+    exported = jax.export.deserialize(bytearray(jax.export.export(
+        prog._plain, platforms=["tpu"])(
+            *(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args)
+        ).serialize()))
+    specs = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+             for a in args]
+    text = prog.run_exported(exported).lower(*specs).compile().as_text()
+    assert f"HloModule jit_{prog.__name__}" in text
+    assert f"%{prog.__name__}" in text and "tpu_custom_call" in text
+    return exported, text
+
+
+@pytest.mark.parametrize("kernel", ["racon_poa_ls", "edge_fwd", "base"])
+def test_exported_programs_compile_for_v5e(single_device, kernel):
+    sharding = _v5e()
+    if sharding is None:
+        pytest.skip("this libtpu cannot describe a v5e topology")
+    prog, args = {"racon_poa_ls": lambda: _ls(500, 32, TPU_BATCH),
+                  "edge_fwd": lambda: _edge(8192, 1024, False, TPU_BATCH),
+                  "base": lambda: _base(1024, TPU_BATCH)}[kernel]()
+    exported, _ = _compile_exported(prog, args, sharding)
+    assert exported.nr_devices == 1 and prog.shardings is None
+
+
+@pytest.mark.parametrize("kernel", ["racon_poa_ls", "edge_bwd", "base"])
+def test_exported_sharded_programs_compile_for_a_v5e_host(v5e_mesh, kernel):
+    """The four-chip cells' programs as a warm process runs them: the
+    four-device export under the mesh's NamedShardings, one Mosaic
+    kernel per chip and no collective."""
+    if kernel == "racon_poa_ls":
+        cfg = poa_driver.make_config(500, 200, *SCORES)
+        poa_driver._build_kernel_cached.cache_clear()
+        prog = poa_driver._build_kernel_cached(cfg, TPU_BATCH, True, 4, "tpu",
+                                               4, False)
+        args = _poa_args(cfg, TPU_BATCH)
+    else:
+        prog, args = (_base(1024, 16) if kernel == "base"
+                      else _edge(8192, 1024, True, 16))
+    assert prog.key[0] == "shard_map"
+    exported, text = _compile_exported(prog, args,
+                                       v5e_mesh.sharding("windows"))
+    assert exported.nr_devices == 4
+    assert not [c for c in ("all-gather", "all-reduce", "all-to-all",
+                            "collective-permute") if c in text]
