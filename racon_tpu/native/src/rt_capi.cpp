@@ -291,16 +291,18 @@ uint64_t rt_pipeline_num_windows(void* handle) {
   return static_cast<PipelineHandle*>(handle)->pipeline->num_windows();
 }
 
-// Per window, in one crossing: out[2 i] = the layer bases the alignments put
-// off the backbone (Window::stray_bases), out[2 i + 1] = the nodes the host
-// engine's graph held (0 until its consensus ran there).
+// Per window, in one crossing: out[3 i] = the layer bases the alignments put
+// off the backbone (Window::stray_bases), out[3 i + 1] = the nodes the host
+// engine's graph held and out[3 i + 2] = the most in-edges one of them held
+// (both 0 until its consensus ran there).
 void rt_pipeline_window_growth(void* handle, uint64_t* out) {
   guarded_void([&] {
   const auto& p = *static_cast<PipelineHandle*>(handle)->pipeline;
   for (size_t i = 0; i < p.num_windows(); ++i) {
     const bool held = p.has_window(i);  // stitch lets the windows go
-    out[2 * i] = held ? p.window(i).stray_bases : 0;
-    out[2 * i + 1] = held ? p.window(i).graph_nodes : 0;
+    out[3 * i] = held ? p.window(i).stray_bases : 0;
+    out[3 * i + 1] = held ? p.window(i).graph_nodes : 0;
+    out[3 * i + 2] = held ? p.window(i).graph_in_edges : 0;
   }
   });
 }

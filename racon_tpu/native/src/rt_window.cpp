@@ -110,6 +110,11 @@ bool Window::generate_consensus(PoaAligner& aligner, bool trim) {
   std::vector<uint32_t> coverages;
   consensus = graph.generate_consensus(&coverages);
   graph_nodes = graph.num_nodes();
+  graph_in_edges = 0;
+  for (const auto& node : graph.nodes()) {
+    graph_in_edges =
+        std::max(graph_in_edges, static_cast<uint32_t>(node.in_edges.size()));
+  }
 
   if (type == WindowType::kTGS && trim) {
     const uint32_t average_coverage =

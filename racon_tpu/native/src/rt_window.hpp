@@ -37,6 +37,9 @@ struct Window {
   uint64_t stray_bases = 0;
   // Nodes the host engine's graph held when generate_consensus ended.
   uint32_t graph_nodes = 0;
+  // The most in-edges one of its nodes held (the kernels give a node
+  // PoaConfig.max_edges slots).
+  uint32_t graph_in_edges = 0;
 
   Window(uint64_t id_, uint32_t rank_, WindowType type_, const char* backbone,
          uint32_t backbone_length, const char* quality,

@@ -242,6 +242,9 @@ def render(doc: dict, path: str) -> str:
             ("consensus graph capacity by rung",
              ("poa.windows.rung.", "poa.nodes.", "poa.windows.overflow.",
               "poa.layers.", "poa.backbone.")),
+            ("what the depth cap dropped, and the trim rules",
+             ("poa.windows.capped", "poa.windows.trim.",
+              "sanitize.parity.skipped.")),
             ("what the filters and the band ladder did",
              ("overlaps.", "layers.", "align.pairs."))):
         rows = {k: v for k, v in sorted(b["counters"].items())

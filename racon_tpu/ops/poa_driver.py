@@ -49,7 +49,46 @@ from . import poa
 from .batch_exec import BatchExecutor, pipeline_depth as _pipeline_depth
 from .encoding import decode, encode
 
-DEPTH_CAP = 200                    # reference: MAX_DEPTH_PER_WINDOW
+#: The most layers a window packs for the device: the reference's
+#: MAX_DEPTH_PER_WINDOW (cudapolisher.cpp:226).  What the cap means, for
+#: every configuration (benchmark/reference_cap.py recomputes all of it
+#: from a job's files alone; tests/test_cap_cell.py holds the driver to
+#: it):
+#:
+#: (a) Which layers stay.  A window's layers come in the order the host
+#:     engine consumes them: by begin on the backbone, as std::sort
+#:     (libstdc++'s introsort, not stable) leaves the layers in the order
+#:     build_windows added them, the overlaps' order in the file
+#:     (rt_window.cpp on the host path, rt_capi.cpp's export on this one:
+#:     the same call on the same input).  Of the layers that pass the
+#:     length admission (1 to cfg.max_len bases: admit_layers) the device
+#:     path packs the first DEPTH_CAP in that order and drops the rest.
+#:     Ties, the ~200 window-spanning reads that all begin at 0, are the
+#:     introsort permutation's: kept because the golden scenarios were
+#:     measured better under it than under a stable order (rt_window.cpp;
+#:     the λ data is not on this machine, so the measurement could not be
+#:     made again), and recomputable: reference_cap.std_sort_order is the
+#:     algorithm transcribed.  The invariant either way: no kept layer
+#:     begins after a dropped one.
+#: (b) The trim.  A window the device serves is trimmed by the sequences
+#:     it admitted (backbone + packed layers; coverage under
+#:     admitted // 2 goes at both ends: upstream's accelerator rule,
+#:     cudabatch.cpp:139-163,233), a window the host path polishes by its
+#:     full count (window.cpp:125-146).  The two agree wherever nothing
+#:     was dropped.  A window the kernel gives up (poa.FAIL_CAUSES) is
+#:     redone on the host from every layer and trimmed by the full
+#:     count, capped or not, as upstream re-polishes a failed window on
+#:     its CPU path (cudapolisher.cpp:354-378).
+#: (c) The host path (backend "cpu", the benchmark's oracle) takes every
+#:     layer, as upstream's CPU path does.
+#:
+#: Counted once a chunk or a launch: poa.layers.admitted + poa.layers.capped
+#: are the layers the windows offered past the length admission,
+#: poa.layers.capped.bases the dropped layers' bases, poa.windows.capped
+#: the windows that lost a layer, poa.windows.capped.redone those of them
+#: the kernel gave up, poa.windows.trim.admitted / .full the windows
+#: trimmed under each rule.
+DEPTH_CAP = 200
 DEPTH_BUCKETS = (8, 32, DEPTH_CAP)
 
 #: Graph-capacity rungs, smallest first: make_config's `rung` indexes
@@ -297,7 +336,7 @@ def run_consensus_phase(pipeline, *, match: int, mismatch: int, gap: int,
     appended as it is installed, so a crash loses at most the in-flight
     batch.
     """
-    fallback = _HostFallback(pipeline)
+    fallback = _HostFallback(pipeline, trim)
     try:
         return _consensus_phase(pipeline, fallback, match, mismatch, gap,
                                 trim, progress, journal)
@@ -313,7 +352,8 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
                          rl.CONSENSUS_TIERS + ("backbone", "journal"))
     report.total = n
     stats = {"device": 0, "host_fallback": 0, "backbone": 0, "failed": 0,
-             "layers_dropped": 0, "report": report}
+             "layers_dropped": 0, "layers_capped": 0, "windows_capped": 0,
+             "report": report}
     # Runtime-sanitizer guard (no-op passthrough when unarmed): flags
     # stats mutations from any thread but this driver thread.
     stats = _sanitize().guard_stats(stats, "poa_driver.run_consensus_phase")
@@ -490,6 +530,9 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
     # change, ADVICE.md): attributes serving-mix shifts on mixed-length
     # datasets
     report.extra["layers_dropped_maxlen"] = stats["layers_dropped"]
+    # what DEPTH_CAP dropped from the windows the device path packed
+    report.extra["capped_windows"] = stats["windows_capped"]
+    report.extra["capped_layers"] = stats["layers_capped"]
     return stats
 
 
@@ -503,10 +546,13 @@ class _HostFallback:
     of arrival.  `join` waits for the pool once, then writes what the
     serial loop wrote per window, on the calling thread and in that
     order: the journal's "host" record and stats["host_fallback"].  An
-    empty fallback makes no native call."""
+    empty fallback makes no native call.  `trim`: the job trims its
+    consensus, so where its windows are long reads' every window polished
+    here is trimmed by its full count (DEPTH_CAP's rule (b))."""
 
-    def __init__(self, pipeline):
+    def __init__(self, pipeline, trim: bool = False):
         self._pipeline = pipeline
+        self._trim = trim
         self._order: List[int] = []
 
     def __len__(self) -> int:
@@ -525,12 +571,17 @@ class _HostFallback:
         write their records; returns the windows in arrival order."""
         order, pipeline = self._order, self._pipeline
         if not order:
+            # the key at every job, a zero too, beside .trim.admitted
+            obs.count("poa.windows.trim.full", 0)
             return order
         polished, hidden = pipeline.consensus_cpu_join(order)
         # how often the overlap engaged: host work that had ended before
         # the driver came to wait for it, and the rest
         obs.count("poa.fallback.hidden", hidden)
         obs.count("poa.fallback.exposed", len(order) - hidden)
+        # a job's windows are of one type (rt_pipeline.cpp)
+        trims = self._trim and pipeline.window_info(order[0])[3]
+        obs.count("poa.windows.trim.full", sum(polished) if trims else 0)
         for i, was_polished in zip(order, polished):
             if journal is not None:
                 _, _, rank, _, _, tid = pipeline.window_info(i)
@@ -1023,6 +1074,13 @@ def _build_kernel_cached(cfg, B, use_pallas, n_dev, platform, shard_n=1,
         kernel, in_axes=[("windows",)] * 9, out_axes=("windows",))
 
 
+def admit_layers(lens, max_len: int) -> list:
+    """Indices of the layers the device path can pack, in the export's
+    order: 1 to `max_len` bases (the window class's geometry).  The
+    first DEPTH_CAP of them are packed (_export_chunk)."""
+    return [j for j in range(len(lens)) if 0 < lens[j] <= max_len]
+
+
 def _export_chunk(pipeline, idxs, cfg, fallback, stats=None, report=None):
     """Export window bases for one chunk; apply per-layer admission.
 
@@ -1031,9 +1089,10 @@ def _export_chunk(pipeline, idxs, cfg, fallback, stats=None, report=None):
     failure (the `window.export` seam) quarantines just that window.
     """
     chunk = []
-    # this chunk's admitted layers that DEPTH_CAP dropped, and the bases
-    # of the layers that are packed
-    capped = bases = 0
+    # this chunk's admitted layers that DEPTH_CAP dropped, their bases,
+    # the windows that lost one, and the bases of the layers that are
+    # packed
+    capped = capped_bases = capped_windows = bases = 0
     obs.count("native.calls.export_window", len(idxs))
     for i in idxs:
         try:
@@ -1043,8 +1102,7 @@ def _export_chunk(pipeline, idxs, cfg, fallback, stats=None, report=None):
             if report is not None:
                 report.record_quarantine(i, e)
             continue
-        k = len(wx.lens)
-        keep = [j for j in range(k) if 0 < wx.lens[j] <= cfg.max_len]
+        keep = admit_layers(wx.lens, cfg.max_len)
         # Per-class geometry admission (ADVICE.md): a layer longer than
         # THIS class's max_len is dropped here where the old dataset-max
         # geometry admitted it; counted (report.extra) so serving-mix
@@ -1055,12 +1113,23 @@ def _export_chunk(pipeline, idxs, cfg, fallback, stats=None, report=None):
         if len(keep) < len(wx.lens[:DEPTH_CAP]) and len(keep) < 2:
             fallback.append(i)
             continue
-        capped += max(0, len(keep) - DEPTH_CAP)
-        keep = keep[:DEPTH_CAP]
+        if len(keep) > DEPTH_CAP:
+            # DEPTH_CAP's rule (a): the export's order is the host
+            # engine's, so the first DEPTH_CAP are the ones that stay
+            keep, dropped = keep[:DEPTH_CAP], keep[DEPTH_CAP:]
+            wx.capped = len(dropped)
+            capped += len(dropped)
+            capped_bases += int(wx.lens[dropped].sum())
+            capped_windows += 1
         bases += int(wx.lens[keep].sum())
         chunk.append((i, wx, keep))
     obs.count("poa.layers.capped", capped)
+    obs.count("poa.layers.capped.bases", capped_bases)
+    obs.count("poa.windows.capped", capped_windows)
     obs.count("poa.layers.bases", bases)
+    if stats is not None:
+        stats["layers_capped"] += capped
+        stats["windows_capped"] += capped_windows
     return chunk
 
 
@@ -1215,6 +1284,9 @@ def _install(pipeline, chunk, results, trim, stats, fallback, report=None,
         band_hit = None
     nodes = getattr(results, "nodes", None)
     n_served = nodes_used = backbone_bases = 0
+    # DEPTH_CAP's counters: windows trimmed by the admitted count, capped
+    # windows the kernel gave up, parity samples skipped for the cap
+    n_trimmed = capped_redone = parity_skipped = 0
     overflow = dict.fromkeys(poa.FAIL_CAUSES, 0)   # cause -> windows
     retry = []
     for bi, (i, wx, keep) in enumerate(chunk):
@@ -1238,6 +1310,8 @@ def _install(pipeline, chunk, results, trim, stats, fallback, report=None,
             stats["failed"] += 1
             cause = int(failed[bi])
             overflow[cause if cause in overflow else poa.FAIL_OTHER] += 1
+            # the host redoes it from every layer, the dropped ones too
+            capped_redone += bool(wx.capped)
             continue
         n_served += 1
         backbone_bases += len(wx.backbone)
@@ -1248,19 +1322,22 @@ def _install(pipeline, chunk, results, trim, stats, fallback, report=None,
         cov = cons_cov[bi, :cl]
         out = np.asarray(codes)
         if wx.is_tgs and trim:
-            # Threshold on the ADMITTED sequence count (backbone + the
-            # layers this driver actually packed), mirroring the
-            # reference accelerator's seqs_added_per_window_ rule — it
-            # counts only sequences successfully added to the GPU group
+            # DEPTH_CAP's rule (b).  Threshold on the ADMITTED sequence
+            # count (backbone + the layers this driver actually packed),
+            # mirroring the reference accelerator's
+            # seqs_added_per_window_ rule — it counts only sequences
+            # successfully added to the GPU group
             # (src/cuda/cudabatch.cpp:139-163,233), not the window's full
             # layer count. Device coverage can only ever reach the
             # admitted count, so a full-window threshold (the CPU rule,
             # src/window.cpp:125-146) would over-trim between DEPTH_CAP
             # and 2*DEPTH_CAP layers and silently never trim above
             # 2*DEPTH_CAP. Host parity therefore holds exactly where the
-            # two counts coincide: depth <= DEPTH_CAP.
+            # two counts coincide: no layer dropped, by the cap or for
+            # its length.
             n_admitted_seqs = len(keep) + 1
             kept_codes = tgs_trim(out, np.asarray(cov), n_admitted_seqs)
+            n_trimmed += 1
         else:
             kept_codes = out
         payload = decode(kept_codes)
@@ -1275,6 +1352,8 @@ def _install(pipeline, chunk, results, trim, stats, fallback, report=None,
                 pipeline.consensus_cpu_one(i)
                 san.check_parity(payload, pipeline.get_consensus(i), i,
                                  where=f"poa._install[{tier or 'device'}]")
+            else:
+                parity_skipped += bool(wx.capped)
         pipeline.set_consensus(i, payload, True)
         if journal is not None:
             journal.append_window(i, wx.target_id, wx.rank,
@@ -1290,6 +1369,10 @@ def _install(pipeline, chunk, results, trim, stats, fallback, report=None,
         obs.count("poa.backbone.bases", backbone_bases)
     for cause, name in poa.FAIL_CAUSES.items():
         obs.count(f"poa.windows.overflow.{name}", overflow[cause])
+    obs.count("poa.windows.trim.admitted", n_trimmed)
+    obs.count("poa.windows.capped.redone", capped_redone)
+    if sanitizing:
+        obs.count("sanitize.parity.skipped.capped", parity_skipped)
     # what the rung rule misjudged: graphs that outgrew the rung the
     # estimate chose, by the depth bucket they ran in (every bucket's key)
     for bucket in DEPTH_BUCKETS:

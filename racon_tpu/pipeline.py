@@ -48,6 +48,9 @@ class WindowExport:
     ends: np.ndarray           # uint32 [K] (inclusive backbone positions)
     bases: np.ndarray          # uint8 concatenated layer bases
     weights: np.ndarray        # uint8 concatenated layer weights
+    # admitted layers the consensus driver's depth cap dropped when it
+    # packed this export (poa_driver._export_chunk sets it)
+    capped: int = 0
 
 
 class Pipeline:
@@ -228,11 +231,12 @@ class Pipeline:
         return self._lib.rt_pipeline_num_windows(self._h)
 
     def window_growth(self) -> np.ndarray:
-        """(windows, 2) uint64 in one ABI crossing: the layer bases each
+        """(windows, 3) uint64 in one ABI crossing: the layer bases each
         window's alignments put off its backbone (another base, or
-        inserted: what makes a graph grow), and the nodes the host
-        engine's graph held (0 where it did not run)."""
-        out = np.zeros((self.num_windows(), 2), dtype=np.uint64)
+        inserted: what makes a graph grow), the nodes the host engine's
+        graph held and the most in-edges one of them held (both 0 where
+        it did not run)."""
+        out = np.zeros((self.num_windows(), 3), dtype=np.uint64)
         if len(out):
             self._lib.rt_pipeline_window_growth(
                 self._h, out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)))

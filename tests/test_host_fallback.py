@@ -194,7 +194,7 @@ class SerialFallback(list):
     """The driver's fallback as it was before ISSUE 25: a plain list,
     walked one window at a time by the driver thread after the flush."""
 
-    def __init__(self, pipeline):
+    def __init__(self, pipeline, trim=False):
         super().__init__()
         self._pipeline = pipeline
 
