@@ -174,8 +174,10 @@ def audit_poa(window_lengths: Optional[Sequence[int]] = None,
                 f"{type(e).__name__}: {e}"))
             continue
         # a node rung changes no input shape and is a program of its own
-        # all the same: max_nodes rides the signature
-        signatures.add((cfg.max_nodes,) + _signature(closed.in_avals))
+        # all the same, and so is each width the lockstep kernel runs
+        # the geometry at: max_nodes and the width ride the signature
+        signatures |= {(cfg.max_nodes, width) + _signature(closed.in_avals)
+                       for width in poa_driver.audit_widths(cfg)}
         out.extend(check_jaxpr(closed, _POA_PATH, label))
     budget = poa_driver.POA_RECOMPILE_BUDGET
     if len(signatures) > budget:

@@ -238,7 +238,7 @@ def render(doc: dict, path: str) -> str:
     for title, prefixes in (
             ("alignment launches over the mesh", ("align.mesh.",)),
             ("consensus programs in lock-step",
-             ("poa.programs.", "poa.lockstep.")),
+             ("poa.programs.", "poa.lockstep.", "poa.width.")),
             ("consensus graph capacity by rung",
              ("poa.windows.rung.", "poa.nodes.", "poa.windows.overflow.",
               "poa.layers.", "poa.backbone.")),

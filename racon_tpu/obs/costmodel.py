@@ -49,7 +49,7 @@ DEPTH_BUCKETS = (8, 32, 200)
 ALIGN_BUCKETS = ((1024, 256), (2048, 512), (4096, 1024), (8192, 2048))
 #: poa_pallas_ls.G — windows per sublane group of a lane-lockstep
 #: program (amortizes the serial rank loop across G windows).  A program
-#: runs one or two groups (poa_driver._group_width); this model does not
+#: runs one, two or four groups (poa_driver._group_width); this model does not
 #: follow the width, its serial term stays that of one group.
 LS_GROUP = 8
 #: poa_driver.AUDIT_WINDOW_LENGTHS — the window lengths the grid is

@@ -145,22 +145,32 @@ def _sanitize():
 AUDIT_WINDOW_LENGTHS = (500, 1000)
 
 #: Declared compile budget for the audited POA grid (audit_grid): one
-#: program per (depth bucket, window class) on the base rung —
+#: geometry per (depth bucket, window class) on the base rung —
 #: len(DEPTH_BUCKETS) x len(AUDIT_WINDOW_LENGTHS) = 6 — plus one per
-#: window class on the upper rung: 6 + 2 = 8.  Revisited on purpose for
-#: the node rungs (PR 35) and again when windows of every depth were let
-#: climb (PR 41): a climber of at most 32 layers runs in the DEPTH_CAP
-#: bucket's upper-rung program, padded in depth, so the upper rung still
-#: has one program a window class.  The two more are built by the first
-#: job that needs them, not by every process's warm-up, so a process
-#: that never sees such a window still builds 3 per window class.  A
+#: window class on the upper rung: 6 + 2 = 8 — and a program for each
+#: width a geometry's launches choose between (audit_widths): two, of
+#: thirty-two windows and of sixteen, for the four geometries of class
+#: 512; one for those of class 1024, where VMEM holds no more than
+#: sixteen and the upper rung is the XLA twin's: 4 x 2 + 4 = 12.
+#: Revisited on purpose for the node rungs (PR 35), again when windows
+#: of every depth were let climb (PR 41): a climber of at most 32 layers
+#: runs in the DEPTH_CAP bucket's upper-rung program, padded in depth,
+#: so the upper rung still has one geometry a window class; and for the
+#: program of thirty-two (PR 44, 8 -> 12): a launch whose last such
+#: program would be half pad or more runs as programs of sixteen
+#: (_group_width), so a geometry holds both, built together wherever
+#: the geometry is built.  What let the second one in: since PR 42 a
+#: program a process does not trace costs its start ~15 ms.  The upper
+#: rung's are built by the first job that needs them, not by every
+#: process's warm-up, so a process that never sees such a window
+#: builds 3 geometries per window class.  A
 #: deliberate literal, not a product: widening DEPTH_BUCKETS, the
-#: audited window set, the rungs or any geometry change that splits
-#: signatures must consciously revisit this number or the jaxpr audit
-#: (racon_tpu/analysis) fails tier-1 —
+#: audited window set, the rungs, GROUP_WIDTHS or any geometry change
+#: that splits signatures must consciously revisit this number or the
+#: jaxpr audit (racon_tpu/analysis) fails tier-1 —
 #: silent recompile blow-ups are the single biggest TPU serving-latency
 #: cliff.
-POA_RECOMPILE_BUDGET = 8
+POA_RECOMPILE_BUDGET = 12
 
 
 def audit_grid(window_lengths=AUDIT_WINDOW_LENGTHS) -> list:
@@ -175,12 +185,25 @@ def audit_grid(window_lengths=AUDIT_WINDOW_LENGTHS) -> list:
     return grid
 
 
+#: windows a batch on a TPU (_batch_size); audit_widths derives the
+#: lockstep programs of a geometry at it
+TPU_BATCH = 64
+
+
+def audit_widths(cfg) -> tuple:
+    """Group widths of the programs a process can build for one geometry
+    of audit_grid at a TPU's batch on one chip: the lockstep kernel's
+    (_group_widths), or 0 alone, the XLA twin's one program, where the
+    lockstep kernel does not admit the geometry."""
+    return _group_widths(cfg, TPU_BATCH) if _fits_vmem(cfg) else (0,)
+
+
 def _batch_size() -> int:
     env = config.get_raw("RACON_TPU_BATCH_WINDOWS")
     if env:
         return max(1, int(env))
     import jax
-    return 64 if jax.devices()[0].platform == "tpu" else 4
+    return TPU_BATCH if jax.devices()[0].platform == "tpu" else 4
 
 
 def _band_active(kind: str) -> bool:
@@ -220,7 +243,8 @@ def _device_batch(use_pallas: bool) -> int:
     kernel additionally needs the per-shard batch to be a multiple of
     its sublane group G (the XLA twin takes any batch).  The batch
     does not follow the kernel's group width, the width follows the
-    batch (_group_width): 64 windows, or 16 a shard, run as programs of
+    batch and what a launch holds of it (_group_width): 64 windows run
+    as programs of thirty-two or sixteen, 16 a shard as programs of
     sixteen; a batch of 8 somebody asked for stays 8."""
     B = _batch_size()
     m = _shard_n(B)
@@ -653,7 +677,8 @@ def warm_geometries(window_lengths, match: int, mismatch: int,
     iterable of observed backbone lengths — each maps to its 128-grid
     class, exactly as run_consensus_phase buckets them).
 
-    One all-padding batch per (depth bucket, class) runs in milliseconds
+    One all-padding batch per program of a (depth bucket, class), so one
+    a width of the lockstep kernel (_group_widths), runs in milliseconds
     but forces the full compile — so a benchmark's measured pass never
     pays compile time, whatever depth/length mix the real dataset
     produces. Tiers that fail here are recorded in _WARM_DEAD so the
@@ -675,8 +700,10 @@ def warm_geometries(window_lengths, match: int, mismatch: int,
                 faults.check(f"poa.run.{kind}", ())
                 pallas = kind == "ls"
                 banded = _band_active(kind)
-                _unpack(_submit(kernel, _pack([], cfg, B), pallas,
-                                banded), pallas, banded)
+                packed = _pack([], cfg, B)
+                for program in _programs(kernel):
+                    _unpack(_submit(program, packed, pallas, banded),
+                            pallas, banded)
                 break
             except Exception as e:  # noqa: BLE001 — same degrade
                 # philosophy as run_consensus_phase: a Mosaic failure
@@ -838,29 +865,30 @@ class _ConsensusOps:
         # windows are 1-base/0-layer — free).
         return _pack(chunk, ctx.cfg, self.B, self._widths(chunk, ctx.cfg))
 
-    def _groups(self, ctx, kind):
-        """Group width of the kernel the last live_tier built (which
-        keyed on the same partitioner state shard_multiple reads)."""
-        if kind != "ls":
-            return 0
-        return _group_width(ctx.cfg,
-                            self.B // self.shard_multiple(ctx, None))
+    def _launch(self, ctx, kind, packed, n_real):
+        """Count and dispatch one packed batch of `n_real` windows.  A
+        lockstep launch runs the program of the width its real rows
+        call for (_group_width), out of the kernel the last live_tier
+        built (which keyed on the same partitioner state
+        shard_multiple reads)."""
+        kernel, groups = ctx.kernel, 0
+        if kind == "ls":
+            groups = _group_width(
+                ctx.cfg, self.B // self.shard_multiple(ctx, None), n_real)
+            kernel = kernel.programs[groups]
+        _count_launch(n_real, packed, groups, ctx.rung)
+        return _submit(kernel, packed, kind == "ls", _band_active(kind),
+                       ctx.rung)
 
     def dispatch(self, ctx, kind, packed, chunk):
         faults.check(f"poa.run.{kind}", [i for i, _, _ in chunk])
-        _count_launch(len(chunk), packed, self._groups(ctx, kind),
-                      ctx.rung)
-        return _submit(ctx.kernel, packed, kind == "ls",
-                       _band_active(kind), ctx.rung)
+        return self._launch(ctx, kind, packed, len(chunk))
 
     def attempt(self, ctx, kind, sub):
-        pallas = kind == "ls"
-        banded = _band_active(kind)
         faults.check(f"poa.run.{kind}", [i for i, _, _ in sub])
         packed = _pack(sub, ctx.cfg, self.B, self._widths(sub, ctx.cfg))
-        _count_launch(len(sub), packed, self._groups(ctx, kind), ctx.rung)
-        return _unpack(_submit(ctx.kernel, packed, pallas, banded,
-                               ctx.rung), pallas, banded, ctx.rung)
+        return _unpack(self._launch(ctx, kind, packed, len(sub)),
+                       kind == "ls", _band_active(kind), ctx.rung)
 
     def unpack(self, ctx, kind, outs):
         return _unpack(outs, kind == "ls", _band_active(kind), ctx.rung)
@@ -947,7 +975,7 @@ def _platform() -> str:
 
 
 #: sublane groups a lockstep program may run, widest first
-GROUP_WIDTHS = (2, 1)
+GROUP_WIDTHS = (4, 2, 1)
 
 
 def _fits_vmem(cfg, groups: int = 1) -> bool:
@@ -961,10 +989,11 @@ def _fits_vmem(cfg, groups: int = 1) -> bool:
     included, and 1152 / 1280 enter at the XLA twin
     (tests/test_pallas_ls.py holds the table, tests/test_tpu_lowering.py
     compiles its last row).  A wider program admits no class that one
-    group does not; past class 512 its sum outgrows the default limit
-    and it is compiled under one sized from the sum
-    (poa_pallas_ls.vmem_limit_bytes), which may not pass half the
-    chip's VMEM."""
+    group does not; where its sum outgrows the default limit (sixteen
+    windows past class 512, thirty-two past class 128) it is compiled
+    under one sized from the sum (poa_pallas_ls.vmem_limit_bytes), which
+    may not pass half the chip's VMEM: thirty-two windows fit up to
+    class 768 on the base rung and class 512 on the upper one."""
     from . import poa_pallas_ls as ls
 
     limit = ls.vmem_limit_bytes(cfg, groups)
@@ -972,16 +1001,65 @@ def _fits_vmem(cfg, groups: int = 1) -> bool:
             and (limit is None or limit <= ls.VMEM_CEILING))
 
 
-def _group_width(cfg, shard_batch: int) -> int:
-    """Sublane groups U a lockstep program runs, so U x 8 windows under
-    one control flow: the widest the per-shard batch divides into and
-    VMEM holds.  A function of the window class, the per-shard batch
-    and the VMEM sum, nothing else: 64 windows on one chip and 16 a
-    shard on four both give 2; a batch of 8 gives 1."""
+def _group_widths(cfg, shard_batch: int) -> tuple:
+    """The widths a launch of this geometry chooses between
+    (_group_width), widest first, so the lockstep programs the geometry
+    holds: the widest of GROUP_WIDTHS the per-shard batch divides into
+    and VMEM holds and, under a program of thirty-two, the program of
+    sixteen.  A function of the window class, the per-shard batch and
+    the VMEM sum, nothing else: 64 windows on one chip give (4, 2), 16
+    a shard on four chips (2,), a batch of 8 (1,)."""
     from .poa_pallas_ls import G
 
-    return next((u for u in GROUP_WIDTHS
-                 if shard_batch % (u * G) == 0 and _fits_vmem(cfg, u)), 1)
+    fits = [u for u in GROUP_WIDTHS
+            if shard_batch % (u * G) == 0 and _fits_vmem(cfg, u)] or [1]
+    return tuple(fits[:2] if fits[0] > 2 else fits[:1])
+
+
+def _group_width(cfg, shard_batch: int, real_rows=None) -> int:
+    """Sublane groups U the lockstep programs of one launch run, so
+    U x 8 windows under one control flow.  A program costs the same
+    whether its groups hold windows or pad rows (insertion alone is
+    gated per group), and a program of thirty-two never costs more than
+    two of sixteen, so a launch runs at the geometry's widest width
+    (_group_widths) where the last such program of its fullest shard
+    would be more than half real, and at the next width down where it
+    would not (46 real rows: two programs of thirty-two cost what three
+    of sixteen do; 8 real rows: one of thirty-two costs half as much
+    again as one of sixteen).  `real_rows` is what the launch holds
+    (rows are packed real first, so the first shard is the fullest);
+    None or 0, a batch of pad rows alone, gives the widest.  A geometry
+    whose widest program is sixteen windows or eight runs every launch
+    at it, as before there was a wider one."""
+    from .poa_pallas_ls import G
+
+    widths = _group_widths(cfg, shard_batch)
+    last = min(real_rows or 0, shard_batch) % (widths[0] * G)
+    if len(widths) == 1 or last == 0 or 2 * last > widths[0] * G:
+        return widths[0]
+    return widths[1]
+
+
+class _LockstepPrograms:
+    """The lockstep kernel of one geometry: a program a width its
+    launches may run at (_group_widths), all built with the geometry.
+    Called, it runs the widest, which is what a full batch runs as."""
+
+    __slots__ = ("programs",)
+
+    def __init__(self, programs: dict):
+        self.programs = programs
+
+    def __call__(self, *args):
+        return self.programs[max(self.programs)](*args)
+
+
+def _programs(kernel) -> list:
+    """Every program behind a kernel handle: the lockstep kernel's one
+    a width, the XLA twin itself."""
+    if isinstance(kernel, _LockstepPrograms):
+        return list(kernel.programs.values())
+    return [kernel]
 
 
 def _build_kernel(cfg, B, use_pallas):
@@ -1033,7 +1111,9 @@ def _build_kernel(cfg, B, use_pallas):
 @functools.lru_cache(maxsize=64)
 def _build_kernel_cached(cfg, B, use_pallas, n_dev, platform, shard_n=1,
                          banded=False):
-    """Single- or multi-device kernel for a B-window batch.
+    """Single- or multi-device kernel for a B-window batch: the XLA
+    twin's one program, or the lockstep kernel's one a width
+    (_LockstepPrograms).
 
     shard_n > 1: batch dim sharded over the partitioner's mesh (the
     production analogue of the reference's multi-GPU batch striping,
@@ -1054,18 +1134,22 @@ def _build_kernel_cached(cfg, B, use_pallas, n_dev, platform, shard_n=1,
         from .poa_pallas_ls import build_lockstep_poa_kernel
         interp = platform != "tpu"
 
-        def build(b):
-            return build_lockstep_poa_kernel(
-                cfg, interpret=interp, band=banded,
-                groups=_group_width(cfg, b))(b)
+        def program(groups):
+            def build(b):
+                return build_lockstep_poa_kernel(
+                    cfg, interpret=interp, band=banded, groups=groups)(b)
 
-        if shard_n <= 1:
-            return build(B)
-        from ..parallel.partitioner import get_partitioner
-        n_in, n_out = (10, 6) if banded else (9, 5)
-        sharded = get_partitioner().shard_build(build, B, n_in, n_out)
-        assert sharded is not None, (B, shard_n)  # _device_batch divides B
-        return sharded
+            if shard_n <= 1:
+                return build(B)
+            from ..parallel.partitioner import get_partitioner
+            n_in, n_out = (10, 6) if banded else (9, 5)
+            sharded = get_partitioner().shard_build(build, B, n_in, n_out)
+            # _device_batch divides B
+            assert sharded is not None, (B, shard_n)
+            return sharded
+
+        return _LockstepPrograms(
+            {u: program(u) for u in _group_widths(cfg, B // shard_n)})
     kernel = poa.build_poa_kernel(cfg)
     if shard_n <= 1:
         return kernel
@@ -1193,9 +1277,11 @@ def _count_launch(n_real, packed, groups: int = 0,
     group width for this launch (0: the XLA twin serves, which has no
     grid programs): its programs count as wide or narrow, both keys at
     every launch so that a job served by narrow programs alone reads
-    0 % wide and not nothing, and lock-step is billed what it costs:
-    every window of a program runs the program's largest layer
-    count."""
+    0 % wide and not nothing, its real windows count under the one
+    width that ran them (every width's key at every launch, for the
+    same reason; they sum to poa.rows.real over the lockstep launches),
+    and lock-step is billed what it costs: every window of a program
+    runs the program's largest layer count."""
     from .poa_pallas_ls import G
 
     rows = len(packed[0])
@@ -1211,6 +1297,8 @@ def _count_launch(n_real, packed, groups: int = 0,
     obs.count("poa.programs.wide", programs if groups > 1 else 0)
     obs.count("poa.programs.narrow", programs if groups == 1 else 0)
     if width:
+        for u in GROUP_WIDTHS:
+            obs.count(f"poa.width.windows.u{u}", n_real if u == groups else 0)
         # a shard's rows are contiguous and a multiple of the program's
         # width, so programs are consecutive runs of the packed rows
         obs.count("poa.lockstep.layers.real", int(n_layers.sum()))

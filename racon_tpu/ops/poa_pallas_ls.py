@@ -8,9 +8,12 @@ rolls, a masked reduce, a read-modify-write; ~700-1000 cycles a rank on
 the v5e whether a row holds 4 vregs or 7), so one window per program
 leaves the VPU idle. One grid program therefore runs U x 8 windows in
 lock-step under ONE control flow: one window per sublane, U sublane
-groups (U = `groups`: 2 where the per-shard batch and VMEM allow, which
-is every geometry the benchmark's cells run; the driver derives it, see
-poa_driver._group_width):
+groups (U = `groups`: 4, 2 or 1; the driver derives it launch by launch
+from the per-shard batch, the VMEM sum and the rows the launch really
+holds, see poa_driver._group_width: 4 wherever a batch of 64 is more
+than half a program of thirty-two past its last full one and VMEM holds
+the geometry, which is every geometry the benchmark's one-chip cells
+run; 2 at 16 rows a shard):
 
   * j-rows: (U, JC, 8, 128) — window u*8 + g of the program in group u,
     sublane g, DP column j at [u, j // 128, g, j % 128]. Every row op
@@ -99,11 +102,12 @@ VMEM_CEILING = 64 << 20
 def vmem_limit_bytes(cfg: PoaConfig, groups: int):
     """The scoped-VMEM limit a program is compiled under: None, the
     compiler's default, wherever the arrays' sum is one the default is
-    known to hold (every program of eight the driver admits, and the
-    program of sixteen up to class 512: 10.85 MiB).  The default is not
-    the chip's VMEM, so a wider program of a larger class asks for
-    twice its own sum: the arrays, and as much again for Mosaic's
-    temporaries, which took 42 % of the sum at one group."""
+    known to hold (every program of eight the driver admits, the
+    program of sixteen up to class 512: 10.85 MiB, the program of
+    thirty-two at class 128: 8.06 MiB).  The default is not the chip's
+    VMEM, so a wider program of a larger class asks for twice its own
+    sum: the arrays, and as much again for Mosaic's temporaries, which
+    took 42 % of the sum at one group."""
     total = scratch_bytes(cfg, groups)
     if total < DEFAULT_LIMIT_HOLDS:
         return None

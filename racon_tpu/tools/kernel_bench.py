@@ -77,7 +77,7 @@ def main():
     platform = jax.devices()[0].platform
     cfg = poa_driver.make_config(500, depth, 5, -4, -8)
     interp = platform != "tpu"
-    # the program width the driver would ship this batch at
+    # the program width the driver would ship this batch at when full
     fn = poa_pallas_ls.build_lockstep_poa_kernel(
         cfg, interpret=interp, groups=poa_driver._group_width(cfg, B))(B)
 

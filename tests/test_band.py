@@ -283,14 +283,14 @@ def _poa_batch(cfg, B, seed, roll=0):
     return (bb, bbw, bl, nl, seqs, ws, lens, bg, en)
 
 
-@pytest.mark.parametrize("groups", [1, 2], ids=["u1", "u2"])
+@pytest.mark.parametrize("groups", [1, 2, 4], ids=["u1", "u2", "u4"])
 def test_poa_banded_kernel_byte_identity(groups):
     """The banded POA build: wband=0 reproduces the flat kernel
     byte-for-byte (the ladder's floor runs through the same compiled
     build), a generous band matches the flat oracle with no hit, and a
     pathologically narrow band on drifted layers raises band_hit; in
-    programs of eight and of sixteen (wband and band_hit carry the
-    group axis with the rest)."""
+    programs of eight, sixteen and thirty-two (wband and band_hit carry
+    the group axis with the rest)."""
     from racon_tpu.ops import poa, poa_driver
     from racon_tpu.ops.poa_pallas_ls import build_lockstep_poa_kernel as build
 

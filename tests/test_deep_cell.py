@@ -73,7 +73,7 @@ def _twin(a, cfg):
 @pytest.fixture(scope="module")
 def deep_windows():
     """Eight seeded windows of 128 bp, 16 to 100 layers, through ``ls``
-    on the upper rung at one and two sublane groups, through the XLA
+    on the upper rung at one, two and four sublane groups, through the XLA
     twin on the upper rung, and through ``ls`` on the base rung."""
     rng = random.Random(35)
     cases, per_rung = [], {}
@@ -85,11 +85,15 @@ def deep_windows():
         cases.append((truth, layers))
         for a in per_rung.values():
             _set_window(a, b, truth, layers)
-    wide, pos = _deal(per_rung[UPPER], UPPER, 2)
+    def dealt(groups):
+        wide, pos = _deal(per_rung[UPPER], UPPER, groups)
+        return tuple(x[pos] for x in _run_ls(wide, UPPER, groups))
+
     return {
         "cases": cases,
         "upper_u1": _run_ls(per_rung[UPPER], UPPER, 1),
-        "upper_u2": tuple(x[pos] for x in _run_ls(wide, UPPER, 2)),
+        "upper_u2": dealt(2),
+        "upper_u4": dealt(4),
         "upper_twin": _twin(per_rung[UPPER], UPPER),
         "base_u1": _run_ls(per_rung[BASE], BASE, 1),
     }
@@ -128,9 +132,14 @@ def test_ls_on_the_upper_rung_against_the_host_engine(deep_windows):
     assert left["device"] <= left["host"] + 2, left
 
 
-def test_one_and_two_sublane_groups_agree_on_the_upper_rung(deep_windows):
-    for one, two in zip(deep_windows["upper_u1"], deep_windows["upper_u2"]):
-        np.testing.assert_array_equal(one, two)
+@pytest.mark.parametrize("groups", [2, 4], ids=["u2", "u4"])
+def test_one_and_two_sublane_groups_agree_on_the_upper_rung(deep_windows,
+                                                            groups):
+    """And one and four: the eight windows dealt over the groups of one
+    program, pad windows in the other slots."""
+    for one, wide in zip(deep_windows["upper_u1"],
+                         deep_windows[f"upper_u{groups}"]):
+        np.testing.assert_array_equal(one, wide)
 
 
 def test_a_window_that_overflows_the_base_rung_fits_the_upper_one(
@@ -203,15 +212,21 @@ def test_the_kernels_name_the_same_cause():
 
 # -- (b) the capacity table -------------------------------------------------
 
-@pytest.mark.parametrize("wl_class,base,upper,ls_climbs", [
-    (128, 384, 640, True), (256, 768, 1280, True), (384, 1152, 1920, True),
-    (512, 1536, 2560, True), (640, 1920, 3200, True),
-    (768, 2304, 3840, True),
+@pytest.mark.parametrize("wl_class,base,upper,ls_climbs,mib_at_4", [
+    # mib_at_4: the VMEM arrays of a program of thirty-two on the two
+    # rungs; it runs wherever twice that is 64 MiB or less
+    (128, 384, 640, True, (8.06, 9.22)),
+    (256, 768, 1280, True, (11.91, 14.22)),
+    (384, 1152, 1920, True, (17.86, 21.33)),
+    (512, 1536, 2560, True, (21.70, 26.33)),
+    (640, 1920, 3200, True, (27.66, 33.44)),
+    (768, 2304, 3840, True, (31.50, 38.44)),
     # the upper rung's node arrays no longer fit one group's VMEM: the
     # lockstep kernel stays on the base rung, the XLA twin climbs
-    (896, 2688, 4480, False), (1024, 3072, 5120, False)])
+    (896, 2688, 4480, False, (37.45, 45.55)),
+    (1024, 3072, 5120, False, (41.30, 50.55))])
 def test_capacity_table_by_class_rung_and_group_width(wl_class, base, upper,
-                                                      ls_climbs):
+                                                      ls_climbs, mib_at_4):
     cfgs = [poa_driver.make_config(wl_class, poa_driver.DEPTH_CAP, *SCORES,
                                    rung) for rung in (0, 1)]
     assert [c.max_nodes for c in cfgs] == [base, upper]
@@ -219,7 +234,16 @@ def test_capacity_table_by_class_rung_and_group_width(wl_class, base, upper,
     for groups in (1, 2):
         assert poa_driver._fits_vmem(cfgs[0], groups)
         assert poa_driver._fits_vmem(cfgs[1], groups) == ls_climbs
-    assert poa_driver._group_width(cfgs[1], 64) == (2 if ls_climbs else 1)
+    for cfg, mib in zip(cfgs, mib_at_4):
+        assert round(poa_pallas_ls.scratch_bytes(cfg, 4) / 2 ** 20,
+                     2) == mib
+        assert poa_driver._fits_vmem(cfg, 4) == (
+            poa_driver._fits_vmem(cfg) and 2 * mib <= 64)
+    # thirty-two windows a full program up to class 768 on the base rung
+    # and class 512 on the upper one, sixteen past them
+    assert [poa_driver._group_width(c, 64) for c in cfgs] == [
+        4 if wl_class <= 768 else 2,
+        (4 if wl_class <= 512 else 2) if ls_climbs else 1]
     assert poa_driver._rung_capacities(wl_class, True, *SCORES) == (
         (base, upper) if ls_climbs else (base,))
     assert poa_driver._rung_capacities(wl_class, False, *SCORES) == (
@@ -232,6 +256,8 @@ def test_the_program_of_sixteen_on_the_upper_rung_ships_with_a_limit():
     assert poa_pallas_ls.scratch_bytes(cfg) < poa_pallas_ls.DEFAULT_LIMIT_HOLDS
     assert poa_pallas_ls.vmem_limit_bytes(cfg, 1) is None
     assert poa_pallas_ls.vmem_limit_bytes(cfg, 2) == 27 * MiB
+    # and the program of thirty-two (26.33 MiB of arrays) with one of 53
+    assert poa_pallas_ls.vmem_limit_bytes(cfg, 4) == 53 * MiB
 
 
 def test_the_knob_is_the_base_rung(monkeypatch):
@@ -267,9 +293,19 @@ def test_the_envelope_is_sized_for_the_depth_cap():
 
 def test_audit_grid_names_every_program_and_no_more():
     grid = poa_driver.audit_grid()
-    assert len(grid) == len(set(grid)) == poa_driver.POA_RECOMPILE_BUDGET
+    assert len(grid) == len(set(grid)) == 8
     assert {r for _, _, r in grid} == {0, 1}
     assert all(d == poa_driver.DEPTH_CAP for d, _, r in grid if r)
+    # a geometry holds a program a width its launches choose between:
+    # thirty-two and sixteen at class 512, sixteen at class 1024, whose
+    # upper rung is the XLA twin's (0: no grid programs)
+    widths = {(d, c, r): poa_driver.audit_widths(
+        poa_driver.make_config(c, d, *SCORES, r)) for d, c, r in grid}
+    assert {w for (_, c, _), w in widths.items() if c == 512} == {(4, 2)}
+    assert {(r, w) for (_, c, r), w in widths.items() if c == 1024} == {
+        (0, (2,)), (1, (0,))}
+    assert sum(map(len, widths.values())) == \
+        poa_driver.POA_RECOMPILE_BUDGET == 12
 
 
 # -- (c) a served deep job against the reference ---------------------------

@@ -88,7 +88,7 @@ print("ls == xla under the TPU interpreter")
 """
 
 
-@pytest.mark.parametrize("groups", [1, 2], ids=["u1", "u2"])
+@pytest.mark.parametrize("groups", [1, 2, 4], ids=["u1", "u2", "u4"])
 def test_lockstep_spill_semaphores_balance_under_tpu_interpreter(groups):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=os.pathsep.join(

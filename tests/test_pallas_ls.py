@@ -70,7 +70,8 @@ def _set_window(a, b, backbone, layers, weights=None, begins=None,
         a["en"][b, i] = (len(backbone) - 1) if ends is None else ends[i]
 
 
-GROUPS = pytest.mark.parametrize("groups", [1, 2], ids=["u1", "u2"])
+GROUPS = pytest.mark.parametrize("groups", [1, 2, 4],
+                                 ids=["u1", "u2", "u4"])
 
 
 def _run_ls(a, cfg, groups=1):
@@ -335,21 +336,23 @@ def test_ls_pair_step_tail(tail, groups):
         np.testing.assert_array_equal(cc[b, :cl[b, 0]], jc[b, :jl[b]])
 
 
-def _sixteen(fill):
-    """Sixteen windows for one program of two groups.  `mixed`: unequal
-    layer counts and lengths across the groups (short and shallow in
-    group 0, long and deep in group 1, so every loop bound is set by one
-    group and masked in the other), one window that trips the DMAX cap
-    beside fifteen that do not, one pad slot in each group.  `pad-group`:
-    group 1 is pad windows only (a batch's last program)."""
+def _one_program(fill, groups):
+    """The windows of one program of `groups` sublane groups.  `mixed`:
+    unequal layer counts and lengths across the groups (short and
+    shallow in the first half of them, long and deep in the second, so
+    every loop bound is set by one group and masked in another), one
+    window that trips the DMAX cap beside the others that do not, one
+    pad slot in a group of each half.  `pad-group`: the second half of
+    the groups is pad windows only (a batch's last program)."""
     rng = random.Random(34)
-    a = _alloc(16, CFG)
-    for b in range(16):
-        if fill == "pad-group" and b >= 8:
+    n = 8 * groups
+    a = _alloc(n, CFG)
+    for b in range(n):
+        if fill == "pad-group" and b >= n // 2:
             continue
-        if fill == "mixed" and b in (3, 12):
+        if fill == "mixed" and b in (3, n - 4):
             continue
-        deep = fill == "mixed" and b >= 8
+        deep = fill == "mixed" and b >= n // 2
         L = rng.randrange(85, 115) if deep else rng.randrange(30, 70)
         truth = bytes(rng.choice(b"ACGT") for _ in range(L))
         rate = rng.uniform(0.04, 0.15)
@@ -361,25 +364,29 @@ def _sixteen(fill):
     if fill == "mixed":
         truth = bytes(rng.choice(b"CGT") for _ in range(50))
         far = truth[:25] + b"A" * (poa_pallas_ls.DMAX + 10) + truth[25:]
-        _set_window(a, 9, far, [truth, truth])
+        _set_window(a, n // 2 + 1, far, [truth, truth])
     return a
 
 
+@pytest.mark.parametrize("groups", [2, 4], ids=["u2", "u4"])
 @pytest.mark.parametrize("fill", ["mixed", "pad-group"])
-def test_program_of_sixteen_equals_programs_of_eight(fill):
-    """One program of two sublane groups gives every window what the same
-    window gets in a program of eight: consensus, coverage, length,
-    `failed` and node count, byte for byte — the groups share the control
-    flow (loop bounds are maxima over sixteen windows) and nothing else."""
-    a = _sixteen(fill)
+def test_wide_program_equals_programs_of_eight(fill, groups):
+    """One program of two or four sublane groups gives every window what
+    the same window gets in a program of eight: consensus, coverage,
+    length, `failed` and node count, byte for byte — the groups share the
+    control flow (loop bounds are maxima over the program's windows) and
+    nothing else."""
+    a = _one_program(fill, groups)
+    n = 8 * groups
     narrow = _run_ls(a, CFG, groups=1)
-    wide = _run_ls(a, CFG, groups=2)
+    wide = _run_ls(a, CFG, groups=groups)
     assert int((narrow[3] != 0).sum()) == (1 if fill == "mixed" else 0)
     if fill == "mixed":
-        assert narrow[3][9, 0] == poa.FAIL_DISTANCE
-        # group 1 sets every loop bound, group 0 is masked under it
-        assert a["nl"][:8].max() < a["nl"][8:].max()
-        assert a["bb_len"][:8].max() < a["bb_len"][8:].max()
+        assert narrow[3][n // 2 + 1, 0] == poa.FAIL_DISTANCE
+        # the later groups set every loop bound, the earlier ones are
+        # masked under them
+        assert a["nl"][:n // 2].max() < a["nl"][n // 2:].max()
+        assert a["bb_len"][:n // 2].max() < a["bb_len"][n // 2:].max()
     for name, x, y in zip(("consensus", "coverage", "length", "failed",
                            "nodes"), narrow, wide):
         np.testing.assert_array_equal(x, y, err_msg=f"{fill}: {name}")
@@ -432,7 +439,7 @@ def test_lockstep_production_geometry_real_window():
 
 
 @pytest.mark.parametrize("length,pallas,tier,groups", [
-    (200, True, "ls", 2), (500, True, "ls", 2), (1000, True, "ls", 2),
+    (200, True, "ls", 4), (500, True, "ls", 4), (1000, True, "ls", 2),
     (1152, True, "xla", 0), (1408, True, "xla", 0), (2000, True, "xla", 0),
     (500, False, "xla", 0)],
     ids=["200", "500", "1000", "1152", "1408", "2000", "pallas-off"])
@@ -441,13 +448,13 @@ def test_entry_tier_by_window_length(length, pallas, tier, groups):
     the score sets the deployments use, and how wide its programs are at
     a TPU's batch (64, or 16 a shard): the lockstep kernel's scratch
     fits VMEM up to class 1024 (so -w 200, -w 500 and upstream's largest
-    documented -w 1000 are served by it, sixteen windows a program; the
-    v5e compiler refuses class 1152, and the limit the wide program
-    raises past class 512 admits no class a program of eight does not
-    fit), the XLA twin takes what is longer and everything when Pallas
-    is off.  A change to RING, NODE_FACTOR or the budget that drops a
-    documented window length off the kernel fails here, not on the
-    chip."""
+    documented -w 1000 are served by it, thirty-two windows a full
+    program up to class 768 and sixteen past it; the v5e compiler
+    refuses class 1152, and the limit the wide programs raise admits no
+    class a program of eight does not fit), the XLA twin takes what is
+    longer and everything when Pallas is off.  A change to RING,
+    NODE_FACTOR or the budget that drops a documented window length off
+    the kernel fails here, not on the chip."""
     from racon_tpu.ops import poa_driver
 
     for depth in poa_driver.DEPTH_BUCKETS:
@@ -456,11 +463,12 @@ def test_entry_tier_by_window_length(length, pallas, tier, groups):
                                          depth, *scores)
             assert poa_driver._pick_tier(cfg, pallas) == tier
             if pallas:
-                fits = [u for u in (1, 2) if poa_driver._fits_vmem(cfg, u)]
-                assert fits == ([1, 2] if groups else [])
+                fits = [u for u in (1, 2, 4)
+                        if poa_driver._fits_vmem(cfg, u)]
+                assert fits == [u for u in (1, 2, 4) if u <= groups]
             if groups:
                 assert poa_driver._group_width(cfg, 64) == groups
-                assert poa_driver._group_width(cfg, 16) == groups
+                assert poa_driver._group_width(cfg, 16) == 2
                 assert poa_driver._group_width(cfg, 8) == 1
     assert poa_driver._next_tier("ls") == "xla"
     assert poa_driver._next_tier("xla") == "host"

@@ -1,13 +1,14 @@
-"""The width of a lockstep consensus program (PR 34): how the driver
-derives it, what the launch counters say of it, and the two per-layer
-metrics that read them.
+"""The width of a lockstep consensus program (PR 34; thirty-two windows
+since PR 44): how the driver derives it, what the launch counters say of
+it, and the three per-layer metrics that read them.
 
 `racon_poa_ls` runs U x 8 windows a grid program under one control flow.
-U is a function of the window class, the per-shard batch and the VMEM
-sum (`poa_driver._group_width`); `poa_driver._count_launch` counts the
-programs as wide or narrow and bills lock-step what it costs (every
-window of a program runs the program's largest layer count), once per
-launch from the packed `n_layers` row.
+U is a function of the window class, the per-shard batch, the VMEM sum
+and the rows a launch really holds (`poa_driver._group_width`);
+`poa_driver._count_launch` counts the programs as wide or narrow, the
+launch's real windows under the width that ran them, and bills lock-step
+what it costs (every window of a program runs the program's largest
+layer count), once per launch from the packed `n_layers` row.
 """
 
 import random
@@ -22,24 +23,76 @@ from racon_tpu.ops import poa_driver, poa_pallas_ls
 SCORES = (5, -4, -8)
 CELLS = ["ecoli-ont.sam", "ecoli-ont.paf", "chr20-sr.sam",
          "ecoli-ont-x4.sam", "ecoli-frag.paf", "ecoli-ont-x4.paf"]
+ALL_CELLS = CELLS + ["ecoli-ont-deep.sam", "lambda-ont.paf",
+                     "ecoli-ont-cap.sam"]
 
 
 @pytest.mark.parametrize("wl_class,shard_batch,want", [
-    (512, 64, 2),      # one chip: four programs of sixteen
-    (512, 16, 2),      # a shard's batch on four chips: one program
-    (256, 64, 2), (128, 64, 2), (384, 64, 2), (1024, 64, 2),
-    (512, 8, 1),       # a batch of 8 somebody asked for
-    (512, 24, 1),      # three programs of eight do not pair up
-    (256, 40, 1),
+    (512, 64, (4, 2)),   # one chip: two programs of thirty-two, or four
+    (512, 32, (4, 2)),   # of sixteen where the last would be half empty
+    (512, 16, (2,)),     # a shard's batch on four chips: one program
+    (256, 64, (4, 2)), (128, 64, (4, 2)), (384, 64, (4, 2)),
+    (768, 64, (4, 2)),   # 31.5 MiB of arrays under a limit of 63
+    (896, 64, (2,)),     # 37.45 MiB would ask for 75 of the 64 allowed
+    (1024, 64, (2,)),
+    (512, 8, (1,)),      # a batch of 8 somebody asked for
+    (512, 24, (1,)),     # three programs of eight do not pair up
+    (256, 40, (1,)),
+    (512, 48, (2,)),     # three programs of sixteen, no thirty-two
 ], ids=lambda v: str(v))
 def test_group_width_follows_class_batch_and_vmem(wl_class, shard_batch,
                                                   want):
     for depth in poa_driver.DEPTH_BUCKETS:
         cfg = poa_driver.make_config(wl_class, depth, *SCORES)
-        assert poa_driver._group_width(cfg, shard_batch) == want
-    # the device batch is a multiple of the program's width on every
+        assert poa_driver._group_widths(cfg, shard_batch) == want
+        # a full batch, and a batch of pad rows alone, run at the widest
+        assert poa_driver._group_width(cfg, shard_batch) == want[0]
+        assert poa_driver._group_width(cfg, shard_batch,
+                                       shard_batch) == want[0]
+    # the device batch is a multiple of every program's width on every
     # shard, which is what make() asserts
-    assert shard_batch % (want * poa_pallas_ls.G) == 0
+    assert not [u for u in want if shard_batch % (u * poa_pallas_ls.G)]
+
+
+#: the launch's real rows -> width at a shard batch of 64, 16 and 8: the
+#: last program of thirty-two has to be more than half real
+LAUNCH_RULE = [(1, 2, 2, 1), (8, 2, 2, 1), (16, 2, 2, 1), (17, 4, 2, 1),
+               (32, 4, 2, 1), (33, 2, 2, 1), (46, 2, 2, 1), (48, 2, 2, 1),
+               (49, 4, 2, 1), (64, 4, 2, 1)]
+
+
+@pytest.mark.parametrize("real,at64,at16,at8", LAUNCH_RULE,
+                         ids=[f"rows{r[0]}" for r in LAUNCH_RULE])
+def test_launch_width_follows_the_rows_the_launch_holds(real, at64, at16,
+                                                        at8):
+    """What reaches the rule is the launch's real rows; the fullest
+    shard holds min(rows, shard batch) of them (rows are packed real
+    first).  Every geometry the one-chip cells run, both rungs."""
+    for wl_class, depth, rung in ((512, 200, 0), (512, 200, 1),
+                                  (512, 32, 0), (256, 200, 0),
+                                  (128, 200, 0), (384, 8, 0)):
+        cfg = poa_driver.make_config(wl_class, depth, *SCORES, rung)
+        assert poa_driver._group_width(cfg, 64, real) == at64
+        assert poa_driver._group_width(cfg, 16, real) == at16
+        assert poa_driver._group_width(cfg, 8, real) == at8
+    # a geometry VMEM holds at sixteen windows and no wider runs every
+    # launch at sixteen, as before there was a wider program
+    cfg = poa_driver.make_config(1024, 200, *SCORES)
+    assert poa_driver._group_width(cfg, 64, real) == 2
+
+
+def test_a_program_of_thirty_two_never_replaces_fewer_than_two_of_sixteen():
+    """The rule's ground: at four groups a launch runs at most half as
+    many programs with a window in them as at two, plus none."""
+    cfg = poa_driver.make_config(512, 200, *SCORES)
+    for real in range(1, 65):
+        live = {u: -(-real // (u * poa_pallas_ls.G)) for u in (2, 4)}
+        width = poa_driver._group_width(cfg, 64, real)
+        assert width in (2, 4)
+        if width == 4:
+            assert 2 * live[4] == live[2]
+        else:
+            assert 2 * live[4] > live[2]
 
 
 def test_group_width_narrows_where_vmem_does_not_hold_the_wide_program(
@@ -55,8 +108,11 @@ def test_group_width_narrows_where_vmem_does_not_hold_the_wide_program(
     assert not poa_driver._fits_vmem(cfg, 2) and poa_driver._fits_vmem(cfg)
     assert poa_driver._group_width(cfg, 64) == 1
     assert poa_driver._pick_tier(cfg, True) == "ls"
-    assert poa_driver._group_width(          # class 512 is still wide
-        poa_driver.make_config(512, 32, *SCORES), 64) == 2
+    # class 512 is still wide, though the program of thirty-two (44 MiB
+    # asked for) is out: every launch at sixteen
+    at_512 = poa_driver.make_config(512, 32, *SCORES)
+    assert poa_driver._group_widths(at_512, 64) == (2,)
+    assert poa_driver._group_width(at_512, 64, 64) == 2
 
 
 def test_scratch_sum_doubles_with_the_groups():
@@ -64,6 +120,8 @@ def test_scratch_sum_doubles_with_the_groups():
     one = poa_pallas_ls.scratch_bytes(cfg)
     assert round(one / 2 ** 20, 2) == 5.43
     assert poa_pallas_ls.scratch_bytes(cfg, 2) == 2 * one
+    assert poa_pallas_ls.scratch_bytes(cfg, 4) == 4 * one
+    assert poa_pallas_ls.vmem_limit_bytes(cfg, 4) == 44 << 20
 
 
 def _packed(n_layers):
@@ -90,6 +148,8 @@ def test_count_launch_full_batch_of_wide_programs():
     by_hand = 16 * sum(max(layers[i:i + 16]) for i in range(0, 64, 16))
     assert c == {"poa.launches": 1, "poa.rows.real": 64, "poa.rows.pad": 0,
                  "poa.programs.wide": 4, "poa.programs.narrow": 0,
+                 "poa.width.windows.u1": 0, "poa.width.windows.u2": 64,
+                 "poa.width.windows.u4": 0,
                  "poa.lockstep.layers.real": sum(layers),
                  "poa.lockstep.layers.slots": by_hand,
                  # the node rungs' counters (tests/test_deep_cell.py)
@@ -97,6 +157,14 @@ def test_count_launch_full_batch_of_wide_programs():
                  "poa.layers.admitted": sum(layers)}
     assert by_hand == 16 * (layers[15] + layers[31] + layers[47]
                             + layers[63])
+    # the same batch as two programs of thirty-two: loop bounds are
+    # maxima over 32 sorted windows, so lock-step bills a little more
+    c4 = _counted(64, layers, 4)
+    assert c4["poa.programs.wide"] == 2 and c4["poa.programs.narrow"] == 0
+    assert [c4[f"poa.width.windows.u{u}"] for u in (1, 2, 4)] == [0, 0, 64]
+    assert c4["poa.lockstep.layers.real"] == sum(layers)
+    assert c4["poa.lockstep.layers.slots"] == 32 * (layers[31] + layers[63])
+    assert c4["poa.lockstep.layers.slots"] >= by_hand
 
 
 def test_count_launch_batch_with_pad_rows():
@@ -109,33 +177,45 @@ def test_count_launch_batch_with_pad_rows():
     assert c["poa.programs.wide"] == 4 and c["poa.programs.narrow"] == 0
     assert c["poa.lockstep.layers.real"] == 16 * 30 + 80
     assert c["poa.lockstep.layers.slots"] == 16 * 30 + 16 * 40
+    # the launch's real windows under the one width that ran them
+    assert [c[f"poa.width.windows.u{u}"] for u in (1, 2, 4)] == [0, 21, 0]
+    # which is the width the rule gives 21 rows of 64: 4
+    cfg = poa_driver.make_config(512, 200, *SCORES)
+    c4 = _counted(21, layers, poa_driver._group_width(cfg, 64, 21))
+    assert c4["poa.programs.wide"] == 2
+    assert [c4[f"poa.width.windows.u{u}"] for u in (1, 2, 4)] == [0, 0, 21]
+    assert c4["poa.lockstep.layers.slots"] == 32 * 40
 
 
 def test_count_launch_per_shard_batch_of_sixteen():
     # four chips: 64 rows, 16 a shard, one program of sixteen a chip
     layers = [25] * 16 + [31] * 15 + [44] + [8] * 16 + [0] * 16
     cfg = poa_driver.make_config(512, 200, *SCORES)
-    groups = poa_driver._group_width(cfg, 64 // 4)
+    groups = poa_driver._group_width(cfg, 64 // 4, 48)
     c = _counted(48, layers, groups)
     assert groups == 2 and c["poa.programs.wide"] == 4
+    assert [c[f"poa.width.windows.u{u}"] for u in (1, 2, 4)] == [0, 48, 0]
     assert c["poa.lockstep.layers.real"] == 16 * 25 + 15 * 31 + 44 + 16 * 8
     assert c["poa.lockstep.layers.slots"] == 16 * (25 + 44 + 8 + 0)
 
 
 def test_count_launch_geometry_that_gets_one_group():
     cfg = poa_driver.make_config(512, 32, *SCORES)
-    groups = poa_driver._group_width(cfg, 8)
+    groups = poa_driver._group_width(cfg, 8, 4)
     layers = [3, 9, 9, 4, 0, 0, 0, 0]
     c = _counted(4, layers, groups)
     assert groups == 1
     # both keys at every launch, a zero too: a job served by narrow
     # programs alone reads 0 % wide, not nothing
     assert c["poa.programs.wide"] == 0 and c["poa.programs.narrow"] == 1
+    assert [c[f"poa.width.windows.u{u}"] for u in (1, 2, 4)] == [4, 0, 0]
     assert c["poa.lockstep.layers.real"] == 25
     assert c["poa.lockstep.layers.slots"] == 8 * 9
 
 
 def test_count_launch_of_the_xla_twin_has_no_programs():
+    # nor windows under a width: the poa.width.* keys are the lockstep
+    # kernel's, so their sum over a job is the windows it was given
     c = _counted(3, [5, 5, 5, 0], 0)
     assert c == {"poa.launches": 1, "poa.rows.real": 3, "poa.rows.pad": 1,
                  "poa.programs.wide": 0, "poa.programs.narrow": 0,
@@ -167,6 +247,60 @@ def test_driver_counts_what_it_launches(tmp_path, monkeypatch):
     assert counted == [(3, 16, 2)]
 
 
+@pytest.mark.parametrize("window_length,want", [
+    (10, (24, 32, 4)),      # 24 of 32 rows: one program of thirty-two
+    (20, (12, 32, 2)),      # 12 of 32: two of sixteen, the second all pad
+], ids=["rows24-u4", "rows12-u2"])
+def test_driver_picks_the_width_launch_by_launch(tmp_path, monkeypatch,
+                                                 window_length, want):
+    """Through the consensus driver (interpret mode) at a batch of 32:
+    the geometry holds a program of thirty-two and one of sixteen, a
+    launch runs the one its real rows call for, counts its windows
+    under that width, and the consensus is the same bytes either way."""
+    import racon_tpu
+    from tests.test_pallas_ls import _perfect_reads_dataset
+
+    target = _perfect_reads_dataset(tmp_path)     # 240 bases
+    monkeypatch.setenv("RACON_TPU_PALLAS", "1")
+    monkeypatch.setenv("RACON_TPU_SHARD", "0")
+    monkeypatch.setenv("RACON_TPU_BATCH_WINDOWS", "32")
+    launched, ran = [], []
+    real = poa_driver._count_launch
+    submit = poa_driver._submit
+
+    def spy(n_real, packed, groups=0, *rung):
+        launched.append((n_real, len(packed[0]), groups))
+        real(n_real, packed, groups, *rung)
+
+    def spy_submit(kernel, *args, **kw):
+        ran.append(kernel)
+        return submit(kernel, *args, **kw)
+
+    monkeypatch.setattr(poa_driver, "_count_launch", spy)
+    monkeypatch.setattr(poa_driver, "_submit", spy_submit)
+    monkeypatch.setenv("RACON_TPU_METRICS", "1")   # the polisher arms obs
+    try:
+        p = racon_tpu.TpuPolisher(
+            str(tmp_path / "r.fasta"), str(tmp_path / "o.sam"),
+            str(tmp_path / "t.fasta"), window_length=window_length,
+            match=5, mismatch=-4, gap=-8)
+        p.initialize()
+        res = p.polish(True)
+        counters = obs.snapshot()["counters"]
+    finally:
+        obs.reset()
+    assert res[0][1] == target
+    assert p.report.as_dict()["phases"]["consensus"]["served"]["ls"] == want[0]
+    assert launched == [want]
+    # the program that ran was built at that width (its key: name, cfg,
+    # interpret, band, groups, batch)
+    assert [k.key[4:] for k in ran] == [(want[2], 32)]
+    widths = {u: counters[f"poa.width.windows.u{u}"] for u in (1, 2, 4)}
+    assert widths == {1: 0, 2: 0, 4: 0, want[2]: want[0]}
+    assert sum(widths.values()) == counters["poa.rows.real"]
+    assert counters["kernel.builds.poa.ls"] == 1   # one build, two programs
+
+
 def test_obs_report_lists_the_program_counters():
     """`python -m racon_tpu.obs <trace>` lists the four counters beside
     the mesh counters of alignment."""
@@ -175,6 +309,8 @@ def test_obs_report_lists_the_program_counters():
     counters = {"poa.programs.wide": 72, "poa.programs.narrow": 0,
                 "poa.lockstep.layers.real": 36000,
                 "poa.lockstep.layers.slots": 40000,
+                "poa.width.windows.u1": 0, "poa.width.windows.u2": 40,
+                "poa.width.windows.u4": 960,
                 "align.mesh.launches.single": 380, "poa.launches": 18}
     text = obs_cli.render(
         {"traceEvents": [], "racon_tpu": {"metrics": {"counters": counters}}},
@@ -183,11 +319,11 @@ def test_obs_report_lists_the_program_counters():
     assert "-- alignment launches over the mesh" in text
     section = text.split("-- consensus programs in lock-step")[1]
     for name in counters:
-        assert (name in section) == name.startswith(("poa.programs.",
-                                                     "poa.lockstep."))
+        assert (name in section) == name.startswith(
+            ("poa.programs.", "poa.lockstep.", "poa.width."))
 
 
-# -- the two per-layer metrics --------------------------------------------
+# -- the three per-layer metrics ------------------------------------------
 
 def _run(*job_counters):
     jobs = [{"counters": c, "spans": {}, "phases": {},
@@ -227,3 +363,81 @@ def test_wide_program_metrics_load_and_read_their_counters(cell_name):
     older = {"poa.launches": 18, "poa.rows.real": 1000, "poa.rows.pad": 152}
     assert read(wide, older, older) is None
     assert read(fill, older, older) is None
+
+
+def _width_counters(u1, u2, u4):
+    return {"poa.width.windows.u1": u1, "poa.width.windows.u2": u2,
+            "poa.width.windows.u4": u4}
+
+
+@pytest.mark.parametrize("cell_name", ALL_CELLS)
+def test_program32_metric_loads_and_reads_its_counters(cell_name):
+    cell = loader.load_cell(cell_name)        # the file agrees with its entry
+    spec = {m["name"]: m for m in cell.per_layer}[
+        "poa_program32_window_share"]
+    assert spec["workloads"] == ALL_CELLS and spec["layer"] == "kernels"
+    assert spec["moves"] == "polished_mbp_per_s" and spec["unit"] == "%"
+    assert spec["better"] == "higher"
+    assert spec["reducer"] == "counter_share"
+    registry = reducers.registry()
+
+    def read(*jobs):
+        return registry[spec["reducer"]](_run(*jobs), **spec["params"])
+
+    # ecoli-ont.sam: fifteen full launches and three part-full ones
+    job = dict(_width_counters(0, 40, 960), **{"poa.rows.real": 1000})
+    assert read(job, job) == pytest.approx(96.0)
+    # sixteen rows a shard: every key counted, the share reads 0
+    assert read(dict(_width_counters(0, 1000, 0))) == 0.0
+    assert read(dict(_width_counters(8, 0, 0))) == 0.0
+    assert read(job, dict(_width_counters(0, 1000, 0))) == pytest.approx(48.0)
+    # the parent's program counts none of them: nothing, and no raise
+    older = {"poa.launches": 18, "poa.rows.real": 1000, "poa.rows.pad": 152,
+             "poa.programs.wide": 72, "poa.programs.narrow": 0}
+    assert read(older, older) is None
+
+
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_older_program_metrics_read_past_the_width_counters(cell_name):
+    """poa_wide_program_share and poa_lockstep_fill_share sum every
+    counter under their prefixes: the width counters live under a prefix
+    of their own, so a job's counters read what they read without them
+    (a real job's counters: _count_launch over launches of every
+    width)."""
+    cell = loader.load_cell(cell_name)
+    specs = {m["name"]: m for m in cell.per_layer}
+    registry = reducers.registry()
+    cfg = poa_driver.make_config(512, 200, *SCORES)
+    rng = random.Random(7)
+    obs.reset()
+    obs.configure(metrics=True)
+    try:
+        for n_real in (64, 64, 46, 21, 8):
+            layers = sorted(rng.randrange(20, 46) for _ in range(n_real))
+            poa_driver._count_launch(
+                n_real, _packed(layers + [0] * (64 - n_real)),
+                poa_driver._group_width(cfg, 64, n_real))
+        poa_driver._count_launch(5, _packed([9] * 5 + [0] * 3), 1)
+        poa_driver._count_launch(3, _packed([5, 5, 5, 0]), 0)    # the twin
+        counters = dict(obs.snapshot()["counters"])
+    finally:
+        obs.reset()
+    widths = {k: v for k, v in counters.items()
+              if k.startswith("poa.width.")}
+    assert widths == _width_counters(5, 46 + 8, 64 + 64 + 21)
+    # their sum is the windows the lockstep kernel was given: every real
+    # row but the twin's three
+    assert sum(widths.values()) == counters["poa.rows.real"] - 3
+    without = {k: v for k, v in counters.items() if k not in widths}
+    for name in ("poa_wide_program_share", "poa_lockstep_fill_share"):
+        spec = specs[name]
+        read = registry[spec["reducer"]]
+        assert read(_run(counters, counters), **spec["params"]) == read(
+            _run(without, without), **spec["params"]) is not None
+    share = specs["poa_program32_window_share"]
+    assert registry[share["reducer"]](_run(counters), **share["params"]) \
+        == pytest.approx(100 * 149 / 208)
+    # programs: 2 + 2 + 4 (46 rows at sixteen) + 2 (21 at thirty-two)
+    # + 4 (8 at sixteen) wide, one narrow
+    assert counters["poa.programs.wide"] == 14
+    assert counters["poa.programs.narrow"] == 1

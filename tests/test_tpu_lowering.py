@@ -94,17 +94,24 @@ def _poa_args(cfg, B, band=False):
     return args + (np.zeros(B, np.int32),) if band else args
 
 
-def _ls(window_length, depth, B, band=False, scores=SCORES, rung=0):
+def _ls(window_length, depth, B, band=False, scores=SCORES, rung=0,
+        groups=None):
     from racon_tpu.ops.poa_pallas_ls import build_lockstep_poa_kernel
 
     cfg = poa_driver.make_config(window_length, depth, *scores, rung)
     # the VMEM-fit model must agree: a geometry it approves has to build
     assert poa_driver._fits_vmem(cfg), "fit model rejects geometry"
-    # at the group width the driver derives for this class and batch, and
-    # so under the vmem_limit_bytes it ships with: sixteen windows a
-    # program at 64 and at 16 a shard, eight at a batch of 8
-    groups = poa_driver._group_width(cfg, B)
-    assert groups == (1 if B % 16 else 2), (window_length, depth, B)
+    # at a group width the driver derives for this class and batch, and
+    # so under the vmem_limit_bytes it ships with: by default the widest,
+    # which is what a full batch runs as (thirty-two windows a program at
+    # 64 where VMEM holds them, sixteen at 16 a shard, eight at a batch
+    # of 8); `groups` asks for the geometry's other program, the one of
+    # sixteen a part-full launch of 64 runs
+    widths = poa_driver._group_widths(cfg, B)
+    assert widths[0] == poa_driver._group_width(cfg, B)
+    assert widths[-1] == (1 if B % 16 else 2), (window_length, depth, B)
+    groups = groups or widths[0]
+    assert groups in widths, (window_length, depth, B, widths)
     fn = build_lockstep_poa_kernel(cfg, interpret=False, band=band,
                                    groups=groups)(B)
     return fn, _poa_args(cfg, B, band)
@@ -224,11 +231,47 @@ def test_lockstep_poa_kernel_compiles_for_v5e(depth):
 ])
 def test_lockstep_program_compiles_for_v5e_at_the_cells_geometries(
         window_length, depth, B, scores):
-    """The program of sixteen (two sublane groups, 10.85 MiB of arrays at
-    class 512 against the compiler's default 16 MB scoped limit) at every
-    geometry a benchmark cell runs beside class 512 at batch 64 above,
-    with the limit it ships with; and the program of eight it narrows to."""
+    """The widest program of every geometry a benchmark cell runs beside
+    class 512 at batch 64 above, with the limit it ships with: sixteen
+    windows at 16 a shard (two sublane groups, 10.85 MiB of arrays at
+    class 512 against the compiler's default 16 MB scoped limit),
+    thirty-two at a batch of 64; and the program of eight a batch of 8
+    narrows to."""
     _compile_v5e(*_ls(window_length, depth, B, scores=scores))
+
+
+@pytest.mark.parametrize("window_length,rung,scores,groups,limit_mib", [
+    # the program of thirty-two (four sublane groups) a launch of 64 runs
+    # wherever its last program is more than half real: ecoli-ont.sam and
+    # the PAF cells (class 512, 21.70 MiB of arrays), the deep and the cap
+    # cell (class 512 on the upper rung, 26.33 MiB: never compiled before
+    # PR 44), chr20-sr.sam (class 256 at -w 200, 11.33 MiB; 11.91 at
+    # -w 256)
+    (500, 0, SCORES, 4, 44), (500, 1, SCORES, 4, 53),
+    (200, 0, (3, -5, -4), 4, 23),
+    # the last class VMEM holds at four groups: 31.50 MiB under 63 of the
+    # 64 a limit may ask for (class 896 would ask for 75)
+    (768, 0, SCORES, 4, 63),
+    # and the geometry's other program, of sixteen: what a launch of 64
+    # runs where the last program of thirty-two would be half pad or more
+    (500, 0, SCORES, 2, None), (500, 1, SCORES, 2, 27),
+    (200, 0, (3, -5, -4), 2, None),
+], ids=["w500-u4", "w500-upper-u4", "w200-u4", "w768-u4",
+        "w500-u2", "w500-upper-u2", "w200-u2"])
+def test_both_programs_of_a_batch_of_64_compile_for_v5e(
+        window_length, rung, scores, groups, limit_mib):
+    from racon_tpu.ops import poa_pallas_ls
+
+    cfg = poa_driver.make_config(window_length, 200, *scores, rung)
+    assert poa_driver._group_widths(cfg, TPU_BATCH) == (4, 2)
+    assert not poa_driver._fits_vmem(
+        poa_driver.make_config(896, 200, *SCORES), 4)
+    limit = poa_pallas_ls.vmem_limit_bytes(cfg, groups)
+    assert limit == (limit_mib and limit_mib << 20)
+    fn, args = _ls(window_length, 200, TPU_BATCH, scores=scores, rung=rung,
+                   groups=groups)
+    _export_tpu(fn, args)
+    _compile_v5e(fn, args)
 
 
 def test_wide_program_past_class_512_needs_the_limit_it_ships_with(
@@ -258,7 +301,7 @@ def test_wide_program_past_class_512_needs_the_limit_it_ships_with(
 
 
 @pytest.mark.parametrize("window_length,B,limit_mib", [
-    (500, 8, None), (500, TPU_BATCH, 27),
+    (500, 8, None), (500, SHARD_BATCH, 27),
     (768, TPU_BATCH, 39),    # the last class the upper rung is climbed at
 ], ids=["w500-u1", "w500-u2", "w768-u2"])
 def test_upper_rung_program_compiles_for_v5e(window_length, B, limit_mib):
@@ -267,9 +310,13 @@ def test_upper_rung_program_compiles_for_v5e(window_length, B, limit_mib):
     where the base rung's are 12.  One group's arrays sum to 6.58 MiB,
     under the default scoped-VMEM limit; the program of sixteen's to
     13.16 MiB, which compiles under vmem_limit_bytes (27 MiB) and had
-    not met the chip before PR 35.  Past class 768 one group's arrays
-    pass what the default limit holds and poa_driver._rung_capacities
-    leaves the rung out (tests/test_deep_cell.py holds the table)."""
+    not met the chip before PR 35 (here at 16 a shard; at a batch of 64
+    it is the geometry's second program, beside the one of thirty-two:
+    test_both_programs_of_a_batch_of_64_compile_for_v5e).  At class 768
+    VMEM holds no more than sixteen windows of the rung.  Past class 768
+    one group's arrays pass what the default limit holds and
+    poa_driver._rung_capacities leaves the rung out
+    (tests/test_deep_cell.py holds the table)."""
     from racon_tpu.ops import poa_pallas_ls
 
     cfg = poa_driver.make_config(window_length, 200, *SCORES, 1)
@@ -375,11 +422,13 @@ def test_sharded_lockstep_program_compiles_for_a_v5e_host(v5e_mesh):
     program of sixteen a chip (two sublane groups) where there were two
     of eight.  One Mosaic kernel per chip and no collective."""
     cfg = poa_driver.make_config(500, 200, *SCORES)
-    assert poa_driver._group_width(cfg, TPU_BATCH // 4) == 2
+    assert poa_driver._group_widths(cfg, TPU_BATCH // 4) == (2,)
     poa_driver._build_kernel_cached.cache_clear()
     try:
-        fn = poa_driver._build_kernel_cached(cfg, TPU_BATCH, True, 4, "tpu",
-                                             4, False)
+        handle = poa_driver._build_kernel_cached(cfg, TPU_BATCH, True, 4,
+                                                 "tpu", 4, False)
+        assert sorted(handle.programs) == [2]     # one program a geometry
+        fn = handle.programs[2]
         rows = v5e_mesh.sharding("windows")
         specs = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rows)
                  for a in _poa_args(cfg, TPU_BATCH)]
@@ -430,8 +479,8 @@ def test_exported_sharded_programs_compile_for_a_v5e_host(v5e_mesh, kernel):
     if kernel == "racon_poa_ls":
         cfg = poa_driver.make_config(500, 200, *SCORES)
         poa_driver._build_kernel_cached.cache_clear()
-        prog = poa_driver._build_kernel_cached(cfg, TPU_BATCH, True, 4, "tpu",
-                                               4, False)
+        prog = poa_driver._build_kernel_cached(
+            cfg, TPU_BATCH, True, 4, "tpu", 4, False).programs[2]
         args = _poa_args(cfg, TPU_BATCH)
     else:
         prog, args = (_base(1024, 16) if kernel == "base"
