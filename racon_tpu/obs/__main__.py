@@ -238,7 +238,8 @@ def render(doc: dict, path: str) -> str:
     for title, prefixes in (
             ("alignment launches over the mesh", ("align.mesh.",)),
             ("consensus programs in lock-step",
-             ("poa.programs.", "poa.lockstep.", "poa.width.")),
+             ("poa.programs.", "poa.lockstep.", "poa.width.",
+              "poa.mesh.")),
             ("consensus graph capacity by rung",
              ("poa.windows.rung.", "poa.nodes.", "poa.windows.overflow.",
               "poa.layers.", "poa.backbone.")),

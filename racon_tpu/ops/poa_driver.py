@@ -198,12 +198,14 @@ def audit_widths(cfg) -> tuple:
     return _group_widths(cfg, TPU_BATCH) if _fits_vmem(cfg) else (0,)
 
 
-def _batch_size() -> int:
+def _batch_asked():
+    """The batch somebody asked for (RACON_TPU_BATCH_WINDOWS), or None."""
     env = config.get_raw("RACON_TPU_BATCH_WINDOWS")
-    if env:
-        return max(1, int(env))
-    import jax
-    return TPU_BATCH if jax.devices()[0].platform == "tpu" else 4
+    return max(1, int(env)) if env else None
+
+
+def _batch_size() -> int:
+    return _batch_asked() or (TPU_BATCH if _platform() == "tpu" else 4)
 
 
 def _band_active(kind: str) -> bool:
@@ -244,17 +246,22 @@ def _device_batch(use_pallas: bool) -> int:
     its sublane group G (the XLA twin takes any batch).  The batch
     does not follow the kernel's group width, the width follows the
     batch and what a launch holds of it (_group_width): 64 windows run
-    as programs of thirty-two or sixteen, 16 a shard as programs of
-    sixteen; a batch of 8 somebody asked for stays 8."""
+    as programs of thirty-two or sixteen; a batch of 8 somebody asked
+    for stays 8.  The batch follows the mesh: a TPU's own batch
+    (nobody asked for one) that the lockstep kernel will run over m
+    shards gives every shard at least one widest program,
+    m x GROUP_WIDTHS[0] x G rows, so 64 on one chip and on two, 128 on
+    four, 256 on eight; a launch's real rows are then split evenly over
+    the shards (_mesh_order)."""
     B = _batch_size()
     m = _shard_n(B)
-    if m > 1:
-        B = ((B + m - 1) // m) * m
     if use_pallas:
         from .poa_pallas_ls import G
+        if m > 1 and _batch_asked() is None and _platform() == "tpu":
+            B = max(B, m * GROUP_WIDTHS[0] * G)
         q = G * m
-        B = max(1, (B + q - 1) // q) * q
-    return B
+        return max(1, (B + q - 1) // q) * q
+    return ((B + m - 1) // m) * m
 
 
 def _node_factor() -> int:
@@ -863,22 +870,25 @@ class _ConsensusOps:
         # Always pad to B: a dataset-size-dependent final-chunk shape
         # would force an extra jit compile per distinct remainder (padded
         # windows are 1-base/0-layer — free).
-        return _pack(chunk, ctx.cfg, self.B, self._widths(chunk, ctx.cfg))
+        return _pack(chunk, ctx.cfg, self.B, self._widths(chunk, ctx.cfg),
+                     self.shard_multiple(ctx, chunk))
 
     def _launch(self, ctx, kind, packed, n_real):
-        """Count and dispatch one packed batch of `n_real` windows.  A
-        lockstep launch runs the program of the width its real rows
-        call for (_group_width), out of the kernel the last live_tier
-        built (which keyed on the same partitioner state
+        """Count and dispatch one packed batch of `n_real` windows;
+        returns the device futures and the batch's _mesh_order, which
+        is what unpack needs to undo the layout.  A lockstep
+        launch runs the program of the width its fullest shard's real
+        rows call for (_group_width), out of the kernel the last
+        live_tier built (which keyed on the same partitioner state
         shard_multiple reads)."""
+        m = self.shard_multiple(ctx, None)
         kernel, groups = ctx.kernel, 0
         if kind == "ls":
-            groups = _group_width(
-                ctx.cfg, self.B // self.shard_multiple(ctx, None), n_real)
+            groups = _group_width(ctx.cfg, self.B // m, -(-n_real // m))
             kernel = kernel.programs[groups]
-        _count_launch(n_real, packed, groups, ctx.rung)
-        return _submit(kernel, packed, kind == "ls", _band_active(kind),
-                       ctx.rung)
+        _count_launch(n_real, packed, groups, ctx.rung, m)
+        return (_submit(kernel, packed, kind == "ls", _band_active(kind),
+                        ctx.rung), _mesh_order(n_real, self.B, m))
 
     def dispatch(self, ctx, kind, packed, chunk):
         faults.check(f"poa.run.{kind}", [i for i, _, _ in chunk])
@@ -886,12 +896,14 @@ class _ConsensusOps:
 
     def attempt(self, ctx, kind, sub):
         faults.check(f"poa.run.{kind}", [i for i, _, _ in sub])
-        packed = _pack(sub, ctx.cfg, self.B, self._widths(sub, ctx.cfg))
-        return _unpack(self._launch(ctx, kind, packed, len(sub)),
-                       kind == "ls", _band_active(kind), ctx.rung)
+        packed = self.pack(ctx, sub)
+        return self.unpack(ctx, kind,
+                           self._launch(ctx, kind, packed, len(sub)))
 
-    def unpack(self, ctx, kind, outs):
-        return _unpack(outs, kind == "ls", _band_active(kind), ctx.rung)
+    def unpack(self, ctx, kind, launched):
+        outs, order = launched
+        return _unpack(outs, kind == "ls", _band_active(kind), ctx.rung,
+                       order)
 
     def span_args(self, ctx, chunk, pipelined):
         return {"windows": len(chunk), "pipelined": pipelined}
@@ -1026,8 +1038,9 @@ def _group_width(cfg, shard_batch: int, real_rows=None) -> int:
     would be more than half real, and at the next width down where it
     would not (46 real rows: two programs of thirty-two cost what three
     of sixteen do; 8 real rows: one of thirty-two costs half as much
-    again as one of sixteen).  `real_rows` is what the launch holds
-    (rows are packed real first, so the first shard is the fullest);
+    again as one of sixteen).  `real_rows` is what the launch's fullest
+    shard holds: every real row on one chip, ceil(real rows / shards) on
+    a mesh, where a launch's real rows are split evenly (_mesh_order);
     None or 0, a batch of pad rows alone, gives the widest.  A geometry
     whose widest program is sixteen windows or eight runs every launch
     at it, as before there was a wider one."""
@@ -1217,8 +1230,38 @@ def _export_chunk(pipeline, idxs, cfg, fallback, stats=None, report=None):
     return chunk
 
 
-def _pack(chunk, cfg, pad_to=None, band_widths=None):
+def _mesh_order(n_real: int, rows: int, shards: int):
+    """Where a launch's rows sit in a batch of `rows` that `shards`
+    shards split into contiguous runs: order[p] is the row of chunk item
+    p for p < n_real, and the pad rows follow in ascending order, so
+    `order` is a permutation of the batch.  The real rows are split
+    evenly over the shards, each shard's share a contiguous run of the
+    chunk's (depth, length) order at the head of the shard, its pad rows
+    behind it: shares differ by at most one row, so no shard runs a
+    wider or a longer program than it would packed real first, and none
+    idles while another runs two (46 rows over 4 x 32: 12 / 12 / 11 /
+    11, one program of sixteen a chip, where real first is 32 / 14 / 0 /
+    0); a full batch comes out real first as it stands.  None on one
+    shard, where real first is the layout.  The one place a row's slot
+    is decided: _pack writes through it, _unpack reads back through it,
+    and everything between and after (install, journal, parity sample,
+    bisection, the band ladder) indexes by chunk position."""
+    if shards <= 1:
+        return None
+    per_shard = rows // shards
+    share, extra = divmod(n_real, shards)
+    slot = np.arange(rows).reshape(shards, per_shard)
+    real = (np.arange(per_shard)
+            < (share + (np.arange(shards) < extra))[:, None])
+    return np.concatenate([slot[real], slot[~real]])
+
+
+def _pack(chunk, cfg, pad_to=None, band_widths=None, shards: int = 1):
+    """The chunk's windows as one padded batch, chunk item p on row p or,
+    where the batch will run over `shards` > 1 shards, on row
+    _mesh_order(...)[p]."""
     B = pad_to if pad_to is not None else len(chunk)
+    order = _mesh_order(len(chunk), B, shards)
     bb = np.zeros((B, cfg.max_backbone), dtype=np.uint8)
     bbw = np.zeros((B, cfg.max_backbone), dtype=np.int32)
     bb_len = np.ones(B, dtype=np.int32)   # padded windows: 1-base backbone
@@ -1230,7 +1273,8 @@ def _pack(chunk, cfg, pad_to=None, band_widths=None):
     ends = np.zeros((B, cfg.depth), dtype=np.int32)
     wband = np.zeros(B, dtype=np.int32)   # 0 = flat (padded rows stay 0)
 
-    for bi, (i, wx, keep) in enumerate(chunk):
+    for p, (i, wx, keep) in enumerate(chunk):
+        bi = p if order is None else order[p]
         if band_widths:
             wband[bi] = band_widths.get(i, 0)
         L = len(wx.backbone)
@@ -1268,10 +1312,11 @@ def _pack(chunk, cfg, pad_to=None, band_widths=None):
 
 
 def _count_launch(n_real, packed, groups: int = 0,
-                  rung: str = NODE_RUNGS[0]) -> None:
+                  rung: str = NODE_RUNGS[0], shards: int = 1) -> None:
     """One batch on its way to the device: `n_real` rows carry a
     window, the rest pad the batch to its compiled size (and to the
-    shard multiple), on the node rung `rung` (every rung's key at every
+    shard multiple), packed for `shards` shards (_mesh_order), on the
+    node rung `rung` (every rung's key at every
     launch, a zero too, so that a job the base rung served alone reads
     0 % upper and not nothing).  `groups` is the lockstep kernel's
     group width for this launch (0: the XLA twin serves, which has no
@@ -1281,7 +1326,11 @@ def _count_launch(n_real, packed, groups: int = 0,
     width that ran them (every width's key at every launch, for the
     same reason; they sum to poa.rows.real over the lockstep launches),
     and lock-step is billed what it costs: every window of a program
-    runs the program's largest layer count."""
+    runs the program's largest layer count.  A lockstep launch over a
+    mesh also counts how evenly its real rows lie on the shards: the
+    rows, and what the shards would hold if each were as full as the
+    fullest (100 % of it where the split is even, 36 % for 46 rows
+    packed real first into 4 x 32); nothing on one chip."""
     from .poa_pallas_ls import G
 
     rows = len(packed[0])
@@ -1300,10 +1349,18 @@ def _count_launch(n_real, packed, groups: int = 0,
         for u in GROUP_WIDTHS:
             obs.count(f"poa.width.windows.u{u}", n_real if u == groups else 0)
         # a shard's rows are contiguous and a multiple of the program's
-        # width, so programs are consecutive runs of the packed rows
+        # width, so programs are consecutive runs of the packed rows; on
+        # a mesh the pad rows (0 layers) sit behind each shard's real
+        # rows, not at the end of the batch
         obs.count("poa.lockstep.layers.real", int(n_layers.sum()))
         obs.count("poa.lockstep.layers.slots", int(
             width * n_layers.reshape(-1, width).max(axis=1).sum()))
+        if shards > 1:
+            held = np.bincount(
+                _mesh_order(n_real, rows, shards)[:n_real]
+                // (rows // shards), minlength=shards)
+            obs.count("poa.mesh.rows.real", n_real)
+            obs.count("poa.mesh.fullest.slots", shards * int(held.max()))
 
 
 def _submit(kernel, packed, use_pallas, banded=False,
@@ -1334,9 +1391,12 @@ class _Unpacked(tuple):
     nodes = None
 
 
-def _unpack(outs, use_pallas, banded=False, rung: str = NODE_RUNGS[0]):
+def _unpack(outs, use_pallas, banded=False, rung: str = NODE_RUNGS[0],
+            order=None):
     """Block on device futures; normalize to host arrays.  `failed` is 0
-    for a served window, else the cause (poa.FAIL_CAUSES)."""
+    for a served window, else the cause (poa.FAIL_CAUSES).  `order` is
+    the batch's _mesh_order: row p of every array returned is chunk
+    item p's, wherever _pack put it."""
     cb, cc, cl, fl = outs[0], outs[1], outs[2], outs[3]
     with obs.span("poa.wait", cat="launch", B=len(cb), rung=rung):
         cons_base = np.asarray(cb)
@@ -1348,6 +1408,11 @@ def _unpack(outs, use_pallas, banded=False, rung: str = NODE_RUNGS[0]):
                     if use_pallas and banded else None)
     if use_pallas:
         cons_len, failed, nodes = cons_len[:, 0], failed[:, 0], nodes[:, 0]
+    if order is not None:
+        cons_base, cons_cov, cons_len, failed, nodes = (
+            a[order] for a in (cons_base, cons_cov, cons_len, failed, nodes))
+        if band_hit is not None:
+            band_hit = band_hit[order]
     res = _Unpacked((cons_base, cons_cov, cons_len, failed)
                     + ((band_hit,) if use_pallas and banded else ()))
     res.nodes = nodes

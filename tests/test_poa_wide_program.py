@@ -311,6 +311,7 @@ def test_obs_report_lists_the_program_counters():
                 "poa.lockstep.layers.slots": 40000,
                 "poa.width.windows.u1": 0, "poa.width.windows.u2": 40,
                 "poa.width.windows.u4": 960,
+                "poa.mesh.rows.real": 1000, "poa.mesh.fullest.slots": 1004,
                 "align.mesh.launches.single": 380, "poa.launches": 18}
     text = obs_cli.render(
         {"traceEvents": [], "racon_tpu": {"metrics": {"counters": counters}}},
@@ -320,7 +321,185 @@ def test_obs_report_lists_the_program_counters():
     section = text.split("-- consensus programs in lock-step")[1]
     for name in counters:
         assert (name in section) == name.startswith(
-            ("poa.programs.", "poa.lockstep.", "poa.width."))
+            ("poa.programs.", "poa.lockstep.", "poa.width.", "poa.mesh."))
+
+
+# -- the shape of a launch on a mesh (PR 45) --------------------------------
+
+#: (real rows, shards, rows a shard): part-full and full launches of the
+#: four-chip cells' batch (4 x 32), of 16 a shard, of two and eight
+#: shards, and the degenerate ones (no real row, one, one fewer than full)
+MESH_LAUNCHES = [(46, 4, 32), (76, 4, 32), (128, 4, 32), (28, 4, 32),
+                 (1, 4, 32), (3, 4, 32), (127, 4, 32), (65, 4, 32),
+                 (0, 4, 32), (46, 4, 16), (64, 4, 16), (33, 2, 32),
+                 (64, 2, 32), (100, 8, 32), (7, 8, 8), (46, 1, 64),
+                 (64, 1, 64), (5, 1, 8)]
+MESH_IDS = [f"{n}rows-{m}x{b}" for n, m, b in MESH_LAUNCHES]
+
+
+@pytest.mark.parametrize("n_real,m,shard_batch", MESH_LAUNCHES, ids=MESH_IDS)
+def test_mesh_order_splits_the_real_rows_evenly(n_real, m, shard_batch):
+    rows = m * shard_batch
+    order = poa_driver._mesh_order(n_real, rows, m)
+    if m == 1:
+        assert order is None        # one chip: real first, nothing to undo
+        return
+    assert sorted(order) == list(range(rows))      # a permutation
+    if n_real == rows:
+        # a full launch on any mesh is laid out as it was: real first
+        assert list(order) == list(range(rows))
+    real, pad = order[:n_real], order[n_real:]
+    held = np.bincount(real // shard_batch, minlength=m)
+    assert held.sum() == n_real and held.max() - held.min() <= 1
+    assert held.max() == -(-n_real // m)           # what _group_width hears
+    assert list(held) == sorted(held, reverse=True)
+    # each shard's share is a contiguous run of the chunk's order at the
+    # head of the shard, so a program holds neighbours of the sort
+    assert list(real) == sorted(real)
+    at = 0
+    for j in range(m):
+        assert list(real[at:at + held[j]]) == list(
+            range(j * shard_batch, j * shard_batch + held[j]))
+        at += held[j]
+    assert list(pad) == sorted(pad)
+
+
+@pytest.mark.parametrize("n_real,m,shard_batch", MESH_LAUNCHES, ids=MESH_IDS)
+def test_unpack_returns_results_in_chunk_order(n_real, m, shard_batch):
+    """_pack puts chunk item p on row _mesh_order[p], a kernel answers
+    row for row, _unpack hands row p back as item p's: whatever the
+    mesh, the results index by chunk position."""
+    from tests.test_pack import _random_export
+
+    rows = m * shard_batch
+    cfg = poa_driver.poa.PoaConfig(
+        max_nodes=384, max_len=16, max_backbone=128, max_edges=12, depth=2,
+        match=5, mismatch=-4, gap=-8)
+    rng = random.Random(n_real * 31 + m)
+    chunk = [(1000 + p, _random_export(rng, p, 2, 20 + p % 100, cfg.max_len),
+              [0, 1]) for p in range(n_real)]
+    flat = poa_driver._pack(chunk, cfg, rows)
+    packed = poa_driver._pack(chunk, cfg, rows, None, m)
+    order = poa_driver._mesh_order(n_real, rows, m)
+    for a, b in zip(flat, packed):
+        if m == 1 or n_real == rows:
+            assert a.tobytes() == b.tobytes()      # byte for byte
+        else:
+            np.testing.assert_array_equal(a, b[order])
+    bb, _, bb_len, n_layers = packed[:4]
+    # a kernel that answers each row with what it was given
+    outs = (bb.astype(np.int32), bb.astype(np.int32), bb_len[:, None],
+            np.zeros((rows, 1), np.int32), n_layers[:, None])
+    res = poa_driver._unpack(outs, True, order=order)
+    cons_base, _, cons_len, failed = res
+    assert len(cons_len) == rows and not failed.any()
+    for p, (_, wx, keep) in enumerate(chunk):
+        assert cons_len[p] == len(wx.backbone)
+        assert res.nodes[p] == len(keep)
+        np.testing.assert_array_equal(
+            cons_base[p, :cons_len[p]], poa_driver.encode(wx.backbone))
+    assert (cons_len[n_real:] == 1).all() and not res.nodes[n_real:].any()
+
+
+@pytest.mark.parametrize("n_real,want", [(46, 2), (76, 4), (128, 4), (28, 2),
+                                         (1, 2), (65, 4), (64, 2), (68, 4)],
+                         ids=lambda v: str(v))
+def test_launch_width_over_the_even_split(n_real, want):
+    """Four shards of 32: the rule hears what the fullest shard holds,
+    ceil(real rows / shards).  46 rows are 12 a shard (a program of
+    sixteen a chip; real first they were 32 + 14 and a program of
+    thirty-two on chip 0), 76 are 19 (thirty-two)."""
+    for rung in (0, 1):
+        cfg = poa_driver.make_config(512, 200, *SCORES, rung)
+        assert poa_driver._group_widths(cfg, 32) == (4, 2)
+        assert poa_driver._group_width(cfg, 32, -(-n_real // 4)) == want
+    # where VMEM holds no program of thirty-two a shard runs two of
+    # sixteen a launch
+    cfg = poa_driver.make_config(1024, 200, *SCORES)
+    assert poa_driver._group_widths(cfg, 32) == (2,)
+    assert poa_driver._group_width(cfg, 32, -(-n_real // 4)) == 2
+
+
+#: a program's cost on the chip in units of the one-group program's, by
+#: its sublane groups (PERF.md section 6, PR 44)
+PROGRAM_COST = {1: 1.0, 2: 1.38, 4: 2.01}
+
+
+def _fullest_shard_cost(cfg, shard_batch, held):
+    """What the launch's slowest chip runs: its programs with a window
+    in them, at the width the rule picks for `held` rows."""
+    groups = poa_driver._group_width(cfg, shard_batch, held)
+    return -(-held // (groups * poa_pallas_ls.G)) * PROGRAM_COST[groups]
+
+
+@pytest.mark.parametrize("m,shard_batch", [(4, 32), (4, 16), (2, 32),
+                                           (8, 32), (8, 8)],
+                         ids=lambda v: str(v))
+def test_an_even_split_never_runs_a_wider_or_a_longer_program(m, shard_batch):
+    """Against real-first packing into the same shards, at every count
+    of real rows: the fullest shard of an even split costs no more, and
+    strictly less for 17-32 rows over 4 x 32 (one program of sixteen
+    where chip 0 ran one of thirty-two and three chips idled)."""
+    cfg = poa_driver.make_config(512, 200, *SCORES)
+    for n_real in range(1, m * shard_batch + 1):
+        even = _fullest_shard_cost(cfg, shard_batch, -(-n_real // m))
+        first = _fullest_shard_cost(cfg, shard_batch,
+                                    min(n_real, shard_batch))
+        assert even <= first, (n_real, even, first)
+        if (m, shard_batch) == (4, 32) and 17 <= n_real <= 32:
+            assert (even, first) == (1.38, 2.01)
+
+
+@pytest.mark.parametrize("n_real,fullest", [(46, 12), (76, 19), (128, 32),
+                                            (3, 1), (0, 0)],
+                         ids=lambda v: str(v))
+def test_count_launch_counts_the_balance_of_a_mesh_launch(n_real, fullest):
+    layers = np.zeros(128, np.int32)
+    layers[poa_driver._mesh_order(n_real, 128, 4)[:n_real]] = 30
+    obs.reset()
+    obs.configure(metrics=True)
+    try:
+        poa_driver._count_launch(n_real, _packed(layers), 2, "base", 4)
+        c = dict(obs.snapshot()["counters"])
+    finally:
+        obs.reset()
+    assert c["poa.mesh.rows.real"] == n_real
+    assert c["poa.mesh.fullest.slots"] == 4 * fullest
+    assert c["poa.rows.pad"] == 128 - n_real
+    # programs are still consecutive runs of the packed rows, the pad
+    # rows inside the batch bill nothing: a shard's programs of sixteen
+    # with a window in them run 30 layers
+    live = 4 * -(-fullest // 16) if n_real >= 4 else n_real
+    assert c["poa.lockstep.layers.slots"] == 16 * 30 * live
+    # one chip, and the XLA twin on a mesh, count neither key
+    assert not [k for k in _counted(46, [30] * 46 + [0] * 18, 2)
+                if k.startswith("poa.mesh.")]
+    obs.configure(metrics=True)
+    try:
+        poa_driver._count_launch(n_real, _packed(layers), 0, "base", 4)
+        assert not [k for k in obs.snapshot()["counters"]
+                    if k.startswith("poa.mesh.")]
+    finally:
+        obs.reset()
+
+
+def test_real_first_packing_into_wide_shards_would_read_36_percent():
+    """What the counters' ratio says of the layout this PR replaces: 46
+    rows packed real first into 4 x 32 fill shard 0."""
+    cell = loader.load_cell("ecoli-ont-x4.sam")
+    spec = {m["name"]: m for m in cell.per_layer}[
+        "x4_poa_shard_balance_share"]
+    read = reducers.registry()[spec["reducer"]]
+    assert spec["layer"] == "drivers" and spec["better"] == "higher"
+    assert spec["moves"] == "polished_mbp_per_s"
+    assert spec["workloads"] == ["ecoli-ont-x4.sam", "ecoli-ont-x4.paf"]
+    real_first = {"poa.mesh.rows.real": 46, "poa.mesh.fullest.slots": 128}
+    even = {"poa.mesh.rows.real": 46, "poa.mesh.fullest.slots": 48}
+    assert read(_run(real_first), **spec["params"]) == pytest.approx(35.9375)
+    assert read(_run(even, even), **spec["params"]) == pytest.approx(
+        100 * 46 / 48)
+    # the parent's program counts neither: nothing, and no error
+    assert read(_run({"poa.rows.real": 46}), **spec["params"]) is None
 
 
 # -- the three per-layer metrics ------------------------------------------

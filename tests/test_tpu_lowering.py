@@ -1,7 +1,8 @@
 """Lower and compile every production Pallas kernel for the TPU without one.
 
 Two gates, at the shapes the defaults dispatch — the TPU batch (64), the
-four-chip per-shard batch (16), the three depth buckets:
+four-chip per-shard batch (32 since PR 45; 16 where 64 is asked for), the
+three depth buckets:
 
 * **lowering** — ``jax.export`` with ``platforms=['tpu']`` runs the full
   Pallas -> Mosaic lowering on the CPU backend.  Interpret-mode tests (the
@@ -32,11 +33,11 @@ import pytest
 import jax
 import jax.export
 
-from racon_tpu.ops import align_pallas, poa_driver
+from racon_tpu.ops import align_pallas, poa_driver, poa_pallas_ls
 from racon_tpu.parallel import reset_partitioner
 
 TPU_BATCH = 64          # poa_driver._batch_size() on a TPU
-SHARD_BATCH = 16        # the same batch over a four-chip host
+SHARD_BATCH = 16        # a batch of 64 asked for, over a four-chip host
 SCORES = (5, -4, -8)
 UNIT_SCORES = (1, -1, -1)   # upstream's fragment scenarios (-m 1 -x -1 -g -1)
 
@@ -416,28 +417,43 @@ def test_sharded_hirschberg_kernels_compile_for_a_v5e_host(v5e_mesh, kernel,
                             "collective-permute") if c in text]
 
 
-def test_sharded_lockstep_program_compiles_for_a_v5e_host(v5e_mesh):
+#: the batch a TPU's mesh of four gives itself (poa_driver._device_batch:
+#: a widest program a shard)
+MESH_BATCH = 4 * poa_driver.GROUP_WIDTHS[0] * poa_pallas_ls.G
+
+
+@pytest.mark.parametrize("B,rung,want", [
+    (MESH_BATCH, 0, [2, 4]),    # 32 a shard: what the driver builds
+    (MESH_BATCH, 1, [2, 4]),    # the upper rung's [1,4,20,8,128] program
+    (TPU_BATCH, 0, [2]),        # 16 a shard: a batch of 64 asked for
+], ids=["128-base", "128-upper", "64-base"])
+def test_sharded_lockstep_program_compiles_for_a_v5e_host(v5e_mesh, B, rung,
+                                                          want):
     """The consensus launch of the four-chip cells, as the driver builds
-    it: 64 rows under shard_map over the (4, 1) mesh, 16 a shard, so one
-    program of sixteen a chip (two sublane groups) where there were two
-    of eight.  One Mosaic kernel per chip and no collective."""
-    cfg = poa_driver.make_config(500, 200, *SCORES)
-    assert poa_driver._group_widths(cfg, TPU_BATCH // 4) == (2,)
+    it: since PR 45 128 rows under shard_map over the (4, 1) mesh, 32 a
+    shard, so a geometry holds a program of thirty-two a chip (four
+    sublane groups) and one of sixteen, on both node rungs of class 512;
+    a batch of 64 somebody asked for through the knob is still 16 a
+    shard and one program of sixteen.  One Mosaic kernel per chip and no
+    collective."""
+    cfg = poa_driver.make_config(500, 200, *SCORES, rung)
+    assert list(poa_driver._group_widths(cfg, B // 4)) == want[::-1]
     poa_driver._build_kernel_cached.cache_clear()
     try:
-        handle = poa_driver._build_kernel_cached(cfg, TPU_BATCH, True, 4,
-                                                 "tpu", 4, False)
-        assert sorted(handle.programs) == [2]     # one program a geometry
-        fn = handle.programs[2]
+        handle = poa_driver._build_kernel_cached(cfg, B, True, 4, "tpu", 4,
+                                                 False)
+        assert sorted(handle.programs) == want    # built with the geometry
         rows = v5e_mesh.sharding("windows")
         specs = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rows)
-                 for a in _poa_args(cfg, TPU_BATCH)]
-        text = fn.lower(*specs).compile().as_text()
+                 for a in _poa_args(cfg, B)]
+        texts = [handle.programs[u].lower(*specs).compile().as_text()
+                 for u in want]
     finally:
         poa_driver._build_kernel_cached.cache_clear()
-    assert "tpu_custom_call" in text
-    assert not [c for c in ("all-gather", "all-reduce", "all-to-all",
-                            "collective-permute") if c in text]
+    for text in texts:
+        assert "tpu_custom_call" in text
+        assert not [c for c in ("all-gather", "all-reduce", "all-to-all",
+                                "collective-permute") if c in text]
 
 
 # -- what the program cache hands XLA ---------------------------------------
@@ -480,8 +496,8 @@ def test_exported_sharded_programs_compile_for_a_v5e_host(v5e_mesh, kernel):
         cfg = poa_driver.make_config(500, 200, *SCORES)
         poa_driver._build_kernel_cached.cache_clear()
         prog = poa_driver._build_kernel_cached(
-            cfg, TPU_BATCH, True, 4, "tpu", 4, False).programs[2]
-        args = _poa_args(cfg, TPU_BATCH)
+            cfg, MESH_BATCH, True, 4, "tpu", 4, False).programs[4]
+        args = _poa_args(cfg, MESH_BATCH)
     else:
         prog, args = (_base(1024, 16) if kernel == "base"
                       else _edge(8192, 1024, True, 16))
