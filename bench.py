@@ -621,10 +621,11 @@ def stream_profile(contigs: int = 4) -> int:
     if not on_device:
         # the streaming bench measures memory behavior, not kernels: the
         # rehearsal runs the small-window XLA path — same reasoning as
-        # serve_profile: the twin at w=500 runs minutes/window on a CPU
+        # serve_profile: the twin at w=500 runs minutes/window on a CPU —
+        # and phase 1 on the host aligner
         env.update(JAX_PLATFORMS="cpu", RACON_TPU_PALLAS="0",
                    RACON_TPU_BATCH_WINDOWS="8",
-                   RACON_TPU_DEVICE_ALIGNER="xla")
+                   RACON_TPU_DEVICE_ALIGNER="host")
     device = _require_chip(env)
     budget = config.get_int("RACON_TPU_MEM_BUDGET_MB") or 2048
     paths = stream_dataset(MBP, contigs)

@@ -115,8 +115,6 @@ def judge_report(rep: dict, *, alignment: bool, rehearsal: bool = False,
             served = ali.get("served", {})
             if ali.get("degradations"):
                 bad.append(f"alignment degraded: {ali['degradations']}")
-            if served.get("xla", 0):
-                bad.append(f"alignment tier xla served {served['xla']}")
             if served.get("hirschberg", 0) < (HIRSCHBERG_MIN_SHARE
                                               * ali["total"]):
                 bad.append(f"hirschberg served {served.get('hirschberg', 0)}"

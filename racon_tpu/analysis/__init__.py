@@ -14,11 +14,11 @@ parsed-AST cache (`astcache.py`).  The two founding engines:
   excepts around device seams that don't document the degradation
   lattice boundary.
 
-* **Jaxpr audit** (`jaxpr_audit.py`): abstractly traces the POA and
-  alignment kernels over the bucket-config grid and statically rejects
-  forbidden primitives (host callbacks, infeed/outfeed, float64) and
-  recompile blow-ups (distinct jit signatures across the grid vs. the
-  budgets declared in `ops/poa_driver.py` / `ops/align.py`).
+* **Jaxpr audit** (`jaxpr_audit.py`): abstractly traces the POA
+  kernel over the bucket-config grid and statically rejects forbidden
+  primitives (host callbacks, infeed/outfeed, float64) and recompile
+  blow-ups (distinct jit signatures across the grid vs. the budget
+  declared in `ops/poa_driver.py`).
 
 The later engines live in their own subpackages: `concurrency/` (lock
 discipline + contract cross-checks, ``--concurrency``/``--contracts``),

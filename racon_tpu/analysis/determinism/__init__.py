@@ -5,9 +5,9 @@ Statically proves the repo's byte-identity contract: every knob in the
 **cost-only** by propagating explicit dataflow taint from its read
 sites through the interprocedural call graph to the consensus/CIGAR
 install seams (``pipeline.set_consensus`` / ``pipeline.set_job_cigar``
-— ``poa_driver._install``, ``align.run_jobs``, the CPU polisher stitch
-and journal replay).  The verdicts are then cross-checked against the
-fingerprint compositions declared in ``racon_tpu/fingerprint.py``:
+— ``poa_driver._install``, ``align_pallas.run_jobs``, the CPU polisher
+stitch and journal replay).  The verdicts are then cross-checked against
+the fingerprint compositions declared in ``racon_tpu/fingerprint.py``:
 
 * ``determinism-leak`` — a cost-only knob's value reaches an install
   seam (the contract broken in code);

@@ -4,7 +4,7 @@ Sources are knob reads (``config.get_*("RACON_TPU_X")``); sinks are the
 byte-install seams every polished byte passes through —
 ``pipeline.set_consensus(i, payload, ...)`` (poa_driver._install, the
 CPU polisher, journal replay) and ``pipeline.set_job_cigar(job, cigar)``
-(align.run_jobs / align_pallas, CigarTap).  A knob whose *value* can
+(align_pallas.run_jobs, CigarTap).  A knob whose *value* can
 reach a sink payload is output-affecting; a knob that cannot is
 cost-only under the model below.
 

@@ -2,9 +2,8 @@
 TPU invariants that no unit test exercises.
 
 Two properties are checked over the *whole* compile grid (every
-(depth bucket, window class) the consensus driver can request, every
-aligner bucket), using `jax.make_jaxpr` — abstract tracing only, no
-device, no compilation:
+(depth bucket, window class) the consensus driver can request), using
+`jax.make_jaxpr` — abstract tracing only, no device, no compilation:
 
 * **forbidden primitives** — host callbacks (`pure_callback`,
   `io_callback`, ...), infeed/outfeed and explicit `device_put`
@@ -15,11 +14,10 @@ device, no compilation:
 
 * **recompile budget** — the number of distinct jit input signatures
   across the audited grid must not exceed the budget declared next to
-  the geometry (`POA_RECOMPILE_BUDGET`, `ALIGN_RECOMPILE_BUDGET`).
-  Every signature is one XLA compile at serving time; a geometry change
-  that silently splits signatures is the biggest TPU latency cliff this
-  repo has hit, so widening the grid must consciously raise the
-  literal.
+  the geometry (`POA_RECOMPILE_BUDGET`).  Every signature is one XLA
+  compile at serving time; a geometry change that silently splits
+  signatures is the biggest TPU latency cliff this repo has hit, so
+  widening the grid must consciously raise the literal.
 
 The audit traces through `jax.jit` wrappers (the pjit equation's inner
 jaxpr is walked recursively), so it sees exactly what XLA would lower.
@@ -45,7 +43,6 @@ FORBIDDEN_PRIMITIVES = {
 }
 
 _POA_PATH = "racon_tpu/ops/poa.py"
-_ALIGN_PATH = "racon_tpu/ops/align.py"
 
 
 # --------------------------------------------------------------------------
@@ -192,54 +189,7 @@ def audit_poa(window_lengths: Optional[Sequence[int]] = None,
     return out
 
 
-# --------------------------------------------------------------------------
-# banded aligner bucket grid
-# --------------------------------------------------------------------------
-
-def audit_align(buckets: Optional[Sequence[Tuple[int, int]]] = None
-                ) -> List[Violation]:
-    """Trace the banded NW aligner over its (cap, band) buckets and
-    enforce ALIGN_RECOMPILE_BUDGET."""
-    import jax
-    import numpy as np
-
-    from ..ops import align
-
-    grid = tuple(buckets if buckets is not None else align.BUCKETS)
-    out: List[Violation] = []
-    signatures: Set[Tuple] = set()
-    for cap, band in grid:
-        kernel = align.build_align_kernel.__wrapped__(cap, band)
-        u8, i32 = np.uint8, np.int32
-        args = [
-            jax.ShapeDtypeStruct((1, cap), u8),   # query codes
-            jax.ShapeDtypeStruct((1, cap), u8),   # target codes
-            jax.ShapeDtypeStruct((1,), i32),      # query lengths
-            jax.ShapeDtypeStruct((1,), i32),      # target lengths
-        ]
-        label = f"align cap={cap} band={band}"
-        try:
-            closed = jax.make_jaxpr(kernel)(*args)
-        except Exception as e:  # noqa: BLE001 — audit reports, not raises
-            out.append(Violation(
-                "jaxpr-trace-error", _ALIGN_PATH, 0,
-                f"{label}: abstract trace failed: "
-                f"{type(e).__name__}: {e}"))
-            continue
-        signatures.add(_signature(closed.in_avals))
-        out.extend(check_jaxpr(closed, _ALIGN_PATH, label))
-    budget = align.ALIGN_RECOMPILE_BUDGET
-    if len(signatures) > budget:
-        out.append(Violation(
-            "recompile-budget", _ALIGN_PATH, 0,
-            f"aligner compiles {len(signatures)} distinct jit "
-            f"signatures over buckets={grid}, exceeding "
-            f"ALIGN_RECOMPILE_BUDGET={budget}; raise the declared "
-            f"budget only after sizing the serving-latency cost"))
-    return out
-
-
 def run_audit() -> List[Violation]:
-    """Full static jaxpr audit (POA grid + aligner buckets)."""
-    return sorted(audit_poa() + audit_align(),
+    """Full static jaxpr audit: the consensus kernel's grid."""
+    return sorted(audit_poa(),
                   key=lambda v: (v.path, v.rule, v.message))

@@ -15,8 +15,9 @@ dynamic counterpart to this package's static lint + jaxpr audit:
   (``RACON_TPU_SANITIZE_PARITY``, default every 8th) recomputes the
   window on the host and compares byte-for-byte *before* the device
   result is installed, so an armed run stays byte-identical to an
-  unarmed one.  The aligner seam (``align.run_jobs``) asserts CIGAR op
-  codes stay in the M/I/D range on served rows.
+  unarmed one.  (The aligner's install seam, ``align_pallas.run_jobs``,
+  needs no armed check: its native run-length pass refuses an op code
+  outside M/I/D on every run.)
 * **shared-state guards** — the drivers' stats dicts are wrapped so a
   mutation from any thread other than the owning driver thread is
   recorded as a ``racy-stats`` finding.
@@ -53,8 +54,7 @@ _MAX_FINDINGS = 100
 class Finding:
     """One sanitizer violation class, aggregated across occurrences."""
 
-    kind: str    # nonfinite | cigar-op-range | consensus-range |
-                 # parity | racy-stats
+    kind: str    # nonfinite | consensus-range | parity | racy-stats
     where: str   # kernel builder / driver seam that caught it
     detail: str  # first occurrence's specifics
     count: int = 1
@@ -142,24 +142,8 @@ def check_kernel_outputs(name: str, out) -> None:
 
 
 # --------------------------------------------------------------------------
-# driver-seam checks (called from ops/align.py and ops/poa_driver.py)
+# driver-seam checks (called from ops/poa_driver.py)
 # --------------------------------------------------------------------------
-
-def check_align_outputs(ops, cnt, ok, where: str) -> None:
-    """Aligner outputs: op codes on a served (ok) row must stay in the
-    M/I/D range 0..2 — code 3 is the kernel's out-of-band failure marker
-    and is only legal on rows whose ok flag is already false."""
-    ops = np.asarray(ops)
-    cnt = np.asarray(cnt).reshape(-1)
-    ok = np.asarray(ok).reshape(-1)
-    for bi in range(ops.shape[0]):
-        if bi >= len(ok) or not bool(ok[bi]):
-            continue
-        row = ops[bi, :int(cnt[bi])]
-        if row.size and int(row.max()) > 2:
-            record("cigar-op-range", where,
-                   f"op code {int(row.max())} > 2 on served row {bi}")
-
 
 def check_consensus_outputs(results, idxs, where: str) -> None:
     """Consensus chunk invariants at the install seam, where the arrays

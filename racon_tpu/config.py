@@ -62,7 +62,7 @@ KNOBS: Dict[str, Knob] = {k.name: k for k in (
        "fused Pallas kernels vs the XLA twin (default: 1 on TPU, 0 "
        "elsewhere)"),
     _k("RACON_TPU_DEVICE_ALIGNER", "auto", "str",
-       "phase-1 aligner: auto | hirschberg | 1/xla | 0/host"),
+       "phase-1 aligner: auto | hirschberg | 0/host"),
     _k("RACON_TPU_BAND", "0", "bool",
        "banded DP on the hot kernels: Ukkonen-banded Hirschberg "
        "alignment + diagonal-banded POA with verify-and-widen "

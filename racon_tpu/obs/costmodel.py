@@ -2,7 +2,7 @@
 
 ROADMAP item 3's hardware-free half: before cutting serial DP steps we
 need to *predict* where the cycles go — per POA bucket (DEPTH_BUCKETS x
-128-lane window class, tier ls/xla) and per aligner bucket — and
+128-lane window class, tier ls/xla) and per aligner band — and
 check those predictions against what `--trace` actually measured.  The
 vocabulary is the one AnySeq/GPU and gpuPairHMM use to justify DP
 optimizations: cell updates per second against a machine roofline.
@@ -45,8 +45,9 @@ from typing import Dict, List, NamedTuple, Optional, Tuple
 
 #: poa_driver.DEPTH_BUCKETS — layer-count buckets windows batch into.
 DEPTH_BUCKETS = (8, 32, 200)
-#: align.BUCKETS — (max length, band) buckets for the xla aligner.
-ALIGN_BUCKETS = ((1024, 256), (2048, 512), (4096, 1024), (8192, 2048))
+#: (pair length, band) rows the model prints for the aligner: one per
+#: compiled band of align_pallas.BANDS, at a pair of 4 x band bases.
+ALIGN_BUCKETS = tuple((4 * band, band) for band in (256, 512, 1024, 2048))
 #: poa_pallas_ls.G — windows per sublane group of a lane-lockstep
 #: program (amortizes the serial rank loop across G windows).  A program
 #: runs one, two or four groups (poa_driver._group_width); this model does not
@@ -88,7 +89,6 @@ POA_FLOPS_PER_CELL = 14.0
 POA_LAYER_BYTES = 5.0
 #: Aligner DP: add/min/select + move byte per cell.
 ALIGN_FLOPS_PER_CELL = 10.0
-ALIGN_BYTES_PER_CELL = 2.0   # move byte written + amortized re-read
 
 
 def window_class(bb_len: int) -> int:
@@ -251,23 +251,16 @@ def poa_window_cost(depth: int, wl_class: int, tier: str) -> CostEstimate:
     return CostEstimate(flops, hbm, steps)
 
 
-def align_job_cost(cap: int, band: int, tier: str = "xla") -> CostEstimate:
-    """Predicted work for ONE aligner job in a (cap, band) bucket.
-
-    xla: full cap x band moves-matrix DP (scan over cap rows, then a
-    2*cap traceback while-loop).  hirschberg: fwd+bwd distance passes
-    over the recursion tree ~ 2x the base DP, no stored matrix.
+def align_job_cost(cap: int, band: int) -> CostEstimate:
+    """Predicted work for ONE Hirschberg job of `cap` rows at `band`:
+    fwd+bwd distance passes over the recursion tree ~ 2x the base DP,
+    no stored matrix.
     """
-    cells = float(cap) * band
-    if tier == "hirschberg":
-        cells *= 2.0
-        # Row scans across recursion levels; the packed kernels score
-        # ALIGN_ROW_PACK adjacent rows per serial iteration.
-        steps = 4.0 * cap / ALIGN_ROW_PACK
-        hbm = cap * 2.0            # sequences only; no moves matrix
-    else:
-        steps = 3.0 * cap          # row scan + traceback chain
-        hbm = cells * ALIGN_BYTES_PER_CELL
+    cells = 2.0 * float(cap) * band
+    # Row scans across recursion levels; the packed kernels score
+    # ALIGN_ROW_PACK adjacent rows per serial iteration.
+    steps = 4.0 * cap / ALIGN_ROW_PACK
+    hbm = cap * 2.0            # sequences only; no moves matrix
     return CostEstimate(cells * ALIGN_FLOPS_PER_CELL, hbm, steps)
 
 
@@ -278,9 +271,7 @@ def banded_align_job_cost(cap: int, k: int) -> CostEstimate:
     divides by the band ratio.  The serial row scan is UNCHANGED: the
     band narrows each row's live lanes, it does not shorten the
     latency chain (same rows, fewer columns per row)."""
-    cells = 2.0 * float(cap) * k
-    steps = 4.0 * cap / ALIGN_ROW_PACK
-    return CostEstimate(cells * ALIGN_FLOPS_PER_CELL, cap * 2.0, steps)
+    return align_job_cost(cap, k)
 
 
 def banded_poa_window_cost(depth: int, wl_class: int, w: int,
@@ -425,10 +416,11 @@ def model_rows(prof: MachineProfile,
                     "verdict": verdict,
                 })
     for cap, band in ALIGN_BUCKETS:
-        est = align_job_cost(cap, band, "xla")
+        est = align_job_cost(cap, band)
         s, verdict = roofline(est, prof)
         rows.append({
-            "kind": "align", "tier": "xla", "cap": cap, "band": band,
+            "kind": "align", "tier": "hirschberg", "cap": cap,
+            "band": band,
             "flops": est.flops, "hbm_bytes": est.hbm_bytes,
             "serial_steps": est.serial_steps,
             "predicted_s": s, "predicted_cycles": s * prof.clock_hz,
@@ -441,7 +433,6 @@ def model_rows(prof: MachineProfile,
 
 _POA_CELLS = re.compile(r"^poa\.cells\.d(\d+)\.c(\d+)$")
 _POA_WINDOWS = re.compile(r"^poa\.windows\.d(\d+)\.c(\d+)$")
-_ALIGN_CELLS = re.compile(r"^align\.cells\.c(\d+)$")
 _SHARD_ROWS = re.compile(r"^shard\.rows\.d(\d+)$")
 
 
@@ -503,9 +494,9 @@ def predict_from_counters(counters: Dict[str, int],
 
     POA: `poa.cells.d<D>.c<C>` = sum over the bucket's windows of
     (admitted depth x class C) — the serial-step count at graph growth 1.
-    Aligner: `align.cells.c<CAP>` = padded cap x band DP cells per xla
-    bucket, `align.cells.hirschberg` likewise, `align.cells.total` the
-    need-band cells over ALL phase-1 jobs (host share included).
+    Aligner: `align.cells.hirschberg` = the flat-band DP cells of the
+    pairs the device engine took, `align.cells.total` the need-band
+    cells over ALL phase-1 jobs (host share included).
 
     `n_devices` divides the device-side FLOP/byte bill (data-parallel
     mesh sharding; serial steps are NOT divided — shards run their DP
@@ -557,20 +548,6 @@ def predict_from_counters(counters: Dict[str, int],
     # ---- alignment
     a_est = ZERO
     dev_cells = 0.0
-    for name, raw in sorted(counters.items()):
-        m = _ALIGN_CELLS.match(name)
-        if m:
-            cap = int(m.group(1))
-            band = dict(ALIGN_BUCKETS).get(cap, cap // 4)
-            jobs = max(1, raw // (cap * band))
-            est = _over_devices(
-                align_job_cost(cap, band, "xla").scaled(jobs), n_devices)
-            a_est = a_est.plus(est)
-            dev_cells += float(raw)
-            sec, verdict = roofline(est, prof)
-            buckets.append({"kind": "align", "tier": "xla", "cap": cap,
-                            "band": band, "cells": float(raw),
-                            "predicted_s": sec, "verdict": verdict})
     hs_cells = counters.get("align.cells.hirschberg", 0)
     if hs_cells:
         est = _over_devices(

@@ -27,22 +27,20 @@ def _engine() -> str:
     device-on-TPU posture as the consensus path (_use_pallas) and the
     reference, whose accelerator serves phase 1 whenever CUDA devices
     exist (/root/reference/src/cuda/cudapolisher.cpp:74-214). Explicit
-    overrides: '0'/'host', 'hirschberg', '1'/'xla' (the moves-matrix
-    kernel, small pairs only). A device-engine failure degrades to the
-    host aligner for the remaining jobs (see run_alignment_phase).
+    overrides: '0'/'host', 'hirschberg'. A device-engine failure
+    degrades to the host aligner for the remaining jobs (see
+    run_alignment_phase).
     """
     env = config.get_str("RACON_TPU_DEVICE_ALIGNER")
     if env in ("auto", ""):
         return "hirschberg" if _on_tpu() else "host"
     if env in ("0", "host"):
         return "host"
-    if env in ("1", "xla"):
-        return "xla"
     if env == "hirschberg":
         return "hirschberg"
     print(f"[racon_tpu::align] WARNING: unknown RACON_TPU_DEVICE_ALIGNER="
           f"{env!r}; using the host aligner "
-          f"(valid: auto, 0/host, 1/xla, hirschberg)", file=sys.stderr)
+          f"(valid: auto, 0/host, hirschberg)", file=sys.stderr)
     return "host"
 
 
@@ -50,15 +48,15 @@ def run_alignment_phase(pipeline, progress: bool = False,
                         journal=None) -> dict:
     """Device alignment for every eligible CIGAR-less overlap; host for
     the rest.  Device failures run through the degradation lattice inside
-    the engines' run_jobs (per-cohort retry, bisection-quarantine, engine
+    the engine's run_jobs (per-cohort retry, bisection-quarantine, engine
     death -> host for the remainder); already-installed CIGARs are kept
     and the served count survives a mid-phase engine failure.
 
     With `journal` armed, device-served CIGARs journaled by a previous
     run are replayed (and excluded from device batching — the native
     host pass skips any job whose CIGAR is already set), and fresh
-    device results are journaled through a CigarTap as the engines
-    install them.  Host-computed CIGARs are not journaled: the native
+    device results are journaled through a CigarTap as the engine
+    installs them.  Host-computed CIGARs are not journaled: the native
     engine recomputes them deterministically on resume.
 
     Returns stats {device:…, host:…, report: PhaseReport} — the report's
@@ -77,8 +75,7 @@ def run_alignment_phase(pipeline, progress: bool = False,
     n = pipeline.num_align_jobs()
     report.total = n
     # Bulk-FFI lengths array, fetched ONCE and threaded through the cells
-    # counter, per-engine eligibility, and the engines' own bucketing
-    # (each used to refetch it independently).
+    # counter, the engine's eligibility rule and its own bucketing.
     lengths = (pipeline.align_job_lengths()
                if n and hasattr(pipeline, "align_job_lengths") else None)
     if lengths is not None and obs.enabled():
@@ -105,22 +102,16 @@ def run_alignment_phase(pipeline, progress: bool = False,
                 # what served: compiled or interpreted kernels, the
                 # cohort size and the mesh width they dispatch over
                 from ..parallel.partitioner import get_partitioner
-                from .align_pallas import cohort_size
+                from . import align_pallas
 
                 report.extra["kernels"] = {
                     "engine": engine,
-                    "interpreted": engine == "hirschberg" and not _on_tpu(),
-                    "batch": cohort_size(),
+                    "interpreted": not _on_tpu(),
+                    "batch": align_pallas.cohort_size(),
                     "shards": get_partitioner().batch_axis_size}
-            if engine == "host":
-                pass
-            elif engine == "hirschberg":
                 faults.check("align.compile")
-                from . import align_pallas
-
                 # duck-typed pipelines without the lengths table raise
-                # AttributeError here -> outer catch -> host degrade,
-                # same as the per-engine fetch used to
+                # AttributeError here -> outer catch -> host degrade
                 ln = (lengths if lengths is not None
                       else pipeline.align_job_lengths())
                 jobs = [i for i in range(n) if i not in replayed
@@ -136,19 +127,6 @@ def run_alignment_phase(pipeline, progress: bool = False,
                     # the host-served figure below is derived from it.
                     align_pallas.run_jobs(sink, jobs, report=report,
                                           stats=stats, lengths=ln)
-            else:
-                faults.check("align.compile")
-                from . import align
-
-                ln = (lengths if lengths is not None
-                      else pipeline.align_job_lengths())
-                jobs = [i for i in range(n) if i not in replayed
-                        and align.device_eligible(ln[i, 0], ln[i, 1])]
-                if jobs:
-                    sink = (CigarTap(pipeline, journal, "xla")
-                            if journal is not None else pipeline)
-                    align.run_jobs(sink, jobs, report=report, stats=stats,
-                                   lengths=ln)
         except Exception as e:  # noqa: BLE001 — engine/backend init
             print(f"[racon_tpu::align] WARNING: device aligner "
                   f"'{engine}' failed ({type(e).__name__}: {e}); "

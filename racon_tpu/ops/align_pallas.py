@@ -3,7 +3,7 @@ kernels.
 
 TPU-native replacement for the edlib seam (reference:
 /root/reference/src/overlap.cpp:205-224) built for FULL-LENGTH reads. The
-moves-matrix design (ops/align.py) needs O(rows x band) memory per pair,
+moves-matrix design needs O(rows x band) memory per pair,
 which caps device-eligible pairs far below ONT read lengths; this engine
 keeps only O(band) state per kernel program — the classic
 divide-and-conquer (Hirschberg) trick:
@@ -958,7 +958,18 @@ def _assemble(run):
     return results
 
 
-from .align import ops_to_cigars  # same 0=M/1=I/2=D convention
+def ops_to_cigars(ops_list) -> list:
+    """CIGAR strings of forward-ordered op code arrays (0=M, 1=I, 2=D):
+    one native run-length pass over them all."""
+    off = np.zeros(len(ops_list) + 1, np.uint64)
+    np.cumsum([len(ops) for ops in ops_list], out=off[1:])
+    flat = np.concatenate(ops_list) if len(ops_list) else ()
+    return native.ops_to_cigars(np.ascontiguousarray(flat, np.int32), off)
+
+
+def ops_to_cigar(ops: np.ndarray) -> str:
+    """Run-length encode forward-ordered op codes (0=M,1=I,2=D)."""
+    return ops_to_cigars([ops])[0]
 
 
 def cohort_size(default: int = 64) -> int:

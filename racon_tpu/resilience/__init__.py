@@ -10,7 +10,7 @@ that posture a tested subsystem instead of scattered try/except blocks:
   the `RACON_TPU_FAULT` env spec, so any lattice edge can be triggered
   deterministically on the CPU backend in CI.
 * `lattice` — the ordered degradation tiers (ls -> xla -> host for
-  consensus; hirschberg/xla -> host for alignment) plus the shared
+  consensus; hirschberg -> host for alignment) plus the shared
   retry / watchdog / batch-bisection machinery the drivers run through.
 * `watchdog`— the deadline-scoped timer around device dispatch and the
   wedge tracker that classifies repeated timeouts as a wedged tier

@@ -306,10 +306,10 @@ def replay_cigars(pipeline, journal: Optional[Journal], n: int,
 class CigarTap:
     """Pipeline proxy that journals each CIGAR as an engine installs it.
 
-    The device aligners (`align.run_jobs` / `align_pallas.run_jobs`)
-    install results through `pipeline.set_job_cigar`; wrapping the
-    pipeline taps that one seam without the engines knowing the journal
-    exists.  Everything else delegates untouched."""
+    The device aligner (`align_pallas.run_jobs`) installs results
+    through `pipeline.set_job_cigar`; wrapping the pipeline taps that one
+    seam without the engine knowing the journal exists.  Everything else
+    delegates untouched."""
 
     def __init__(self, pipeline, journal: Journal, tier: str):
         self._pipeline = pipeline

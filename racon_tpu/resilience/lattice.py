@@ -10,11 +10,9 @@ explicit and deterministically testable via `resilience.faults`.
 Tier orders (best first; a tier's failure demotes to the next):
 
     consensus:  ls -> xla -> host
-    alignment:  hirschberg -> host,  xla -> host
-                (the entry tier is chosen by RACON_TPU_DEVICE_ALIGNER;
-                either device engine degrades straight to the host Myers
-                aligner — there is no cross-engine demotion because the
-                xla moves-matrix tier only admits small pairs)
+    alignment:  hirschberg -> host
+                (RACON_TPU_DEVICE_ALIGNER says whether phase 1 enters at
+                the device engine or at the host Myers aligner)
 
 Failure taxonomy the drivers map onto this module:
 
@@ -49,10 +47,9 @@ from .watchdog import (WatchdogTimeout, call_with_watchdog,  # noqa: F401
 #: re-polished one-by-one by the native SPOA-equivalent engine.
 CONSENSUS_TIERS = ("ls", "xla", "host")
 
-#: Alignment tiers.  hirschberg and xla are alternative entry engines
-#: (RACON_TPU_DEVICE_ALIGNER); both degrade straight to the host Myers
-#: aligner.
-ALIGN_TIERS = ("hirschberg", "xla", "host")
+#: Alignment tiers, best first: the one device engine
+#: (ops/align_pallas.py) degrades straight to the host Myers aligner.
+ALIGN_TIERS = ("hirschberg", "host")
 
 
 class TierDead(Exception):

@@ -2,7 +2,7 @@
 task at a time (commit 3e57bc8): a `Task` object per task, a Python loop
 per launch slot.  Kept as the differential-test oracles of the per-launch
 calls that replaced them (`align_pallas._pack_launch`, `_select`,
-`_collect_base` + `_assemble`, `_deal_programs`; `align.ops_to_cigars`) —
+`_collect_base` + `_assemble`, `_deal_programs`, `ops_to_cigars`) —
 nothing outside the tests imports this module."""
 
 import numpy as np

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from racon_tpu import native
-from racon_tpu.ops import align, align_pallas
+from racon_tpu.ops import align_pallas
 from racon_tpu.ops.encoding import encode
 from tests.test_align import mutate
 
@@ -88,10 +88,9 @@ def test_oversize_band_goes_to_host():
     assert _align_one(q, t) is None
 
 
-def test_polish_with_hirschberg_engine(tmp_path, monkeypatch):
-    """RACON_TPU_DEVICE_ALIGNER=hirschberg serves the PAF alignment phase
-    through the Pallas engine end-to-end; consensus matches the
-    host-aligned run within tie-break noise."""
+def _small_paf_polish(tmp_path, monkeypatch, engines):
+    """A 400-base draft and five reads as a CIGAR-less PAF, polished
+    once per value of RACON_TPU_DEVICE_ALIGNER: (truth, polishers)."""
     import racon_tpu
 
     rng = random.Random(11)
@@ -129,14 +128,39 @@ def test_polish_with_hirschberg_engine(tmp_path, monkeypatch):
                                   window_length=100, match=5, mismatch=-4,
                                   gap=-8)
         p.initialize()
-        return p.polish(True)
+        return p.polish(True), p
 
-    dev = run("hirschberg")
-    host = run("0")
+    return truth, [run(engine) for engine in engines]
+
+
+def test_polish_with_hirschberg_engine(tmp_path, monkeypatch):
+    """RACON_TPU_DEVICE_ALIGNER=hirschberg serves the PAF alignment phase
+    through the Pallas engine end-to-end; consensus matches the
+    host-aligned run within tie-break noise."""
+    truth, ((dev, _), (host, _)) = _small_paf_polish(
+        tmp_path, monkeypatch, ("hirschberg", "0"))
     assert len(dev) == len(host) == 1
     d = native.edit_distance(dev[0][1].encode(), host[0][1].encode())
     assert d <= 2, d
     assert native.edit_distance(dev[0][1].encode(), truth.encode()) <= 8
+
+
+@pytest.mark.parametrize("value", ["1", "xla"])
+def test_retired_engine_values_fall_to_host(tmp_path, monkeypatch, capsys,
+                                            value):
+    """The moves-matrix aligner went in PR 46 and its two spellings of
+    the knob with it: they fall under the rule of every unknown value,
+    one warning that names the valid ones and the host aligner for every
+    pair, byte-equal to `host`."""
+    _, ((res, p), (host, _)) = _small_paf_polish(
+        tmp_path, monkeypatch, (value, "host"))
+    err = capsys.readouterr().err
+    assert err.count("unknown RACON_TPU_DEVICE_ALIGNER") == 1, err
+    assert "(valid: auto, 0/host, hirschberg)" in err
+    al = p.report.as_dict()["phases"]["alignment"]
+    assert al["served"]["host"] == al["total"] == 5
+    assert not al["degradations"]
+    assert res == host
 
 
 @pytest.mark.parametrize("shards", [4, 8])
@@ -266,7 +290,7 @@ def test_cigar_roundtrip():
     q = _rand(rng, 300)
     t = mutate(q, 0.1, rng)
     ops = _align_one(q, t)
-    cigar = align.ops_to_cigar(ops)
+    cigar = align_pallas.ops_to_cigar(ops)
     qc = tc = 0
     num = ""
     for ch in cigar:
@@ -583,7 +607,7 @@ def _six_pairs():
     for _ in range(6):
         q = _rand(rng, rng.randrange(560, 640))
         pairs.append((q, mutate(q, 0.05, rng)))
-    cigars = [align.ops_to_cigar(r) for r in align_pallas.align_pairs(
+    cigars = [align_pallas.ops_to_cigar(r) for r in align_pallas.align_pairs(
         [_enc(q, t) for q, t in pairs], interpret=True)]
     return pairs, cigars
 
@@ -1001,14 +1025,14 @@ def test_ops_to_cigar_equals_the_per_run_loop(name):
     strings = _op_strings()
     ops = strings[name]
     want = oracle.ops_to_cigar(ops)
-    assert align.ops_to_cigar(ops) == want
+    assert align_pallas.ops_to_cigar(ops) == want
     cohort = [strings[k] for k in sorted(strings)]
-    assert align.ops_to_cigars(cohort) == \
+    assert align_pallas.ops_to_cigars(cohort) == \
         [oracle.ops_to_cigar(o) for o in cohort]
     if name == "1500-runs":
         assert sum(c in "MID" for c in want) == 1500
     with pytest.raises(ValueError):
-        align.ops_to_cigar(np.array([0, 3], np.int32))
+        align_pallas.ops_to_cigar(np.array([0, 3], np.int32))
 
 
 def test_host_task_counters_say_what_ran_per_launch():

@@ -156,13 +156,6 @@ def test_audit_fails_on_widened_poa_grid():
         [v.render() for v in vs]
 
 
-def test_audit_fails_on_widened_align_buckets():
-    from racon_tpu.ops import align
-    widened = tuple(align.BUCKETS) + ((16384, 4096),)
-    vs = jaxpr_audit.audit_align(buckets=widened)
-    assert any(v.rule == "recompile-budget" for v in vs)
-
-
 def test_audit_flags_forbidden_primitive():
     import jax
 

@@ -8,11 +8,8 @@ kill=1 journal resume.
 
 import json
 import os
-import random
 import subprocess
 import sys
-
-import numpy as np
 
 import racon_tpu
 
@@ -346,64 +343,6 @@ def test_surrender_and_quarantine_share_the_overlapped_fallback(
     with open(jp) as f:
         records = [json.loads(line) for line in f.read().splitlines()[1:]]
     assert [r["i"] for r in records if r["tier"] == "host"] == arrived
-
-
-def test_xla_align_driver_through_executor(tmp_path, monkeypatch):
-    """The moves-matrix aligner now runs on the executor: poisoned job
-    quarantined, the rest stay device-served, wall split stamped."""
-    paths = _write_dataset(tmp_path, overlaps="paf", n_reads=2)
-    oracle = _oracle(paths)
-    res, p = _tpu_run(paths, monkeypatch, {
-        "RACON_TPU_DEVICE_ALIGNER": "xla",
-        "RACON_TPU_FAULT": "align.run:window=3",
-    })
-    assert res == oracle
-    d = _assert_report_sums(p)
-    al = d["phases"]["alignment"]
-    assert 3 in al["quarantined"]
-    assert al["served"]["xla"] == 5 and al["served"]["host"] == 1
-    assert al["bisections"] >= 1
-    assert al["extra"]["kernel_wall_s"] > 0
-
-
-def test_xla_align_engine_death_mid_cohort(monkeypatch):
-    """Engine death after the first cohort resolved: already-installed
-    CIGARs are kept and counted device-served (the ADVICE.md regression,
-    now enforced by the executor's demote/surrender seam)."""
-    rng = random.Random(9)
-    pairs = []
-    for _ in range(6):
-        t = bytes(rng.choice(b"ACGT") for _ in range(300))
-        pairs.append((t, t))
-
-    class FakePipe:
-        def __init__(self, pairs):
-            self.pairs = pairs
-            self.cigars = {}
-
-        def align_job(self, i):
-            q, t = self.pairs[i]
-            return (np.frombuffer(q, np.uint8), np.frombuffer(t, np.uint8))
-
-        def set_job_cigar(self, i, c):
-            self.cigars[i] = c
-
-    monkeypatch.setenv("RACON_TPU_PIPELINE_DEPTH", "1")
-    monkeypatch.setenv("RACON_TPU_TIER_RETRIES", "0")
-    monkeypatch.setenv(
-        "RACON_TPU_FAULT",
-        ",".join(f"align.run:batch={i}" for i in range(1, 12)))
-    from racon_tpu.ops import align
-    rep = PhaseReport("alignment", ("xla", "host"))
-    pipe = FakePipe(pairs)
-    served = align.run_jobs(pipe, list(range(6)), batch=2, report=rep)
-    # cohort 0 (jobs 0,1) was dispatched AND resolved before the engine
-    # died on cohort 1's dispatch; cohorts 1,2 fall to the host
-    assert served == 2
-    assert sorted(pipe.cigars) == [0, 1]
-    assert rep.served.get("xla") == 2
-    assert any(d["from"] == "xla" and d["to"] == "host"
-               for d in rep.as_dict()["degradations"])
 
 
 def test_kill_resume_through_executor(tmp_path):

@@ -187,7 +187,7 @@ def _report(**over):
             "alignment": {
                 "total": 1900, "retries": 0, "bisections": 0,
                 "quarantined": [], "degradations": [],
-                "served": {"hirschberg": 1890, "xla": 0, "host": 10,
+                "served": {"hirschberg": 1890, "host": 10,
                            "journal": 0},
                 "extra": {"kernels": {"engine": "hirschberg",
                                       "interpreted": False, "batch": 64,

@@ -333,12 +333,14 @@ def test_mem_oom_kill_mid_fleet_resumes_byte_identical(
     fault fires before the chunk journals anything, so — unlike the
     worker.result drill — resume may legitimately replay zero windows."""
     from racon_tpu.distrib import Coordinator
+    from test_distrib import hold_queue_for_worker
 
     paths = _write_dataset(tmp_path, n_targets=6)
     oracle_b = "".join(
         f">{n}\n{s}\n" for n, s in _oracle(paths)).encode()
     monkeypatch.setenv("RACON_TPU_FAULT", "mem.oom:kill=1:count=1")
     monkeypatch.setenv("RACON_TPU_DISTRIB_FAULT_WORKER", "0")
+    hold_queue_for_worker(monkeypatch, 0)
     coord = Coordinator(paths[0], paths[1], paths[2],
                         str(tmp_path / "coord"), args=dict(_ARGS),
                         backend="cpu", workers=3,

@@ -26,12 +26,13 @@ TPU = costmodel.PROFILES["tpu-v5e"]
 def test_grid_constants_match_ops_modules():
     """costmodel mirrors the kernel grid so it can stay stdlib-only;
     this pin is the only thing keeping the mirror honest."""
-    from racon_tpu.ops import align, align_pallas, poa_driver
+    from racon_tpu.ops import align_pallas, poa_driver
     from racon_tpu.ops import poa_pallas_ls
 
     assert costmodel.DEPTH_BUCKETS == poa_driver.DEPTH_BUCKETS
     assert costmodel.AUDIT_WINDOW_LENGTHS == poa_driver.AUDIT_WINDOW_LENGTHS
-    assert costmodel.ALIGN_BUCKETS == align.BUCKETS
+    assert costmodel.ALIGN_BUCKETS == tuple(
+        (4 * band, band) for band in align_pallas.BANDS)
     assert costmodel.LS_GROUP == poa_pallas_ls.G
     from racon_tpu.ops import encoding
     assert costmodel.ALIGN_ROW_PACK == encoding.PACK
@@ -74,14 +75,14 @@ def test_ls_tier_divides_serial_steps_by_pair_and_group():
 
 
 def test_row_pack_divides_hirschberg_serial_steps():
-    hs = costmodel.align_job_cost(1024, 256, "hirschberg")
+    hs = costmodel.align_job_cost(1024, 256)
     assert hs.serial_steps == 4.0 * 1024 / costmodel.ALIGN_ROW_PACK
 
 
 def test_banded_closed_forms_cut_cells_not_serial_steps():
     """Banding narrows each DP row's live lanes: the cell/FLOP bill
     divides by the band ratio, the latency-chained step count does not."""
-    flat = costmodel.align_job_cost(1024, 256, "hirschberg")
+    flat = costmodel.align_job_cost(1024, 256)
     nar = costmodel.banded_align_job_cost(1024, 128)
     assert nar.serial_steps == flat.serial_steps
     assert nar.flops * 2 == flat.flops
@@ -164,8 +165,8 @@ def _counters(device=True):
         "poa.windows.d32.c512": 100,
         # 100 windows, ~30 admitted layers each, class 512
         "poa.cells.d32.c512": 100 * 30 * 512,
-        "served.alignment.xla": 40, "served.alignment.host": 10,
-        "align.cells.c1024": 40 * 1024 * 256,
+        "served.alignment.hirschberg": 40, "served.alignment.host": 10,
+        "align.cells.hirschberg": 40 * 1024 * 256,
         "align.cells.total": 45 * 1024 * 256,
     }
     if not device:
@@ -180,7 +181,7 @@ def test_predict_from_counters_builds_phases_and_buckets():
     assert pred["phases"]["poa"]["tier"] == "ls"
     assert pred["phases"]["poa"]["predicted_s"] > 0.0
     kinds = {(b["kind"], b.get("tier")) for b in pred["buckets"]}
-    assert ("poa", "ls") in kinds and ("align", "xla") in kinds
+    assert ("poa", "ls") in kinds and ("align", "hirschberg") in kinds
     poa_b = next(b for b in pred["buckets"] if b["kind"] == "poa")
     # measured steps at growth 1, scaled by NODE_GROWTH ranks, x class
     assert poa_b["cells"] == pytest.approx(
@@ -189,7 +190,7 @@ def test_predict_from_counters_builds_phases_and_buckets():
 
 def test_predict_flags_host_served_alignment():
     c = _counters()
-    del c["align.cells.c1024"]          # no device aligner bucket ran
+    del c["align.cells.hirschberg"]     # the device aligner served none
     c["align.cells.total"] = 10 ** 9
     pred = costmodel.predict_from_counters(c, CPU)
     assert pred["phases"]["align"]["verdict"] == "host-served"

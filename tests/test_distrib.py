@@ -62,6 +62,28 @@ def _coordinator(paths, tmp_path, **over):
                        str(tmp_path / "coord"), **over)
 
 
+def hold_queue_for_worker(monkeypatch, worker=0):
+    """The kill drills scope their fault to one worker
+    (RACON_TPU_DISTRIB_FAULT_WORKER), and a fleet's workers come up in
+    whatever order the machine's load gives: the others can drain a
+    queue of millisecond chunks before that worker has said hello, and
+    then nobody dies.  Hold the queue until it holds a chunk: every
+    other worker is told to wait, as it is whenever nothing is
+    eligible."""
+    real_fetch = Coordinator._fetch
+    holds_a_chunk = threading.Event()
+
+    def fetch(self, w):
+        if w != worker and not holds_a_chunk.is_set():
+            return {"ok": True, "wait": True, "poll_s": 0.05}
+        resp = real_fetch(self, w)
+        if w == worker and "chunk" in resp:
+            holds_a_chunk.set()
+        return resp
+
+    monkeypatch.setattr(Coordinator, "_fetch", fetch)
+
+
 # ------------------------------------------------------------ wire protocol
 
 def test_protocol_roundtrip():
@@ -352,12 +374,13 @@ def test_worker_sigkill_redispatch_resumes(tmp_path, monkeypatch):
     re-dispatches to a different worker, the re-run resumes the journal
     (replayed > 0), and the gathered output is still byte-identical.
 
-    Six chunks across three workers so worker 0 is guaranteed to fetch
-    one before the fleet drains the queue."""
+    Six chunks across three workers, and the queue held until worker 0
+    has fetched one (`hold_queue_for_worker`)."""
     paths = _write_dataset(tmp_path, n_targets=6)
     oracle = _oracle_bytes(paths)
     monkeypatch.setenv("RACON_TPU_FAULT", "worker.result:kill=1:count=1")
     monkeypatch.setenv("RACON_TPU_DISTRIB_FAULT_WORKER", "0")
+    hold_queue_for_worker(monkeypatch, 0)
     coord = _coordinator(paths, tmp_path, workers=3,
                          report_path=str(tmp_path / "report.json"))
     out = str(tmp_path / "polished.fasta")
@@ -511,6 +534,7 @@ def test_sigkilled_worker_leaves_flight_dump(tmp_path, monkeypatch):
     oracle = _oracle_bytes(paths)
     monkeypatch.setenv("RACON_TPU_FAULT", "worker.result:kill=1:count=1")
     monkeypatch.setenv("RACON_TPU_DISTRIB_FAULT_WORKER", "0")
+    hold_queue_for_worker(monkeypatch, 0)
     coord = _coordinator(paths, tmp_path, workers=3,
                          report_path=str(tmp_path / "report.json"))
     out = str(tmp_path / "polished.fasta")

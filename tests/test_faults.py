@@ -325,6 +325,22 @@ def test_alignment_poisoned_job_quarantined(tmp_path, monkeypatch):
     assert 3 in al["quarantined"]
     assert al["served"]["hirschberg"] == 5 and al["served"]["host"] == 1
     assert al["bisections"] >= 1
+    assert al["extra"]["kernel_wall_s"] > 0      # the executor's wall split
+
+
+def test_alignment_report_names_live_tiers_only(tmp_path, monkeypatch):
+    """One device aligner since PR 46: the lattice's alignment tiers are
+    the engine and the host, and a clean PAF run's report carries those
+    and the journal's replay count, nothing of the tier that went."""
+    assert lattice.ALIGN_TIERS == ("hirschberg", "host")
+    paths = _write_dataset(tmp_path, overlaps="paf", n_reads=2)
+    res, p = _tpu_run(paths, monkeypatch, {
+        "RACON_TPU_DEVICE_ALIGNER": "hirschberg"})
+    assert res == _oracle(paths)
+    al = _assert_report_sums(p)["phases"]["alignment"]
+    assert set(al["served"]) == {"hirschberg", "host", "journal"}
+    assert al["served"]["hirschberg"] == al["total"] == 6
+    assert not al["degradations"]
 
 
 def test_align_compile_fault_degrades_to_host(tmp_path, monkeypatch):

@@ -174,7 +174,7 @@ def test_traced_polish_trace_schema_and_phases(tmp_path, monkeypatch):
     paths = _write_dataset(tmp_path)
     trace = tmp_path / "run_trace.json"
     res, p = _tpu_run(paths, monkeypatch,
-                      {"RACON_TPU_DEVICE_ALIGNER": "1"},
+                      {"RACON_TPU_DEVICE_ALIGNER": "hirschberg"},
                       trace_path=str(trace))
     assert res and trace.exists()
     doc, errors = obs_cli.load_trace(str(trace))
@@ -228,7 +228,7 @@ def test_env_knob_arms_tracing(tmp_path, monkeypatch):
 # ------------------------------------- e2e: align accounting under faults
 
 def test_partial_install_death_keeps_device_count(tmp_path, monkeypatch):
-    """Regression (satellite): the xla engine dying mid-cohort AFTER some
+    """Regression (satellite): the device engine dying mid-cohort AFTER some
     CIGARs were installed must keep those jobs counted as device-served —
     the old `stats["device"] = run_jobs(...)` assignment lost them all,
     over-reporting the host share."""
@@ -237,7 +237,7 @@ def test_partial_install_death_keeps_device_count(tmp_path, monkeypatch):
     oracle_p.initialize()
     oracle = oracle_p.polish(True)
     res, p = _tpu_run(paths, monkeypatch, {
-        "RACON_TPU_DEVICE_ALIGNER": "1",
+        "RACON_TPU_DEVICE_ALIGNER": "hirschberg",
         "RACON_TPU_FAULT": "align.install:window=5",
     })
     assert res == oracle            # host finished the rest, byte-equal
@@ -245,7 +245,7 @@ def test_partial_install_death_keeps_device_count(tmp_path, monkeypatch):
     align_rep = d["phases"]["alignment"]
     # jobs 0..4 were installed before the fault on job 5 killed the
     # engine: they must survive as device-served
-    assert align_rep["served"].get("xla") == 5, align_rep
+    assert align_rep["served"].get("hirschberg") == 5, align_rep
     assert sum(align_rep["served"].values()) == align_rep["total"]
     assert align_rep["degradations"], "engine death must be recorded"
 
@@ -371,7 +371,7 @@ def test_traced_polish_span_quantiles_and_cost_counters(tmp_path,
     paths = _write_dataset(tmp_path)
     trace = tmp_path / "q_trace.json"
     res, _ = _tpu_run(paths, monkeypatch,
-                      {"RACON_TPU_DEVICE_ALIGNER": "1"},
+                      {"RACON_TPU_DEVICE_ALIGNER": "hirschberg"},
                       trace_path=str(trace))
     assert res
     doc, errors = obs_cli.load_trace(str(trace))

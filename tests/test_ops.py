@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from racon_tpu import native
-from racon_tpu.ops import align, encoding, poa
+from racon_tpu.ops import align_pallas, encoding, poa
 from racon_tpu.ops.encoding import decode, encode
 
 
@@ -122,65 +122,10 @@ def test_device_poa_partial_layers_and_quality(poa_kernel):
     assert len(cov) == len(dev)
 
 
-def test_device_aligner_optimal():
-    rng = random.Random(9)
-    pairs = []
-    for _ in range(6):
-        L = rng.randint(150, 1500)
-        t = bytes(rng.choice(b"ACGT") for _ in range(L))
-        q = mutate(t, rng.choice([0.05, 0.2]), rng)
-        pairs.append((q, t))
-
-    class FakePipe:
-        def __init__(self, pairs):
-            self.pairs = pairs
-            self.cigars = {}
-
-        def align_job(self, i):
-            q, t = self.pairs[i]
-            return (np.frombuffer(q, np.uint8), np.frombuffer(t, np.uint8))
-
-        def set_job_cigar(self, i, c):
-            self.cigars[i] = c
-
-    pipe = FakePipe(pairs)
-    served = align.run_jobs(pipe, list(range(len(pairs))))
-    assert served == len(pairs)
-    for i, (q, t) in enumerate(pairs):
-        cigar = pipe.cigars[i]
-        cost = qi = ti = 0
-        num = ""
-        for ch in cigar:
-            if ch.isdigit():
-                num += ch
-                continue
-            k = int(num)
-            num = ""
-            if ch == "M":
-                for _ in range(k):
-                    cost += q[qi] != t[ti]
-                    qi += 1
-                    ti += 1
-            elif ch == "I":
-                cost += k
-                qi += k
-            elif ch == "D":
-                cost += k
-                ti += k
-        assert (qi, ti) == (len(q), len(t))
-        assert cost == native.edit_distance(q, t)
-
-
 def test_ops_to_cigar():
-    assert align.ops_to_cigar(np.array([], np.uint8)) == ""
-    assert align.ops_to_cigar(np.array([0, 0, 1, 2, 2], np.uint8)) == "2M1I2D"
-
-
-def test_device_eligible():
-    assert align.device_eligible(1000, 1000)
-    assert not align.device_eligible(0, 100)
-    assert not align.device_eligible(100, 9000)
-    assert not align.device_eligible(100, 1000)  # length gap exceeds band
+    assert align_pallas.ops_to_cigar(np.array([], np.uint8)) == ""
+    assert align_pallas.ops_to_cigar(
+        np.array([0, 0, 1, 2, 2], np.uint8)) == "2M1I2D"
 
 
 # --------------------------------------------------- packed encoding

@@ -303,7 +303,7 @@ def test_traced_pipelined_polish_overlap_and_pack_split(tmp_path):
         full_env = dict(os.environ, JAX_PLATFORMS="cpu",
                         RACON_TPU_PALLAS="0",
                         RACON_TPU_BATCH_WINDOWS="8",
-                        RACON_TPU_DEVICE_ALIGNER="xla")
+                        RACON_TPU_DEVICE_ALIGNER="hirschberg")
         full_env.pop("RACON_TPU_FAULT", None)
         full_env.pop("XLA_FLAGS", None)
         full_env.update(env or {})

@@ -1,13 +1,11 @@
-"""Polish-phase semantics: drop-unpolished behavior and the opt-in device
-aligner phase (reference behaviors: src/polisher.cpp:520-527 emit rule;
-cuda aligner claiming src/cuda/cudapolisher.cpp:74-214)."""
+"""Polish-phase semantics: drop-unpolished behavior and window trimming
+(reference behavior: src/polisher.cpp:520-527 emit rule)."""
 
 import random
 
 import pytest
 
 import racon_tpu
-from racon_tpu import native
 
 
 def _dataset(tmp_path, rng, with_orphan_target=True):
@@ -79,54 +77,3 @@ def test_no_trimming_keeps_low_coverage_ends(tmp_path):
     trimmed = run(True)[0][1]
     untrimmed = run(False)[0][1]
     assert len(untrimmed) > len(trimmed)
-
-
-def test_device_aligner_phase_opt_in(tmp_path, monkeypatch):
-    """RACON_TPU_DEVICE_ALIGNER=1 serves PAF overlaps on the device
-    aligner; result equals the host-aligned run."""
-    rng = random.Random(4)
-    truth = "".join(rng.choice("ACGT") for _ in range(400))
-
-    def mutate(s, rate):
-        out = []
-        for c in s:
-            r = rng.random()
-            if r < rate / 2:
-                out.append(rng.choice("ACGT"))
-            elif r < rate:
-                continue
-            else:
-                out.append(c)
-        return "".join(out)
-
-    draft = mutate(truth, 0.02)
-    reads = [mutate(truth, 0.05) for _ in range(5)]
-    with open(tmp_path / "t.fasta", "w") as f:
-        f.write(f">t\n{draft}\n")
-    with open(tmp_path / "r.fasta", "w") as rf, \
-            open(tmp_path / "o.paf", "w") as of:
-        for i, r in enumerate(reads):
-            rf.write(f">r{i}\n{r}\n")
-            of.write(f"r{i}\t{len(r)}\t0\t{len(r)}\t+\tt\t{len(draft)}\t0\t"
-                     f"{len(draft)}\t{min(len(r), len(draft))}\t"
-                     f"{max(len(r), len(draft))}\t60\n")
-
-    def run(device):
-        monkeypatch.setenv("RACON_TPU_DEVICE_ALIGNER",
-                           "1" if device else "0")
-        p = racon_tpu.TpuPolisher(str(tmp_path / "r.fasta"),
-                                  str(tmp_path / "o.paf"),
-                                  str(tmp_path / "t.fasta"),
-                                  window_length=100, match=5, mismatch=-4,
-                                  gap=-8)
-        p.initialize()
-        return p.polish(True)
-
-    dev = run(True)
-    host = run(False)
-    assert len(dev) == len(host) == 1
-    # Equally-optimal alignments may break ties differently; consensus must
-    # stay within a pinned sliver of each other and near the truth.
-    d = native.edit_distance(dev[0][1].encode(), host[0][1].encode())
-    assert d <= 2, d
-    assert native.edit_distance(dev[0][1].encode(), truth.encode()) <= 8

@@ -308,7 +308,7 @@ def test_bench_normalize_entry_malformed_partial_summaries():
     assert "phase_wall" not in bench.normalize_entry(
         {"value": 0.01, "report": "corrupt"})
     mixed = bench.normalize_entry({"value": 0.01, "report": {
-        "alignment": {"served": {"xla": 5}},            # wall_s absent
+        "alignment": {"served": {"hirschberg": 5}},     # wall_s absent
         "consensus": {"wall_s": {"ls": 1.5, "host": 0.5}},
         "stitch": {"wall_s": "not-a-dict"},
         "parse": 3.0,                                    # not even a dict
@@ -327,21 +327,11 @@ def test_bench_normalize_entry_malformed_partial_summaries():
 
 # ------------------------------------------- the journal's CIGAR contract
 
-def test_paf_job_journals_one_fsynced_cigar_record_a_pair(tmp_path,
-                                                          monkeypatch):
-    """The Hirschberg engine installs a cohort's CIGARs from one native
-    run-length pass; what the journal is given has not moved: one
-    `cigar` record a pair, each its own append with its own flush and
-    fsync, in install order (bucket by bucket, cohort by cohort, job by
-    job), holding the CIGAR the per-run loop gives for the same ops —
-    and a run resumed from it replays every pair and polishes the same
-    bytes."""
+def _paf_job(tmp_path, monkeypatch):
+    """Twelve CIGAR-less pairs over three targets, the Hirschberg engine
+    pinned (interpreted here) at cohorts of three: the input paths."""
     import random
 
-    from racon_tpu.ops import align_pallas
-    from racon_tpu.ops.encoding import encode
-    from racon_tpu.resilience import journal as journal_mod
-    from tests import hirschberg_oracle as oracle
     from tests.test_align import mutate
 
     rng = random.Random(17)
@@ -364,6 +354,24 @@ def test_paf_job_journals_one_fsynced_cigar_record_a_pair(tmp_path,
                  "RACON_TPU_DEVICE_ALIGNER": "hirschberg",
                  "RACON_TPU_ALIGN_COHORT": "3"}.items():
         monkeypatch.setenv(k, v)
+    return paths
+
+
+def test_paf_job_journals_one_fsynced_cigar_record_a_pair(tmp_path,
+                                                          monkeypatch):
+    """The Hirschberg engine installs a cohort's CIGARs from one native
+    run-length pass; what the journal is given has not moved: one
+    `cigar` record a pair, each its own append with its own flush and
+    fsync, in install order (bucket by bucket, cohort by cohort, job by
+    job), holding the CIGAR the per-run loop gives for the same ops —
+    and a run resumed from it replays every pair and polishes the same
+    bytes."""
+    from racon_tpu.ops import align_pallas
+    from racon_tpu.ops.encoding import encode
+    from racon_tpu.resilience import journal as journal_mod
+    from tests import hirschberg_oracle as oracle
+
+    paths = _paf_job(tmp_path, monkeypatch)
 
     events = []
     real_append = journal_mod.Journal.append_cigar
@@ -416,3 +424,33 @@ def test_paf_job_journals_one_fsynced_cigar_record_a_pair(tmp_path,
     assert p2.polish(True) == first
     served = p2.report.as_dict()["phases"]["alignment"]["served"]
     assert served["journal"] == 12 and not served.get("hirschberg")
+
+
+def test_cigars_journaled_under_a_retired_tier_replay(tmp_path, monkeypatch):
+    """A `cigar` record's `tier` is a label (PR 46 retired the `xla`
+    aligner; a journal an older run wrote under that name is still a
+    journal of this job): the records replay, no pair goes to the
+    engine again, and the FASTA is byte-identical."""
+    paths = _paf_job(tmp_path, monkeypatch)
+    jp = str(tmp_path / "run.journal")
+    p = racon_tpu.create_polisher(*paths, backend="tpu", journal_path=jp,
+                                  **_ARGS)
+    p.initialize()
+    first = p.polish(True)
+
+    with open(jp) as f:
+        records = [json.loads(line) for line in f]
+    cigars = [r for r in records if r["kind"] == "cigar"]
+    assert len(cigars) == 12
+    for r in cigars:
+        r["tier"] = "xla"
+    with open(jp, "w") as f:
+        f.writelines(json.dumps(r, sort_keys=True) + "\n" for r in records)
+
+    p2 = racon_tpu.create_polisher(*paths, backend="tpu", journal_path=jp,
+                                   resume_journal=True, **_ARGS)
+    p2.initialize()
+    assert p2.polish(True) == first
+    served = p2.report.as_dict()["phases"]["alignment"]["served"]
+    assert served["journal"] == 12
+    assert not served.get("hirschberg") and not served["host"]

@@ -82,17 +82,6 @@ def test_wrap_kernel_clean_outputs_record_nothing():
 
 # ------------------------------------------------------ unit: seam checkers
 
-def test_check_align_outputs_flags_out_of_band_code_on_served_row():
-    ops = np.array([[0, 1, 2, 0], [3, 3, 3, 3]], dtype=np.uint8)
-    cnt = np.array([4, 4], dtype=np.int32)
-    # row 1 carries code 3 but is not served (ok False): legal
-    sanitize.check_align_outputs(ops, cnt, np.array([True, False]), "t")
-    assert sanitize.findings() == []
-    # the same row served: violation
-    sanitize.check_align_outputs(ops, cnt, np.array([True, True]), "t")
-    assert [f.kind for f in sanitize.findings()] == ["cigar-op-range"]
-
-
 def test_check_consensus_outputs_flags_bad_rows():
     cons_base = np.array([[0, 1, 2, 3], [0, 9, 0, 0]], dtype=np.int32)
     cons_cov = np.ones_like(cons_base)

@@ -200,7 +200,7 @@ def test_job_ledger_without_ship_mark_falls_back_to_finish():
 def test_stage_seconds_sums_per_tier_walls():
     summary = {
         "parse": {"wall_s": {"host": 0.5}},
-        "alignment": {"wall_s": {"xla": 1.0, "host": 0.25}},
+        "alignment": {"wall_s": {"hirschberg": 1.0, "host": 0.25}},
         "consensus": {"wall_s": 2.0},                 # scalar tolerated
         "stitch": {"wall_s": {"host": "x", "ls": 0.5}},   # garbage skipped
         "memory": {"extra": {"peak_rss_mb": 1}},      # not a ledger stage

@@ -21,7 +21,7 @@ from benchmark import (generate, judge, loader, prepare, reducers,
                        reference_frag)
 from benchmark import reference_align as ra
 from racon_tpu import native, obs
-from racon_tpu.ops import align, align_pallas
+from racon_tpu.ops import align_pallas
 from racon_tpu.ops.encoding import encode
 from racon_tpu.parallel import reset_partitioner
 from tests.test_align_hirschberg import _FakePipe
@@ -135,7 +135,7 @@ def test_mesh_cigars_are_valid_and_optimal(dataset, mesh, armed, n_pairs,
             "several_programs": max(shares) > G}[share], shares
     for (q, t), ops in zip(pairs, results):
         assert ops is not None
-        assert ra.check_cigar(align.ops_to_cigar(ops), q, t) == []
+        assert ra.check_cigar(align_pallas.ops_to_cigar(ops), q, t) == []
 
 
 # -- (c) two cohorts in flight on the mesh ---------------------------------
@@ -146,7 +146,7 @@ def test_two_cohorts_on_the_mesh_install_what_one_device_blocking_gave(
 
     pairs = _pairs(dataset[0])[:18]
     mesh(1)
-    want = [align.ops_to_cigar(r) for r in align_pallas.align_pairs(
+    want = [align_pallas.ops_to_cigar(r) for r in align_pallas.align_pairs(
         _enc(pairs), interpret=True)]
     assert obs.snapshot()["counters"].get(
         "align.mesh.launches.sharded", 0) == 0
