@@ -150,8 +150,12 @@ AUDIT_WINDOW_LENGTHS = (500, 1000)
 #: window class on the upper rung: 6 + 2 = 8 — and a program for each
 #: width a geometry's launches choose between (audit_widths): two, of
 #: thirty-two windows and of sixteen, for the four geometries of class
-#: 512; one for those of class 1024, where VMEM holds no more than
-#: sixteen and the upper rung is the XLA twin's: 4 x 2 + 4 = 12.
+#: 512; one, of sixteen, for the four of class 1024, where VMEM holds
+#: no more on either rung (20.65 / 25.27 MiB of arrays under limits of
+#: 42 / 51 MiB): 4 x 2 + 4 = 12.  Revisited on purpose when class 1024
+#: got its upper rung inside the lockstep kernel (PR 47): the count
+#: stays 12, because that rung's one program was the XLA twin's until
+#: then and is the kernel's program of sixteen now.
 #: Revisited on purpose for the node rungs (PR 35), again when windows
 #: of every depth were let climb (PR 41): a climber of at most 32 layers
 #: runs in the DEPTH_CAP bucket's upper-rung program, padded in depth,
@@ -430,6 +434,9 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
     obs.count("native.calls.window_info", n - len(replayed))
     report.record_served("backbone", stats["backbone"])
 
+    # windows whose node estimate no rung this class admits holds: they run
+    # on the top one and the host redoes those whose graph outgrows it
+    beyond = 0
     if jobs:
         use_pallas = _use_pallas()
         B = _device_batch(use_pallas)
@@ -465,6 +472,7 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
                 capacities[wl_class] = _rung_capacities(
                     wl_class, use_pallas, match, mismatch, gap)
             rung = _node_rung(est_nodes, capacities[wl_class])
+            beyond += est_nodes > capacities[wl_class][-1]
             if rung:
                 # a rung above the base has one program a window class,
                 # the DEPTH_CAP bucket's, whatever the window's depth
@@ -548,6 +556,8 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
         # for bench.py's machine-checkable criterion
         executor.stamp_walls(report)
 
+    # the key at every job, a zero too
+    obs.count("poa.windows.rung.beyond", beyond)
     t0 = time.perf_counter()
     with obs.span("poa.host_fallback", windows=len(fallback)):
         fallback.join(journal, stats)
@@ -734,9 +744,11 @@ def _rung_capacities(wl_class: int, use_pallas: bool, match: int,
                      mismatch: int, gap: int) -> tuple:
     """max_nodes of the rungs a window of this class may climb, smallest
     first.  With the Pallas tier on, a rung whose node arrays the
-    lockstep kernel cannot hold in VMEM (the upper rung past class 768)
-    is left out: such windows stay on the rung below, and the ones that
-    outgrow it go to the host."""
+    lockstep kernel cannot hold in VMEM at one group (_fits_vmem: the
+    upper rung past class 2560; class 1024, -w 1000, gets (3072, 5120))
+    is left out: such windows stay on the rung below, the ones that
+    outgrow it go to the host, and poa.windows.rung.beyond counts the
+    windows whose estimate no rung of their class holds."""
     caps = []
     for rung in range(len(_rung_factors())):
         cfg = make_config(wl_class, DEPTH_CAP, match, mismatch, gap, rung)
@@ -882,11 +894,13 @@ class _ConsensusOps:
         live_tier built (which keyed on the same partitioner state
         shard_multiple reads)."""
         m = self.shard_multiple(ctx, None)
-        kernel, groups = ctx.kernel, 0
+        kernel, groups, raised = ctx.kernel, 0, False
         if kind == "ls":
+            from .poa_pallas_ls import vmem_limit_bytes
             groups = _group_width(ctx.cfg, self.B // m, -(-n_real // m))
             kernel = kernel.programs[groups]
-        _count_launch(n_real, packed, groups, ctx.rung, m)
+            raised = vmem_limit_bytes(ctx.cfg, groups) is not None
+        _count_launch(n_real, packed, groups, ctx.rung, m, raised)
         return (_submit(kernel, packed, kind == "ls", _band_active(kind),
                         ctx.rung), _mesh_order(n_real, self.B, m))
 
@@ -993,24 +1007,28 @@ GROUP_WIDTHS = (4, 2, 1)
 def _fits_vmem(cfg, groups: int = 1) -> bool:
     """Whether the lockstep Pallas kernel's VMEM arrays
     (poa_pallas_ls.scratch_bytes) fit at `groups` sublane groups a
-    program.  A group is held to where the v5e compiler draws the line
-    under its default 16 MB scoped limit, in that sum's terms: the sum
-    leaves out Mosaic's own temporaries, and the compiler refused every
-    program of eight from 11.5 MiB up (classes 896-1152 at NODE_FACTOR 3
-    and 4).  So at NODE_FACTOR 3 classes up to 1024 fit, -w 1000
-    included, and 1152 / 1280 enter at the XLA twin
-    (tests/test_pallas_ls.py holds the table, tests/test_tpu_lowering.py
-    compiles its last row).  A wider program admits no class that one
-    group does not; where its sum outgrows the default limit (sixteen
-    windows past class 512, thirty-two past class 128) it is compiled
-    under one sized from the sum (poa_pallas_ls.vmem_limit_bytes), which
-    may not pass half the chip's VMEM: thirty-two windows fit up to
-    class 768 on the base rung and class 512 on the upper one."""
+    program.  One rule for every width: the scoped-VMEM limit the
+    program needs (poa_pallas_ls.vmem_limit_bytes: none where the
+    arrays' sum is one the compiler's default 16 MB holds, which it did
+    up to 10.85 MiB and refused from 11.5 MiB up; else twice the sum,
+    the arrays and as much again for Mosaic's temporaries) may not pass
+    half the chip's VMEM (VMEM_CEILING); a program of eight ships with
+    a raised limit where it needs one, as the wider ones do (the upper
+    rung of classes 896 and 1024, 11.39 / 12.64 MiB a group, and every
+    class past 1024: the compiler refuses them under its default).  At
+    NODE_FACTOR 3 the rule gives, by class (base rung / upper rung):
+    thirty-two windows a program up to 768 / 512, sixteen up to 1536 /
+    1280 (-w 1000 is class 1024: 20.65 / 25.27 MiB under limits of 42 /
+    51), eight up to 3200 / 2560; past them the XLA twin
+    (tests/test_pallas_ls.py and tests/test_deep_cell.py hold the table,
+    tests/test_tpu_lowering.py compiles class 1024's rows and the last
+    row of each width for a described v5e).  A program the chip's
+    compiler refuses all the same demotes its geometry to the XLA twin
+    through the lattice (_live_tier), as any build failure does."""
     from . import poa_pallas_ls as ls
 
     limit = ls.vmem_limit_bytes(cfg, groups)
-    return (ls.scratch_bytes(cfg) < ls.DEFAULT_LIMIT_HOLDS
-            and (limit is None or limit <= ls.VMEM_CEILING))
+    return limit is None or limit <= ls.VMEM_CEILING
 
 
 def _group_widths(cfg, shard_batch: int) -> tuple:
@@ -1114,9 +1132,17 @@ def _build_kernel(cfg, B, use_pallas):
                 rl.record_shard_demotion(None, kind, e)
             continue
         if _build_kernel_cached.cache_info().misses != misses0:
+            args = dict(builder=f"poa.{kind}", B=B, shards=m,
+                        max_nodes=cfg.max_nodes, depth=cfg.depth)
+            if use_pallas:
+                # the scoped-VMEM limit each of the geometry's programs
+                # is compiled under, by width (0: the compiler's default)
+                from .poa_pallas_ls import vmem_limit_bytes
+                args["vmem_limit"] = {
+                    f"u{u}": vmem_limit_bytes(cfg, u) or 0
+                    for u in _group_widths(cfg, B // m)}
             obs.add_complete("kernel.build", t0, time.monotonic_ns(),
-                             builder=f"poa.{kind}", B=B, shards=m,
-                             max_nodes=cfg.max_nodes, depth=cfg.depth)
+                             **args)
             obs.count(f"kernel.builds.poa.{kind}")
         return built
 
@@ -1312,7 +1338,8 @@ def _pack(chunk, cfg, pad_to=None, band_widths=None, shards: int = 1):
 
 
 def _count_launch(n_real, packed, groups: int = 0,
-                  rung: str = NODE_RUNGS[0], shards: int = 1) -> None:
+                  rung: str = NODE_RUNGS[0], shards: int = 1,
+                  raised: bool = False) -> None:
     """One batch on its way to the device: `n_real` rows carry a
     window, the rest pad the batch to its compiled size (and to the
     shard multiple), packed for `shards` shards (_mesh_order), on the
@@ -1330,7 +1357,11 @@ def _count_launch(n_real, packed, groups: int = 0,
     mesh also counts how evenly its real rows lie on the shards: the
     rows, and what the shards would hold if each were as full as the
     fullest (100 % of it where the split is even, 36 % for 46 rows
-    packed real first into 4 x 32); nothing on one chip."""
+    packed real first into 4 x 32); nothing on one chip.  `raised`: the
+    launch's programs were compiled under a scoped-VMEM limit of their
+    own (vmem_limit_bytes), counted at every lockstep launch, a zero too,
+    under a prefix of its own (poa_wide_program_share sums every
+    counter under poa.programs.)."""
     from .poa_pallas_ls import G
 
     rows = len(packed[0])
@@ -1346,6 +1377,7 @@ def _count_launch(n_real, packed, groups: int = 0,
     obs.count("poa.programs.wide", programs if groups > 1 else 0)
     obs.count("poa.programs.narrow", programs if groups == 1 else 0)
     if width:
+        obs.count("poa.vmem.programs.raised", programs if raised else 0)
         for u in GROUP_WIDTHS:
             obs.count(f"poa.width.windows.u{u}", n_real if u == groups else 0)
         # a shard's rows are contiguous and a multiple of the program's

@@ -79,8 +79,9 @@ def scratch_bytes(cfg: PoaConfig, groups: int = 1) -> int:
     program.  Mirrors make()'s scratch_shapes: a 128-row H ring instead
     of the full H matrix, plus rank-space graph arrays and per-layer DMA
     slots; layers stream from HBM, so depth does not appear.  Mosaic's
-    own temporaries are left out (poa_driver._fits_vmem holds the sum
-    to where the compiler draws the line)."""
+    own temporaries are left out (vmem_limit_bytes asks for as much
+    again where the sum passes what the default limit holds, and
+    poa_driver._fits_vmem holds that limit under VMEM_CEILING)."""
     NC = cfg.max_nodes // 128
     JC = _round_up(cfg.max_len + 1, 128) // 128
     lane_bytes = groups * G * 128 * 4
@@ -102,12 +103,14 @@ VMEM_CEILING = 64 << 20
 def vmem_limit_bytes(cfg: PoaConfig, groups: int):
     """The scoped-VMEM limit a program is compiled under: None, the
     compiler's default, wherever the arrays' sum is one the default is
-    known to hold (every program of eight the driver admits, the
-    program of sixteen up to class 512: 10.85 MiB, the program of
-    thirty-two at class 128: 8.06 MiB).  The default is not the chip's
-    VMEM, so a wider program of a larger class asks for twice its own
-    sum: the arrays, and as much again for Mosaic's temporaries, which
-    took 42 % of the sum at one group."""
+    known to hold (the program of eight up to class 1024 on the base
+    rung and class 768 on the upper one: 10.32 / 9.61 MiB, the program
+    of sixteen up to class 512 on the base rung: 10.85 MiB, the program
+    of thirty-two at class 128: 8.06 MiB).  The default is not the
+    chip's VMEM, so a larger program, of any width (since PR 47 the
+    program of eight too: class 1024's upper rung, 12.64 MiB, asks for
+    26), asks for twice its own sum: the arrays, and as much again for
+    Mosaic's temporaries, which took 42 % of the sum at one group."""
     total = scratch_bytes(cfg, groups)
     if total < DEFAULT_LIMIT_HOLDS:
         return None

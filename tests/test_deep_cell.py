@@ -212,42 +212,100 @@ def test_the_kernels_name_the_same_cause():
 
 # -- (b) the capacity table -------------------------------------------------
 
-@pytest.mark.parametrize("wl_class,base,upper,ls_climbs,mib_at_4", [
-    # mib_at_4: the VMEM arrays of a program of thirty-two on the two
-    # rungs; it runs wherever twice that is 64 MiB or less
-    (128, 384, 640, True, (8.06, 9.22)),
-    (256, 768, 1280, True, (11.91, 14.22)),
-    (384, 1152, 1920, True, (17.86, 21.33)),
-    (512, 1536, 2560, True, (21.70, 26.33)),
-    (640, 1920, 3200, True, (27.66, 33.44)),
-    (768, 2304, 3840, True, (31.50, 38.44)),
-    # the upper rung's node arrays no longer fit one group's VMEM: the
-    # lockstep kernel stays on the base rung, the XLA twin climbs
-    (896, 2688, 4480, False, (37.45, 45.55)),
-    (1024, 3072, 5120, False, (41.30, 50.55))])
+@pytest.mark.parametrize("wl_class,base,upper,widest,mib_at_4", [
+    # widest: the widest program VMEM holds on the two rungs; mib_at_4:
+    # the VMEM arrays of a program of thirty-two there; a program runs
+    # wherever twice its own arrays is 64 MiB or less, one rule for
+    # every width
+    (128, 384, 640, (4, 4), (8.06, 9.22)),
+    (256, 768, 1280, (4, 4), (11.91, 14.22)),
+    (384, 1152, 1920, (4, 4), (17.86, 21.33)),
+    (512, 1536, 2560, (4, 4), (21.70, 26.33)),
+    (640, 1920, 3200, (4, 2), (27.66, 33.44)),
+    (768, 2304, 3840, (4, 2), (31.50, 38.44)),
+    # until PR 47 the lockstep kernel stayed on the base rung from here
+    # on (one group's upper-rung arrays, 11.39 and 12.64 MiB, pass what
+    # the compiler's default limit holds, and a program of eight was
+    # held under it); it climbs under a raised limit now, as sixteen and
+    # thirty-two windows have since PR 34
+    (896, 2688, 4480, (2, 2), (37.45, 45.55)),
+    (1024, 3072, 5120, (2, 2), (41.30, 50.55)),
+    # and past class 1024 (-w over 1000), where it did not serve at all
+    (1152, 3456, 5760, (2, 2), (47.25, 57.66)),
+    (1280, 3840, 6400, (2, 2), (51.09, 62.66)),
+    (1536, 4608, 7680, (2, 1), (60.89, 74.77)),
+    (2560, 7680, 12800, (1, 1), (100.08, 123.20)),
+    # the upper rung's last class is 2560, the base rung's 3200
+    (2688, 8064, 13440, (1, 0), (106.03, 130.31)),
+    (3200, 9600, 16000, (1, 0), (125.62, 154.53)),
+    (3328, 9984, 16640, (0, 0), (129.47, 159.53))])
 def test_capacity_table_by_class_rung_and_group_width(wl_class, base, upper,
-                                                      ls_climbs, mib_at_4):
+                                                      widest, mib_at_4):
     cfgs = [poa_driver.make_config(wl_class, poa_driver.DEPTH_CAP, *SCORES,
                                    rung) for rung in (0, 1)]
     assert [c.max_nodes for c in cfgs] == [base, upper]
     assert cfgs[0].max_edges == cfgs[1].max_edges == 12
-    for groups in (1, 2):
-        assert poa_driver._fits_vmem(cfgs[0], groups)
-        assert poa_driver._fits_vmem(cfgs[1], groups) == ls_climbs
-    for cfg, mib in zip(cfgs, mib_at_4):
+    for cfg, mib, most in zip(cfgs, mib_at_4, widest):
         assert round(poa_pallas_ls.scratch_bytes(cfg, 4) / 2 ** 20,
                      2) == mib
-        assert poa_driver._fits_vmem(cfg, 4) == (
-            poa_driver._fits_vmem(cfg) and 2 * mib <= 64)
+        for groups in (1, 2, 4):
+            assert poa_driver._fits_vmem(cfg, groups) == (
+                2 * mib * groups / 4 <= 64) == (groups <= most)
     # thirty-two windows a full program up to class 768 on the base rung
-    # and class 512 on the upper one, sixteen past them
-    assert [poa_driver._group_width(c, 64) for c in cfgs] == [
-        4 if wl_class <= 768 else 2,
-        (4 if wl_class <= 512 else 2) if ls_climbs else 1]
+    # and class 512 on the upper one, sixteen up to 1536 / 1280, eight
+    # up to 3200 / 2560
+    if widest[0]:
+        assert [poa_driver._group_width(c, 64) for c in cfgs] == [
+            widest[0], widest[1] or 1]
+    ls_climbs = widest[1] > 0
     assert poa_driver._rung_capacities(wl_class, True, *SCORES) == (
         (base, upper) if ls_climbs else (base,))
     assert poa_driver._rung_capacities(wl_class, False, *SCORES) == (
         base, upper)
+    assert poa_driver._pick_tier(cfgs[0], True) == (
+        "ls" if widest[0] else "xla")
+
+
+#: (window class, rung) -> widths at a batch of 64, 32, 16 and 8, and the
+#: scoped-VMEM limit (MiB; None: the compiler's default) of the programs
+#: of thirty-two, sixteen and eight, as the parent (PR 46) built them
+PARENT_TABLE = {
+    (128, 0): (((4, 2), (4, 2), (2,), (1,)), (None, None, None)),
+    (128, 1): (((4, 2), (4, 2), (2,), (1,)), (None, None, None)),
+    (256, 0): (((4, 2), (4, 2), (2,), (1,)), (24, None, None)),
+    (256, 1): (((4, 2), (4, 2), (2,), (1,)), (29, None, None)),
+    (384, 0): (((4, 2), (4, 2), (2,), (1,)), (36, None, None)),
+    (384, 1): (((4, 2), (4, 2), (2,), (1,)), (43, None, None)),
+    (512, 0): (((4, 2), (4, 2), (2,), (1,)), (44, None, None)),
+    (512, 1): (((4, 2), (4, 2), (2,), (1,)), (53, 27, None)),
+    (640, 0): (((4, 2), (4, 2), (2,), (1,)), (56, 28, None)),
+    (640, 1): (((2,), (2,), (2,), (1,)), (67, 34, None)),
+    (768, 0): (((4, 2), (4, 2), (2,), (1,)), (63, 32, None)),
+    (768, 1): (((2,), (2,), (2,), (1,)), (77, 39, None)),
+}
+
+
+@pytest.mark.parametrize("wl_class,rung", sorted(PARENT_TABLE),
+                         ids=lambda v: str(v))
+def test_classes_up_to_768_keep_the_widths_and_limits_of_the_parent(
+        wl_class, rung):
+    """PR 47 made _fits_vmem one rule; every geometry of class 768 or
+    less builds the programs it built before, under the limits it had
+    (the table is the parent's, written down before the change)."""
+    widths, limits = PARENT_TABLE[(wl_class, rung)]
+    for depth in poa_driver.DEPTH_BUCKETS:
+        cfg = poa_driver.make_config(wl_class, depth, *SCORES, rung)
+        assert tuple(poa_driver._group_widths(cfg, b)
+                     for b in (64, 32, 16, 8)) == widths
+        assert tuple(poa_pallas_ls.vmem_limit_bytes(cfg, u)
+                     for u in (4, 2, 1)) == tuple(
+                         m and m << 20 for m in limits)
+        # a limit past the ceiling is never asked for: the width is out
+        for u, m in zip((4, 2, 1), limits):
+            assert poa_driver._fits_vmem(cfg, u) == (m is None or m <= 64)
+        assert poa_driver._pick_tier(cfg, True) == "ls"
+    assert poa_driver._rung_capacities(wl_class, True, *SCORES) == (
+        3 * wl_class, 5 * wl_class)
 
 
 def test_the_program_of_sixteen_on_the_upper_rung_ships_with_a_limit():
@@ -297,13 +355,14 @@ def test_audit_grid_names_every_program_and_no_more():
     assert {r for _, _, r in grid} == {0, 1}
     assert all(d == poa_driver.DEPTH_CAP for d, _, r in grid if r)
     # a geometry holds a program a width its launches choose between:
-    # thirty-two and sixteen at class 512, sixteen at class 1024, whose
-    # upper rung is the XLA twin's (0: no grid programs)
+    # thirty-two and sixteen at class 512, sixteen at class 1024 on both
+    # rungs (until PR 47 its upper rung was the XLA twin's one program;
+    # the count is the same)
     widths = {(d, c, r): poa_driver.audit_widths(
         poa_driver.make_config(c, d, *SCORES, r)) for d, c, r in grid}
     assert {w for (_, c, _), w in widths.items() if c == 512} == {(4, 2)}
     assert {(r, w) for (_, c, r), w in widths.items() if c == 1024} == {
-        (0, (2,)), (1, (0,))}
+        (0, (2,)), (1, (2,))}
     assert sum(map(len, widths.values())) == \
         poa_driver.POA_RECOMPILE_BUDGET == 12
 

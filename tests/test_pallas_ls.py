@@ -440,21 +440,24 @@ def test_lockstep_production_geometry_real_window():
 
 @pytest.mark.parametrize("length,pallas,tier,groups", [
     (200, True, "ls", 4), (500, True, "ls", 4), (1000, True, "ls", 2),
-    (1152, True, "xla", 0), (1408, True, "xla", 0), (2000, True, "xla", 0),
-    (500, False, "xla", 0)],
-    ids=["200", "500", "1000", "1152", "1408", "2000", "pallas-off"])
+    (1152, True, "ls", 2), (1408, True, "ls", 2), (2000, True, "ls", 1),
+    (3200, True, "ls", 1), (3300, True, "xla", 0), (500, False, "xla", 0)],
+    ids=["200", "500", "1000", "1152", "1408", "2000", "3200", "3300",
+         "pallas-off"])
 def test_entry_tier_by_window_length(length, pallas, tier, groups):
     """Which tier a window length enters at, in every depth bucket and at
     the score sets the deployments use, and how wide its programs are at
     a TPU's batch (64, or 16 a shard): the lockstep kernel's scratch
-    fits VMEM up to class 1024 (so -w 200, -w 500 and upstream's largest
-    documented -w 1000 are served by it, thirty-two windows a full
-    program up to class 768 and sixteen past it; the v5e compiler
-    refuses class 1152, and the limit the wide programs raise admits no
-    class a program of eight does not fit), the XLA twin takes what is
-    longer and everything when Pallas is off.  A change to RING,
-    NODE_FACTOR or the budget that drops a documented window length off
-    the kernel fails here, not on the chip."""
+    fits VMEM up to class 3200 under the one rule that the limit a
+    program needs may not pass half the chip's VMEM (so -w 200, -w 500
+    and upstream's largest documented -w 1000 are served by it, and
+    since PR 47 what is longer too: thirty-two windows a full program
+    up to class 768, sixteen up to 1536, eight up to 3200; until then a
+    program of eight was held under the compiler's default limit, which
+    stopped at class 1024), the XLA twin takes what is longer still and
+    everything when Pallas is off.  A change to RING, NODE_FACTOR or the
+    ceiling that drops a documented window length off the kernel fails
+    here, not on the chip."""
     from racon_tpu.ops import poa_driver
 
     for depth in poa_driver.DEPTH_BUCKETS:
@@ -468,7 +471,7 @@ def test_entry_tier_by_window_length(length, pallas, tier, groups):
                 assert fits == [u for u in (1, 2, 4) if u <= groups]
             if groups:
                 assert poa_driver._group_width(cfg, 64) == groups
-                assert poa_driver._group_width(cfg, 16) == 2
+                assert poa_driver._group_width(cfg, 16) == min(groups, 2)
                 assert poa_driver._group_width(cfg, 8) == 1
     assert poa_driver._next_tier("ls") == "xla"
     assert poa_driver._next_tier("xla") == "host"

@@ -24,7 +24,7 @@ SCORES = (5, -4, -8)
 CELLS = ["ecoli-ont.sam", "ecoli-ont.paf", "chr20-sr.sam",
          "ecoli-ont-x4.sam", "ecoli-frag.paf", "ecoli-ont-x4.paf"]
 ALL_CELLS = CELLS + ["ecoli-ont-deep.sam", "lambda-ont.paf",
-                     "ecoli-ont-cap.sam"]
+                     "ecoli-ont-cap.sam", "lambda-ont-w1000.paf"]
 
 
 @pytest.mark.parametrize("wl_class,shard_batch,want", [
@@ -35,6 +35,11 @@ ALL_CELLS = CELLS + ["ecoli-ont-deep.sam", "lambda-ont.paf",
     (768, 64, (4, 2)),   # 31.5 MiB of arrays under a limit of 63
     (896, 64, (2,)),     # 37.45 MiB would ask for 75 of the 64 allowed
     (1024, 64, (2,)),
+    # past class 1024 the kernel serves since PR 47 (one rule for every
+    # width: the limit a program needs may not pass the ceiling)
+    (1152, 64, (2,)), (1536, 64, (2,)),   # 30.45 MiB under 61: the last
+    (1664, 64, (1,)),    # sixteen would ask for 67; eight run under 34
+    (3200, 64, (1,)),    # 31.41 MiB a group under 63: the last class
     (512, 8, (1,)),      # a batch of 8 somebody asked for
     (512, 24, (1,)),     # three programs of eight do not pair up
     (256, 40, (1,)),
@@ -61,6 +66,29 @@ LAUNCH_RULE = [(1, 2, 2, 1), (8, 2, 2, 1), (16, 2, 2, 1), (17, 4, 2, 1),
                (49, 4, 2, 1), (64, 4, 2, 1)]
 
 
+@pytest.mark.parametrize("wl_class,want", [
+    (512, (4, 2)),       # 26.33 MiB under 53
+    (640, (2,)), (768, (2,)),
+    # sixteen windows of the upper rung past class 768 since PR 47: the
+    # rung was the XLA twin's while a program of eight was held under
+    # the default limit's line (11.39 / 12.64 MiB a group)
+    (896, (2,)), (1024, (2,)),            # 25.27 MiB under 51
+    (1280, (2,)),        # 31.33 MiB under 63: the last at sixteen
+    (1408, (1,)), (2560, (1,)),           # 30.80 MiB a group under 62
+], ids=lambda v: str(v))
+def test_upper_rung_group_width_follows_the_same_rule(wl_class, want):
+    cfg = poa_driver.make_config(wl_class, poa_driver.DEPTH_CAP, *SCORES, 1)
+    assert cfg.max_nodes == 5 * wl_class
+    assert poa_driver._group_widths(cfg, 64) == want
+    assert poa_driver._group_widths(cfg, 32) == want
+    assert poa_driver._group_widths(cfg, 16) == want[-1:]
+    assert poa_driver._group_widths(cfg, 8) == (1,)
+    for real in (1, 16, 17, 32, 47, 64):
+        assert poa_driver._group_width(cfg, 64, real) == (
+            want[0] if len(want) == 1 or real in (17, 32, 64)
+            else want[1])
+
+
 @pytest.mark.parametrize("real,at64,at16,at8", LAUNCH_RULE,
                          ids=[f"rows{r[0]}" for r in LAUNCH_RULE])
 def test_launch_width_follows_the_rows_the_launch_holds(real, at64, at16,
@@ -76,9 +104,11 @@ def test_launch_width_follows_the_rows_the_launch_holds(real, at64, at16,
         assert poa_driver._group_width(cfg, 16, real) == at16
         assert poa_driver._group_width(cfg, 8, real) == at8
     # a geometry VMEM holds at sixteen windows and no wider runs every
-    # launch at sixteen, as before there was a wider program
-    cfg = poa_driver.make_config(1024, 200, *SCORES)
-    assert poa_driver._group_width(cfg, 64, real) == 2
+    # launch at sixteen, as before there was a wider program: class 1024
+    # on both rungs (-w 1000: lambda-ont-w1000.paf)
+    for rung in (0, 1):
+        cfg = poa_driver.make_config(1024, 200, *SCORES, rung)
+        assert poa_driver._group_width(cfg, 64, real) == 2
 
 
 def test_a_program_of_thirty_two_never_replaces_fewer_than_two_of_sixteen():
@@ -97,10 +127,11 @@ def test_a_program_of_thirty_two_never_replaces_fewer_than_two_of_sixteen():
 
 def test_group_width_narrows_where_vmem_does_not_hold_the_wide_program(
         monkeypatch):
-    """Nothing the driver admits today is too large at two groups (class
-    1024 asks for 42 MiB of the 64 a limit may reach), so the ceiling is
-    lowered here: the width then falls to one group, the class stays on
-    the kernel."""
+    """Up to class 1536 nothing is too large at two groups (class 1024
+    asks for 42 MiB of the 64 a limit may reach; past 1536 the width
+    falls to one group by the same rule), so the ceiling is lowered
+    here: the width then falls to one group, the class stays on the
+    kernel."""
     cfg = poa_driver.make_config(1024, 32, *SCORES)
     assert poa_pallas_ls.vmem_limit_bytes(cfg, 2) == 42 << 20
     assert poa_driver._group_width(cfg, 64) == 2
@@ -150,6 +181,9 @@ def test_count_launch_full_batch_of_wide_programs():
                  "poa.programs.wide": 4, "poa.programs.narrow": 0,
                  "poa.width.windows.u1": 0, "poa.width.windows.u2": 64,
                  "poa.width.windows.u4": 0,
+                 # programs compiled under a scoped-VMEM limit of their
+                 # own (PR 47; tests/test_w1000_cell.py)
+                 "poa.vmem.programs.raised": 0,
                  "poa.lockstep.layers.real": sum(layers),
                  "poa.lockstep.layers.slots": by_hand,
                  # the node rungs' counters (tests/test_deep_cell.py)
@@ -414,10 +448,11 @@ def test_launch_width_over_the_even_split(n_real, want):
         assert poa_driver._group_widths(cfg, 32) == (4, 2)
         assert poa_driver._group_width(cfg, 32, -(-n_real // 4)) == want
     # where VMEM holds no program of thirty-two a shard runs two of
-    # sixteen a launch
-    cfg = poa_driver.make_config(1024, 200, *SCORES)
-    assert poa_driver._group_widths(cfg, 32) == (2,)
-    assert poa_driver._group_width(cfg, 32, -(-n_real // 4)) == 2
+    # sixteen a launch, on either rung
+    for rung in (0, 1):
+        cfg = poa_driver.make_config(1024, 200, *SCORES, rung)
+        assert poa_driver._group_widths(cfg, 32) == (2,)
+        assert poa_driver._group_width(cfg, 32, -(-n_real // 4)) == 2
 
 
 #: a program's cost on the chip in units of the one-group program's, by

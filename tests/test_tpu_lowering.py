@@ -110,7 +110,8 @@ def _ls(window_length, depth, B, band=False, scores=SCORES, rung=0,
     # sixteen a part-full launch of 64 runs
     widths = poa_driver._group_widths(cfg, B)
     assert widths[0] == poa_driver._group_width(cfg, B)
-    assert widths[-1] == (1 if B % 16 else 2), (window_length, depth, B)
+    assert widths[-1] == (1 if B % 16 or not poa_driver._fits_vmem(cfg, 2)
+                          else 2), (window_length, depth, B)
     groups = groups or widths[0]
     assert groups in widths, (window_length, depth, B, widths)
     fn = build_lockstep_poa_kernel(cfg, interpret=False, band=band,
@@ -303,8 +304,15 @@ def test_wide_program_past_class_512_needs_the_limit_it_ships_with(
 
 @pytest.mark.parametrize("window_length,B,limit_mib", [
     (500, 8, None), (500, SHARD_BATCH, 27),
-    (768, TPU_BATCH, 39),    # the last class the upper rung is climbed at
-], ids=["w500-u1", "w500-u2", "w768-u2"])
+    (768, TPU_BATCH, 39),    # the last class the upper rung was climbed at
+    # class 1024 (-w 1000, lambda-ont-w1000.paf) since PR 47: the row the
+    # table refused while a program of eight was held under the default
+    # limit's line (12.64 MiB a group), at the batch the cell runs (64:
+    # programs of sixteen, 25.27 MiB under 51), at 16 a shard and at a
+    # batch of 8 (one group under a limit of 26)
+    (1000, TPU_BATCH, 51), (1000, SHARD_BATCH, 51), (1000, 8, 26),
+], ids=["w500-u1", "w500-u2", "w768-u2", "w1000-u2", "w1000-u2-shard",
+        "w1000-u1"])
 def test_upper_rung_program_compiles_for_v5e(window_length, B, limit_mib):
     """The deep cell's program (ecoli-ont-deep.sam): class 512 on the
     upper node rung, 2560 graph slots, node arrays of 20 lane-chunks
@@ -315,9 +323,11 @@ def test_upper_rung_program_compiles_for_v5e(window_length, B, limit_mib):
     it is the geometry's second program, beside the one of thirty-two:
     test_both_programs_of_a_batch_of_64_compile_for_v5e).  At class 768
     VMEM holds no more than sixteen windows of the rung.  Past class 768
-    one group's arrays pass what the default limit holds and
-    poa_driver._rung_capacities leaves the rung out
-    (tests/test_deep_cell.py holds the table)."""
+    one group's arrays pass what the default limit holds; until PR 47
+    poa_driver._rung_capacities left the rung out there, since then the
+    program of eight ships with a limit of its own like the wider ones
+    (class 1024: 5120 graph slots, node arrays of 40 lane-chunks;
+    tests/test_deep_cell.py holds the table)."""
     from racon_tpu.ops import poa_pallas_ls
 
     cfg = poa_driver.make_config(window_length, 200, *SCORES, 1)
@@ -331,18 +341,56 @@ def test_upper_rung_program_compiles_for_v5e(window_length, B, limit_mib):
     _compile_v5e(fn, args)
 
 
-@pytest.mark.parametrize("node_factor,window_length",
-                         [("3", 1000), ("4", 896)])
+@pytest.mark.parametrize("node_factor,window_length,rung,B,limit_mib", [
+    # the last class of each width under the one rule (the limit a
+    # program needs may not pass VMEM_CEILING), at the deepest bucket
+    ("3", 1000, 0, TPU_BATCH, 42),    # upstream's largest documented -w
+    ("3", 1536, 0, TPU_BATCH, 61),    # sixteen windows: the last class
+    ("3", 1280, 1, TPU_BATCH, 63),    # and on the upper rung
+    ("3", 3200, 0, 8, 63),            # eight windows: the last class
+    ("3", 2560, 1, 8, 62),            # and on the upper rung
+    ("4", 1408, 0, TPU_BATCH, 64),    # sixteen at NODE_FACTOR 4
+], ids=["w1000", "w1536-u2", "w1280-upper-u2", "w3200-u1", "w2560-upper-u1",
+        "nf4-w1408-u2"])
 def test_lockstep_poa_kernel_compiles_at_its_largest_class(
-        monkeypatch, node_factor, window_length):
-    # the last geometry poa_driver._fits_vmem approves at each node
-    # factor (upstream's largest documented -w at the default one), at
-    # the deepest bucket: the compiler refuses the next class up (16.3 MB
-    # of scoped VMEM against 16), which is where the budget was drawn
+        monkeypatch, node_factor, window_length, rung, B, limit_mib):
+    """Until PR 47 the line was drawn where the compiler refuses a
+    program of eight under its *default* 16 MB scoped limit (class 1152
+    at NODE_FACTOR 3: 16.3 MB with Mosaic's temporaries); with a limit
+    of its own every width compiles up to the class whose limit reaches
+    the ceiling, and the next class up is not admitted at that width."""
+    from racon_tpu.ops import poa_pallas_ls
+
     monkeypatch.setenv("RACON_TPU_NODE_FACTOR", node_factor)
-    nxt = poa_driver.make_config(window_length + 128, 200, *SCORES)
-    assert not poa_driver._fits_vmem(nxt)
-    _compile_v5e(*_ls(window_length, 200, TPU_BATCH))
+    cfg = poa_driver.make_config(window_length, 200, *SCORES, rung)
+    groups = poa_driver._group_width(cfg, B)
+    assert poa_pallas_ls.vmem_limit_bytes(cfg, groups) == limit_mib << 20
+    if window_length != 1000:
+        nxt = poa_driver.make_config(window_length + 128, 200, *SCORES, rung)
+        assert not poa_driver._fits_vmem(nxt, groups)
+    fn, args = _ls(window_length, 200, B, rung=rung)
+    _compile_v5e(fn, args)
+
+
+def test_program_of_eight_past_the_default_limit_needs_the_one_it_ships_with(
+        monkeypatch):
+    """Class 1024's upper rung at one group, 12.64 MiB of arrays: the
+    compiler refuses it under its default scoped-VMEM limit, which is
+    why the rung was left out of the lockstep kernel until PR 47, and
+    takes it under the 26 MiB that vmem_limit_bytes asks for."""
+    from racon_tpu.ops import poa_pallas_ls
+
+    cfg = poa_driver.make_config(1000, 200, *SCORES, 1)
+    assert poa_pallas_ls.scratch_bytes(cfg) > poa_pallas_ls.DEFAULT_LIMIT_HOLDS
+    assert poa_pallas_ls.vmem_limit_bytes(cfg, 1) == 26 << 20
+    monkeypatch.setattr(poa_pallas_ls, "vmem_limit_bytes",
+                        lambda cfg, groups: None)
+    poa_pallas_ls.build_lockstep_poa_kernel.cache_clear()
+    try:
+        with pytest.raises(Exception, match="memory space vmem"):
+            _compile_v5e(*_ls(1000, 200, 8, rung=1))
+    finally:
+        poa_pallas_ls.build_lockstep_poa_kernel.cache_clear()
 
 
 def test_banded_lockstep_poa_kernel_compiles_for_v5e():
