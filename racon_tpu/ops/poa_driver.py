@@ -894,15 +894,17 @@ class _ConsensusOps:
         live_tier built (which keyed on the same partitioner state
         shard_multiple reads)."""
         m = self.shard_multiple(ctx, None)
-        kernel, groups, raised = ctx.kernel, 0, False
+        kernel, groups, raised, slots_all = ctx.kernel, 0, False, None
         if kind == "ls":
             from .poa_pallas_ls import vmem_limit_bytes
             groups = _group_width(ctx.cfg, self.B // m, -(-n_real // m))
             kernel = kernel.programs[groups]
             raised = vmem_limit_bytes(ctx.cfg, groups) is not None
+            slots_all = _insert_slots_all(packed[3], groups,
+                                          ctx.cfg.max_edges)
         _count_launch(n_real, packed, groups, ctx.rung, m, raised)
         return (_submit(kernel, packed, kind == "ls", _band_active(kind),
-                        ctx.rung), _mesh_order(n_real, self.B, m))
+                        ctx.rung), _mesh_order(n_real, self.B, m), slots_all)
 
     def dispatch(self, ctx, kind, packed, chunk):
         faults.check(f"poa.run.{kind}", [i for i, _, _ in chunk])
@@ -915,9 +917,9 @@ class _ConsensusOps:
                            self._launch(ctx, kind, packed, len(sub)))
 
     def unpack(self, ctx, kind, launched):
-        outs, order = launched
+        outs, order, slots_all = launched
         return _unpack(outs, kind == "ls", _band_active(kind), ctx.rung,
-                       order)
+                       order, slots_all)
 
     def span_args(self, ctx, chunk, pipelined):
         return {"windows": len(chunk), "pipelined": pipelined}
@@ -1181,7 +1183,7 @@ def _build_kernel_cached(cfg, B, use_pallas, n_dev, platform, shard_n=1,
             if shard_n <= 1:
                 return build(B)
             from ..parallel.partitioner import get_partitioner
-            n_in, n_out = (10, 6) if banded else (9, 5)
+            n_in, n_out = (10, 7) if banded else (9, 6)
             sharded = get_partitioner().shard_build(build, B, n_in, n_out)
             # _device_batch divides B
             assert sharded is not None, (B, shard_n)
@@ -1337,6 +1339,27 @@ def _pack(chunk, cfg, pad_to=None, band_widths=None, shards: int = 1):
     return (bb, bbw, bb_len, n_layers, seqs, ws, lens, begins, ends, wband)
 
 
+def _program_layers(n_layers, width: int) -> int:
+    """The layers the lockstep programs of a launch run, summed over its
+    programs: each runs its deepest window's count.  A shard's rows are
+    contiguous and a multiple of the program's width, so programs are
+    consecutive runs of `width` packed rows; on a mesh the pad rows (0
+    layers) sit behind each shard's real rows, not at the end of the
+    batch."""
+    return int(np.asarray(n_layers).reshape(-1, width).max(axis=1).sum())
+
+
+def _insert_slots_all(n_layers, groups: int, max_edges: int) -> int:
+    """The in-edge slots the node-insertion blocks of a lockstep launch
+    sweep where nothing bounds them: `max_edges` a sublane group a layer
+    of each program.  The kernel reports what it swept under its bound
+    (a group's largest in-edge count, read once a layer) in the same
+    unit."""
+    from .poa_pallas_ls import G
+
+    return max_edges * groups * _program_layers(n_layers, groups * G)
+
+
 def _count_launch(n_real, packed, groups: int = 0,
                   rung: str = NODE_RUNGS[0], shards: int = 1,
                   raised: bool = False) -> None:
@@ -1380,13 +1403,9 @@ def _count_launch(n_real, packed, groups: int = 0,
         obs.count("poa.vmem.programs.raised", programs if raised else 0)
         for u in GROUP_WIDTHS:
             obs.count(f"poa.width.windows.u{u}", n_real if u == groups else 0)
-        # a shard's rows are contiguous and a multiple of the program's
-        # width, so programs are consecutive runs of the packed rows; on
-        # a mesh the pad rows (0 layers) sit behind each shard's real
-        # rows, not at the end of the batch
         obs.count("poa.lockstep.layers.real", int(n_layers.sum()))
-        obs.count("poa.lockstep.layers.slots", int(
-            width * n_layers.reshape(-1, width).max(axis=1).sum()))
+        obs.count("poa.lockstep.layers.slots",
+                  width * _program_layers(n_layers, width))
         if shards > 1:
             held = np.bincount(
                 _mesh_order(n_real, rows, shards)[:n_real]
@@ -1418,17 +1437,24 @@ class _Unpacked(tuple):
     """_unpack's host arrays, (cons_base, cons_cov, cons_len, failed[,
     band_hit]) as every caller takes them apart; `nodes`, the kernels'
     fifth output (each window's graph size at the end), rides beside
-    them for _install's fill counters."""
+    them for _install's fill counters, and so do the in-edge slots the
+    lockstep launch's node insertions swept (`slots_swept`, the kernel's
+    last output summed over its programs; None from the XLA twin) and
+    would have swept unbounded (`slots_all`: _insert_slots_all, from the
+    launch, which knows its programs)."""
 
     nodes = None
+    slots_swept = None
+    slots_all = None
 
 
 def _unpack(outs, use_pallas, banded=False, rung: str = NODE_RUNGS[0],
-            order=None):
+            order=None, slots_all=None):
     """Block on device futures; normalize to host arrays.  `failed` is 0
     for a served window, else the cause (poa.FAIL_CAUSES).  `order` is
     the batch's _mesh_order: row p of every array returned is chunk
-    item p's, wherever _pack put it."""
+    item p's, wherever _pack put it.  `slots_all` is the launch's
+    _insert_slots_all, handed on beside what the kernel swept."""
     cb, cc, cl, fl = outs[0], outs[1], outs[2], outs[3]
     with obs.span("poa.wait", cat="launch", B=len(cb), rung=rung):
         cons_base = np.asarray(cb)
@@ -1438,6 +1464,7 @@ def _unpack(outs, use_pallas, banded=False, rung: str = NODE_RUNGS[0],
         nodes = np.asarray(outs[4])
         band_hit = (np.asarray(outs[5])[:, 0]
                     if use_pallas and banded else None)
+        swept = int(np.asarray(outs[-1]).sum()) if use_pallas else None
     if use_pallas:
         cons_len, failed, nodes = cons_len[:, 0], failed[:, 0], nodes[:, 0]
     if order is not None:
@@ -1448,6 +1475,7 @@ def _unpack(outs, use_pallas, banded=False, rung: str = NODE_RUNGS[0],
     res = _Unpacked((cons_base, cons_cov, cons_len, failed)
                     + ((band_hit,) if use_pallas and banded else ()))
     res.nodes = nodes
+    res.slots_swept, res.slots_all = swept, slots_all
     return res
 
 
@@ -1552,6 +1580,12 @@ def _install(pipeline, chunk, results, trim, stats, fallback, report=None,
         obs.count("poa.nodes.used", nodes_used)
         obs.count("poa.nodes.capacity", n_served * cons_base.shape[1])
         obs.count("poa.backbone.bases", backbone_bases)
+    # how far the kernel's bound on the node-insertion sweep engaged
+    swept = getattr(results, "slots_swept", None)
+    slots_all = getattr(results, "slots_all", None)
+    if swept is not None and slots_all is not None:
+        obs.count("poa.insert.slots.swept", swept)
+        obs.count("poa.insert.slots.all", slots_all)
     for cause, name in poa.FAIL_CAUSES.items():
         obs.count(f"poa.windows.overflow.{name}", overflow[cause])
     obs.count("poa.windows.trim.admitted", n_trimmed)

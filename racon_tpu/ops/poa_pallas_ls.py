@@ -145,14 +145,15 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
         if band:
             (bb_len_s, n_layers_s, lens_s, begins_s, ends_s,
              bb_ref, bbw_ref, seqs_hbm, ws_hbm, wband_s,
-             cons_base_ref, cons_cov_ref, cl_s, fl_s, nn_s, bh_s, hbm_H,
+             cons_base_ref, cons_cov_ref, cl_s, fl_s, nn_s, bh_s, sw_s,
+             hbm_H,
              Hring, H0, rk_base, rk_key, rk_cov, rk_cnt, rk_delta, rk_ew,
              rk_dmax, esc, score, spred, revbuf, nkey, runrem,
              seq_scr, w_scr, dma_sem, flush_sem, tb_sem) = refs
         else:
             (bb_len_s, n_layers_s, lens_s, begins_s, ends_s,
              bb_ref, bbw_ref, seqs_hbm, ws_hbm,
-             cons_base_ref, cons_cov_ref, cl_s, fl_s, nn_s, hbm_H,
+             cons_base_ref, cons_cov_ref, cl_s, fl_s, nn_s, sw_s, hbm_H,
              Hring, H0, rk_base, rk_key, rk_cov, rk_cnt, rk_delta, rk_ew,
              rk_dmax, esc, score, spred, revbuf, nkey, runrem,
              seq_scr, w_scr, dma_sem, flush_sem, tb_sem) = refs
@@ -341,10 +342,11 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
 
         # ================= one layer =====================================
         def do_layer(li, slot, carry):
+            # n, failed[, hit]: (U,1,G,1) i32; swept: i32 scalar
             if band:
-                n, failed, hit = carry                 # (U,1,G,1) i32
+                n, failed, hit, swept = carry
             else:
-                n, failed = carry                      # (U,1,G,1) i32
+                n, failed, swept = carry
             Ln = svec(lambda i: lens_s[0, i, li])
             begin = svec(lambda i: begins_s[0, i, li])
             end = svec(lambda i: ends_s[0, i, li])
@@ -681,6 +683,19 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             # ---- graph update (parity: rt_poa.cpp add_alignment) --------
             maxL = jnp.max(jnp.where(lact & (failed == 0), Ln, 0))
 
+            # In-edge slots a group's node insertions sweep this layer:
+            # a slot at or past a row's rk_cnt is zero in rk_delta and
+            # rk_ew (an edge is written at slot rk_cnt, an inserted row
+            # starts at 0, rk_cnt moves with its row), and a layer adds
+            # at most one in-edge to a node (the path visits a column
+            # once), so one more than the group's largest count at the
+            # layer's start bounds every slot that holds anything, or
+            # comes to hold it, before the next layer.  One
+            # vector-to-scalar turn a group a layer, not one a step.
+            k_ins = [jnp.minimum(1 + jnp.max(rk_cnt[u:u + 1]), E)
+                     for u in range(U)]
+            swept = swept + sum(k_ins)
+
             def upd_body(j, c):
                 n, failed, prev_r, prev_key, prev_w = c
                 act = lact & (j < Ln) & (failed == 0)
@@ -730,15 +745,16 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                             v = jnp.where(sh, shift_right(v, fill), v)
                             ref[grp] = jnp.where(new_row, val, v)
 
-                        # A loop over the edge slots, not E copies of
-                        # its body: the block is traced and lowered once
-                        # per group, and E copies made a program's
-                        # trace + lower 28 % longer at two groups, which
-                        # set-up pays 3 to 12 times a process.  Measured
-                        # on the v5e: the loop costs 5-8 % of the
-                        # kernel's time at ONT error rates and under 1 %
-                        # on short reads; four slots a trip cost the
-                        # same, so it is the dynamic slot, not the trip.
+                        # Slots 0 .. k_ins[u] - 1 and no others: a slot
+                        # at or past a row's rk_cnt is zero, so shifting
+                        # it moves nothing.  Measured on the v5e (PR 48;
+                        # the loop swept all E until then): 12.87 ->
+                        # 12.00 s of kernel over two 30x ONT jobs at
+                        # 55 % of the slots swept, x1.07 end to end there,
+                        # x1.01 at 200 layers, x1.02 on short reads.  E
+                        # copies under pl.when(e < k_ins[u]), each with a
+                        # static slot, ran the same seconds (12.01) and
+                        # made a tree's first run 17 s longer: a loop.
                         def shift_slot(e, _):
                             slot = pl.ds(e, 1)
                             vd = rk_delta[slot, grp][0]
@@ -758,7 +774,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                                           vw))[None]
                             return 0
 
-                        jax.lax.fori_loop(0, E, shift_slot, 0)
+                        jax.lax.fori_loop(0, k_ins[u], shift_slot, 0)
 
                 for u in range(U):
                     insert_node(u)
@@ -817,7 +833,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                  jnp.full(w_shape, -1, jnp.int32),
                  jnp.full(w_shape, -1.0, jnp.float32),
                  jnp.zeros(w_shape, jnp.int32)))
-            return (n, failed, hit) if band else (n, failed)
+            return (n, failed, hit, swept) if band else (n, failed, swept)
 
         @pl.when(max_layers > 0)
         def _():
@@ -834,14 +850,14 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             return do_layer(li, slot, carry)
 
         if band:
-            n, failed, hit = jax.lax.fori_loop(
+            n, failed, hit, swept = jax.lax.fori_loop(
                 0, max_layers, layer_loop,
                 (bb_len, jnp.zeros(w_shape, jnp.int32),
-                 jnp.zeros(w_shape, jnp.int32)))
+                 jnp.zeros(w_shape, jnp.int32), jnp.int32(0)))
         else:
-            n, failed = jax.lax.fori_loop(
+            n, failed, swept = jax.lax.fori_loop(
                 0, max_layers, layer_loop,
-                (bb_len, jnp.zeros(w_shape, jnp.int32)))
+                (bb_len, jnp.zeros(w_shape, jnp.int32), jnp.int32(0)))
 
         # ================= consensus =====================================
         # (parity: rt_poa.cpp generate_consensus — heaviest bundle)
@@ -948,6 +964,9 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             nn_s[0, 0, i] = scalar_of(n, i)
             if band:
                 bh_s[0, 0, i] = jnp.where(scalar_of(hit, i) > 0, 1, 0)
+        # in-edge slots the program's node-insertion blocks were bounded
+        # to, summed over its groups and layers (E a group a layer: all)
+        sw_s[0, 0, 0] = swept
 
     def make(batch: int):
         assert batch % W == 0, (batch, U, G)
@@ -970,18 +989,22 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             return pltpu.VMEM(lead + (U, JC, G, 128), dtype)
 
         gshape = jax.ShapeDtypeStruct((nb, 1, W), jnp.int32)
+        # one scalar a program
+        smem1 = pl.BlockSpec((1, 1, 1), lambda b: (b, 0, 0),
+                             memory_space=pltpu.SMEM)
         return pl.pallas_call(
             kernel,
             grid=(nb,),
             in_specs=[smem2, smem2, smem3, smem3, smem3, vblk, vblk,
                       hbm, hbm] + ([smem2] if band else []),
             out_specs=[vblk, vblk, smem2, smem2, smem2] +
-                      ([smem2] if band else []) + [hbm],
+                      ([smem2] if band else []) + [smem1, hbm],
             out_shape=[
                 jax.ShapeDtypeStruct((nb, U, NC, G, 128), jnp.int32),
                 jax.ShapeDtypeStruct((nb, U, NC, G, 128), jnp.int32),
                 gshape, gshape, gshape,
             ] + ([gshape] if band else []) + [
+                jax.ShapeDtypeStruct((nb, 1, 1), jnp.int32),
                 jax.ShapeDtypeStruct((nb, N, U, JC, G, 128), jnp.int32),
             ],
             scratch_shapes=[
@@ -1046,7 +1069,8 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                    nn.reshape(batch, 1))
             if band:
                 res = res + (outs[5].reshape(batch, 1),)
-            return res
+            # last: the in-edge slots each program's insertions swept
+            return res + (outs[-2].reshape(nb),)
 
         return Program(fn, key=("racon_poa_ls", cfg, interpret, band, U,
                                 batch))

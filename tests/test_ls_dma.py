@@ -73,9 +73,12 @@ interp = pltpu.InterpretParams(dma_execution_mode="on_wait",
                                uninitialized_memory="nan")
 ls = poa_pallas_ls.build_lockstep_poa_kernel(cfg, interpret=interp,
                                              groups=U)(B)
-cb, cc, cl, fl, nn = (np.asarray(x) for x in ls(
+cb, cc, cl, fl, nn, swept = (np.asarray(x) for x in ls(
     bb_len[:, None], nl[:, None], lens, bg, en, bb.astype(np.int32), bbw,
     seqs.astype(np.int32), ws))
+# perfect reads: the chain's one in-edge plus one, a group a layer (the
+# scalar is written under uninitialised-memory NaNs like everything else)
+assert swept.tolist() == [2 * U * 3] * (B // W), swept
 jb, jc, jl, jf, jn = (np.asarray(x) for x in poa.build_poa_kernel(cfg)(
     bb, bbw, bb_len, nl, seqs, ws, lens, bg, en))
 assert not fl.any() and not jf.any(), (fl.ravel(), jf.ravel())

@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from racon_tpu import native
-from racon_tpu.ops import poa, poa_pallas_ls
+from racon_tpu.ops import poa, poa_driver, poa_pallas_ls
 from racon_tpu.ops.encoding import decode, encode
 
 
@@ -74,14 +74,18 @@ GROUPS = pytest.mark.parametrize("groups", [1, 2, 4],
                                  ids=["u1", "u2", "u4"])
 
 
-def _run_ls(a, cfg, groups=1):
+def _run_ls(a, cfg, groups=1, swept=False):
+    """The kernel's five per-window outputs; with `swept`, beside them
+    the in-edge slots each program's node insertions swept."""
     B = len(a["bb"])
     ls_fn = poa_pallas_ls.build_lockstep_poa_kernel(
         cfg, interpret=True, groups=groups)(B)
-    return tuple(np.asarray(x) for x in ls_fn(
+    outs = tuple(np.asarray(x) for x in ls_fn(
         a["bb_len"][:, None], a["nl"][:, None], a["lens"], a["bg"],
         a["en"], a["bb"].astype(np.int32), a["bbw"],
         a["seqs"].astype(np.int32), a["ws"]))
+    assert outs[5].shape == (B // (8 * groups),)
+    return (outs[:5], outs[5]) if swept else outs[:5]
 
 
 def _deal(a, cfg, groups):
@@ -394,6 +398,305 @@ def test_wide_program_equals_programs_of_eight(fill, groups):
         a["bb"], a["bbw"], a["bb_len"], a["nl"], a["seqs"], a["ws"],
         a["lens"], a["bg"], a["en"])[3])
     assert not jf.any()
+
+
+# -- the node-insertion block sweeps the in-edge slots its group uses -------
+
+EDGE_CFG = poa.PoaConfig(max_nodes=384, max_len=256, max_backbone=128,
+                         max_edges=12, depth=16, match=5, mismatch=-4,
+                         gap=-8)
+E = EDGE_CFG.max_edges
+HUB = 40                         # the backbone position _fan_in feeds
+
+
+def _fan_in(n_layers, tail=0):
+    """A backbone and `n_layers` layers, each of which gives the node of
+    backbone position HUB one more in-edge from a node of its own, so the
+    hub holds 1 + n_layers in-edges after them (layer 12 asks for a
+    thirteenth): three substitutions of the base before the hub, then
+    insertions before it, two bases a depth, down five depths.  No
+    inserted base equals a neighbour, so every alignment is the only
+    best one.  `tail` perfect layers follow."""
+    rng = random.Random(5)
+    bb = bytearray(rng.choice(b"ACGT") for _ in range(70))
+    bb[HUB - 2:HUB + 1] = b"CGT"
+    bb = bytes(bb)
+    layers = [bb[:HUB - 1] + s + bb[HUB:] for s in (b"A", b"C", b"T")]
+    chain = b""
+    for pair in (b"AC", b"CG", b"AG", b"CG", b"AG"):
+        layers += [bb[:HUB] + chain + bytes([b]) + bb[HUB:] for b in pair]
+        chain += pair[:1]
+    return bb, layers[:n_layers] + [bb] * tail
+
+
+def _twin_graph_walk(cfg, a, b):
+    """Window b's graph as the XLA twin builds it, layer by layer: the
+    most in-edges of one node and the node count before each layer and
+    after the last, and the cause it ended on.  The twin keeps in-edges
+    as source ids, not as slots by rank distance: an oracle of its own
+    for what the lockstep kernel reads out of rk_cnt."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    add = jax.jit(functools.partial(poa._add_layer, cfg))
+    g = poa._init_graph(cfg, *(jnp.asarray(a[k][b])
+                               for k in ("bb", "bbw", "bb_len")))
+    edges, nodes = [], []
+    for li in range(int(a["nl"][b]) + 1):
+        edges.append(int((g.in_src >= 0).sum(axis=1).max()))
+        nodes.append(int(g.n))
+        if li < a["nl"][b] and int(g.failed) == 0:
+            g = add(g, *(jnp.asarray(a[k][b, li])
+                         for k in ("seqs", "ws", "lens", "bg", "en")),
+                    jnp.asarray(a["bb_len"][b]))
+    return edges, nodes, int(g.failed)
+
+
+def _expected_sweep(cfg, a, groups):
+    """What one program of `groups` sublane groups reports for the batch
+    `a`, a group: over the program's layers, min(E, 1 + the most
+    in-edges a node of the group's windows holds before the layer), from
+    the twin's graphs.  A window past its last layer keeps its graph."""
+    walks = [_twin_graph_walk(cfg, a, b)[0] for b in range(8 * groups)]
+    return [sum(min(cfg.max_edges,
+                    1 + max(w[min(li, len(w) - 1)]
+                            for w in walks[8 * u:8 * u + 8]))
+                for li in range(int(a["nl"].max())))
+            for u in range(groups)]
+
+
+def _check_against_twin_and_host(a, cfg, ls, cases):
+    """ls's outputs for the windows of `cases` (index -> backbone, layers)
+    against the XLA twin (coverage, node count, failure cause) and the
+    host engine (consensus)."""
+    cb, cc, cl, fl, nn = ls
+    jb, jc, jl, jf, jn = (np.asarray(x) for x in poa.build_poa_kernel(cfg)(
+        a["bb"], a["bbw"], a["bb_len"], a["nl"], a["seqs"], a["ws"],
+        a["lens"], a["bg"], a["en"]))
+    np.testing.assert_array_equal(fl[:, 0], jf, err_msg="failure cause")
+    for b, (backbone, layers) in cases.items():
+        if fl[b, 0]:
+            continue
+        host, _ = native.window_consensus(backbone, list(layers), trim=False)
+        assert decode(cb[b, :cl[b, 0]]) == decode(jb[b, :jl[b]]) == host, b
+        assert int(nn[b, 0]) == int(jn[b]), f"window {b} node count"
+        np.testing.assert_array_equal(cc[b, :cl[b, 0]], jc[b, :jl[b]],
+                                      err_msg=f"window {b} coverage")
+
+
+@pytest.mark.parametrize("n_layers,ends_at", [
+    (0, 1), (2, 3), (E - 2, E - 1), (E - 1, E), (E, "past E"),
+], ids=["1", "3", "E-1", "E", "past-E"])
+def test_insert_sweep_follows_the_groups_largest_in_edge_count(n_layers,
+                                                               ends_at):
+    """One window whose hub node ends at 1, 3, E - 1 and E in-edges, and
+    one that asks for E + 1 (FAIL_EDGES, the host's from there), beside a
+    noisy mate in a program of eight: consensus, coverage, node count and
+    failure cause are the twin's and the host's, and the program swept,
+    layer by layer, one slot more than the most in-edges a node held."""
+    rng = random.Random(48)
+    a = _alloc(8, EDGE_CFG)
+    hub_bb, hub_layers = _fan_in(n_layers, tail=0 if n_layers else 3)
+    truth = bytes(rng.choice(b"ACGT") for _ in range(90))
+    mate = (mutate(truth, 0.1, rng), [mutate(truth, 0.1, rng)
+                                      for _ in range(4)])
+    cases = {0: (hub_bb, hub_layers), 1: mate}
+    for b, (backbone, layers) in cases.items():
+        _set_window(a, b, backbone, layers)
+
+    edges, _, cause = _twin_graph_walk(EDGE_CFG, a, 0)
+    if ends_at == "past E":
+        assert edges[-2:] == [E, E] and cause == poa.FAIL_EDGES
+    else:
+        assert edges[-1] == ends_at and cause == 0
+        assert edges[:n_layers + 1] == list(range(1, n_layers + 2))
+    mate_most = max(_twin_graph_walk(EDGE_CFG, a, 1)[0])
+    assert 1 < mate_most < E - 2
+
+    ls, swept = _run_ls(a, EDGE_CFG, swept=True)
+    assert ls[3][0, 0] == (poa.FAIL_EDGES if ends_at == "past E" else 0)
+    _check_against_twin_and_host(a, EDGE_CFG, ls, cases)
+    assert swept.tolist() == _expected_sweep(EDGE_CFG, a, 1)
+    slots_all = poa_driver._insert_slots_all(a["nl"], 1, E)
+    assert slots_all == E * int(a["nl"].max())
+    assert 2 * int(a["nl"].max()) <= swept[0] < slots_all
+
+
+def test_edge_written_at_the_bound_then_nodes_inserted_in_the_same_layer():
+    """The `+ 1` of the bound.  The hub holds the group's most in-edges,
+    3; one layer gives it a fourth (written at slot 3, the last the
+    layer's bound of 4 covers) and further along inserts two nodes more;
+    the next layer inserts a node below the hub, so the hub's row moves
+    with all four edges.  (Inside one layer a row cannot move after it
+    gained its edge: the path climbs in rank, and a node is inserted
+    above every row the layer has touched.)"""
+    a = _alloc(8, EDGE_CFG)
+    bb, layers = _fan_in(2)
+    far = HUB + 12
+    other = bytes([next(c for c in b"ACGT"
+                        if c not in (bb[far], bb[far - 1], bb[far + 1]))])
+    both = bb[:HUB] + b"A" + bb[HUB:far] + other + bb[far:]
+    sub = next(bytes([c]) for c in b"ACGT" if c != bb[HUB - 8])
+    below = bb[:HUB - 8] + sub + bb[HUB - 7:]
+    layers = layers + [both, below, bb]
+    _set_window(a, 0, bb, layers)
+
+    edges, nodes, cause = _twin_graph_walk(EDGE_CFG, a, 0)
+    assert cause == 0
+    assert edges == [1, 2, 3, 4, 4, 4]          # `both` is layer 2
+    assert nodes[3] - nodes[2] == 2              # hub's source + `other`
+    assert nodes[4] - nodes[3] == 1              # `below`
+    ls, swept = _run_ls(a, EDGE_CFG, swept=True)
+    _check_against_twin_and_host(a, EDGE_CFG, ls, {0: (bb, layers)})
+    assert swept.tolist() == [2 + 3 + 4 + 5 + 5]
+
+
+def _pad_beside_deep():
+    """A program of thirty-two: group 0 holds the window whose hub
+    reaches E in-edges and noisy mates, group 1 perfect reads alone,
+    group 2 noisy windows, group 3 pad rows alone."""
+    rng = random.Random(32)
+    a = _alloc(32, EDGE_CFG)
+    cases = {0: _fan_in(E - 1, tail=2)}
+    for b in (1, 2, 5, 16, 17, 20):
+        truth = bytes(rng.choice(b"ACGT") for _ in range(rng.randrange(50,
+                                                                       100)))
+        cases[b] = (mutate(truth, 0.1, rng),
+                    [mutate(truth, 0.12, rng)
+                     for _ in range(rng.randrange(3, 9))])
+    for b in (8, 9, 13):
+        truth = bytes(rng.choice(b"ACGT") for _ in range(60 + b))
+        cases[b] = (truth, [truth] * (b - 4))
+    for b, (backbone, layers) in cases.items():
+        _set_window(a, b, backbone, layers)
+    return a, cases
+
+
+def test_pad_group_beside_a_deep_one_each_sweeps_its_own_slots():
+    """The bound is a group's, not the program's: in one program of
+    thirty-two the deep group climbs to all E slots while the group of
+    perfect reads stays at 2 and the pad group at 1, and every window
+    gets what it gets in a program of eight."""
+    a, cases = _pad_beside_deep()
+    wide, swept = _run_ls(a, EDGE_CFG, groups=4, swept=True)
+    narrow, swept8 = _run_ls(a, EDGE_CFG, groups=1, swept=True)
+    for name, x, y in zip(("consensus", "coverage", "length", "failed",
+                           "nodes"), narrow, wide):
+        np.testing.assert_array_equal(x, y, err_msg=name)
+    assert not wide[3].any()
+    _check_against_twin_and_host(a, EDGE_CFG, wide, cases)
+
+    layers = int(a["nl"].max())
+    assert layers == E + 1
+    by_group = _expected_sweep(EDGE_CFG, a, 4)
+    assert by_group[1] == 2 * layers and by_group[3] == layers
+    assert by_group[0] > by_group[2] > by_group[1]
+    assert by_group[0] == sum(range(2, E + 1)) + 2 * E
+    assert swept.tolist() == [sum(by_group)]
+    assert swept[0] < poa_driver._insert_slots_all(a["nl"], 4, E) \
+        == E * 4 * layers
+    # a program of eight runs its own layer count: the pad program none
+    assert swept8[3] == 0 and swept8[1] == 2 * int(a["nl"][8:16].max())
+
+
+# -- poa.insert.slots.swept / .all and poa_insert_slot_sweep_share ----------
+
+def test_sweep_equals_all_from_the_layer_a_window_holds_E_in_edges():
+    """Once a node of the group holds E - 1 in-edges the bound is E:
+    every further layer sweeps every slot, and swept grows as all does."""
+    runs = {}
+    for tail in (1, 4):
+        a = _alloc(8, EDGE_CFG)
+        _set_window(a, 0, *_fan_in(E - 1, tail=tail))
+        runs[tail] = (int(_run_ls(a, EDGE_CFG, swept=True)[1][0]),
+                      poa_driver._insert_slots_all(a["nl"], 1, E))
+    (swept1, all1), (swept4, all4) = runs[1], runs[4]
+    assert swept4 - swept1 == all4 - all1 == 3 * E
+    assert swept1 == sum(range(2, E + 1)) + E < all1 == E * E
+
+
+def test_driver_counts_two_slots_of_twelve_on_reads_equal_to_the_backbone(
+        tmp_path, monkeypatch):
+    """Through the consensus driver: reads identical to the backbone add
+    no edge, so every layer's bound is the chain's one in-edge plus one,
+    2 of 12, and the driver counts it at install beside all twelve."""
+    from racon_tpu import obs
+
+    target = _perfect_reads_dataset(tmp_path)
+    monkeypatch.setenv("RACON_TPU_PALLAS", "1")
+    monkeypatch.setenv("RACON_TPU_SHARD", "0")
+    monkeypatch.setenv("RACON_TPU_BATCH_WINDOWS", "8")
+    monkeypatch.setenv("RACON_TPU_METRICS", "1")   # the polisher arms obs
+    try:
+        res, phase = _polish_perfect_reads(tmp_path)
+        counters = obs.snapshot()["counters"]
+    finally:
+        obs.reset()
+    assert res[0][1] == target and phase["served"]["ls"] == 3
+    # three windows in one program of eight, four layers each
+    assert counters["poa.insert.slots.all"] == 12 * 1 * 4
+    assert counters["poa.insert.slots.swept"] == 2 * 1 * 4
+    assert counters["poa.launches"] == 1
+
+
+def test_xla_twin_counts_no_insert_slots(tmp_path, monkeypatch):
+    """The twin has no slot sweep to bound: it reports nothing, and the
+    driver counts nothing (the metric reads nothing, as on a program
+    that predates the counters)."""
+    from racon_tpu import obs
+
+    _perfect_reads_dataset(tmp_path)
+    monkeypatch.setenv("RACON_TPU_PALLAS", "0")
+    monkeypatch.setenv("RACON_TPU_SHARD", "0")
+    monkeypatch.setenv("RACON_TPU_METRICS", "1")
+    try:
+        _, phase = _polish_perfect_reads(tmp_path)
+        counters = obs.snapshot()["counters"]
+    finally:
+        obs.reset()
+    assert phase["served"]["xla"] == 3 and counters["poa.launches"] >= 1
+    assert not [k for k in counters if k.startswith("poa.insert.")]
+
+
+def _bench_cells():
+    import json
+    import os
+
+    from benchmark import loader
+    with open(os.path.join(loader.ROOT, "BENCHMARK.json")) as f:
+        return [w["name"] for w in json.load(f)["workloads"]]
+
+
+@pytest.mark.parametrize("cell_name", _bench_cells())
+def test_sweep_share_metric_loads_and_reads_its_counters(cell_name):
+    from benchmark import loader, reducers
+
+    cell = loader.load_cell(cell_name)        # the file agrees with its entry
+    spec = {m["name"]: m for m in cell.per_layer}[
+        "poa_insert_slot_sweep_share"]
+    assert spec["workloads"] == _bench_cells() and len(spec["workloads"]) == 10
+    assert (spec["layer"], spec["moves"], spec["unit"], spec["better"],
+            spec["source"]) == ("kernels", "polished_mbp_per_s", "%",
+                                "lower", "program_counter")
+    assert spec["reducer"] == "counter_share" and spec["what"]
+    read = reducers.registry()[spec["reducer"]]
+
+    def run(*job_counters):
+        return {"jobs": [{"counters": c, "spans": {}, "phases": {}}
+                         for c in job_counters]}
+
+    job = {"poa.insert.slots.swept": 8, "poa.insert.slots.all": 48,
+           "poa.launches": 1}
+    deep = {"poa.insert.slots.swept": 96, "poa.insert.slots.all": 96}
+    assert read(run(job), **spec["params"]) == pytest.approx(100 * 2 / 12)
+    assert read(run(job, deep), **spec["params"]) == pytest.approx(
+        100 * 104 / 144)
+    assert read(run(deep), **spec["params"]) == 100.0
+    # the parent's program counts neither: nothing, and no error
+    older = {"poa.launches": 18, "poa.rows.real": 1000}
+    assert read(run(older, older), **spec["params"]) is None
 
 
 def test_lockstep_production_geometry_real_window():

@@ -346,6 +346,7 @@ def test_obs_report_lists_the_program_counters():
                 "poa.width.windows.u1": 0, "poa.width.windows.u2": 40,
                 "poa.width.windows.u4": 960,
                 "poa.mesh.rows.real": 1000, "poa.mesh.fullest.slots": 1004,
+                "poa.insert.slots.swept": 9000, "poa.insert.slots.all": 21600,
                 "align.mesh.launches.single": 380, "poa.launches": 18}
     text = obs_cli.render(
         {"traceEvents": [], "racon_tpu": {"metrics": {"counters": counters}}},
@@ -355,7 +356,8 @@ def test_obs_report_lists_the_program_counters():
     section = text.split("-- consensus programs in lock-step")[1]
     for name in counters:
         assert (name in section) == name.startswith(
-            ("poa.programs.", "poa.lockstep.", "poa.width.", "poa.mesh."))
+            ("poa.programs.", "poa.lockstep.", "poa.width.", "poa.mesh.",
+             "poa.insert."))
 
 
 # -- the shape of a launch on a mesh (PR 45) --------------------------------
