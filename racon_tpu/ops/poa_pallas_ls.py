@@ -29,8 +29,12 @@ run; 2 at 16 rows a shard):
     edges (RT_POA_STATS histograms), so distances are capped at DMAX=64
     and a window with a longer in-subgraph edge fails to the host path
     (the same degradation lattice as every other device limit).
-    Insertion alone is bound by throughput, not latency, and is gated
-    per group: a group pays for its own windows' insertions only.
+    Insertion is gated per group: a group pays for its own windows'
+    insertions only.  What it pays is the number of loop steps it runs
+    on the in-edge slot arrays (a step is a serial chain of load, lane
+    roll, selects and store on one ref, ~150 cycles on the v5e whether
+    it carries 2 vregs an array or 24), so the slots a group uses are
+    shifted SLOT_BLOCK at a step.
   * H rows live in a 128-row rank-keyed VMEM ring (RING, U, JC, 8, 128)
     (the distance cap makes older rows dead); completed 64-row chunks
     are DMA'd to an HBM spill buffer under the compute.
@@ -49,6 +53,7 @@ batch orchestration mirrors the reference's cudapoa batch
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -68,6 +73,7 @@ DMAX = 64        # max predecessor rank distance the device accepts
 KEY_INF = 3.0e38
 BIG = 1 << 20    # "no slot" sentinel inside packed slot*256+delta minima
 WNONE = BIG * 512
+SLOT_BLOCK = 4   # in-edge slots a step of the node-insertion loop shifts
 
 
 def _round_up(x, m):
@@ -129,6 +135,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
     D = cfg.depth
     assert N % 128 == 0 and BB <= N
     NC = N // 128                       # node/rank lane-chunks
+    SB = math.gcd(E, SLOT_BLOCK)        # slots a step: whole blocks in E
     JL = _round_up(L + 1, 128)
     JC = JL // 128                      # j lane-chunks
     M = int(cfg.match)
@@ -724,10 +731,8 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 p_ins = wsum(jnp.where(keys <= key_val, 1, 0))
                 nid = jnp.where(has, found, jnp.minimum(p_ins, N - 1))
 
-                # Node insertion is throughput-bound (2 * E + 4 arrays
-                # of NC vregs shifted), not a dependency chain: each
-                # group pays for its own insertions only, under its own
-                # gate.  Everything else in the step is shared.
+                # Each group pays for its own insertions only, under
+                # its own gate.  Everything else in the step is shared.
                 def insert_node(u):
                     grp = pl.ds(u, 1)
                     dn = do_new[u:u + 1]
@@ -745,19 +750,25 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                             v = jnp.where(sh, shift_right(v, fill), v)
                             ref[grp] = jnp.where(new_row, val, v)
 
-                        # Slots 0 .. k_ins[u] - 1 and no others: a slot
-                        # at or past a row's rk_cnt is zero, so shifting
-                        # it moves nothing.  Measured on the v5e (PR 48;
-                        # the loop swept all E until then): 12.87 ->
-                        # 12.00 s of kernel over two 30x ONT jobs at
-                        # 55 % of the slots swept, x1.07 end to end there,
-                        # x1.01 at 200 layers, x1.02 on short reads.  E
-                        # copies under pl.when(e < k_ins[u]), each with a
-                        # static slot, ran the same seconds (12.01) and
-                        # made a tree's first run 17 s longer: a loop.
-                        def shift_slot(e, _):
-                            slot = pl.ds(e, 1)
-                            vd = rk_delta[slot, grp][0]
+                        # Slots 0 .. k_ins[u] - 1, rounded up to whole
+                        # blocks of SB: a slot at or past a row's rk_cnt
+                        # is zero, so shifting it moves nothing.
+                        # Measured on the v5e, two 30x ONT jobs of
+                        # kernel: all E slots in one pass 12.87 s; a
+                        # loop of k_ins steps, a slot a step, 12.00 s
+                        # (PR 48; E copies under pl.when the same); SB
+                        # slots a step as one load, one shift and one
+                        # store (PR 49), x1.026 end to end at SB = 4
+                        # against x1.019 / x1.018 / x1.015 at 2 / 6 / 12.
+                        # A step costs its serial chain, not its vregs:
+                        # bounding the lane-chunks a step shifts to
+                        # those the insertion reaches (2 to 6 chunks a
+                        # step, down from a bound on the group's node
+                        # counts) ran x0.78 to x0.98, each step of that
+                        # walk running this loop once more.
+                        def shift_slots(i, _):
+                            slots = pl.ds(i * SB, SB)
+                            vd = rk_delta[slots, grp][:, 0]
                             sd = shift_right(vd, 0)
                             # an edge whose source sits below the
                             # insertion point now spans it: distance
@@ -765,16 +776,18 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                             sd = sd + jnp.where(
                                 (sd > 0) & (rr - 1 - sd < pi), 1, 0)
                             # the inserted row starts with no edges
-                            rk_delta[slot, grp] = jnp.where(
-                                new_row, 0, jnp.where(sh, sd, vd))[None]
-                            vw = rk_ew[slot, grp][0]
-                            rk_ew[slot, grp] = jnp.where(
+                            rk_delta[slots, grp] = jnp.where(
+                                new_row, 0,
+                                jnp.where(sh, sd, vd))[:, None]
+                            vw = rk_ew[slots, grp][:, 0]
+                            rk_ew[slots, grp] = jnp.where(
                                 new_row, 0,
                                 jnp.where(sh, shift_right(vw, 0),
-                                          vw))[None]
+                                          vw))[:, None]
                             return 0
 
-                        jax.lax.fori_loop(0, k_ins[u], shift_slot, 0)
+                        jax.lax.fori_loop(0, (k_ins[u] + SB - 1) // SB,
+                                          shift_slots, 0)
 
                 for u in range(U):
                     insert_node(u)

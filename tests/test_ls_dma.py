@@ -65,9 +65,14 @@ for b in range(B):
         truth = bytes(rng.choice(b"ACGT") for _ in range(2 * BLK))
         put(b, truth, [truth] * 3, 0, len(truth) - 1)
     else:
-        # every layer of the program starts past the first chunk
+        # every layer of the program starts past the first chunk; its
+        # first window's layers carry one base the backbone lacks, so a
+        # node is inserted at rank 150 of 200 and the slot arrays are
+        # shifted, a block of slots a step, under the interpreter too
         truth = bytes(rng.choice(b"ACGT") for _ in range(200))
-        put(b, truth, [truth[100:]] * 3, 100, 199)
+        extra = bytes([next(c for c in b"ACGT"
+                            if c not in truth[149:151])]) if b == W else b""
+        put(b, truth, [truth[100:150] + extra + truth[150:]] * 3, 100, 199)
 
 interp = pltpu.InterpretParams(dma_execution_mode="on_wait",
                                uninitialized_memory="nan")
@@ -76,9 +81,11 @@ ls = poa_pallas_ls.build_lockstep_poa_kernel(cfg, interpret=interp,
 cb, cc, cl, fl, nn, swept = (np.asarray(x) for x in ls(
     bb_len[:, None], nl[:, None], lens, bg, en, bb.astype(np.int32), bbw,
     seqs.astype(np.int32), ws))
-# perfect reads: the chain's one in-edge plus one, a group a layer (the
-# scalar is written under uninitialised-memory NaNs like everything else)
-assert swept.tolist() == [2 * U * 3] * (B // W), swept
+# perfect reads: the chain's one in-edge plus one, a group a layer; the
+# inserted node's successor holds two in-edges from the second layer on,
+# one slot more twice (the scalar is written under uninitialised-memory
+# NaNs like everything else)
+assert swept.tolist() == [2 * U * 3, 2 * U * 3 + 2], swept
 jb, jc, jl, jf, jn = (np.asarray(x) for x in poa.build_poa_kernel(cfg)(
     bb, bbw, bb_len, nl, seqs, ws, lens, bg, en))
 assert not fl.any() and not jf.any(), (fl.ravel(), jf.ravel())

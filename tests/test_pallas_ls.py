@@ -41,6 +41,9 @@ def mutate(seq, rate, rng):
 
 CFG = poa.PoaConfig(max_nodes=384, max_len=256, max_backbone=128,
                     max_edges=12, depth=8, match=5, mismatch=-4, gap=-8)
+#: node arrays of four lane-chunks, windows of up to 384 bases: graphs
+#: whose insertions land past rank 128 and whose shifts cross chunks
+WIDE_CFG = CFG._replace(max_nodes=512, max_len=384, max_backbone=384)
 
 
 def _alloc(B, cfg):
@@ -187,22 +190,26 @@ def test_lockstep_matches_host_and_jax(groups):
 
 
 @GROUPS
-@pytest.mark.parametrize("seed", [101, 202, 303])
+@pytest.mark.parametrize("seed", [101, 202, 303, 404])
 def test_lockstep_differential_fuzz(seed, groups):
     """Seeded random windows — lengths, depths, mutation rates, partial
     spans, per-base layer weights AND backbone weights (the product
     exports PHRED-33 backbone weights, dummy '!' = 0 when the target has
     no quality; rt_capi.cpp rt_pipeline_window_export) — asserted
-    lockstep == XLA twin == host oracle."""
+    lockstep == XLA twin == host oracle.  Seed 404 runs windows of
+    260-330 bases at four layers on WIDE_CFG (graphs of 300-450 nodes),
+    the others 40-110 on CFG."""
     rng = random.Random(seed)
     B = 8
-    a = _alloc(B, CFG)
+    cfg, lengths = ((WIDE_CFG._replace(depth=4), (260, 330)) if seed == 404
+                    else (CFG, (40, 110)))
+    a = _alloc(B, cfg)
     cases = {}
     for b in range(B):
-        L = rng.randrange(40, 110)
+        L = rng.randrange(*lengths)
         truth = bytes(rng.choice(b"ACGT") for _ in range(L))
         backbone = mutate(truth, rng.uniform(0.02, 0.12), rng)
-        nl = rng.randrange(2, CFG.depth + 1)
+        nl = rng.randrange(2, cfg.depth + 1)
         layers = [mutate(truth, rng.uniform(0.02, 0.12), rng)
                   for _ in range(nl)]
         bq = np.array([rng.randrange(0, 60) for _ in range(len(backbone))],
@@ -222,10 +229,11 @@ def test_lockstep_differential_fuzz(seed, groups):
         a["bbw"][b, :len(backbone)] = bq
         cases[b] = (backbone, layers, w, bq, begins, ends)
 
-    (cb, cc, cl, fl, nn), (jb, jc, jl, jf, jn) = _run_both(a, CFG, B,
+    (cb, cc, cl, fl, nn), (jb, jc, jl, jf, jn) = _run_both(a, cfg, B,
                                                            groups)
 
     assert not fl.any() and not jf.any()
+    np.testing.assert_array_equal(nn[:, 0], jn)
     for b, (backbone, layers, w, bq, begins, ends) in cases.items():
         quals = [bytes((x + 33).astype(np.uint8)) for x in w]
         host, _ = native.window_consensus(
@@ -235,6 +243,13 @@ def test_lockstep_differential_fuzz(seed, groups):
         ls = decode(cb[b, :cl[b, 0]])
         jx = decode(jb[b, :jl[b]])
         assert ls == jx == host, f"seed {seed} window {b}"
+    if seed == 404 and groups == 1:
+        # the data, not the kernel: several chunks, and most insertions
+        # past the first
+        walks = [_twin_insertions(cfg, a, b) for b in range(B)]
+        assert max(w[0][-1] for w in walks) > 3 * 128
+        ranks = [p for w in walks for lay in w[1] for _, p, _ in lay]
+        assert sum(p >= 128 for p in ranks) > len(ranks) // 2
 
 
 @GROUPS
@@ -470,7 +485,8 @@ def _expected_sweep(cfg, a, groups):
 def _check_against_twin_and_host(a, cfg, ls, cases):
     """ls's outputs for the windows of `cases` (index -> backbone, layers)
     against the XLA twin (coverage, node count, failure cause) and the
-    host engine (consensus)."""
+    host engine (consensus; it takes the layers' spans as the batch
+    holds them)."""
     cb, cc, cl, fl, nn = ls
     jb, jc, jl, jf, jn = (np.asarray(x) for x in poa.build_poa_kernel(cfg)(
         a["bb"], a["bbw"], a["bb_len"], a["nl"], a["seqs"], a["ws"],
@@ -479,7 +495,10 @@ def _check_against_twin_and_host(a, cfg, ls, cases):
     for b, (backbone, layers) in cases.items():
         if fl[b, 0]:
             continue
-        host, _ = native.window_consensus(backbone, list(layers), trim=False)
+        host, _ = native.window_consensus(
+            backbone, list(layers), trim=False,
+            begins=a["bg"][b, :len(layers)].tolist(),
+            ends=a["en"][b, :len(layers)].tolist())
         assert decode(cb[b, :cl[b, 0]]) == decode(jb[b, :jl[b]]) == host, b
         assert int(nn[b, 0]) == int(jn[b]), f"window {b} node count"
         np.testing.assert_array_equal(cc[b, :cl[b, 0]], jc[b, :jl[b]],
@@ -641,6 +660,33 @@ def test_driver_counts_two_slots_of_twelve_on_reads_equal_to_the_backbone(
     assert counters["poa.launches"] == 1
 
 
+def test_driver_counts_the_bound_not_the_blocks_the_loop_rounds_it_to(
+        tmp_path, monkeypatch):
+    """Through the consensus driver: every read carries one base the
+    target lacks, at position 130, so the second of three windows
+    inserts one node, whose successor then holds two in-edges: the
+    bound is 2 at the first layer and 3 at the three after it, and that
+    is what the driver counts, though the loop shifts whole blocks of
+    SLOT_BLOCK slots."""
+    from racon_tpu import obs
+
+    target = _perfect_reads_dataset(tmp_path, insert_at=130)
+    monkeypatch.setenv("RACON_TPU_PALLAS", "1")
+    monkeypatch.setenv("RACON_TPU_SHARD", "0")
+    monkeypatch.setenv("RACON_TPU_BATCH_WINDOWS", "8")
+    monkeypatch.setenv("RACON_TPU_METRICS", "1")
+    try:
+        res, phase = _polish_perfect_reads(tmp_path)
+        counters = obs.snapshot()["counters"]
+    finally:
+        obs.reset()
+    assert res[0][1] == target[:130] + "T" + target[130:]
+    assert phase["served"]["ls"] == 3 and counters["poa.launches"] == 1
+    assert poa_pallas_ls.SLOT_BLOCK > 3
+    assert counters["poa.insert.slots.swept"] == 2 + 3 * 3
+    assert counters["poa.insert.slots.all"] == 12 * 1 * 4
+
+
 def test_xla_twin_counts_no_insert_slots(tmp_path, monkeypatch):
     """The twin has no slot sweep to bound: it reports nothing, and the
     driver counts nothing (the metric reads nothing, as on a program
@@ -658,6 +704,197 @@ def test_xla_twin_counts_no_insert_slots(tmp_path, monkeypatch):
         obs.reset()
     assert phase["served"]["xla"] == 3 and counters["poa.launches"] >= 1
     assert not [k for k in counters if k.startswith("poa.insert.")]
+
+
+# -- node insertions at the ranks and chunk boundaries a shift can miss ------
+
+def _twin_insertions(cfg, a, b):
+    """Window b's node insertions as the XLA twin's graphs tell them,
+    layer by layer: the node count before each layer (and after the
+    last), a layer's insertions as (step j, rank, nodes before), and the
+    cause the window ended on.  A step inserts where the path's column
+    holds no node of the layer's base (the twin's `has`); the j-th new
+    node takes id n, and its rank is the count of older nodes whose key
+    is not above its own (the kernel's p_ins).  A window the twin fails
+    for nodes stops where it overflows, as the kernel's does; no other
+    cause is walked."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    def path(g, seq, L, begin, end, bb_len):     # poa._add_layer's head
+        offset = (0.01 * bb_len.astype(jnp.float32)).astype(jnp.int32)
+        full = (begin < offset) & (end > bb_len - offset)
+        lo = jnp.where(full, -jnp.inf, begin.astype(jnp.float32))
+        hi = jnp.where(full, jnp.inf, end.astype(jnp.float32))
+        sub = (g.key >= lo) & (g.key <= hi)
+        order = jnp.argsort(jnp.where(sub, g.key, poa.KEY_INF)).astype(
+            jnp.int32)
+        n_sub = sub.sum().astype(jnp.int32)
+        H = poa._dp_matrix(cfg, g, seq, sub, order, n_sub)
+        return poa._traceback(cfg, g, H, seq, sub, order, n_sub, L)[0]
+
+    path = jax.jit(path)
+    add = jax.jit(functools.partial(poa._add_layer, cfg))
+    g = poa._init_graph(cfg, *(jnp.asarray(a[k][b])
+                               for k in ("bb", "bbw", "bb_len")))
+    starts, layers = [int(g.n)], []
+    for li in range(int(a["nl"][b])):
+        seq, w, L, bg, en = (jnp.asarray(a[k][b, li])
+                             for k in ("seqs", "ws", "lens", "bg", "en"))
+        bb_len = jnp.asarray(a["bb_len"][b])
+        pos = np.asarray(path(g, seq, L, bg, en, bb_len))
+        key0, base0, n0 = np.asarray(g.key), np.asarray(g.base), int(g.n)
+        g = add(g, seq, w, L, bg, en, bb_len)
+        assert int(g.failed) in (0, poa.FAIL_NODES), int(g.failed)
+        key2 = np.asarray(g.key)
+        steps = [j for j in range(int(L)) if not (
+            pos[j] >= 0 and ((key0[:n0] == key0[pos[j]]) &
+                             (base0[:n0] == int(seq[j]))).any())]
+        room = cfg.max_nodes - n0
+        assert int(g.n) == n0 + min(len(steps), room)
+        assert (len(steps) > room) == (int(g.failed) == poa.FAIL_NODES)
+        layers.append([(j, int((key2[:n0 + i] <= key2[n0 + i]).sum()),
+                        n0 + i) for i, j in enumerate(steps[:room])])
+        starts.append(int(g.n))
+        if int(g.failed):
+            break
+    return starts, layers, int(g.failed)
+
+
+def _other(*bases):
+    return bytes([next(c for c in b"ACGT" if c not in bases)])
+
+
+def _bases(rng, n):
+    return bytes(rng.choice(b"ACGT") for _ in range(n))
+
+
+def _crafted_insertion(name):
+    """A crafted window, (backbone, layers, the layers' end or None for
+    the backbone's last base), and what its insertions have to be:
+    [(rank, nodes before)] in order.  Every layer comes three times (the
+    host engine wants three to vote; a repeat inserts nothing)."""
+    rng = random.Random(49)
+    if name == "rank-0":
+        # a base before the backbone's first: key -1, below every node;
+        # the next layer puts one more before that
+        bb = _bases(rng, 300)
+        x = _other(bb[0])
+        y = _other(bb[0], x[0])
+        return bb, [x + bb] * 3 + [y + x + bb] * 3, None, [(0, 300),
+                                                           (0, 301)]
+    if name in ("rank-128", "rank-256"):
+        # a base between backbone positions 127 | 128 (255 | 256): lane
+        # 0 of a chunk is the new row, and the chunk below gives nothing
+        at = int(name[5:])
+        bb = _bases(rng, 300)
+        x = _other(bb[at - 1], bb[at])
+        return bb, [bb[:at] + x + bb[at:]] * 3, None, [(at, 300)]
+    if name.startswith("append-"):
+        # a base past the backbone's last, then one more: rows n and
+        # n + 1, the second of them (n = 127, 255) or both (n = 128) in
+        # a chunk that held only fill.  The layers end at the
+        # backbone's length: the full-graph rule, or the next layer
+        # would not see the node the one before appended.
+        n = int(name[7:])
+        bb = _bases(rng, n)
+        x = _other(bb[-1])
+        y = _other(x[0])
+        return bb, [bb + x] * 3 + [bb + x + y] * 3, n, [(n, n),
+                                                        (n + 1, n + 1)]
+    assert name == "full"
+    # substitutions until the graph holds N - 1 nodes, one more (the
+    # last row is written, nothing falls off), then one the graph has
+    # no room for: FAIL_NODES, nothing shifted.  A substitute differs
+    # from its neighbours too, so no alignment slides.
+    N = WIDE_CFG.max_nodes
+    bb = _bases(rng, 384)
+    spots = rng.sample(range(4, 380, 2), N - 384 + 1)
+    cuts = [0, 60, N - 384 - 1, N - 384, N - 384 + 1]
+    layers = []
+    for lo, hi in zip(cuts, cuts[1:]):
+        lay = bytearray(bb)
+        for at in spots[lo:hi]:
+            lay[at] = _other(*bb[at - 1:at + 2])[0]
+        layers.append(bytes(lay))
+    return bb, layers, None, None
+
+
+@pytest.mark.parametrize("name", ["rank-0", "rank-128", "rank-256",
+                                  "append-127", "append-128", "append-255",
+                                  "full"])
+def test_insertion_at_a_chunk_boundary(name):
+    """One crafted window beside a noisy mate in a program of eight, on
+    a geometry of four lane-chunks: an insertion at rank 0, at a rank
+    that is a multiple of 128, at rank n (append) where the new row is
+    the last lane of a chunk, the first of one that held only fill, or
+    crosses into it, and into a graph of N - 1 nodes, then one more.
+    The twin's graphs, walked layer by layer, say the window inserts
+    where it was crafted to; consensus, coverage, node count and cause
+    are the twin's and the host's."""
+    rng = random.Random(149)
+    a = _alloc(8, WIDE_CFG)
+    bb, layers, end, want = _crafted_insertion(name)
+    truth = _bases(rng, 200)
+    mate = (mutate(truth, 0.08, rng), [mutate(truth, 0.08, rng)
+                                       for _ in range(3)])
+    cases = {0: (bb, layers), 1: mate}
+    _set_window(a, 0, bb, layers, ends=end and [end] * len(layers))
+    _set_window(a, 1, *mate)
+
+    starts, ins, cause = _twin_insertions(WIDE_CFG, a, 0)
+    if want is None:
+        N = WIDE_CFG.max_nodes
+        assert starts == [384, 444, N - 1, N, N] and cause == poa.FAIL_NODES
+        assert [n for _, _, n in ins[2]] == [N - 1] and ins[3] == []
+    else:
+        assert cause == 0
+        assert [(p, n) for lay in ins for _, p, n in lay] == want
+
+    ls = _run_ls(a, WIDE_CFG)
+    assert ls[3][0, 0] == cause
+    _check_against_twin_and_host(a, WIDE_CFG, ls, cases)
+
+
+@GROUPS
+def test_insertions_far_apart_in_one_step(groups):
+    """Two windows of one group insert in the same steps, one near rank
+    10 and one near rank 290, beside a window that inserts nothing and
+    holds the group's largest graph, and the second inserts once alone:
+    each window's rows move from its own insertion rank up, and the
+    bystander's rows read as the twin's.  At two and four groups the
+    others hold noisy windows and a pad group."""
+    rng = random.Random(249)
+    B = 8 * groups
+    a = _alloc(B, WIDE_CFG)
+    near = _bases(rng, 330)
+    far = _bases(rng, 330)
+    wide = _bases(rng, 380)
+    x, y = _other(near[9], near[10]), _other(far[289], far[290])
+    z = _other(far[299], far[300])
+    cases = {
+        0: (near, [near[:10] + x + near[10:]] * 3),
+        # a layer over the backbone's tail: its step 10 is rank 290
+        3: (far, [far[280:290] + y + far[290:300] + z + far[300:]] * 3),
+        5: (wide, [wide] * 3),
+    }
+    begins = {3: [280] * 3}
+    if groups > 1:
+        truth = _bases(rng, 150)
+        for b in (8, 9, 12):
+            cases[b] = (mutate(truth, 0.1, rng),
+                        [mutate(truth, 0.1, rng) for _ in range(3)])
+    for b, (backbone, lays) in cases.items():
+        _set_window(a, b, backbone, lays, begins=begins.get(b))
+
+    for b, want in ((0, [(10, 10, 330)]), (5, []),
+                    (3, [(10, 290, 330), (21, 301, 331)])):
+        assert _twin_insertions(WIDE_CFG, a, b)[1] == [want, [], []]
+    ls = _run_ls(a, WIDE_CFG, groups=groups)
+    assert not ls[3].any()
+    _check_against_twin_and_host(a, WIDE_CFG, ls, cases)
 
 
 def _bench_cells():
@@ -814,17 +1051,23 @@ def test_lockstep_driver_path_end_to_end(tmp_path, monkeypatch):
     assert res[0][1] == target  # perfect reads -> perfect consensus
 
 
-def _perfect_reads_dataset(tmp_path):
+def _perfect_reads_dataset(tmp_path, insert_at=None):
+    """Four reads equal to the target; with `insert_at`, each with a T
+    the target lacks before that position."""
     target = "ACGT" * 60
+    read, cigar = target, f"{len(target)}M"
+    if insert_at is not None:
+        read = target[:insert_at] + "T" + target[insert_at:]
+        cigar = f"{insert_at}M1I{len(target) - insert_at}M"
     with open(tmp_path / "t.fasta", "w") as f:
         f.write(f">t\n{target}\n")
     with open(tmp_path / "r.fasta", "w") as f:
         for i in range(4):
-            f.write(f">r{i}\n{target}\n")
+            f.write(f">r{i}\n{read}\n")
     with open(tmp_path / "o.sam", "w") as f:
         f.write("@HD\tVN:1.6\n")
         for i in range(4):
-            f.write(f"r{i}\t0\tt\t1\t60\t{len(target)}M\t*\t0\t0\t{target}"
+            f.write(f"r{i}\t0\tt\t1\t60\t{cigar}\t*\t0\t0\t{read}"
                     f"\t*\n")
     return target
 
