@@ -236,6 +236,8 @@ def render(doc: dict, path: str) -> str:
             lines.append(f"  {phase:<16s} {mix}  (sum="
                          f"{sum(tiers.values())})")
     for title, prefixes in (
+            ("the consensus launch stream; peak host memory (MiB)",
+             ("poa.launches", "poa.rows.", "poa.queue.", "job.rss.")),
             ("alignment launches over the mesh", ("align.mesh.",)),
             ("consensus programs in lock-step",
              ("poa.programs.", "poa.lockstep.", "poa.width.",

@@ -207,6 +207,11 @@ class BatchExecutor:
         if len(self._pending) >= self.depth:
             self._resolve(*self._pending.popleft())
 
+    def in_flight(self) -> int:
+        """Chunks dispatched and not yet resolved: what a launch going
+        out now finds queued on the device ahead of it."""
+        return len(self._pending)
+
     def flush(self) -> None:
         """Block on every in-flight chunk and install its results."""
         while self._pending:

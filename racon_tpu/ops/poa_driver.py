@@ -493,10 +493,10 @@ def _consensus_phase(pipeline, fallback, match, mismatch, gap, trim,
         # analogue of the reference's continuous batch fill running
         # concurrently with kernel execution (cudapolisher.cpp:83-145).
         # This driver is only the bucket policy on top of it.
-        executor = BatchExecutor(
-            _ConsensusOps(pipeline, B, trim, stats, fallback, report,
-                          journal, dead_geoms),
-            report=report)
+        ops = _ConsensusOps(pipeline, B, trim, stats, fallback, report,
+                            journal, dead_geoms)
+        executor = BatchExecutor(ops, report=report)
+        ops.queued = executor.in_flight
         # windows in a class under the job's largest: the targets' tails
         # (one per contig; one per read in fragment correction)
         nominal = max(c for _, c, _ in buckets)
@@ -846,6 +846,9 @@ class _ConsensusOps:
         # executor's widen loop
         self.band = {}
         self._band_retry = []
+        # how many dispatched launches are still out (the executor's
+        # queue, set by _consensus_phase); nothing without an executor
+        self.queued = lambda: 0
 
     def _widths(self, chunk, cfg):
         """Per-window half-band widths for _pack (0 = flat), creating
@@ -902,7 +905,8 @@ class _ConsensusOps:
             raised = vmem_limit_bytes(ctx.cfg, groups) is not None
             slots_all = _insert_slots_all(packed[3], groups,
                                           ctx.cfg.max_edges)
-        _count_launch(n_real, packed, groups, ctx.rung, m, raised)
+        _count_launch(n_real, packed, groups, ctx.rung, m, raised,
+                      self.queued() > 0)
         return (_submit(kernel, packed, kind == "ls", _band_active(kind),
                         ctx.rung), _mesh_order(n_real, self.B, m), slots_all)
 
@@ -1362,13 +1366,18 @@ def _insert_slots_all(n_layers, groups: int, max_edges: int) -> int:
 
 def _count_launch(n_real, packed, groups: int = 0,
                   rung: str = NODE_RUNGS[0], shards: int = 1,
-                  raised: bool = False) -> None:
+                  raised: bool = False, behind: bool = False) -> None:
     """One batch on its way to the device: `n_real` rows carry a
     window, the rest pad the batch to its compiled size (and to the
     shard multiple), packed for `shards` shards (_mesh_order), on the
     node rung `rung` (every rung's key at every
     launch, a zero too, so that a job the base rung served alone reads
-    0 % upper and not nothing).  `groups` is the lockstep kernel's
+    0 % upper and not nothing).  A launch whose every row is a window
+    counts as full, and it goes out `behind` an earlier launch that is
+    still dispatched and not waited for, or finds the device's queue
+    empty (the aligner's pair, align_pallas._launch): all three keys
+    at every launch, a zero too; the pair sums to poa.launches.
+    `groups` is the lockstep kernel's
     group width for this launch (0: the XLA twin serves, which has no
     grid programs): its programs count as wide or narrow, both keys at
     every launch so that a job served by narrow programs alone reads
@@ -1389,6 +1398,9 @@ def _count_launch(n_real, packed, groups: int = 0,
 
     rows = len(packed[0])
     obs.count("poa.launches")
+    obs.count("poa.launches.full", int(n_real == rows))
+    obs.count("poa.queue.behind", int(behind))
+    obs.count("poa.queue.empty", int(not behind))
     obs.count("poa.rows.real", n_real)
     obs.count("poa.rows.pad", rows - n_real)
     for name in NODE_RUNGS:

@@ -172,6 +172,8 @@ def _close_job(journal: Optional[Journal], report: RunReport,
     with obs.span("job.close.report"):
         if before_report is not None:
             before_report()
+        # the process's peak so far, once a job: a gauge kept as a count
+        obs.count("job.rss.peak_mb", round(budget.peak_rss_mb()))
         report.finalize().write_env()
     obs.maybe_stop_device_trace()
     t0 = time.monotonic_ns()

@@ -913,7 +913,7 @@ def test_sweep_share_metric_loads_and_reads_its_counters(cell_name):
     cell = loader.load_cell(cell_name)        # the file agrees with its entry
     spec = {m["name"]: m for m in cell.per_layer}[
         "poa_insert_slot_sweep_share"]
-    assert spec["workloads"] == _bench_cells() and len(spec["workloads"]) == 10
+    assert spec["workloads"] == _bench_cells() and len(spec["workloads"]) >= 10
     assert (spec["layer"], spec["moves"], spec["unit"], spec["better"],
             spec["source"]) == ("kernels", "polished_mbp_per_s", "%",
                                 "lower", "program_counter")

@@ -25,6 +25,9 @@ CELLS = ["ecoli-ont.sam", "ecoli-ont.paf", "chr20-sr.sam",
          "ecoli-ont-x4.sam", "ecoli-frag.paf", "ecoli-ont-x4.paf"]
 ALL_CELLS = CELLS + ["ecoli-ont-deep.sam", "lambda-ont.paf",
                      "ecoli-ont-cap.sam", "lambda-ont-w1000.paf"]
+#: the full-size four-chip cell (PR 50), appended to every list that
+#: names ecoli-ont-x4.sam
+FULL_CELL = "ecoli-ont-full-x4.sam"
 
 
 @pytest.mark.parametrize("wl_class,shard_batch,want", [
@@ -178,6 +181,9 @@ def test_count_launch_full_batch_of_wide_programs():
     c = _counted(64, layers, 2)
     by_hand = 16 * sum(max(layers[i:i + 16]) for i in range(0, 64, 16))
     assert c == {"poa.launches": 1, "poa.rows.real": 64, "poa.rows.pad": 0,
+                 # the launch stream's own (PR 50; tests/test_full_cell.py)
+                 "poa.launches.full": 1, "poa.queue.behind": 0,
+                 "poa.queue.empty": 1,
                  "poa.programs.wide": 4, "poa.programs.narrow": 0,
                  "poa.width.windows.u1": 0, "poa.width.windows.u2": 64,
                  "poa.width.windows.u4": 0,
@@ -252,6 +258,8 @@ def test_count_launch_of_the_xla_twin_has_no_programs():
     # kernel's, so their sum over a job is the windows it was given
     c = _counted(3, [5, 5, 5, 0], 0)
     assert c == {"poa.launches": 1, "poa.rows.real": 3, "poa.rows.pad": 1,
+                 "poa.launches.full": 0, "poa.queue.behind": 0,
+                 "poa.queue.empty": 1,
                  "poa.programs.wide": 0, "poa.programs.narrow": 0,
                  "poa.windows.rung.base": 3, "poa.windows.rung.upper": 0,
                  "poa.layers.admitted": 15}
@@ -529,7 +537,8 @@ def test_real_first_packing_into_wide_shards_would_read_36_percent():
     read = reducers.registry()[spec["reducer"]]
     assert spec["layer"] == "drivers" and spec["better"] == "higher"
     assert spec["moves"] == "polished_mbp_per_s"
-    assert spec["workloads"] == ["ecoli-ont-x4.sam", "ecoli-ont-x4.paf"]
+    assert spec["workloads"] == ["ecoli-ont-x4.sam", "ecoli-ont-x4.paf",
+                                 FULL_CELL]
     real_first = {"poa.mesh.rows.real": 46, "poa.mesh.fullest.slots": 128}
     even = {"poa.mesh.rows.real": 46, "poa.mesh.fullest.slots": 48}
     assert read(_run(real_first), **spec["params"]) == pytest.approx(35.9375)
@@ -556,7 +565,8 @@ def test_wide_program_metrics_load_and_read_their_counters(cell_name):
     wide, fill = (specs["poa_wide_program_share"],
                   specs["poa_lockstep_fill_share"])
     for spec in (wide, fill):
-        assert spec["workloads"] == CELLS and spec["layer"] == "kernels"
+        assert spec["workloads"] == CELLS + [FULL_CELL]
+        assert spec["layer"] == "kernels"
         assert spec["moves"] == "polished_mbp_per_s"
         assert spec["reducer"] == "counter_share"
 
@@ -591,7 +601,8 @@ def test_program32_metric_loads_and_reads_its_counters(cell_name):
     cell = loader.load_cell(cell_name)        # the file agrees with its entry
     spec = {m["name"]: m for m in cell.per_layer}[
         "poa_program32_window_share"]
-    assert spec["workloads"] == ALL_CELLS and spec["layer"] == "kernels"
+    assert spec["workloads"] == ALL_CELLS + [FULL_CELL]
+    assert spec["layer"] == "kernels"
     assert spec["moves"] == "polished_mbp_per_s" and spec["unit"] == "%"
     assert spec["better"] == "higher"
     assert spec["reducer"] == "counter_share"

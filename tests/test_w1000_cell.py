@@ -544,18 +544,20 @@ def test_the_cell_loads_and_is_the_deployment():
             assert m["workloads"] == [CELL]
             assert m["moves"] == "polished_mbp_per_s"
         if m["name"] in SHARED_METRICS:
-            assert m["workloads"][-1] == CELL
+            # the last until a later PR appended its own cell
+            assert CELL in m["workloads"][-2:]
         # set once a job in which a window was rejected: this cell's jobs
         # reject none, so its reader finds nothing to read here
         if m["name"] == "poa_fallback_hidden_share":
             assert CELL not in m["workloads"]
-    assert sum(w["chips"] == 4 for w in bm["workloads"]) == 2
-    # the nine cells as PR 43 left them, this one the last
-    assert [w["name"] for w in bm["workloads"]] == [
+    assert sum(w["chips"] == 4 for w in bm["workloads"][:10]) == 2
+    # the nine cells as PR 43 left them, this one the last: a later PR
+    # appends its own and moves none
+    assert [w["name"] for w in bm["workloads"]][:10] == [
         "ecoli-ont.sam", "ecoli-ont.paf", "chr20-sr.sam",
         "ecoli-ont-x4.sam", "ecoli-frag.paf", "ecoli-ont-x4.paf",
         "ecoli-ont-deep.sam", "lambda-ont.paf", "ecoli-ont-cap.sam", CELL]
-    assert len(bm["configs"]) == 9
+    assert [c["name"] for c in bm["configs"]][8] == "lambda-ont-w1000"
 
 
 def _run_of(jobs):
