@@ -28,11 +28,15 @@ differs per task is folded into the data the host stages
 lanes, and the loop runs to the program's largest row count while
 shorter tasks carry their row through.  The host orders a launch's
 tasks by row count before it cuts them into programs; pad slots have no
-rows.  A task's result does not depend on which tasks share its program.
+rows.  The base kernel's traceback follows the same rule: one loop walks
+the program's eight tasks back together, a 128-lane chunk of the move
+matrix read and one lane of the op tile written a task and trip, to the
+program's longest walk.  A task's result does not depend on which tasks
+share its program.
 
 Mosaic constraints honored throughout (no scalar VMEM stores — masked row
-RMW; no dynamic-lane scalar loads — masked reductions; 3-D per-program
-blocks; i32 everywhere).
+RMW; no dynamic-lane scalar loads — masked reductions, or a rotation of
+the wanted lane to lane 0; 3-D per-program blocks; i32 everywhere).
 
 Costs are unit (edit distance), matching the reference's edlib NW config.
 In-band-only contract as the reference's banded CUDA aligner; pairs whose
@@ -338,9 +342,34 @@ def _build_base_kernel(K: int, interpret: bool = False):
     lock-step, task g in sublane g, inputs staged by _pack_launch.  Each
     loop iteration retires MOVE_ROWS rows and stores their moves as one
     word tile, a byte per row, so the move matrix of eight tasks is
-    BASE_ROWS / MOVE_ROWS tiles of (GROUP, K): 0.5-4 MB of VMEM at K
-    256-2048.  The traceback is a serial walk of data-dependent length,
-    so it runs one task after another, reading sublane g of that matrix.
+    BASE_ROWS / MOVE_ROWS x K / 128 tiles of (GROUP, 128), a 128-lane
+    chunk a leading index (the one (GROUP, K) store of a trip is K / 128
+    static stores of the same vregs): 0.5-4 MB of VMEM at K 256-2048.
+
+    The traceback is a serial walk of data-dependent length — scalar
+    (i, j) -> row and chunk address -> load -> lane rotation -> vector
+    to scalar -> move -> the next (i, j) — so the program's GROUP walks
+    run as eight such chains side by side in one loop, which ends with
+    the longest: the scheduler overlaps their latencies, and a task that
+    has reached (0, 0), left the band (move 3) or filled its op row
+    carries its state through.  A step loads the one (1, 128) chunk of
+    the one move word row it reads, at whatever K (the chunk is an
+    address, not a lane slice: Mosaic refuses one sublane at a dynamic
+    row with a dynamic lane offset), and rotates the lane it wants to
+    lane 0 (one XLU op; a masked sum over the lanes is two reductions
+    and a dozen VPU ops for an int32; reading the whole row and rotating
+    K lanes ran the launch 12 % longer at K >= 512 on the chip).  Every
+    walking task writes op number `step` at trip `step`, so a trip's ops
+    are one lane of a (GROUP, 128) tile held in a register and stored
+    whole once a trip (`ops_scr`, laid out to the (GROUP, OPS) output
+    after the loop): no load, and no pass over the op row.  The loop is
+    bound by its scalar instructions (~230 bundles a trip of eight steps
+    at every K, two scalar slots a bundle: tools/kernel_bundles.py), so
+    what it carries is kept small: i and j a task, nothing else — the
+    tile holds op + 1, which makes a task's op count the lanes of its
+    row that are not zero, and a task that left the band stops with
+    i = -1, which never reads as finished.  Nothing is recomputed: the
+    move byte the DP stored decides each step.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -348,18 +377,23 @@ def _build_base_kernel(K: int, interpret: bool = False):
     G, U = GROUP, MOVE_ROWS
     RB = BASE_ROWS
     TCAP = RB + K
-    OPS = _round_up(RB + K + 2, 128)
+    CHUNK = 128              # lanes of a vreg: what a walk step touches
+    LOG_CHUNK, LOG_U = CHUNK.bit_length() - 1, U.bit_length() - 1
+    # the walk's index arithmetic is shifts and masks
+    assert all(x & (x - 1) == 0 for x in (CHUNK, U, RB)), (CHUNK, U, RB)
+    OPS = _round_up(RB + K + 2, CHUNK)
     # packed query words (encoding.pack_bases), as in _build_edge_kernel;
     # a word never straddles two iterations
     assert U % PACK == 0, (U, PACK)
     QCAP = max(128, _round_up(RB // PACK, 128))
 
     def kernel(trip_ref, scal_s, scal_ref, q_ref, t_ref, ops_ref, cnt_ref,
-               ok_ref, dist_ref, MVS, fin_scr):
+               ok_ref, dist_ref, MVS, fin_scr, ops_scr):
         lane_k, R, S, dmin, lroll, _, fwd_row = _row_ops(
             K, TCAP, scal_ref, t_ref)
         lane_1 = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
-        lane_ops = jax.lax.broadcasted_iota(jnp.int32, (1, OPS), 1)
+        lane_g = jax.lax.broadcasted_iota(jnp.int32, (G, CHUNK), 1)
+        sub_g = jax.lax.broadcasted_iota(jnp.int32, (G, CHUNK), 0)
 
         def load_lane(rowvec, iota, idx):
             return jnp.sum(jnp.where(iota == idx, rowvec,
@@ -380,58 +414,87 @@ def _build_base_kernel(K: int, interpret: bool = False):
                 # a task past its own R carries `row` through; its moves
                 # there are never read (the walk starts at row R)
                 row = jnp.where(k < R, nrow, row)
-            MVS[pl.ds(pl.multiple_of(it * G, G), G), :] = moves
+            for c in range(K // CHUNK):
+                MVS[it, c] = moves[:, c * CHUNK:(c + 1) * CHUNK]
             return row
 
         j0 = dmin + lane_k
         row0 = jnp.where((j0 >= 0) & (j0 <= S), j0, INF)
         fin_scr[:] = jax.lax.fori_loop(0, trip_ref[0, 0, 0], body, row0)
 
-        def walk(g, carry):
-            Rg = scal_s[0, g, 0]
-            Sg = scal_s[0, g, 1]
-            dg = scal_s[0, g, 2]
+        Rs = [scal_s[0, g, 0] for g in range(G)]
+        Ss = [scal_s[0, g, 1] for g in range(G)]
+        ds = [scal_s[0, g, 2] for g in range(G)]
+        for g in range(G):
             # terminal distance D = DP[R][S]: lane o with R + dmin + o == S
             # (INF when the terminal cell is out of band).  Free with the
             # final row already live — it is the banded mode's exact
             # Ukkonen-verify input (ops/band.py) for base-case-only pairs.
-            o_fin = Sg - Rg - dg
+            o_fin = Ss[g] - Rs[g] - ds[g]
             d_at = load_lane(fin_scr[pl.ds(g, 1), :], lane_1,
                              jnp.clip(o_fin, 0, K - 1))
             dist_ref[0, 0, g] = jnp.where((o_fin >= 0) & (o_fin < K),
                                           d_at, INF)
 
-            # traceback from (R, S) to (0, 0); ops: 0=M 1=I(query)
-            # 2=D(target)
-            def cond(c):
-                i, j, cnt, ok = c
-                return ((i > 0) | (j > 0)) & (cnt < OPS) & ok
+        # traceback from (R, S) to (0, 0), all GROUP tasks in one loop;
+        # ops: 0=M 1=I(query) 2=D(target).  Every walking task writes op
+        # number `step` at trip `step`, so the trip's ops are one lane
+        # of the (GROUP, CHUNK) tile `acc`, stored whole once a trip; it
+        # holds op + 1, and 0 where a task no longer walks.  A task walks
+        # while (i | j) > 0.
+        def cond(c):
+            step, i, j, _ = c
+            left = functools.reduce(
+                jnp.maximum, [a | b for a, b in zip(i, j)])
+            return (left > 0) & (step < OPS)
 
-            def bodytb(c):
-                i, j, cnt, ok = c
-                o = j - i - dg
+        def bodytb(c):
+            step, i, j, acc = c
+            at = step & (CHUNK - 1)
+            col = lane_g == at
+            acc = jnp.where(at == 0, jnp.zeros_like(acc), acc)
+            nxt = []
+            for g in range(G):
+                o = j[g] - i[g] - ds[g]
                 in_band = (o >= 0) & (o < K)
-                r = jnp.maximum(i - 1, 0)
-                mvrow = MVS[pl.ds(r // U * G + g, 1), :]
-                mv_at = (load_lane(mvrow, lane_1, jnp.clip(o, 0, K - 1))
-                         >> (8 * (r % U))) & 0xFF
-                mv = jnp.where(i > 0, jnp.where(in_band, mv_at, 3), 2)
-                ok = ok & (mv != 3)
-                ops_ref[0, pl.ds(g, 1), :] = jnp.where(
-                    lane_ops == cnt, mv, ops_ref[0, pl.ds(g, 1), :])
-                i = jnp.where(mv == 2, i, i - 1)
-                j = jnp.where(mv == 1, j, j - 1)
-                return (i, j, cnt + 1, ok)
+                # where the move is not the matrix's (row 0, out of
+                # band, a task that has stopped) any word of it will do
+                oc = jnp.where(in_band, o, 0)
+                r = (i[g] - 1) & (RB - 1)
+                row = MVS[r >> LOG_U, oc >> LOG_CHUNK, pl.ds(g, 1), :]
+                # row r's byte of lane oc, brought to lane 0
+                mv_at = pltpu.roll((row >> ((r & (U - 1)) << 3)) & 0xFF,
+                                   (CHUNK - oc) & (CHUNK - 1), 1)[0, 0]
+                # op + 1: 0 stopped, 1 M, 2 I, 3 D, 4 left the band
+                known = jnp.where(
+                    (i[g] | j[g]) > 0,
+                    jnp.where(i[g] > 0, jnp.where(in_band, -1, 4), 3), 0)
+                op1 = jnp.where(known < 0, mv_at + 1, known)
+                acc = jnp.where(col & (sub_g == g), op1, acc)
+                # M, I and the band's edge step a row up, M, D and the
+                # edge a column left (a bit an op + 1); past the edge the
+                # task stops with i = -1, which never reads as finished
+                ig = i[g] - ((0b10110 >> op1) & 1)
+                nxt.append((jnp.where(op1 == 4, -1, ig),
+                            j[g] - ((0b11010 >> op1) & 1)))
+            ops_scr[step >> LOG_CHUNK] = acc
+            return (step + 1, *(tuple(x) for x in zip(*nxt)), acc)
 
-            ops_ref[0, pl.ds(g, 1), :] = jnp.zeros((1, OPS), jnp.int32)
-            i, j, cnt, ok = jax.lax.while_loop(
-                cond, bodytb, (Rg, Sg, jnp.int32(0), jnp.bool_(True)))
-            ok = ok & (i == 0) & (j == 0)
-            cnt_ref[0, 0, g] = cnt
-            ok_ref[0, 0, g] = ok.astype(jnp.int32)
-            return carry
-
-        jax.lax.fori_loop(0, G, walk, 0)
+        for c in range(OPS // CHUNK):
+            ops_scr[c] = jnp.zeros((G, CHUNK), jnp.int32)
+        _, i, j, _ = jax.lax.while_loop(
+            cond, bodytb, (jnp.int32(0), tuple(Rs), tuple(Ss),
+                           jnp.zeros((G, CHUNK), jnp.int32)))
+        # a task's op count: the lanes of its row that hold an op
+        cnt = jnp.zeros((G, CHUNK), jnp.int32)
+        for c in range(OPS // CHUNK):
+            ops1 = ops_scr[c]
+            cnt = cnt + (ops1 > 0).astype(jnp.int32)
+            ops_ref[0, :, c * CHUNK:(c + 1) * CHUNK] = jnp.maximum(
+                ops1 - 1, 0)
+        for g in range(G):
+            cnt_ref[0, 0, g] = jnp.sum(cnt[g:g + 1])
+            ok_ref[0, 0, g] = ((i[g] | j[g]) == 0).astype(jnp.int32)
 
     def make(nb):
         smem1 = pl.BlockSpec((1, 1, 1), lambda b: (b, 0, 0),
@@ -451,8 +514,10 @@ def _build_base_kernel(K: int, interpret: bool = False):
             out_specs=[vtile(OPS), smemg, smemg, smemg],
             out_shape=[jax.ShapeDtypeStruct((nb, G, OPS), jnp.int32),
                        gshape, gshape, gshape],
-            scratch_shapes=[pltpu.VMEM((RB // U * G, K), jnp.int32),
-                            pltpu.VMEM((G, K), jnp.int32)],
+            scratch_shapes=[pltpu.VMEM((RB // U, K // CHUNK, G, CHUNK),
+                                       jnp.int32),
+                            pltpu.VMEM((G, K), jnp.int32),
+                            pltpu.VMEM((OPS // CHUNK, G, CHUNK), jnp.int32)],
             interpret=interpret,
             name="racon_hirschberg_base",
         )
@@ -495,12 +560,14 @@ class _InFlight(set):
 
 class _Launch:
     """One dispatched kernel launch, a member of `in_flight` until
-    `wait` has blocked for its outputs."""
+    `wait` has blocked for its outputs; `width` is the slots a grid
+    program of it holds, as dispatched."""
 
-    __slots__ = ("in_flight", "outs", "span_args")
+    __slots__ = ("in_flight", "outs", "span_args", "width")
 
-    def __init__(self, in_flight, outs, span_args):
+    def __init__(self, in_flight, outs, span_args, width):
         self.in_flight, self.outs, self.span_args = in_flight, outs, span_args
+        self.width = width
         in_flight.add(self)
 
     def ready(self):
@@ -737,7 +804,10 @@ def _launch(in_flight, kernel, call, args, n_real, n_single=0, **geom):
     else:
         in_flight.single += 1
         obs.count("align.mesh.launches.single")
-    rows = args[0][:, 0].reshape(-1, min(GROUP, per_shard))
+    # every shard cuts its consecutive share of the slots into programs
+    # of GROUP; a share under GROUP slots is one program
+    width = min(GROUP, per_shard)
+    rows = args[0][:, 0].reshape(-1, width)
     obs.count("align.lockstep.rows.real", int(rows.sum()))
     obs.count("align.lockstep.rows.slots",
               GROUP * int(rows.max(axis=1).sum()))
@@ -755,7 +825,7 @@ def _launch(in_flight, kernel, call, args, n_real, n_single=0, **geom):
     obs.count("align.tasks.pad", B - n_real)
     obs.count("align.host.tasks.batched", n_real - n_single)
     obs.count("align.host.tasks.single", n_single)
-    return _Launch(in_flight, outs, span_args)
+    return _Launch(in_flight, outs, span_args, width)
 
 
 def _buckets(order, *keys):
@@ -903,18 +973,25 @@ def _solve_base(run, tasks):
             outs = launch.wait()
             with obs.span("align.traceback", cat="launch", tasks=n_tasks,
                           K=launch.span_args["K"]):
-                _collect_base(run, slots, outs)
+                _collect_base(run, slots, outs, launch.width)
             flying.extend(issue(*c) for c in itertools.islice(todo, 1))
     finally:
         run.in_flight.difference_update(launch for *_, launch in flying)
 
 
-def _collect_base(run, slots, outs):
+def _collect_base(run, slots, outs, width=GROUP):
     """A base launch's op codes, reversed (the walk runs from the end)
     and laid back to back in slot order by one native call; `run.segs`
-    keeps them with each segment's pair, first query row and length."""
+    keeps them with each segment's pair, first query row and length.
+    `width` is the slots a grid program of the launch held."""
     ops, cnt, ok, dist = outs
     rows = np.flatnonzero(slots[:, PAIR] >= 0)
+    # how well the joint walk engages: the ops the real tasks returned
+    # over what their programs' trips billed, GROUP x each program's
+    # longest walk (a pad slot has R = S = 0 and returns none)
+    obs.count("align.traceback.steps.real", int(cnt[rows].sum()))
+    obs.count("align.traceback.steps.slots",
+              GROUP * int(cnt.reshape(-1, width).max(axis=1).sum()))
     good = ok[rows] != 0
     # base-case-only banded pair: the kernel's terminal distance carries
     # the exact Ukkonen certificate

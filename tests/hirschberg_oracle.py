@@ -2,8 +2,10 @@
 task at a time (commit 3e57bc8): a `Task` object per task, a Python loop
 per launch slot.  Kept as the differential-test oracles of the per-launch
 calls that replaced them (`align_pallas._pack_launch`, `_select`,
-`_collect_base` + `_assemble`, `_deal_programs`, `ops_to_cigars`) —
-nothing outside the tests imports this module."""
+`_collect_base` + `_assemble`, `_deal_programs`, `ops_to_cigars`), and
+a base task solved a cell at a time (`base_task`), the oracle of
+`racon_hirschberg_base`'s outputs — nothing outside the tests imports
+this module."""
 
 import numpy as np
 
@@ -151,3 +153,41 @@ def ops_to_cigar(ops: np.ndarray) -> str:
     for s, e in zip(starts, ends):
         out.append(f"{e - s}{chr(_OPC[ops[s]])}")
     return "".join(out)
+
+
+def base_task(q, t, R, S, dmin, K, OPS):
+    """What `racon_hirschberg_base` returns for one task, a cell at a
+    time: the banded DP of query rows q[:R] against target columns
+    t[:S] (row i holds columns i + dmin .. i + dmin + K - 1), a move a
+    cell with the kernel's preference (diagonal before up, left only
+    where it is strictly cheaper), and the walk back from (R, S).
+    -> (ops, cnt, ok, dist), ops as the kernel lays them: from the end,
+    zero past cnt."""
+    D = np.full((R + 1, S + 1), INF, np.int64)
+    mv = np.zeros((R + 1, S + 1), np.int64)
+
+    def band(i):
+        return range(max(0, i + dmin), min(S, i + dmin + K - 1) + 1)
+
+    for j in band(0):
+        D[0, j] = j
+    for i in range(1, R + 1):
+        for j in band(i):
+            sub = (D[i - 1, j - 1] + (q[i - 1] != t[j - 1])
+                   if j - 1 in band(i - 1) else INF)
+            up = D[i - 1, j] + 1 if j in band(i - 1) else INF
+            D[i, j], mv[i, j] = (i, 1) if j == 0 else min((sub, 0), (up, 1))
+            if j - 1 in band(i) and D[i, j - 1] + 1 < D[i, j]:
+                D[i, j], mv[i, j] = D[i, j - 1] + 1, 2
+        D[i] = np.minimum(D[i], INF)
+    ops = np.zeros(OPS, np.int32)
+    i, j, cnt, ok = R, S, 0, True
+    while (i > 0 or j > 0) and cnt < OPS and ok:
+        m = 2 if i == 0 else mv[i, j] if j in band(i) else 3
+        ops[cnt] = m
+        cnt += 1
+        ok = m != 3
+        i -= m != 2
+        j -= m != 1
+    return ops, cnt, int(ok and i == 0 and j == 0), \
+        int(D[R, S]) if S in band(R) else INF

@@ -459,6 +459,172 @@ def test_one_row_task_beside_a_full_one(backward):
     assert (both[2:] >= 0).all()        # idle sublanes: any value, no fault
 
 
+# -- the base kernel's joint walk -------------------------------------------
+
+def _walk_program(case):
+    """One base program's eight slots for `case`: -> (K, pairs, tasks,
+    per-pair gdmin, slots whose scalars the test overwrites {slot: (R, S,
+    dmin)}, the slots that must come out ok)."""
+    rng = random.Random(len(case))
+    x = _rand(rng, 256)
+    if case == "lengths":
+        # R = 1 beside R = 256, a task with no target columns (S = 0:
+        # insertions only), four more in between; slot 7 is a pad
+        specs = [(x[:1], x[:2]), (x, mutate(x, 0.05, rng)), (x[:60], x[:60]),
+                 (x[:129], mutate(x[:129], 0.1, rng)), (x[:7], x[:7]),
+                 (x[:200], mutate(x[:200], 0.2, rng)),
+                 (x[:128], x[:128])]
+        return 256, specs, None, {}, range(7)
+    if case == "escape":
+        # slot 2 leaves the band at its first step (terminal cell 300
+        # diagonals off a band of 256: move 3, ok = 0); slot 5's scalars
+        # are overwritten with a walk no host sends, R = 0 and S past
+        # OPS, which runs left until the op row is full
+        specs = [(x[:250], mutate(x[:250], 0.1, rng))
+                 for _ in range(8)]
+        specs[2] = (x[:100], x[:50] + _rand(rng, 300) + x[50:100])
+        return 256, specs, [-127, -127, -10] + [-127] * 5, {5: (0, 700, 0)}, \
+            (0, 1, 3, 4, 6, 7)
+    K = int(case.rsplit("k", 1)[1])
+    if case == "mixed-k512":
+        # eight walks of eight lengths at the band no other case builds
+        specs = [(x[:n], mutate(x[:n], 0.15, rng))
+                 for n in (256, 31, 255, 128, 2, 190, 129, 64)]
+        return K, specs, None, {}, range(8)
+    run = 200 if K == 256 else 600
+    # slot 0: a deletion run that carries the band offset up over chunk
+    # edges as the walk goes back, slot 1: an insertion run that carries
+    # it down over one; both op counts pass 128 (slot 1's 256 too); the
+    # other slots walk the diagonal meanwhile
+    specs = [(x[:100], x[:50] + _rand(rng, run) + x[50:100]),
+             (x[:28] + _rand(rng, 200) + x[28:56], x[:56]),
+             (x[:130], mutate(x[:130], 0.1, rng)),
+             (x[:256], mutate(x[:256], 0.1, rng))]
+    gdmin = {256: [-20, -230, -100, -127], 1024: [-100, -600, -511, -127]}[K]
+    return K, specs, gdmin, {}, range(4)
+
+
+@pytest.mark.parametrize("case", ["lengths", "escape", "chunks-k256",
+                                  "chunks-k1024", "mixed-k512"])
+def test_joint_walk_gives_each_task_what_it_gives_alone(case):
+    """`racon_hirschberg_base` walks a program's eight tasks back in one
+    loop, a 128-lane chunk of the moves read and of the op row written a
+    step: every slot's ops, count, ok and distance equal what the task
+    gives in a program of its own (in sublane 0, seven idle beside it),
+    whatever walks beside it — one of a single step, one that never
+    starts (a pad), one that leaves the band or runs its op row full —
+    and wherever its band offset and its op count cross a chunk's edge,
+    in either direction.  Both equal the task solved a cell at a time
+    (`hirschberg_oracle.base_task`: the walk one step after another),
+    and what comes out ok is an optimal alignment."""
+    from tests import hirschberg_oracle
+    K, specs, gdmin, forced, good = _walk_program(case)
+    pairs = [_enc(q, t) for q, t in specs]
+    state = _run_state(pairs, K=K, gdmin=gdmin)
+    tasks = np.array([[p, 0, len(q), 0, len(t)]
+                      for p, (q, t) in enumerate(specs)], np.int32)
+    if case == "lengths":
+        tasks[2] = (2, 10, 50, 20, 20)      # rows 10..50 against no column
+    kern, OPS, _, _ = align_pallas._build_base_kernel(K, True)
+
+    def run(slots):
+        args = list(align_pallas._pack_launch(
+            state, slots, align_pallas.BASE_ROWS, K, False))
+        scal = np.array(args[0])
+        for slot, (R, S, dmin) in forced.items():
+            hit = np.flatnonzero(slots[:, 0] == slot)   # pair = its slot
+            scal[hit, :3] = (R, S, dmin)
+        return scal, [np.asarray(o) for o in kern(8)(scal, *args[1:])]
+
+    scal, (ops, cnt, ok, dist) = run(_slots(tasks, 8))
+    for g in range(len(tasks)):
+        _, alone = run(_slots(tasks[g:g + 1], 8))
+        _, ia, _, ja, _ = tasks[g]
+        plain = hirschberg_oracle.base_task(
+            specs[g][0][ia:], specs[g][1][ja:], *scal[g, :3], K, OPS)
+        for got, want, cell in zip((ops, cnt, ok, dist), alone, plain):
+            np.testing.assert_array_equal(got[g], want[0], err_msg=str(g))
+            np.testing.assert_array_equal(got[g], cell, err_msg=str(g))
+    for g in range(len(tasks), 8):                      # pads never walk
+        assert cnt[g] == 0 and not ops[g].any(), g
+    assert [g for g in range(len(tasks)) if ok[g]] == list(good)
+    for g in good:
+        _, ia, ib, ja, jb = tasks[g]
+        q, t = specs[g][0][ia:ib], specs[g][1][ja:jb]
+        assert path_cost(ops[g, :cnt[g]][::-1], q, t) == dist[g] \
+            == (native.edit_distance(q, t) if t else len(q)), g
+    if case == "lengths":
+        assert cnt[0] == 2 and cnt[1] >= 256 and cnt[2] == 40
+        assert (ops[2, :40] == 1).all()
+    elif case == "escape":
+        assert (cnt[2], ops[2, 0], ok[2]) == (1, 3, 0)
+        assert (cnt[5], ok[5]) == (OPS, 0) and (ops[5] == 2).all()
+    elif case.startswith("chunks"):
+        # the offsets the two runs walk through, by hand: the band
+        # offset of cell (i, j) is j - i - gdmin
+        run_len = len(specs[0][1]) - 100
+        lo, hi = -gdmin[0], run_len - gdmin[0]
+        assert lo // 128 < hi // 128 and hi < K      # slot 0 goes up
+        lo, hi = -200 - gdmin[1], -gdmin[1]
+        assert lo // 128 < hi // 128 and lo >= 0     # slot 1 goes down
+        assert cnt[0] == 100 + run_len and cnt[0] > 128
+        assert cnt[1] == 256 and cnt[3] >= 256
+
+
+@pytest.mark.parametrize("placement", ["one_device"], indirect=True)
+def test_traceback_fill_share_reads_its_counter_pair(placement):
+    """``align.traceback.steps.real`` / ``.slots`` by hand for a launch
+    of known op counts (`_collect_base`, once a launch): sixteen slots
+    on one device, two programs; the first program's longest walk is
+    300, the second holds one task of 7 and seven pads; the programs are
+    as wide as the launch was dispatched.  ``align_traceback_fill_share``
+    reads the pair in both PAF cells, and nothing on a program that
+    does not count it (the parent)."""
+    from benchmark import loader, reducers
+    from racon_tpu import obs
+
+    pairs = [_enc(b"A" * 300, b"A" * 300) for _ in range(9)]
+    state = _run_state(pairs, K=256, gdmin=-3)
+    slots = _slots(np.array([[p, 0, 100, 0, 100] for p in range(9)],
+                            np.int32), 16)
+    cnt = np.array([100, 300, 250, 1, 0, 120, 299, 64] + [7] + [0] * 7,
+                   np.int32)
+    outs = (np.zeros((16, 640), np.int32), cnt, np.ones(16, np.int32),
+            np.zeros(16, np.int32))
+    obs.reset()
+    obs.configure(metrics=True)
+    try:
+        align_pallas._collect_base(state, slots, outs)
+        counters = obs.snapshot()["counters"]
+    finally:
+        obs.reset()
+    assert counters["align.traceback.steps.real"] == int(cnt.sum()) == 1141
+    assert counters["align.traceback.steps.slots"] == 8 * (300 + 7)
+    # over a mesh of four the same sixteen slots are four programs of
+    # four (`_Launch.width`, as dispatched), each billed its own longest
+    obs.configure(metrics=True)
+    try:
+        align_pallas._collect_base(state, slots, outs, 4)
+        sharded = obs.snapshot()["counters"]
+    finally:
+        obs.reset()
+    assert sharded["align.traceback.steps.slots"] == 8 * (300 + 299 + 7)
+    for cell_name in ("ecoli-ont.paf", "ecoli-frag.paf"):
+        spec, = (m for m in loader.load_cell(cell_name).per_layer
+                 if m["name"] == "align_traceback_fill_share")
+        assert spec["workloads"] == ["ecoli-ont.paf", "ecoli-frag.paf"]
+        read = reducers.registry()[spec["reducer"]]
+        job = {"counters": counters, "spans": {}, "phases": {}}
+        run = {"jobs": [job, dict(job)], "facts": {}, "data": {},
+               "edits": {}, "notes": {}, "trace": None, "device": None,
+               "peaks": {}}
+        assert read(run, **spec["params"]) == pytest.approx(
+            100 * 1141 / (8 * 307))
+        job["counters"] = {"align.tasks.real": 5}
+        run["jobs"] = [job, dict(job)]
+        assert read(run, **spec["params"]) is None
+
+
 @pytest.mark.parametrize("placement", ["one_device"], indirect=True)
 def test_pad_task_never_lengthens_a_group(placement):
     """The counter pair that says how well the groups engage, checked by

@@ -26,6 +26,7 @@ non-compiling kernel cannot land there either.
 """
 
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -413,10 +414,30 @@ def test_lockstep_edge_kernels_compile_for_v5e(single_device, rcap, K,
     _compile_v5e(*_edge(rcap, K, backward, TPU_BATCH))
 
 
-@pytest.mark.parametrize("K", [256, 2048])
+@pytest.mark.parametrize("K", [256, 512, 2048])
 def test_lockstep_base_kernel_compiles_for_v5e(single_device, K):
-    # the move matrix of eight tasks, a byte per row: 0.5 and 4 MB of VMEM
+    # the move matrix of eight tasks, a byte per row, a 128-lane chunk a
+    # leading index (0.5 to 4 MB of VMEM), and the joint walk's dynamic
+    # chunk loads and lane rotations; with K = 1024 above, every band in
+    # BANDS
     _compile_v5e(*_base(K, TPU_BATCH))
+
+
+def test_kernel_bundles_reads_the_base_kernels_two_loops(single_device,
+                                                         capsys):
+    """`racon_tpu/tools/kernel_bundles.py` compiles the base kernel for
+    a described v5e with the LLO dumps on and finds the forward DP's
+    loop and the joint walk's: a trip of the walk, eight steps, stays a
+    few hundred bundles (it was 613-672 with a masked lane sum and a
+    read-modify-write a step: scalar spills)."""
+    from racon_tpu.tools import kernel_bundles
+
+    kernel_bundles.main(["256"])
+    out = capsys.readouterr().out
+    if "no dump" in out:
+        pytest.skip("this libtpu writes no LLO dumps")
+    dp, walk = (int(n) for n in re.findall(r"(\d+) bundles a trip", out))
+    assert dp > 0 and 0 < walk < 400
 
 
 # -- Mosaic compile over the mesh ------------------------------------------
