@@ -241,7 +241,7 @@ def render(doc: dict, path: str) -> str:
             ("alignment launches over the mesh", ("align.mesh.",)),
             ("consensus programs in lock-step",
              ("poa.programs.", "poa.lockstep.", "poa.width.",
-              "poa.mesh.", "poa.vmem.", "poa.insert.")),
+              "poa.mesh.", "poa.vmem.", "poa.insert.", "poa.ls.")),
             ("consensus graph capacity by rung",
              ("poa.windows.rung.", "poa.nodes.", "poa.windows.overflow.",
               "poa.layers.", "poa.backbone.")),

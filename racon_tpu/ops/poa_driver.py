@@ -898,17 +898,20 @@ class _ConsensusOps:
         shard_multiple reads)."""
         m = self.shard_multiple(ctx, None)
         kernel, groups, raised, slots_all = ctx.kernel, 0, False, None
+        layers = None
         if kind == "ls":
-            from .poa_pallas_ls import vmem_limit_bytes
+            from .poa_pallas_ls import G, vmem_limit_bytes
             groups = _group_width(ctx.cfg, self.B // m, -(-n_real // m))
             kernel = kernel.programs[groups]
             raised = vmem_limit_bytes(ctx.cfg, groups) is not None
             slots_all = _insert_slots_all(packed[3], groups,
                                           ctx.cfg.max_edges)
+            layers = _program_layers(packed[3], groups * G)
         _count_launch(n_real, packed, groups, ctx.rung, m, raised,
                       self.queued() > 0)
         return (_submit(kernel, packed, kind == "ls", _band_active(kind),
-                        ctx.rung), _mesh_order(n_real, self.B, m), slots_all)
+                        ctx.rung), _mesh_order(n_real, self.B, m), slots_all,
+                layers)
 
     def dispatch(self, ctx, kind, packed, chunk):
         faults.check(f"poa.run.{kind}", [i for i, _, _ in chunk])
@@ -921,9 +924,9 @@ class _ConsensusOps:
                            self._launch(ctx, kind, packed, len(sub)))
 
     def unpack(self, ctx, kind, launched):
-        outs, order, slots_all = launched
+        outs, order, slots_all, layers = launched
         return _unpack(outs, kind == "ls", _band_active(kind), ctx.rung,
-                       order, slots_all)
+                       order, slots_all, layers)
 
     def span_args(self, ctx, chunk, pipelined):
         return {"windows": len(chunk), "pipelined": pipelined}
@@ -1449,24 +1452,28 @@ class _Unpacked(tuple):
     """_unpack's host arrays, (cons_base, cons_cov, cons_len, failed[,
     band_hit]) as every caller takes them apart; `nodes`, the kernels'
     fifth output (each window's graph size at the end), rides beside
-    them for _install's fill counters, and so do the in-edge slots the
-    lockstep launch's node insertions swept (`slots_swept`, the kernel's
-    last output summed over its programs; None from the XLA twin) and
-    would have swept unbounded (`slots_all`: _insert_slots_all, from the
-    launch, which knows its programs)."""
+    them for _install's fill counters, and so does the lockstep kernel's
+    last output summed over the launch's programs (None from the XLA
+    twin): the in-edge slots its node insertions swept (`slots_swept`),
+    beside what they would have swept unbounded (`slots_all`:
+    _insert_slots_all, from the launch, which knows its programs), and
+    the trips of its own loops (`steps`: poa_pallas_ls.STEP_COUNTERS by
+    name, under the launch's _program_layers as `layers`)."""
 
     nodes = None
     slots_swept = None
     slots_all = None
+    steps = None
 
 
 def _unpack(outs, use_pallas, banded=False, rung: str = NODE_RUNGS[0],
-            order=None, slots_all=None):
+            order=None, slots_all=None, layers=None):
     """Block on device futures; normalize to host arrays.  `failed` is 0
     for a served window, else the cause (poa.FAIL_CAUSES).  `order` is
     the batch's _mesh_order: row p of every array returned is chunk
-    item p's, wherever _pack put it.  `slots_all` is the launch's
-    _insert_slots_all, handed on beside what the kernel swept."""
+    item p's, wherever _pack put it.  `slots_all` and `layers` are the
+    launch's _insert_slots_all and _program_layers, handed on beside
+    what the kernel swept and the steps its loops ran."""
     cb, cc, cl, fl = outs[0], outs[1], outs[2], outs[3]
     with obs.span("poa.wait", cat="launch", B=len(cb), rung=rung):
         cons_base = np.asarray(cb)
@@ -1476,7 +1483,8 @@ def _unpack(outs, use_pallas, banded=False, rung: str = NODE_RUNGS[0],
         nodes = np.asarray(outs[4])
         band_hit = (np.asarray(outs[5])[:, 0]
                     if use_pallas and banded else None)
-        swept = int(np.asarray(outs[-1]).sum()) if use_pallas else None
+        counts = (np.asarray(outs[-1]).sum(axis=0).tolist()
+                  if use_pallas else None)
     if use_pallas:
         cons_len, failed, nodes = cons_len[:, 0], failed[:, 0], nodes[:, 0]
     if order is not None:
@@ -1487,7 +1495,14 @@ def _unpack(outs, use_pallas, banded=False, rung: str = NODE_RUNGS[0],
     res = _Unpacked((cons_base, cons_cov, cons_len, failed)
                     + ((band_hit,) if use_pallas and banded else ()))
     res.nodes = nodes
-    res.slots_swept, res.slots_all = swept, slots_all
+    if counts is not None:
+        from .poa_pallas_ls import PROGRAM_COUNTS
+
+        steps = dict(zip(PROGRAM_COUNTS, counts))
+        res.slots_swept = steps.pop("slots_swept")
+        res.slots_all = slots_all
+        if layers is not None:
+            res.steps = {"layers": layers, **steps}
     return res
 
 
@@ -1598,6 +1613,10 @@ def _install(pipeline, chunk, results, trim, stats, fallback, report=None,
     if swept is not None and slots_all is not None:
         obs.count("poa.insert.slots.swept", swept)
         obs.count("poa.insert.slots.all", slots_all)
+    # what the kernel's own loops ran, over the layers of its programs
+    # (every key at a lockstep launch, a zero too; none from the XLA twin)
+    for name, trips in (getattr(results, "steps", None) or {}).items():
+        obs.count(f"poa.ls.{name}", trips)
     for cause, name in poa.FAIL_CAUSES.items():
         obs.count(f"poa.windows.overflow.{name}", overflow[cause])
     obs.count("poa.windows.trim.admitted", n_trimmed)

@@ -75,6 +75,24 @@ BIG = 1 << 20    # "no slot" sentinel inside packed slot*256+delta minima
 WNONE = BIG * 512
 SLOT_BLOCK = 4   # in-edge slots a step of the node-insertion loop shifts
 
+#: The kernel's phases as regions of a device trace (a jax.named_scope in
+#: the body lowers to Mosaic tpu.trace_start / trace_stop, which libtpu
+#: keeps, and carries into a profile, only where its operator started it
+#: with the two flags docs/observability.md names): the five blocks of a
+#: layer one after another, and once a program the consensus walk.
+#: Flat: none around the layer, none inside a rank, step or slot loop.
+(R_SETUP, R_DP, R_ENDS, R_TRACEBACK, R_UPDATE,
+ R_CONSENSUS) = REGIONS = ("ls.setup", "ls.dp", "ls.ends", "ls.traceback",
+                           "ls.update", "ls.consensus")
+#: What a grid program counts of its own loops, summed over its layers:
+#: the trips of each (poa.ls.<name> once poa_driver installs them, beside
+#: poa.ls.layers, the layers themselves, which the host knows) and,
+#: first, the in-edge slots its node insertions were bounded to.  The
+#: kernel's last output, a slot each, in PROGRAM_COUNTS' order.
+STEP_COUNTERS = ("steps.dp", "steps.traceback", "steps.update",
+                 "insert.firings", "insert.shift_steps")
+PROGRAM_COUNTS = ("slots_swept",) + STEP_COUNTERS
+
 
 def _round_up(x, m):
     return (x + m - 1) // m * m
@@ -304,6 +322,17 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
         if band:
             wbv = svec(lambda i: wband_s[0, 0, i])    # (U,1,G,1) half-band
 
+        # PROGRAM_COUNTS accumulate in the SMEM output itself, each
+        # bumped once a layer where its loop bound is already a scalar
+        # (the two of the node insertion once a firing), so no loop
+        # carries them
+        def bump(name, by):
+            slot = PROGRAM_COUNTS.index(name)
+            sw_s[0, 0, slot] = sw_s[0, 0, slot] + by
+
+        for slot in range(len(PROGRAM_COUNTS)):
+            sw_s[0, 0, slot] = jnp.int32(0)
+
         # ---- graph init from the backbone chain ------------------------
         # (parity: rt_poa.cpp add_alignment, empty-alignment branch)
         used0 = rr < bb_len
@@ -349,504 +378,519 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
 
         # ================= one layer =====================================
         def do_layer(li, slot, carry):
-            # n, failed[, hit]: (U,1,G,1) i32; swept: i32 scalar
+            # n, failed[, hit]: (U,1,G,1) i32
             if band:
-                n, failed, hit, swept = carry
+                n, failed, hit = carry
             else:
-                n, failed, swept = carry
-            Ln = svec(lambda i: lens_s[0, i, li])
-            begin = svec(lambda i: begins_s[0, i, li])
-            end = svec(lambda i: ends_s[0, i, li])
-            lact = (li < n_layers) & (Ln > 0) & (failed == 0)
+                n, failed = carry
+            with jax.named_scope(R_SETUP):
+                Ln = svec(lambda i: lens_s[0, i, li])
+                begin = svec(lambda i: begins_s[0, i, li])
+                end = svec(lambda i: ends_s[0, i, li])
+                lact = (li < n_layers) & (Ln > 0) & (failed == 0)
 
-            # full-graph rule (reference: src/window.cpp:88-97)
-            offset = (0.01 * bb_len.astype(jnp.float32)).astype(jnp.int32)
-            full = (begin < offset) & (end > bb_len - offset)
-            lo = jnp.where(full, jnp.float32(-KEY_INF),
-                           begin.astype(jnp.float32))
-            hi = jnp.where(full, jnp.float32(KEY_INF),
-                           end.astype(jnp.float32))
-
-            keys = rk_key[...]
-            r_lo = wsum(jnp.where(keys < lo, 1, 0))
-            r_hi = jnp.minimum(wsum(jnp.where(keys <= hi, 1, 0)), n)
-            r_start = jnp.min(jnp.where(lact, r_lo, N))
-            r_end = jnp.max(jnp.where(lact, r_hi, 0))
-
-            seqv = seq_scr[pl.ds(slot, 1)][0]          # (U, JC, G, 128)
-            seqm1 = shift_right(seqv, 255)             # lane j: seq[j-1]
-            rk_dmax[...] = jnp.max(rk_delta[...], axis=0)
-
-            # layer-invariant snapshots (the graph does not change during
-            # DP + traceback; Mosaic keeps these as VMEM-backed values)
-            dmax_v = rk_dmax[...]
-            delta_v = [rk_delta[e] for e in range(E)]
-            H0v = H0[...]
-
-            # distance cap: an IN-SUBGRAPH edge beyond DMAX fails the
-            # window (its H row is evicted from the ring; the host path
-            # takes over — the rank-distance histograms say this is rare)
-            in_sub = (rr >= r_lo) & (rr < r_hi)
-            far = jnp.zeros(w_shape, jnp.int32)
-            for e in range(E):
-                bad = ((delta_v[e] > DMAX) & in_sub &
-                       ((rr - delta_v[e]) >= r_lo))
-                far = far | wany(bad).astype(jnp.int32)
-            failed = first_cause(failed, lact & (far > 0), FAIL_DISTANCE)
-
-            esc[...] = jnp.full(n_shape, NEG, jnp.int32)
-
-            # ---- DP over ranks in lock-step -----------------------------
-            rs64 = (r_start // BLK) * BLK
-
-            def dp_body(r, _):
-                act = lact & (r >= r_lo) & (r < r_hi)
-                dmax_r = jnp.minimum(jnp.max(exr(rk_dmax, r)), DMAX)
-                dmax_r = jnp.minimum(dmax_r, r)
-                ds = []
-                for e in range(E):
-                    d_e = exr(rk_delta.at[e], r)
-                    valid = ((d_e > 0) & (d_e <= DMAX) &
-                             (r - d_e >= r_lo) & act)
-                    ds.append(jnp.where(valid, d_e, 0))
-                any_valid = ds[0] > 0
-                for e in range(1, E):
-                    any_valid = any_valid | (ds[e] > 0)
-
-                def delta_scan(d, P):
-                    prow = Hring[pl.ds((r - d) % RING, 1)][0]
-                    has = ds[0] == d
-                    for e in range(1, E):
-                        has = has | (ds[e] == d)
-                    return jnp.where(has, jnp.maximum(P, prow), P)
-
-                P0 = jnp.full(j_shape, NEG, jnp.int32)
-                P = jax.lax.fori_loop(1, dmax_r + 1, delta_scan, P0)
-                P = jnp.where(any_valid, P, H0v)
-
-                ub = exr(rk_base, r)
-                scvec = jnp.where(seqm1 == ub, M, X)
-                diag = shift_right(P, NEG) + scvec
-                up = P + GP
-                V = jnp.maximum(diag, up)
-                row = cummaxj(V - gvec) + gvec
-                if band:
-                    # diagonal band around the rank's backbone offset:
-                    # cells past the per-window half-band are masked to
-                    # NEG before the ring write, so later ranks, the end
-                    # score and the traceback all see banded values
-                    cr = (exr(rk_key, r) + 0.5).astype(jnp.int32) - begin
-                    row = jnp.where((wbv > 0) & (jnp.abs(jj - cr) > wbv),
-                                    NEG, row)
-                Hring[pl.ds(r % RING, 1)] = row[None]
-                rmw(esc, r, ex_v(row, Ln), act)
-
-                @pl.when((r + 1) % BLK == 0)
-                def _():
-                    flush_chunk((r + 1) // BLK - 1)
-                    # the chunk whose ring slots ranks [r+1, r+1+BLK)
-                    # will overwrite must have landed in HBM — if this
-                    # layer flushed it (its first chunk starts at rs64)
-                    @pl.when(r + 1 - RING >= rs64)
-                    def _():
-                        flush_wait((r + 1 - RING) // BLK)
-                return 0
-
-            # Rank-pair stepping: every serial iteration retires TWO
-            # consecutive ranks, halving the trip count. Ranks still
-            # execute strictly in order inside the body (rank r's ring
-            # row is written before rank r+1's delta scan reads it at
-            # d == 1), so the result is that of one rank per iteration.
-            # The flush schedule is untouched: rs64 and BLK are even, so
-            # the (r+1) % BLK == 0 trigger only ever fires on the second
-            # rank of a pair.
-            def pair_body(p, _):
-                r = rs64 + 2 * p
-                dp_body(r, 0)
-
-                @pl.when(r + 1 < r_end)
-                def _():
-                    dp_body(r + 1, 0)
-
-                return 0
-
-            jax.lax.fori_loop(0, (r_end - rs64 + 1) // 2, pair_body, 0)
-
-            # Every flush started is waited on exactly once: a DMA wait
-            # with no matching start never returns on the chip (interpret
-            # mode does not block, so only the TPU interpreter or silicon
-            # shows it).  The loop waited on every full chunk but the
-            # last; that one and the partial tail are still in flight.
-            @pl.when(r_end // BLK > rs64 // BLK)
-            def _():
-                flush_wait(r_end // BLK - 1)
-
-            @pl.when(r_end % BLK != 0)
-            def _():
-                flush_chunk(r_end // BLK)
-                flush_wait(r_end // BLK)
-
-            # ---- end-node selection -------------------------------------
-            # rank r is an end node iff no in-subgraph node has an edge
-            # from it (one masked dynamic shift per distance serves every
-            # rank at once)
-            dmax_all = jnp.minimum(
-                jnp.max(jnp.where(in_sub, dmax_v, 0)), DMAX)
-
-            def out_body(d, hm):
-                has_d = delta_v[0] == d
-                for e in range(1, E):
-                    has_d = has_d | (delta_v[e] == d)
-                src_ok = has_d & in_sub & ((rr - d) >= r_lo)
-                return hm | shift_left_dyn(src_ok.astype(jnp.int32), d, 0)
-
-            has_out = jax.lax.fori_loop(
-                1, dmax_all + 1, out_body,
-                jnp.zeros(n_shape, jnp.int32))
-            endok = in_sub & (has_out == 0)
-
-            escv = jnp.where(endok, esc[...], NEG)
-            best_s = wmax(escv)
-            best_r = wmin(jnp.where((escv == best_s) & endok, rr, N))
-            has_end = best_s > NEG
-            failed = first_cause(failed, lact & ~has_end, FAIL_OTHER)
-            if band:
-                # score-deficit verify (host mirror: band.poa_deficit_bound)
-                deficit_bad = (M * Ln - best_s >
-                               2 * (-GP) * jnp.maximum(wbv // 2, 1))
-                hit = hit | jnp.where(lact & (wbv > 0) & deficit_bad, 1, 0)
-
-            # ---- traceback: block-descending re-derivation --------------
-            walking = lact & has_end & (failed == 0)
-            cur = jnp.where(walking, best_r, -1)
-            jcur = jnp.where(walking, Ln, 0)
-            nk0 = jnp.full(w_shape, KEY_INF, jnp.float32)
-            run0 = jnp.zeros(w_shape, jnp.int32)
-            # loop-carried flags are i32 0/1: Mosaic cannot legalize an
-            # scf.for / scf.while that carries an i1 vector
-            done0 = jnp.where(walking, 0, 1)
-            b_top = jnp.max(jnp.where(walking, cur, 0)) // BLK
-
-            def tb_load(b, half):
-                pltpu.make_async_copy(
-                    hbm_H.at[b_prog, pl.ds(b * BLK, BLK)],
-                    Hring.at[pl.ds(half * BLK, BLK)],
-                    tb_sem.at[half]).start()
-
-            def tb_wait(b, half):
-                pltpu.make_async_copy(
-                    hbm_H.at[b_prog, pl.ds(b * BLK, BLK)],
-                    Hring.at[pl.ds(half * BLK, BLK)],
-                    tb_sem.at[half]).wait()
-
-            def ring_row(p):
-                """resident spill row for rank p (blocks b and b-1)."""
-                return Hring[pl.ds(((p // BLK) % 2) * BLK + p % BLK, 1)][0]
-
-            tb_load(b_top, b_top % 2)
-            tb_wait(b_top, b_top % 2)
-
-            @pl.when(b_top >= 1)
-            def _():
-                tb_load(b_top - 1, (b_top - 1) % 2)
-
-            def tb_rank_work(r, c):
-                cur, jcur, nk, run, done, failed = c[:6]
-                here = (done == 0) & (cur == r)
-                row = ring_row(r)
-                ub = exr(rk_base, r)
-                scv = jnp.where(seqm1 == ub, M, X)
-                ds = []
-                for e in range(E):
-                    d_e = exr(rk_delta.at[e], r)
-                    valid = (d_e > 0) & (d_e <= DMAX) & (r - d_e >= r_lo)
-                    ds.append(jnp.where(valid, d_e, 0))
-                any_v = ds[0] > 0
-                for e in range(1, E):
-                    any_v = any_v | (ds[e] > 0)
-                dmax_r = jnp.minimum(jnp.max(exr(rk_dmax, r)), DMAX)
-                dmax_r = jnp.minimum(dmax_r, r)
-
-                # min over (slot, delta) packed as slot*256+delta: the
-                # winning predecessor is the FIRST slot whose row explains
-                # the H value (host tie-break: edge insertion order)
-                def mscan(d, c2):
-                    wdiag, wup = c2
-                    prow = ring_row(r - d)
-                    s_of_d = jnp.full(w_shape, BIG, jnp.int32)
-                    for e in range(E - 1, -1, -1):
-                        s_of_d = jnp.where(ds[e] == d, e, s_of_d)
-                    has = s_of_d < BIG
-                    pk = s_of_d * 256 + d
-                    dm = has & (shift_right(prow, NEG) + scv == row)
-                    um = has & (prow + GP == row)
-                    wdiag = jnp.minimum(wdiag, jnp.where(dm, pk, WNONE))
-                    wup = jnp.minimum(wup, jnp.where(um, pk, WNONE))
-                    return (wdiag, wup)
-
-                W0 = jnp.full(j_shape, WNONE, jnp.int32)
-                wdiag, wup = jax.lax.fori_loop(1, dmax_r + 1, mscan,
-                                               (W0, W0))
-                vdiag = ~any_v & (shift_right(H0v, NEG) + scv == row)
-                vup = ~any_v & (H0v + GP == row)
-                diag_ok = (wdiag < WNONE) | vdiag
-                ok = diag_ok | (wup < WNONE) | vup
-
-                # insertion run: walk left to the nearest explained cell
-                okm = ok & (jj <= jcur) & here
-                j_stop = wmax(jnp.where(okm, jj, -1))
-                stuck = here & (j_stop < 0)
-                failed = first_cause(failed, stuck, FAIL_OTHER)
-                done = done | jnp.where(stuck, 1, 0)
-                act = here & ~stuck
-                j_stop = jnp.maximum(j_stop, 0)
-                if band:
-                    # boundary touch: a column visited at this rank came
-                    # within one cell of the band edge (the run's extreme
-                    # columns are j_stop and the entry jcur)
-                    cr_tb = (exr(rk_key, r) + 0.5).astype(jnp.int32) - begin
-                    near = act & (wbv > 0) & (
-                        (jnp.abs(j_stop - cr_tb) >= wbv - 1) |
-                        (jnp.abs(jcur - cr_tb) >= wbv - 1))
-                    hit_tb = c[6] | jnp.where(near, 1, 0)
-
-                lanes = (jj >= j_stop) & (jj < jcur) & act
-                runrem[...] = jnp.where(lanes, run + (jcur - jj),
-                                        runrem[...])
-                nkey[...] = jnp.where(lanes, nk, nkey[...])
-                run = jnp.where(act, run + (jcur - j_stop), run)
-
-                # the descending move at j_stop (diag > up priority)
-                take_diag = act & (ex_v(
-                    jnp.where(diag_ok, 1, 0), j_stop) == 1)
-                wd = ex_v(jnp.where(wdiag == WNONE, 0, wdiag), j_stop)
-                wd_virt = ex_v(jnp.where(wdiag == WNONE, 1, 0),
-                               j_stop) == 1
-                wu = ex_v(jnp.where(wup == WNONE, 0, wup), j_stop)
-                wu_virt = ex_v(jnp.where(wup == WNONE, 1, 0), j_stop) == 1
-                take_up = act & ~take_diag
-
-                kr = exr(rk_key, r)
-                nk = jnp.where(take_diag, kr, nk)
-                mlane = (jj == j_stop - 1) & take_diag
-                runrem[...] = jnp.where(mlane, 0, runrem[...])
-                nkey[...] = jnp.where(mlane, kr, nkey[...])
-                run = jnp.where(take_diag, 0, run)
-                jcur = jnp.where(take_diag, j_stop - 1,
-                                 jnp.where(take_up, j_stop, jcur))
-
-                new_cur = jnp.where(
-                    take_diag,
-                    jnp.where(wd_virt, -1, r - wd % 256),
-                    jnp.where(wu_virt, -1, r - wu % 256))
-                cur = jnp.where(act, new_cur, cur)
-
-                # a window that reached the virtual row finishes its
-                # remaining insertions in one masked write
-                at_virt = act & (cur == -1)
-                vl = (jj < jcur) & at_virt
-                runrem[...] = jnp.where(vl, run + (jcur - jj), runrem[...])
-                nkey[...] = jnp.where(vl, nk, nkey[...])
-                done = done | jnp.where(at_virt, 1, 0)
-                out = (cur, jcur, nk, run, done, failed)
-                if band:
-                    out = out + (hit_tb,)
-                return out
-
-            def tb_rank(i, c):
-                b = c[0]
-                r = b * BLK + (BLK - 1 - i)
-                cc = c[1:]
-                here_any = jnp.any((cc[4] == 0) & (cc[0] == r))
-                cc2 = jax.lax.cond(here_any,
-                                   lambda cc: tb_rank_work(r, cc),
-                                   lambda cc: cc, cc)
-                return (b,) + cc2
-
-            def tb_block(i, c):
-                b = b_top - i
-
-                @pl.when(b >= 1)
-                def _():
-                    tb_wait(b - 1, (b - 1) % 2)
-
-                c2 = jax.lax.fori_loop(0, BLK, tb_rank, (b,) + c)[1:]
-
-                @pl.when(b >= 2)
-                def _():
-                    tb_load(b - 2, b % 2)
-                return c2
-
-            if band:
-                cur, jcur, nk, run, done, failed, hit = jax.lax.fori_loop(
-                    0, b_top + 1, tb_block,
-                    (cur, jcur, nk0, run0, done0, failed, hit))
-            else:
-                cur, jcur, nk, run, done, failed = jax.lax.fori_loop(
-                    0, b_top + 1, tb_block,
-                    (cur, jcur, nk0, run0, done0, failed))
-            failed = first_cause(failed, (done == 0) & lact, FAIL_OTHER)
-
-            # ---- graph update (parity: rt_poa.cpp add_alignment) --------
-            maxL = jnp.max(jnp.where(lact & (failed == 0), Ln, 0))
-
-            # In-edge slots a group's node insertions sweep this layer:
-            # a slot at or past a row's rk_cnt is zero in rk_delta and
-            # rk_ew (an edge is written at slot rk_cnt, an inserted row
-            # starts at 0, rk_cnt moves with its row), and a layer adds
-            # at most one in-edge to a node (the path visits a column
-            # once), so one more than the group's largest count at the
-            # layer's start bounds every slot that holds anything, or
-            # comes to hold it, before the next layer.  One
-            # vector-to-scalar turn a group a layer, not one a step.
-            k_ins = [jnp.minimum(1 + jnp.max(rk_cnt[u:u + 1]), E)
-                     for u in range(U)]
-            swept = swept + sum(k_ins)
-
-            def upd_body(j, c):
-                n, failed, prev_r, prev_key, prev_w = c
-                act = lact & (j < Ln) & (failed == 0)
-                b = exs(seq_scr, slot, j)
-                wj = exs(w_scr, slot, j)
-                run_j = exr(runrem, j)
-                nk_j = exr(nkey, j)
-                is_match = (run_j == 0) & act
-                k0 = nk_j
+                # full-graph rule (reference: src/window.cpp:88-97)
+                offset = (0.01 * bb_len.astype(jnp.float32)).astype(jnp.int32)
+                full = (begin < offset) & (end > bb_len - offset)
+                lo = jnp.where(full, jnp.float32(-KEY_INF),
+                               begin.astype(jnp.float32))
+                hi = jnp.where(full, jnp.float32(KEY_INF),
+                               end.astype(jnp.float32))
 
                 keys = rk_key[...]
-                basev = rk_base[...]
-                cand = (keys == k0) & (basev == b)
-                has = wany(cand) & is_match
-                found = wmin(jnp.where(cand, rr, N))
+                r_lo = wsum(jnp.where(keys < lo, 1, 0))
+                r_hi = jnp.minimum(wsum(jnp.where(keys <= hi, 1, 0)), n)
+                r_start = jnp.min(jnp.where(lact, r_lo, N))
+                r_end = jnp.max(jnp.where(lact, r_hi, 0))
 
-                runf = run_j.astype(jnp.float32)
-                hi2 = jnp.where(nk_j < KEY_INF, nk_j, prev_key + 1.0)
-                lo2 = jnp.where(prev_r >= 0, prev_key, hi2 - runf - 1.0)
-                k_new = lo2 + (hi2 - lo2) / (runf + 1.0)
-                key_val = jnp.where(is_match, k0, k_new)
+                seqv = seq_scr[pl.ds(slot, 1)][0]          # (U, JC, G, 128)
+                seqm1 = shift_right(seqv, 255)             # lane j: seq[j-1]
+                rk_dmax[...] = jnp.max(rk_delta[...], axis=0)
 
-                need_new = act & ~has
-                overflow = need_new & (n >= N)
-                do_new = need_new & ~overflow
-                p_ins = wsum(jnp.where(keys <= key_val, 1, 0))
-                nid = jnp.where(has, found, jnp.minimum(p_ins, N - 1))
+                # layer-invariant snapshots (the graph does not change during
+                # DP + traceback; Mosaic keeps these as VMEM-backed values)
+                dmax_v = rk_dmax[...]
+                delta_v = [rk_delta[e] for e in range(E)]
+                H0v = H0[...]
 
-                # Each group pays for its own insertions only, under
-                # its own gate.  Everything else in the step is shared.
-                def insert_node(u):
-                    grp = pl.ds(u, 1)
-                    dn = do_new[u:u + 1]
-                    pi = p_ins[u:u + 1]
+                # distance cap: an IN-SUBGRAPH edge beyond DMAX fails the
+                # window (its H row is evicted from the ring; the host path
+                # takes over — the rank-distance histograms say this is rare)
+                in_sub = (rr >= r_lo) & (rr < r_hi)
+                far = jnp.zeros(w_shape, jnp.int32)
+                for e in range(E):
+                    bad = ((delta_v[e] > DMAX) & in_sub &
+                           ((rr - delta_v[e]) >= r_lo))
+                    far = far | wany(bad).astype(jnp.int32)
+                failed = first_cause(failed, lact & (far > 0), FAIL_DISTANCE)
 
-                    @pl.when(jnp.any(dn))
+                esc[...] = jnp.full(n_shape, NEG, jnp.int32)
+
+            with jax.named_scope(R_DP):
+                # ---- DP over ranks in lock-step -----------------------------
+                rs64 = (r_start // BLK) * BLK
+
+                def dp_body(r, _):
+                    act = lact & (r >= r_lo) & (r < r_hi)
+                    dmax_r = jnp.minimum(jnp.max(exr(rk_dmax, r)), DMAX)
+                    dmax_r = jnp.minimum(dmax_r, r)
+                    ds = []
+                    for e in range(E):
+                        d_e = exr(rk_delta.at[e], r)
+                        valid = ((d_e > 0) & (d_e <= DMAX) &
+                                 (r - d_e >= r_lo) & act)
+                        ds.append(jnp.where(valid, d_e, 0))
+                    any_valid = ds[0] > 0
+                    for e in range(1, E):
+                        any_valid = any_valid | (ds[e] > 0)
+
+                    def delta_scan(d, P):
+                        prow = Hring[pl.ds((r - d) % RING, 1)][0]
+                        has = ds[0] == d
+                        for e in range(1, E):
+                            has = has | (ds[e] == d)
+                        return jnp.where(has, jnp.maximum(P, prow), P)
+
+                    P0 = jnp.full(j_shape, NEG, jnp.int32)
+                    P = jax.lax.fori_loop(1, dmax_r + 1, delta_scan, P0)
+                    P = jnp.where(any_valid, P, H0v)
+
+                    ub = exr(rk_base, r)
+                    scvec = jnp.where(seqm1 == ub, M, X)
+                    diag = shift_right(P, NEG) + scvec
+                    up = P + GP
+                    V = jnp.maximum(diag, up)
+                    row = cummaxj(V - gvec) + gvec
+                    if band:
+                        # diagonal band around the rank's backbone offset:
+                        # cells past the per-window half-band are masked to
+                        # NEG before the ring write, so later ranks, the end
+                        # score and the traceback all see banded values
+                        cr = (exr(rk_key, r) + 0.5).astype(jnp.int32) - begin
+                        row = jnp.where((wbv > 0) & (jnp.abs(jj - cr) > wbv),
+                                        NEG, row)
+                    Hring[pl.ds(r % RING, 1)] = row[None]
+                    rmw(esc, r, ex_v(row, Ln), act)
+
+                    @pl.when((r + 1) % BLK == 0)
                     def _():
-                        sh = (rr >= pi) & dn
-                        new_row = (rr == pi) & dn
-                        for ref, fill, val in (
-                                (rk_base, -1, b[u:u + 1]),
-                                (rk_key, KEY_INF, key_val[u:u + 1]),
-                                (rk_cov, 0, 0), (rk_cnt, 0, 0)):
-                            v = ref[grp]
-                            v = jnp.where(sh, shift_right(v, fill), v)
-                            ref[grp] = jnp.where(new_row, val, v)
-
-                        # Slots 0 .. k_ins[u] - 1, rounded up to whole
-                        # blocks of SB: a slot at or past a row's rk_cnt
-                        # is zero, so shifting it moves nothing.
-                        # Measured on the v5e, two 30x ONT jobs of
-                        # kernel: all E slots in one pass 12.87 s; a
-                        # loop of k_ins steps, a slot a step, 12.00 s
-                        # (PR 48; E copies under pl.when the same); SB
-                        # slots a step as one load, one shift and one
-                        # store (PR 49), x1.026 end to end at SB = 4
-                        # against x1.019 / x1.018 / x1.015 at 2 / 6 / 12.
-                        # A step costs its serial chain, not its vregs:
-                        # bounding the lane-chunks a step shifts to
-                        # those the insertion reaches (2 to 6 chunks a
-                        # step, down from a bound on the group's node
-                        # counts) ran x0.78 to x0.98, each step of that
-                        # walk running this loop once more.
-                        def shift_slots(i, _):
-                            slots = pl.ds(i * SB, SB)
-                            vd = rk_delta[slots, grp][:, 0]
-                            sd = shift_right(vd, 0)
-                            # an edge whose source sits below the
-                            # insertion point now spans it: distance
-                            # grows by one
-                            sd = sd + jnp.where(
-                                (sd > 0) & (rr - 1 - sd < pi), 1, 0)
-                            # the inserted row starts with no edges
-                            rk_delta[slots, grp] = jnp.where(
-                                new_row, 0,
-                                jnp.where(sh, sd, vd))[:, None]
-                            vw = rk_ew[slots, grp][:, 0]
-                            rk_ew[slots, grp] = jnp.where(
-                                new_row, 0,
-                                jnp.where(sh, shift_right(vw, 0),
-                                          vw))[:, None]
-                            return 0
-
-                        jax.lax.fori_loop(0, (k_ins[u] + SB - 1) // SB,
-                                          shift_slots, 0)
-
-                for u in range(U):
-                    insert_node(u)
-
-                touch = act & ~overflow
-                rmw_v(rk_cov, nid, ex_v(rk_cov[...], nid) + 1, touch)
-                n = n + jnp.where(do_new, 1, 0)
-                failed = first_cause(failed, overflow, FAIL_NODES)
-
-                # edge prev -> nid with weight w[j-1] + w[j]
-                prev_r = prev_r + jnp.where(do_new & (prev_r >= p_ins),
-                                            1, 0)
-                has_prev = touch & (prev_r >= 0)
-                d_tgt = nid - prev_r
-                cntv = ex_v(rk_cnt[...], nid)
-                cnt_max = jnp.max(jnp.where(has_prev, cntv, 0))
-
-                def same_scan(e, s):
-                    de = ex_v(rk_delta[pl.ds(e, 1)][0], nid)
-                    return jnp.where((s < 0) & (e < cntv) & (de == d_tgt),
-                                     e, s)
-
-                same = jax.lax.fori_loop(
-                    0, cnt_max, same_scan,
-                    jnp.full(w_shape, -1, jnp.int32))
-                ew = prev_w + wj
-                add_new = has_prev & (same < 0) & (cntv < E)
-
-                def eslot_write(e, _):
-                    m_same = has_prev & (same == e)
-                    m_new = add_new & (cntv == e)
-                    roww = rk_ew[pl.ds(e, 1)][0]
-                    rk_ew[pl.ds(e, 1)] = jnp.where(
-                        (rr == nid) & (m_same | m_new),
-                        jnp.where(m_same, roww + ew, ew), roww)[None]
-                    rowd = rk_delta[pl.ds(e, 1)][0]
-                    rk_delta[pl.ds(e, 1)] = jnp.where(
-                        (rr == nid) & m_new, d_tgt, rowd)[None]
+                        flush_chunk((r + 1) // BLK - 1)
+                        # the chunk whose ring slots ranks [r+1, r+1+BLK)
+                        # will overwrite must have landed in HBM — if this
+                        # layer flushed it (its first chunk starts at rs64)
+                        @pl.when(r + 1 - RING >= rs64)
+                        def _():
+                            flush_wait((r + 1 - RING) // BLK)
                     return 0
 
-                slot_hi = jnp.maximum(
-                    cnt_max, jnp.max(jnp.where(add_new, cntv + 1, 0)))
-                jax.lax.fori_loop(0, slot_hi, eslot_write, 0)
-                rmw_v(rk_cnt, nid, cntv + 1, add_new)
-                failed = first_cause(
-                    failed, has_prev & (same < 0) & (cntv >= E), FAIL_EDGES)
+                # Rank-pair stepping: every serial iteration retires TWO
+                # consecutive ranks, halving the trip count. Ranks still
+                # execute strictly in order inside the body (rank r's ring
+                # row is written before rank r+1's delta scan reads it at
+                # d == 1), so the result is that of one rank per iteration.
+                # The flush schedule is untouched: rs64 and BLK are even, so
+                # the (r+1) % BLK == 0 trigger only ever fires on the second
+                # rank of a pair.
+                def pair_body(p, _):
+                    r = rs64 + 2 * p
+                    dp_body(r, 0)
 
-                prev_r = jnp.where(act, nid, prev_r)
-                prev_key = jnp.where(act, key_val, prev_key)
-                prev_w = jnp.where(act, wj, prev_w)
-                return (n, failed, prev_r, prev_key, prev_w)
+                    @pl.when(r + 1 < r_end)
+                    def _():
+                        dp_body(r + 1, 0)
 
-            n, failed, _, _, _ = jax.lax.fori_loop(
-                0, maxL, upd_body,
-                (n, failed,
-                 jnp.full(w_shape, -1, jnp.int32),
-                 jnp.full(w_shape, -1.0, jnp.float32),
-                 jnp.zeros(w_shape, jnp.int32)))
-            return (n, failed, hit, swept) if band else (n, failed, swept)
+                    return 0
+
+                pairs = (r_end - rs64 + 1) // 2
+                jax.lax.fori_loop(0, pairs, pair_body, 0)
+                bump("steps.dp", 2 * jnp.maximum(pairs, 0))
+
+                # Every flush started is waited on exactly once: a DMA wait
+                # with no matching start never returns on the chip (interpret
+                # mode does not block, so only the TPU interpreter or silicon
+                # shows it).  The loop waited on every full chunk but the
+                # last; that one and the partial tail are still in flight.
+                @pl.when(r_end // BLK > rs64 // BLK)
+                def _():
+                    flush_wait(r_end // BLK - 1)
+
+                @pl.when(r_end % BLK != 0)
+                def _():
+                    flush_chunk(r_end // BLK)
+                    flush_wait(r_end // BLK)
+
+            with jax.named_scope(R_ENDS):
+                # ---- end-node selection -------------------------------------
+                # rank r is an end node iff no in-subgraph node has an edge
+                # from it (one masked dynamic shift per distance serves every
+                # rank at once)
+                dmax_all = jnp.minimum(
+                    jnp.max(jnp.where(in_sub, dmax_v, 0)), DMAX)
+
+                def out_body(d, hm):
+                    has_d = delta_v[0] == d
+                    for e in range(1, E):
+                        has_d = has_d | (delta_v[e] == d)
+                    src_ok = has_d & in_sub & ((rr - d) >= r_lo)
+                    return hm | shift_left_dyn(src_ok.astype(jnp.int32), d, 0)
+
+                has_out = jax.lax.fori_loop(
+                    1, dmax_all + 1, out_body,
+                    jnp.zeros(n_shape, jnp.int32))
+                endok = in_sub & (has_out == 0)
+
+                escv = jnp.where(endok, esc[...], NEG)
+                best_s = wmax(escv)
+                best_r = wmin(jnp.where((escv == best_s) & endok, rr, N))
+                has_end = best_s > NEG
+                failed = first_cause(failed, lact & ~has_end, FAIL_OTHER)
+                if band:
+                    # score-deficit verify (host mirror:
+                    # band.poa_deficit_bound)
+                    deficit_bad = (M * Ln - best_s >
+                                   2 * (-GP) * jnp.maximum(wbv // 2, 1))
+                    hit = hit | jnp.where(lact & (wbv > 0) & deficit_bad, 1, 0)
+
+            with jax.named_scope(R_TRACEBACK):
+                # ---- traceback: block-descending re-derivation --------------
+                walking = lact & has_end & (failed == 0)
+                cur = jnp.where(walking, best_r, -1)
+                jcur = jnp.where(walking, Ln, 0)
+                nk0 = jnp.full(w_shape, KEY_INF, jnp.float32)
+                run0 = jnp.zeros(w_shape, jnp.int32)
+                # loop-carried flags are i32 0/1: Mosaic cannot legalize an
+                # scf.for / scf.while that carries an i1 vector
+                done0 = jnp.where(walking, 0, 1)
+                b_top = jnp.max(jnp.where(walking, cur, 0)) // BLK
+
+                def tb_load(b, half):
+                    pltpu.make_async_copy(
+                        hbm_H.at[b_prog, pl.ds(b * BLK, BLK)],
+                        Hring.at[pl.ds(half * BLK, BLK)],
+                        tb_sem.at[half]).start()
+
+                def tb_wait(b, half):
+                    pltpu.make_async_copy(
+                        hbm_H.at[b_prog, pl.ds(b * BLK, BLK)],
+                        Hring.at[pl.ds(half * BLK, BLK)],
+                        tb_sem.at[half]).wait()
+
+                def ring_row(p):
+                    """resident spill row for rank p (blocks b and b-1)."""
+                    return Hring[pl.ds(((p // BLK) % 2) * BLK + p % BLK, 1)][0]
+
+                tb_load(b_top, b_top % 2)
+                tb_wait(b_top, b_top % 2)
+
+                @pl.when(b_top >= 1)
+                def _():
+                    tb_load(b_top - 1, (b_top - 1) % 2)
+
+                def tb_rank_work(r, c):
+                    cur, jcur, nk, run, done, failed = c[:6]
+                    here = (done == 0) & (cur == r)
+                    row = ring_row(r)
+                    ub = exr(rk_base, r)
+                    scv = jnp.where(seqm1 == ub, M, X)
+                    ds = []
+                    for e in range(E):
+                        d_e = exr(rk_delta.at[e], r)
+                        valid = (d_e > 0) & (d_e <= DMAX) & (r - d_e >= r_lo)
+                        ds.append(jnp.where(valid, d_e, 0))
+                    any_v = ds[0] > 0
+                    for e in range(1, E):
+                        any_v = any_v | (ds[e] > 0)
+                    dmax_r = jnp.minimum(jnp.max(exr(rk_dmax, r)), DMAX)
+                    dmax_r = jnp.minimum(dmax_r, r)
+
+                    # min over (slot, delta) packed as slot*256+delta: the
+                    # winning predecessor is the FIRST slot whose row explains
+                    # the H value (host tie-break: edge insertion order)
+                    def mscan(d, c2):
+                        wdiag, wup = c2
+                        prow = ring_row(r - d)
+                        s_of_d = jnp.full(w_shape, BIG, jnp.int32)
+                        for e in range(E - 1, -1, -1):
+                            s_of_d = jnp.where(ds[e] == d, e, s_of_d)
+                        has = s_of_d < BIG
+                        pk = s_of_d * 256 + d
+                        dm = has & (shift_right(prow, NEG) + scv == row)
+                        um = has & (prow + GP == row)
+                        wdiag = jnp.minimum(wdiag, jnp.where(dm, pk, WNONE))
+                        wup = jnp.minimum(wup, jnp.where(um, pk, WNONE))
+                        return (wdiag, wup)
+
+                    W0 = jnp.full(j_shape, WNONE, jnp.int32)
+                    wdiag, wup = jax.lax.fori_loop(1, dmax_r + 1, mscan,
+                                                   (W0, W0))
+                    vdiag = ~any_v & (shift_right(H0v, NEG) + scv == row)
+                    vup = ~any_v & (H0v + GP == row)
+                    diag_ok = (wdiag < WNONE) | vdiag
+                    ok = diag_ok | (wup < WNONE) | vup
+
+                    # insertion run: walk left to the nearest explained cell
+                    okm = ok & (jj <= jcur) & here
+                    j_stop = wmax(jnp.where(okm, jj, -1))
+                    stuck = here & (j_stop < 0)
+                    failed = first_cause(failed, stuck, FAIL_OTHER)
+                    done = done | jnp.where(stuck, 1, 0)
+                    act = here & ~stuck
+                    j_stop = jnp.maximum(j_stop, 0)
+                    if band:
+                        # boundary touch: a column visited at this rank came
+                        # within one cell of the band edge (the run's extreme
+                        # columns are j_stop and the entry jcur)
+                        cr_tb = ((exr(rk_key, r) + 0.5).astype(jnp.int32)
+                                 - begin)
+                        near = act & (wbv > 0) & (
+                            (jnp.abs(j_stop - cr_tb) >= wbv - 1) |
+                            (jnp.abs(jcur - cr_tb) >= wbv - 1))
+                        hit_tb = c[6] | jnp.where(near, 1, 0)
+
+                    lanes = (jj >= j_stop) & (jj < jcur) & act
+                    runrem[...] = jnp.where(lanes, run + (jcur - jj),
+                                            runrem[...])
+                    nkey[...] = jnp.where(lanes, nk, nkey[...])
+                    run = jnp.where(act, run + (jcur - j_stop), run)
+
+                    # the descending move at j_stop (diag > up priority)
+                    take_diag = act & (ex_v(
+                        jnp.where(diag_ok, 1, 0), j_stop) == 1)
+                    wd = ex_v(jnp.where(wdiag == WNONE, 0, wdiag), j_stop)
+                    wd_virt = ex_v(jnp.where(wdiag == WNONE, 1, 0),
+                                   j_stop) == 1
+                    wu = ex_v(jnp.where(wup == WNONE, 0, wup), j_stop)
+                    wu_virt = ex_v(jnp.where(wup == WNONE, 1, 0), j_stop) == 1
+                    take_up = act & ~take_diag
+
+                    kr = exr(rk_key, r)
+                    nk = jnp.where(take_diag, kr, nk)
+                    mlane = (jj == j_stop - 1) & take_diag
+                    runrem[...] = jnp.where(mlane, 0, runrem[...])
+                    nkey[...] = jnp.where(mlane, kr, nkey[...])
+                    run = jnp.where(take_diag, 0, run)
+                    jcur = jnp.where(take_diag, j_stop - 1,
+                                     jnp.where(take_up, j_stop, jcur))
+
+                    new_cur = jnp.where(
+                        take_diag,
+                        jnp.where(wd_virt, -1, r - wd % 256),
+                        jnp.where(wu_virt, -1, r - wu % 256))
+                    cur = jnp.where(act, new_cur, cur)
+
+                    # a window that reached the virtual row finishes its
+                    # remaining insertions in one masked write
+                    at_virt = act & (cur == -1)
+                    vl = (jj < jcur) & at_virt
+                    runrem[...] = jnp.where(vl, run + (jcur - jj), runrem[...])
+                    nkey[...] = jnp.where(vl, nk, nkey[...])
+                    done = done | jnp.where(at_virt, 1, 0)
+                    out = (cur, jcur, nk, run, done, failed)
+                    if band:
+                        out = out + (hit_tb,)
+                    return out
+
+                def tb_rank(i, c):
+                    b = c[0]
+                    r = b * BLK + (BLK - 1 - i)
+                    cc = c[1:]
+                    here_any = jnp.any((cc[4] == 0) & (cc[0] == r))
+                    cc2 = jax.lax.cond(here_any,
+                                       lambda cc: tb_rank_work(r, cc),
+                                       lambda cc: cc, cc)
+                    return (b,) + cc2
+
+                def tb_block(i, c):
+                    b = b_top - i
+
+                    @pl.when(b >= 1)
+                    def _():
+                        tb_wait(b - 1, (b - 1) % 2)
+
+                    c2 = jax.lax.fori_loop(0, BLK, tb_rank, (b,) + c)[1:]
+
+                    @pl.when(b >= 2)
+                    def _():
+                        tb_load(b - 2, b % 2)
+                    return c2
+
+                if band:
+                    cur, jcur, nk, run, done, failed, hit = jax.lax.fori_loop(
+                        0, b_top + 1, tb_block,
+                        (cur, jcur, nk0, run0, done0, failed, hit))
+                else:
+                    cur, jcur, nk, run, done, failed = jax.lax.fori_loop(
+                        0, b_top + 1, tb_block,
+                        (cur, jcur, nk0, run0, done0, failed))
+                failed = first_cause(failed, (done == 0) & lact, FAIL_OTHER)
+                bump("steps.traceback", (b_top + 1) * BLK)
+
+            with jax.named_scope(R_UPDATE):
+                # ---- graph update (parity: rt_poa.cpp add_alignment) --------
+                maxL = jnp.max(jnp.where(lact & (failed == 0), Ln, 0))
+
+                # In-edge slots a group's node insertions sweep this layer:
+                # a slot at or past a row's rk_cnt is zero in rk_delta and
+                # rk_ew (an edge is written at slot rk_cnt, an inserted row
+                # starts at 0, rk_cnt moves with its row), and a layer adds
+                # at most one in-edge to a node (the path visits a column
+                # once), so one more than the group's largest count at the
+                # layer's start bounds every slot that holds anything, or
+                # comes to hold it, before the next layer.  One
+                # vector-to-scalar turn a group a layer, not one a step.
+                k_ins = [jnp.minimum(1 + jnp.max(rk_cnt[u:u + 1]), E)
+                         for u in range(U)]
+                # (E a group a layer would be all of them)
+                bump("slots_swept", sum(k_ins))
+
+                def upd_body(j, c):
+                    n, failed, prev_r, prev_key, prev_w = c
+                    act = lact & (j < Ln) & (failed == 0)
+                    b = exs(seq_scr, slot, j)
+                    wj = exs(w_scr, slot, j)
+                    run_j = exr(runrem, j)
+                    nk_j = exr(nkey, j)
+                    is_match = (run_j == 0) & act
+                    k0 = nk_j
+
+                    keys = rk_key[...]
+                    basev = rk_base[...]
+                    cand = (keys == k0) & (basev == b)
+                    has = wany(cand) & is_match
+                    found = wmin(jnp.where(cand, rr, N))
+
+                    runf = run_j.astype(jnp.float32)
+                    hi2 = jnp.where(nk_j < KEY_INF, nk_j, prev_key + 1.0)
+                    lo2 = jnp.where(prev_r >= 0, prev_key, hi2 - runf - 1.0)
+                    k_new = lo2 + (hi2 - lo2) / (runf + 1.0)
+                    key_val = jnp.where(is_match, k0, k_new)
+
+                    need_new = act & ~has
+                    overflow = need_new & (n >= N)
+                    do_new = need_new & ~overflow
+                    p_ins = wsum(jnp.where(keys <= key_val, 1, 0))
+                    nid = jnp.where(has, found, jnp.minimum(p_ins, N - 1))
+
+                    # Each group pays for its own insertions only, under
+                    # its own gate.  Everything else in the step is shared.
+                    def insert_node(u):
+                        grp = pl.ds(u, 1)
+                        dn = do_new[u:u + 1]
+                        pi = p_ins[u:u + 1]
+
+                        @pl.when(jnp.any(dn))
+                        def _():
+                            sh = (rr >= pi) & dn
+                            new_row = (rr == pi) & dn
+                            for ref, fill, val in (
+                                    (rk_base, -1, b[u:u + 1]),
+                                    (rk_key, KEY_INF, key_val[u:u + 1]),
+                                    (rk_cov, 0, 0), (rk_cnt, 0, 0)):
+                                v = ref[grp]
+                                v = jnp.where(sh, shift_right(v, fill), v)
+                                ref[grp] = jnp.where(new_row, val, v)
+
+                            # Slots 0 .. k_ins[u] - 1, rounded up to whole
+                            # blocks of SB: a slot at or past a row's rk_cnt
+                            # is zero, so shifting it moves nothing.
+                            # Measured on the v5e, two 30x ONT jobs of
+                            # kernel: all E slots in one pass 12.87 s; a
+                            # loop of k_ins steps, a slot a step, 12.00 s
+                            # (PR 48; E copies under pl.when the same); SB
+                            # slots a step as one load, one shift and one
+                            # store (PR 49), x1.026 end to end at SB = 4
+                            # against x1.019 / x1.018 / x1.015 at 2 / 6 / 12.
+                            # A step costs its serial chain, not its vregs:
+                            # bounding the lane-chunks a step shifts to
+                            # those the insertion reaches (2 to 6 chunks a
+                            # step, down from a bound on the group's node
+                            # counts) ran x0.78 to x0.98, each step of that
+                            # walk running this loop once more.
+                            def shift_slots(i, _):
+                                slots = pl.ds(i * SB, SB)
+                                vd = rk_delta[slots, grp][:, 0]
+                                sd = shift_right(vd, 0)
+                                # an edge whose source sits below the
+                                # insertion point now spans it: distance
+                                # grows by one
+                                sd = sd + jnp.where(
+                                    (sd > 0) & (rr - 1 - sd < pi), 1, 0)
+                                # the inserted row starts with no edges
+                                rk_delta[slots, grp] = jnp.where(
+                                    new_row, 0,
+                                    jnp.where(sh, sd, vd))[:, None]
+                                vw = rk_ew[slots, grp][:, 0]
+                                rk_ew[slots, grp] = jnp.where(
+                                    new_row, 0,
+                                    jnp.where(sh, shift_right(vw, 0),
+                                              vw))[:, None]
+                                return 0
+
+                            steps = (k_ins[u] + SB - 1) // SB
+                            jax.lax.fori_loop(0, steps, shift_slots, 0)
+                            bump("insert.firings", 1)
+                            bump("insert.shift_steps", steps)
+
+                    for u in range(U):
+                        insert_node(u)
+
+                    touch = act & ~overflow
+                    rmw_v(rk_cov, nid, ex_v(rk_cov[...], nid) + 1, touch)
+                    n = n + jnp.where(do_new, 1, 0)
+                    failed = first_cause(failed, overflow, FAIL_NODES)
+
+                    # edge prev -> nid with weight w[j-1] + w[j]
+                    prev_r = prev_r + jnp.where(do_new & (prev_r >= p_ins),
+                                                1, 0)
+                    has_prev = touch & (prev_r >= 0)
+                    d_tgt = nid - prev_r
+                    cntv = ex_v(rk_cnt[...], nid)
+                    cnt_max = jnp.max(jnp.where(has_prev, cntv, 0))
+
+                    def same_scan(e, s):
+                        de = ex_v(rk_delta[pl.ds(e, 1)][0], nid)
+                        return jnp.where((s < 0) & (e < cntv) & (de == d_tgt),
+                                         e, s)
+
+                    same = jax.lax.fori_loop(
+                        0, cnt_max, same_scan,
+                        jnp.full(w_shape, -1, jnp.int32))
+                    ew = prev_w + wj
+                    add_new = has_prev & (same < 0) & (cntv < E)
+
+                    def eslot_write(e, _):
+                        m_same = has_prev & (same == e)
+                        m_new = add_new & (cntv == e)
+                        roww = rk_ew[pl.ds(e, 1)][0]
+                        rk_ew[pl.ds(e, 1)] = jnp.where(
+                            (rr == nid) & (m_same | m_new),
+                            jnp.where(m_same, roww + ew, ew), roww)[None]
+                        rowd = rk_delta[pl.ds(e, 1)][0]
+                        rk_delta[pl.ds(e, 1)] = jnp.where(
+                            (rr == nid) & m_new, d_tgt, rowd)[None]
+                        return 0
+
+                    slot_hi = jnp.maximum(
+                        cnt_max, jnp.max(jnp.where(add_new, cntv + 1, 0)))
+                    jax.lax.fori_loop(0, slot_hi, eslot_write, 0)
+                    rmw_v(rk_cnt, nid, cntv + 1, add_new)
+                    failed = first_cause(
+                        failed, has_prev & (same < 0) & (cntv >= E),
+                        FAIL_EDGES)
+
+                    prev_r = jnp.where(act, nid, prev_r)
+                    prev_key = jnp.where(act, key_val, prev_key)
+                    prev_w = jnp.where(act, wj, prev_w)
+                    return (n, failed, prev_r, prev_key, prev_w)
+
+                n, failed, _, _, _ = jax.lax.fori_loop(
+                    0, maxL, upd_body,
+                    (n, failed,
+                     jnp.full(w_shape, -1, jnp.int32),
+                     jnp.full(w_shape, -1.0, jnp.float32),
+                     jnp.zeros(w_shape, jnp.int32)))
+                bump("steps.update", maxL)
+            return (n, failed, hit) if band else (n, failed)
 
         @pl.when(max_layers > 0)
         def _():
@@ -863,113 +907,114 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             return do_layer(li, slot, carry)
 
         if band:
-            n, failed, hit, swept = jax.lax.fori_loop(
+            n, failed, hit = jax.lax.fori_loop(
                 0, max_layers, layer_loop,
                 (bb_len, jnp.zeros(w_shape, jnp.int32),
-                 jnp.zeros(w_shape, jnp.int32), jnp.int32(0)))
+                 jnp.zeros(w_shape, jnp.int32)))
         else:
-            n, failed, swept = jax.lax.fori_loop(
+            n, failed = jax.lax.fori_loop(
                 0, max_layers, layer_loop,
-                (bb_len, jnp.zeros(w_shape, jnp.int32), jnp.int32(0)))
+                (bb_len, jnp.zeros(w_shape, jnp.int32)))
 
-        # ================= consensus =====================================
-        # (parity: rt_poa.cpp generate_consensus — heaviest bundle)
-        score[...] = jnp.zeros(n_shape, jnp.int32)
-        spred[...] = jnp.full(n_shape, -1, jnp.int32)
-        n_max = jnp.max(n)
-        delta_f = [rk_delta[e] for e in range(E)]
-        ew_f = [rk_ew[e] for e in range(E)]
+        with jax.named_scope(R_CONSENSUS):
+            # ================= consensus =====================================
+            # (parity: rt_poa.cpp generate_consensus — heaviest bundle)
+            score[...] = jnp.zeros(n_shape, jnp.int32)
+            spred[...] = jnp.full(n_shape, -1, jnp.int32)
+            n_max = jnp.max(n)
+            delta_f = [rk_delta[e] for e in range(E)]
+            ew_f = [rk_ew[e] for e in range(E)]
 
-        def score_body(r, c):
-            best_r, best_s = c
-            act = r < n
-            cnt_r = exr(rk_cnt, r)
-            bw = jnp.full(w_shape, NEG, jnp.int32)
-            bs = jnp.full(w_shape, NEG, jnp.int32)
-            bp = jnp.full(w_shape, -1, jnp.int32)
-            for e in range(E):
-                d_e = exr(rk_delta.at[e], r)
-                w_e = exr(rk_ew.at[e], r)
-                valid = (d_e > 0) & (e < cnt_r)
-                s_e = ex_v(score[...], jnp.clip(r - d_e, 0, N - 1))
-                better = valid & ((w_e > bw) | ((w_e == bw) & (s_e > bs)))
-                bw = jnp.where(better, w_e, bw)
-                bs = jnp.where(better, s_e, bs)
-                bp = jnp.where(better, r - d_e, bp)
-            s = jnp.where(bp >= 0, bw + bs, 0)
-            rmw(score, r, s, act)
-            rmw(spred, r, bp, act)
-            better = act & (s > best_s)
-            return (jnp.where(better, r, best_r),
-                    jnp.where(better, s, best_s))
+            def score_body(r, c):
+                best_r, best_s = c
+                act = r < n
+                cnt_r = exr(rk_cnt, r)
+                bw = jnp.full(w_shape, NEG, jnp.int32)
+                bs = jnp.full(w_shape, NEG, jnp.int32)
+                bp = jnp.full(w_shape, -1, jnp.int32)
+                for e in range(E):
+                    d_e = exr(rk_delta.at[e], r)
+                    w_e = exr(rk_ew.at[e], r)
+                    valid = (d_e > 0) & (e < cnt_r)
+                    s_e = ex_v(score[...], jnp.clip(r - d_e, 0, N - 1))
+                    better = valid & ((w_e > bw) | ((w_e == bw) & (s_e > bs)))
+                    bw = jnp.where(better, w_e, bw)
+                    bs = jnp.where(better, s_e, bs)
+                    bp = jnp.where(better, r - d_e, bp)
+                s = jnp.where(bp >= 0, bw + bs, 0)
+                rmw(score, r, s, act)
+                rmw(spred, r, bp, act)
+                better = act & (s > best_s)
+                return (jnp.where(better, r, best_r),
+                        jnp.where(better, s, best_s))
 
-        summit, _ = jax.lax.fori_loop(
-            0, n_max, score_body,
-            (jnp.zeros(w_shape, jnp.int32),
-             jnp.full(w_shape, NEG, jnp.int32)))
+            summit, _ = jax.lax.fori_loop(
+                0, n_max, score_body,
+                (jnp.zeros(w_shape, jnp.int32),
+                 jnp.full(w_shape, NEG, jnp.int32)))
 
-        # backward walk to a source (ranks into revbuf)
-        def bcond(c):
-            u, cnt = c
-            return jnp.any((u >= 0) & (cnt < N))
+            # backward walk to a source (ranks into revbuf)
+            def bcond(c):
+                u, cnt = c
+                return jnp.any((u >= 0) & (cnt < N))
 
-        def bbody(c):
-            u, cnt = c
-            act = (u >= 0) & (cnt < N)
-            rmw_v(revbuf, cnt, u, act)
-            pu = ex_v(spred[...], jnp.maximum(u, 0))
-            return (jnp.where(act, pu, u),
-                    cnt + jnp.where(act, 1, 0))
+            def bbody(c):
+                u, cnt = c
+                act = (u >= 0) & (cnt < N)
+                rmw_v(revbuf, cnt, u, act)
+                pu = ex_v(spred[...], jnp.maximum(u, 0))
+                return (jnp.where(act, pu, u),
+                        cnt + jnp.where(act, 1, 0))
 
-        _, cnt_b = jax.lax.while_loop(
-            bcond, bbody, (summit, jnp.zeros(w_shape, jnp.int32)))
+            _, cnt_b = jax.lax.while_loop(
+                bcond, bbody, (summit, jnp.zeros(w_shape, jnp.int32)))
 
-        cons_base_ref[0] = jnp.full(n_shape, -1, jnp.int32)
-        cons_cov_ref[0] = jnp.zeros(n_shape, jnp.int32)
-        base_f = rk_base[...]
-        cov_f = rk_cov[...]
+            cons_base_ref[0] = jnp.full(n_shape, -1, jnp.int32)
+            cons_cov_ref[0] = jnp.zeros(n_shape, jnp.int32)
+            base_f = rk_base[...]
+            cov_f = rk_cov[...]
 
-        def emit(i, u, act):
-            bv = ex_v(base_f, u)
-            cv = ex_v(cov_f, u)
-            m = (rr == i) & act
-            cons_base_ref[0] = jnp.where(m, bv, cons_base_ref[0])
-            cons_cov_ref[0] = jnp.where(m, cv, cons_cov_ref[0])
+            def emit(i, u, act):
+                bv = ex_v(base_f, u)
+                cv = ex_v(cov_f, u)
+                m = (rr == i) & act
+                cons_base_ref[0] = jnp.where(m, bv, cons_base_ref[0])
+                cons_cov_ref[0] = jnp.where(m, cv, cons_cov_ref[0])
 
-        def flip_body(i, _):
-            act = i < cnt_b
-            u = ex_v(revbuf[...], jnp.clip(cnt_b - 1 - i, 0, N - 1))
-            emit(i, jnp.clip(u, 0, N - 1), act)
-            return 0
+            def flip_body(i, _):
+                act = i < cnt_b
+                u = ex_v(revbuf[...], jnp.clip(cnt_b - 1 - i, 0, N - 1))
+                emit(i, jnp.clip(u, 0, N - 1), act)
+                return 0
 
-        jax.lax.fori_loop(0, jnp.max(cnt_b), flip_body, 0)
+            jax.lax.fori_loop(0, jnp.max(cnt_b), flip_body, 0)
 
-        # forward walk to a sink along heaviest out-edges
-        def fcond(c):
-            u, cnt, more = c
-            return jnp.any(more > 0)
+            # forward walk to a sink along heaviest out-edges
+            def fcond(c):
+                u, cnt, more = c
+                return jnp.any(more > 0)
 
-        def fbody(c):
-            u, cnt, more = c
-            ew = jnp.full(n_shape, NEG, jnp.int32)
-            for e in range(E):
-                m = ((delta_f[e] > 0) & (delta_f[e] == rr - u) &
-                     (rr < n))
-                ew = jnp.maximum(ew, jnp.where(m, ew_f[e], NEG))
-            w_top = wmax(ew)
-            any_out = (more > 0) & (w_top > NEG)
-            cand_s = jnp.where(ew == w_top, score[...], NEG)
-            smax = wmax(cand_s)
-            v = wmin(jnp.where(cand_s == smax, rr, N))
-            emit(cnt, jnp.clip(v, 0, N - 1), any_out)
-            return (jnp.where(any_out, v, u),
-                    cnt + jnp.where(any_out, 1, 0),
-                    jnp.where(any_out, 1, 0))
+            def fbody(c):
+                u, cnt, more = c
+                ew = jnp.full(n_shape, NEG, jnp.int32)
+                for e in range(E):
+                    m = ((delta_f[e] > 0) & (delta_f[e] == rr - u) &
+                         (rr < n))
+                    ew = jnp.maximum(ew, jnp.where(m, ew_f[e], NEG))
+                w_top = wmax(ew)
+                any_out = (more > 0) & (w_top > NEG)
+                cand_s = jnp.where(ew == w_top, score[...], NEG)
+                smax = wmax(cand_s)
+                v = wmin(jnp.where(cand_s == smax, rr, N))
+                emit(cnt, jnp.clip(v, 0, N - 1), any_out)
+                return (jnp.where(any_out, v, u),
+                        cnt + jnp.where(any_out, 1, 0),
+                        jnp.where(any_out, 1, 0))
 
-        _, cnt_f, _ = jax.lax.while_loop(
-            fcond, fbody,
-            (summit, cnt_b,
-             jnp.ones(w_shape, jnp.int32)))
+            _, cnt_f, _ = jax.lax.while_loop(
+                fcond, fbody,
+                (summit, cnt_b,
+                 jnp.ones(w_shape, jnp.int32)))
 
         for i in range(W):
             cl_s[0, 0, i] = scalar_of(cnt_f, i)
@@ -977,9 +1022,6 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             nn_s[0, 0, i] = scalar_of(n, i)
             if band:
                 bh_s[0, 0, i] = jnp.where(scalar_of(hit, i) > 0, 1, 0)
-        # in-edge slots the program's node-insertion blocks were bounded
-        # to, summed over its groups and layers (E a group a layer: all)
-        sw_s[0, 0, 0] = swept
 
     def make(batch: int):
         assert batch % W == 0, (batch, U, G)
@@ -1002,8 +1044,8 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             return pltpu.VMEM(lead + (U, JC, G, 128), dtype)
 
         gshape = jax.ShapeDtypeStruct((nb, 1, W), jnp.int32)
-        # one scalar a program
-        smem1 = pl.BlockSpec((1, 1, 1), lambda b: (b, 0, 0),
+        n_counts = len(PROGRAM_COUNTS)
+        smem1 = pl.BlockSpec((1, 1, n_counts), lambda b: (b, 0, 0),
                              memory_space=pltpu.SMEM)
         return pl.pallas_call(
             kernel,
@@ -1017,7 +1059,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 jax.ShapeDtypeStruct((nb, U, NC, G, 128), jnp.int32),
                 gshape, gshape, gshape,
             ] + ([gshape] if band else []) + [
-                jax.ShapeDtypeStruct((nb, 1, 1), jnp.int32),
+                jax.ShapeDtypeStruct((nb, 1, n_counts), jnp.int32),
                 jax.ShapeDtypeStruct((nb, N, U, JC, G, 128), jnp.int32),
             ],
             scratch_shapes=[
@@ -1082,8 +1124,8 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                    nn.reshape(batch, 1))
             if band:
                 res = res + (outs[5].reshape(batch, 1),)
-            # last: the in-edge slots each program's insertions swept
-            return res + (outs[-2].reshape(nb),)
+            # last: each program's PROGRAM_COUNTS
+            return res + (outs[-2].reshape(nb, -1),)
 
         return Program(fn, key=("racon_poa_ls", cfg, interpret, band, U,
                                 batch))

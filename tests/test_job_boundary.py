@@ -468,8 +468,12 @@ def test_armed_and_disarmed_leave_the_same_bytes(tiny, tmp_path,
     for name, rep in plain_rep["phases"].items():
         for key in ("total", "served"):
             assert armed_rep["phases"][name][key] == rep[key], (name, key)
-    counters = armed_rep["obs"]["metrics"]["counters"]
-    assert counters == counted_rep["obs"]["metrics"]["counters"]
+    counters, counted = (dict(rep["obs"]["metrics"]["counters"])
+                         for rep in (armed_rep, counted_rep))
+    # the process's peak so far, not the job's: it can grow from one run
+    # of a process to the next
+    assert counters.pop("job.rss.peak_mb") <= counted.pop("job.rss.peak_mb")
+    assert counters == counted
     assert counters["polish.targets"] == 1 and counters["overlaps.kept"] > 0
     # the armed run's own file: a bare polisher leaves the root open,
     # and the file holds it up to the write

@@ -78,14 +78,27 @@ interp = pltpu.InterpretParams(dma_execution_mode="on_wait",
                                uninitialized_memory="nan")
 ls = poa_pallas_ls.build_lockstep_poa_kernel(cfg, interpret=interp,
                                              groups=U)(B)
-cb, cc, cl, fl, nn, swept = (np.asarray(x) for x in ls(
+cb, cc, cl, fl, nn, counts = (np.asarray(x) for x in ls(
     bb_len[:, None], nl[:, None], lens, bg, en, bb.astype(np.int32), bbw,
     seqs.astype(np.int32), ws))
 # perfect reads: the chain's one in-edge plus one, a group a layer; the
 # inserted node's successor holds two in-edges from the second layer on,
 # one slot more twice (the scalar is written under uninitialised-memory
 # NaNs like everything else)
-assert swept.tolist() == [2 * U * 3, 2 * U * 3 + 2], swept
+got = dict(zip(poa_pallas_ls.PROGRAM_COUNTS, counts.T.tolist()))
+swept = got.pop("slots_swept")
+assert swept == [2 * U * 3, 2 * U * 3 + 2], swept
+# the kernel's own step counts accumulate in that SMEM output, which
+# starts as garbage here: three layers a program; the first program's
+# DP runs ranks 0 .. 127, the second's 64 .. 199 (a pair a trip, from
+# the chunk its layers start in; 201 ranks once the node is in) and
+# its traceback walks down from block 3; a read's length of update
+# steps a layer; one insertion, in one block of slots
+assert got == {"steps.dp": [3 * 128, 136 + 2 * 138],
+               "steps.traceback": [3 * 2 * BLK, 3 * 4 * BLK],
+               "steps.update": [3 * 128, 3 * 101],
+               "insert.firings": [0, 1],
+               "insert.shift_steps": [0, 1]}, got
 jb, jc, jl, jf, jn = (np.asarray(x) for x in poa.build_poa_kernel(cfg)(
     bb, bbw, bb_len, nl, seqs, ws, lens, bg, en))
 assert not fl.any() and not jf.any(), (fl.ravel(), jf.ravel())

@@ -77,9 +77,11 @@ GROUPS = pytest.mark.parametrize("groups", [1, 2, 4],
                                  ids=["u1", "u2", "u4"])
 
 
-def _run_ls(a, cfg, groups=1, swept=False):
+def _run_ls(a, cfg, groups=1, swept=False, counts=False):
     """The kernel's five per-window outputs; with `swept`, beside them
-    the in-edge slots each program's node insertions swept."""
+    the in-edge slots each program's node insertions swept (slot 0 of
+    its last output); with `counts`, every slot of it, a row a program
+    (poa_pallas_ls.PROGRAM_COUNTS)."""
     B = len(a["bb"])
     ls_fn = poa_pallas_ls.build_lockstep_poa_kernel(
         cfg, interpret=True, groups=groups)(B)
@@ -87,8 +89,12 @@ def _run_ls(a, cfg, groups=1, swept=False):
         a["bb_len"][:, None], a["nl"][:, None], a["lens"], a["bg"],
         a["en"], a["bb"].astype(np.int32), a["bbw"],
         a["seqs"].astype(np.int32), a["ws"]))
-    assert outs[5].shape == (B // (8 * groups),)
-    return (outs[:5], outs[5]) if swept else outs[:5]
+    assert outs[5].shape == (B // (8 * groups),
+                             len(poa_pallas_ls.PROGRAM_COUNTS))
+    assert poa_pallas_ls.PROGRAM_COUNTS[0] == "slots_swept"
+    if counts:
+        return outs[:5], outs[5]
+    return (outs[:5], outs[5][:, 0]) if swept else outs[:5]
 
 
 def _deal(a, cfg, groups):
@@ -189,16 +195,9 @@ def test_lockstep_matches_host_and_jax(groups):
                                       err_msg=f"window {b} coverage")
 
 
-@GROUPS
-@pytest.mark.parametrize("seed", [101, 202, 303, 404])
-def test_lockstep_differential_fuzz(seed, groups):
-    """Seeded random windows — lengths, depths, mutation rates, partial
-    spans, per-base layer weights AND backbone weights (the product
-    exports PHRED-33 backbone weights, dummy '!' = 0 when the target has
-    no quality; rt_capi.cpp rt_pipeline_window_export) — asserted
-    lockstep == XLA twin == host oracle.  Seed 404 runs windows of
-    260-330 bases at four layers on WIDE_CFG (graphs of 300-450 nodes),
-    the others 40-110 on CFG."""
+def _fuzz_batch(seed):
+    """test_lockstep_differential_fuzz's eight windows of a seed: the
+    batch, its geometry, and each window's own inputs."""
     rng = random.Random(seed)
     B = 8
     cfg, lengths = ((WIDE_CFG._replace(depth=4), (260, 330)) if seed == 404
@@ -228,6 +227,21 @@ def test_lockstep_differential_fuzz(seed, groups):
                     ends=ends)
         a["bbw"][b, :len(backbone)] = bq
         cases[b] = (backbone, layers, w, bq, begins, ends)
+    return a, cfg, cases
+
+
+@GROUPS
+@pytest.mark.parametrize("seed", [101, 202, 303, 404])
+def test_lockstep_differential_fuzz(seed, groups):
+    """Seeded random windows — lengths, depths, mutation rates, partial
+    spans, per-base layer weights AND backbone weights (the product
+    exports PHRED-33 backbone weights, dummy '!' = 0 when the target has
+    no quality; rt_capi.cpp rt_pipeline_window_export) — asserted
+    lockstep == XLA twin == host oracle.  Seed 404 runs windows of
+    260-330 bases at four layers on WIDE_CFG (graphs of 300-450 nodes),
+    the others 40-110 on CFG."""
+    a, cfg, cases = _fuzz_batch(seed)
+    B = len(cases)
 
     (cb, cc, cl, fl, nn), (jb, jc, jl, jf, jn) = _run_both(a, cfg, B,
                                                            groups)
@@ -704,6 +718,115 @@ def test_xla_twin_counts_no_insert_slots(tmp_path, monkeypatch):
         obs.reset()
     assert phase["served"]["xla"] == 3 and counters["poa.launches"] >= 1
     assert not [k for k in counters if k.startswith("poa.insert.")]
+
+
+# -- poa.ls.*: the trips of the kernel's own loops ---------------------------
+
+def _check_step_counts(a, cfg, groups, nn, counts):
+    """A program's STEP_COUNTERS against what its windows' inputs and the
+    node counts it returned say alone, over the layers of its deepest
+    window (the driver's _program_layers, which counts poa.ls.layers):
+    a layer's longest admitted read of update steps; a group's node
+    insertions fire once a step in which any of its eight windows
+    inserts a node (a window inserts at most one a step), so at least
+    what its busiest window inserted and at most what all eight did,
+    each firing running one to E / SLOT_BLOCK blocks of slots; a pair
+    of rank slots a trip of the DP loop, under the program's largest
+    graph a layer; whole blocks of BLK traceback ranks."""
+    W = 8 * groups
+    blocks = -(-cfg.max_edges // poa_pallas_ls.SLOT_BLOCK)
+    for p, row in enumerate(counts.tolist()):
+        got = dict(zip(poa_pallas_ls.PROGRAM_COUNTS, row))
+        rows = slice(p * W, (p + 1) * W)
+        nl = a["nl"][rows]
+        layers = poa_driver._program_layers(nl, W)
+        assert layers == int(nl.max())
+        live = np.arange(cfg.depth)[None, :] < nl[:, None]
+        inserted = (nn[rows, 0] - a["bb_len"][rows]).reshape(groups, 8)
+        assert got["steps.update"] == int(
+            np.where(live, a["lens"][rows], 0).max(axis=0).sum()), p
+        assert (inserted.max(axis=1).sum() <= got["insert.firings"]
+                <= inserted.sum()), (p, got, inserted)
+        assert (got["insert.firings"] <= got["insert.shift_steps"]
+                <= blocks * got["insert.firings"]), (p, got)
+        most = int(nn[rows, 0].max())
+        assert got["steps.dp"] % 2 == 0 and (
+            2 * layers <= got["steps.dp"] <= layers * (most + 1)), (p, got)
+        assert got["steps.traceback"] % poa_pallas_ls.BLK == 0 and (
+            layers <= got["steps.traceback"] // poa_pallas_ls.BLK
+            <= layers * -(-most // poa_pallas_ls.BLK)), (p, got)
+
+
+@GROUPS
+@pytest.mark.parametrize("seed", [101, 202, 303, 404])
+def test_step_counters_against_plain_counts_on_the_fuzz(seed, groups):
+    """The differential fuzz's windows, dealt over the sublane groups of
+    one program (pad windows fill the other slots): the kernel's last
+    output counts its own loops as the inputs and outputs say it must."""
+    a, cfg, _ = _fuzz_batch(seed)
+    wide, _ = _deal(a, cfg, groups)
+    (cb, cc, cl, fl, nn), counts = _run_ls(wide, cfg, groups, counts=True)
+    assert not fl.any()
+    assert counts[:, 1:].all(), "noisy reads: every loop ran"
+    _check_step_counts(wide, cfg, groups, nn, counts)
+
+
+@pytest.mark.parametrize("groups", [4, 1], ids=["u4", "u1"])
+def test_step_counters_with_a_pad_group_and_a_pad_program(groups):
+    """A group of pad rows beside a deep one counts nothing of its own
+    (the program's layers are the deep group's), and as a program of
+    eight by itself it counts nothing at all; the group of perfect reads
+    fires no insertion."""
+    a, _ = _pad_beside_deep()
+    (cb, cc, cl, fl, nn), counts = _run_ls(a, EDGE_CFG, groups, counts=True)
+    assert not fl.any()
+    _check_step_counts(a, EDGE_CFG, groups, nn, counts)
+    if groups == 1:
+        assert not counts[3].any(), "the pad program ran no layer"
+        firings = counts[:, poa_pallas_ls.PROGRAM_COUNTS.index(
+            "insert.firings")]
+        assert firings[1] == 0 and firings[0] > 0 and firings[2] > 0
+    else:
+        assert poa_driver._program_layers(a["nl"], 8 * groups) == E + 1
+
+
+@pytest.mark.parametrize("pallas,insert_at,want", [
+    ("1", None, {"layers": 4, "steps.update": 4 * 100,
+                 "insert.firings": 0, "insert.shift_steps": 0}),
+    ("1", 130, {"layers": 4, "steps.update": 4 * 101,
+                "insert.firings": 1, "insert.shift_steps": 1}),
+    ("0", None, None),
+], ids=["ls", "ls-one-insertion", "xla"])
+def test_install_counts_every_step_counter_or_none(tmp_path, monkeypatch,
+                                                   pallas, insert_at, want):
+    """Through the consensus driver: an installed lockstep launch counts
+    all six poa.ls.* keys, a zero too (reads equal to the backbone fire
+    no insertion; one base the target lacks fires one, in one block of
+    slots), beside poa.insert.slots.*; the XLA twin counts none."""
+    from racon_tpu import obs
+
+    _perfect_reads_dataset(tmp_path, insert_at=insert_at)
+    monkeypatch.setenv("RACON_TPU_PALLAS", pallas)
+    monkeypatch.setenv("RACON_TPU_SHARD", "0")
+    monkeypatch.setenv("RACON_TPU_BATCH_WINDOWS", "8")
+    monkeypatch.setenv("RACON_TPU_METRICS", "1")
+    try:
+        _, phase = _polish_perfect_reads(tmp_path)
+        counters = obs.snapshot()["counters"]
+    finally:
+        obs.reset()
+    got = {k[len("poa.ls."):]: v for k, v in counters.items()
+           if k.startswith("poa.ls.")}
+    if want is None:
+        assert phase["served"]["xla"] == 3 and got == {}
+        return
+    assert phase["served"]["ls"] == 3 and counters["poa.launches"] == 1
+    assert sorted(got) == sorted(("layers",) + poa_pallas_ls.STEP_COUNTERS)
+    # three windows of 100 bases in one program of eight: a backbone of
+    # ranks in one block, walked down from its top a layer
+    assert got.pop("steps.traceback") == 4 * 2 * poa_pallas_ls.BLK
+    assert 4 * 100 <= got.pop("steps.dp") <= 4 * 102
+    assert got == want
 
 
 # -- node insertions at the ranks and chunk boundaries a shift can miss ------

@@ -344,8 +344,9 @@ def test_driver_picks_the_width_launch_by_launch(tmp_path, monkeypatch,
 
 
 def test_obs_report_lists_the_program_counters():
-    """`python -m racon_tpu.obs <trace>` lists the four counters beside
-    the mesh counters of alignment."""
+    """`python -m racon_tpu.obs <trace>` lists the four counters, and
+    the kernel's own step counts (poa.ls.*), beside the mesh counters of
+    alignment."""
     from racon_tpu.obs import __main__ as obs_cli
 
     counters = {"poa.programs.wide": 72, "poa.programs.narrow": 0,
@@ -355,6 +356,11 @@ def test_obs_report_lists_the_program_counters():
                 "poa.width.windows.u4": 960,
                 "poa.mesh.rows.real": 1000, "poa.mesh.fullest.slots": 1004,
                 "poa.insert.slots.swept": 9000, "poa.insert.slots.all": 21600,
+                "poa.ls.layers": 1800, "poa.ls.steps.dp": 1_400_000,
+                "poa.ls.steps.traceback": 1_500_000,
+                "poa.ls.steps.update": 900_000,
+                "poa.ls.insert.firings": 1_500_000,
+                "poa.ls.insert.shift_steps": 3_000_000,
                 "align.mesh.launches.single": 380, "poa.launches": 18}
     text = obs_cli.render(
         {"traceEvents": [], "racon_tpu": {"metrics": {"counters": counters}}},
@@ -365,7 +371,7 @@ def test_obs_report_lists_the_program_counters():
     for name in counters:
         assert (name in section) == name.startswith(
             ("poa.programs.", "poa.lockstep.", "poa.width.", "poa.mesh.",
-             "poa.insert."))
+             "poa.insert.", "poa.ls."))
 
 
 # -- the shape of a launch on a mesh (PR 45) --------------------------------

@@ -213,6 +213,46 @@ def test_lowered_kernel_carries_its_stable_name(single_device, name, build):
     assert "stablehlo.custom_call @tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("build", [
+    lambda: _ls(500, 32, TPU_BATCH),
+    lambda: _ls(500, 200, SHARD_BATCH, rung=1),
+    lambda: _ls(500, 32, SHARD_BATCH, band=True),
+], ids=["u4", "u2-upper-rung", "u2-banded"])
+def test_lockstep_kernel_marks_its_phases_as_flat_regions(monkeypatch,
+                                                          build):
+    """The Mosaic module Pallas hands the TPU compiler holds one
+    ``tpu.trace_start`` for each of poa_pallas_ls.REGIONS, in the
+    kernel's order, each stopped before the next starts (none around a
+    layer, none nested): the five of a layer side by side in the layer
+    loop's body, one loop level under the consensus walk's, so none
+    inside the rank, traceback, update or slot loops, whose bodies lie
+    deeper still."""
+    from jax._src.pallas.mosaic import lowering
+
+    modules, real = [], lowering.lower_jaxpr_to_module
+
+    def spy(*a, **kw):
+        module = real(*a, **kw)
+        modules.append(str(module))
+        return module
+
+    monkeypatch.setattr(lowering, "lower_jaxpr_to_module", spy)
+    jax.clear_caches()       # a geometry lowered before is not lowered again
+    _export_tpu(*build())
+    assert len(modules) == 1
+    marks = [(len(line) - len(line.lstrip()),
+              re.search(r'message = "([^"]*)"', line))
+             for line in modules[0].splitlines()
+             if "tpu.trace_start" in line or "tpu.trace_stop" in line]
+    names = [m.group(1) if m else None for _, m in marks]
+    assert names[0::2] == list(poa_pallas_ls.REGIONS)
+    assert names[1::2] == [None] * len(poa_pallas_ls.REGIONS)
+    depths = [d for d, _ in marks]
+    in_layer, in_program = depths[:10], depths[10:]
+    assert len(set(in_layer)) == 1 and len(set(in_program)) == 1
+    assert in_layer[0] == in_program[0] + 2    # one region level of MLIR
+
+
 # -- Mosaic compile --------------------------------------------------------
 
 @pytest.mark.parametrize("depth", poa_driver.DEPTH_BUCKETS)
