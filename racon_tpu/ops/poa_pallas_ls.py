@@ -4,9 +4,17 @@ Same window-consensus semantics as the host oracle (rt_poa.cpp) and the
 XLA twin (poa.py), laid out for VPU throughput. A window's DP is a
 serial chain of dependent vector operations (per rank: a vector-to-scalar
 turn, a dynamic loop of ring-row loads, a shift, ten dependent lane
-rolls, a masked reduce, a read-modify-write; ~700-1000 cycles a rank on
-the v5e whether a row holds 4 vregs or 7), so one window per program
-leaves the VPU idle. One grid program therefore runs U x 8 windows in
+rolls, a masked reduce, a read-modify-write: ~1000-1300 cycles of
+latency a rank on the v5e at one sublane group and at two, whatever a
+row holds up to 28 vregs), so one window per program leaves the VPU
+idle, and the chain is filled by widening the program until it issues as
+many bundles as it waits cycles.  At four groups it does: a DP rank of
+class 512 is ~1640 scheduled bundles and a traceback rank ~1050 (counted
+from the final bundles of a compile for a described v5e,
+tools/kernel_bundles.py --kernel ls), a job's kernel cycles are its
+loops' bundles times their trips (PERF.md section 7), and a bundle
+taken out of a rank loop is a cycle taken out.  One grid program
+therefore runs U x 8 windows in
 lock-step under ONE control flow: one window per sublane, U sublane
 groups (U = `groups`: 4, 2 or 1; the driver derives it launch by launch
 from the per-shard batch, the VMEM sum and the rows the launch really
@@ -87,11 +95,99 @@ SLOT_BLOCK = 4   # in-edge slots a step of the node-insertion loop shifts
 #: What a grid program counts of its own loops, summed over its layers:
 #: the trips of each (poa.ls.<name> once poa_driver installs them, beside
 #: poa.ls.layers, the layers themselves, which the host knows) and,
-#: first, the in-edge slots its node insertions were bounded to.  The
-#: kernel's last output, a slot each, in PROGRAM_COUNTS' order.
+#: first, the in-edge slots its node insertions were bounded to; the last
+#: two are the trips of the scans inside a rank step (the DP's delta_scan,
+#: the traceback's mscan).  The kernel's last output, a slot each, in
+#: PROGRAM_COUNTS' order.
 STEP_COUNTERS = ("steps.dp", "steps.traceback", "steps.update",
-                 "insert.firings", "insert.shift_steps")
+                 "insert.firings", "insert.shift_steps",
+                 "steps.dp_scan", "steps.tb_scan")
 PROGRAM_COUNTS = ("slots_swept",) + STEP_COUNTERS
+
+#: A per-window scalar comes out of a row as a masked sum over lanes, and
+#: the v5e's cross-lane add is a float one: an int32 sum lowers to two of
+#: them, one a 16-bit half (mask, split, two converts, two vadd.xlane, two
+#: vpop, two converts back, shift, add), a sum whose one nonzero term lies
+#: inside (-2**24, 2**24) to one, exactly.  So what a rank step reads at
+#: one index is packed into words of NARROW_BITS before the reduction.
+NARROW_BITS = 24
+#: The in-edge record: a rank's E distances, REC_BITS each, REC_SLOTS a
+#: word, in slot order.  A distance past REC_MAX reads REC_MAX, which is
+#: past DMAX too, so what is valid stays what it was.
+REC_BITS = 8
+REC_SLOTS = NARROW_BITS // REC_BITS
+REC_MAX = (1 << REC_BITS) - 1
+assert DMAX < REC_MAX
+
+
+def record_words(E: int) -> int:
+    return -(-E // REC_SLOTS)
+
+
+def pack_record(deltas):
+    """E arrays of distances (>= 0) -> record_words(E) arrays."""
+    words = []
+    for at in range(0, len(deltas), REC_SLOTS):
+        word = jnp.minimum(deltas[at], REC_MAX)
+        for k, d in enumerate(deltas[at + 1:at + REC_SLOTS], 1):
+            word = word | (jnp.minimum(d, REC_MAX) << (REC_BITS * k))
+        words.append(word)
+    return words
+
+
+def unpack_record(words, E: int):
+    """-> the E distances of pack_record's words, each capped at REC_MAX."""
+    return [(words[e // REC_SLOTS] >> (REC_BITS * (e % REC_SLOTS))) & REC_MAX
+            for e in range(E)]
+
+
+def move_bits(E: int) -> int:
+    """Bits of one move's code: a packed slot * 256 + distance of the
+    traceback's minima, or one of the two codes past every one of them."""
+    return max(12, (E * 256 + 1).bit_length())
+
+
+def pack_moves(wdiag, wup, vdiag, vup, bits: int):
+    """The traceback's two moves at a cell as one word, diag above up: a
+    move's packed predecessor where a row explains the cell (w < WNONE),
+    else all ones where the virtual row does (v: it needs a rank with no
+    valid in-edge, so it excludes the first), else all ones but the last
+    bit: no move."""
+    virtual = (1 << bits) - 1
+    cd = jnp.minimum(wdiag, jnp.where(vdiag, virtual, virtual - 1))
+    cu = jnp.minimum(wup, jnp.where(vup, virtual, virtual - 1))
+    return (cd << bits) | cu
+
+
+def no_move(bits: int) -> int:
+    """pack_moves' word of a cell neither move explains."""
+    none = (1 << bits) - 2
+    return (none << bits) | none
+
+
+def unpack_moves(word, bits: int):
+    """-> (diag_ok, wd, wd_virt, wu, wu_virt): whether the diagonal move
+    explains the cell, its packed predecessor (0 without one), whether
+    it has none (the move goes to the virtual row), and the last two for
+    the move up."""
+    virtual = (1 << bits) - 1
+    cd = word >> bits
+    cu = word & virtual
+    wd_virt = cd >= virtual - 1
+    wu_virt = cu >= virtual - 1
+    return (cd != virtual - 1, jnp.where(wd_virt, 0, cd), wd_virt,
+            jnp.where(wu_virt, 0, cu), wu_virt)
+
+
+def lane_sum(x, narrow: bool = False):
+    """Sum over the last axis, kept.  `narrow`: the caller's terms are
+    int32 whose every partial sum lies inside (-2**24, 2**24) (one
+    nonzero term that does, or a count under it): one float reduction,
+    exact."""
+    if narrow:
+        return jnp.sum(x.astype(jnp.float32), axis=-1,
+                       keepdims=True).astype(jnp.int32)
+    return jnp.sum(x, axis=-1, keepdims=True)
 
 
 def _round_up(x, m):
@@ -111,7 +207,8 @@ def scratch_bytes(cfg: PoaConfig, groups: int = 1) -> int:
     lane_bytes = groups * G * 128 * 4
     ring = RING * JC * lane_bytes
     j_rows = (1 + 2 + 2 * 2) * JC * lane_bytes   # H0, nkey/runrem, scr
-    n_rows = (9 + 2 * cfg.max_edges) * NC * lane_bytes
+    aux = max(3, record_words(cfg.max_edges))     # at E = 12, 4 rows
+    n_rows = (5 + aux + 2 * cfg.max_edges) * NC * lane_bytes
     io = 4 * NC * lane_bytes                      # bb/bbw in, cons out
     return ring + j_rows + n_rows + io
 
@@ -151,11 +248,13 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
     BB = cfg.max_backbone
     E = cfg.max_edges
     D = cfg.depth
-    assert N % 128 == 0 and BB <= N
+    assert N % 128 == 0 and BB <= N < 1 << NARROW_BITS
     NC = N // 128                       # node/rank lane-chunks
     SB = math.gcd(E, SLOT_BLOCK)        # slots a step: whole blocks in E
     JL = _round_up(L + 1, 128)
     JC = JL // 128                      # j lane-chunks
+    NW = record_words(E)                # words of a rank's in-edge record
+    MB = move_bits(E)                   # bits of a traceback move's code
     M = int(cfg.match)
     X = int(cfg.mismatch)
     GP = int(cfg.gap)
@@ -173,16 +272,20 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
              cons_base_ref, cons_cov_ref, cl_s, fl_s, nn_s, bh_s, sw_s,
              hbm_H,
              Hring, H0, rk_base, rk_key, rk_cov, rk_cnt, rk_delta, rk_ew,
-             rk_dmax, esc, score, spred, revbuf, nkey, runrem,
+             esc, aux, nkey, runrem,
              seq_scr, w_scr, dma_sem, flush_sem, tb_sem) = refs
         else:
             (bb_len_s, n_layers_s, lens_s, begins_s, ends_s,
              bb_ref, bbw_ref, seqs_hbm, ws_hbm,
              cons_base_ref, cons_cov_ref, cl_s, fl_s, nn_s, sw_s, hbm_H,
              Hring, H0, rk_base, rk_key, rk_cov, rk_cnt, rk_delta, rk_ew,
-             rk_dmax, esc, score, spred, revbuf, nkey, runrem,
+             esc, aux, nkey, runrem,
              seq_scr, w_scr, dma_sem, flush_sem, tb_sem) = refs
         b_prog = pl.program_id(0)
+        # one scratch, two lives: the layers' in-edge record, then the
+        # consensus walk's three arrays, each written before it is read
+        rec = [aux.at[w] for w in range(NW)]
+        score, spred, revbuf = aux.at[0], aux.at[1], aux.at[2]
 
         # index vectors carry a unit group axis and broadcast over U
         lane_n = jax.lax.broadcasted_iota(jnp.int32, (1, NC, G, 128), 3)
@@ -212,9 +315,10 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
         def lanes_of(x):
             return lane_n if x.shape[1] == NC else lane_j
 
-        def wsum(x):
-            """per-window reduction over chunks and lanes -> (u,1,G,1)."""
-            return jnp.sum(x, axis=(1, 3), keepdims=True)
+        def wsum(x, narrow=False):
+            """per-window reduction over chunks and lanes -> (u,1,G,1);
+            `narrow` as lane_sum's, the chunks added as integers first."""
+            return lane_sum(jnp.sum(x, axis=1, keepdims=True), narrow)
 
         def wmax(x):
             return jnp.max(x, axis=(1, 3), keepdims=True)
@@ -225,14 +329,17 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
         def wany(x):
             return jnp.any(x, axis=(1, 3), keepdims=True)
 
-        def _lane_extract(c, idx):
+        # `narrow` on an extract is chosen where it is called, by what
+        # the array can hold there: the value read lies in (-2**24,
+        # 2**24), so lane_sum reduces it once.  H values (NEG = -2**28
+        # plus a score) and the consensus scores keep the int form.
+        def _lane_extract(c, idx, narrow):
             """(U,1,G,128) rows -> (U,1,G,1) value at lane idx (masked
             sum)."""
             m = lane1 == (idx % 128)
-            return jnp.sum(jnp.where(m, c, jnp.zeros_like(c)), axis=3,
-                           keepdims=True)
+            return lane_sum(jnp.where(m, c, jnp.zeros_like(c)), narrow)
 
-        def exr(ref, r):
+        def exr(ref, r, narrow=False):
             """ref (U,C,G,128) at global index r (shared scalar) ->
             (U,1,G,1).
 
@@ -241,17 +348,19 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             cross-lowering check; interpret mode accepts it silently).
             One (U,1,G,128) VMEM load + a lane mask, not an O(N) masked
             reduction over every chunk."""
-            return _lane_extract(ref[:, pl.ds(r // 128, 1)], r)
+            return _lane_extract(ref[:, pl.ds(r // 128, 1)], r, narrow)
 
         def exs(ref, slot, j):
-            """(2,U,JC,G,128) double-buffer ref at (slot, global j)."""
+            """(2,U,JC,G,128) double-buffer ref at (slot, global j): a
+            layer's bases (codes under 256) or weights (a quality, or a
+            unit: under 256 too), so narrow."""
             return _lane_extract(
-                ref[pl.ds(slot, 1), :, pl.ds(j // 128, 1)][0], j)
+                ref[pl.ds(slot, 1), :, pl.ds(j // 128, 1)][0], j, True)
 
-        def ex_v(val, rv):
+        def ex_v(val, rv, narrow=False):
             """val (U,C,G,128) at per-window indices rv (U,1,G,1)."""
             m = glob(val) == rv
-            return wsum(jnp.where(m, val, jnp.zeros_like(val)))
+            return wsum(jnp.where(m, val, jnp.zeros_like(val)), narrow)
 
         def rmw(ref, r, v, active):
             """ref value at shared scalar index r <- v where active."""
@@ -324,8 +433,8 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
 
         # PROGRAM_COUNTS accumulate in the SMEM output itself, each
         # bumped once a layer where its loop bound is already a scalar
-        # (the two of the node insertion once a firing), so no loop
-        # carries them
+        # (the two of the node insertion once a firing, the two of the
+        # rank steps' scans once a rank), so no loop carries them
         def bump(name, by):
             slot = PROGRAM_COUNTS.index(name)
             sw_s[0, 0, slot] = sw_s[0, 0, slot] + by
@@ -405,13 +514,15 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
 
                 seqv = seq_scr[pl.ds(slot, 1)][0]          # (U, JC, G, 128)
                 seqm1 = shift_right(seqv, 255)             # lane j: seq[j-1]
-                rk_dmax[...] = jnp.max(rk_delta[...], axis=0)
 
                 # layer-invariant snapshots (the graph does not change during
                 # DP + traceback; Mosaic keeps these as VMEM-backed values)
-                dmax_v = rk_dmax[...]
                 delta_v = [rk_delta[e] for e in range(E)]
                 H0v = H0[...]
+                # and the in-edge record the DP's and the traceback's rank
+                # steps read: NW words a rank where the slots are E
+                for w, word in enumerate(pack_record(delta_v)):
+                    rec[w][...] = word
 
                 # distance cap: an IN-SUBGRAPH edge beyond DMAX fails the
                 # window (its H row is evicted from the ring; the host path
@@ -426,23 +537,32 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
 
                 esc[...] = jnp.full(n_shape, NEG, jnp.int32)
 
+            def rank_record(r, act):
+                """-> (ds, d_top): rank r's in-edge distances in slot
+                order, 0 where the edge is not one of this layer's
+                subgraph (or the window not `act`), and the largest of
+                them.  A word holds REC_SLOTS distances of REC_BITS:
+                inside NARROW_BITS, so one reduction a word."""
+                words = [exr(rec[w], r, narrow=True) for w in range(NW)]
+                ds = []
+                for d_e in unpack_record(words, E):
+                    valid = ((d_e > 0) & (d_e <= DMAX) &
+                             (r - d_e >= r_lo) & act)
+                    ds.append(jnp.where(valid, d_e, 0))
+                return ds, functools.reduce(jnp.maximum, ds)
+
             with jax.named_scope(R_DP):
                 # ---- DP over ranks in lock-step -----------------------------
                 rs64 = (r_start // BLK) * BLK
 
                 def dp_body(r, _):
                     act = lact & (r >= r_lo) & (r < r_hi)
-                    dmax_r = jnp.minimum(jnp.max(exr(rk_dmax, r)), DMAX)
-                    dmax_r = jnp.minimum(dmax_r, r)
-                    ds = []
-                    for e in range(E):
-                        d_e = exr(rk_delta.at[e], r)
-                        valid = ((d_e > 0) & (d_e <= DMAX) &
-                                 (r - d_e >= r_lo) & act)
-                        ds.append(jnp.where(valid, d_e, 0))
-                    any_valid = ds[0] > 0
-                    for e in range(1, E):
-                        any_valid = any_valid | (ds[e] > 0)
+                    ds, d_top = rank_record(r, act)
+                    any_valid = d_top > 0
+                    # the scan's bound: the largest distance that is valid
+                    # here (so at most DMAX, and r - r_lo)
+                    dmax_r = jnp.max(d_top)
+                    bump("steps.dp_scan", dmax_r)
 
                     def delta_scan(d, P):
                         prow = Hring[pl.ds((r - d) % RING, 1)][0]
@@ -455,7 +575,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                     P = jax.lax.fori_loop(1, dmax_r + 1, delta_scan, P0)
                     P = jnp.where(any_valid, P, H0v)
 
-                    ub = exr(rk_base, r)
+                    ub = exr(rk_base, r, narrow=True)    # a code, or -1
                     scvec = jnp.where(seqm1 == ub, M, X)
                     diag = shift_right(P, NEG) + scvec
                     up = P + GP
@@ -524,6 +644,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 # rank r is an end node iff no in-subgraph node has an edge
                 # from it (one masked dynamic shift per distance serves every
                 # rank at once)
+                dmax_v = functools.reduce(jnp.maximum, delta_v)
                 dmax_all = jnp.minimum(
                     jnp.max(jnp.where(in_sub, dmax_v, 0)), DMAX)
 
@@ -590,18 +711,14 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                     cur, jcur, nk, run, done, failed = c[:6]
                     here = (done == 0) & (cur == r)
                     row = ring_row(r)
-                    ub = exr(rk_base, r)
+                    ub = exr(rk_base, r, narrow=True)    # a code, or -1
                     scv = jnp.where(seqm1 == ub, M, X)
-                    ds = []
-                    for e in range(E):
-                        d_e = exr(rk_delta.at[e], r)
-                        valid = (d_e > 0) & (d_e <= DMAX) & (r - d_e >= r_lo)
-                        ds.append(jnp.where(valid, d_e, 0))
-                    any_v = ds[0] > 0
-                    for e in range(1, E):
-                        any_v = any_v | (ds[e] > 0)
-                    dmax_r = jnp.minimum(jnp.max(exr(rk_dmax, r)), DMAX)
-                    dmax_r = jnp.minimum(dmax_r, r)
+                    ds, d_top = rank_record(r, True)
+                    any_v = d_top > 0
+                    # the scan serves the windows that stand at this rank:
+                    # nothing below reads another's minima
+                    dmax_r = jnp.max(jnp.where(here, d_top, 0))
+                    bump("steps.tb_scan", dmax_r)
 
                     # min over (slot, delta) packed as slot*256+delta: the
                     # winning predecessor is the FIRST slot whose row explains
@@ -625,8 +742,9 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                                                    (W0, W0))
                     vdiag = ~any_v & (shift_right(H0v, NEG) + scv == row)
                     vup = ~any_v & (H0v + GP == row)
-                    diag_ok = (wdiag < WNONE) | vdiag
-                    ok = diag_ok | (wup < WNONE) | vup
+                    # both moves of every cell as one word of 2 * MB bits
+                    moves = pack_moves(wdiag, wup, vdiag, vup, MB)
+                    ok = moves != no_move(MB)
 
                     # insertion run: walk left to the nearest explained cell
                     okm = ok & (jj <= jcur) & here
@@ -654,13 +772,9 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                     run = jnp.where(act, run + (jcur - j_stop), run)
 
                     # the descending move at j_stop (diag > up priority)
-                    take_diag = act & (ex_v(
-                        jnp.where(diag_ok, 1, 0), j_stop) == 1)
-                    wd = ex_v(jnp.where(wdiag == WNONE, 0, wdiag), j_stop)
-                    wd_virt = ex_v(jnp.where(wdiag == WNONE, 1, 0),
-                                   j_stop) == 1
-                    wu = ex_v(jnp.where(wup == WNONE, 0, wup), j_stop)
-                    wu_virt = ex_v(jnp.where(wup == WNONE, 1, 0), j_stop) == 1
+                    diag_ok, wd, wd_virt, wu, wu_virt = unpack_moves(
+                        ex_v(moves, j_stop, narrow=2 * MB <= NARROW_BITS), MB)
+                    take_diag = act & diag_ok
                     take_up = act & ~take_diag
 
                     kr = exr(rk_key, r)
@@ -748,7 +862,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                     act = lact & (j < Ln) & (failed == 0)
                     b = exs(seq_scr, slot, j)
                     wj = exs(w_scr, slot, j)
-                    run_j = exr(runrem, j)
+                    run_j = exr(runrem, j, narrow=True)      # <= L
                     nk_j = exr(nkey, j)
                     is_match = (run_j == 0) & act
                     k0 = nk_j
@@ -768,7 +882,8 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                     need_new = act & ~has
                     overflow = need_new & (n >= N)
                     do_new = need_new & ~overflow
-                    p_ins = wsum(jnp.where(keys <= key_val, 1, 0))
+                    p_ins = wsum(jnp.where(keys <= key_val, 1, 0),
+                                 narrow=True)                # <= N
                     nid = jnp.where(has, found, jnp.minimum(p_ins, N - 1))
 
                     # Each group pays for its own insertions only, under
@@ -835,7 +950,9 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                         insert_node(u)
 
                     touch = act & ~overflow
-                    rmw_v(rk_cov, nid, ex_v(rk_cov[...], nid) + 1, touch)
+                    # a node's coverage: at most a path a layer, <= D + 1
+                    rmw_v(rk_cov, nid,
+                          ex_v(rk_cov[...], nid, narrow=True) + 1, touch)
                     n = n + jnp.where(do_new, 1, 0)
                     failed = first_cause(failed, overflow, FAIL_NODES)
 
@@ -844,11 +961,12 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                                                 1, 0)
                     has_prev = touch & (prev_r >= 0)
                     d_tgt = nid - prev_r
-                    cntv = ex_v(rk_cnt[...], nid)
+                    cntv = ex_v(rk_cnt[...], nid, narrow=True)   # <= E
                     cnt_max = jnp.max(jnp.where(has_prev, cntv, 0))
 
                     def same_scan(e, s):
-                        de = ex_v(rk_delta[pl.ds(e, 1)][0], nid)
+                        de = ex_v(rk_delta[pl.ds(e, 1)][0], nid,
+                                  narrow=True)               # < N
                         return jnp.where((s < 0) & (e < cntv) & (de == d_tgt),
                                          e, s)
 
@@ -928,13 +1046,15 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             def score_body(r, c):
                 best_r, best_s = c
                 act = r < n
-                cnt_r = exr(rk_cnt, r)
+                cnt_r = exr(rk_cnt, r, narrow=True)          # <= E
                 bw = jnp.full(w_shape, NEG, jnp.int32)
                 bs = jnp.full(w_shape, NEG, jnp.int32)
                 bp = jnp.full(w_shape, -1, jnp.int32)
                 for e in range(E):
-                    d_e = exr(rk_delta.at[e], r)
-                    w_e = exr(rk_ew.at[e], r)
+                    # a distance is under N; an edge's weight at most two
+                    # qualities a layer, <= D * 2 * 93
+                    d_e = exr(rk_delta.at[e], r, narrow=True)
+                    w_e = exr(rk_ew.at[e], r, narrow=True)
                     valid = (d_e > 0) & (e < cnt_r)
                     s_e = ex_v(score[...], jnp.clip(r - d_e, 0, N - 1))
                     better = valid & ((w_e > bw) | ((w_e == bw) & (s_e > bs)))
@@ -962,7 +1082,7 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 u, cnt = c
                 act = (u >= 0) & (cnt < N)
                 rmw_v(revbuf, cnt, u, act)
-                pu = ex_v(spred[...], jnp.maximum(u, 0))
+                pu = ex_v(spred[...], jnp.maximum(u, 0), narrow=True)  # < N
                 return (jnp.where(act, pu, u),
                         cnt + jnp.where(act, 1, 0))
 
@@ -975,15 +1095,16 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
             cov_f = rk_cov[...]
 
             def emit(i, u, act):
-                bv = ex_v(base_f, u)
-                cv = ex_v(cov_f, u)
+                bv = ex_v(base_f, u, narrow=True)            # a code, or -1
+                cv = ex_v(cov_f, u, narrow=True)             # <= D + 1
                 m = (rr == i) & act
                 cons_base_ref[0] = jnp.where(m, bv, cons_base_ref[0])
                 cons_cov_ref[0] = jnp.where(m, cv, cons_cov_ref[0])
 
             def flip_body(i, _):
                 act = i < cnt_b
-                u = ex_v(revbuf[...], jnp.clip(cnt_b - 1 - i, 0, N - 1))
+                u = ex_v(revbuf[...], jnp.clip(cnt_b - 1 - i, 0, N - 1),
+                         narrow=True)                        # a rank, < N
                 emit(i, jnp.clip(u, 0, N - 1), act)
                 return 0
 
@@ -1071,11 +1192,8 @@ def build_lockstep_poa_kernel(cfg: PoaConfig, interpret: bool = False,
                 n_rows(),                                    # rk_cnt
                 n_rows(E),                                   # rk_delta
                 n_rows(E),                                   # rk_ew
-                n_rows(),                                    # rk_dmax
                 n_rows(),                                    # esc
-                n_rows(),                                    # score
-                n_rows(),                                    # spred
-                n_rows(),                                    # revbuf
+                n_rows(max(3, NW)),       # aux: record; score, spred, revbuf
                 j_rows(dtype=jnp.float32),                   # nkey
                 j_rows(),                                    # runrem
                 j_rows(2),                                   # seq_scr

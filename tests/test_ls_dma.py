@@ -93,12 +93,17 @@ assert swept == [2 * U * 3, 2 * U * 3 + 2], swept
 # DP runs ranks 0 .. 127, the second's 64 .. 199 (a pair a trip, from
 # the chunk its layers start in; 201 ranks once the node is in) and
 # its traceback walks down from block 3; a read's length of update
-# steps a layer; one insertion, in one block of slots
+# steps a layer; one insertion, in one block of slots; a scan trip a
+# rank of a layer's span past its first (the chain edge, at distance 1:
+# 127 of 128 ranks, 99 of the 100 from rank 100), and once the node is
+# in 101 ranks and one trip more where the chain edge spans it
 assert got == {"steps.dp": [3 * 128, 136 + 2 * 138],
                "steps.traceback": [3 * 2 * BLK, 3 * 4 * BLK],
                "steps.update": [3 * 128, 3 * 101],
                "insert.firings": [0, 1],
-               "insert.shift_steps": [0, 1]}, got
+               "insert.shift_steps": [0, 1],
+               "steps.dp_scan": [3 * 127, 99 + 2 * 101],
+               "steps.tb_scan": [3 * 127, 99 + 2 * 101]}, got
 jb, jc, jl, jf, jn = (np.asarray(x) for x in poa.build_poa_kernel(cfg)(
     bb, bbw, bb_len, nl, seqs, ws, lens, bg, en))
 assert not fl.any() and not jf.any(), (fl.ravel(), jf.ravel())

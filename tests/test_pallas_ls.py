@@ -732,7 +732,14 @@ def _check_step_counts(a, cfg, groups, nn, counts):
     what its busiest window inserted and at most what all eight did,
     each firing running one to E / SLOT_BLOCK blocks of slots; a pair
     of rank slots a trip of the DP loop, under the program's largest
-    graph a layer; whole blocks of BLK traceback ranks."""
+    graph a layer; whole blocks of BLK traceback ranks.  The two scans
+    (PR 53): a rank's DP scan runs to its largest valid in-edge
+    distance, so at least once for every backbone column of a layer's
+    span past the first (its chain edge lies inside the subgraph) of the
+    layer's widest-spanning window, and at most DMAX a rank slot; the
+    traceback's runs at a rank to the largest such distance of the
+    windows that stand there, a rank at most once a layer: never past
+    the DP's."""
     W = 8 * groups
     blocks = -(-cfg.max_edges // poa_pallas_ls.SLOT_BLOCK)
     for p, row in enumerate(counts.tolist()):
@@ -755,6 +762,13 @@ def _check_step_counts(a, cfg, groups, nn, counts):
         assert got["steps.traceback"] % poa_pallas_ls.BLK == 0 and (
             layers <= got["steps.traceback"] // poa_pallas_ls.BLK
             <= layers * -(-most // poa_pallas_ls.BLK)), (p, got)
+        chain = (np.minimum(a["en"][rows], a["bb_len"][rows, None] - 1)
+                 - np.maximum(a["bg"][rows], 0))
+        chain = np.where(live & (a["lens"][rows] > 0), chain, 0)
+        assert (chain.max(axis=0).sum() <= got["steps.dp_scan"]
+                <= poa_pallas_ls.DMAX * got["steps.dp"]), (p, got)
+        assert 0 <= got["steps.tb_scan"] <= got["steps.dp_scan"], (p, got)
+        assert (got["steps.tb_scan"] > 0) == (got["steps.dp_scan"] > 0)
 
 
 @GROUPS
@@ -791,16 +805,24 @@ def test_step_counters_with_a_pad_group_and_a_pad_program(groups):
 
 
 @pytest.mark.parametrize("pallas,insert_at,want", [
+    # reads equal to the backbone: every rank but the first has its
+    # chain edge, at distance 1, and every window walks every rank back
     ("1", None, {"layers": 4, "steps.update": 4 * 100,
-                 "insert.firings": 0, "insert.shift_steps": 0}),
+                 "insert.firings": 0, "insert.shift_steps": 0,
+                 "steps.dp_scan": 4 * 99, "steps.tb_scan": 4 * 99}),
+    # the first layer inserts a node before rank 30 of the second window:
+    # from the next layer on 101 ranks, and the chain edge that spans
+    # the new node has distance 2 (one trip more at its rank)
     ("1", 130, {"layers": 4, "steps.update": 4 * 101,
-                "insert.firings": 1, "insert.shift_steps": 1}),
+                "insert.firings": 1, "insert.shift_steps": 1,
+                "steps.dp_scan": 99 + 3 * 101,
+                "steps.tb_scan": 99 + 3 * 101}),
     ("0", None, None),
 ], ids=["ls", "ls-one-insertion", "xla"])
 def test_install_counts_every_step_counter_or_none(tmp_path, monkeypatch,
                                                    pallas, insert_at, want):
     """Through the consensus driver: an installed lockstep launch counts
-    all six poa.ls.* keys, a zero too (reads equal to the backbone fire
+    all eight poa.ls.* keys, a zero too (reads equal to the backbone fire
     no insertion; one base the target lacks fires one, in one block of
     slots), beside poa.insert.slots.*; the XLA twin counts none."""
     from racon_tpu import obs
@@ -827,6 +849,135 @@ def test_install_counts_every_step_counter_or_none(tmp_path, monkeypatch,
     assert got.pop("steps.traceback") == 4 * 2 * poa_pallas_ls.BLK
     assert 4 * 100 <= got.pop("steps.dp") <= 4 * 102
     assert got == want
+
+
+# -- packed words: what a rank step reads at one index, reduced once ---------
+
+REC_VALUES = (0, 1, 63, 64, 65, 255, 256, 5000)
+
+
+def test_in_edge_record_round_trips_every_tuple_of_distances():
+    """Every E-tuple over REC_VALUES: a word holds REC_SLOTS distances
+    and is packed by itself, so every tuple of a word at every word, the
+    other words holding one of the values each, is every E-tuple.  A
+    distance comes back capped at REC_MAX, in its own slot: what is
+    valid (0 < d <= DMAX) stays valid, what is past DMAX stays past it;
+    a word fits NARROW_BITS and one float reduction returns it."""
+    import itertools
+
+    ls = poa_pallas_ls
+    assert (ls.REC_SLOTS, ls.REC_MAX, ls.record_words(E)) == (3, 255, 4)
+    assert ls.record_words(13) == 5 and ls.record_words(8) == 3
+    word_tuples = np.array(list(itertools.product(REC_VALUES,
+                                                  repeat=ls.REC_SLOTS)))
+    for w in range(ls.record_words(E)):
+        for other in REC_VALUES:
+            d = np.full((len(word_tuples), E), other, np.int32)
+            d[:, w * ls.REC_SLOTS:(w + 1) * ls.REC_SLOTS] = word_tuples
+            deltas = [d[:, e] for e in range(E)]
+            words = ls.pack_record(deltas)
+            assert len(words) == ls.record_words(E)
+            for word in words:
+                word = np.asarray(word)
+                assert ((0 <= word) & (word < 1 << ls.NARROW_BITS)).all()
+                np.testing.assert_array_equal(
+                    np.asarray(ls.lane_sum(word[:, None], narrow=True))[:, 0],
+                    word)
+            back = [np.asarray(x) for x in ls.unpack_record(words, E)]
+            for e in range(E):
+                np.testing.assert_array_equal(
+                    back[e], np.minimum(deltas[e], ls.REC_MAX), err_msg=e)
+                np.testing.assert_array_equal(
+                    (back[e] > 0) & (back[e] <= ls.DMAX),
+                    (deltas[e] > 0) & (deltas[e] <= ls.DMAX))
+    # a last word with slots to spare (E = 8: two of three)
+    odd = [np.array([v], np.int32) for v in REC_VALUES]
+    back = ls.unpack_record(ls.pack_record(odd), len(odd))
+    assert [int(x[0]) for x in back] == [min(v, ls.REC_MAX)
+                                         for v in REC_VALUES]
+
+
+def test_narrow_lane_sum_equals_the_int_one_at_every_lane():
+    """The one float reduction against the int32 one (two float
+    reductions of 16-bit halves on the chip) for a row with one nonzero
+    term: the ends of (-2**24, 2**24), 0 and -1, at every lane; and a
+    count of ones."""
+    ls = poa_pallas_ls
+    top = (1 << ls.NARROW_BITS) - 1
+    values = np.array([top, -top, 0, -1, 1, 1 << 16, -(1 << 16) - 1],
+                      np.int32)
+    rows = np.zeros((len(values), 128, 128), np.int32)
+    for lane in range(128):
+        rows[:, lane, lane] = values
+    narrow = np.asarray(ls.lane_sum(rows, narrow=True))
+    wide = np.asarray(ls.lane_sum(rows))
+    assert narrow.dtype == wide.dtype == np.int32
+    assert narrow.shape == wide.shape == (len(values), 128, 1)
+    np.testing.assert_array_equal(narrow, wide)
+    np.testing.assert_array_equal(narrow[:, :, 0],
+                                  np.repeat(values[:, None], 128, axis=1))
+    ones = (np.arange(128)[None, :] < np.arange(129)[:, None]).astype(
+        np.int32)
+    np.testing.assert_array_equal(
+        np.asarray(ls.lane_sum(ones, narrow=True))[:, 0], np.arange(129))
+
+
+@pytest.mark.parametrize("edges", [E, 8, 16], ids=["E12", "E8", "E16"])
+def test_move_word_decodes_to_the_five_reads(edges):
+    """The traceback's word at a cell against the five values its reads
+    at j_stop gave (diag_ok, wdiag or 0, wdiag == WNONE, wup or 0,
+    wup == WNONE), for a real, a virtual and no predecessor on both
+    moves: every packed slot * 256 + distance on one move beside each
+    case of the other, and the virtual row's four cases (it needs a rank
+    with no valid in-edge, so neither move has a real predecessor
+    there).  A cell is explained unless its word is no_move's."""
+    ls = poa_pallas_ls
+    bits = ls.move_bits(edges)
+    assert bits == (12 if edges < 16 else 13)
+    assert (2 * bits <= ls.NARROW_BITS) == (edges < 16)
+    real = [s * 256 + d for s in range(edges)
+            for d in (1, 2, 63, ls.DMAX)]
+    cases = [(wd, wu, False, False) for wd in real + [ls.WNONE]
+             for wu in (real[0], real[-1], real[len(real) // 2], ls.WNONE)]
+    cases += [(wd, wu, False, False) for wu in real
+              for wd in (real[0], real[-1], ls.WNONE)]
+    cases += [(ls.WNONE, ls.WNONE, vd, vu) for vd in (False, True)
+              for vu in (False, True)]
+    wdiag, wup, vdiag, vup = (np.array(c) for c in zip(*cases))
+    wdiag, wup = wdiag.astype(np.int32), wup.astype(np.int32)
+    word = np.asarray(ls.pack_moves(wdiag, wup, vdiag, vup, bits))
+    assert ((0 <= word) & (word < 1 << 2 * bits)).all()
+    diag_ok, wd, wd_virt, wu, wu_virt = (
+        np.asarray(x) for x in ls.unpack_moves(word, bits))
+    np.testing.assert_array_equal(diag_ok, (wdiag < ls.WNONE) | vdiag)
+    np.testing.assert_array_equal(wd, np.where(wdiag == ls.WNONE, 0, wdiag))
+    np.testing.assert_array_equal(wd_virt, wdiag == ls.WNONE)
+    np.testing.assert_array_equal(wu, np.where(wup == ls.WNONE, 0, wup))
+    np.testing.assert_array_equal(wu_virt, wup == ls.WNONE)
+    np.testing.assert_array_equal(
+        word != ls.no_move(bits),
+        (wdiag < ls.WNONE) | vdiag | (wup < ls.WNONE) | vup)
+    if 2 * bits <= ls.NARROW_BITS:
+        np.testing.assert_array_equal(
+            np.asarray(ls.lane_sum(word[:, None], narrow=True))[:, 0], word)
+
+
+def test_scratch_holds_the_record_where_the_consensus_arrays_were():
+    """The record's words live in the rows the layer loop leaves idle
+    (score, spred, revbuf and what was rk_dmax): at E = 12 the VMEM sum
+    is what it was before the record, (9 + 2 E) node rows beside the
+    ring, the j rows and the I/O blocks; a geometry with fewer slots
+    needs the consensus walk's three."""
+    cfg = poa_driver.make_config(500, 200, 5, -4, -8)
+    assert cfg.max_edges == 12
+    NC, JC = cfg.max_nodes // 128, -(-(cfg.max_len + 1) // 128)
+    for groups in (1, 2, 4):
+        lane_bytes = groups * 8 * 128 * 4
+        assert poa_pallas_ls.scratch_bytes(cfg, groups) == lane_bytes * (
+            poa_pallas_ls.RING * JC + 7 * JC + (9 + 2 * 12) * NC + 4 * NC)
+    small = cfg._replace(max_edges=6)
+    assert (poa_pallas_ls.scratch_bytes(cfg) - poa_pallas_ls.scratch_bytes(
+        small)) == (12 + 1) * NC * 8 * 128 * 4
 
 
 # -- node insertions at the ranks and chunk boundaries a shift can miss ------

@@ -480,6 +480,39 @@ def test_kernel_bundles_reads_the_base_kernels_two_loops(single_device,
     assert dp > 0 and 0 < walk < 400
 
 
+def test_kernel_bundles_reads_the_lockstep_kernels_loops_by_name(capsys):
+    """`--kernel ls` finds `racon_poa_ls`'s loops by their place in the
+    loop tree at class 512 in a program of thirty-two, and the two rank
+    loops stay at what PR 53 shipped them with (+3 %): a DP rank pair
+    3272 bundles (3619 while a rank read its twelve in-edge distances
+    and their maximum as thirteen int32 lane sums, each two float
+    reductions), a traceback rank 1048 (1473 with those and five reads
+    at j_stop), by this libtpu's scheduler."""
+    from racon_tpu.tools import kernel_bundles
+
+    kernel_bundles.main(["--kernel", "ls", "--window", "500", "--depth",
+                         "200", "--groups", "4"])
+    out = capsys.readouterr().out
+    if "no dump" in out:
+        pytest.skip("this libtpu writes no LLO dumps")
+    got = {name: tuple(int(n) for n in nums) for name, *nums in re.findall(
+        r": (\w+) (\d+) bundles a trip, (\d+) ops, (\d+) cross-lane adds, "
+        r"(\d+) bundles of at most one op", out)}
+    assert list(got) == [name for name, _ in kernel_bundles.LS_LOOPS]
+    assert 0 < got["dp_pair"][0] <= 3272 * 1.03
+    assert 0 < got["tb_rank"][0] <= 1048 * 1.03
+    # a rank's four record words and its base are one float reduction a
+    # sublane group each, esc's H value two; a traceback rank's word at
+    # j_stop one, its column key one
+    assert got["dp_pair"][2] == 2 * (5 + 2) * 4
+    assert got["tb_rank"][2] == (5 + 1 + 1) * 4
+    for inner, outer in (("delta_scan", "dp_pair"), ("mscan", "tb_rank"),
+                         ("insert_shift", "update_step")):
+        assert 0 < got[inner][0] < got[outer][0]
+        assert got[inner][2] == 0          # no reduction inside a scan
+    assert all(ops >= bundles for bundles, ops, _, _ in got.values())
+
+
 # -- Mosaic compile over the mesh ------------------------------------------
 
 @pytest.fixture
